@@ -41,12 +41,14 @@ import numpy as np
 from .models import full_forward, get_config, init_kv_cache, init_params
 from .models.config import ModelConfig
 from .models.partition import StagePlan, parse_splits, slice_stage_params
+from .native import codec_name
 from .ops.sampling import SamplingParams
 from .runtime.client import PipelineClient, make_server_record
 from .runtime.executor import StageExecutor
 from .runtime.server import ElasticStageServer
 from .runtime.transport import LocalTransport
 from .scheduling.registry import PlacementRegistry
+from .utils.platform import compile_cache_dir, device_line
 
 logger = logging.getLogger("mini_petals_tpu")
 
@@ -57,6 +59,12 @@ def _emit(*parts, **kwargs) -> None:
     Diagnostics belong on a logger; _emit is for the REPORT a mode exists
     to print — generation text, status tables, scrape output."""
     print(*parts, **kwargs)  # noqa: T201 — the one sanctioned print
+
+# Random init as ONE jitted program: eager, every leaf's RNG ops compile one
+# by one (53 s for gpt2-xl on the v5e) and hold float32 copies of whole
+# stacks. Every role loads through it, so they all hold the same weights.
+_init_params_jit = jax.jit(init_params, static_argnums=(1, 2))
+
 
 # float16 runs as bfloat16: TPUs have no fp16 compute path (load_model warns).
 _DTYPE_MAP = {"float32": jnp.float32, "bfloat16": jnp.bfloat16,
@@ -116,7 +124,37 @@ def _is_remote(checkpoint) -> bool:
     return bool(checkpoint) and checkpoint.startswith(("http://", "https://"))
 
 
+def _preset_config(args) -> ModelConfig:
+    """The --model preset, depth cut to --num_layers when given (every
+    width stays as published; random-init only)."""
+    import dataclasses
+
+    cfg = get_config(args.model)
+    if args.num_layers:
+        if not 0 < args.num_layers <= cfg.num_layers:
+            raise SystemExit(
+                f"--num_layers {args.num_layers} outside 1..{cfg.num_layers} "
+                f"for {args.model}")
+        cfg = dataclasses.replace(cfg, num_layers=args.num_layers)
+    return cfg
+
+
+def load_config(args) -> ModelConfig:
+    """The model's config WITHOUT its weights: what the client and gateway
+    need at start-up (their stage-0 weights load on first use)."""
+    if args.checkpoint:
+        from .models.hf_import import config_from_checkpoint
+
+        return config_from_checkpoint(
+            _remote_store(args).fetch_config() if _is_remote(args.checkpoint)
+            else args.checkpoint)
+    return _preset_config(args)
+
+
 def load_model(args) -> Tuple[ModelConfig, dict]:
+    if args.checkpoint and args.num_layers:
+        raise SystemExit("--num_layers cuts a random-init preset; a "
+                         "--checkpoint serves the depth it was saved with")
     if args.dtype == "float16":
         # TPUs have no fp16 compute path; bf16 differs numerically (8-bit
         # exponent / 7-bit mantissa vs 5/10) so an fp16 baseline will not
@@ -172,10 +210,10 @@ def load_model(args) -> Tuple[ModelConfig, dict]:
                            if jnp.issubdtype(x.dtype, jnp.floating) else x),
                 params)
         return cfg, params
-    cfg = get_config(args.model)
+    cfg = _preset_config(args)
     logger.info("no --checkpoint: random-initializing %s (%d layers)",
                 args.model, cfg.num_layers)
-    return cfg, init_params(jax.random.PRNGKey(args.seed), cfg, dtype=dtype)
+    return cfg, _init_params_jit(jax.random.PRNGKey(args.seed), cfg, dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -779,6 +817,9 @@ def _generate_and_report(args, generate_fn, cfg: ModelConfig,
     _emit(f"\n=== Generation ({len(res.tokens)} tokens, "
           f"stopped by {res.stopped_by}) ===")
     _emit(text)
+    # The ids themselves: the byte tokenizer's text is lossy, and parity
+    # checks (chip_smoke.py) compare tokens.
+    _emit(f"IDS {json.dumps([int(t) for t in res.tokens])}")
     _emit(f"\nTTFT: {res.ttft_s:.3f}s")
     total_decode = sum(res.decode_times_s)
     _emit(f"Decode: {total_decode:.3f}s total, "
@@ -956,6 +997,12 @@ def run_serve(args, cfg: ModelConfig, params) -> int:
                   burst=getattr(args, "burst", 0))
     else:
         ex.warmup()
+    if args.quant != "none":
+        # Which quantized matmul sites the warm-up traced, and where each
+        # ran (Pallas kernel or XLA) — read by chip_smoke.py.
+        from .ops import quant_kernel_report
+
+        _emit("KERNELS " + json.dumps(quant_kernel_report()), flush=True)
     # Per-session executors serialize compute through the prioritized
     # runtime (one compute thread owns the chip; N handler threads own the
     # sockets — the reference's handlers→Runtime split). The batched engine
@@ -1059,7 +1106,8 @@ def run_serve(args, cfg: ModelConfig, params) -> int:
                        extra_peers_fn=_seed_peers)
     gloop.start()
     _emit(f"SERVING stage={args.stage} span=[{spec.start},{spec.end}) "
-          f"addr={advert} peer={ex.peer_id}", flush=True)
+          f"addr={advert} peer={ex.peer_id} {device_line()} "
+          f"codec={codec_name()}", flush=True)
     # Next-hop RTT probe (petals/server/server.py:760-767) reuses ping_tx.
     from .runtime.server import measure_next_server_rtts as _rtts
 
@@ -1200,7 +1248,8 @@ def _run_serve_elastic(args, cfg: ModelConfig, params) -> int:
     )
     es.start()
     _emit(f"SERVING elastic span=[{es.spec.start},{es.spec.end}) "
-          f"addr={advert} peer={peer}", flush=True)
+          f"addr={advert} peer={peer} {device_line()} "
+          f"codec={codec_name()}", flush=True)
 
     from .runtime.net import gossip_exchange as _gx
     from .scheduling.registry import rec_to_dict as _r2d
@@ -1233,19 +1282,52 @@ def _run_serve_elastic(args, cfg: ModelConfig, params) -> int:
     return 0
 
 
+def _lazy_stage0(args, cfg: ModelConfig, plan: StagePlan,
+                 peer_ids: Sequence[str]) -> list:
+    """One zero-arg stage-0 factory per peer id, sharing one weight load.
+
+    The client and gateway are host-side roles until a classic route
+    starts after block 0: a session served whole by a full-span peer (every
+    --burst session, and plain per-step ones while such a peer is live)
+    computes nothing locally, so start-up loads no weights and opens no
+    device. The first classic route builds stage 0 — that process then
+    owns a chip, and says which on its STAGE0 line."""
+    import threading
+
+    lock = threading.Lock()
+    loaded: list = []
+
+    def weights():
+        with lock:
+            if not loaded:
+                loaded.append(load_model(args)[1])
+            return loaded[0]
+
+    def factory(peer_id: str):
+        def build() -> StageExecutor:
+            spec = plan.stages[0]
+            ex = StageExecutor(cfg, spec,
+                               _stage_params(args, cfg, weights(), spec),
+                               peer_id=peer_id)
+            _emit(f"STAGE0 span=[{spec.start},{spec.end}) peer={peer_id} "
+                  f"{device_line()}", flush=True)
+            return ex
+        return build
+
+    return [factory(p) for p in peer_ids]
+
+
 def run_client(args, cfg: ModelConfig, params) -> int:
-    from .runtime.executor import StageExecutor as _SE
     from .runtime.net import RemoteRegistry, TcpTransport
 
+    del params  # stage-0 weights load on first use (_lazy_stage0)
     splits = parse_splits(args.splits) if args.splits else None
     plan = (StagePlan.from_splits(cfg.num_layers, splits) if splits
             else StagePlan.even(cfg.num_layers, 4))
     registry = RemoteRegistry(args.registry_addr, peers_cache=args.peers_cache)
     transport = TcpTransport(registry, wire_dtype=args.wire_dtype,
                              model=_model_id(args))
-    stage0 = _SE(cfg, plan.stages[0],
-                 _stage_params(args, cfg, params, plan.stages[0]),
-                 peer_id="client-local")
+    stage0, = _lazy_stage0(args, cfg, plan, ["client-local"])
     client = PipelineClient(
         cfg, plan, stage0, transport, registry,
         use_module_routing=bool(args.use_load_balancing),
@@ -1279,10 +1361,10 @@ def run_gateway(args, cfg: ModelConfig, params) -> int:
     """--mode gateway: the multi-tenant serving front door. Owns one or
     more PipelineClients against the swarm at --registry_addr and serves
     the framed-TCP `submit` verb (docs/SERVING.md)."""
-    from .runtime.executor import StageExecutor as _SE
     from .runtime.net import RemoteRegistry, TcpTransport
     from .serving import GatewayServer
 
+    del params  # stage-0 weights load on first use (_lazy_stage0)
     tenants, max_queue_depth, max_active = _load_tenants_config(args.tenants)
     splits = parse_splits(args.splits) if args.splits else None
     plan = (StagePlan.from_splits(cfg.num_layers, splits) if splits
@@ -1291,13 +1373,13 @@ def run_gateway(args, cfg: ModelConfig, params) -> int:
                               peers_cache=args.peers_cache)
     transports = []
     clients = []
-    for i in range(max(1, args.gateway_clients)):
+    n_clients = max(1, args.gateway_clients)
+    stage0s = _lazy_stage0(args, cfg, plan,
+                           [f"gateway-local-{i}" for i in range(n_clients)])
+    for stage0 in stage0s:
         tx = TcpTransport(registry, wire_dtype=args.wire_dtype,
                           model=_model_id(args))
         transports.append(tx)
-        stage0 = _SE(cfg, plan.stages[0],
-                     _stage_params(args, cfg, params, plan.stages[0]),
-                     peer_id=f"gateway-local-{i}")
         clients.append(PipelineClient(
             cfg, plan, stage0, tx, registry,
             use_module_routing=bool(args.use_load_balancing),
@@ -1313,11 +1395,15 @@ def run_gateway(args, cfg: ModelConfig, params) -> int:
                        port=args.rpc_port,
                        max_queue_depth=max_queue_depth,
                        max_active=max_active,
-                       allow_fault_injection=args.allow_fault_injection)
+                       allow_fault_injection=args.allow_fault_injection,
+                       burst=args.burst)
     gw.start()
+    # Host-side until a classic route builds stage 0 (which then prints
+    # its own STAGE0 line with the device it opened).
     _emit(f"GATEWAY addr={gw.address} tenants={','.join(sorted(tenants))} "
           f"clients={len(clients)} max_queue_depth={max_queue_depth} "
-          f"max_active={max_active}", flush=True)
+          f"max_active={max_active} burst={args.burst} stage0=on-first-use "
+          f"codec={codec_name()}", flush=True)
     try:
         while True:
             time.sleep(3600)
@@ -2493,6 +2579,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "structured text format")
     p.add_argument("--model", default="gpt2",
                    help="architecture preset (gpt2[-xl], llama-3-8b, ...)")
+    p.add_argument("--num_layers", type=int, default=None, metavar="N",
+                   help="random-init presets: cut the depth to the first N "
+                        "layers, every width as published (how a model "
+                        "that does not fit one chip is sized for it)")
     p.add_argument("--model_name", default=None,
                    help="swarm-scoping model id for the registry (the model "
                         "name embedded in every reference DHT key, "
@@ -3252,7 +3342,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         return run_top(args)  # no model needed
     if args.mode == "submit":
         return run_submit(args)  # no weights: tokenizer + preset cfg only
-    cfg, params = load_model(args)
+    compile_cache_dir()
+    if args.mode in ("client", "gateway"):
+        # Host-side until a classic route needs stage 0 (_lazy_stage0).
+        cfg, params = load_config(args), None
+    else:
+        cfg, params = load_model(args)
     run = {"local": run_local, "fused": run_fused, "oracle": run_oracle,
            "serve": run_serve, "client": run_client,
            "chaos": run_chaos, "gateway": run_gateway}[args.mode]

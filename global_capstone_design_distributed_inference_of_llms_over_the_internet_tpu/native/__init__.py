@@ -1,8 +1,10 @@
 """ctypes bindings for the native wire codec, with numpy fallbacks.
 
-Auto-builds ``libcodec.so`` on first import when a compiler is available
-(`make -C native`); otherwise the numpy implementations serve — identical
-semantics (round-to-nearest-even bf16, CRC-32C), just slower.
+``libcodec.so`` is built from ``codec.cpp`` on first use (`make -C native`;
+the binary is not in git). Where the build or load fails the numpy
+implementations serve — identical semantics (round-to-nearest-even bf16,
+CRC-32C) but a per-byte Python CRC loop — at a WARNING, and every serving
+role prints ``codec_name()`` at start-up so the fallback cannot hide.
 """
 
 from __future__ import annotations
@@ -33,7 +35,8 @@ def _load() -> Optional[ctypes.CDLL]:
                 capture_output=True, timeout=60,
             )
         except Exception as exc:
-            logger.info("native codec build unavailable (%s); numpy fallback", exc)
+            logger.warning("native codec build failed (%s); numpy fallback",
+                           exc)
             return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
@@ -46,12 +49,17 @@ def _load() -> Optional[ctypes.CDLL]:
         _lib = lib
         return lib
     except OSError as exc:
-        logger.info("native codec load failed (%s); numpy fallback", exc)
+        logger.warning("native codec load failed (%s); numpy fallback", exc)
         return None
 
 
 def have_native() -> bool:
     return _load() is not None
+
+
+def codec_name() -> str:
+    """"native" (libcodec.so) or "numpy-fallback": what the wire uses."""
+    return "native" if have_native() else "numpy-fallback"
 
 
 def fp32_to_bf16_bytes(arr: np.ndarray) -> bytes:

@@ -26,3 +26,20 @@ __all__ = [
     "sample_probs",
     "sample_token",
 ]
+
+
+def quant_kernel_report() -> dict:
+    """Where this process's quantized matmul sites ran, as traced so far:
+    per kernel, whether it is interpreted, how many sites took the Pallas
+    path, and each site ``MxKxN`` -> "pallas tn=..,tk=.." or "xla"."""
+    from . import int8_kernel, nf4_kernel
+
+    return {
+        name: {
+            "interpret": mod._INTERPRET,
+            "launches": mod._launches,
+            "sites": {"x".join(map(str, mkn)): where
+                      for mkn, where in sorted(mod._sites.items())},
+        }
+        for name, mod in (("int8", int8_kernel), ("nf4", nf4_kernel))
+    }

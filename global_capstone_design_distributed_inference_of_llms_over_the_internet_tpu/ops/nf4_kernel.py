@@ -17,10 +17,10 @@ matmul is split by nibble parity:
 which is exact because matmul contraction is order-free. The activation
 is split host-side (x[:, 0::2], x[:, 1::2] — tiny [M, K] tensors).
 
-Grid: one program per N tile (128- or 256-wide — `_tile_n` picks the
-widest that divides N and fits the VMEM budget), full-K stripes (the K
-loop lives in the MXU contraction; no cross-program accumulation
-state). Tile-size gotchas learned on-chip, encoded as guards below: N
+Grid: (N tiles, K stripes) with an f32 accumulator across the K axis —
+`_tiles` picks the pair that moves the most bytes per step among those
+whose own VMEM estimate fits, and a K that fits whole is one stripe.
+Tile-size gotchas learned on-chip, encoded as guards below: N
 must split into whole tiles (a non-dividing grid silently truncates),
 scales ride as f32 so the scale block's sublane count stays legal, and
 the uint8 block is widened to int32 BEFORE shifting (Mosaic cannot
@@ -50,6 +50,7 @@ pinned by tests/test_nf4_kernel.py.
 from __future__ import annotations
 
 import functools
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,9 +59,18 @@ import jax.numpy as jnp
 # models.quant.dequant_tree (which decides whether packed NF4 leaves
 # reach the matmul sites at all); nf4_dot itself dispatches purely on
 # leaf type and shape.
-from ..models.quant import NF4_LEVELS, NF4Tensor, _lut16
+from ..models.quant import NF4_BLOCK, NF4_LEVELS, NF4Tensor, _lut16
 
 TILE_N = 128
+# Packed rows per scale row: a packed byte holds two K rows, and one
+# absmax scale covers NF4_BLOCK of them.
+_ROWS_PER_SCALE = NF4_BLOCK // 2
+
+# What `_tiles` plans a program's VMEM footprint against, and what Mosaic
+# is allowed to use (`vmem_limit_bytes`): the difference is room for the
+# compiler's own temporaries, which the estimate cannot see.
+VMEM_BUDGET = 12 * 1024 * 1024
+VMEM_LIMIT = 32 * 1024 * 1024
 
 # Tests flip this to run the kernel through the Pallas interpreter on the
 # CPU backend (slow, exact semantics) — the kernel itself targets TPU.
@@ -71,29 +81,49 @@ _INTERPRET = False
 # tests can pin "launch sites per decode step" without running on-chip.
 _launches = 0
 
-
-def _vmem_bytes(m: int, p: int, sb: int, tn: int, x_bytes: int) -> int:
-    """Per-program VMEM footprint estimate, double-buffered: two x blocks
-    [m, p], packed [p, tn] u8, scales [sb, tn] f32, two dequantized weight
-    tiles [p, tn], and the out tile [m, tn] f32."""
-    one = (2 * m * p * x_bytes + p * tn + sb * tn * 4
-           + 2 * p * tn * x_bytes + m * tn * 4)
-    return 2 * one
+# Trace-time record of where each matmul site ran: (m, k, n) -> "pallas
+# tn=..,tk=.." or "xla". chip_smoke.py prints it, so a backend string
+# that stops matching cannot turn the kernel off unnoticed.
+_sites: Dict[Tuple[int, int, int], str] = {}
 
 
-def _tile_n(n: int, k: int, m: int, x_bytes: int) -> int:
-    """Widest N tile that divides N AND fits the VMEM budget: 256 halves
-    the grid steps per launch (measured +3.8% flagship nf4 decode,
-    7.04 -> 6.78 ms/step; post gate+up fusion every flagship/gpt2 N
-    divides 256). The budget guard matters: 512 already exceeded VMEM at
-    the flagship K (compile failure, measured), and a larger-K model or a
-    big prefill m would hit the same wall at 256 — fall back to 128
-    rather than fail a shape that used to serve."""
-    p, sb = k // 2, k // 64
-    budget = 12 * 1024 * 1024          # ~16 MB/core minus headroom
-    if n % 256 == 0 and _vmem_bytes(m, p, sb, 256, x_bytes) <= budget:
-        return 256
-    return TILE_N
+def _vmem_bytes(m: int, tp: int, tn: int, x_bytes: int) -> int:
+    """Per-program VMEM footprint estimate for one (tp, tn) grid step
+    (tp = PACKED rows, i.e. 2*tp rows of K). Pipelined blocks are
+    double-buffered: two x blocks [m, tp], packed [tp, tn] u8, scales
+    [tp/32, tn] f32 and the out tile [m, tn]. In-kernel temporaries exist
+    once per [tp, tn] element: the int32 widening of the packed bytes,
+    one int32 nibble plane, the repeated f32 scale, the f32 codebook
+    value and its scaled product, and the two activation-dtype weight
+    tiles; plus the f32 accumulator scratch and partial product."""
+    pipelined = (2 * m * tp * x_bytes + tp * tn
+                 + max(tp // _ROWS_PER_SCALE, 8) * tn * 4
+                 + m * tn * x_bytes)
+    temps = tp * tn * (5 * 4 + 2 * x_bytes) + 2 * m * tn * 4
+    return 2 * pipelined + temps
+
+
+def _tiles(n: int, k: int, m: int, x_bytes: int) -> Optional[Tuple[int, int]]:
+    """(tn, tp) moving the most weight bytes per grid step among the
+    tiles whose own estimate fits VMEM_BUDGET (ties: the longer K stripe,
+    so a shape that fits whole runs one K step). tn divides N; tp divides
+    the packed row count P = K/2 and is either P itself or a multiple of
+    256, so the scale block [tp/32, tn] keeps a legal f32 sublane count.
+    None when nothing fits: the shape then takes the dequant path."""
+    p = k // 2
+    stripes = [p] + [t for t in range(p - p % 256, 0, -256)
+                     if t != p and p % t == 0]
+    best = None
+    for tn in (512, 256, TILE_N):
+        if n % tn:
+            continue
+        for tp in stripes:
+            if _vmem_bytes(m, tp, tn, x_bytes) <= VMEM_BUDGET:
+                cand = (tp * tn, tp, tn)
+                if best is None or cand > best:
+                    best = cand
+                break
+    return None if best is None else (best[2], best[1])
 
 # MOSAIC CONSTRAINT on quant._lut16 (one shared select tree): the level
 # constants must stay f32 — bf16 levels would make Mosaic relayout the
@@ -106,47 +136,61 @@ def _tile_n(n: int, k: int, m: int, x_bytes: int) -> int:
 def _make_kernel(m: int, k: int, n: int, out_dtype: str,
                  interpret: bool = False):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     p = k // 2
-    sb = k // 64
-    tn = _tile_n(n, k, m, jnp.dtype(out_dtype).itemsize)
+    tn, tp = _tiles(n, k, m, jnp.dtype(out_dtype).itemsize)
+    ts = tp // _ROWS_PER_SCALE
 
-    def kernel(xe_ref, xo_ref, pk_ref, sc_ref, out_ref):
-        packed = pk_ref[:].astype(jnp.int32)   # int32 FIRST: Mosaic has no
-        hi = (packed >> 4) & 0xF               # vector i8 shrui
+    def kernel(xe_ref, xo_ref, pk_ref, sc_ref, out_ref, acc_ref):
+        kk = pl.program_id(1)
+
+        @pl.when(kk == 0)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        packed = pk_ref[...].astype(jnp.int32)  # int32 FIRST: Mosaic has
+        hi = (packed >> 4) & 0xF                # no vector i8 shrui
         lo = packed & 0xF
-        scale = jnp.repeat(sc_ref[:], p // sb, axis=0)      # [P, tn]
+        scale = jnp.repeat(sc_ref[...], _ROWS_PER_SCALE, axis=0)  # [tp, tn]
         # Weights take the ACTIVATION dtype (bf16 serving feeds the MXU at
         # bf16 rate; an f32 activation keeps f32 — also what the CPU
         # interpreter's dot supports).
         wdt = xe_ref.dtype
         wh = (_lut16(hi, NF4_LEVELS) * scale).astype(wdt)
         wl = (_lut16(lo, NF4_LEVELS) * scale).astype(wdt)
-        acc = jnp.dot(xe_ref[:], wh, preferred_element_type=jnp.float32)
-        acc = acc + jnp.dot(xo_ref[:], wl,
-                            preferred_element_type=jnp.float32)
-        out_ref[:] = acc.astype(out_ref.dtype)
+        acc = jnp.dot(xe_ref[...], wh, preferred_element_type=jnp.float32)
+        acc_ref[...] += acc + jnp.dot(xo_ref[...], wl,
+                                      preferred_element_type=jnp.float32)
+
+        @pl.when(kk == pl.num_programs(1) - 1)
+        def _():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
     @jax.jit
     def fn(xe, xo, packed, scales):
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
-            grid=(n // tn,),
+            grid=(n // tn, p // tp),
             in_specs=[
-                pl.BlockSpec((m, p), lambda j: (0, 0)),
-                pl.BlockSpec((m, p), lambda j: (0, 0)),
-                pl.BlockSpec((p, tn), lambda j: (0, j)),
-                pl.BlockSpec((sb, tn), lambda j: (0, j)),
+                pl.BlockSpec((m, tp), lambda j, kk: (0, kk)),
+                pl.BlockSpec((m, tp), lambda j, kk: (0, kk)),
+                pl.BlockSpec((tp, tn), lambda j, kk: (kk, j)),
+                pl.BlockSpec((ts, tn), lambda j, kk: (kk, j)),
             ],
-            out_specs=pl.BlockSpec((m, tn), lambda j: (0, j)),
+            out_specs=pl.BlockSpec((m, tn), lambda j, kk: (0, j)),
+            scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT),
             interpret=interpret,
         )(xe, xo, packed, scales)
 
     return fn
 
 
-def _supported(m: int, w: NF4Tensor) -> bool:
+def _supported(m: int, w: NF4Tensor, x_bytes: int) -> bool:
     in_dim = w.in_dim
     n = w.packed.shape[-1]
     assert m % 8 == 0, "caller pads rows to a multiple of 8"
@@ -154,7 +198,8 @@ def _supported(m: int, w: NF4Tensor) -> bool:
             and in_dim == w.packed.shape[0] * 2   # no in-axis padding
             and in_dim % 128 == 0
             and n % TILE_N == 0
-            and (jax.default_backend() == "tpu" or _INTERPRET))
+            and (jax.default_backend() == "tpu" or _INTERPRET)
+            and _tiles(n, in_dim, m, x_bytes) is not None)
 
 
 def nf4_dot(x: jnp.ndarray, w: NF4Tensor) -> jnp.ndarray:
@@ -169,13 +214,17 @@ def nf4_dot(x: jnp.ndarray, w: NF4Tensor) -> jnp.ndarray:
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
     m_pad = -(-max(m, 8) // 8) * 8
-    if _supported(m_pad, w):
+    n = w.packed.shape[-1]
+    if _supported(m_pad, w, x.dtype.itemsize):
         _launches += 1
+        _sites[(m_pad, k, n)] = "pallas tn=%d,tk=%d" % tuple(
+            t * f for t, f in zip(_tiles(n, k, m_pad, x.dtype.itemsize),
+                                  (1, 2)))
         if m_pad != m:
             x2 = jnp.pad(x2, ((0, m_pad - m), (0, 0)))
-        fn = _make_kernel(m_pad, k, w.packed.shape[-1], str(x.dtype),
-                          interpret=_INTERPRET)
+        fn = _make_kernel(m_pad, k, n, str(x.dtype), interpret=_INTERPRET)
         out = fn(x2[:, 0::2], x2[:, 1::2], w.packed,
                  w.scales.astype(jnp.float32))
         return out[:m].reshape(*lead, -1)
+    _sites[(m_pad, k, n)] = "xla"
     return x @ w.dequant().astype(x.dtype)

@@ -1,33 +1,2 @@
 """Single-program parallel engines (fused pipeline, ring decode/attention,
-tensor/sequence/expert parallelism).
-
-Compat: these modules target the promoted ``jax.shard_map`` (jax >= 0.4.38).
-On older jax the same function lives at ``jax.experimental.shard_map``; graft
-it onto the jax namespace here — every ``parallel.*`` import runs through
-this package first, so both the ``jax.shard_map`` attribute uses and
-``from jax import shard_map`` resolve on either version. Call sites only use
-the kwargs common to both (mesh/in_specs/out_specs).
-"""
-
-import functools
-
-import jax
-
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # check_rep=False: the old replication checker has no rule for
-    # while/fori_loop bodies (the engines' tick loops) and the new vma
-    # annotations it would want (pcast) don't exist here; disabling it is
-    # the jax-documented workaround and does not change computed values.
-    jax.shard_map = functools.partial(_shard_map, check_rep=False)
-
-if not hasattr(jax.lax, "pcast"):
-    # ``pcast(x, axes, to="varying")`` is a varying-manual-axes TYPE
-    # annotation (new-jax check_vma); old shard_map's check_rep infers
-    # replication itself, so the value-level identity is exact.
-    def _pcast(x, axis_name, to=None):
-        del axis_name, to
-        return x
-
-    jax.lax.pcast = _pcast
+tensor/sequence/expert parallelism)."""

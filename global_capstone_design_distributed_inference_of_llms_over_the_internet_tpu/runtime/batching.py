@@ -142,6 +142,34 @@ def _residual(cfg, lp, h, attn_out):
     return h + mlp_out
 
 
+def _scan_layers_in_place(layer, h, layers, k_all, v_all):
+    """``lax.scan`` over the span's layers with the cache stacks as CARRY:
+    each layer reads and writes its own ``[S, max_len, Hkv, Dh]`` slice by
+    index, so XLA updates the stacks in place. ``layer(h, (lp, (k_l, v_l)))
+    -> (h, (k_l, v_l))``.
+
+    As scan xs/ys the stacks are rewritten into a second buffer every step,
+    input and output both live. On the TPU the ``[.., Hkv, Dh]`` minor dims
+    pad to (8,128) tiles — 2.6x at gpt2-xl's 25 x 64 — so at 8 slots x 1024
+    rows each copy is 3 GB and the burst program asked for 17.3 GB of a
+    v5e's 15.75 (chip run, PR 21)."""
+
+    def body(carry, xs):
+        h, k_all, v_all = carry
+        lp, i = xs
+        k_l = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+        v_l = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
+        h, (k_l, v_l) = layer(h, (lp, (k_l, v_l)))
+        k_all = jax.lax.dynamic_update_index_in_dim(k_all, k_l, i, 0)
+        v_all = jax.lax.dynamic_update_index_in_dim(v_all, v_l, i, 0)
+        return (h, k_all, v_all), None
+
+    (h, k_all, v_all), _ = jax.lax.scan(
+        body, (h, k_all, v_all),
+        (layers, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
+    return h, k_all, v_all
+
+
 class BatchedStageExecutor:
     """One stage span serving up to `slots` sessions with batched decode."""
 
@@ -620,8 +648,8 @@ class BatchedStageExecutor:
                 h = _residual(cfg, lp, h, out)
                 return h, (k_l, v_l)
 
-            h, (k_all, v_all) = jax.lax.scan(
-                layer, h, (params["layers"], (k_all, v_all)))
+            h, k_all, v_all = _scan_layers_in_place(
+                layer, h, params["layers"], k_all, v_all)
             # Inactive slots produced garbage — zero them so nothing
             # downstream can mistake them for real activations.
             h = jnp.where(active[:, None, None], h, 0.0)
@@ -677,8 +705,11 @@ class BatchedStageExecutor:
         step = self._decode_jits.get(t)
         if step is None:
             step = self._decode_jits[t] = self._build_decode(t)
+        # lengths is COPIED: jnp.asarray may alias a numpy buffer (the CPU
+        # client does, zero-copy, whenever it is 64-byte aligned) and the
+        # host bumps self.lengths below while the step is still in flight.
         h, self.k, self.v = step(
-            self.params, jnp.asarray(x), jnp.asarray(self.lengths),
+            self.params, jnp.asarray(x), jnp.asarray(self.lengths.copy()),
             jnp.asarray(active), self.k, self.v)
         for s in rows:
             self.lengths[s] += t
@@ -773,8 +804,8 @@ class BatchedStageExecutor:
                     h = _residual(cfg, lp, h, out)
                     return h, (k_l, v_l)
 
-                h, (k_all, v_all) = jax.lax.scan(
-                    layer, h, (params["layers"], (k_all, v_all)))
+                h, k_all, v_all = _scan_layers_in_place(
+                    layer, h, params["layers"], k_all, v_all)
                 h = jnp.where(active[:, None, None], h, 0.0)
                 logits = lm_head(cfg, params, h)[:, 0]        # [S, V] fp32
                 keys = jax.vmap(jax.random.PRNGKey)(seeds + i)
@@ -885,7 +916,8 @@ class BatchedStageExecutor:
             rp[s] = float(e["repetition_penalty"])
             alive[s] = True
             rows[sid] = s
-        args = (jnp.asarray(tok0), jnp.asarray(self.lengths),
+        # lengths copied for the same reason as in decode_batch.
+        args = (jnp.asarray(tok0), jnp.asarray(self.lengths.copy()),
                 jnp.asarray(alive), jnp.asarray(seeds), jnp.asarray(recent),
                 jnp.asarray(nvalid), jnp.asarray(run0), jnp.asarray(left),
                 jnp.asarray(eos), jnp.asarray(temp), jnp.asarray(top_p),

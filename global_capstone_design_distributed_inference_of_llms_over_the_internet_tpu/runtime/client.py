@@ -60,7 +60,7 @@ REPEAT_STOP = 5           # 5 consecutive identical tokens, src/main.py:197-204
 # up to SEQ_BUCKETS whose largest entry is 8192.
 MAX_COALESCED_TOKENS = 4096
 # Journal/route key for the single full-span hop a burst session pins
-# (_generate_steps_burst); _rediscover_excluding special-cases it.
+# (_generate_steps_full_span); _rediscover_excluding special-cases it.
 BURST_HOP_KEY = "burst"
 
 
@@ -332,7 +332,13 @@ class PipelineClient:
         # None = single-model swarm, all records match.
         self.model = model
         self.plan = plan
-        self.stage0 = stage0
+        # The client-local stage-0 executor, or a zero-arg factory for it:
+        # a session served whole by a full-span peer computes nothing
+        # here, so a CLI client builds its stage-0 weights (and opens the
+        # device) only when a classic route first needs them.
+        self._stage0 = None if callable(stage0) else stage0
+        self._stage0_factory = stage0 if callable(stage0) else None
+        self._stage0_lock = threading.Lock()
         self.transport = transport
         self.registry = registry
         if route_by_latency and not use_module_routing:
@@ -686,7 +692,7 @@ class PipelineClient:
         for i, e in enumerate(entries):
             req = StageRequest(
                 session_id=session_id,
-                hidden=jnp.asarray(e.hidden),
+                hidden=e.hidden,
                 seq_len=e.seq_len,
                 cur_len=e.cur_len,
                 is_prefill=(i == 0),
@@ -1260,6 +1266,14 @@ class PipelineClient:
             f"push chain: all {MAX_ATTEMPTS} attempts failed"
         ) from last_exc
 
+    @property
+    def stage0(self):
+        with self._stage0_lock:
+            if self._stage0_factory is not None:
+                self._stage0 = self._stage0_factory()
+                self._stage0_factory = None
+            return self._stage0
+
     # ------------------------------------------------------------------
     # Generation (run_rank0, src/main.py:62-227)
     # ------------------------------------------------------------------
@@ -1371,11 +1385,24 @@ class PipelineClient:
                  prompt_len=len(prompt_ids), max_new_tokens=max_new_tokens)
         recoveries_before = self.recoveries
         tokens_out = 0
-        if burst > 0:
-            steps = self._generate_steps_burst(
+        # A plain session goes WHOLE to a full-span batched peer when one
+        # is live — in N-token bursts with --burst, one token per round
+        # trip without — and then needs no client-local stage 0.
+        peer = None
+        if burst > 0 or (
+                speculative_k == 0 and deep_prompts is None
+                and not (self.long_context_threshold is not None
+                         and len(prompt_ids) >= self.long_context_threshold)):
+            peer = self._discover_burst_peer()
+        if burst > 0 and peer is None:
+            _ev.emit("burst_fallback", session_id=session_id,
+                     reason="no full-span batched peer is live")
+        if peer is not None:
+            steps = self._generate_steps_full_span(
                 prompt_ids, max_new_tokens, sampling=sampling,
                 eos_token_id=eos_token_id, session_id=session_id,
-                max_length=max_length, burst=burst, deadline_at=deadline_at)
+                max_length=max_length, peer=peer, burst=burst,
+                deadline_at=deadline_at)
         else:
             steps = self._generate_steps(
                 prompt_ids, max_new_tokens, sampling=sampling,
@@ -1586,7 +1613,7 @@ class PipelineClient:
             return None
         return max(cands, key=lambda r: r.throughput).peer_id
 
-    def _generate_steps_burst(
+    def _generate_steps_full_span(
         self,
         prompt_ids: Sequence[int],
         max_new_tokens: int,
@@ -1595,30 +1622,24 @@ class PipelineClient:
         eos_token_id: Optional[int],
         session_id: str,
         max_length: Optional[int],
+        peer: str,
         burst: int,
         deadline_at: Optional[float] = None,
     ) -> Iterator[GenerationStep]:
-        """Burst counterpart of ``_generate_steps``: the whole session runs
-        on ONE full-span batched peer, and each decode round ships a
-        ``burst_len`` request the server answers with up to N tokens from a
-        single jitted multi-tick dispatch. The client's per-token stop scan
-        stays authoritative (the device mirrors it only to stop WRITING);
-        the journal records one multi-token entry per burst — the tokens
-        whose KV the burst wrote — so failover replay rebuilds a
-        replacement peer across burst boundaries exactly."""
+        """Full-span counterpart of ``_generate_steps``: the whole session
+        runs on ONE full-span batched peer (``peer``), raw token ids in,
+        sampled tokens out — the client computes nothing. With ``burst``
+        each decode round ships a ``burst_len`` request the server answers
+        with up to N tokens from a single jitted multi-tick dispatch;
+        with ``burst == 0`` each round is one per-step decode returning one
+        token (same seeds, same stop rules: identical ids). The client's
+        per-token stop scan stays authoritative (the device mirrors it
+        only to stop WRITING); the journal records one entry per round —
+        the tokens whose KV the round wrote — so failover replay rebuilds
+        a replacement peer across round boundaries exactly."""
         sampling = sampling or SamplingParams()
         prompt_len = len(prompt_ids)
         max_length = max_length or (prompt_len + max_new_tokens)
-        peer = self._discover_burst_peer()
-        if peer is None:
-            _ev.emit("burst_fallback", session_id=session_id,
-                     reason="no full-span batched peer is live")
-            yield from self._generate_steps(
-                prompt_ids, max_new_tokens, sampling=sampling,
-                eos_token_id=eos_token_id, session_id=session_id,
-                max_length=max_length, speculative_k=0, draft_fn=None,
-                deadline_at=deadline_at)
-            return
         hop = Hop(key=BURST_HOP_KEY, peer_id=peer, start_block=0,
                   end_block=self.total_blocks, expect_token=True)
         generated: List[int] = []
@@ -1628,7 +1649,7 @@ class PipelineClient:
         t0 = time.monotonic()
         ids = np.asarray(prompt_ids, np.int32)[None, :]
         resp = self._call_with_recovery(hop, StageRequest(
-            session_id=session_id, hidden=jnp.asarray(ids),
+            session_id=session_id, hidden=ids,
             seq_len=prompt_len, cur_len=0, is_prefill=True,
             max_length=max_length, sampling=sampling, step_seed=self.seed,
             start_block=hop.start_block, end_block=hop.end_block,
@@ -1639,7 +1660,7 @@ class PipelineClient:
         ))
         if not resp.is_token:
             raise RuntimeError(
-                f"burst peer {hop.peer_id} returned no prefill token")
+                f"full-span peer {hop.peer_id} returned no prefill token")
         self._journal_append(hop.key, session_id,
                              JournalEntry(ids, prompt_len, 0))
         ttft = time.monotonic() - t0
@@ -1647,7 +1668,7 @@ class PipelineClient:
         generated.append(int(resp.token_id))
         yield GenerationStep(new_tokens=[generated[-1]])
 
-        # ---- burst decode loop ----
+        # ---- decode loop: one burst, or one token, per round ----
         decode_times: List[float] = []
         cur_len = prompt_len
         while len(generated) < max_new_tokens:
@@ -1665,7 +1686,7 @@ class PipelineClient:
             t0 = time.monotonic()
             resp = self._call_with_recovery(hop, StageRequest(
                 session_id=session_id,
-                hidden=jnp.asarray([[generated[-1]]], jnp.int32),
+                hidden=np.asarray([[generated[-1]]], np.int32),
                 seq_len=1, cur_len=cur_len, is_prefill=False,
                 max_length=max_length, sampling=sampling,
                 generated_tokens=clip_generated(generated),
@@ -1673,18 +1694,22 @@ class PipelineClient:
                 start_block=hop.start_block, end_block=hop.end_block,
                 burst_len=burst,
                 burst_budget=min(burst, max_new_tokens - len(generated)),
-                eos_token_id=eos_token_id,
+                eos_token_id=eos_token_id if burst else None,
                 deadline_budget_s=self._deadline_budget(
                     deadline_at, session_id, peer=hop.peer_id),
                 priority=self._session_priority.get(session_id),
             ))
-            if not resp.is_burst:
+            if burst and not resp.is_burst:
                 raise RuntimeError(
                     f"burst peer {hop.peer_id} returned no token block")
-            toks = list(resp.burst_tokens)
-            # Journal the burst's KV footprint: the carried-in token plus
+            if not burst and not resp.is_token:
+                raise RuntimeError(
+                    f"full-span peer {hop.peer_id} returned no token")
+            toks = (list(resp.burst_tokens) if burst
+                    else [int(resp.token_id)])
+            # Journal the round's KV footprint: the carried-in token plus
             # every emitted token except the last (whose KV the device has
-            # not written — it is the NEXT burst's carry).
+            # not written — it is the NEXT round's carry).
             self._journal_append(hop.key, session_id, JournalEntry(
                 np.asarray([[generated[-1], *toks[:-1]]], np.int32),
                 len(toks), cur_len))
@@ -1864,7 +1889,10 @@ class PipelineClient:
                           num_beams=nb, ttft_s=ttft)
 
     def _end_session(self, session_id: str) -> None:
-        self.stage0.drop_session(session_id)
+        with self._stage0_lock:
+            stage0 = self._stage0
+        if stage0 is not None:           # never built: nothing to drop
+            stage0.drop_session(session_id)
         self._session_prompts.pop(session_id, None)
         # Release the KV lease on every peer that ever held it (best-effort):
         # current route hops PLUS peers abandoned by failover — without this,

@@ -59,7 +59,7 @@ def initialize(cfg: DcnConfig) -> None:
     if cfg.cpu_devices_per_process:
         from ..utils.platform import force_cpu_devices
 
-        force_cpu_devices(cfg.cpu_devices_per_process, hard=True)
+        force_cpu_devices(cfg.cpu_devices_per_process)
     import jax
 
     jax.distributed.initialize(
@@ -118,7 +118,6 @@ def sanity_check() -> Tuple[float, float]:
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = global_mesh(("dp",))
     n_local = jax.local_device_count()
@@ -128,7 +127,7 @@ def sanity_check() -> Tuple[float, float]:
 
     @jax.jit
     def f(x):
-        return shard_map(lambda s: jax.lax.psum(s, "dp"),
+        return jax.shard_map(lambda s: jax.lax.psum(s, "dp"),
                          mesh=mesh, in_specs=P("dp"), out_specs=P())(x)
 
     got = float(np.asarray(jax.device_get(f(arr).addressable_shards[0].data))[0, 0])
@@ -147,7 +146,6 @@ def ring_shift() -> bool:
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = global_mesh(("dp",))
     n = jax.device_count()
@@ -165,7 +163,7 @@ def ring_shift() -> bool:
     @jax.jit
     def f(x):
         perm = [(i, (i + 1) % n) for i in range(n)]
-        return shard_map(lambda s: jax.lax.ppermute(s, "dp", perm),
+        return jax.shard_map(lambda s: jax.lax.ppermute(s, "dp", perm),
                          mesh=mesh, in_specs=P("dp"), out_specs=P("dp"))(x)
 
     out = f(arr)

@@ -88,23 +88,6 @@ def measure_next_server_rtts(
     return rtts
 
 
-def _tpu_hbm_bytes(device_kind: str) -> Optional[int]:
-    """HBM capacity per chip by TPU generation (public specs), for runtimes
-    that expose no allocator stats. None for unknown kinds."""
-    kind = device_kind.lower()
-    table = (
-        ("v5 lite", 16), ("v5e", 16),
-        ("v5p", 95), ("v5", 95),          # bare "v5" after lite/e checked
-        ("v6 lite", 32), ("v6e", 32), ("trillium", 32),
-        ("v4 lite", 8), ("v4", 32),
-        ("v3", 16), ("v2", 8),
-    )
-    for key, gib in table:
-        if key in kind:
-            return gib << 30
-    return None
-
-
 def derive_num_blocks(
     cfg: ModelConfig,
     *,
@@ -121,10 +104,11 @@ def derive_num_blocks(
     budgets weights + attention cache + headroom out of free GPU memory when
     ``--num_blocks`` is omitted.
 
-    Reads ``device.memory_stats()`` (real HBM numbers on TPU). Returns None
-    when the backend publishes no byte limit (e.g. host CPU) — the caller
-    falls back to its topology heuristic, mirroring the reference's behavior
-    on devices it cannot introspect."""
+    Reads ``device.memory_stats()`` (real HBM numbers on TPU; a TPU that
+    publishes none is an error). Returns None when the backend publishes no
+    byte limit (host CPU) — the caller falls back to its topology
+    heuristic, mirroring the reference's behavior on devices it cannot
+    introspect."""
     import jax
 
     from ..models.quant import choose_num_blocks
@@ -132,12 +116,13 @@ def derive_num_blocks(
     device = device or jax.devices()[0]
     stats = getattr(device, "memory_stats", lambda: None)() or {}
     limit = stats.get("bytes_limit")
-    if not limit and getattr(device, "platform", None) == "tpu":
-        # Some TPU runtimes (e.g. tunneled plugins) publish no allocator
-        # stats; fall back to the device generation's known HBM size so a
-        # flagless server still sizes itself on real hardware.
-        limit = _tpu_hbm_bytes(getattr(device, "device_kind", ""))
     if not limit:
+        if getattr(device, "platform", None) == "tpu":
+            # libtpu publishes bytes_limit (16909336064 on a v5e); a TPU
+            # without it is a broken runtime, not something to guess around.
+            raise RuntimeError(
+                f"{device} publishes no memory_stats()['bytes_limit']; "
+                "pass --num_blocks")
         return None
     free = max(0, int(limit) - int(stats.get("bytes_in_use", 0) or 0))
     from ..models.quant import block_bytes

@@ -52,6 +52,14 @@ FLAGS: Dict[str, Flag] = {f.name: f for f in (
     Flag("JAX_PLATFORMS", "",
          "Backend selection; written (not read) by force_cpu_devices to "
          "pin the CPU backend under tests and dry runs."),
+    Flag("TPU_VISIBLE_CHIPS", "",
+         "libtpu: which chip(s) of the host this process may open. Set by "
+         "utils.platform.chip_env when a launcher gives each process one "
+         "chip; echoed on handshake lines."),
+    Flag("JAX_COMPILATION_CACHE_DIR", "",
+         "Persistent compile cache placed from outside: when set, JAX reads "
+         "it itself and utils.platform.compile_cache_dir sets nothing in "
+         "code; unset, the cache is <checkout>/.jax_cache."),
 )}
 
 

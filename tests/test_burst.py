@@ -269,6 +269,33 @@ def test_burst_client_matches_oracle(cfg, sp):
 
 
 @pytest.mark.parity
+def test_full_span_session_builds_no_stage0(cfg):
+    """A plain session served whole by a full-span peer — in bursts, or one
+    token per round trip without --burst — computes nothing locally: the
+    stage-0 factory (a CLI client's weights and device) is never called,
+    and both give the oracle's ids."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
+        PipelineClient,
+    )
+
+    _base, transport, registry, params, plan = build_cluster(
+        cfg, splits="2,4")
+    _add_burst_peer(cfg, transport, registry, params)
+    built = []
+
+    client = PipelineClient(cfg, plan, lambda: built.append(1), transport,
+                            registry, settle_seconds=0.0)
+    ref = oracle_generate(cfg, params, PROMPT, 10, SAMPLED)
+    per_step = client.generate(PROMPT, max_new_tokens=10, sampling=SAMPLED)
+    burst = client.generate(PROMPT, max_new_tokens=10, sampling=SAMPLED,
+                            burst=4)
+    assert per_step.tokens == burst.tokens == ref
+    assert built == []
+    client.stage0                  # what a classic route does first
+    assert built == [1]
+
+
+@pytest.mark.parity
 def test_burst_client_eos_mid_burst(cfg):
     client, transport, registry, params, _plan = build_cluster(
         cfg, splits="2,4")
