@@ -1,0 +1,193 @@
+"""BENCHMARK.json and the data files it names, found by name.
+
+A cell is an entry of ``workloads``: it names a configuration
+(``configs/<config>.json``) and a traffic mix (``traffic/<traffic>.json``).
+A per-layer metric is ``layer_metrics/<name>.json`` (+ an optional
+``<name>.py`` reader). Adding any of them adds files and edits none: every
+function here takes the root directory, so the tests load throw-away
+examples from a temporary one."""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+from typing import Dict, List, Optional
+
+NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# `reduced` may never name a width (the contract's list).
+WIDTH_RE = re.compile(
+    r"((hidden|intermediate|latent|state|proj)\w*_size$|_dim$|_rank$|"
+    r"^head_|expan|experts_per_tok|^n_embd$|^n_inner$)")
+
+
+class ManifestError(ValueError):
+    pass
+
+
+def _load_json(path: str) -> dict:
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except FileNotFoundError:
+        raise ManifestError(f"missing file {path}") from None
+    except json.JSONDecodeError as exc:
+        raise ManifestError(f"{path}: {exc}") from None
+
+
+class Manifest:
+    def __init__(self, root: str, bench_dir: str = "perfbench"):
+        self.root = os.path.abspath(root)
+        self.dir = os.path.join(self.root, bench_dir)
+        self.data = _load_json(os.path.join(self.root, "BENCHMARK.json"))
+
+    # -- lookups by name ---------------------------------------------------
+
+    def workload(self, name: str) -> dict:
+        for w in self.data["workloads"]:
+            if w["name"] == name:
+                return w
+        raise ManifestError(
+            f"no workload {name!r}; BENCHMARK.json has "
+            f"{[w['name'] for w in self.data['workloads']]}")
+
+    def config_entry(self, name: str) -> dict:
+        for c in self.data["configs"]:
+            if c["name"] == name:
+                return c
+        raise ManifestError(f"no config {name!r} in BENCHMARK.json")
+
+    def config(self, name: str) -> dict:
+        return _load_json(os.path.join(self.root,
+                                       self.config_entry(name)["file"]))
+
+    def traffic(self, name: str) -> dict:
+        return _load_json(os.path.join(self.dir, "traffic", name + ".json"))
+
+    def layer_metric(self, name: str) -> dict:
+        return _load_json(os.path.join(self.dir, "layer_metrics",
+                                       name + ".json"))
+
+    def layer_reader_file(self, name: str) -> Optional[str]:
+        path = os.path.join(self.dir, "layer_metrics", name + ".py")
+        return path if os.path.exists(path) else None
+
+    def metrics_for(self, workload: str, kind: str) -> List[dict]:
+        """The ``end_to_end`` or ``per_layer`` metrics this cell reports."""
+        return [m for m in self.data[kind]
+                if "workloads" not in m or workload in m["workloads"]]
+
+    # -- validation (the contract's rules that a file can break) -----------
+
+    def validate(self) -> None:
+        d = self.data
+        want = {"command", "paths", "run_seconds", "configs", "workloads",
+                "end_to_end", "per_layer"}
+        if set(d) != want:
+            raise ManifestError(f"keys {sorted(d)} != {sorted(want)}")
+        if not (isinstance(d["run_seconds"], int)
+                and 1 <= d["run_seconds"] <= 51):
+            raise ManifestError("run_seconds outside 1..51")
+        names: Dict[str, str] = {}
+
+        def name_ok(n, what):
+            if not (isinstance(n, str) and NAME_RE.match(n)):
+                raise ManifestError(f"bad {what} name {n!r}")
+
+        for c in d["configs"]:
+            name_ok(c["name"], "config")
+            if set(c) != {"name", "source", "file", "reduced", "why"}:
+                raise ManifestError(f"config {c['name']}: keys {sorted(c)}")
+            if not any(c["file"].startswith(p + "/") for p in d["paths"]):
+                raise ManifestError(f"{c['file']} is outside paths")
+            for key in c["reduced"]:
+                name_ok(key, "reduced key")
+                if WIDTH_RE.search(key):
+                    raise ManifestError(
+                        f"config {c['name']}: reduced names a width {key!r}")
+            body = self.config(c["name"])
+            if sorted(body.get("reduced", [])) != sorted(c["reduced"]):
+                raise ManifestError(
+                    f"config {c['name']}: its file lists reduced="
+                    f"{body.get('reduced')}, BENCHMARK.json {c['reduced']}")
+        cfg_names = [c["name"] for c in d["configs"]]
+        if len(set(cfg_names)) != len(cfg_names):
+            raise ManifestError("two configs share a name")
+        cells = []
+        for w in d["workloads"]:
+            name_ok(w["name"], "workload")
+            name_ok(w["traffic"], "traffic")
+            if set(w) != {"name", "config", "traffic", "chips", "why"}:
+                raise ManifestError(f"workload {w['name']}: keys {sorted(w)}")
+            if w["config"] not in cfg_names:
+                raise ManifestError(f"{w['name']}: unknown config")
+            if w["chips"] not in (1, 4):
+                raise ManifestError(f"{w['name']}: chips {w['chips']}")
+            if not 1 <= len(w["why"]) <= 200 or "\n" in w["why"]:
+                raise ManifestError(f"{w['name']}: why is not one line "
+                                    "of 1..200 characters")
+            self.traffic(w["traffic"])
+            cells.append((w["config"], w["traffic"]))
+        if len(set(cells)) != len(cells):
+            raise ManifestError("a (config, traffic) pair appears twice")
+        wl_names = [w["name"] for w in d["workloads"]]
+        if len(set(wl_names)) != len(wl_names):
+            raise ManifestError("two workloads share a name")
+        four = sum(1 for w in d["workloads"] if w["chips"] == 4)
+        if four > max(1, len(wl_names) // 4):
+            raise ManifestError(f"{four} four-chip cells of {len(wl_names)}")
+        unused = set(cfg_names) - {w["config"] for w in d["workloads"]}
+        if unused:
+            raise ManifestError(f"configs used by no cell: {sorted(unused)}")
+        for kind in ("end_to_end", "per_layer"):
+            for m in d[kind]:
+                name_ok(m["name"], "metric")
+                if m["name"] in names:
+                    raise ManifestError(f"metric {m['name']} twice")
+                names[m["name"]] = kind
+                if not UNIT_RE.match(m["unit"]):
+                    raise ManifestError(f"{m['name']}: unit {m['unit']!r}")
+                if m["better"] not in ("lower", "higher"):
+                    raise ManifestError(f"{m['name']}: better")
+                if m["source"] not in SOURCES:
+                    raise ManifestError(f"{m['name']}: source")
+                for w in m.get("workloads", []):
+                    if w not in wl_names:
+                        raise ManifestError(f"{m['name']}: workload {w}")
+        e2e = {m["name"]: m for m in d["end_to_end"]}
+        if "setup_s" not in e2e:
+            raise ManifestError("no setup_s")
+        for m in d["end_to_end"]:
+            if set(m) - {"workloads"} != {"name", "unit", "better", "bound",
+                                         "source"}:
+                raise ManifestError(f"{m['name']}: keys {sorted(m)}")
+            if m["source"] not in ("host_clock", "device_trace"):
+                raise ManifestError(f"{m['name']}: end-to-end source")
+            if not 0 < m["bound"] <= 0.1:
+                raise ManifestError(f"{m['name']}: bound {m['bound']}")
+        for m in d["per_layer"]:
+            if set(m) - {"workloads"} != {"name", "unit", "better", "source",
+                                         "layer", "moves"}:
+                raise ManifestError(f"{m['name']}: keys {sorted(m)}")
+            if m["moves"] not in e2e:
+                raise ManifestError(f"{m['name']}: moves {m['moves']!r} is "
+                                    "not an end-to-end metric")
+            mover = e2e[m["moves"]]
+            for w in m.get("workloads", wl_names):
+                if "workloads" in mover and w not in mover["workloads"]:
+                    raise ManifestError(
+                        f"{m['name']}: cell {w} does not report "
+                        f"{m['moves']}")
+            desc = self.layer_metric(m["name"])
+            for key in ("layer", "unit", "moves", "source"):
+                if desc.get(key) != m[key]:
+                    raise ManifestError(
+                        f"{m['name']}: its file says {key}="
+                        f"{desc.get(key)!r}, BENCHMARK.json {m[key]!r}")
+        for w in wl_names:
+            if len(self.metrics_for(w, "end_to_end")) < 2:
+                raise ManifestError(f"{w}: fewer than two end-to-end metrics")
+            if not self.metrics_for(w, "per_layer"):
+                raise ManifestError(f"{w}: no per-layer metric")
