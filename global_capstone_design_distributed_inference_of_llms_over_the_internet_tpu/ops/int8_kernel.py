@@ -134,7 +134,7 @@ def _make_kernel(m: int, k: int, n: int, out_dtype: str,
             out_ref[...] = (acc_ref[...] * s_ref[...]).astype(out_ref.dtype)
 
     @jax.jit
-    def fn(x, q, s):
+    def int8_matmul(x, q, s):
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
@@ -150,9 +150,10 @@ def _make_kernel(m: int, k: int, n: int, out_dtype: str,
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT),
             interpret=interpret,
+            name="int8_matmul",
         )(x, q, s)
 
-    return fn
+    return int8_matmul
 
 
 def _supported(m: int, w: QuantizedTensor, x_bytes: int) -> bool:

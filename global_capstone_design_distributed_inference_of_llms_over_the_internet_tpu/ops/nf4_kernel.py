@@ -168,7 +168,7 @@ def _make_kernel(m: int, k: int, n: int, out_dtype: str,
             out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
     @jax.jit
-    def fn(xe, xo, packed, scales):
+    def nf4_matmul(xe, xo, packed, scales):
         return pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
@@ -185,9 +185,10 @@ def _make_kernel(m: int, k: int, n: int, out_dtype: str,
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT),
             interpret=interpret,
+            name="nf4_matmul",
         )(xe, xo, packed, scales)
 
-    return fn
+    return nf4_matmul
 
 
 def _supported(m: int, w: NF4Tensor, x_bytes: int) -> bool:

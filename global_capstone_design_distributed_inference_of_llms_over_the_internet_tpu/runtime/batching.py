@@ -132,14 +132,16 @@ def _softcap_and_mask(cfg, scores, allowed):
 
 def _residual(cfg, lp, h, attn_out):
     """Residual + MLP with optional sandwich norms (gemma2 post_norms:
-    ln3 after attention, ln4 after the MLP, before each residual add)."""
-    if cfg.post_norms:
-        attn_out = _norm(cfg, lp["ln3"], attn_out)
-    h = h + attn_out
-    mlp_out = _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], h), None)
-    if cfg.post_norms:
-        mlp_out = _norm(cfg, lp["ln4"], mlp_out)
-    return h + mlp_out
+    ln3 after attention, ln4 after the MLP, before each residual add): the
+    ``mlp`` section of every engine program on the device trace."""
+    with jax.named_scope("mlp"):
+        if cfg.post_norms:
+            attn_out = _norm(cfg, lp["ln3"], attn_out)
+        h = h + attn_out
+        mlp_out = _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], h), None)
+        if cfg.post_norms:
+            mlp_out = _norm(cfg, lp["ln4"], mlp_out)
+        return h + mlp_out
 
 
 def _scan_layers_in_place(layer, h, layers, k_all, v_all):
@@ -157,11 +159,13 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     def body(carry, xs):
         h, k_all, v_all = carry
         lp, i = xs
-        k_l = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
+        with jax.named_scope("kv_update"):
+            k_l = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+            v_l = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
         h, (k_l, v_l) = layer(h, (lp, (k_l, v_l)))
-        k_all = jax.lax.dynamic_update_index_in_dim(k_all, k_l, i, 0)
-        v_all = jax.lax.dynamic_update_index_in_dim(v_all, v_l, i, 0)
+        with jax.named_scope("kv_update"):
+            k_all = jax.lax.dynamic_update_index_in_dim(k_all, k_l, i, 0)
+            v_all = jax.lax.dynamic_update_index_in_dim(v_all, v_l, i, 0)
         return (h, k_all, v_all), None
 
     (h, k_all, v_all), _ = jax.lax.scan(
@@ -273,13 +277,14 @@ class BatchedStageExecutor:
         cfg, spec = self.cfg, self.spec
 
         @partial(jax.jit, donate_argnums=engine_donation(3, 4))
-        def fn(params, x, slot, k_all, v_all, t_real):
+        def prefill(params, x, slot, k_all, v_all, t_real):
             b = 1
             t = x.shape[1]
             positions = jnp.arange(t, dtype=jnp.int32)[None, :]
-            h = (embed_tokens(cfg, params["embed"], x, positions)
-                 if spec.is_first else x)
-            rope = make_rope(cfg, positions)
+            with jax.named_scope("embed"):
+                h = (embed_tokens(cfg, params["embed"], x, positions)
+                     if spec.is_first else x)
+                rope = make_rope(cfg, positions)
             # Causal self-attention over the fresh prompt (prefill restarts
             # the session, so there is no prior cache to attend to). O(T^2)
             # scores — long prompts belong to the sp engine or the chunked
@@ -298,38 +303,42 @@ class BatchedStageExecutor:
                 from ..models.quant import dequant_tree
 
                 lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                a = _norm(cfg, lp["ln1"], h)
-                q, k, v = qkv_proj(cfg, lp["attn"], a)
-                if rope is not None:
-                    q = apply_rope(q, *rope)
-                    k = apply_rope(k, *rope)
-                groups = cfg.num_heads // cfg.num_kv_heads
-                qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
-                scores = jnp.einsum(
-                    "bthgd,bshd->bhgts", qg * _qscale(cfg), k,
-                    preferred_element_type=jnp.float32)
-                m = _layer_mask(lp, mask, rows, cols)
-                scores = _softcap_and_mask(cfg, scores, m[None, None, None])
-                probs = jax.nn.softmax(scores, axis=-1)
-                out = jnp.einsum("bhgts,bshd->bthgd",
-                                 probs.astype(v.dtype), v)
-                out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
-                if "bo" in lp["attn"]:
-                    out = out + lp["attn"]["bo"]
+                with jax.named_scope("attention"):
+                    a = _norm(cfg, lp["ln1"], h)
+                    q, k, v = qkv_proj(cfg, lp["attn"], a)
+                    if rope is not None:
+                        q = apply_rope(q, *rope)
+                        k = apply_rope(k, *rope)
+                    groups = cfg.num_heads // cfg.num_kv_heads
+                    qg = q.reshape(b, t, cfg.num_kv_heads, groups,
+                                   cfg.head_dim)
+                    scores = jnp.einsum(
+                        "bthgd,bshd->bhgts", qg * _qscale(cfg), k,
+                        preferred_element_type=jnp.float32)
+                    m = _layer_mask(lp, mask, rows, cols)
+                    scores = _softcap_and_mask(cfg, scores,
+                                               m[None, None, None])
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    out = jnp.einsum("bhgts,bshd->bthgd",
+                                     probs.astype(v.dtype), v)
+                    out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
+                    if "bo" in lp["attn"]:
+                        out = out + lp["attn"]["bo"]
                 h = _residual(cfg, lp, h, out)
                 return h, (k[0], v[0])
 
             h, (ks, vs) = jax.lax.scan(layer, h, params["layers"])
             # ks/vs: [L, T, Hkv, Dh] -> write rows [slot, 0:T).
-            k_all = jax.lax.dynamic_update_slice(
-                k_all, ks[:, None].astype(k_all.dtype),
-                (0, slot, 0, 0, 0))
-            v_all = jax.lax.dynamic_update_slice(
-                v_all, vs[:, None].astype(v_all.dtype),
-                (0, slot, 0, 0, 0))
+            with jax.named_scope("kv_update"):
+                k_all = jax.lax.dynamic_update_slice(
+                    k_all, ks[:, None].astype(k_all.dtype),
+                    (0, slot, 0, 0, 0))
+                v_all = jax.lax.dynamic_update_slice(
+                    v_all, vs[:, None].astype(v_all.dtype),
+                    (0, slot, 0, 0, 0))
             return h, k_all, v_all
 
-        return fn
+        return prefill
 
     def _build_prefill_suffix(self):
         """Prefill CONTINUATION for a prefix-cache hit: the suffix enters at
@@ -339,13 +348,14 @@ class BatchedStageExecutor:
         cfg, spec = self.cfg, self.spec
 
         @partial(jax.jit, donate_argnums=engine_donation(3, 4))
-        def fn(params, x, slot, k_all, v_all, p_len, t_real):
+        def prefill_suffix(params, x, slot, k_all, v_all, p_len, t_real):
             b = 1
             t = x.shape[1]
             positions = p_len + jnp.arange(t, dtype=jnp.int32)[None, :]
-            h = (embed_tokens(cfg, params["embed"], x, positions)
-                 if spec.is_first else x)
-            rope = make_rope(cfg, positions)
+            with jax.named_scope("embed"):
+                h = (embed_tokens(cfg, params["embed"], x, positions)
+                     if spec.is_first else x)
+                rope = make_rope(cfg, positions)
             groups = cfg.num_heads // cfg.num_kv_heads
             m = k_all.shape[2]
             pos_grid = jnp.arange(m, dtype=jnp.int32)
@@ -353,59 +363,66 @@ class BatchedStageExecutor:
             allowed = pos_grid[None, :] <= qpos              # [T, M] causal
             if cfg.sliding_window:
                 allowed &= pos_grid[None, :] > qpos - cfg.sliding_window
-            k_slot = jax.lax.dynamic_index_in_dim(k_all, slot, 1,
-                                                  keepdims=False)
-            v_slot = jax.lax.dynamic_index_in_dim(v_all, slot, 1,
-                                                  keepdims=False)
+            with jax.named_scope("kv_update"):
+                k_slot = jax.lax.dynamic_index_in_dim(k_all, slot, 1,
+                                                      keepdims=False)
+                v_slot = jax.lax.dynamic_index_in_dim(v_all, slot, 1,
+                                                      keepdims=False)
 
             def layer(h, xs):
                 from ..models.quant import dequant_tree
 
                 lp, k_l, v_l = xs                    # k_l: [M, Hkv, Dh]
                 lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                a = _norm(cfg, lp["ln1"], h)
-                q, k, v = qkv_proj(cfg, lp["attn"], a)
-                if rope is not None:
-                    q = apply_rope(q, *rope)
-                    k = apply_rope(k, *rope)
-                k_l = jax.lax.dynamic_update_slice_in_dim(
-                    k_l, k[0].astype(k_l.dtype), p_len, 0)
-                v_l = jax.lax.dynamic_update_slice_in_dim(
-                    v_l, v[0].astype(v_l.dtype), p_len, 0)
-                qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
-                scores = jnp.einsum(
-                    "bthgd,shd->bhgts", qg * _qscale(cfg),
-                    k_l.astype(q.dtype),
-                    preferred_element_type=jnp.float32)
-                m = _layer_mask(lp, allowed, qpos, pos_grid[None, :])
-                scores = _softcap_and_mask(cfg, scores, m[None, None, None])
-                probs = jax.nn.softmax(scores, axis=-1)
-                out = jnp.einsum("bhgts,shd->bthgd",
-                                 probs.astype(v_l.dtype),
-                                 v_l.astype(q.dtype))
-                out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
-                if "bo" in lp["attn"]:
-                    out = out + lp["attn"]["bo"]
+                with jax.named_scope("attention"):
+                    a = _norm(cfg, lp["ln1"], h)
+                    q, k, v = qkv_proj(cfg, lp["attn"], a)
+                    if rope is not None:
+                        q = apply_rope(q, *rope)
+                        k = apply_rope(k, *rope)
+                with jax.named_scope("kv_update"):
+                    k_l = jax.lax.dynamic_update_slice_in_dim(
+                        k_l, k[0].astype(k_l.dtype), p_len, 0)
+                    v_l = jax.lax.dynamic_update_slice_in_dim(
+                        v_l, v[0].astype(v_l.dtype), p_len, 0)
+                with jax.named_scope("attention"):
+                    qg = q.reshape(b, t, cfg.num_kv_heads, groups,
+                                   cfg.head_dim)
+                    scores = jnp.einsum(
+                        "bthgd,shd->bhgts", qg * _qscale(cfg),
+                        k_l.astype(q.dtype),
+                        preferred_element_type=jnp.float32)
+                    m = _layer_mask(lp, allowed, qpos, pos_grid[None, :])
+                    scores = _softcap_and_mask(cfg, scores,
+                                               m[None, None, None])
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    out = jnp.einsum("bhgts,shd->bthgd",
+                                     probs.astype(v_l.dtype),
+                                     v_l.astype(q.dtype))
+                    out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
+                    if "bo" in lp["attn"]:
+                        out = out + lp["attn"]["bo"]
                 h = _residual(cfg, lp, h, out)
                 return h, (k_l, v_l)
 
             h, (ks, vs) = jax.lax.scan(
                 layer, h, (params["layers"], k_slot, v_slot))
-            k_all = jax.lax.dynamic_update_slice(
-                k_all, ks[:, None], (0, slot, 0, 0, 0))
-            v_all = jax.lax.dynamic_update_slice(
-                v_all, vs[:, None], (0, slot, 0, 0, 0))
+            with jax.named_scope("kv_update"):
+                k_all = jax.lax.dynamic_update_slice(
+                    k_all, ks[:, None], (0, slot, 0, 0, 0))
+                v_all = jax.lax.dynamic_update_slice(
+                    v_all, vs[:, None], (0, slot, 0, 0, 0))
             del t_real  # mask correctness needs only qpos; kept for parity
             return h, k_all, v_all
 
-        return fn
+        return prefill_suffix
 
     def _write_prefix_chain(self, slot: int, chain) -> None:
         """Write a chain's KV segments into the slot's leading cache rows
         in ONE jitted dispatch (specialized per chain length)."""
         if self._chain_write_jit is None:
             @partial(jax.jit, donate_argnums=engine_donation(0, 1))
-            def fn(k_all, v_all, slot, segs_k, segs_v):
+            def prefix_chain_write(k_all, v_all, slot, segs_k, segs_v):
                 kc = (segs_k[0] if len(segs_k) == 1
                       else jnp.concatenate(segs_k, axis=1))
                 vc = (segs_v[0] if len(segs_v) == 1
@@ -416,7 +433,7 @@ class BatchedStageExecutor:
                         jax.lax.dynamic_update_slice(
                             v_all, vc[:, None].astype(v_all.dtype), start))
 
-            self._chain_write_jit = fn
+            self._chain_write_jit = prefix_chain_write
         self.k, self.v = self._chain_write_jit(
             self.k, self.v, jnp.int32(slot),
             [e.k for e in chain], [e.v for e in chain])
@@ -429,7 +446,7 @@ class BatchedStageExecutor:
         fn = self._grain_split_jits.get(key)
         if fn is None:
             @jax.jit
-            def fn(k_all, v_all, slot):
+            def grain_split(k_all, v_all, slot):
                 k_s = jax.lax.dynamic_index_in_dim(k_all, slot, 1,
                                                    keepdims=False)
                 v_s = jax.lax.dynamic_index_in_dim(v_all, slot, 1,
@@ -439,7 +456,7 @@ class BatchedStageExecutor:
                         [v_s[:, g * grain:(g + 1) * grain]
                          for g in range(n_grains)])
 
-            self._grain_split_jits[key] = fn
+            fn = self._grain_split_jits[key] = grain_split
         return fn(self.k, self.v, jnp.int32(slot))
 
     def prefill(self, session_id: str, x, prefix_len: int = 0) -> jnp.ndarray:
@@ -583,13 +600,14 @@ class BatchedStageExecutor:
         T = t_step
 
         @partial(jax.jit, donate_argnums=engine_donation(4, 5))
-        def fn(params, x, lengths, active, k_all, v_all):
+        def decode_step(params, x, lengths, active, k_all, v_all):
             # x: ids [S, T] or hidden [S, T, D]; lengths/active: [S].
             offs = jnp.arange(T, dtype=jnp.int32)
             positions = lengths[:, None] + offs[None, :]       # [S, T]
-            h = (embed_tokens(cfg, params["embed"], x, positions)
-                 if spec.is_first else x)
-            rope = make_rope(cfg, positions)
+            with jax.named_scope("embed"):
+                h = (embed_tokens(cfg, params["embed"], x, positions)
+                     if spec.is_first else x)
+                rope = make_rope(cfg, positions)
             groups = cfg.num_heads // cfg.num_kv_heads
             pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)  # [max_len]
             # allowed[s, tq, m]: key position m visible to query token tq of
@@ -606,11 +624,12 @@ class BatchedStageExecutor:
                 from ..models.quant import dequant_tree
 
                 lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                a = _norm(cfg, lp["ln1"], h)
-                q, k, v = qkv_proj(cfg, lp["attn"], a)     # [S,T,H/Hkv,Dh]
-                if rope is not None:
-                    q = apply_rope(q, *rope)
-                    k = apply_rope(k, *rope)
+                with jax.named_scope("attention"):
+                    a = _norm(cfg, lp["ln1"], h)
+                    q, k, v = qkv_proj(cfg, lp["attn"], a)  # [S,T,H/Hkv,Dh]
+                    if rope is not None:
+                        q = apply_rope(q, *rope)
+                        k = apply_rope(k, *rope)
                 # Per-slot cache write of T rows at each slot's own length
                 # (vmap'd dynamic_update_slice). Inactive slots write their
                 # OWN current rows back: a slot parked near max_len would
@@ -628,23 +647,27 @@ class BatchedStageExecutor:
                             jax.lax.dynamic_slice_in_dim(cache, start, T, 0)),
                         start, 0)
                 )
-                k_l = upd(k_l, k.astype(k_l.dtype), lengths, active)
-                v_l = upd(v_l, v.astype(v_l.dtype), lengths, active)
+                with jax.named_scope("kv_update"):
+                    k_l = upd(k_l, k.astype(k_l.dtype), lengths, active)
+                    v_l = upd(v_l, v.astype(v_l.dtype), lengths, active)
                 # Attention over [0, query position] per new token.
-                qg = q.reshape(S, T, cfg.num_kv_heads, groups, cfg.head_dim)
-                scores = jnp.einsum(
-                    "bthgd,bshd->bhgts", qg * _qscale(cfg),
-                    k_l.astype(q.dtype),
-                    preferred_element_type=jnp.float32)      # [S,Hkv,G,T,M]
-                m = _layer_mask(lp, allowed, qpos, pos_grid[None, None, :])
-                scores = _softcap_and_mask(cfg, scores, m[:, None, None])
-                probs = jax.nn.softmax(scores, axis=-1)
-                out = jnp.einsum("bhgts,bshd->bthgd",
-                                 probs.astype(v_l.dtype),
-                                 v_l.astype(q.dtype))
-                out = _dot(out.reshape(S, T, -1), lp["attn"]["wo"])
-                if "bo" in lp["attn"]:
-                    out = out + lp["attn"]["bo"]
+                with jax.named_scope("attention"):
+                    qg = q.reshape(S, T, cfg.num_kv_heads, groups,
+                                   cfg.head_dim)
+                    scores = jnp.einsum(
+                        "bthgd,bshd->bhgts", qg * _qscale(cfg),
+                        k_l.astype(q.dtype),
+                        preferred_element_type=jnp.float32)  # [S,Hkv,G,T,M]
+                    m = _layer_mask(lp, allowed, qpos,
+                                    pos_grid[None, None, :])
+                    scores = _softcap_and_mask(cfg, scores, m[:, None, None])
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    out = jnp.einsum("bhgts,bshd->bthgd",
+                                     probs.astype(v_l.dtype),
+                                     v_l.astype(q.dtype))
+                    out = _dot(out.reshape(S, T, -1), lp["attn"]["wo"])
+                    if "bo" in lp["attn"]:
+                        out = out + lp["attn"]["bo"]
                 h = _residual(cfg, lp, h, out)
                 return h, (k_l, v_l)
 
@@ -655,7 +678,7 @@ class BatchedStageExecutor:
             h = jnp.where(active[:, None, None], h, 0.0)
             return h, k_all, v_all
 
-        return fn
+        return decode_step
 
     def tokens_left(self) -> int:
         """Admission headroom for heartbeats/info (the slot-batched analogue
@@ -743,8 +766,9 @@ class BatchedStageExecutor:
         from ..ops.sampling import push_recent, sample_token
 
         @partial(jax.jit, donate_argnums=engine_donation(14, 15))
-        def fn(params, tok, lengths, alive, seeds, recent, nvalid, run,
-               left, eos_id, temp, top_p, top_k, rp, k_all, v_all):
+        def burst_tick(params, tok, lengths, alive, seeds, recent, nvalid,
+                       run, left, eos_id, temp, top_p, top_k, rp, k_all,
+                       v_all):
             pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
             len0 = lengths
 
@@ -754,8 +778,9 @@ class BatchedStageExecutor:
                 active = alive
                 x = tok[:, None]                              # [S, 1] ids
                 positions = lengths[:, None]                  # [S, 1]
-                h = embed_tokens(cfg, params["embed"], x, positions)
-                rope = make_rope(cfg, positions)
+                with jax.named_scope("embed"):
+                    h = embed_tokens(cfg, params["embed"], x, positions)
+                    rope = make_rope(cfg, positions)
                 groups = cfg.num_heads // cfg.num_kv_heads
                 qpos = positions[:, :, None]                  # [S, 1, 1]
                 allowed = pos_grid[None, None, :] <= qpos
@@ -768,11 +793,12 @@ class BatchedStageExecutor:
                     from ..models.quant import dequant_tree
 
                     lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                    a = _norm(cfg, lp["ln1"], h)
-                    q, k, v = qkv_proj(cfg, lp["attn"], a)
-                    if rope is not None:
-                        q = apply_rope(q, *rope)
-                        k = apply_rope(k, *rope)
+                    with jax.named_scope("attention"):
+                        a = _norm(cfg, lp["ln1"], h)
+                        q, k, v = qkv_proj(cfg, lp["attn"], a)
+                        if rope is not None:
+                            q = apply_rope(q, *rope)
+                            k = apply_rope(k, *rope)
                     upd = jax.vmap(
                         lambda cache, new, start, act:
                         jax.lax.dynamic_update_slice_in_dim(
@@ -783,53 +809,62 @@ class BatchedStageExecutor:
                                     cache, start, 1, 0)),
                             start, 0)
                     )
-                    k_l = upd(k_l, k.astype(k_l.dtype), lengths, active)
-                    v_l = upd(v_l, v.astype(v_l.dtype), lengths, active)
-                    qg = q.reshape(S, 1, cfg.num_kv_heads, groups,
-                                   cfg.head_dim)
-                    scores = jnp.einsum(
-                        "bthgd,bshd->bhgts", qg * _qscale(cfg),
-                        k_l.astype(q.dtype),
-                        preferred_element_type=jnp.float32)
-                    m = _layer_mask(lp, allowed, qpos,
-                                    pos_grid[None, None, :])
-                    scores = _softcap_and_mask(cfg, scores, m[:, None, None])
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    out = jnp.einsum("bhgts,bshd->bthgd",
-                                     probs.astype(v_l.dtype),
-                                     v_l.astype(q.dtype))
-                    out = _dot(out.reshape(S, 1, -1), lp["attn"]["wo"])
-                    if "bo" in lp["attn"]:
-                        out = out + lp["attn"]["bo"]
+                    with jax.named_scope("kv_update"):
+                        k_l = upd(k_l, k.astype(k_l.dtype), lengths, active)
+                        v_l = upd(v_l, v.astype(v_l.dtype), lengths, active)
+                    with jax.named_scope("attention"):
+                        qg = q.reshape(S, 1, cfg.num_kv_heads, groups,
+                                       cfg.head_dim)
+                        scores = jnp.einsum(
+                            "bthgd,bshd->bhgts", qg * _qscale(cfg),
+                            k_l.astype(q.dtype),
+                            preferred_element_type=jnp.float32)
+                        m = _layer_mask(lp, allowed, qpos,
+                                        pos_grid[None, None, :])
+                        scores = _softcap_and_mask(cfg, scores,
+                                                   m[:, None, None])
+                        probs = jax.nn.softmax(scores, axis=-1)
+                        out = jnp.einsum("bhgts,bshd->bthgd",
+                                         probs.astype(v_l.dtype),
+                                         v_l.astype(q.dtype))
+                        out = _dot(out.reshape(S, 1, -1), lp["attn"]["wo"])
+                        if "bo" in lp["attn"]:
+                            out = out + lp["attn"]["bo"]
                     h = _residual(cfg, lp, h, out)
                     return h, (k_l, v_l)
 
                 h, k_all, v_all = _scan_layers_in_place(
                     layer, h, params["layers"], k_all, v_all)
-                h = jnp.where(active[:, None, None], h, 0.0)
-                logits = lm_head(cfg, params, h)[:, 0]        # [S, V] fp32
-                keys = jax.vmap(jax.random.PRNGKey)(seeds + i)
-                sampled = jax.vmap(sample_token)(
-                    keys, logits, recent, nvalid, temp, top_p, top_k, rp)
+                with jax.named_scope("head"):
+                    h = jnp.where(active[:, None, None], h, 0.0)
+                    logits = lm_head(cfg, params, h)[:, 0]    # [S, V] fp32
+                with jax.named_scope("sampler"):
+                    keys = jax.vmap(jax.random.PRNGKey)(seeds + i)
+                    sampled = jax.vmap(sample_token)(
+                        keys, logits, recent, nvalid, temp, top_p, top_k,
+                        rp)
                 # Host stop-rule mirror, in host order: the token is always
                 # EMITTED (the host appends before checking eos/repeat);
                 # stops only gate the NEXT tick.
-                eos_hit = active & (eos_id >= 0) & (sampled == eos_id)
-                run_next = jnp.where(sampled == tok, run + 1, jnp.int32(1))
-                run_next = jnp.where(active, run_next, run)
-                rep_hit = active & (run_next >= BURST_REPEAT_STOP)
-                left_next = jnp.where(active, left - 1, left)
-                rec2, nv2 = jax.vmap(push_recent)(recent, nvalid, sampled)
-                recent = jnp.where(active[:, None], rec2, recent)
-                nvalid = jnp.where(active, nv2, nvalid)
-                lengths = jnp.where(active, lengths + 1, lengths)
-                first = stop == 0
-                stop = jnp.where(eos_hit & first, jnp.int32(1), stop)
-                stop = jnp.where(rep_hit & ~eos_hit & first,
-                                 jnp.int32(2), stop)
-                alive = active & ~eos_hit & ~rep_hit & (left_next > 0)
-                tok = jnp.where(active, sampled, tok)
-                out_tok = jnp.where(active, sampled, jnp.int32(-1))
+                with jax.named_scope("stop_rules"):
+                    eos_hit = active & (eos_id >= 0) & (sampled == eos_id)
+                    run_next = jnp.where(sampled == tok, run + 1,
+                                         jnp.int32(1))
+                    run_next = jnp.where(active, run_next, run)
+                    rep_hit = active & (run_next >= BURST_REPEAT_STOP)
+                    left_next = jnp.where(active, left - 1, left)
+                    rec2, nv2 = jax.vmap(push_recent)(recent, nvalid,
+                                                      sampled)
+                    recent = jnp.where(active[:, None], rec2, recent)
+                    nvalid = jnp.where(active, nv2, nvalid)
+                    lengths = jnp.where(active, lengths + 1, lengths)
+                    first = stop == 0
+                    stop = jnp.where(eos_hit & first, jnp.int32(1), stop)
+                    stop = jnp.where(rep_hit & ~eos_hit & first,
+                                     jnp.int32(2), stop)
+                    alive = active & ~eos_hit & ~rep_hit & (left_next > 0)
+                    tok = jnp.where(active, sampled, tok)
+                    out_tok = jnp.where(active, sampled, jnp.int32(-1))
                 return (tok, lengths, alive, recent, nvalid, run_next,
                         left_next, stop, k_all, v_all), out_tok
 
@@ -847,7 +882,7 @@ class BatchedStageExecutor:
             return (toks, stop, tok, lengths, alive, seeds, recent, nvalid,
                     run, left, k_all, v_all)
 
-        return fn
+        return burst_tick
 
     def _get_burst_jit(self, n_ticks: int):
         fn = self._burst_jits.get(n_ticks)
@@ -956,19 +991,17 @@ class BatchedStageExecutor:
         if not entries:
             return {}
         prof = _get_profiler()
-        with prof.phase("burst_build"):
+        n = len(entries)
+        with prof.phase("burst_build", sessions=n):
             rows, args = self._burst_prep(entries, n_ticks)
             fn = self._get_burst_jit(n_ticks)
-        if prof.enabled:
-            # Fenced dispatch: the device phase is dispatch-to-ready, the
-            # bubble gauge charges idle time between successive readies.
-            t_d = time.perf_counter()
-            out = fn(self.params, *args, self.k, self.v)
-            prof.observe("dispatch", time.perf_counter() - t_d)
-            jax.block_until_ready(out)
-            prof.device_interval(t_d, time.perf_counter())
-        else:
-            out = fn(self.params, *args, self.k, self.v)
+        # Profiled: a fenced dispatch. The device phase is dispatch-to-ready
+        # and the bubble gauge charges idle time between successive readies.
+        with prof.device_phase(sessions=n):
+            with prof.phase("dispatch", sessions=n):
+                out = fn(self.params, *args, self.k, self.v)
+            if prof.enabled:
+                jax.block_until_ready(out)
         toks, stop = out[0], out[1]
         lengths_new = out[3]
         self.k, self.v = out[-2], out[-1]
@@ -976,7 +1009,7 @@ class BatchedStageExecutor:
         self.burst_dispatches += 1
         self._m_burst_disp.inc()
         self._m_burst_ticks.observe(n_ticks)
-        with prof.phase("readback"):
+        with prof.phase("readback", sessions=n):
             return self._burst_collect(rows, toks, stop, lengths_new)
 
     def burst_stream(self, entries: Dict[str, dict], n_ticks: int):
@@ -1153,6 +1186,7 @@ class BatchingStageAdapter:
         # / TcpStageServer) — the adapter owns the batching-specific signals.
         self._m_queue_wait = _tm.get("server_queue_wait_seconds")
         self._m_fill = _tm.get("server_batch_fill_sessions")
+        self._m_held = _tm.get("server_batch_slots_held")
         self._m_round = _tm.get("server_decode_round_seconds")
         # TcpStageServer's info verb + heartbeat read `.arena.tokens_left()`
         # on whatever executor they serve; point that surface at the slot
@@ -1272,21 +1306,35 @@ class BatchingStageAdapter:
     def _prefill(self, req):
         from .executor import StageExecutionError
 
-        with self._lock:  # slot tables + cache arrays are shared state
-            try:
-                h = self.inner.prefill(req.session_id, req.hidden,
-                                       prefix_len=req.prefix_len)
-            except StageExecutionError:
-                raise
-            except Exception as exc:
-                # Same taxonomy as decode's whole-round failures: the engine
-                # recovered its slot/caches specifically so the request is
-                # retryable — a raw XlaRuntimeError would cross the wire as a
-                # kind-less error outside the client's failover taxonomy and
-                # crash the generation instead of re-routing it.
-                raise StageExecutionError(str(exc)) from exc
-            cache_len = int(self.inner.lengths[self.inner.slot(req.session_id)])
-        return self._respond(req, h, cache_len)
+        prof = _get_profiler()
+        sid = req.session_id
+        # A request's life up to its first token, as three phases: the wait
+        # for the lock (a round leader holds it through its whole step,
+        # readback included), the prefill under it, the first token after it.
+        with prof.phase("prefill_wait", session=sid):
+            self._lock.acquire()  # slot tables + cache arrays: shared state
+        try:
+            with prof.phase("prefill", session=sid):
+                try:
+                    h = self.inner.prefill(sid, req.hidden,
+                                           prefix_len=req.prefix_len)
+                except StageExecutionError:
+                    raise
+                except Exception as exc:
+                    # Same taxonomy as decode's whole-round failures: the
+                    # engine recovered its slot/caches specifically so the
+                    # request is retryable — a raw XlaRuntimeError would
+                    # cross the wire as a kind-less error outside the
+                    # client's failover taxonomy and crash the generation
+                    # instead of re-routing it.
+                    raise StageExecutionError(str(exc)) from exc
+                cache_len = int(self.inner.lengths[self.inner.slot(sid)])
+        finally:
+            self._lock.release()
+        if not self.spec.is_last:
+            return self._respond(req, h, cache_len)
+        with prof.phase("first_token", session=sid):
+            return self._respond(req, h, cache_len)
 
     def _validate(self, req) -> Optional[str]:
         """Per-session admission (caller holds the lock). Returns a refusal
@@ -1325,6 +1373,7 @@ class BatchingStageAdapter:
         from .executor import StageExecutionError
         from .messages import StageResponse
 
+        prof = _get_profiler()
         sid = req.session_id
         t = req.seq_len
         t_join = time.monotonic()
@@ -1347,7 +1396,8 @@ class BatchingStageAdapter:
             # exception anywhere (not just inside decode_batch) must still
             # release the followers, else they block for step_timeout.
             try:
-                time.sleep(self.window_s)
+                with prof.span("round_window", session=sid):
+                    time.sleep(self.window_s)
                 with self._lock:
                     r.closed = True
                     if self._rounds.get(t) is r:
@@ -1365,6 +1415,7 @@ class BatchingStageAdapter:
                     if good:
                         r.t_exec = time.monotonic()
                         self._m_fill.observe(len(good))
+                        self._m_held.observe(len(self.inner._slot_of))
                         r.outs = self.inner.decode_batch(
                             {s_id: rq.hidden for s_id, rq in good.items()})
                         if self.spec.is_last:
@@ -1382,8 +1433,10 @@ class BatchingStageAdapter:
                         del self._rounds[t]
             finally:
                 r.event.set()
-        elif not r.event.wait(self.step_timeout):
-            raise StageExecutionError("batched step timed out")
+        else:
+            with prof.span("round_wait", session=sid):
+                if not r.event.wait(self.step_timeout):
+                    raise StageExecutionError("batched step timed out")
         if r.t_exec:
             # Time this session spent parked before its round's step ran —
             # the coalescing window for the leader, window + leader overhead
@@ -1424,6 +1477,7 @@ class BatchingStageAdapter:
         from .executor import StageExecutionError
         from .messages import StageResponse
 
+        prof = _get_profiler()
         sid = req.session_id
         n = int(req.burst_len)
         key = ("burst", n)
@@ -1444,7 +1498,8 @@ class BatchingStageAdapter:
             r.reqs[sid] = req
         if leader:
             try:
-                time.sleep(self.window_s)
+                with prof.span("round_window", session=sid):
+                    time.sleep(self.window_s)
                 with self._lock:
                     r.closed = True
                     if self._rounds.get(key) is r:
@@ -1460,6 +1515,7 @@ class BatchingStageAdapter:
                     if good:
                         r.t_exec = time.monotonic()
                         self._m_fill.observe(len(good))
+                        self._m_held.observe(len(self.inner._slot_of))
                         r.outs = self.inner.decode_burst(
                             {s_id: _burst_entry(rq)
                              for s_id, rq in good.items()}, n)
@@ -1480,8 +1536,10 @@ class BatchingStageAdapter:
                         del self._rounds[key]
             finally:
                 r.event.set()
-        elif not r.event.wait(self.step_timeout):
-            raise StageExecutionError("batched step timed out")
+        else:
+            with prof.span("round_wait", session=sid):
+                if not r.event.wait(self.step_timeout):
+                    raise StageExecutionError("batched step timed out")
         if r.t_exec:
             self._m_queue_wait.observe(max(0.0, r.t_exec - t_join))
         if r.err is not None:

@@ -56,6 +56,11 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
     "server_batch_fill_sessions": (
         HISTOGRAM, "Sessions coalesced into one batched decode round.",
         (), FILL_BUCKETS),
+    "server_batch_slots_held": (
+        HISTOGRAM, "Sessions holding a slot of the batched engine when a "
+                   "decode round closed (beside server_batch_fill_sessions: "
+                   "how many of them the round ran).",
+        (), FILL_BUCKETS),
     "server_decode_round_seconds": (
         HISTOGRAM, "Wall time of one batched decode round (all slots).",
         (), FAST_BUCKETS),
@@ -260,8 +265,9 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
     # -- phase profiler (--profile_phases) ------------------------------------
     "server_phase_seconds": (
         HISTOGRAM, "Serving hot-path phase wall time from the phase "
-                   "profiler, per phase (gateway_queue|burst_build|dispatch|"
-                   "device|readback|socket|server).",
+                   "profiler, per phase (gateway_queue|prefill_wait|prefill|"
+                   "first_token|burst_build|dispatch|device|readback|socket|"
+                   "server).",
         ("phase",), FAST_BUCKETS),
     "server_device_bubble_ratio": (
         GAUGE, "Fraction of wall time the accelerator sat idle between "

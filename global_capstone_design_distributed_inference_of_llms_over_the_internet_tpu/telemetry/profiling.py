@@ -4,6 +4,12 @@ bench.py answers that question offline; this module answers it LIVE. A
 `PhaseProfiler` brackets the serving hot path into named phases —
 
   * ``gateway_queue`` — admission to first pipeline step (serving/gateway.py)
+  * ``prefill_wait``  — a prefill's wait for the batched stage's lock
+                        (runtime/batching.py ``_prefill``: entry -> lock held)
+  * ``prefill``       — the prefill under that lock: slot, prefix store,
+                        program dispatch (the host returns at enqueue)
+  * ``first_token``   — a last stage's head + host-side sampling of the
+                        prefill's token, through the host read
   * ``burst_build``   — host-side burst argument prep (``_burst_prep``)
   * ``dispatch``      — issuing the jitted burst program (host returns as soon
                         as XLA enqueues; this is pure host overhead)
@@ -17,6 +23,15 @@ bench.py answers that question offline; this module answers it LIVE. A
 
 — into per-phase aggregates, mirrored into the catalog histogram
 ``server_phase_seconds{phase}`` whenever the metrics registry is enabled.
+
+ONE CLOCK: a live bracket also opens ``jax.profiler.TraceAnnotation``
+``stage.<phase>`` (with the session id or the session count it was given),
+so while a profiler trace is running every phase is a host span in the same
+``.xplane.pb`` as the device operations, and an idle gap of the device can
+be put down to what the program was doing. ``span()`` gives the annotation
+alone, for stretches that already have their statistic elsewhere (the round
+window, a follower's wait: ``server_queue_wait_seconds``). JAX is imported
+by the first live bracket, never by this module.
 
 On top of the phases it keeps the **device bubble-fraction** gauge: the
 fraction of wall time the accelerator sat idle between burst dispatches.
@@ -52,6 +67,9 @@ from .metrics import MetricsRegistry, get_registry
 # Phases bracketed on the serving hot path (display order).
 PHASES: Tuple[str, ...] = (
     "gateway_queue",
+    "prefill_wait",
+    "prefill",
+    "first_token",
     "burst_build",
     "dispatch",
     "device",
@@ -103,22 +121,47 @@ class _NoopBracket:
 _NOOP_BRACKET = _NoopBracket()
 
 
+def _annotation(name: str, meta: Dict[str, object]):
+    """The profiler-trace span ``stage.<name>``: a no-op outside a running
+    ``jax.profiler`` trace. JAX is imported here, by the first LIVE bracket,
+    so that ``telemetry/`` imports without it (and runs without it: then
+    there is no profiler to annotate)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return _NOOP_BRACKET
+    return TraceAnnotation("stage." + name, **meta)
+
+
 class _Bracket:
-    """One live phase bracket (``with prof.phase("dispatch"):``)."""
+    """One live phase bracket (``with prof.phase("dispatch"):``): the
+    phase's statistic and its ``stage.<phase>`` span on the profiler's
+    clock. ``device=True`` accounts the interval as a fenced dispatch
+    (``PhaseProfiler.device_interval``: the ``device`` phase + the bubble
+    gauge)."""
 
-    __slots__ = ("_prof", "_name", "_t0")
+    __slots__ = ("_prof", "_name", "_t0", "_span", "_device")
 
-    def __init__(self, prof: "PhaseProfiler", name: str):
+    def __init__(self, prof: "PhaseProfiler", name: str,
+                 meta: Dict[str, object], device: bool = False):
         self._prof = prof
         self._name = name
+        self._device = device
+        self._span = _annotation(name, meta)
         self._t0 = time.perf_counter()
 
     def __enter__(self):
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self._prof.observe(self._name, time.perf_counter() - self._t0)
+        t1 = time.perf_counter()
+        if self._device:
+            self._prof.device_interval(self._t0, t1)
+        else:
+            self._prof.observe(self._name, t1 - self._t0)
+        self._span.__exit__(exc_type, exc, tb)
         return None
 
 
@@ -151,12 +194,27 @@ class PhaseProfiler:
 
     # -- phase brackets -----------------------------------------------------
 
-    def phase(self, name: str):
-        """Context manager timing one phase occurrence. Disabled: returns the
-        shared no-op bracket."""
+    def phase(self, name: str, **meta):
+        """Context manager timing one phase occurrence; ``meta`` (a
+        ``session`` id or a ``sessions`` count) rides on its trace span.
+        Disabled: returns the shared no-op bracket."""
         if not self.enabled:
             return _NOOP_BRACKET
-        return _Bracket(self, name)
+        return _Bracket(self, name, meta)
+
+    def device_phase(self, **meta):
+        """Bracket of one FENCED dispatch (the caller blocks on the results
+        inside it): accounted through ``device_interval``."""
+        if not self.enabled:
+            return _NOOP_BRACKET
+        return _Bracket(self, "device", meta, device=True)
+
+    def span(self, name: str, **meta):
+        """``stage.<name>`` on the profiler's trace and nothing else: for a
+        stretch whose statistic lives in another series."""
+        if not self.enabled:
+            return _NOOP_BRACKET
+        return _annotation(name, meta)
 
     def observe(self, name: str, seconds: float) -> None:
         """Record one phase occurrence of `seconds` wall time."""

@@ -16,6 +16,7 @@ Five concerns, matching ISSUE 9's test checklist:
 """
 
 import random
+import re
 
 import pytest
 
@@ -318,3 +319,215 @@ def test_slo_tracker_ignores_undeclared_objectives():
     trk.observe("unknown-tenant", "ttft", 99.0)
     assert trk.burn_rate("free", "ttft") == 0.0
     assert trk.snapshot() == {}
+
+
+# -- the stage engine's own spans (ISSUE 25) ------------------------------------
+
+STAGE_PROMPTS = {"a": [5, 9, 23], "b": [44, 2], "c": [100, 11, 12, 13]}
+
+
+class _Spans:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records every span a
+    live bracket opens."""
+
+    def __init__(self):
+        self.made = []
+
+    def __call__(self, name, **meta):
+        self.made.append((name, meta))
+        return self
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+def _stage_adapter(monkeypatch, *, profiled, window_s=0.0):
+    """A full-span batched adapter at the tiny preset whose profiler and
+    registry are this test's own (nothing global is switched)."""
+    import jax
+
+    from test_batching import full_spec
+
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+        init_params,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
+        batching,
+    )
+
+    reg = MetricsRegistry(enabled=True)
+    prof = PhaseProfiler(enabled=profiled, registry=reg)
+    spans = _Spans()
+    monkeypatch.setattr(batching, "_get_profiler", lambda: prof)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", spans)
+    cfg = tiny_cfg()
+    inner = batching.BatchedStageExecutor(
+        cfg, full_spec(cfg), init_params(jax.random.PRNGKey(21), cfg),
+        slots=4, max_len=32)
+    adapter = batching.BatchingStageAdapter(inner, window_s=window_s)
+    adapter._m_held = catalog.get("server_batch_slots_held", reg)
+    adapter._m_fill = catalog.get("server_batch_fill_sessions", reg)
+    return adapter, prof, reg, spans
+
+
+def _stage_request(sid, ids, *, cur_len, burst=0, prefill=False):
+    import jax.numpy as jnp
+
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+        StageRequest,
+    )
+
+    return StageRequest(
+        session_id=sid, hidden=jnp.asarray([ids], jnp.int32),
+        seq_len=len(ids), cur_len=cur_len, is_prefill=prefill, max_length=32,
+        sampling=SamplingParams(temperature=0.0), generated_tokens=(7,),
+        burst_len=burst, burst_budget=burst)
+
+
+def _prefill_then_round(adapter, prof, burst):
+    """Two sessions join with the profiler dark, then (profiler as the
+    case set it) ONE prefill and ONE round of that session alone."""
+    was = prof.enabled
+    prof.set_enabled(False)
+    for sid in ("a", "b"):
+        adapter.forward(_stage_request(sid, STAGE_PROMPTS[sid], cur_len=0,
+                                       prefill=True))
+    prof.set_enabled(was)
+    first = adapter.forward(_stage_request("c", STAGE_PROMPTS["c"],
+                                           cur_len=0, prefill=True))
+    assert first.token_id is not None
+    resp = adapter.forward(_stage_request(
+        "c", [first.token_id], cur_len=len(STAGE_PROMPTS["c"]), burst=burst))
+    assert len(resp.burst_tokens) == burst if burst else resp.is_token
+
+
+@pytest.mark.parametrize("burst", [4, 0], ids=["burst_round", "step_round"])
+def test_stage_engine_phases_and_slots_held(monkeypatch, burst):
+    adapter, prof, reg, spans = _stage_adapter(monkeypatch, profiled=True)
+    _prefill_then_round(adapter, prof, burst)
+    snap = prof.snapshot()
+    for phase in ("prefill_wait", "prefill", "first_token"):
+        assert snap[phase]["count"] == 1, phase
+    round_phases = {"burst_build", "dispatch", "device", "readback"}
+    assert round_phases & set(snap) == (round_phases if burst else set())
+    # Mirrored into server_phase_seconds{phase}, which the benchmark reads.
+    mirrored = {dict(h.labels)["phase"]: h.count
+                for h in reg.get("server_phase_seconds").children()}
+    assert mirrored["first_token"] == 1 and mirrored["prefill_wait"] == 1
+    # Three sessions HELD a slot when the round ran with one of them.
+    held, fill = adapter._m_held, adapter._m_fill
+    assert (held.count, held.sum) == (1, 3.0)
+    assert (fill.count, fill.sum) == (1, 1.0)
+    # Every live bracket is a span on the profiler's clock, with its session
+    # (a round's brackets: how many sessions), and the window is a span
+    # with no statistic.
+    names = {n for n, _ in spans.made}
+    want = {"stage.prefill_wait", "stage.prefill", "stage.first_token",
+            "stage.round_window"}
+    if burst:
+        want |= {"stage." + p for p in round_phases}
+    assert names == want
+    assert ("stage.first_token", {"session": "c"}) in spans.made
+    if burst:
+        assert ("stage.dispatch", {"sessions": 1}) in spans.made
+    assert "round_window" not in snap
+
+
+@pytest.mark.parametrize("burst", [4, 0], ids=["burst_round", "step_round"])
+def test_stage_engine_dark_profiler_builds_nothing(monkeypatch, burst):
+    adapter, prof, reg, spans = _stage_adapter(monkeypatch, profiled=False)
+    for site in (prof.phase("prefill_wait", session="s"),
+                 prof.phase("first_token", session="s"),
+                 prof.device_phase(sessions=1),
+                 prof.span("round_window", session="s"),
+                 prof.span("round_wait", session="s")):
+        assert site is prof.phase("dispatch")        # the ONE shared no-op
+    _prefill_then_round(adapter, prof, burst)
+    assert spans.made == []                # no TraceAnnotation constructed
+    assert prof.snapshot() == {}
+    assert reg.get("server_phase_seconds") is None   # no series written
+
+
+def test_round_follower_wait_is_a_span(monkeypatch):
+    """Two burst requests enter together: one leads (``round_window``), the
+    other waits for the leader's step (``round_wait``), on ONE round."""
+    import threading
+
+    adapter, prof, _, spans = _stage_adapter(monkeypatch, profiled=True,
+                                             window_s=1.0)
+    firsts = {}
+    for sid in ("a", "b"):
+        firsts[sid] = adapter.forward(_stage_request(
+            sid, STAGE_PROMPTS[sid], cur_len=0, prefill=True)).token_id
+    adapter.inner.decode_burst(         # compile outside the 1 s window
+        {"a": {"token": 1, "seed": 0, "budget": 4, "eos": None,
+               "generated": (1,), "temperature": 0.0, "top_p": 1.0,
+               "top_k": 0, "repetition_penalty": 1.0}}, 4)
+    adapter.inner.rewind("a", len(STAGE_PROMPTS["a"]))
+    spans.made.clear()
+    barrier = threading.Barrier(2)
+    out = {}
+
+    def run(sid):
+        barrier.wait()
+        out[sid] = adapter.forward(_stage_request(
+            sid, [firsts[sid]], cur_len=len(STAGE_PROMPTS[sid]), burst=4))
+
+    threads = [threading.Thread(target=run, args=(s,)) for s in ("a", "b")]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+    assert set(out) == {"a", "b"}
+    names = [n for n, _ in spans.made]
+    assert names.count("stage.round_window") == 1
+    assert names.count("stage.round_wait") == 1
+    assert ("stage.dispatch", {"sessions": 2}) in spans.made
+    assert (adapter._m_fill.sum, adapter._m_held.sum) == (2.0, 2.0)
+
+
+ENGINE_SCOPES = ("embed", "attention", "kv_update", "mlp")
+
+
+@pytest.mark.parametrize("program, scopes", [
+    ("burst_tick", ENGINE_SCOPES + ("head", "sampler", "stop_rules")),
+    ("decode_step", ENGINE_SCOPES),
+    ("prefill", ENGINE_SCOPES),
+    ("prefill_suffix", ENGINE_SCOPES),
+])
+def test_engine_programs_carry_their_names(monkeypatch, program, scopes):
+    """What the device trace shows: the module is ``jit_<program>`` and the
+    operations carry the section's scope name."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    adapter, _, _, _ = _stage_adapter(monkeypatch, profiled=False)
+    inner = adapter.inner
+    inner.prefill("a", np.asarray([STAGE_PROMPTS["a"]], np.int32))
+    ids = jnp.zeros((1, 8), jnp.int32)
+    if program == "burst_tick":
+        _, args = inner._burst_prep(
+            {"a": {"token": 1, "seed": 0, "budget": 4, "eos": None,
+                   "generated": (1,), "temperature": 0.8, "top_p": 0.95,
+                   "top_k": 0, "repetition_penalty": 1.0}}, 4)
+        lowered = inner._get_burst_jit(4).lower(
+            inner.params, *args, inner.k, inner.v)
+    elif program == "decode_step":
+        lowered = inner._build_decode(1).lower(
+            inner.params, jnp.zeros((4, 1), jnp.int32),
+            jnp.asarray(inner.lengths), jnp.ones((4,), bool),
+            inner.k, inner.v)
+    elif program == "prefill":
+        lowered = inner._build_prefill().lower(
+            inner.params, ids, jnp.int32(1), inner.k, inner.v, jnp.int32(5))
+    else:
+        lowered = inner._build_prefill_suffix().lower(
+            inner.params, ids, jnp.int32(1), inner.k, inner.v, jnp.int32(4),
+            jnp.int32(5))
+    text = lowered.as_text(debug_info=True)
+    assert f"module @jit_{program} " in text.replace("attributes", " ")
+    for scope in scopes:    # loc("jit(decode_step)/embed/…"), loc("mlp/…")
+        assert re.search(rf'["/]{scope}/', text), scope
