@@ -9,6 +9,7 @@ from .sampling import (
     push_recent,
     sample_probs,
     sample_token,
+    sample_tokens,
 )
 
 __all__ = [
@@ -25,6 +26,7 @@ __all__ = [
     "push_recent",
     "sample_probs",
     "sample_token",
+    "sample_tokens",
 ]
 
 

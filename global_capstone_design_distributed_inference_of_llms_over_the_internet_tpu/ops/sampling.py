@@ -17,8 +17,20 @@ which runs ON THE FINAL STAGE (sampling params travel in request metadata):
 
 Differences by design (TPU): the "recent tokens" window is a fixed-size int32
 ring buffer so the whole sampler is one compiled XLA program with static
-shapes; ties at the top-k boundary keep all tied entries (sort-threshold
-instead of an exact-k gather) — measure-zero for real logits.
+shapes. The vocabulary is never permuted: steps 5 and 6 are thresholds on the
+UNSORTED row (the k-th largest probability; the smallest probability of the
+nucleus prefix), each found by bisection on the fp32 bit pattern with masked
+sums over the row, so there is no sort, no gather and no scatter over the
+vocabulary, and step 2-3 touch the <= 50 recent ids only. Hence two tie rules,
+both measure-zero for real logits: ties at the top-k boundary keep all tied
+entries, and so do ties at the NUCLEUS boundary: entries exactly equal to the
+smallest kept probability are all kept (the reference's sort order picks some
+of them). A stage that no row of a call asks for is skipped at run time
+(``lax.cond`` on the traced knobs): greedy rows take the argmax alone.
+
+One implementation: `sample_tokens` over [S, V] rows (the burst tick, the
+executor's batch rows, the ring); `sample_token` / `sample_probs` are its
+S = 1 case.
 """
 
 from __future__ import annotations
@@ -76,52 +88,167 @@ def apply_repetition_penalty(
     num_valid: jnp.ndarray,
     repetition_penalty: jnp.ndarray,
 ) -> jnp.ndarray:
-    """Count-scaled, sign-aware repetition penalty over the recent window.
+    """Count-scaled, sign-aware repetition penalty over the recent window,
+    computed AT THE RECENT IDS ONLY: gather their <= 50 logits, count each
+    id's repeats inside the window (a 50 x 50 compare), penalise, write the
+    <= 50 values back. Nothing here is vocabulary-sized but the row itself.
 
     logits: [V] float32. recent_tokens: [RECENT_WINDOW] int32 (newest last).
     """
     vocab = logits.shape[-1]
-    valid = jnp.arange(recent_tokens.shape[0]) < num_valid
-    safe = jnp.where(valid, recent_tokens, 0)
-    counts = jnp.zeros((vocab,), jnp.float32).at[safe].add(valid.astype(jnp.float32))
-
+    window = recent_tokens.shape[0]
+    valid = jnp.arange(window) < num_valid
+    ids = jnp.where(valid, recent_tokens, 0)
+    vals = logits[ids]
+    counts = jnp.sum((ids[:, None] == ids[None, :]) & valid[None, :],
+                     axis=-1).astype(jnp.float32)
     penalty = repetition_penalty ** counts
-    penalized = jnp.where(logits > 0, logits / penalty, logits * penalty)
-    logits = jnp.where(counts > 0, penalized, logits)
+    vals = jnp.where(vals > 0, vals / penalty, vals * penalty)
 
-    # Triple-repeat strong penalty (rp**3) on the token repeated 3x in a row.
+    # Triple-repeat strong penalty (rp**3) on the token repeated 3x in a
+    # row, on top of its count penalty (it is one of the recent ids).
     n = num_valid
-    t1 = recent_tokens[jnp.clip(n - 1, 0, RECENT_WINDOW - 1)]
-    t2 = recent_tokens[jnp.clip(n - 2, 0, RECENT_WINDOW - 1)]
-    t3 = recent_tokens[jnp.clip(n - 3, 0, RECENT_WINDOW - 1)]
+    t1 = recent_tokens[jnp.clip(n - 1, 0, window - 1)]
+    t2 = recent_tokens[jnp.clip(n - 2, 0, window - 1)]
+    t3 = recent_tokens[jnp.clip(n - 3, 0, window - 1)]
     is_triple = (n >= 3) & (t1 == t2) & (t2 == t3)
     strong = repetition_penalty ** 3
-    cur = logits[t1]
-    hit = jnp.where(cur > 0, cur / strong, cur * strong)
-    return logits.at[t1].set(jnp.where(is_triple, hit, cur))
+    hit = jnp.where(vals > 0, vals / strong, vals * strong)
+    vals = jnp.where(is_triple & (ids == t1), hit, vals)
+    # Empty window slots point past the row and are dropped; a repeated id
+    # writes the same value from each of its slots.
+    return logits.at[jnp.where(valid, ids, vocab)].set(vals, mode="drop")
 
 
-def _top_k_filter(probs: jnp.ndarray, top_k: jnp.ndarray) -> jnp.ndarray:
-    vocab = probs.shape[-1]
-    sorted_desc = jnp.sort(probs, axis=-1)[::-1]
-    kth = sorted_desc[jnp.clip(top_k - 1, 0, vocab - 1)]
-    apply = (top_k > 0) & (top_k < vocab)
-    return jnp.where(apply & (probs < kth), 0.0, probs)
+def row_keys(base: jax.Array, rows: int) -> jax.Array:
+    """The [rows] keys of one step of a session with batch rows: row 0 keeps
+    the unfolded step key by contract (batch-1 output never changes), row i
+    folds i in."""
+    return jnp.stack([base] + [jax.random.fold_in(base, i)
+                               for i in range(1, rows)])
 
 
-def _top_p_filter(probs: jnp.ndarray, top_p: jnp.ndarray) -> jnp.ndarray:
-    order = jnp.argsort(-probs, axis=-1)
-    sorted_probs = probs[order]
-    cum = jnp.cumsum(sorted_probs, axis=-1)
-    keep = cum <= top_p
-    keep = keep.at[0].set(True)
-    filtered = sorted_probs * keep
-    filtered = filtered / jnp.maximum(filtered.sum(), 1e-20)
-    scattered = jnp.zeros_like(probs).at[order].set(filtered)
-    apply = (top_p > 0.0) & (top_p < 1.0)
-    return jnp.where(apply, scattered, probs)
+def _row_knobs(rows: int, *knobs):
+    """Scalars or [S] arrays -> [S] arrays (a session's knobs are shared by
+    its batch rows; the burst holds one set per slot)."""
+    return tuple(jnp.broadcast_to(k, (rows,)) for k in knobs)
 
 
+def _stages(top_p, top_k, repetition_penalty, vocab: int):
+    """Per-row masks of what each row's knobs switch on: (top-k, top-p,
+    penalty). Operators only, so the host's numpy knob arrays
+    (`sampler_stages`) and the traced ones give the same answer."""
+    k_on = (top_k > 0) & (top_k < vocab)
+    p_on = (top_p > 0.0) & (top_p < 1.0)
+    return k_on, p_on, repetition_penalty != 1.0
+
+
+def sampler_stages(temperature, top_p, top_k, repetition_penalty,
+                   vocab: int) -> str:
+    """Which of the sampler's stages a round with these knobs runs, as the
+    label of ``server_sampler_rounds_total``: ``greedy`` (argmax only),
+    ``plain`` (softmax + draw), ``filter`` (the top-k / top-p threshold
+    searches), ``penalty``, ``filter+penalty``. Host-side mirror of the
+    ``lax.cond`` predicates in `sample_tokens`: a greedy row switches
+    nothing on, whatever its other knobs say; a penalised row with an empty
+    recent window skips the penalty on device until its first token lands."""
+    sampled = ~(temperature <= 0.0)
+    k_on, p_on, rp_on = _stages(top_p, top_k, repetition_penalty, vocab)
+    if not sampled.any():
+        return "greedy"
+    on = [name for name, mask in (("filter", k_on | p_on),
+                                  ("penalty", rp_on)) if (mask & sampled).any()]
+    return "+".join(on) or "plain"
+
+
+def _order_bits(probs):
+    """Non-negative fp32 -> int32 with the same order: the bit pattern."""
+    return jax.lax.bitcast_convert_type(probs, jnp.int32)
+
+
+def _first_true(pred, hi):
+    """Per row, the smallest int32 ``t`` in [0, hi] with ``pred(t)`` true,
+    for a ``pred`` that is monotone in ``t`` (false, then true) and true at
+    ``hi``. 31 halvings close any interval of non-negative fp32 bit
+    patterns; each is one masked pass over the rows, no sort."""
+    def halve(_, bounds):
+        lo, hi = bounds              # pred false at lo (or lo == -1), true at hi
+        mid = lo + (hi - lo + 1) // 2
+        ok = pred(mid)
+        return jnp.where(ok, lo, mid), jnp.where(ok, mid, hi)
+
+    return jax.lax.fori_loop(0, 31, halve, (jnp.full_like(hi, -1), hi))[1]
+
+
+def _top_k_rows(probs, top_k, k_on):
+    """Zero what lies under the k-th largest probability of each row [S, V]
+    (unrenormalised; ties at the boundary all stay). The k-th largest value
+    is the largest threshold that still keeps k entries."""
+    bits = _order_bits(probs)
+    kth = _first_true(
+        lambda t: jnp.sum(bits >= t[:, None], axis=-1) < top_k,
+        bits.max(axis=-1) + 1) - 1
+    return jnp.where(k_on[:, None] & (bits < kth[:, None]), 0.0, probs)
+
+
+def _top_p_rows(probs, top_p, p_on):
+    """Nucleus on the UNSORTED rows [S, V]: keep ``probs >= p_min`` and
+    renormalise, where ``p_min`` is the smallest probability of the sorted
+    prefix with cumsum <= top_p (first always kept), found as a threshold
+    on the mass above it. No order of the vocabulary is built, so there is
+    nothing to sort, gather or scatter back."""
+    bits = _order_bits(probs)
+    top = bits.max(axis=-1)
+
+    def mass(t):
+        return jnp.sum(jnp.where(bits >= t[:, None], probs, 0.0), axis=-1)
+
+    # Distinct values: the prefix ends at the smallest t with mass(t) <= top_p.
+    t = _first_true(lambda mid: mass(mid) <= top_p, top + 1)
+    # Equal values just under t: the sorted prefix takes them one at a time,
+    # so if ONE more fits, that value is the smallest kept and (the tie
+    # rule) all its equals stay.
+    under = jnp.max(jnp.where(bits < t[:, None], bits, 0), axis=-1)
+    one_more = mass(t) + jax.lax.bitcast_convert_type(under, jnp.float32)
+    p_min = jnp.minimum(jnp.where(one_more <= top_p, under, t), top)
+    nucleus = jnp.where(bits >= p_min[:, None], probs, 0.0)
+    nucleus = nucleus / jnp.maximum(
+        nucleus.sum(axis=-1, keepdims=True), 1e-20)
+    return jnp.where(p_on[:, None], nucleus, probs)
+
+
+def _batched_probs(wanted, logits, recent, nvalid, temperature, top_p, top_k,
+                   repetition_penalty):
+    """[S, V] logits + [S, RECENT_WINDOW] windows + [S] knobs -> [S, V]
+    categorical distributions, for the rows in the [S] mask ``wanted`` (the
+    others come back unfiltered). A stage no wanted row asks for is not run:
+    the ``lax.cond``s sit OUTSIDE the row dimension, on traced knobs, so one
+    executable serves every combination; a row whose own knob is off goes
+    through a running stage unchanged."""
+    logits = logits.astype(jnp.float32)
+    k_on, p_on, rp_on = _stages(
+        top_p, top_k, repetition_penalty, logits.shape[-1])
+    k_on, p_on = k_on & wanted, p_on & wanted
+    rp_on = rp_on & wanted & (nvalid > 0)
+    # A row that is off takes part with an EMPTY window: nothing is written.
+    logits = jax.lax.cond(
+        jnp.any(rp_on),
+        lambda x: jax.vmap(apply_repetition_penalty)(
+            x, recent, jnp.where(rp_on, nvalid, 0), repetition_penalty),
+        lambda x: x,
+        logits)
+    temp = jnp.maximum(temperature, 1e-5)
+    probs = jax.nn.softmax(logits / temp[:, None], axis=-1)
+    probs = jax.lax.cond(
+        jnp.any(k_on), lambda p: _top_k_rows(p, top_k, k_on), lambda p: p,
+        probs)
+    probs = jax.lax.cond(
+        jnp.any(p_on), lambda p: _top_p_rows(p, top_p, p_on), lambda p: p,
+        probs)
+    return probs / jnp.maximum(probs.sum(axis=-1, keepdims=True), 1e-20)
+
+
+@jax.jit
 def sample_probs(
     logits: jnp.ndarray,
     recent_tokens: jnp.ndarray,
@@ -134,19 +261,12 @@ def sample_probs(
     """Final categorical distribution after penalty + temp + top-k + top-p.
 
     logits: [V]. Returns probs [V] summing to 1 (greedy handled by caller).
+    The S = 1 case of `_batched_probs`.
     """
-    logits = logits.astype(jnp.float32)
-    apply_rp = (repetition_penalty != 1.0) & (num_valid > 0)
-    logits = jnp.where(
-        apply_rp,
-        apply_repetition_penalty(logits, recent_tokens, num_valid, repetition_penalty),
-        logits,
-    )
-    temp = jnp.maximum(temperature, 1e-5)
-    probs = jax.nn.softmax(logits / temp, axis=-1)
-    probs = _top_k_filter(probs, top_k)
-    probs = _top_p_filter(probs, top_p)
-    return probs / jnp.maximum(probs.sum(), 1e-20)
+    return _batched_probs(
+        jnp.ones((1,), bool), logits[None], recent_tokens[None],
+        *_row_knobs(1, num_valid, temperature, top_p, top_k,
+                    repetition_penalty))[0]
 
 
 def speculative_verify(
@@ -288,6 +408,47 @@ def speculative_verify_jit(
     return toks, n_acc, recent, nvalid
 
 
+@jax.jit
+def sample_tokens(
+    rngs: jax.Array,
+    logits: jnp.ndarray,
+    recent_tokens: jnp.ndarray,
+    num_valid: jnp.ndarray,
+    temperature: jnp.ndarray,
+    top_p: jnp.ndarray,
+    top_k: jnp.ndarray,
+    repetition_penalty: jnp.ndarray,
+) -> jnp.ndarray:
+    """THE sampler: one key and one token per row. rngs: [S] keys, logits:
+    [S, V], recent_tokens: [S, RECENT_WINDOW] (or one [RECENT_WINDOW] window
+    shared by the rows), num_valid and the knobs: scalars or [S]. -> [S]
+    int32 tokens.
+
+    All knobs are traced so every (temperature, top_p, top_k, rp)
+    combination reuses one executable; what the round's knobs do not ask for
+    is skipped at run time (`_batched_probs`), and a round whose every row
+    is greedy takes the argmax alone.
+    """
+    rows, _ = logits.shape
+    recent_tokens = jnp.broadcast_to(
+        recent_tokens, (rows, recent_tokens.shape[-1]))
+    num_valid, *knobs = _row_knobs(
+        rows, num_valid, temperature, top_p, top_k, repetition_penalty)
+    greedy_row = knobs[0] <= 0.0
+    # Greedy reads the RAW logits (the reference takes the argmax before
+    # any penalty, src/rpc_handler.py:334-335).
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw(_):
+        probs = _batched_probs(~greedy_row, logits, recent_tokens, num_valid,
+                               *knobs)
+        sampled = jax.vmap(jax.random.categorical)(
+            rngs, jnp.log(jnp.maximum(probs, 1e-20)))
+        return jnp.where(greedy_row, greedy, sampled.astype(jnp.int32))
+
+    return jax.lax.cond(jnp.all(greedy_row), lambda _: greedy, draw, None)
+
+
 def sample_token(
     rng: jax.Array,
     logits: jnp.ndarray,
@@ -298,17 +459,12 @@ def sample_token(
     top_k: jnp.ndarray,
     repetition_penalty: jnp.ndarray,
 ) -> jnp.ndarray:
-    """One compiled sampling step. logits: [V] -> scalar int32 token.
-
-    All knobs are traced scalars so every (temperature, top_p, top_k, rp)
-    combination reuses one executable.
+    """One sampling step. logits: [V] -> scalar int32 token: the S = 1 case
+    of `sample_tokens`, so every engine draws through one implementation.
     """
-    probs = sample_probs(
-        logits, recent_tokens, num_valid, temperature, top_p, top_k, repetition_penalty
-    )
-    sampled = jax.random.categorical(rng, jnp.log(jnp.maximum(probs, 1e-20)))
-    greedy = jnp.argmax(logits, axis=-1)
-    return jnp.where(temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+    return sample_tokens(
+        rng[None], logits[None], recent_tokens, num_valid, temperature,
+        top_p, top_k, repetition_penalty)[0]
 
 
 # Jitted entry for HOST-LOOP callers (per-token CLI paths): one compiled

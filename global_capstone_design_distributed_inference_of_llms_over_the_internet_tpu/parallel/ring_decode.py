@@ -73,7 +73,12 @@ from jax.sharding import PartitionSpec as P
 from ..models.config import ModelConfig
 from ..models.transformer import (_norm, embed_tokens, lm_head,
                                   stack_forward)
-from ..ops.sampling import RECENT_WINDOW, push_recent, sample_token
+from ..ops.sampling import (
+    RECENT_WINDOW,
+    push_recent,
+    row_keys,
+    sample_tokens,
+)
 from .pipeline import IciPipeline, _kv_spec
 
 Params = Dict[str, Any]
@@ -118,7 +123,7 @@ def _ring_body(cfg: ModelConfig, num_stages: int, num_groups: int,
     ``sampled=True`` threads per-session sampler state — recent [G, B, W],
     nvalid [G, B] — and per-session knobs (seed_base/temps/top_ps/top_ks/
     reps, all [G]); the last stage then samples via the exact oracle head
-    (``lm_head``, fp32) + ``ops.sampling.sample_token`` with key
+    (``lm_head``, fp32) + ``ops.sampling.sample_tokens`` with key
     ``PRNGKey(seed_base[g] + step_i)``, row b > 0 folded like
     ``executor._sample_rows``."""
     S, G = num_stages, num_groups
@@ -165,17 +170,8 @@ def _ring_body(cfg: ModelConfig, num_stages: int, num_groups: int,
             logits = lm_head(cfg, hp, h)[:, 0]             # [B, V] fp32
             base = jax.random.PRNGKey(seed_base[g] + step_i)
             knobs = (temps[g], top_ps[g], top_ks[g], reps[g])
-            if B == 1:
-                tok = sample_token(base, logits[0], rec_g[0], nv_g[0],
-                                   *knobs)[None]
-            else:
-                rngs = jnp.stack(
-                    [base if i == 0 else jax.random.fold_in(base, i)
-                     for i in range(B)])
-                tok = jax.vmap(
-                    sample_token,
-                    in_axes=(0, 0, 0, 0, None, None, None, None),
-                )(rngs, logits, rec_g, nv_g, *knobs)
+            tok = sample_tokens(row_keys(base, B), logits, rec_g, nv_g,
+                                *knobs)
             rec_g, nv_g = jax.vmap(push_recent)(rec_g, nv_g, tok)
             return tok.astype(jnp.int32), rec_g, nv_g
 

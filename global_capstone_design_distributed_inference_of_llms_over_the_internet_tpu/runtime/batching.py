@@ -219,6 +219,7 @@ class BatchedStageExecutor:
         self._m_burst_ticks = _tm.get("server_burst_ticks")
         self._m_burst_disp = _tm.get("server_burst_dispatches_total")
         self._m_burst_toks = _tm.get("server_burst_tokens_total")
+        self._m_sampler = _tm.get("server_sampler_rounds_total")
         # Prompt-prefix KV reuse (runtime.prefix_cache), slot-layout
         # variant: entries hold [L, G, Hkv, Dh] KV segments (+ [1, G, D]
         # output rows off the final stage). Same grain-chained rolling
@@ -751,7 +752,7 @@ class BatchedStageExecutor:
         Determinism contract: tick i of a slot whose request shipped
         ``step_seed`` samples with ``PRNGKey(step_seed + i)`` — exactly the
         key the sequential client would ship for that token (its step_seed
-        is ``seed + len(generated)``), and the same ``sample_token`` /
+        is ``seed + len(generated)``), and the same ``sample_tokens`` /
         ``push_recent`` math as executor._sample_rows, so burst tokens are
         bit-identical to the per-tick baseline.
 
@@ -763,7 +764,7 @@ class BatchedStageExecutor:
         S = self.slots
         N = n_ticks
         from ..models.transformer import lm_head
-        from ..ops.sampling import push_recent, sample_token
+        from ..ops.sampling import push_recent, sample_tokens
 
         @partial(jax.jit, donate_argnums=engine_donation(14, 15))
         def burst_tick(params, tok, lengths, alive, seeds, recent, nvalid,
@@ -840,7 +841,7 @@ class BatchedStageExecutor:
                     logits = lm_head(cfg, params, h)[:, 0]    # [S, V] fp32
                 with jax.named_scope("sampler"):
                     keys = jax.vmap(jax.random.PRNGKey)(seeds + i)
-                    sampled = jax.vmap(sample_token)(
+                    sampled = sample_tokens(
                         keys, logits, recent, nvalid, temp, top_p, top_k,
                         rp)
                 # Host stop-rule mirror, in host order: the token is always
@@ -897,7 +898,7 @@ class BatchedStageExecutor:
         temperature, top_p, top_k, repetition_penalty} — the stateless
         per-burst mirror of what the wire protocol ships every step, so
         failover needs no server-side sampler state."""
-        from ..ops.sampling import RECENT_WINDOW
+        from ..ops.sampling import RECENT_WINDOW, sampler_stages
 
         if not (self.spec.is_first and self.spec.is_last):
             raise RuntimeError(
@@ -951,6 +952,8 @@ class BatchedStageExecutor:
             rp[s] = float(e["repetition_penalty"])
             alive[s] = True
             rows[sid] = s
+        self._m_sampler.labels(stages=sampler_stages(
+            temp, top_p, top_k, rp, self.cfg.vocab_size)).inc()
         # lengths copied for the same reason as in decode_batch.
         args = (jnp.asarray(tok0), jnp.asarray(self.lengths.copy()),
                 jnp.asarray(alive), jnp.asarray(seeds), jnp.asarray(recent),

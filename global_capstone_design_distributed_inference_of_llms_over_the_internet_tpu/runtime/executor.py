@@ -43,7 +43,7 @@ from ..models.partition import (
     StageSpec,
     stage_forward,
 )
-from ..ops.sampling import RECENT_WINDOW, sample_token
+from ..ops.sampling import RECENT_WINDOW, row_keys, sample_tokens
 from ..models.transformer import stack_forward_train
 from ..telemetry import events as _ev
 from ..utils.platform import engine_donation
@@ -166,16 +166,7 @@ def _sample_rows(logits: jnp.ndarray, t_real: int, req: StageRequest) -> np.ndar
         jnp.asarray(sp.top_k, jnp.int32),
         jnp.asarray(sp.repetition_penalty, jnp.float32),
     )
-    if b == 1:
-        # Hot path (every decode step in every serving mode): skip the vmap
-        # wrapper + key stack — row 0's key is the unfolded base by contract.
-        return np.asarray(sample_token(base, last[0], *args))[None]
-    rngs = jnp.stack([base if i == 0 else jax.random.fold_in(base, i)
-                      for i in range(b)])
-    tokens = jax.vmap(
-        sample_token, in_axes=(0, 0, None, None, None, None, None, None)
-    )(rngs, last, *args)
-    return np.asarray(tokens)
+    return np.asarray(sample_tokens(row_keys(base, b), last, *args))
 
 
 def _sample_last(logits: jnp.ndarray, t_real: int, req: StageRequest) -> int:

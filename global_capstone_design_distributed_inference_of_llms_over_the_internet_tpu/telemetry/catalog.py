@@ -204,6 +204,11 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
     "server_burst_ticks": (
         HISTOGRAM, "Configured tick count per burst dispatch (the N of "
                    "each lax.scan program).", (), FILL_BUCKETS),
+    "server_sampler_rounds_total": (
+        COUNTER, "Burst rounds by the sampler stages their knobs switch on "
+                 "(greedy|plain|filter|penalty|filter+penalty): which path "
+                 "of ops.sampling the round's ticks ran, read from the "
+                 "host's knob arrays.", ("stages",), None),
     # -- server task pools ----------------------------------------------------
     "server_task_queue_depth": (
         GAUGE, "Tasks queued in each stage-server pool "

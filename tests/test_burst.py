@@ -12,6 +12,7 @@ sequential baseline — bursts change the cost structure, never the tokens.
 """
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,13 +81,20 @@ def _sample(logits_row, generated, step_seed, sp):
         jnp.asarray(sp.repetition_penalty, jnp.float32))))
 
 
+def _per_session(sp, prompts):
+    """One SamplingParams for every session, or {session: SamplingParams}."""
+    return sp if isinstance(sp, dict) else dict.fromkeys(prompts, sp)
+
+
 def _sequential(cfg, params, prompts, sp, seed, max_new, eos=None):
     """Per-step decode with host sampling + host stop rules: the baseline
     a burst must match bit-for-bit."""
     ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
                               max_len=64)
     out = {}
+    sps = _per_session(sp, prompts)
     for sid, p in prompts.items():
+        sp = sps[sid]
         h = ex.prefill(sid, np.asarray([p], np.int32))
         logits = ex.logits(h[:, -1:])[0, -1]
         generated = [_sample(logits, [], seed, sp)]
@@ -111,14 +119,16 @@ def _bursty(cfg, params, prompts, sp, seed, max_new, n_ticks, eos=None):
     ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
                               max_len=64)
     gen = {}
+    sps = _per_session(sp, prompts)
     for sid, p in prompts.items():
         h = ex.prefill(sid, np.asarray([p], np.int32))
-        gen[sid] = [_sample(ex.logits(h[:, -1:])[0, -1], [], seed, sp)]
+        gen[sid] = [_sample(ex.logits(h[:, -1:])[0, -1], [], seed, sps[sid])]
     live = set(prompts)
     while live:
         entries = {}
         for sid in sorted(live):
             g = gen[sid]
+            sp = sps[sid]
             if len(g) >= max_new:
                 live.discard(sid)
                 continue
@@ -165,6 +175,82 @@ def test_burst_engine_matches_sequential(cfg, params, sp):
     # never the session count.
     assert ex.burst_dispatches <= math.ceil((12 - 1) / 4)
     assert ex.burst_tokens == sum(len(g) - 1 for g in got.values())
+
+
+# One slot each: greedy (with the filters and the penalty SET, as
+# SamplingParams(temperature=0.0) leaves them), filtered, penalised, plain.
+MIXED = {"a": SamplingParams(temperature=0.0),
+         "b": SamplingParams(temperature=0.8, top_p=0.95, top_k=0,
+                             repetition_penalty=1.0),
+         "c": SamplingParams(temperature=1.0, top_p=1.0, top_k=0,
+                             repetition_penalty=1.5),
+         "d": SamplingParams(temperature=1.0, top_p=1.0, top_k=0,
+                             repetition_penalty=1.0)}
+MIXED_PROMPTS = dict(PROMPTS, d=[2, 44, 6])
+
+
+@pytest.mark.parity
+def test_burst_mixed_knobs_match_row_by_row_sampling(cfg, params):
+    """Slots with DIFFERENT knobs share one batched sampler call a tick;
+    each must get, bit for bit, the token row-by-row `sample_token` draws
+    for it alone, from one executable for every combination."""
+    ref = _sequential(cfg, params, MIXED_PROMPTS, MIXED, seed=3, max_new=12)
+    got, ex = _bursty(cfg, params, MIXED_PROMPTS, MIXED, seed=3, max_new=12,
+                      n_ticks=4)
+    for sid in MIXED_PROMPTS:
+        assert got[sid] == ref[sid], (sid, got[sid], ref[sid])
+    # ... and a round of one knob set alone reuses the same program.
+    ex.decode_burst({"a": {"token": got["a"][-1], "seed": 0, "budget": 4,
+                           "eos": None, "generated": tuple(got["a"]),
+                           "temperature": 0.7, "top_p": 0.9, "top_k": 50,
+                           "repetition_penalty": 1.5}}, 4)
+    assert ex._get_burst_jit(4)._cache_size() == 1
+
+
+def _array_shapes(hlo_text):
+    """name -> dims of every array-valued instruction of an HLO module."""
+    shapes = {}
+    for m in re.finditer(r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]*)\]", hlo_text,
+                         re.M):
+        shapes[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+    return shapes
+
+
+def test_burst_tick_never_permutes_the_vocabulary(cfg, params):
+    """The lowered `jit_burst_tick` (ONE program for every knob combination:
+    the knobs are traced) holds no gather or scatter whose indices or
+    updates are vocabulary-sized (the embedding lookup reads [S] ids, the
+    penalty <= 50 ids a row) and no vocabulary-wide sort: the sampler works
+    on the unsorted rows."""
+    vocab = cfg.vocab_size
+    assert vocab not in (4, 32, 64, RECENT_WINDOW)    # dims tell V apart
+    ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
+                              max_len=32)
+    ex.prefill("a", np.asarray([PROMPT], np.int32))
+    _, args = ex._burst_prep(
+        {"a": {"token": 1, "seed": 0, "budget": 4, "eos": None,
+               "generated": (1,), "temperature": 0.7, "top_p": 0.9,
+               "top_k": 50, "repetition_penalty": 1.5}}, 4)
+    hlo = ex._get_burst_jit(4).lower(ex.params, *args, ex.k, ex.v) \
+        .compiler_ir(dialect="hlo").as_hlo_text()
+    shapes = _array_shapes(hlo)
+    seen = {"gather": 0, "scatter": 0, "sort": 0}
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(\S+) = \S+ (gather|scatter|sort)\(([^)]*)\)",
+            hlo, re.M):
+        name, op, operands = m.group(1), m.group(2), m.group(3)
+        operands = [o.strip().split(" ")[-1] for o in operands.split(",")]
+        seen[op] += 1
+        if op == "sort":
+            assert vocab not in shapes[operands[0]], m.group(0)
+            continue
+        # gather(operand, indices) -> result; scatter(operand, indices,
+        # updates): everything but the operand itself stays small.
+        small = operands[1:] + ([name] if op == "gather" else [])
+        for o in small:
+            assert vocab not in shapes[o], (m.group(0), o, shapes[o])
+    # The walk saw the program: the embedding gather, the penalty's scatter.
+    assert seen["gather"] >= 2 and seen["scatter"] >= 1, seen
 
 
 @pytest.mark.parity

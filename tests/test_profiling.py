@@ -373,7 +373,8 @@ def _stage_adapter(monkeypatch, *, profiled, window_s=0.0):
     return adapter, prof, reg, spans
 
 
-def _stage_request(sid, ids, *, cur_len, burst=0, prefill=False):
+def _stage_request(sid, ids, *, cur_len, burst=0, prefill=False,
+                   sampling=SamplingParams(temperature=0.0)):
     import jax.numpy as jnp
 
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
@@ -383,7 +384,7 @@ def _stage_request(sid, ids, *, cur_len, burst=0, prefill=False):
     return StageRequest(
         session_id=sid, hidden=jnp.asarray([ids], jnp.int32),
         seq_len=len(ids), cur_len=cur_len, is_prefill=prefill, max_length=32,
-        sampling=SamplingParams(temperature=0.0), generated_tokens=(7,),
+        sampling=sampling, generated_tokens=(7,),
         burst_len=burst, burst_budget=burst)
 
 
@@ -449,6 +450,26 @@ def test_stage_engine_dark_profiler_builds_nothing(monkeypatch, burst):
     assert spans.made == []                # no TraceAnnotation constructed
     assert prof.snapshot() == {}
     assert reg.get("server_phase_seconds") is None   # no series written
+
+
+def test_sampler_rounds_counted_by_stage(monkeypatch):
+    """`server_sampler_rounds_total{stages}` says which path of the sampler
+    a burst round's knobs switch on: the benchmark cells' knobs (0.8, 0.95,
+    0, 1.0) run the nucleus filter; a greedy round, whose other knobs are
+    the defaults (top-k 50, penalty 1.5), runs the argmax alone."""
+    adapter, _, reg, _ = _stage_adapter(monkeypatch, profiled=False)
+    adapter.inner._m_sampler = catalog.get("server_sampler_rounds_total", reg)
+    cells = SamplingParams(temperature=0.8, top_p=0.95, top_k=0,
+                           repetition_penalty=1.0)
+    for sid, sp in (("a", cells), ("b", SamplingParams(temperature=0.0))):
+        first = adapter.forward(_stage_request(
+            sid, STAGE_PROMPTS[sid], cur_len=0, prefill=True, sampling=sp))
+        adapter.forward(_stage_request(
+            sid, [first.token_id], cur_len=len(STAGE_PROMPTS[sid]), burst=4,
+            sampling=sp))
+    counted = {dict(c.labels)["stages"]: c.value
+               for c in reg.get("server_sampler_rounds_total").children()}
+    assert counted == {"filter": 1.0, "greedy": 1.0}
 
 
 def test_round_follower_wait_is_a_span(monkeypatch):
