@@ -21,6 +21,15 @@ Two numbers are compared, each printed beside its limit:
                   of tokens; large when the burst path reads the wrong
                   cache rows, positions or weights
 
+The reference is the configuration's own module where its file names one
+(``"reference"``: ``make_weights`` and ``forward`` with the stock
+module's signatures), else ``harness/reference.py``. The program's
+configuration is built the way the server builds it, from the
+configuration's model arguments through the program's own parser. The
+check may run smaller than the cell in what the cell already lists as
+reduced (``check.layers``, ``check.model_args``, ``check.reduced_to``);
+the ``CHECK`` line prints the sizes it ran at beside the cell's.
+
 ``--control`` builds the engine with the configuration's control
 (``check.control``), the program's own next quantisation down (``int8``
 for a bfloat16 configuration, ``nf4`` for an int8 one). It has to come out
@@ -35,10 +44,15 @@ import argparse
 import dataclasses
 import importlib
 import json
+import os
 import sys
 import time
 
+from .manifest import load_module
+
 PKG = "global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu"
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
 def server_arg(config: dict, flag: str, default=None):
@@ -54,28 +68,54 @@ def check_lengths(traffic: dict, n: int) -> list:
     return [table[round(i * (len(table) - 1) / (n - 1))] for i in range(n)]
 
 
+def reference_of(config: dict):
+    """The module that gives ``make_weights`` and ``forward``."""
+    if config.get("reference"):
+        return load_module(os.path.join(ROOT, config["reference"]))
+    from . import reference
+    return reference
+
+
+def program_config(model_args: list):
+    """The program's configuration as the server builds it: whatever
+    argument states a configuration's share reaches the check this way."""
+    main = importlib.import_module(PKG + ".main")
+    return main.load_config(main.build_parser().parse_args(list(model_args)))
+
+
 def run_seed(config: dict, traffic: dict, seed: int, *, control: bool,
              dry: bool) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from . import reference
-
     t0 = time.time()
-    models = importlib.import_module(PKG + ".models")
+    reference = reference_of(config)
     hf_import = importlib.import_module(PKG + ".models.hf_import")
     partition = importlib.import_module(PKG + ".models.partition")
     quant_mod = importlib.import_module(PKG + ".models.quant")
     batching = importlib.import_module(PKG + ".runtime.batching")
 
     chk = config["check"]
-    hf = chk["dry_run_hf_config"] if dry else config["hf_config"]
     layers = int(chk["layers"])
-    model_args = (config["dry_run_model_args"] if dry
-                  else config["deployment"]["model_args"])
-    preset = model_args[model_args.index("--model") + 1]
-    cfg = dataclasses.replace(models.get_config(preset), num_layers=layers)
+    if dry:
+        hf, cut = chk["dry_run_hf_config"], {}
+        cell_args = check_args = config["dry_run_model_args"]
+    else:
+        cut = chk.get("reduced_to", {})
+        hf = dict(config["hf_config"], **cut)
+        cell_args = config["deployment"]["model_args"]
+        check_args = chk.get("model_args", cell_args)
+    cell_cfg = program_config(cell_args)
+    cfg = dataclasses.replace(
+        cell_cfg if check_args == cell_args else program_config(check_args),
+        num_layers=layers)
+    sizes = {"check": dict(cut, layers=layers),
+             "cell": dict({k: config["hf_config"][k] for k in cut},
+                          layers=cell_cfg.num_layers)}
+    if check_args != cell_args:
+        sizes["check"]["model_args"] = check_args
+        sizes["cell"]["model_args"] = cell_args
     quant = chk["control"] if control else server_arg(config, "--quant",
                                                       "none")
     slots = int(server_arg(config, "--slots", 8))
@@ -191,7 +231,7 @@ def run_seed(config: dict, traffic: dict, seed: int, *, control: bool,
           and mean_gap <= lim["burst_gap"]
           and (not rounds or n_gap >= len(lens)))
     return {"seed": seed, "control": chk["control"] if control else None,
-            "quant": quant, "layers": layers, "slots": slots,
+            "quant": quant, "layers": layers, "sizes": sizes, "slots": slots,
             "prompt_lens": lens, "logit_rows": n_rows,
             "burst_rounds": rounds, "burst_tokens": n_gap,
             "logit_rel_rms": worst_rms,

@@ -3,16 +3,21 @@
 A cell is an entry of ``workloads``: it names a configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<traffic>.json``).
 A per-layer metric is ``layer_metrics/<name>.json`` (+ an optional
-``<name>.py`` reader). Adding any of them adds files and edits none: every
-function here takes the root directory, so the tests load throw-away
-examples from a temporary one."""
+``<name>.py`` reader). A configuration of a family the stock reference
+does not know names its own module (``"reference": "<path>.py"``: the
+plain reference, the seeded checkpoint and, optionally, the cost counts).
+Adding any of them adds files and edits none: every function here takes
+the root directory, so the tests load throw-away examples from a temporary
+one."""
 
 from __future__ import annotations
 
+import ast
+import importlib.util
 import json
 import os
 import re
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Set
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -23,8 +28,46 @@ WIDTH_RE = re.compile(
     r"^head_|expan|experts_per_tok|^n_embd$|^n_inner$)")
 
 
+# What a configuration's module has to define (``tick_cost`` is optional).
+REFERENCE_NEEDS = ("make_weights", "forward")
+
+
 class ManifestError(ValueError):
     pass
+
+
+def load_module(path: str):
+    """A Python file of the benchmark, loaded from its path: a per-layer
+    metric's reader, a configuration's reference."""
+    tag = re.sub(r"\W", "_", os.path.splitext(os.path.basename(path))[0])
+    spec = importlib.util.spec_from_file_location("perfbench_file_" + tag,
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def defined_names(path: str) -> Set[str]:
+    """The names a Python file binds at its top level, read without
+    running it (the benchmark's parent process stays free of JAX)."""
+    try:
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+    except FileNotFoundError:
+        raise ManifestError(f"missing file {path}") from None
+    except SyntaxError as exc:
+        raise ManifestError(f"{path}: {exc}") from None
+    names: Set[str] = set()
+    for node in tree.body:
+        if isinstance(node, ast.FunctionDef):
+            names.add(node.name)
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            names.update((a.asname or a.name).split(".")[0]
+                         for a in node.names)
+        elif isinstance(node, ast.Assign):
+            names.update(t.id for t in node.targets
+                         if isinstance(t, ast.Name))
+    return names
 
 
 def _load_json(path: str) -> dict:
@@ -63,6 +106,12 @@ class Manifest:
         return _load_json(os.path.join(self.root,
                                        self.config_entry(name)["file"]))
 
+    def reference_file(self, config: dict) -> Optional[str]:
+        """The module a configuration's file names, or None where it
+        takes the stock ``harness/reference.py`` and ``roofline.py``."""
+        rel = config.get("reference")
+        return os.path.join(self.root, rel) if rel else None
+
     def traffic(self, name: str) -> dict:
         return _load_json(os.path.join(self.dir, "traffic", name + ".json"))
 
@@ -80,6 +129,49 @@ class Manifest:
                 if "workloads" not in m or workload in m["workloads"]]
 
     # -- validation (the contract's rules that a file can break) -----------
+
+    def _validate_reference(self, entry: dict, body: dict) -> None:
+        rel = body.get("reference")
+        if rel is None:
+            return
+        where = f"config {entry['name']}: reference {rel!r}"
+        if not (isinstance(rel, str) and rel.endswith(".py")
+                and ".." not in rel.split("/") and any(
+                    rel.startswith(p + "/") for p in self.data["paths"])):
+            raise ManifestError(f"{where} is no Python file under paths "
+                                f"{self.data['paths']}")
+        path = self.reference_file(body)
+        if not os.path.isfile(path):
+            raise ManifestError(f"{where} is missing")
+        names = defined_names(path)
+        missing = [n for n in REFERENCE_NEEDS if n not in names]
+        if missing:
+            raise ManifestError(f"{where} does not define "
+                                f"{' or '.join(missing)}")
+
+    def _validate_check_sizes(self, entry: dict, body: dict) -> None:
+        """The check may be smaller than the cell only in what the cell
+        already lists as reduced, never in a width, and never under one
+        whole period of the layer pattern."""
+        chk = body.get("check", {})
+        where = f"config {entry['name']}: check"
+        for key in chk.get("reduced_to", {}):
+            if WIDTH_RE.search(key):
+                raise ManifestError(f"{where}.reduced_to names a width "
+                                    f"{key!r}")
+            if key not in entry["reduced"]:
+                raise ManifestError(
+                    f"{where}.reduced_to names {key!r}, which is not in "
+                    f"the configuration's reduced {entry['reduced']}")
+        args = chk.get("model_args", [])
+        if not (isinstance(args, list)
+                and all(isinstance(a, str) for a in args)):
+            raise ManifestError(f"{where}.model_args is no list of strings")
+        period = body.get("layer_period")
+        if period is not None and int(chk.get("layers", period)) < period:
+            raise ManifestError(
+                f"{where}.layers {chk['layers']} is under one whole period "
+                f"of the layer pattern (layer_period {period})")
 
     def validate(self) -> None:
         d = self.data
@@ -112,6 +204,8 @@ class Manifest:
                 raise ManifestError(
                     f"config {c['name']}: its file lists reduced="
                     f"{body.get('reduced')}, BENCHMARK.json {c['reduced']}")
+            self._validate_reference(c, body)
+            self._validate_check_sizes(c, body)
         cfg_names = [c["name"] for c in d["configs"]]
         if len(set(cfg_names)) != len(cfg_names):
             raise ManifestError("two configs share a name")

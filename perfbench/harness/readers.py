@@ -7,16 +7,17 @@ nothing to read returns None and the metric is left out of the line.
 ``w0``/``w1``, ``counters_before``/``counters_after`` (each server's
 ``metrics`` verb, parsed), ``trace`` (harness/trace.py's summary), ``setup``
 (the parts of set-up, seconds), ``hf`` (the published config), ``config``,
-``traffic`` and ``device``."""
+``reference_file`` (the configuration's own module, where its file names
+one), ``traffic`` and ``device``."""
 
 from __future__ import annotations
 
-import importlib.util
 import re
 import statistics
 from typing import Callable, Dict, Optional
 
 from . import roofline, stats
+from .manifest import defined_names, load_module
 
 
 def parse_prometheus(text: str) -> Dict[str, float]:
@@ -121,10 +122,34 @@ def trace_ms_per_tick(ctx, params):
 
 
 def trace_idle_share(ctx, params):
+    """1 - busy over the stretch the trace RECORDED (first to last device
+    operation), not over the profiler's start-to-stop wall time: a trace
+    that starts late or ends early would read as idle (PERF.md, PR 27)."""
     tr = ctx.get("trace")
-    if not tr or not tr.get("window_s") or not tr.get("busy_s"):
+    if not tr or not tr.get("extent_s") or not tr.get("busy_s"):
         return None
-    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
+    return 100.0 * (1.0 - tr["busy_s"] / tr["extent_s"])
+
+
+def tick_cost_of(ctx: dict) -> Callable:
+    """The count of a tick's bytes and operations: the configuration's own
+    (``tick_cost`` in the module its file names, which also gets ``ctx``
+    for what only a run can count), else the stock one. The module is
+    only run where it defines the function: it imports JAX, the parent
+    of a run otherwise does not."""
+    own = ctx.get("reference_file")
+    if own and "tick_cost" in defined_names(own):
+        fn = load_module(own).tick_cost
+        return lambda hf, **kw: fn(hf, ctx=ctx, **kw)
+    return roofline.tick_cost
+
+
+def served_layers(hf: dict) -> int:
+    """The depth the cell serves: the published depth key, as cut."""
+    for key in ("num_hidden_layers", "n_layer", "n_layers", "num_layers"):
+        if key in hf:
+            return int(hf[key])
+    raise KeyError(f"no depth key among {sorted(hf)}")
 
 
 def step_roofline(ctx, params):
@@ -138,8 +163,8 @@ def step_roofline(ctx, params):
     if tick is None or fill is None or rows is None:
         return None
     hf = ctx["hf"]
-    cost = roofline.tick_cost(
-        hf, layers=roofline.shape_of(hf)["layers"], sessions=fill,
+    cost = tick_cost_of(ctx)(
+        hf, layers=served_layers(hf), sessions=fill,
         kv_rows=rows, weight_bytes=ctx["config"]["weight_bytes"])
     least, bound = roofline.roofline_s(cost, ctx["device"]["kind"])
     ctx.setdefault("notes", {})["step_roofline_bound"] = bound
@@ -196,11 +221,7 @@ def read_metric(manifest, name: str, ctx: dict) -> Optional[float]:
     desc = manifest.layer_metric(name)
     own = manifest.layer_reader_file(name)
     if own:
-        spec = importlib.util.spec_from_file_location(
-            "perfbench_reader_" + re.sub(r"\W", "_", name), own)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        fn = mod.read
+        fn = load_module(own).read
     else:
         fn = STOCK[desc["reader"]]
     value = fn(ctx, desc.get("params", {}))

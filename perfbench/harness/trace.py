@@ -121,7 +121,7 @@ def summarize(device_ops: Dict[str, List[Event]], host: List[Event],
     """device_ops: per device plane, its operation events. Returns busy
     seconds (mean over devices), per-name totals and labelled gaps."""
     per_dev_busy, per_name, counts = [], {}, {}
-    extent, gaps = 0.0, []
+    extent, gaps, recorded = 0.0, [], {}
     for i, (dev, evs) in enumerate(sorted(device_ops.items())):
         iv = [(s, s + d) for _, s, d in evs]
         per_dev_busy.append(union_seconds(iv))
@@ -133,6 +133,12 @@ def summarize(device_ops: Dict[str, List[Event]], host: List[Event],
         extent = max(extent, t_last - t_first)
         if i == 0:          # gaps are labelled on the first device's clock
             gaps = idle_gaps(iv, t_first, t_last)
+            recorded = {"device_events": len(evs), "first_op_s": t_first,
+                        "last_op_s": t_last}
+    if host:                # the same clock: the host tracer's own stretch
+        recorded["host_events"] = len(host)
+        recorded["first_host_s"] = min(s for _, s, _ in host)
+        recorded["last_host_s"] = max(s + d for _, s, d in host)
     n = max(1, len(device_ops))
     window = window_s or extent
     top_ops = sorted(per_name.items(), key=lambda kv: -kv[1])
@@ -142,6 +148,7 @@ def summarize(device_ops: Dict[str, List[Event]], host: List[Event],
         "op_seconds_total": sum(per_name.values()) / n,
         "window_s": window,
         "extent_s": extent,
+        "recorded": recorded,
         "programs": program_stats(modules or {}),
         "ops": {k: {"seconds": v / n, "count": counts[k] / n}
                 for k, v in top_ops[:400]},

@@ -289,6 +289,9 @@ def main() -> int:
                    if load["finished"] else 0.0)
     say("RUN " + json.dumps({
         "workload": args.workload, "seed": args.seed, "trace": args.trace,
+        # how much of the profiler's start-to-stop the device trace holds
+        **({"trace_recorded_share": trace["extent_s"] / trace["window_s"]}
+           if trace else {}),
         "window_s": load["window_s"], "setup_parts_s": setup,
         "gap_samples": load["gap_samples"],
         "gap_p50_ms": load["gap_p50_ms"], "gap_p95_ms": load["gap_p95_ms"],
@@ -304,10 +307,11 @@ def main() -> int:
         "compiles_in_window": res["compiles_in_window"],
         "memory_peak_bytes": device["memory_peak_bytes"],
         "device": {k: device[k] for k in ("platform", "kind", "count")}}))
-    say("CHECK " + json.dumps({k: check[k] for k in (
+    check_line = "CHECK " + json.dumps({k: check[k] for k in (
         "logit_rel_rms", "logit_rel_rms_limit", "burst_gap",
         "burst_gap_limit", "burst_gap_max", "logit_rows", "burst_rounds",
-        "burst_tokens", "finite", "layers", "quant", "pass")}))
+        "burst_tokens", "finite", "layers", "sizes", "quant", "pass")})
+    say(check_line)
     correct = bool(check["pass"] and load["failed"] == 0
                    and early_share <= 0.01
                    and res["compiles_in_window"] == 0
@@ -330,8 +334,9 @@ def main() -> int:
                "counters_after": load_counters(
                    os.path.join(out_dir, "metrics_after.jsonl")),
                "trace": trace, "setup": setup, "hf": res["config"]["hf_config"],
-               "config": res["config"], "traffic": res["traffic"],
-               "device": device}
+               "config": res["config"],
+               "reference_file": man.reference_file(res["config"]),
+               "traffic": res["traffic"], "device": device}
         for m in man.metrics_for(args.workload, "per_layer"):
             try:
                 v = readers.read_metric(man, m["name"], ctx)
@@ -342,11 +347,15 @@ def main() -> int:
                     raise
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        # busy and idle are shares of the stretch the trace recorded
         device["busy_s"] = trace["busy_s"]
-        device["window_s"] = trace["window_s"]
-        say("TRACE " + json.dumps({"notes": ctx.get("notes", {}),
-                                   "devices": trace["devices"],
-                                   "extent_s": trace["extent_s"]}))
+        device["window_s"] = trace["extent_s"]
+        say("TRACE " + json.dumps({
+            "notes": ctx.get("notes", {}), "devices": trace["devices"],
+            "busy_s": trace["busy_s"], "window_s": trace["window_s"],
+            "extent_s": trace["extent_s"], "recorded": trace["recorded"],
+            "profiler": [{k: t[k] for k in ("t_start", "t_stop", "write_s")}
+                         for t in res["traced"]]}))
     if dry:
         metrics = {"cpu_dry_run." + k: v for k, v in metrics.items()}
     line = {"correct": correct, "attempted": load["attempted"],
@@ -356,6 +365,8 @@ def main() -> int:
     if dry:
         line["cpu_dry_run"] = True
     print(json.dumps(line), flush=True)
+    # what was compared beside its limits, as the last of standard error too
+    print(check_line, file=sys.stderr, flush=True)
     return 0
 
 

@@ -161,7 +161,8 @@ def test_stock_readers_on_hand_made_counters():
     ctx = {"counters_before": {"p": readers.parse_prometheus(text_a)},
            "counters_after": {"p": readers.parse_prometheus(text_b)},
            "traffic": {"route": {"burst": 16}},
-           "trace": {"busy_s": 8.0, "window_s": 10.0, "programs": {
+           "trace": {"busy_s": 8.0, "window_s": 12.5, "extent_s": 10.0,
+                     "programs": {
                "jit_fn(1)": {"count": 5, "whole": 3, "mean_s": 0.8},
                "jit_fn(2)": {"count": 9, "whole": 9, "mean_s": 0.02},
                "jit_fn(3)": {"count": 1, "whole": 0, "mean_s": None}}}}
@@ -177,6 +178,7 @@ def test_stock_readers_on_hand_made_counters():
     assert readers.trace_ms_per_tick(per_step, {}) is None
     assert readers.trace_ms_per_tick({"trace": None, "traffic": {
         "route": {}}}, {}) is None
+    # over the stretch the trace recorded, not the profiler's 12.5 s
     assert readers.trace_idle_share(ctx, {}) == pytest.approx(20.0)
     assert readers.trace_idle_share({"trace": None}, {}) is None
     recs = [{"sent": 1.0, "due": None, "error": None, "prompt_len": 8,

@@ -97,6 +97,13 @@ def test_trace_reduction_on_recorded_events():
         assert gaps[label] == pytest.approx(secs)
     idle = 100 * (1 - got["busy_s"] / got["window_s"])
     assert idle == pytest.approx(want["idle_share"])
+    # the stretch the trace recorded, which device_idle_share divides by
+    rec, first = got["recorded"], sorted(ops.items())[0][1]
+    assert rec["device_events"] == len(first)
+    assert rec["first_op_s"] == min(s for _, s, _ in first)
+    assert rec["last_op_s"] - rec["first_op_s"] == pytest.approx(
+        got["extent_s"])
+    assert rec["host_events"] == len(host) and got["extent_s"] <= fx["window_s"]
 
 
 def test_union_and_self_times():
