@@ -5,7 +5,9 @@ A cell is an entry of ``workloads``: it names a configuration
 A per-layer metric is ``layer_metrics/<name>.json`` (+ an optional
 ``<name>.py`` reader). A configuration of a family the stock reference
 does not know names its own module (``"reference": "<path>.py"``: the
-plain reference, the seeded checkpoint and, optionally, the cost counts).
+plain reference, the seeded checkpoint and, optionally, the cost counts),
+and one whose step is not one token a sequence names the file that drives
+its engine in the check (``"check": {"drive": "<path>.py"}``).
 Adding any of them adds files and edits none: every function here takes
 the root directory, so the tests load throw-away examples from a temporary
 one."""
@@ -28,8 +30,10 @@ WIDTH_RE = re.compile(
     r"^head_|expan|experts_per_tok|^n_embd$|^n_inner$)")
 
 
-# What a configuration's module has to define (``tick_cost`` is optional).
+# What a configuration's module has to define (``tick_cost`` is optional),
+# and what the file that drives its engine has to (``rows_needed`` is).
 REFERENCE_NEEDS = ("make_weights", "forward")
+DRIVE_NEEDS = ("drive",)
 
 
 class ManifestError(ValueError):
@@ -130,21 +134,23 @@ class Manifest:
 
     # -- validation (the contract's rules that a file can break) -----------
 
-    def _validate_reference(self, entry: dict, body: dict) -> None:
-        rel = body.get("reference")
+    def _validate_module(self, entry: dict, key: str, rel,
+                         needs: tuple) -> None:
+        """A Python file a configuration names (``key`` says where): under
+        ``paths``, there, and defining what the harness will call."""
         if rel is None:
             return
-        where = f"config {entry['name']}: reference {rel!r}"
+        where = f"config {entry['name']}: {key} {rel!r}"
         if not (isinstance(rel, str) and rel.endswith(".py")
                 and ".." not in rel.split("/") and any(
                     rel.startswith(p + "/") for p in self.data["paths"])):
             raise ManifestError(f"{where} is no Python file under paths "
                                 f"{self.data['paths']}")
-        path = self.reference_file(body)
+        path = os.path.join(self.root, rel)
         if not os.path.isfile(path):
             raise ManifestError(f"{where} is missing")
         names = defined_names(path)
-        missing = [n for n in REFERENCE_NEEDS if n not in names]
+        missing = [n for n in needs if n not in names]
         if missing:
             raise ManifestError(f"{where} does not define "
                                 f"{' or '.join(missing)}")
@@ -204,7 +210,11 @@ class Manifest:
                 raise ManifestError(
                     f"config {c['name']}: its file lists reduced="
                     f"{body.get('reduced')}, BENCHMARK.json {c['reduced']}")
-            self._validate_reference(c, body)
+            self._validate_module(c, "reference", body.get("reference"),
+                                  REFERENCE_NEEDS)
+            self._validate_module(c, "check.drive",
+                                  body.get("check", {}).get("drive"),
+                                  DRIVE_NEEDS)
             self._validate_check_sizes(c, body)
         cfg_names = [c["name"] for c in d["configs"]]
         if len(set(cfg_names)) != len(cfg_names):

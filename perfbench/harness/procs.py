@@ -47,8 +47,12 @@ class Proc:
         self.popen.stdin.flush()
 
     def lines(self, prefix: str) -> List[str]:
+        """The log's COMPLETE lines that start with `prefix`: the child may
+        be half way through writing its last one (a 3 KB answer is not
+        flushed in one piece), and half a JSON object does not parse."""
         with open(self.log, errors="replace") as f:
-            return [l.rstrip("\n") for l in f if l.startswith(prefix)]
+            return [l[:-1] for l in f
+                    if l.endswith("\n") and l.startswith(prefix)]
 
     def wait_line(self, prefix: str, timeout: float, nth: int = 1) -> str:
         """The nth line of the log that starts with `prefix`."""

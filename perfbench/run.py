@@ -310,7 +310,8 @@ def main() -> int:
     check_line = "CHECK " + json.dumps({k: check[k] for k in (
         "logit_rel_rms", "logit_rel_rms_limit", "burst_gap",
         "burst_gap_limit", "burst_gap_max", "logit_rows", "burst_rounds",
-        "burst_tokens", "finite", "layers", "sizes", "quant", "pass")})
+        "burst_tokens", "finite", "layers", "sizes", "quant", "episodes",
+        "drive", "pass")})
     say(check_line)
     correct = bool(check["pass"] and load["failed"] == 0
                    and early_share <= 0.01
@@ -364,6 +365,9 @@ def main() -> int:
         line["breakdown"] = trace["breakdown"]
     if dry:
         line["cpu_dry_run"] = True
+    # each number compared beside its limit, last in the line
+    line["compared"] = {k: [check[k], check[k + "_limit"]]
+                        for k in ("logit_rel_rms", "burst_gap")}
     print(json.dumps(line), flush=True)
     # what was compared beside its limits, as the last of standard error too
     print(check_line, file=sys.stderr, flush=True)

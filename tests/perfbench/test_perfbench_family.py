@@ -79,7 +79,8 @@ def family_root(tmp_path):
             assert f.read() == body, path
 
 
-def run_check(root, config_rel, *extra):
+def check_process(root, config_rel, *extra):
+    """The check of one seed in the root: the process and its CHECK rows."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=root + os.pathsep + ROOT)
     env.pop("XLA_FLAGS", None)
@@ -89,8 +90,12 @@ def run_check(root, config_rel, *extra):
          "--traffic", os.path.join(root, "perfbench/traffic/mini8.json"),
          "--seeds", "11", *extra],
         cwd=root, env=env, capture_output=True, text=True, timeout=600)
-    rows = [json.loads(l[6:]) for l in res.stdout.splitlines()
-            if l.startswith("CHECK ")]
+    return res, [json.loads(l[6:]) for l in res.stdout.splitlines()
+                 if l.startswith("CHECK ")]
+
+
+def run_check(root, config_rel, *extra):
+    res, rows = check_process(root, config_rel, *extra)
     assert rows, res.stderr[-2000:]
     return res.returncode, rows[-1]
 
@@ -143,6 +148,15 @@ REFUSED = {
             reference="perfbench/references/absent.py"),
         "absent.py' is missing"),
     "reference lacks a function": (half_a_module, "does not define forward"),
+    "drive outside paths": (
+        lambda body, root: body["check"].update(drive="scripts/steps4.py"),
+        "check.drive 'scripts/steps4.py' is no Python file under paths"),
+    "drive file is missing": (
+        lambda body, root: body["check"].update(
+            drive="perfbench/drives/absent.py"), "absent.py' is missing"),
+    "drive file defines no drive": (
+        lambda body, root: body["check"].update(drive=PLAIN),
+        "check.drive .* does not define drive"),
     "reduced_to names a key that is not reduced": (
         lambda body, root: body["check"]["reduced_to"].update(vocab_size=1024),
         "not in the configuration's reduced"),
