@@ -106,6 +106,46 @@ class QuantizedTensor:
         return f"QuantizedTensor(shape={tuple(self.q.shape)}, dtype={self.dtype})"
 
 
+class QuantizedLayerView:
+    """One layer of a stacked int8 weight, BY REFERENCE: the whole
+    ``[L, in, out]`` `QuantizedTensor` and a layer index (a Python int or
+    the traced counter of a ``lax.scan``). Reads as that layer's 2-D leaf
+    (`shape`, `dtype`, `dequant`), but the slice is only taken by whoever
+    needs it (`layer`): ops.int8_kernel hands the stack itself to its
+    Pallas call, which starts its DMA at the layer's offset, so the
+    layer's bytes are read once and never copied out first.
+
+    Made inside a scan body (runtime.batching) and consumed there;
+    deliberately NOT a pytree node, so it cannot cross a ``jit`` or
+    ``scan`` boundary as an argument."""
+
+    def __init__(self, stack: QuantizedTensor, index):
+        self.stack = stack
+        self.index = index
+
+    @property
+    def shape(self):
+        return self.stack.q.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+    def layer(self) -> QuantizedTensor:
+        """The 2-D leaf a scan over the stack would have been handed."""
+        q, s = (jax.lax.dynamic_index_in_dim(a, self.index, 0,
+                                             keepdims=False)
+                for a in (self.stack.q, self.stack.s))
+        return QuantizedTensor(q, s, self.stack.dtype)
+
+    def dequant(self) -> jnp.ndarray:
+        return self.layer().dequant()
+
+    def __repr__(self):
+        return (f"QuantizedLayerView(shape={tuple(self.shape)}, "
+                f"dtype={self.dtype})")
+
+
 @jax.tree_util.register_pytree_node_class
 class NF4Tensor:
     """4-bit NormalFloat weight: packed codes + per-block bf16 absmax scales.
@@ -238,7 +278,7 @@ def quantize_params(params: Params, quant: str = "int8") -> Params:
     return out
 
 
-_QUANT_TYPES = (QuantizedTensor, NF4Tensor)
+_QUANT_TYPES = (QuantizedTensor, NF4Tensor, QuantizedLayerView)
 
 
 def nf4_kernel_enabled() -> bool:
@@ -257,8 +297,9 @@ def int8_fold_enabled() -> bool:
     """INT8_FOLD=1 (default ON) keeps per-layer 2-D int8 leaves packed so
     the matmul sites stream the int8 bytes and apply the per-channel
     scale in the matmul EPILOGUE (ops.int8_kernel: ``(x @ q) * s``)
-    instead of materializing a full bf16 weight per layer first — the
-    difference between 0.65 and roofline `frac_of_sustained` on decode.
+    instead of materializing a full bf16 weight per layer first (the
+    int8 read, then a bf16 write and read, where decode is bound by the
+    bytes it moves).
     INT8_FOLD=0 restores the dequant-materialize path (bit-for-bit the
     round-5 behavior) as the kill switch.
 
@@ -279,7 +320,8 @@ def dequant_tree(tree: Params, keep_experts: bool = False) -> Params:
     the matmul sites (`models.transformer._dot`) feed them to the fused
     kernel. With `int8_fold_enabled()` (default), per-layer (2-D) int8
     leaves stay packed the same way and run the scale-folded epilogue
-    (ops.int8_kernel).
+    (ops.int8_kernel); a `QuantizedLayerView` IS such a leaf, passed on
+    as it is or sliced and materialized like one.
 
     `keep_experts=True` (the PER-LAYER MoE call sites: layer_forward and
     the engine scan bodies, where any 3-D quantized leaf IS an [E, in,
@@ -300,10 +342,11 @@ def dequant_tree(tree: Params, keep_experts: bool = False) -> Params:
     def f(x):
         if not isinstance(x, _QUANT_TYPES):
             return x
-        nd = x.q.ndim if isinstance(x, QuantizedTensor) else x.packed.ndim
+        nd = len(x.shape)
         if keep_nf4 and isinstance(x, NF4Tensor) and nd == 2:
             return x
-        if keep_int8 and isinstance(x, QuantizedTensor) and nd == 2:
+        if (keep_int8 and nd == 2
+                and isinstance(x, (QuantizedTensor, QuantizedLayerView))):
             return x
         if keep_experts and nd == 3:
             return x

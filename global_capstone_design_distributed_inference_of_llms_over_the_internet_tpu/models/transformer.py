@@ -167,16 +167,17 @@ def _dot(x: jnp.ndarray, w) -> jnp.ndarray:
     """Weight matmul with quantized dispatch: a packed NF4Tensor leaf
     (left intact by dequant_tree under NF4_KERNEL=1) runs the fused Pallas
     dequant-matmul (ops.nf4_kernel); a packed QuantizedTensor leaf (left
-    intact under INT8_FOLD, the default) runs the scale-folded int8
-    epilogue (ops.int8_kernel); plain arrays take the ordinary matmul.
-    One helper so every projection site dispatches identically."""
-    from .quant import NF4Tensor, QuantizedTensor
+    intact under INT8_FOLD, the default) or a QuantizedLayerView of a
+    stacked one runs the scale-folded int8 epilogue (ops.int8_kernel);
+    plain arrays take the ordinary matmul. One helper so every projection
+    site dispatches identically."""
+    from .quant import NF4Tensor, QuantizedLayerView, QuantizedTensor
 
     if isinstance(w, NF4Tensor):
         from ..ops.nf4_kernel import nf4_dot
 
         return nf4_dot(x, w)
-    if isinstance(w, QuantizedTensor):
+    if isinstance(w, (QuantizedTensor, QuantizedLayerView)):
         from ..ops.int8_kernel import int8_dot
 
         return int8_dot(x, w)

@@ -1,49 +1,70 @@
-"""int8 matmul with the per-channel scale folded into the epilogue (the
-round-7 int8 decode lever).
+"""int8 matmul with the per-channel scale folded into the epilogue.
 
-The round-5 int8 path materialized a full bf16 weight per layer before
-each matmul (``dequant_tree`` -> ``(q * s).astype(bf16)`` -> ``x @ w``):
-HBM sees the int8 read AND the bf16 write+read of the materialized
-weight, which is why int8 decode sat at 0.65 of sustained bandwidth
-while reading half the bytes of bf16. The fix is to never materialize:
+A weight-only int8 leaf is ``q`` int8 ``[K, N]`` and one f32 scale per
+output channel. Decode reads every weight once a token, so the matmul
+is bound by the bytes it moves, and the int8 bytes must be ALL it moves:
 
     y = (x @ q) * s            # q int8 streams straight into the dot,
                                # one f32 multiply per OUTPUT element
 
 which is exact per output channel — scaling a column after the
 K-reduction is algebraically identical to scaling the column's weights
-before it; the only difference from the materialize path is floating-
-point accumulation order (the same contract as ops.nf4_kernel).
+before it; the only difference from dequant-materialize (``x @ (q * s)``,
+``INT8_FOLD=0``) is floating-point accumulation order (the same contract
+as ops.nf4_kernel). Two things would move more bytes than that, and
+both have been measured on the v5e at qwen2-7b widths:
+
+  * materializing a bf16 weight per layer before the matmul (the int8
+    read, then a bf16 write and read): never done here;
+  * handing the kernel ONE layer's slice of a stacked ``[L, K, N]``
+    weight. A Pallas call is a custom call, which needs its operand in
+    a buffer of its own, so XLA writes the slice out first: a
+    ``dynamic-slice`` that produces ``s8[K, N]``, read + write + the
+    kernel's read = three times the traffic. In the served qwen2-7b
+    burst tick those copies took 2.39 s of device time against 1.09 s
+    for the two large kernels they fed, 48% of the tick (ledger, PR 29).
+    So the stacked form below takes the WHOLE stack and the layer
+    index: its DMA starts at the layer's offset, and no ``s8`` tensor
+    is ever produced (PERF.md, PR 30).
 
 Two execution paths, selected per shape:
 
-  * Pallas kernel (TPU decode shapes): streams the int8 tile from HBM,
-    widens to the activation dtype in VMEM (|q| <= 127 is exact in
-    bf16), feeds the MXU, applies the scale row to the f32 accumulator
-    before writeback. Grid = (N tiles, K stripes) of ONE launch with an
-    f32 accumulator across the K axis; `_tiles` picks the stripe so the
-    program's own VMEM estimate fits, and a K that fits whole is one
-    stripe — the same aggregated-launch layout as ops.nf4_kernel.
+  * Pallas kernel (TPU decode and prefill shapes): streams the int8
+    tile from HBM, widens to the activation dtype in VMEM (|q| <= 127
+    is exact in bf16), feeds the MXU, applies the scale row to the f32
+    accumulator before writeback. Grid = (N tiles, K stripes) of ONE
+    launch with an f32 accumulator across the K axis; `_tiles` picks the
+    stripe so the program's own VMEM estimate fits, and a K that fits
+    whole is one stripe — the same aggregated-launch layout as
+    ops.nf4_kernel. Given a `QuantizedLayerView` (a stack and a layer
+    index, made by runtime.batching's layer scans) the layer index is a
+    scalar-prefetch operand and the weight's and scale's block index
+    maps lead with it; body, tiles, grid and order are the 2-D form's,
+    so the result is bit for bit the 2-D kernel's on the slice.
   * XLA mixed-dtype dot (everything else, and all of CPU CI):
     ``lax.dot_general`` takes an int8 rhs with f32 accumulation
     directly, so even the fallback never materializes a scaled weight.
+    A view is sliced first (``dynamic_index_in_dim``): the program a
+    scan over the stack runs.
 
 `int8_dot` is dispatched from models.transformer._dot when
-models.quant.int8_fold_enabled() leaves 2-D QuantizedTensor leaves
-packed (default ON; INT8_FOLD=0 restores dequant-materialize). Token
-parity with the materialize path is pinned by tests/test_int8_kernel.py
-and the serving parity suites.
+models.quant.int8_fold_enabled() leaves 2-D QuantizedTensor leaves (and
+views) packed (default ON; INT8_FOLD=0 restores dequant-materialize).
+Token parity with the materialize path is pinned by
+tests/test_int8_kernel.py, tests/test_burst.py and the serving parity
+suites; `_sites` records per shape which path ran ("pallas stacked ..",
+"pallas ..", "xla"), and a quantized server prints it (`KERNELS`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
-from ..models.quant import QuantizedTensor
+from ..models.quant import QuantizedLayerView, QuantizedTensor
 
 TILE_N = 128
 
@@ -106,7 +127,13 @@ def _tiles(n: int, k: int, m: int, x_bytes: int) -> Optional[Tuple[int, int]]:
 
 @functools.lru_cache(maxsize=64)
 def _make_kernel(m: int, k: int, n: int, out_dtype: str,
-                 interpret: bool = False):
+                 interpret: bool = False, layers: int = 0):
+    """The jitted ``int8_matmul`` of one site. ``layers == 0``: ``(x, q
+    [K, N], s [1, N])``. ``layers == L``: ``(layer int32[1], x, q [L, K,
+    N], s [L, 1, N])`` — the same body, grid and tiles in the same order,
+    with the weight's and the scale's DMA starting at the prefetched
+    layer's offset in the stack: bit for bit the 2-D result on the slice,
+    and the slice is never written out."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -133,63 +160,111 @@ def _make_kernel(m: int, k: int, n: int, out_dtype: str,
             # weight.
             out_ref[...] = (acc_ref[...] * s_ref[...]).astype(out_ref.dtype)
 
+    call = dict(
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="int8_matmul",
+    )
+    grid = (n // tn, k // tk)
+    scratch = [pltpu.VMEM((m, tn), jnp.float32)]
+
+    if layers:
+        # Index maps take the prefetched scalar after the grid indices; the
+        # squeezed leading block dimension hands the body its 2-D tiles.
+        def stacked_kernel(layer_ref, *refs):
+            kernel(*refs)
+
+        @jax.jit
+        def int8_matmul(layer, x, q, s):
+            return pl.pallas_call(
+                stacked_kernel,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1,
+                    grid=grid,
+                    in_specs=[
+                        pl.BlockSpec((m, tk), lambda j, kk, l: (0, kk)),
+                        pl.BlockSpec((None, tk, tn),
+                                     lambda j, kk, l: (l[0], kk, j)),
+                        pl.BlockSpec((None, 1, tn),
+                                     lambda j, kk, l: (l[0], 0, j)),
+                    ],
+                    out_specs=pl.BlockSpec((m, tn), lambda j, kk, l: (0, j)),
+                    scratch_shapes=scratch),
+                **call,
+            )(layer, x, q, s)
+
+        return int8_matmul
+
     @jax.jit
     def int8_matmul(x, q, s):
         return pl.pallas_call(
             kernel,
-            out_shape=jax.ShapeDtypeStruct((m, n), jnp.dtype(out_dtype)),
-            grid=(n // tn, k // tk),
+            grid=grid,
             in_specs=[
                 pl.BlockSpec((m, tk), lambda j, kk: (0, kk)),
                 pl.BlockSpec((tk, tn), lambda j, kk: (kk, j)),
                 pl.BlockSpec((1, tn), lambda j, kk: (0, j)),
             ],
             out_specs=pl.BlockSpec((m, tn), lambda j, kk: (0, j)),
-            scratch_shapes=[pltpu.VMEM((m, tn), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"),
-                vmem_limit_bytes=VMEM_LIMIT),
-            interpret=interpret,
-            name="int8_matmul",
+            scratch_shapes=scratch,
+            **call,
         )(x, q, s)
 
     return int8_matmul
 
 
-def _supported(m: int, w: QuantizedTensor, x_bytes: int) -> bool:
-    k, n = w.q.shape[-2], w.q.shape[-1]
+def _supported(m: int, k: int, n: int, x_bytes: int) -> bool:
     assert m % 8 == 0, "caller pads rows to a multiple of 8"
-    return (w.q.ndim == 2                 # one layer's weight, not a stack
-            and k % 128 == 0              # x lane dim / q sublane tiling
+    return (k % 128 == 0                  # x lane dim / q sublane tiling
             and n % TILE_N == 0
             and (jax.default_backend() == "tpu" or _INTERPRET)
             and _tiles(n, k, m, x_bytes) is not None)
 
 
-def int8_dot(x: jnp.ndarray, w: QuantizedTensor) -> jnp.ndarray:
+def int8_dot(x: jnp.ndarray,
+             w: Union[QuantizedTensor, QuantizedLayerView]) -> jnp.ndarray:
     """x [..., K] @ int8 weight [K, N] (scale folded into the epilogue)
-    -> [..., N] in x.dtype.
+    -> [..., N] in x.dtype. ``w`` is one layer's 2-D leaf, or a
+    `QuantizedLayerView` of an ``[L, K, N]`` stack.
 
-    Pallas kernel when the shape qualifies (see `_supported`); XLA
-    mixed-dtype dot_general otherwise — BOTH stream the int8 bytes and
-    scale the accumulator, so enabling the fold never changes which
-    shapes serve and never materializes a scaled weight."""
+    Pallas kernel when the shape qualifies (see `_supported`): on the leaf
+    itself, or on the view's WHOLE stack with the layer index prefetched,
+    so that no copy of the layer's weight is made for the call. XLA
+    mixed-dtype dot_general otherwise (a view is sliced first: the
+    program a scan over the stack would have run) — BOTH stream the int8
+    bytes and scale the accumulator, so enabling the fold never changes
+    which shapes serve and never materializes a scaled weight."""
     global _launches
     lead = x.shape[:-1]
     k = x.shape[-1]
     x2 = x.reshape(-1, k)
     m = x2.shape[0]
     m_pad = -(-max(m, 8) // 8) * 8
-    n = w.q.shape[-1]
-    if _supported(m_pad, w, x.dtype.itemsize):
+    n = w.shape[-1]
+    view = isinstance(w, QuantizedLayerView)
+    if ((view or w.q.ndim == 2)           # one layer's weight, not a stack
+            and _supported(m_pad, k, n, x.dtype.itemsize)):
         _launches += 1
-        _sites[(m_pad, k, n)] = "pallas tn=%d,tk=%d" % _tiles(
-            n, k, m_pad, x.dtype.itemsize)
+        tn, tk = _tiles(n, k, m_pad, x.dtype.itemsize)
+        _sites[(m_pad, k, n)] = (
+            f"pallas {'stacked ' if view else ''}tn={tn},tk={tk}")
         if m_pad != m:
             x2 = jnp.pad(x2, ((0, m_pad - m), (0, 0)))
-        fn = _make_kernel(m_pad, k, n, str(x.dtype), interpret=_INTERPRET)
-        out = fn(x2, w.q, w.s.astype(jnp.float32))
+        if view:
+            layer = jnp.asarray(w.index, jnp.int32).reshape(1)
+            w = w.stack
+            args, layers = (layer, x2, w.q), w.q.shape[0]
+        else:
+            args, layers = (x2, w.q), 0
+        fn = _make_kernel(m_pad, k, n, str(x.dtype), interpret=_INTERPRET,
+                          layers=layers)
+        out = fn(*args, w.s.astype(jnp.float32))
         return out[:m].reshape(*lead, -1)
+    if view:
+        w = w.layer()
     _sites[(m_pad, k, n)] = "xla"
     acc = jax.lax.dot_general(
         x2, w.q, (((1,), (0,)), ((), ())),

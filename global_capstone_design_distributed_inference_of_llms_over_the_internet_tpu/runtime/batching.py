@@ -144,6 +144,70 @@ def _residual(cfg, lp, h, attn_out):
         return h + mlp_out
 
 
+def _split_stacks(layers):
+    """``(xs, held)`` of a stacked layer tree. ``held`` maps the key path of
+    every dense int8 weight stack (a 3-D `QuantizedTensor`, ``[L, K, N]``)
+    to the leaf: those stay WHOLE, and a layer scan's body reads them
+    through `_layer_at`. ``xs`` is the tree without them, for ``lax.scan``
+    to slice: norms, biases, ``window``, every unquantised leaf, MoE expert
+    stacks (4-D), NF4 leaves. As scan ``xs`` an int8 stack reaches the
+    body as a ``dynamic-slice``, and the Pallas call (a custom call, which
+    needs its operand in a buffer of its own) makes XLA write that slice
+    out first: 2.2 x the kernels' own time at qwen2-7b widths (ledger, PR
+    29). A tree with no such leaf comes back as the same object."""
+    from ..models.quant import QuantizedTensor
+
+    held = {}
+
+    def strip(tree, path):
+        if not isinstance(tree, dict):
+            return tree
+        out = {}
+        for key, sub in tree.items():
+            if isinstance(sub, QuantizedTensor) and sub.q.ndim == 3:
+                held[path + (key,)] = sub
+            else:
+                out[key] = strip(sub, path + (key,))
+        return out
+
+    xs = strip(layers, ())
+    return (xs, held) if held else (layers, held)
+
+
+def _layer_at(lp, held, i):
+    """Layer ``i``'s parameters: the scan's slice ``lp`` of `_split_stacks`'s
+    ``xs`` with a `QuantizedLayerView` at index ``i`` in place of each held
+    stack. ``lp`` itself when nothing is held."""
+    from ..models.quant import QuantizedLayerView
+
+    def put(tree, path, leaf):
+        if not path:
+            return leaf
+        return {**tree, path[0]: put(tree.get(path[0], {}), path[1:], leaf)}
+
+    for path, stack in held.items():
+        lp = put(lp, path, QuantizedLayerView(stack, i))
+    return lp
+
+
+def _scan_layers(layer, h, layers, *xs):
+    """``lax.scan(layer, h, (layers, *xs))`` for the prefill programs, with
+    ``layer`` handed ``(lp, *xs_l)`` and the int8 stacks of ``layers`` held
+    whole (`_split_stacks`): the scan then carries the layer index in its
+    ``xs``. A tree with nothing to hold is scanned as it is."""
+    rest, held = _split_stacks(layers)
+    if not held:
+        return jax.lax.scan(layer, h, (layers, *xs))
+
+    def body(h, xs):
+        i, lp, *more = xs
+        return layer(h, (_layer_at(lp, held, i), *more))
+
+    n = next(iter(held.values())).shape[0]
+    return jax.lax.scan(
+        body, h, (jnp.arange(n, dtype=jnp.int32), rest, *xs))
+
+
 def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     """``lax.scan`` over the span's layers with the cache stacks as CARRY:
     each layer reads and writes its own ``[S, max_len, Hkv, Dh]`` slice by
@@ -156,9 +220,12 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     rows each copy is 3 GB and the burst program asked for 17.3 GB of a
     v5e's 15.75 (chip run, PR 21)."""
 
+    rest, held = _split_stacks(layers)
+
     def body(carry, xs):
         h, k_all, v_all = carry
         lp, i = xs
+        lp = _layer_at(lp, held, i)
         with jax.named_scope("kv_update"):
             k_l = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
             v_l = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
@@ -170,7 +237,7 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
 
     (h, k_all, v_all), _ = jax.lax.scan(
         body, (h, k_all, v_all),
-        (layers, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
+        (rest, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
     return h, k_all, v_all
 
 
@@ -300,9 +367,10 @@ class BatchedStageExecutor:
                 # (i - window, i].
                 mask &= cols > rows - cfg.sliding_window
 
-            def layer(h, lp):
+            def layer(h, xs):
                 from ..models.quant import dequant_tree
 
+                (lp,) = xs
                 lp = dequant_tree(lp, keep_experts=cfg.is_moe)
                 with jax.named_scope("attention"):
                     a = _norm(cfg, lp["ln1"], h)
@@ -328,7 +396,7 @@ class BatchedStageExecutor:
                 h = _residual(cfg, lp, h, out)
                 return h, (k[0], v[0])
 
-            h, (ks, vs) = jax.lax.scan(layer, h, params["layers"])
+            h, (ks, vs) = _scan_layers(layer, h, params["layers"])
             # ks/vs: [L, T, Hkv, Dh] -> write rows [slot, 0:T).
             with jax.named_scope("kv_update"):
                 k_all = jax.lax.dynamic_update_slice(
@@ -406,8 +474,8 @@ class BatchedStageExecutor:
                 h = _residual(cfg, lp, h, out)
                 return h, (k_l, v_l)
 
-            h, (ks, vs) = jax.lax.scan(
-                layer, h, (params["layers"], k_slot, v_slot))
+            h, (ks, vs) = _scan_layers(
+                layer, h, params["layers"], k_slot, v_slot)
             with jax.named_scope("kv_update"):
                 k_all = jax.lax.dynamic_update_slice(
                     k_all, ks[:, None], (0, slot, 0, 0, 0))

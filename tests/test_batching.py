@@ -28,7 +28,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     SlotFull,
 )
 
-from test_runtime_pipeline import tiny_cfg
+from test_runtime_pipeline import kernel_cfg, tiny_cfg
 
 # Quarantine-with-teeth (tests/conftest.py pytest_runtest_protocol): the
 # DETERMINISTIC single-threaded token-parity tests below carry
@@ -802,3 +802,135 @@ def test_batched_gemma2_with_prefix_cache():
     warm = gen("warm")
     assert ex.prefix_store.stats()["grains_reused"] == 4
     assert cold == warm
+
+
+# ---------------------------------------------------------------------------
+# int8 layer stacks reach the Pallas kernel WHOLE (runtime.batching
+# _split_stacks / _layer_at): no program slices a layer's int8 weight out
+# of its stack for the call.
+# ---------------------------------------------------------------------------
+
+
+def _stacked_tree(kind):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+        mixtral_config,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
+        quantize_params,
+    )
+
+    cfg = (mixtral_config(
+        vocab_size=128, hidden_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=2, intermediate_size=96, num_experts=4,
+        num_experts_per_tok=2, max_position_embeddings=32)
+        if kind == "int8-moe" else kernel_cfg())
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    return quantize_params(params, kind.split("-")[0])["layers"]
+
+
+@pytest.mark.parametrize("kind", ["none", "nf4", "int8", "int8-moe"])
+def test_split_stacks_holds_dense_int8_stacks_only(kind):
+    """`_split_stacks` takes the dense [L, K, N] int8 stacks out of what
+    lax.scan slices, and nothing else: a bf16 or an NF4 tree comes back
+    as the SAME object with nothing held; MoE expert stacks ([L, E, K,
+    N]) stay in xs. `_layer_at` puts a view of layer i where each held
+    stack was, so the body sees the tree's own structure."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
+        QuantizedLayerView,
+        QuantizedTensor,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
+        _layer_at,
+        _split_stacks,
+    )
+
+    layers = _stacked_tree(kind)
+    xs, held = _split_stacks(layers)
+    if kind in ("none", "nf4"):
+        assert xs is layers and held == {}
+        lp = jax.tree.map(lambda a: a[1], layers)
+        assert _layer_at(lp, held, 1) is lp
+        return
+    dense = {("attn", k) for k in ("wq", "wk", "wv", "wo")}
+    if kind == "int8":
+        dense |= {("mlp", k) for k in ("wg", "wu", "wd")}
+    assert set(held) == dense
+    assert all(w.q.ndim == 3 for w in held.values())
+    is_q = lambda v: isinstance(v, QuantizedTensor)          # noqa: E731
+    left = [v for v in jax.tree.leaves(xs, is_leaf=is_q) if is_q(v)]
+    assert all(v.q.ndim == 4 for v in left)                  # expert stacks
+    assert len(left) == (3 if kind == "int8-moe" else 0)
+    lp = _layer_at(jax.tree.map(lambda a: a[1], xs), held, 1)
+    assert jax.tree.structure(
+        jax.tree.map(lambda a: 0, lp, is_leaf=lambda v: isinstance(
+            v, (QuantizedTensor, QuantizedLayerView)))
+    ) == jax.tree.structure(jax.tree.map(lambda a: 0, layers, is_leaf=is_q))
+    for path, stack in held.items():
+        view = lp[path[0]][path[1]]
+        assert isinstance(view, QuantizedLayerView)
+        assert view.stack is stack and view.index == 1
+        np.testing.assert_array_equal(np.asarray(view.layer().q),
+                                      np.asarray(stack.q[1]))
+
+
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it (scan,
+    cond, pjit bodies), except the bodies of Pallas kernels."""
+    for e in jaxpr.eqns:
+        yield e
+        if e.primitive.name == "pallas_call":
+            continue
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)
+
+
+@pytest.mark.parametrize(
+    "program", ["burst_tick", "decode_step", "prefill", "prefill_suffix"])
+def test_int8_programs_hand_the_kernel_the_whole_stack(monkeypatch, program):
+    """In each device program of an int8 llama-shaped engine, every
+    pallas_call takes a rank-3 int8 operand (the layer stack itself) and
+    NO equation (dynamic_slice, dynamic_index, gather, anything) yields
+    an int8 array of rank 2 or more: nothing is there for XLA to write
+    out as a staging copy before the custom call."""
+    import global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.int8_kernel as IK
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
+        quantize_params,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
+        RECENT_WINDOW,
+    )
+
+    monkeypatch.setattr(IK, "_INTERPRET", True)
+    cfg = kernel_cfg()
+    qp = quantize_params(init_params(jax.random.PRNGKey(0), cfg), "int8")
+    S = 2
+    ex = BatchedStageExecutor(cfg, full_spec(cfg), qp, slots=S, max_len=16)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)         # noqa: E731
+    f32 = lambda *shape: jnp.ones(shape, jnp.float32)        # noqa: E731
+    on = jnp.ones((S,), bool)
+    fn, args = {
+        "burst_tick": (ex._build_burst(2), (
+            ex.params, i32(S), i32(S), on, i32(S), i32(S, RECENT_WINDOW),
+            i32(S), i32(S), i32(S) + 2, i32(S) - 1, f32(S), f32(S), i32(S),
+            f32(S), ex.k, ex.v)),
+        "decode_step": (ex._build_decode(1), (
+            ex.params, i32(S, 1), i32(S), on, ex.k, ex.v)),
+        "prefill": (ex._build_prefill(), (
+            ex.params, i32(1, 8), 0, ex.k, ex.v, 5)),
+        "prefill_suffix": (ex._build_prefill_suffix(), (
+            ex.params, i32(1, 8), 0, ex.k, ex.v, 4, 3)),
+    }[program]
+    eqns = list(_all_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 4                        # wqkv, wo, wgu, wd
+    for e in calls:
+        int8_in = [v.aval for v in e.invars if v.aval.dtype == jnp.int8]
+        assert [a.ndim for a in int8_in] == [3], int8_in
+        assert int8_in[0].shape[0] == cfg.num_layers
+    made = [(e.primitive.name, v.aval) for e in eqns for v in e.outvars
+            if getattr(v.aval, "dtype", None) == jnp.int8
+            and v.aval.ndim >= 2]
+    assert not made, made

@@ -41,7 +41,12 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     DeficitRoundRobin,
 )
 
-from test_runtime_pipeline import build_cluster, oracle_generate, tiny_cfg
+from test_runtime_pipeline import (
+    build_cluster,
+    kernel_cfg,
+    oracle_generate,
+    tiny_cfg,
+)
 
 GREEDY = SamplingParams(temperature=0.0)
 SAMPLED = SamplingParams(temperature=0.9, top_p=0.95, top_k=50,
@@ -112,10 +117,12 @@ def _sequential(cfg, params, prompts, sp, seed, max_new, eos=None):
     return out
 
 
-def _bursty(cfg, params, prompts, sp, seed, max_new, n_ticks, eos=None):
+def _bursty(cfg, params, prompts, sp, seed, max_new, n_ticks, eos=None,
+            stops=None):
     """decode_burst driver: re-ships the stateless per-burst protocol
     (sampling params + recent window + seed) each burst, like the wire
-    client does."""
+    client does. ``stops``: a dict that collects each session's stop
+    reason, burst by burst."""
     ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
                               max_len=64)
     gen = {}
@@ -145,6 +152,8 @@ def _bursty(cfg, params, prompts, sp, seed, max_new, n_ticks, eos=None):
         res = ex.decode_burst(entries, n_ticks)
         for sid, r in res.items():
             gen[sid].extend(r["tokens"])
+            if stops is not None:
+                stops.setdefault(sid, []).append(r["stop"])
             if r["stop"] is not None:
                 live.discard(sid)
     return gen, ex
@@ -510,37 +519,41 @@ def test_burst_engine_quantized_matches_dequantized(cfg, params, mode):
         assert got[sid] == ref[sid], (mode, sid, got[sid], ref[sid])
 
 
-@pytest.mark.parity
-def test_nf4_kernel_launch_count_guard(monkeypatch):
-    """Launch aggregation pinned: with NF4_KERNEL=1 on a kernel-eligible
-    shape, ONE N-tick burst traces at most FOUR pallas_call sites (wqkv,
-    wo, wgu, wd — the engine-fused layout; lax.scan shares them across
-    layers and ticks), and an already-compiled burst dispatches ZERO new
-    launches. This is the structural floor: attention and norms sit
-    between the matmuls, so per-layer sites cannot merge further."""
-    import global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.nf4_kernel as NK
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-        init_params,
-        llama_config,
-    )
+def _kernel_params(kcfg, mode):
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
         quantize_params,
     )
 
-    monkeypatch.setattr(NK, "_INTERPRET", True)
+    return quantize_params(init_params(jax.random.PRNGKey(0), kcfg), mode)
+
+
+@pytest.mark.parity
+@pytest.mark.parametrize("mode", ["nf4", "int8"])
+def test_kernel_launch_count_guard(monkeypatch, mode):
+    """Launch aggregation pinned: on a kernel-eligible shape (NF4 under
+    NF4_KERNEL=1; int8 by default, its layer stacks reaching the kernel
+    whole), ONE N-tick burst traces at most FOUR pallas_call sites (wqkv,
+    wo, wgu, wd — the engine-fused layout; lax.scan shares them across
+    layers and ticks), and an already-compiled burst dispatches ZERO new
+    launches. This is the structural floor: attention and norms sit
+    between the matmuls, so per-layer sites cannot merge further."""
+    import global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.int8_kernel as IK
+    import global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.nf4_kernel as NK
+
+    K = {"nf4": NK, "int8": IK}[mode]
+    monkeypatch.setattr(K, "_INTERPRET", True)
+    monkeypatch.setattr(K, "_sites", {})
     monkeypatch.setenv("NF4_KERNEL", "1")
-    kcfg = llama_config(vocab_size=128, hidden_size=128, num_layers=2,
-                        num_heads=4, num_kv_heads=2, intermediate_size=256,
-                        max_position_embeddings=32)
-    qp = quantize_params(init_params(jax.random.PRNGKey(0), kcfg), "nf4")
-    ex = BatchedStageExecutor(kcfg, _full_spec(kcfg), qp, slots=2,
+    kcfg = kernel_cfg()
+    ex = BatchedStageExecutor(kcfg, _full_spec(kcfg),
+                              _kernel_params(kcfg, mode), slots=2,
                               max_len=16)
     # The fused layout is what makes 4 the bound (7 canonical sites).
     assert "wqkv" in ex.params["layers"]["attn"]
     assert "wgu" in ex.params["layers"]["mlp"]
     h = ex.prefill("s", np.asarray([[3, 5, 7]], np.int32))
     tok = int(jnp.argmax(ex.logits(h[:, -1:])[0, -1]))
-    monkeypatch.setattr(NK, "_launches", 0)
+    monkeypatch.setattr(K, "_launches", 0)
 
     def burst(t):
         return ex.decode_burst({"s": {
@@ -549,7 +562,44 @@ def test_nf4_kernel_launch_count_guard(monkeypatch):
             "top_k": 0, "repetition_penalty": 1.0}}, 2)
 
     res = burst(tok)
-    assert NK._launches <= 4, NK._launches   # one trace, four sites
-    first = NK._launches
+    assert K._launches <= 4, K._launches     # one trace, four sites
+    first = K._launches
     burst(int(res["s"]["tokens"][-1]))
-    assert NK._launches == first             # cached program: zero new
+    assert K._launches == first              # cached program: zero new
+    if mode == "int8":
+        assert len(K._sites) == 4 and all(
+            w.startswith("pallas stacked") for w in K._sites.values())
+
+
+@pytest.mark.parity
+@pytest.mark.parametrize("sp", [GREEDY, SAMPLED], ids=["greedy", "sampled"])
+def test_int8_stacked_kernel_burst_matches_materialize(monkeypatch, sp):
+    """Served bursts of the batched int8 engine with every layer stack
+    read by the (interpreted) Pallas kernel in place give the tokens AND
+    stop reasons of INT8_FOLD=0 (dequant-materialize per layer), greedy
+    and seeded-sampled; all four sites report the stacked kernel."""
+    import global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.int8_kernel as IK
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
+        quant_kernel_report,
+    )
+
+    monkeypatch.setattr(IK, "_INTERPRET", True)
+    monkeypatch.setattr(IK, "_sites", {})
+    kcfg = kernel_cfg()
+    qp = _kernel_params(kcfg, "int8")
+
+    def serve(fold):
+        monkeypatch.setenv("INT8_FOLD", fold)
+        stops = {}
+        gen, _ = _bursty(kcfg, qp, PROMPTS, sp, seed=3, max_new=10,
+                         n_ticks=4, stops=stops)
+        return gen, stops
+
+    got = serve("1")
+    sites = quant_kernel_report()["int8"]["sites"]
+    assert {s.split("x", 1)[1] for s in sites} == {
+        "128x256", "128x128", "128x512", "256x128"}   # wqkv wo wgu wd
+    assert all(w.startswith("pallas stacked") for w in sites.values()), sites
+    monkeypatch.setattr(IK, "_sites", {})
+    assert serve("0") == got
+    assert not IK._sites                  # materialized: no int8_dot ran

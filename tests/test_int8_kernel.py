@@ -17,6 +17,7 @@ import pytest
 import global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.int8_kernel as IK
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
     NF4Tensor,
+    QuantizedLayerView,
     QuantizedTensor,
     _quantize_leaf,
     _quantize_leaf_nf4,
@@ -58,6 +59,93 @@ def test_kernel_pads_rows_and_restores_shape(interpret_kernel):
     want = x @ q.dequant().astype(x.dtype)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
+
+
+def _stack(rng, layers, k, n):
+    w = rng.standard_normal((layers, k, n)).astype(np.float32) * 0.02
+    return _quantize_leaf(jnp.asarray(w))
+
+
+def _slice_of(stack, i):
+    return QuantizedTensor(stack.q[i], stack.s[i], stack.dtype)
+
+
+def _views_dot(x, stack, how):
+    """int8_dot on a view of every layer: [L, m, N]. The index is a Python
+    int, or the traced counter of a lax.scan (the engines' case)."""
+    layers = stack.q.shape[0]
+    if how == "int":
+        return jnp.stack([IK.int8_dot(x, QuantizedLayerView(stack, i))
+                          for i in range(layers)])
+    return jax.jit(lambda x, stack: jax.lax.scan(
+        lambda c, i: (c, IK.int8_dot(x, QuantizedLayerView(stack, i))),
+        0, jnp.arange(layers, dtype=jnp.int32))[1])(x, stack)
+
+
+@pytest.mark.parametrize("how", ["int", "scan"])
+@pytest.mark.parametrize("stripes", [1, 4])
+@pytest.mark.parametrize("m", [8, 16])
+def test_stacked_kernel_is_the_2d_kernel_on_the_slice(
+        interpret_kernel, monkeypatch, m, stripes, how):
+    """The stacked form (layer index prefetched, DMA from the [L, K, N]
+    stack) gives BIT FOR BIT what the 2-D kernel gives on that layer's
+    slice, at every layer index: the same tiles in the same order."""
+    k, n = 512, 256
+    if stripes > 1:
+        # Small enough that a 128-row stripe is all that fits: K runs in
+        # several grid steps through the f32 accumulator.
+        monkeypatch.setattr(IK, "VMEM_BUDGET", 300_000)
+    IK._make_kernel.cache_clear()
+    assert k // IK._tiles(n, k, m, 4)[1] == stripes
+    monkeypatch.setattr(IK, "_sites", {})
+    rng = np.random.default_rng(10 * m + stripes)
+    stack = _stack(rng, 3, k, n)
+    x = jnp.asarray(rng.standard_normal((m, k)).astype(np.float32))
+    try:
+        got = np.asarray(_views_dot(x, stack, how))
+        assert IK._sites[(m, k, n)].startswith("pallas stacked tn=")
+        for i in range(3):
+            want = IK.int8_dot(x, _slice_of(stack, i))
+            np.testing.assert_array_equal(got[i], np.asarray(want))
+        assert IK._sites[(m, k, n)].startswith("pallas tn=")
+    finally:
+        IK._make_kernel.cache_clear()     # tiles planned at a test budget
+
+
+@pytest.mark.parametrize("how", ["int", "scan"])
+@pytest.mark.parametrize("shape", [(256, 384), (100, 96)],
+                         ids=["aligned", "odd"])
+def test_view_fallback_is_int8_dot_on_the_sliced_leaf(monkeypatch, shape,
+                                                      how):
+    """No Pallas (plain CPU, or a shape the kernel does not cover): a
+    view is sliced where it is used and takes the XLA fold, bit for bit
+    what int8_dot gives on the leaf a scan over the stack hands out."""
+    k, n = shape
+    monkeypatch.setattr(IK, "_sites", {})
+    rng = np.random.default_rng(k)
+    stack = _stack(rng, 3, k, n)
+    x = jnp.asarray(rng.standard_normal((2, 3, k)).astype(np.float32))
+    got = np.asarray(_views_dot(x, stack, how))
+    assert IK._sites == {(8, k, n): "xla"}
+    for i in range(3):
+        want = IK.int8_dot(x, _slice_of(stack, i))
+        np.testing.assert_array_equal(got[i], np.asarray(want))
+
+
+@pytest.mark.parametrize("fold", ["1", "0"])
+def test_dequant_tree_treats_a_view_as_the_2d_leaf(monkeypatch, fold):
+    """INT8_FOLD=1 passes a view on as it is (as a 2-D leaf); INT8_FOLD=0
+    materializes the layer: the values of the sliced leaf's dequant."""
+    monkeypatch.setenv("INT8_FOLD", fold)
+    stack = _stack(np.random.default_rng(5), 3, 64, 32)
+    view = QuantizedLayerView(stack, 1)
+    assert view.shape == (64, 32) and view.dtype == stack.dtype
+    out = dequant_tree({"attn": {"wo": view}})["attn"]["wo"]
+    if fold == "1":
+        assert out is view
+    else:
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(_slice_of(stack, 1).dequant()))
 
 
 def test_xla_fallback_never_materializes_and_is_close():

@@ -45,6 +45,14 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 )
 
 
+def kernel_cfg():
+    """Llama-shaped, every matmul site eligible for the Pallas kernels (K
+    and N multiples of 128 in the engine-fused layout)."""
+    return llama_config(vocab_size=128, hidden_size=128, num_layers=2,
+                        num_heads=4, num_kv_heads=2, intermediate_size=256,
+                        max_position_embeddings=32)
+
+
 def tiny_cfg(family="llama"):
     if family == "gpt2":
         return gpt2_config(vocab_size=257, hidden_size=64, num_layers=8,
