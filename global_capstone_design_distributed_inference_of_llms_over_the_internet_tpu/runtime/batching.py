@@ -101,7 +101,7 @@ class SlotFull(RuntimeError):
     """No free slot (admission control — the caller queues or fails over)."""
 
 
-# -- gemma2-aware layer pieces shared by the three batched bodies ----------
+# -- the decoder layer of the four engine programs --------------------------
 
 def _qscale(cfg) -> float:
     """Attention score scale (gemma2 query_pre_attn_scalar override)."""
@@ -142,6 +142,45 @@ def _residual(cfg, lp, h, attn_out):
         if cfg.post_norms:
             mlp_out = _norm(cfg, lp["ln4"], mlp_out)
         return h + mlp_out
+
+
+def _decoder_layer(cfg, lp, h, rope, cache_policy):
+    """One decoder layer of every engine program: ``(h, state)``.
+
+    ``cache_policy(k, v)`` is all a program supplies. Given the layer's
+    fresh keys and values (``[B, T, Hkv, Dh]``, rotated) it returns
+    ``(keys, values, (mask, q_pos, k_pos), state)``: what to attend over
+    (``[B, S, Hkv, Dh]``), the allowed grid (``[T, S]`` for one sequence or
+    per row ``[B, T, S]``) with the position grids `_layer_mask` windows it
+    by, and what the layer scan carries or stacks. Cache writes are the
+    policy's, under its own ``kv_update`` scope."""
+    from ..models.quant import dequant_tree
+
+    lp = dequant_tree(lp, keep_experts=cfg.is_moe)
+    with jax.named_scope("attention"):
+        a = _norm(cfg, lp["ln1"], h)
+        q, k, v = qkv_proj(cfg, lp["attn"], a)          # [B, T, H/Hkv, Dh]
+        if rope is not None:
+            q = apply_rope(q, *rope)
+            k = apply_rope(k, *rope)
+    keys, values, (mask, q_pos, k_pos), state = cache_policy(k, v)
+    with jax.named_scope("attention"):
+        b, t = h.shape[:2]
+        groups = cfg.num_heads // cfg.num_kv_heads
+        qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
+        scores = jnp.einsum(
+            "bthgd,bshd->bhgts", qg * _qscale(cfg), keys.astype(q.dtype),
+            preferred_element_type=jnp.float32)          # [B, Hkv, G, T, S]
+        m = _layer_mask(lp, mask, q_pos, k_pos)
+        m = m[:, None, None] if m.ndim == 3 else m[None, None, None]
+        scores = _softcap_and_mask(cfg, scores, m)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhgts,bshd->bthgd", probs.astype(values.dtype),
+                         values.astype(q.dtype))
+        out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
+        if "bo" in lp["attn"]:
+            out = out + lp["attn"]["bo"]
+    return _residual(cfg, lp, h, out), state
 
 
 def _split_stacks(layers):
@@ -239,6 +278,59 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
         body, (h, k_all, v_all),
         (rest, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
     return h, k_all, v_all
+
+
+def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
+                 k_all, v_all):
+    """The span's layers over ``T`` new tokens a slot: ``(h, k_all, v_all)``.
+    The body of the decode step (T = 1 plain, T = K+1 speculative verify:
+    the draft block enters as new tokens, causal within itself) and, at
+    T = 1, of every burst tick. ``x``: ids ``[S, T]`` or hidden ``[S, T, D]``
+    at ``positions`` (``lengths[:, None]`` + the offset in the block);
+    ``pos_grid``: ``arange(max_len)``, the caller's so that a burst builds
+    it once for all its ticks."""
+    T = positions.shape[1]
+    with jax.named_scope("embed"):
+        h = (embed_tokens(cfg, params["embed"], x, positions)
+             if spec.is_first else x)
+        rope = make_rope(cfg, positions)
+    # allowed[s, tq, m]: key position m visible to query token tq of slot s
+    # — everything up to and including the query's own position (causal
+    # within the new block too).
+    qpos = positions[:, :, None]                            # [S, T, 1]
+    allowed = pos_grid[None, None, :] <= qpos               # [S, T, M]
+    if cfg.sliding_window:
+        # Window spans (qpos - window, qpos].
+        allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
+    # Per-slot cache write of T rows at each slot's own length (vmap'd
+    # dynamic_update_slice). Inactive slots write their OWN current rows
+    # back: a slot parked near max_len would clamp its start and clobber
+    # that session's last real KV rows, so the write value for inactive
+    # slots is the rows already there (read and write clamp to the SAME
+    # start, so the round trip is a no-op — cheaper than a full-cache
+    # select on the donated buffers).
+    upd = jax.vmap(
+        lambda cache, new, start, act:
+        jax.lax.dynamic_update_slice_in_dim(
+            cache,
+            jnp.where(act, new,
+                      jax.lax.dynamic_slice_in_dim(cache, start, T, 0)),
+            start, 0)
+    )
+
+    def layer(h, lp_kv):
+        lp, (k_l, v_l) = lp_kv                     # k_l: [S, max_len, Hkv, Dh]
+
+        def per_slot_append(k, v):
+            with jax.named_scope("kv_update"):
+                k_new = upd(k_l, k.astype(k_l.dtype), lengths, active)
+                v_new = upd(v_l, v.astype(v_l.dtype), lengths, active)
+            return (k_new, v_new, (allowed, qpos, pos_grid[None, None, :]),
+                    (k_new, v_new))
+
+        return _decoder_layer(cfg, lp, h, rope, per_slot_append)
+
+    return _scan_layers_in_place(layer, h, params["layers"], k_all, v_all)
 
 
 class BatchedStageExecutor:
@@ -346,7 +438,6 @@ class BatchedStageExecutor:
 
         @partial(jax.jit, donate_argnums=engine_donation(3, 4))
         def prefill(params, x, slot, k_all, v_all, t_real):
-            b = 1
             t = x.shape[1]
             positions = jnp.arange(t, dtype=jnp.int32)[None, :]
             with jax.named_scope("embed"):
@@ -367,33 +458,12 @@ class BatchedStageExecutor:
                 # (i - window, i].
                 mask &= cols > rows - cfg.sliding_window
 
-            def layer(h, xs):
-                from ..models.quant import dequant_tree
+            def fresh_prompt(k, v):
+                return k, v, (mask, rows, cols), (k, v)
 
+            def layer(h, xs):
                 (lp,) = xs
-                lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                with jax.named_scope("attention"):
-                    a = _norm(cfg, lp["ln1"], h)
-                    q, k, v = qkv_proj(cfg, lp["attn"], a)
-                    if rope is not None:
-                        q = apply_rope(q, *rope)
-                        k = apply_rope(k, *rope)
-                    groups = cfg.num_heads // cfg.num_kv_heads
-                    qg = q.reshape(b, t, cfg.num_kv_heads, groups,
-                                   cfg.head_dim)
-                    scores = jnp.einsum(
-                        "bthgd,bshd->bhgts", qg * _qscale(cfg), k,
-                        preferred_element_type=jnp.float32)
-                    m = _layer_mask(lp, mask, rows, cols)
-                    scores = _softcap_and_mask(cfg, scores,
-                                               m[None, None, None])
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    out = jnp.einsum("bhgts,bshd->bthgd",
-                                     probs.astype(v.dtype), v)
-                    out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
-                    if "bo" in lp["attn"]:
-                        out = out + lp["attn"]["bo"]
-                h = _residual(cfg, lp, h, out)
+                h, (k, v) = _decoder_layer(cfg, lp, h, rope, fresh_prompt)
                 return h, (k[0], v[0])
 
             h, (ks, vs) = _scan_layers(layer, h, params["layers"])
@@ -418,69 +488,42 @@ class BatchedStageExecutor:
 
         @partial(jax.jit, donate_argnums=engine_donation(3, 4))
         def prefill_suffix(params, x, slot, k_all, v_all, p_len, t_real):
-            b = 1
             t = x.shape[1]
             positions = p_len + jnp.arange(t, dtype=jnp.int32)[None, :]
             with jax.named_scope("embed"):
                 h = (embed_tokens(cfg, params["embed"], x, positions)
                      if spec.is_first else x)
                 rope = make_rope(cfg, positions)
-            groups = cfg.num_heads // cfg.num_kv_heads
-            m = k_all.shape[2]
-            pos_grid = jnp.arange(m, dtype=jnp.int32)
+            pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)[None, :]
             qpos = positions[0][:, None]                     # [T, 1]
-            allowed = pos_grid[None, :] <= qpos              # [T, M] causal
+            allowed = pos_grid <= qpos                       # [T, M] causal
             if cfg.sliding_window:
-                allowed &= pos_grid[None, :] > qpos - cfg.sliding_window
+                allowed &= pos_grid > qpos - cfg.sliding_window
             with jax.named_scope("kv_update"):
-                k_slot = jax.lax.dynamic_index_in_dim(k_all, slot, 1,
-                                                      keepdims=False)
-                v_slot = jax.lax.dynamic_index_in_dim(v_all, slot, 1,
-                                                      keepdims=False)
+                k_slot = jax.lax.dynamic_slice_in_dim(k_all, slot, 1, 1)
+                v_slot = jax.lax.dynamic_slice_in_dim(v_all, slot, 1, 1)
 
             def layer(h, xs):
-                from ..models.quant import dequant_tree
+                lp, k_l, v_l = xs                    # k_l: [1, M, Hkv, Dh]
 
-                lp, k_l, v_l = xs                    # k_l: [M, Hkv, Dh]
-                lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                with jax.named_scope("attention"):
-                    a = _norm(cfg, lp["ln1"], h)
-                    q, k, v = qkv_proj(cfg, lp["attn"], a)
-                    if rope is not None:
-                        q = apply_rope(q, *rope)
-                        k = apply_rope(k, *rope)
-                with jax.named_scope("kv_update"):
-                    k_l = jax.lax.dynamic_update_slice_in_dim(
-                        k_l, k[0].astype(k_l.dtype), p_len, 0)
-                    v_l = jax.lax.dynamic_update_slice_in_dim(
-                        v_l, v[0].astype(v_l.dtype), p_len, 0)
-                with jax.named_scope("attention"):
-                    qg = q.reshape(b, t, cfg.num_kv_heads, groups,
-                                   cfg.head_dim)
-                    scores = jnp.einsum(
-                        "bthgd,shd->bhgts", qg * _qscale(cfg),
-                        k_l.astype(q.dtype),
-                        preferred_element_type=jnp.float32)
-                    m = _layer_mask(lp, allowed, qpos, pos_grid[None, :])
-                    scores = _softcap_and_mask(cfg, scores,
-                                               m[None, None, None])
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    out = jnp.einsum("bhgts,shd->bthgd",
-                                     probs.astype(v_l.dtype),
-                                     v_l.astype(q.dtype))
-                    out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
-                    if "bo" in lp["attn"]:
-                        out = out + lp["attn"]["bo"]
-                h = _residual(cfg, lp, h, out)
-                return h, (k_l, v_l)
+                def slot_continuation(k, v):
+                    with jax.named_scope("kv_update"):
+                        k_new = jax.lax.dynamic_update_slice_in_dim(
+                            k_l, k.astype(k_l.dtype), p_len, 1)
+                        v_new = jax.lax.dynamic_update_slice_in_dim(
+                            v_l, v.astype(v_l.dtype), p_len, 1)
+                    return (k_new, v_new, (allowed, qpos, pos_grid),
+                            (k_new, v_new))
+
+                return _decoder_layer(cfg, lp, h, rope, slot_continuation)
 
             h, (ks, vs) = _scan_layers(
                 layer, h, params["layers"], k_slot, v_slot)
             with jax.named_scope("kv_update"):
                 k_all = jax.lax.dynamic_update_slice(
-                    k_all, ks[:, None], (0, slot, 0, 0, 0))
+                    k_all, ks, (0, slot, 0, 0, 0))
                 v_all = jax.lax.dynamic_update_slice(
-                    v_all, vs[:, None], (0, slot, 0, 0, 0))
+                    v_all, vs, (0, slot, 0, 0, 0))
             del t_real  # mask correctness needs only qpos; kept for parity
             return h, k_all, v_all
 
@@ -665,7 +708,6 @@ class BatchedStageExecutor:
         is plain decode; t_step == K+1 is a speculative verify round (the
         draft block enters as new tokens, causal within itself)."""
         cfg, spec = self.cfg, self.spec
-        S = self.slots
         T = t_step
 
         @partial(jax.jit, donate_argnums=engine_donation(4, 5))
@@ -673,75 +715,10 @@ class BatchedStageExecutor:
             # x: ids [S, T] or hidden [S, T, D]; lengths/active: [S].
             offs = jnp.arange(T, dtype=jnp.int32)
             positions = lengths[:, None] + offs[None, :]       # [S, T]
-            with jax.named_scope("embed"):
-                h = (embed_tokens(cfg, params["embed"], x, positions)
-                     if spec.is_first else x)
-                rope = make_rope(cfg, positions)
-            groups = cfg.num_heads // cfg.num_kv_heads
-            pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)  # [max_len]
-            # allowed[s, tq, m]: key position m visible to query token tq of
-            # slot s — everything up to and including the query's own
-            # position (causal within the new block too).
-            qpos = positions[:, :, None]                        # [S, T, 1]
-            allowed = pos_grid[None, None, :] <= qpos           # [S, T, M]
-            if cfg.sliding_window:
-                # Window spans (qpos - window, qpos].
-                allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
-
-            def layer(h, lp_kv):
-                lp, (k_l, v_l) = lp_kv                 # k_l: [S,max_len,Hkv,Dh]
-                from ..models.quant import dequant_tree
-
-                lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                with jax.named_scope("attention"):
-                    a = _norm(cfg, lp["ln1"], h)
-                    q, k, v = qkv_proj(cfg, lp["attn"], a)  # [S,T,H/Hkv,Dh]
-                    if rope is not None:
-                        q = apply_rope(q, *rope)
-                        k = apply_rope(k, *rope)
-                # Per-slot cache write of T rows at each slot's own length
-                # (vmap'd dynamic_update_slice). Inactive slots write their
-                # OWN current rows back: a slot parked near max_len would
-                # clamp its start and clobber that session's last real KV
-                # rows, so the write value for inactive slots is the rows
-                # already there (read and write clamp to the SAME start, so
-                # the round trip is a no-op — cheaper than a full-cache
-                # select on the donated buffers).
-                upd = jax.vmap(
-                    lambda cache, new, start, act:
-                    jax.lax.dynamic_update_slice_in_dim(
-                        cache,
-                        jnp.where(
-                            act, new,
-                            jax.lax.dynamic_slice_in_dim(cache, start, T, 0)),
-                        start, 0)
-                )
-                with jax.named_scope("kv_update"):
-                    k_l = upd(k_l, k.astype(k_l.dtype), lengths, active)
-                    v_l = upd(v_l, v.astype(v_l.dtype), lengths, active)
-                # Attention over [0, query position] per new token.
-                with jax.named_scope("attention"):
-                    qg = q.reshape(S, T, cfg.num_kv_heads, groups,
-                                   cfg.head_dim)
-                    scores = jnp.einsum(
-                        "bthgd,bshd->bhgts", qg * _qscale(cfg),
-                        k_l.astype(q.dtype),
-                        preferred_element_type=jnp.float32)  # [S,Hkv,G,T,M]
-                    m = _layer_mask(lp, allowed, qpos,
-                                    pos_grid[None, None, :])
-                    scores = _softcap_and_mask(cfg, scores, m[:, None, None])
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    out = jnp.einsum("bhgts,bshd->bthgd",
-                                     probs.astype(v_l.dtype),
-                                     v_l.astype(q.dtype))
-                    out = _dot(out.reshape(S, T, -1), lp["attn"]["wo"])
-                    if "bo" in lp["attn"]:
-                        out = out + lp["attn"]["bo"]
-                h = _residual(cfg, lp, h, out)
-                return h, (k_l, v_l)
-
-            h, k_all, v_all = _scan_layers_in_place(
-                layer, h, params["layers"], k_all, v_all)
+            pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
+            h, k_all, v_all = _decode_span(
+                cfg, spec, params, x, positions, pos_grid, lengths, active,
+                k_all, v_all)
             # Inactive slots produced garbage — zero them so nothing
             # downstream can mistake them for real activations.
             h = jnp.where(active[:, None, None], h, 0.0)
@@ -814,8 +791,8 @@ class BatchedStageExecutor:
 
     def _build_burst(self, n_ticks: int):
         """N decode ticks in one program: ``lax.scan`` over ticks, each tick
-        a T=1 batched decode body (same graph as ``_build_decode(1)``) plus
-        the final head and per-slot sampling.
+        `_decode_span` at T = 1 (what ``_build_decode(1)`` runs) plus the
+        final head and per-slot sampling.
 
         Determinism contract: tick i of a slot whose request shipped
         ``step_seed`` samples with ``PRNGKey(step_seed + i)`` — exactly the
@@ -845,65 +822,9 @@ class BatchedStageExecutor:
                 (tok, lengths, alive, recent, nvalid, run, left,
                  stop, k_all, v_all) = carry
                 active = alive
-                x = tok[:, None]                              # [S, 1] ids
-                positions = lengths[:, None]                  # [S, 1]
-                with jax.named_scope("embed"):
-                    h = embed_tokens(cfg, params["embed"], x, positions)
-                    rope = make_rope(cfg, positions)
-                groups = cfg.num_heads // cfg.num_kv_heads
-                qpos = positions[:, :, None]                  # [S, 1, 1]
-                allowed = pos_grid[None, None, :] <= qpos
-                if cfg.sliding_window:
-                    allowed &= (pos_grid[None, None, :]
-                                > qpos - cfg.sliding_window)
-
-                def layer(h, lp_kv):
-                    lp, (k_l, v_l) = lp_kv
-                    from ..models.quant import dequant_tree
-
-                    lp = dequant_tree(lp, keep_experts=cfg.is_moe)
-                    with jax.named_scope("attention"):
-                        a = _norm(cfg, lp["ln1"], h)
-                        q, k, v = qkv_proj(cfg, lp["attn"], a)
-                        if rope is not None:
-                            q = apply_rope(q, *rope)
-                            k = apply_rope(k, *rope)
-                    upd = jax.vmap(
-                        lambda cache, new, start, act:
-                        jax.lax.dynamic_update_slice_in_dim(
-                            cache,
-                            jnp.where(
-                                act, new,
-                                jax.lax.dynamic_slice_in_dim(
-                                    cache, start, 1, 0)),
-                            start, 0)
-                    )
-                    with jax.named_scope("kv_update"):
-                        k_l = upd(k_l, k.astype(k_l.dtype), lengths, active)
-                        v_l = upd(v_l, v.astype(v_l.dtype), lengths, active)
-                    with jax.named_scope("attention"):
-                        qg = q.reshape(S, 1, cfg.num_kv_heads, groups,
-                                       cfg.head_dim)
-                        scores = jnp.einsum(
-                            "bthgd,bshd->bhgts", qg * _qscale(cfg),
-                            k_l.astype(q.dtype),
-                            preferred_element_type=jnp.float32)
-                        m = _layer_mask(lp, allowed, qpos,
-                                        pos_grid[None, None, :])
-                        scores = _softcap_and_mask(cfg, scores,
-                                                   m[:, None, None])
-                        probs = jax.nn.softmax(scores, axis=-1)
-                        out = jnp.einsum("bhgts,bshd->bthgd",
-                                         probs.astype(v_l.dtype),
-                                         v_l.astype(q.dtype))
-                        out = _dot(out.reshape(S, 1, -1), lp["attn"]["wo"])
-                        if "bo" in lp["attn"]:
-                            out = out + lp["attn"]["bo"]
-                    h = _residual(cfg, lp, h, out)
-                    return h, (k_l, v_l)
-
-                h, k_all, v_all = _scan_layers_in_place(
-                    layer, h, params["layers"], k_all, v_all)
+                h, k_all, v_all = _decode_span(
+                    cfg, spec, params, tok[:, None], lengths[:, None],
+                    pos_grid, lengths, active, k_all, v_all)
                 with jax.named_scope("head"):
                     h = jnp.where(active[:, None, None], h, 0.0)
                     logits = lm_head(cfg, params, h)[:, 0]    # [S, V] fp32

@@ -171,9 +171,33 @@ def _add_burst_peer(cfg, transport, registry, params, name="burst-peer"):
 
 # -- engine: one dispatch per burst, bit-identical tokens ---------------------
 
+def _family_cfg(family):
+    """What the one layer body serves beside the llama shape: learned
+    positions + LayerNorm (gpt2), one sliding window for every layer
+    (mistral), a window LEAF per layer + softcap + sandwich norms (gemma2).
+    Windows of 4 under 3-5 prompt tokens + 12 new ones truncate."""
+    if family == "mistral-window":
+        from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+            mistral_config,
+        )
+
+        return mistral_config(
+            sliding_window=4, vocab_size=257, hidden_size=64, num_layers=4,
+            num_heads=4, num_kv_heads=2, intermediate_size=128,
+            max_position_embeddings=256)
+    return tiny_cfg(family)
+
+
 @pytest.mark.parity
-@pytest.mark.parametrize("sp", [GREEDY, SAMPLED], ids=["greedy", "sampled"])
-def test_burst_engine_matches_sequential(cfg, params, sp):
+@pytest.mark.parametrize("family,sp", [
+    ("llama", GREEDY), ("llama", SAMPLED), ("gpt2", GREEDY),
+    ("mistral-window", GREEDY), ("gemma2", GREEDY)],
+    ids=["greedy", "sampled", "gpt2-greedy", "mistral-window-greedy",
+         "gemma2-greedy"])
+def test_burst_engine_matches_sequential(cfg, params, family, sp):
+    if family != "llama":
+        cfg = _family_cfg(family)
+        params = init_params(jax.random.PRNGKey(0), cfg)
     ref = _sequential(cfg, params, PROMPTS, sp, seed=0, max_new=12)
     got, ex = _bursty(cfg, params, PROMPTS, sp, seed=0, max_new=12,
                       n_ticks=4)

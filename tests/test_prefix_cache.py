@@ -11,6 +11,7 @@ prefill, so fusion differences move the last ulp, not the math).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
@@ -330,8 +331,21 @@ def test_batched_engine_hit_parity_through_decode():
                                    atol=1e-5, rtol=1e-5)
 
 
-def test_batched_engine_shared_prefix_matches_cacheless():
+@pytest.mark.parametrize("window", [None, 4], ids=["global", "window4"])
+def test_batched_engine_shared_prefix_matches_cacheless(window):
+    """The warm suffix continuation against the FULL prefill of the same
+    prompt. ``window4``: Mistral's sliding window, so the suffix rows see
+    only the last 4 keys, across the copied prefix's edge."""
     cfg = tiny_cfg()
+    if window:
+        from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+            mistral_config,
+        )
+
+        cfg = mistral_config(
+            sliding_window=window, vocab_size=257, hidden_size=64,
+            num_layers=8, num_heads=4, num_kv_heads=2,
+            intermediate_size=128, max_position_embeddings=256)
     params = init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(9)
     shared = rng.standard_normal((1, 32, cfg.hidden_size)).astype(np.float32)
