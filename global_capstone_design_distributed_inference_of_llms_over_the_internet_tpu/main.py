@@ -337,8 +337,8 @@ _maybe_lora._cache = {}
 
 
 def _maybe_quantize(args, params, tp: int = 1):
-    """Apply ``--quant`` weight-only quantization (int8 measured +26%
-    decode tokens/s on-chip — docs/PERFORMANCE.md): QuantizedTensor/
+    """Apply ``--quant`` weight-only quantization (int8 the throughput
+    mode, nf4 the capacity mode — docs/PERFORMANCE.md): QuantizedTensor/
     NF4Tensor leaves ride the layer trees and dequantize per layer inside
     the scans; embed/head stay full precision. Rejected with tp > 1 on
     the fused path: the megatron sharding tables key on leaf names that
@@ -2622,9 +2622,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="weight-only block quantization (reference V9 "
                         "surface: int8 per-channel, nf4 4-bit NormalFloat "
                         "at 4.25 bits/param) — stage servers AND the "
-                        "fused/ring/oracle engines. int8 measured +26% "
-                        "decode tokens/s on a v5e; nf4 is the capacity "
-                        "mode (docs/PERFORMANCE.md)")
+                        "fused/ring/oracle engines. int8 is the "
+                        "throughput mode, nf4 the capacity mode "
+                        "(docs/PERFORMANCE.md)")
     p.add_argument("--prompt", default="Hello, my name is")
     p.add_argument("--max_new_tokens", type=int, default=32)
     p.add_argument("--temperature", type=float, default=0.7)

@@ -44,8 +44,8 @@ class ModelConfig:
     # Decode-step KV paging (ops.attention.paged_decode_attention): > 0
     # makes T == 1 steps read only cache pages holding real rows (online-
     # softmax over a dynamic page count) instead of streaming the whole
-    # static bucket — HBM reads then track occupancy, the ~8pp padded-
-    # bucket roofline loss of docs/PERFORMANCE.md. 0 = one-pass attention.
+    # static bucket — HBM reads then track occupancy (docs/PERFORMANCE.md
+    # "Paged KV reads"). 0 = one-pass attention.
     decode_kv_page: int = 0
 
     # MoE (mixtral)

@@ -228,24 +228,6 @@ def dispatch_stats(cfg: ModelConfig, router: jnp.ndarray, x: jnp.ndarray
     return counts, kept, cap
 
 
-def sparse_mlp_flops(n_tokens: int, cfg: ModelConfig) -> int:
-    """Structural MLP FLOPs one MoE layer EXECUTES per forward on the
-    sparse path: three grouped [E, C, D]x[E, D, I] matmuls. The dense
-    path's count is the same expression with C = n_tokens — the ratio is
-    C / N ~= top_k / num_experts * capacity_factor (bench.py asserts
-    this)."""
-    cap = moe_capacity(n_tokens, cfg.num_experts, cfg.num_experts_per_tok)
-    d, i = cfg.hidden_size, cfg.intermediate_size
-    return cfg.num_experts * cap * 3 * d * i * 2
-
-
-def dense_mlp_flops(n_tokens: int, cfg: ModelConfig) -> int:
-    """Structural MLP FLOPs the DENSE formulation executes: every expert
-    on every token."""
-    d, i = cfg.hidden_size, cfg.intermediate_size
-    return cfg.num_experts * n_tokens * 3 * d * i * 2
-
-
 # -- expert-load telemetry (host side) ---------------------------------------
 
 

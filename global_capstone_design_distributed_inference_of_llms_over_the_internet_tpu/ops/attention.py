@@ -64,11 +64,9 @@ def paged_decode_attention(
 ) -> jnp.ndarray:
     """Single-token decode attention whose HBM reads track OCCUPANCY.
 
-    `cached_attention` streams the whole static cache bucket every step —
-    at the flagship bench shape that is ~1.8x the occupied rows (bucket
-    512 vs mean occupancy 288), measured as ~8pp of roofline lost to
-    padded-bucket reads (docs/PERFORMANCE.md, VERDICT r4 item 5). This
-    variant runs the classic online-softmax (flash) accumulation over
+    `cached_attention` streams the whole static cache bucket every step,
+    occupied or not (a 512-row bucket at a mean occupancy of 288 reads
+    ~1.8x the occupied rows). This variant runs the classic online-softmax (flash) accumulation over
     PAGES of the cache with a DYNAMIC trip count ``ceil((cache_len+1)/
     page)`` — lax.fori_loop with a traced bound — so a step reads only
     pages holding real rows. Same math: fp32 running max/denominator,

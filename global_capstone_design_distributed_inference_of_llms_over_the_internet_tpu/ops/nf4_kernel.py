@@ -3,8 +3,8 @@ lever).
 
 XLA cannot fuse the 4-bit unpack + 16-level codebook lookup into the MXU
 operand feed: the dequantized weight materializes through a ~20-op VPU
-elementwise chain per weight per step, measured 5x slower than bf16
-serving on the flagship (docs/PERFORMANCE.md "Quantized serving"). This
+elementwise chain per weight per step (docs/PERFORMANCE.md "Quantized
+serving"; its speed on today's machine is not measured). This
 kernel streams the PACKED nibbles (0.5 B/weight) + per-block scales from
 HBM, dequantizes per N-tile in VMEM, and feeds the MXU directly.
 

@@ -24,8 +24,9 @@ computes instead of P² — the step-work ratio (P+1)/2P → ~0.5 at large P.
 This cuts total FLOPs/energy; single-ring LATENCY is still P-1 rotations
 because the last device computes at every step (balancing that needs a
 zigzag chunk layout — two half-chunks per device, one low one high —
-which would change sp_stage's on-device sequence layout; measured and
-deferred, see docs/PERFORMANCE.md).
+which would change sp_stage's on-device sequence layout;
+`zigzag_ring_attention` below is that layout, docs/PERFORMANCE.md "Scale
+features").
 
 Numerics: scores and the softmax accumulator run in float32 regardless of the
 activation dtype (matching ops.attention's fp32-softmax contract); the output
@@ -93,7 +94,6 @@ def ring_attention(
     q_offset: Optional[jnp.ndarray] = None,
     chunk_positions: Optional[jnp.ndarray] = None,
     causal: bool = True,
-    skip_masked_blocks: bool = True,
 ) -> jnp.ndarray:
     """Exact attention with sequence sharded over `axis_name`.
 
@@ -149,7 +149,7 @@ def ring_attention(
         k_blk, v_blk, m, l, o = carry
         k_blk = jax.lax.ppermute(k_blk, axis_name, perm)
         v_blk = jax.lax.ppermute(v_blk, axis_name, perm)
-        if causal and skip_masked_blocks:
+        if causal:
             # Causal skip: if the incoming block is WHOLLY in this device's
             # future (its first key position is past our last query), every
             # score would be masked — skip the block's compute entirely.
@@ -333,14 +333,11 @@ def make_zigzag_ring_attention_fn(mesh, axis_name: str = "sp"):
     return fn
 
 
-def make_ring_attention_fn(mesh, axis_name: str = "sp",
-                           skip_masked_blocks: bool = True):
+def make_ring_attention_fn(mesh, axis_name: str = "sp"):
     """shard_map-wrapped ring attention over full arrays.
 
     q: [B, T, H, Dh]; k/v: [B, T, Hkv, Dh]; T must divide by the axis size.
     Returns the full [B, T, H, Dh] output (sequence re-assembled).
-    ``skip_masked_blocks=False`` forces the full-ring compute (the bench's
-    comparison baseline for the causal-skip work ratio).
     """
     from jax.sharding import PartitionSpec as P
 
@@ -352,7 +349,6 @@ def make_ring_attention_fn(mesh, axis_name: str = "sp",
         in_specs=(spec, spec, spec), out_specs=spec,
     )
     def fn(q, k, v):
-        return ring_attention(q, k, v, axis_name,
-                              skip_masked_blocks=skip_masked_blocks)
+        return ring_attention(q, k, v, axis_name)
 
     return fn

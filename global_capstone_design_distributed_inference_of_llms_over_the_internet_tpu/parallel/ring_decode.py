@@ -2,8 +2,7 @@
 
 The GPipe-style fused pipeline (`parallel.pipeline`) serves ONE session's
 microbatches: during decode, a token must traverse all S stages before the
-next token can start, so S-1 of S chips idle every tick (measured
-bubble_frac 0.33-0.49 in BENCH_r03 `pipeline_microbatch_s4`). The fix —
+next token can start, so S-1 of S chips idle every tick. The fix —
 and the reference's whole serving model, which its GPU deployment could
 never exploit because each stage was a separate host
 (`petals/server/handler.py:132-195`: every handler serves many concurrent

@@ -34,8 +34,7 @@ token, running the layer scan, sampling ON DEVICE with the session-local
 seed schedule ``PRNGKey(step_seed + i)`` (bit-identical to the sequential
 ``_sample_rows`` path), and maintaining per-slot alive masks so eos /
 repeat / budget stops truncate mid-scan without a host round trip. The
-host pays one dispatch per N tokens instead of one per token, and
-``burst_stream`` double-buffers dispatch k+1 against burst k's readback.
+host pays one dispatch per N tokens instead of one per token.
 """
 
 from __future__ import annotations
@@ -708,12 +707,11 @@ class BatchedStageExecutor:
         is plain decode; t_step == K+1 is a speculative verify round (the
         draft block enters as new tokens, causal within itself)."""
         cfg, spec = self.cfg, self.spec
-        T = t_step
 
         @partial(jax.jit, donate_argnums=engine_donation(4, 5))
         def decode_step(params, x, lengths, active, k_all, v_all):
             # x: ids [S, T] or hidden [S, T, D]; lengths/active: [S].
-            offs = jnp.arange(T, dtype=jnp.int32)
+            offs = jnp.arange(t_step, dtype=jnp.int32)
             positions = lengths[:, None] + offs[None, :]       # [S, T]
             pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
             h, k_all, v_all = _decode_span(
@@ -1003,86 +1001,6 @@ class BatchedStageExecutor:
         self._m_burst_ticks.observe(n_ticks)
         with prof.phase("readback", sessions=n):
             return self._burst_collect(rows, toks, stop, lengths_new)
-
-    def burst_stream(self, entries: Dict[str, dict], n_ticks: int):
-        """Double-buffered burst driver (generator): every carry — tokens,
-        lengths, alive masks, sampler state, KV — stays DEVICE-RESIDENT
-        across bursts, and burst k+1 is dispatched BEFORE burst k's tokens
-        are read back, so on an async backend the host-side readback and
-        framing of burst k overlap the device executing burst k+1. Yields
-        one {session_id: {tokens, stop, cache_len}} block per burst (empty
-        blocks are skipped). The in-process serving/bench driver for one
-        resident cohort; the wire path uses per-burst ``decode_burst``."""
-        if not entries:
-            return
-        prof = _get_profiler()
-        with prof.phase("burst_build"):
-            rows, args = self._burst_prep(entries, n_ticks)
-            fn = self._get_burst_jit(n_ticks)
-        remaining = {sid: int(e["budget"]) for sid, e in entries.items()}
-        finished: Dict[str, bool] = {sid: False for sid in entries}
-        # _burst_prep clamps the ``left`` counter to ONE burst's ticks (the
-        # per-dispatch wire contract); a stream spans many bursts, so seed
-        # the carry with the FULL budget instead — it ticks down on device
-        # across dispatches and a slot goes dead exactly when its total
-        # budget is spent, no host round-trip in between.
-        left_full = np.zeros((self.slots,), np.int32)
-        for sid, s in rows.items():
-            b = int(entries[sid]["budget"])
-            if int(self.lengths[s]) + b > self.max_len:
-                raise RuntimeError(
-                    f"session {sid}: stream budget of {b} past length "
-                    f"{int(self.lengths[s])} exceeds max_len {self.max_len}")
-            left_full[s] = b
-        carry, static = args[:8], args[8:]   # sampler params never change
-        carry = carry[:7] + (jnp.asarray(left_full),)
-        pending: List[tuple] = []
-        done = False
-        while not done or pending:
-            if not done:
-                t_d = time.perf_counter() if prof.enabled else None
-                out = fn(self.params, *carry, *static, self.k, self.v)
-                if t_d is not None:
-                    prof.observe("dispatch", time.perf_counter() - t_d)
-                toks, stop = out[0], out[1]
-                carry = out[2:10]
-                self.k, self.v = out[-2], out[-1]
-                self.decode_steps += 1
-                self.burst_dispatches += 1
-                self._m_burst_disp.inc()
-                self._m_burst_ticks.observe(n_ticks)
-                # out[3] is the post-burst lengths (device array, not yet
-                # read back — _burst_collect does the sync).
-                pending.append((toks, stop, out[3], t_d))
-            # Keep exactly one burst in flight: read back the OLDEST burst
-            # only once a newer one has been dispatched (or we are done).
-            while pending and (done or len(pending) > 1):
-                toks_p, stop_p, len_p, t_d = pending.pop(0)
-                if t_d is not None and prof.enabled:
-                    # Fence device completion apart from the host-side
-                    # readback: the fenced burst is the one being collected
-                    # anyway, so dispatch overlap is preserved — overlapped
-                    # dispatches show up as zero bubble, host stalls between
-                    # readies as idle device time.
-                    jax.block_until_ready((toks_p, stop_p, len_p))
-                    t_r = time.perf_counter()
-                    prof.device_interval(t_d, t_r)
-                    block = self._burst_collect(rows, toks_p, stop_p, len_p)
-                    prof.observe("readback", time.perf_counter() - t_r)
-                else:
-                    block = self._burst_collect(rows, toks_p, stop_p, len_p)
-                live = {}
-                for sid, res in block.items():
-                    m = len(res["tokens"])
-                    remaining[sid] -= m
-                    if res["stop"] is not None or remaining[sid] <= 0:
-                        finished[sid] = True
-                    if m:
-                        live[sid] = res
-                if all(finished.values()):
-                    done = True
-                if live:
-                    yield live
 
     # ------------------------------------------------------------------
 

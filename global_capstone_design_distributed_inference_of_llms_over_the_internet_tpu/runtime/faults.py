@@ -18,9 +18,8 @@ socket paths in ``runtime/net.py`` consult at three seams:
     (`_FramedTcpServer`'s per-connection loop, `RegistryServer`).
 
 Every hook is a no-op when no plan is installed: the hot path pays one
-attribute read (``plan is None``) and never wraps a socket, so the
-zero-overhead acceptance bound (bench fused-decode / recorder_overhead
-< 1%) holds by construction.
+attribute read (``plan is None``) and never wraps a socket: no cost to
+measure without a plan.
 
 Fault kinds (`FaultRule.kind`):
 

@@ -5,8 +5,9 @@ The TPU-idiomatic analogue of the reference's CUDA-graph decode
 program (``lax.scan`` over steps), so steady state pays zero per-step host
 round trips.
 
-Two measured structural choices (slope-timed on a v5e, gpt2-124M b8 and a
-1.1B flagship — see bench.py):
+Two structural choices, slope-timed on a v5e in round 4 (gpt2-124M b8 and
+a 1.1B llama; that rig is gone and no benchmark cell runs this engine, so
+the numbers below date from then):
 
   * **Caches as loop CARRY with per-layer in-place updates**, not as the
     layer scan's xs/ys. The xs/ys structure rewrites every layer's whole
@@ -22,12 +23,12 @@ Two measured structural choices (slope-timed on a v5e, gpt2-124M b8 and a
     pair at gpt2's vocab.
 
 Donation stays ungated here (cf. utils.platform.engine_donation): both
-fused engines are single-controller programs — the bench/oracle caller
+fused engines are single-controller programs — the oracle caller
 owns every dispatch, so the CPU async-dispatch/free race the threaded
 serving engines gate against has no second thread to race.
 
-`make_fused_decode` is the greedy throughput engine (bench + oracle fast
-path); `make_fused_sample_decode` folds the FULL reference sampler into
+`make_fused_decode` is the greedy throughput engine (``--mode oracle``'s
+fast path); `make_fused_sample_decode` folds the FULL reference sampler into
 the scan for batch-1 sampled generation, bit-identical to the per-token
 oracle loop. Distributed serving still samples per step on the final hop
 (the sampler needs the request's live metadata there).
@@ -75,7 +76,7 @@ def make_fused_decode(cfg: ModelConfig, max_steps: int, batch: int,
     ``exact_head=True`` runs the head matmul in fp32 like ``lm_head`` does —
     bit-matching the per-token sampler's greedy argmax on reduced-precision
     checkpoints (near-tied logits can otherwise flip under the bf16 one-pass
-    head). The oracle baseline uses it; the bench keeps the fast weight-dtype
+    head). The oracle baseline uses it; the default is the fast weight-dtype
     head (the measured ~1.5x).
     """
     L = cfg.num_layers

@@ -13,8 +13,7 @@ causal story of the failure.
 Design mirrors ``telemetry/metrics.py``:
 
   * the process-global recorder starts DISABLED; a disabled ``emit()`` is
-    one attribute check + return (the `recorder_overhead` BENCH row prices
-    the ENABLED cost at <1% of a fused decode step);
+    one attribute check + return;
   * event names are declared ONCE in the ``EVENTS`` catalog below — a typo'd
     name is a KeyError at the emit site, not a silently forked stream — and
     ``scripts/check_metrics_documented.py`` diffs the catalog against

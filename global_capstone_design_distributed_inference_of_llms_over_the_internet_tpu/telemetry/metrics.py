@@ -18,8 +18,8 @@ Design points:
   * Histograms are fixed-bucket (cumulative counts per upper bound, +Inf
     implicit) with `quantile()` via linear interpolation inside the winning
     bucket — the same estimate a Prometheus `histogram_quantile()` would give,
-    computed locally so `--mode status` and bench.py can print p50/p95 without
-    a scrape stack.
+    computed locally so `--mode status` can print p50/p95 without a scrape
+    stack.
   * The process-global registry starts DISABLED (`enable()` flips it); library
     code instruments unconditionally and the flag decides the cost.
 """

@@ -1,7 +1,8 @@
 """Hot-path phase profiler: where does one token's wall time actually go?
 
-bench.py answers that question offline; this module answers it LIVE. A
-`PhaseProfiler` brackets the serving hot path into named phases —
+This module answers that question LIVE (the benchmark's traced run,
+perfbench/, reads its phases). A `PhaseProfiler` brackets the serving hot
+path into named phases —
 
   * ``gateway_queue`` — admission to first pipeline step (serving/gateway.py)
   * ``prefill_wait``  — a prefill's wait for the batched stage's lock

@@ -66,8 +66,8 @@ def compile_cache_dir() -> str:
 
     With ``JAX_COMPILATION_CACHE_DIR`` set, JAX reads it itself and the
     program sets nothing in code. Otherwise the cache is the fixed
-    ``<checkout>/.jax_cache``. Called from ``main.main()``, ``bench.py``
-    and ``chip_smoke.py``'s children; tests keep the cache off
+    ``<checkout>/.jax_cache``. Called from ``main.main()`` and
+    ``chip_smoke.py``'s children; tests keep the cache off
     (tests/conftest.py)."""
     from .flags import raw_flag
 

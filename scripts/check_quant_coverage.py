@@ -4,8 +4,8 @@
 The check itself lives in scripts/graftlint/legacy.py — one driver, one
 finding format, one baseline. This entry point survives so existing
 tier-1 wrappers (tests/test_quant_coverage.py) keep working; it exits
-non-zero when a quant format in models/quant.py::QUANT_BITS lacks a bench
-row, a parity test, or an MoE-path parity test.
+non-zero when a quant format in models/quant.py::QUANT_BITS lacks a parity
+test or an MoE-path parity test.
 """
 
 import pathlib

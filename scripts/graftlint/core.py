@@ -53,7 +53,6 @@ class Context:
     tests_text: Dict[str, str]             # tests/*.py rel-path -> source
     scripts_text: Dict[str, str]           # scripts/*.py rel-path -> source
     docs_text: Dict[str, str]              # README.md + docs/*.md
-    bench_text: str                        # bench.py ("" if absent)
 
     def module(self, rel_suffix: str) -> Optional[astutil.Module]:
         for m in self.modules:
@@ -79,7 +78,6 @@ def build_context(repo: pathlib.Path,
     readme = repo / "README.md"
     if readme.exists():
         docs["README.md"] = readme.read_text(encoding="utf-8")
-    bench = repo / "bench.py"
     return Context(
         repo=repo,
         modules=modules,
@@ -88,7 +86,6 @@ def build_context(repo: pathlib.Path,
         tests_text=_texts(repo / "tests", "*.py"),
         scripts_text=_texts(repo / "scripts", "*.py"),
         docs_text=docs,
-        bench_text=bench.read_text(encoding="utf-8") if bench.exists() else "",
     )
 
 

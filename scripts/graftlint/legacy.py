@@ -208,7 +208,6 @@ def analyze_quant_coverage(ctx: Context) -> List[Finding]:
             "table moved; update scripts/graftlint/legacy.py")]
     fmts = [f for f in re.findall(r'"([a-z0-9_]+)"\s*:', m.group(1))
             if f != "none"]
-    bench_cov = _quantize_calls(ctx.bench_text, fmts)
     parity_cov: Set[str] = set()
     moe_cov: Set[str] = set()
     for rel, text in ctx.tests_text.items():
@@ -225,8 +224,6 @@ def analyze_quant_coverage(ctx: Context) -> List[Finding]:
     findings: List[Finding] = []
     for fmt in fmts:
         missing = []
-        if fmt not in bench_cov:
-            missing.append("bench row in bench.py")
         if fmt not in parity_cov:
             missing.append("parity test under tests/")
         if fmt not in moe_cov:

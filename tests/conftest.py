@@ -24,8 +24,8 @@ import pytest  # noqa: E402
 # S>1 GEMM, vs a per-session oracle, T=1 GEMV); under the default precision
 # they only agree while argmax gaps exceed that noise, which made
 # longer-horizon parity assertions flaky. "highest" makes every engine
-# bit-comparable on CPU; TPU perf runs (bench.py, no conftest) keep the
-# native bf16 MXU path.
+# bit-comparable on CPU; runs on the TPU (perfbench/, chip_smoke.py: no
+# conftest) keep the native bf16 MXU path.
 jax.config.update("jax_default_matmul_precision", "highest")
 
 # Tests keep the persistent compilation cache off (the CLI turns it on,

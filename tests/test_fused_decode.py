@@ -1,5 +1,5 @@
 """Fused multi-step decode (runtime.fused_decode): token parity with the
-per-step full_forward oracle — the bench's engine must generate exactly
+per-step full_forward oracle — the fused engine must generate exactly
 what serving generates (greedy)."""
 
 import jax

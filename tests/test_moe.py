@@ -20,12 +20,10 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     mixtral_config,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.moe import (
-    dense_mlp_flops,
     dispatch_stats,
     moe_capacity,
     moe_capacity_factor,
     moe_sparse_enabled,
-    sparse_mlp_flops,
     sparse_moe_mlp,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
@@ -164,14 +162,16 @@ def test_capacity_overflow_drops_and_stays_finite(monkeypatch):
     assert not np.allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
-# -- structural FLOPs ---------------------------------------------------------
+# -- structural work: slots an expert computes over tokens --------------------
 
 
-def test_flops_ratio_proportional_to_topk_over_experts():
+def test_capacity_share_proportional_to_topk_over_experts():
+    """The sparse path's three grouped matmuls run over C slots an expert
+    where the dense formulation runs over all N tokens: C / N is the share
+    of the dense MLP's work it executes."""
     for e, k in [(8, 1), (8, 2), (16, 2), (16, 4)]:
-        cfg = moe_cfg(e, k)
         n = 512
-        ratio = sparse_mlp_flops(n, cfg) / dense_mlp_flops(n, cfg)
+        ratio = moe_capacity(n, e, k) / n
         expect = min(1.0, k / e * moe_capacity_factor())
         assert abs(ratio - expect) <= 1.0 / n
 

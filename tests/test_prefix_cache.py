@@ -331,21 +331,12 @@ def test_batched_engine_hit_parity_through_decode():
                                    atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("window", [None, 4], ids=["global", "window4"])
-def test_batched_engine_shared_prefix_matches_cacheless(window):
+@pytest.mark.parametrize("family", ["llama", "mistral-window"])
+def test_batched_engine_shared_prefix_matches_cacheless(family):
     """The warm suffix continuation against the FULL prefill of the same
-    prompt. ``window4``: Mistral's sliding window, so the suffix rows see
-    only the last 4 keys, across the copied prefix's edge."""
-    cfg = tiny_cfg()
-    if window:
-        from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-            mistral_config,
-        )
-
-        cfg = mistral_config(
-            sliding_window=window, vocab_size=257, hidden_size=64,
-            num_layers=8, num_heads=4, num_kv_heads=2,
-            intermediate_size=128, max_position_embeddings=256)
+    prompt. ``mistral-window``: a sliding window of 4, so the suffix rows
+    see only the last 4 keys, across the copied prefix's edge."""
+    cfg = tiny_cfg(family)
     params = init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(9)
     shared = rng.standard_normal((1, 32, cfg.hidden_size)).astype(np.float32)

@@ -289,7 +289,7 @@ def test_nf4_sizing_matches_4_25_bits():
 
 
 def test_quantized_fused_decode_matches_dequantized_fused():
-    """The fused multi-step decode engine (the bench's flagship path) must
+    """The fused multi-step decode engine (``--mode oracle``) must
     produce the same greedy tokens whether QuantizedTensor leaves
     dequantize inside the scan or the dequantized weights are materialized
     up front — for BOTH int8 and nf4."""

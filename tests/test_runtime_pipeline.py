@@ -79,6 +79,16 @@ def tiny_cfg(family="llama"):
                              sliding_window=4, query_pre_attn_scalar=16.0,
                              attn_softcap=2.0, final_softcap=3.0,
                              max_position_embeddings=256)
+    if family == "mistral-window":
+        from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+            mistral_config,
+        )
+
+        # One sliding window for every layer; 4 truncates at these lengths.
+        return mistral_config(sliding_window=4, vocab_size=257,
+                              hidden_size=64, num_layers=8, num_heads=4,
+                              num_kv_heads=2, intermediate_size=128,
+                              max_position_embeddings=256)
     return llama_config(vocab_size=257, hidden_size=64, num_layers=8,
                         num_heads=4, num_kv_heads=2, intermediate_size=128,
                         max_position_embeddings=256)
