@@ -248,35 +248,55 @@ def _scan_layers(layer, h, layers, *xs):
 
 def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     """``lax.scan`` over the span's layers with the cache stacks as CARRY:
-    each layer reads and writes its own ``[S, max_len, Hkv, Dh]`` slice by
-    index, so XLA updates the stacks in place. ``layer(h, (lp, (k_l, v_l)))
-    -> (h, (k_l, v_l))``.
+    ``layer(h, (lp, i, k_all, v_all)) -> (h, (k_all, v_all))`` is handed
+    the WHOLE ``[L, S, max_len, Hkv, Dh]`` stacks and its own index ``i``,
+    writes its new rows into them and reads what it attends over out of
+    them, so XLA updates the carried buffers in place and no layer's
+    ``[S, max_len, Hkv, Dh]`` slab is ever the operand of an update.
 
-    As scan xs/ys the stacks are rewritten into a second buffer every step,
-    input and output both live. On the TPU the ``[.., Hkv, Dh]`` minor dims
-    pad to (8,128) tiles — 2.6x at gpt2-xl's 25 x 64 — so at 8 slots x 1024
-    rows each copy is 3 GB and the burst program asked for 17.3 GB of a
-    v5e's 15.75 (chip run, PR 21)."""
+    Two forms this replaced, and what each cost on the v5e. As scan xs/ys
+    the stacks are rewritten into a second buffer every step, input and
+    output both live: with the ``[.., Hkv, Dh]`` minor dims padded to
+    (8,128) tiles (2.6x at gpt2-xl's 25 x 64) each copy is 3 GB at 8
+    slots x 1024 rows and the burst program asked for 17.3 GB of a v5e's
+    15.75 (chip run, PR 21). As a carry whose layer slab is sliced out,
+    appended to and written back, XLA materialised the slice, the updated
+    slab, the write-back and one more copy: 72% of the gpt2-xl tick and
+    22% of qwen2-7b's (ledger, PR 31)."""
 
     rest, held = _split_stacks(layers)
 
     def body(carry, xs):
         h, k_all, v_all = carry
         lp, i = xs
-        lp = _layer_at(lp, held, i)
-        with jax.named_scope("kv_update"):
-            k_l = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
-        h, (k_l, v_l) = layer(h, (lp, (k_l, v_l)))
-        with jax.named_scope("kv_update"):
-            k_all = jax.lax.dynamic_update_index_in_dim(k_all, k_l, i, 0)
-            v_all = jax.lax.dynamic_update_index_in_dim(v_all, v_l, i, 0)
+        h, (k_all, v_all) = layer(
+            h, (_layer_at(lp, held, i), i, k_all, v_all))
         return (h, k_all, v_all), None
 
     (h, k_all, v_all), _ = jax.lax.scan(
         body, (h, k_all, v_all),
         (rest, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
     return h, k_all, v_all
+
+
+def _append_rows(stack, i, new, lengths, active):
+    """``stack`` (``[L, S, max_len, Hkv, Dh]``) with ``new`` (``[S, T, Hkv,
+    Dh]``) at ``stack[i, s, lengths[s] : lengths[s] + T]`` for every slot:
+    one row-sized ``dynamic_update_slice`` a slot (S is static), so the
+    carried stack is updated in place. The start clamps as
+    ``dynamic_update_slice`` clamps. An INACTIVE slot writes back the rows
+    already there: a slot parked near ``max_len`` would clamp its start and
+    clobber that session's last real KV rows, so its write value is what it
+    reads at the SAME clamped start (``T`` rows; the round trip is a
+    no-op, and cheaper than a select over the donated buffers)."""
+    zero = jnp.int32(0)
+    size = (1, 1) + new.shape[1:]
+    for s in range(new.shape[0]):
+        at = (i, jnp.int32(s), lengths[s], zero, zero)
+        old = jax.lax.dynamic_slice(stack, at, size)
+        stack = jax.lax.dynamic_update_slice(
+            stack, jnp.where(active[s], new[s][None, None], old), at)
+    return stack
 
 
 def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
@@ -288,7 +308,6 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
     at ``positions`` (``lengths[:, None]`` + the offset in the block);
     ``pos_grid``: ``arange(max_len)``, the caller's so that a burst builds
     it once for all its ticks."""
-    T = positions.shape[1]
     with jax.named_scope("embed"):
         h = (embed_tokens(cfg, params["embed"], x, positions)
              if spec.is_first else x)
@@ -301,30 +320,25 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
     if cfg.sliding_window:
         # Window spans (qpos - window, qpos].
         allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
-    # Per-slot cache write of T rows at each slot's own length (vmap'd
-    # dynamic_update_slice). Inactive slots write their OWN current rows
-    # back: a slot parked near max_len would clamp its start and clobber
-    # that session's last real KV rows, so the write value for inactive
-    # slots is the rows already there (read and write clamp to the SAME
-    # start, so the round trip is a no-op — cheaper than a full-cache
-    # select on the donated buffers).
-    upd = jax.vmap(
-        lambda cache, new, start, act:
-        jax.lax.dynamic_update_slice_in_dim(
-            cache,
-            jnp.where(act, new,
-                      jax.lax.dynamic_slice_in_dim(cache, start, T, 0)),
-            start, 0)
-    )
 
-    def layer(h, lp_kv):
-        lp, (k_l, v_l) = lp_kv                     # k_l: [S, max_len, Hkv, Dh]
+    def layer(h, xs):
+        lp, i, k_all, v_all = xs
 
         def per_slot_append(k, v):
+            # Write the T new rows a slot into the stacks, THEN read this
+            # layer's keys and values out of them: the read's only
+            # consumers are the two attention products.
             with jax.named_scope("kv_update"):
-                k_new = upd(k_l, k.astype(k_l.dtype), lengths, active)
-                v_new = upd(v_l, v.astype(v_l.dtype), lengths, active)
-            return (k_new, v_new, (allowed, qpos, pos_grid[None, None, :]),
+                k_new = _append_rows(
+                    k_all, i, k.astype(k_all.dtype), lengths, active)
+                v_new = _append_rows(
+                    v_all, i, v.astype(v_all.dtype), lengths, active)
+            with jax.named_scope("attention"):
+                keys = jax.lax.dynamic_index_in_dim(
+                    k_new, i, 0, keepdims=False)
+                values = jax.lax.dynamic_index_in_dim(
+                    v_new, i, 0, keepdims=False)
+            return (keys, values, (allowed, qpos, pos_grid[None, None, :]),
                     (k_new, v_new))
 
         return _decoder_layer(cfg, lp, h, rope, per_slot_append)
