@@ -282,21 +282,36 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
 def _append_rows(stack, i, new, lengths, active):
     """``stack`` (``[L, S, max_len, Hkv, Dh]``) with ``new`` (``[S, T, Hkv,
     Dh]``) at ``stack[i, s, lengths[s] : lengths[s] + T]`` for every slot:
-    one row-sized ``dynamic_update_slice`` a slot (S is static), so the
-    carried stack is updated in place. The start clamps as
-    ``dynamic_update_slice`` clamps. An INACTIVE slot writes back the rows
-    already there: a slot parked near ``max_len`` would clamp its start and
-    clobber that session's last real KV rows, so its write value is what it
-    reads at the SAME clamped start (``T`` rows; the round trip is a
-    no-op, and cheaper than a select over the donated buffers)."""
-    zero = jnp.int32(0)
-    size = (1, 1) + new.shape[1:]
-    for s in range(new.shape[0]):
-        at = (i, jnp.int32(s), lengths[s], zero, zero)
-        old = jax.lax.dynamic_slice(stack, at, size)
-        stack = jax.lax.dynamic_update_slice(
-            stack, jnp.where(active[s], new[s][None, None], old), at)
-    return stack
+    ONE scatter of S x T rows, so the carried stack is updated in place
+    and nothing larger than the rows moves. A slot's start clamps to
+    ``max_len - T``, as ``dynamic_update_slice`` clamps. An INACTIVE slot
+    writes back the rows already there: a slot parked near ``max_len``
+    would clamp its start and clobber that session's last real KV rows, so
+    its write value is what the gather reads at the SAME clamped start (T
+    rows; the round trip is a no-op, and cheaper than a select over the
+    donated buffers). A row (``[Hkv, Dh]``, the scatter's window) at a
+    ``(layer, slot, position)`` point is the form the TPU keeps as one
+    native scatter; a window that spans the T positions is expanded into
+    a loop over the slots."""
+    slots, t = new.shape[:2]
+    start = jnp.clip(lengths, 0, stack.shape[2] - t)
+    at = jnp.stack(jnp.broadcast_arrays(
+        i, jnp.arange(slots, dtype=jnp.int32)[:, None],
+        start[:, None] + jnp.arange(t, dtype=jnp.int32)), axis=-1)
+    old = jax.lax.gather(
+        stack, at,
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(2, 3), collapsed_slice_dims=(0, 1, 2),
+            start_index_map=(0, 1, 2)),
+        slice_sizes=(1, 1, 1) + new.shape[2:], mode="promise_in_bounds",
+        unique_indices=True, indices_are_sorted=True)
+    return jax.lax.scatter(
+        stack, at, jnp.where(active[:, None, None, None], new, old),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(2, 3), inserted_window_dims=(0, 1, 2),
+            scatter_dims_to_operand_dims=(0, 1, 2)),
+        mode="promise_in_bounds", unique_indices=True,
+        indices_are_sorted=True)
 
 
 def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,

@@ -934,3 +934,271 @@ def test_int8_programs_hand_the_kernel_the_whole_stack(monkeypatch, program):
             if getattr(v.aval, "dtype", None) == jnp.int8
             and v.aval.ndim >= 2]
     assert not made, made
+
+
+# ---------------------------------------------------------------------------
+# The decode step and the burst tick append their T new rows a slot to the
+# carried [L, S, max_len, Hkv, Dh] stacks IN PLACE and attend over what they
+# read out of the stacks after the write. Until PR 32 each layer's whole
+# [S, max_len, Hkv, Dh] slab was sliced out, appended to and written back:
+# 72% of the gpt2-xl tick on the v5e. That policy stays HERE, as the oracle,
+# in two halves, because XLA's CPU backend contracts the rotary multiply-add
+# differently when the fresh rows feed a row scatter than when they feed a
+# dynamic_update_slice (float32 rows a last bit apart at layer 0; on the v5e
+# every form wrote the same bits): `slab_append` is the old append itself,
+# which `_append_rows` must equal bit for bit as plain data movement, and
+# `slab_policy_decode_span` is the old ROUND TRIP of a slab around one layer,
+# with the rows appended by `_append_rows`.
+# ---------------------------------------------------------------------------
+
+
+def slab_append(slab, new, start, active):
+    """The append as it was: a vmap'd `dynamic_update_slice` of T rows a
+    slot on one layer's ``[S, max_len, Hkv, Dh]`` slab; an inactive slot
+    writes back what it reads at the same (clamped) start."""
+    t = new.shape[1]
+    return jax.vmap(
+        lambda cache, rows, at, act: jax.lax.dynamic_update_slice_in_dim(
+            cache, jnp.where(act, rows, jax.lax.dynamic_slice_in_dim(
+                cache, at, t, 0)), at, 0))(slab, new, start, active)
+
+
+def slab_policy_decode_span(cfg, spec, params, x, positions, pos_grid,
+                            lengths, active, k_all, v_all):
+    """`runtime.batching._decode_span` with the slab's round trip as it
+    was: slab out of the stack, rows appended to the SLAB, attention over
+    the new slab, slab written back. Same signature, same
+    `_decoder_layer`, same `_append_rows` (on a stack of one layer)."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
+        batching as B,
+    )
+
+    h = (B.embed_tokens(cfg, params["embed"], x, positions)
+         if spec.is_first else x)
+    rope = B.make_rope(cfg, positions)
+    qpos = positions[:, :, None]
+    allowed = pos_grid[None, None, :] <= qpos
+    if cfg.sliding_window:
+        allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
+    rest, held = B._split_stacks(params["layers"])
+
+    def body(carry, xs):
+        h, k_all, v_all = carry
+        lp, i = xs
+        k_l = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=True)
+        v_l = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=True)
+
+        def slab_round_trip(k, v):
+            k_new = B._append_rows(k_l, 0, k.astype(k_l.dtype), lengths,
+                                   active)[0]
+            v_new = B._append_rows(v_l, 0, v.astype(v_l.dtype), lengths,
+                                   active)[0]
+            return (k_new, v_new, (allowed, qpos, pos_grid[None, None, :]),
+                    (k_new, v_new))
+
+        h, (k_new, v_new) = B._decoder_layer(
+            cfg, B._layer_at(lp, held, i), h, rope, slab_round_trip)
+        return (h, jax.lax.dynamic_update_index_in_dim(k_all, k_new, i, 0),
+                jax.lax.dynamic_update_index_in_dim(v_all, v_new, i, 0)), None
+
+    (h, k_all, v_all), _ = jax.lax.scan(
+        body, (h, k_all, v_all),
+        (rest, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
+    return h, k_all, v_all
+
+
+def bits(a):
+    """An array's bytes, for comparisons that a NaN or a -0.0 cannot fool."""
+    a = np.asarray(a)
+    return a.view(np.uint8 if a.dtype.itemsize == 1 else
+                  {2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def family_engine(family, dtype, *, slots=4, max_len=32, seed=3):
+    """A tiny full-span engine of ``family`` with weights and cache in
+    ``dtype``, three sessions prefilled and a fourth slot left to park."""
+    dtype = jnp.dtype(dtype)
+    cfg = tiny_cfg(family)
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    params = jax.tree.map(
+        lambda a: a.astype(dtype) if a.dtype == jnp.float32 else a, params)
+    ex = BatchedStageExecutor(cfg, full_spec(cfg), params, slots=slots,
+                              max_len=max_len, dtype=dtype)
+    for sid in ("a", "b", "c", "d"):
+        ex.prefill(sid, np.asarray(PROMPTS[sid], np.int32)[None, :])
+    return ex
+
+
+def both_policies(monkeypatch, drive):
+    """``drive()`` under the slab's round trip and under the engine's own:
+    ``(oracle's result, engine's result)``. Each run builds its engine and
+    its programs inside ``drive``, so each traces the policy in force."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
+        batching as B,
+    )
+
+    with monkeypatch.context() as m:
+        m.setattr(B, "_decode_span", slab_policy_decode_span)
+        want = drive()
+    return want, drive()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t_step", [1, 3])
+def test_append_rows_is_the_slab_append_in_place(t_step, dtype):
+    """`_append_rows` on the whole stack at a traced layer index leaves
+    what `slab_append` (a vmap'd `dynamic_update_slice`) leaves on that
+    layer's slab, bit for bit, and touches no other layer. Every start is
+    there active and inactive: 0, mid-cache, the exact fit ``max_len - T``,
+    ``max_len - 1`` (for T = 3 it clamps back to ``max_len - 3``: an
+    inactive slot parked there must keep its last rows) and ``max_len``."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
+        _append_rows,
+    )
+
+    layers, max_len, hkv, dh = 3, 16, 2, 8
+    starts = [0, 5, max_len - t_step, max_len - 1, max_len]
+    lengths = jnp.asarray(starts + starts, jnp.int32)
+    active = jnp.asarray([True] * len(starts) + [False] * len(starts))
+    slots = len(starts) * 2
+    ks, kn = jax.random.split(jax.random.PRNGKey(t_step))
+    stack = jax.random.normal(
+        ks, (layers, slots, max_len, hkv, dh)).astype(dtype)
+    new = jax.random.normal(kn, (slots, t_step, hkv, dh)).astype(dtype)
+    for i in range(layers):
+        got = jax.jit(_append_rows)(stack, jnp.int32(i), new, lengths, active)
+        want = stack.at[i].set(slab_append(stack[i], new, lengths, active))
+        np.testing.assert_array_equal(bits(got), bits(want))
+        assert np.any(bits(got)[i] != bits(stack)[i])
+        parked = bits(got)[i, len(starts):]
+        np.testing.assert_array_equal(parked, bits(stack)[i, len(starts):])
+
+
+FAMILIES = ["gpt2", "qwen2", "mistral-window", "gemma2"]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_decode_steps_bit_equal_to_slab_policy(monkeypatch, family, dtype):
+    """Hidden states and the WHOLE K and V stacks after a plain decode
+    step (T = 1) and a speculative-verify step (T = 3), with one session
+    sitting both out, are bit for bit what the slab's round trip leaves."""
+
+    def drive():
+        ex = family_engine(family, dtype)
+        one = ex.decode_batch({"a": jnp.asarray([[3]], jnp.int32),
+                               "b": jnp.asarray([[4]], jnp.int32)})
+        three = ex.decode_batch({"a": jnp.asarray([[3, 9, 1]], jnp.int32),
+                                 "c": jnp.asarray([[4, 8, 2]], jnp.int32)})
+        return {"one.a": one["a"], "one.b": one["b"], "three.a": three["a"],
+                "three.c": three["c"], "k": ex.k, "v": ex.v}
+
+    want, got = both_policies(monkeypatch, drive)
+    assert np.any(bits(want["k"]))
+    for name in want:
+        np.testing.assert_array_equal(bits(got[name]), bits(want[name]),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("t_step", [1, 3])
+@pytest.mark.parametrize("case", ["parked-inactive", "active-to-max-len"])
+def test_decode_append_clamps_as_the_slab_policy_did(monkeypatch, case,
+                                                     t_step):
+    """The two ends of the clamp. A slot parked at ``max_len - 1`` that
+    sits a step out has its start clamped to ``max_len - T``: it must
+    write back the rows it read there, so its last rows stay bit for bit
+    while the others decode. A slot at ``max_len - T`` that takes the step
+    reaches exactly ``max_len``: its rows land at ``[max_len - T,
+    max_len)``, where the slab policy put them."""
+    max_len = 32
+
+    def drive():
+        ex = family_engine("qwen2", "float32", max_len=max_len)
+        d = ex._slot_of["d"]
+        before = bits(ex.k)[:, d].copy(), bits(ex.v)[:, d].copy()
+        ids = np.asarray([[3, 9, 1][:t_step]], np.int32)
+        if case == "parked-inactive":
+            ex.lengths[d] = max_len - 1
+            ex.decode_batch({"a": ids, "b": ids})
+        else:
+            ex.lengths[d] = max_len - t_step
+            ex.decode_batch({"a": ids, "d": ids})
+        return {"k": ex.k, "v": ex.v, "before": before, "slot": d}
+
+    want, got = both_policies(monkeypatch, drive)
+    np.testing.assert_array_equal(bits(got["k"]), bits(want["k"]))
+    np.testing.assert_array_equal(bits(got["v"]), bits(want["v"]))
+    d = got["slot"]
+    tail = slice(max_len - t_step, max_len)
+    for stack, was in zip((got["k"], got["v"]), got["before"]):
+        now = bits(stack)[:, d]
+        if case == "parked-inactive":
+            np.testing.assert_array_equal(now, was)
+        else:
+            assert np.all(np.any(now[:, tail] != was[:, tail], axis=(2, 3)))
+            np.testing.assert_array_equal(now[:, :tail.start],
+                                          was[:, :tail.start])
+
+
+def _cache_writes_and_slabs(jaxpr, stack_shape):
+    """Of every equation under ``jaxpr``: the updates written into an
+    operand shaped like the cache stack, and the equations whose output
+    is one layer's ``[S, max_len, Hkv, Dh]`` slab."""
+    writes, slabs = [], []
+    for e in _all_eqns(jaxpr):
+        name = e.primitive.name
+        if (name in ("dynamic_update_slice", "scatter")
+                and e.invars[0].aval.shape == stack_shape):
+            upd = e.invars[1 if name == "dynamic_update_slice" else 2]
+            writes.append((name, upd.aval.shape))
+        slabs += [name for v in e.outvars
+                  if getattr(v.aval, "shape", None) == stack_shape[1:]]
+    return writes, slabs
+
+
+@pytest.mark.parametrize("tree", ["int8", "bfloat16"])
+@pytest.mark.parametrize("program", ["burst_tick", "decode_step-1",
+                                     "decode_step-3"])
+def test_tick_writes_rows_and_never_a_slab(program, tree):
+    """In the jaxpr of the burst tick and of the decode step, every write
+    into a cache stack is a `scatter` whose update holds T rows a slot
+    (``[S, T, Hkv, Dh]``: one for K, one for V), and the only equations that
+    yield a layer's ``[S, max_len, Hkv, Dh]`` slab are the two reads that
+    feed attention: the `squeeze` of `dynamic_index_in_dim` on the stack,
+    once for K and once for V in the one layer body. A slab that is never
+    an update's operand is one XLA need not copy."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
+        quantize_params,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
+        RECENT_WINDOW,
+    )
+
+    cfg = tiny_cfg("qwen2")
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    if tree == "int8":
+        params, dtype = quantize_params(params, "int8"), jnp.float32
+    else:
+        dtype = jnp.bfloat16
+        params = jax.tree.map(lambda a: a.astype(dtype), params)
+    S, M = 3, 24
+    ex = BatchedStageExecutor(cfg, full_spec(cfg), params, slots=S,
+                              max_len=M, dtype=dtype)
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)         # noqa: E731
+    f32 = lambda *shape: jnp.ones(shape, jnp.float32)        # noqa: E731
+    on = jnp.ones((S,), bool)
+    if program == "burst_tick":
+        T = 1
+        fn, args = ex._build_burst(2), (
+            ex.params, i32(S), i32(S), on, i32(S), i32(S, RECENT_WINDOW),
+            i32(S), i32(S), i32(S) + 2, i32(S) - 1, f32(S), f32(S), i32(S),
+            f32(S), ex.k, ex.v)
+    else:
+        T = int(program[-1])
+        fn, args = ex._build_decode(T), (
+            ex.params, i32(S, T), i32(S), on, ex.k, ex.v)
+    writes, slabs = _cache_writes_and_slabs(
+        jax.make_jaxpr(fn)(*args).jaxpr, ex.k.shape)
+    rows = (S, T, cfg.num_kv_heads, cfg.head_dim)
+    assert writes == [("scatter", rows)] * 2, writes
+    assert slabs == ["squeeze", "squeeze"], slabs

@@ -41,6 +41,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     DeficitRoundRobin,
 )
 
+from test_batching import FAMILIES, bits, both_policies, family_engine
 from test_runtime_pipeline import (
     build_cluster,
     kernel_cfg,
@@ -195,6 +196,77 @@ def test_burst_engine_matches_sequential(cfg, params, family, sp):
     # never the session count.
     assert ex.burst_dispatches <= math.ceil((12 - 1) / 4)
     assert ex.burst_tokens == sum(len(g) - 1 for g in got.values())
+
+
+def _entry(token, seed, budget):
+    return {"token": token, "seed": seed, "budget": budget, "eos": None,
+            "generated": (), "temperature": 0.8, "top_p": 0.95, "top_k": 0,
+            "repetition_penalty": 1.0}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_burst_caches_bit_equal_to_slab_policy(monkeypatch, family, dtype):
+    """Tokens and the WHOLE K and V stacks after one 16-tick sampled burst
+    are bit for bit what the slab policy (the engine's until PR 32, kept in
+    tests/test_batching.py) leaves: two sessions run all 16 ticks, one
+    spends its budget after 7 and sits the rest out, one sits out all."""
+
+    def drive():
+        ex = family_engine(family, dtype)
+        res = ex.decode_burst({"a": _entry(5, 11, 16), "b": _entry(6, 12, 7),
+                               "c": _entry(7, 13, 16)}, 16)
+        return {"tokens": [res[s]["tokens"] for s in "abc"],
+                "k": ex.k, "v": ex.v}
+
+    want, got = both_policies(monkeypatch, drive)
+    assert got["tokens"] == want["tokens"]
+    assert [len(t) for t in got["tokens"]] == [16, 7, 16]
+    np.testing.assert_array_equal(bits(got["k"]), bits(want["k"]))
+    np.testing.assert_array_equal(bits(got["v"]), bits(want["v"]))
+
+
+@pytest.mark.parametrize("case", ["parked-inactive", "active-to-max-len"])
+def test_burst_append_clamps_as_the_slab_policy_did(monkeypatch, case):
+    """The clamp's two ends through a 16-tick burst in which other slots
+    decode. A slot parked at ``max_len - 1`` and left out keeps its last
+    rows bit for bit (every tick reads and writes back its row ``max_len -
+    1``). A slot at ``max_len - 16`` with a budget of 16 reaches exactly
+    ``max_len``: its last row lands at ``max_len - 1``, where the slab
+    policy wrote it, and the burst reports the full cache."""
+    max_len = 32
+
+    def drive():
+        ex = family_engine("qwen2", "float32", max_len=max_len)
+        d = ex._slot_of["d"]
+        before = bits(ex.k)[:, d].copy(), bits(ex.v)[:, d].copy()
+        entries = {"a": _entry(5, 11, 16), "b": _entry(6, 12, 16)}
+        if case == "parked-inactive":
+            ex.lengths[d] = max_len - 1
+        else:
+            ex.lengths[d] = max_len - 16
+            entries["d"] = _entry(7, 13, 16)
+        res = ex.decode_burst(entries, 16)
+        return {"res": res, "k": ex.k, "v": ex.v, "before": before,
+                "slot": d, "len": int(ex.lengths[d])}
+
+    want, got = both_policies(monkeypatch, drive)
+    np.testing.assert_array_equal(bits(got["k"]), bits(want["k"]))
+    np.testing.assert_array_equal(bits(got["v"]), bits(want["v"]))
+    d = got["slot"]
+    for stack, was in zip((got["k"], got["v"]), got["before"]):
+        now = bits(stack)[:, d]
+        if case == "parked-inactive":
+            np.testing.assert_array_equal(now, was)
+        else:
+            assert np.all(np.any(now[:, max_len - 16:] != was[:, max_len - 16:],
+                                 axis=(2, 3)))
+            np.testing.assert_array_equal(now[:, :max_len - 16],
+                                          was[:, :max_len - 16])
+    if case == "active-to-max-len":
+        assert got["len"] == max_len
+        assert got["res"]["d"]["tokens"] == want["res"]["d"]["tokens"]
+        assert len(got["res"]["d"]["tokens"]) == 16
 
 
 # One slot each: greedy (with the filters and the penalty SET, as
