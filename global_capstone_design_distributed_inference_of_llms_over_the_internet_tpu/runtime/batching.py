@@ -7,10 +7,14 @@ STATIC-SHAPE slot batching (the shape-stable cousin of vLLM-style
 continuous batching): the server owns one slot-major KV cache
 ``[L, S, max_len, Hkv, Dh]``, every live session occupies a slot, and one
 jitted step advances EVERY active slot at once — per-slot cache lengths, an
-active mask for empty slots, zero gathers/copies of cache rows. Compute
-scales with the slot count S (the server's intended concurrency), not with
-how many requests happen to arrive, and the step is one compiled program
-replayed forever.
+active mask for empty slots. The cache stacks are the step's loop carry:
+each layer scatters its T new rows a slot into them at the slots' own
+lengths (`_append_rows`; an inactive slot rewrites the rows it holds) and
+attends over its ``[S, max_len, Hkv, Dh]`` rows read straight out of the
+stack, so a step moves the new rows and nothing else of the cache.
+Compute scales with the slot count S (the server's intended concurrency),
+not with how many requests happen to arrive, and the step is one compiled
+program replayed forever.
 
 Sessions join at prefill (slot allocated, prompt written into the slot's
 rows), decode via `decode_batch` (whatever subset of sessions has a token
