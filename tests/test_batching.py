@@ -1014,19 +1014,42 @@ def bits(a):
                   {2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
 
 
-def family_engine(family, dtype, *, slots=4, max_len=32, seed=3):
+def family_engine(family, dtype, max_len=32):
     """A tiny full-span engine of ``family`` with weights and cache in
-    ``dtype``, three sessions prefilled and a fourth slot left to park."""
+    ``dtype``, its four slots prefilled with `PROMPTS`."""
     dtype = jnp.dtype(dtype)
     cfg = tiny_cfg(family)
-    params = init_params(jax.random.PRNGKey(seed), cfg)
+    params = init_params(jax.random.PRNGKey(3), cfg)
     params = jax.tree.map(
         lambda a: a.astype(dtype) if a.dtype == jnp.float32 else a, params)
-    ex = BatchedStageExecutor(cfg, full_spec(cfg), params, slots=slots,
+    ex = BatchedStageExecutor(cfg, full_spec(cfg), params, slots=4,
                               max_len=max_len, dtype=dtype)
-    for sid in ("a", "b", "c", "d"):
-        ex.prefill(sid, np.asarray(PROMPTS[sid], np.int32)[None, :])
+    for sid, prompt in PROMPTS.items():
+        ex.prefill(sid, np.asarray(prompt, np.int32)[None, :])
     return ex
+
+
+def slot_rows(ex, sid):
+    """``(slot, (K bits, V bits))`` of a session's rows in every layer."""
+    d = ex._slot_of[sid]
+    return d, (bits(ex.k)[:, d].copy(), bits(ex.v)[:, d].copy())
+
+
+def check_clamped_slot(got, case, first_new):
+    """What the clamp tests assert of slot ``got["slot"]`` beyond equality
+    with the oracle: parked and left out, every row is as it was
+    (``got["before"]``); taking the step up to exactly ``max_len``, rows
+    ``[first_new, max_len)`` are new in every layer and the rest as it was."""
+    d = got["slot"]
+    for stack, was in zip((got["k"], got["v"]), got["before"]):
+        now = bits(stack)[:, d]
+        if case == "parked-inactive":
+            np.testing.assert_array_equal(now, was)
+        else:
+            assert np.all(np.any(now[:, first_new:] != was[:, first_new:],
+                                 axis=(2, 3)))
+            np.testing.assert_array_equal(now[:, :first_new],
+                                          was[:, :first_new])
 
 
 def both_policies(monkeypatch, drive):
@@ -1079,7 +1102,7 @@ FAMILIES = ["gpt2", "qwen2", "mistral-window", "gemma2"]
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("family", FAMILIES)
-def test_decode_steps_bit_equal_to_slab_policy(monkeypatch, family, dtype):
+def test_decode_steps_bit_equal_to_slab_round_trip(monkeypatch, family, dtype):
     """Hidden states and the WHOLE K and V stacks after a plain decode
     step (T = 1) and a speculative-verify step (T = 3), with one session
     sitting both out, are bit for bit what the slab's round trip leaves."""
@@ -1102,20 +1125,19 @@ def test_decode_steps_bit_equal_to_slab_policy(monkeypatch, family, dtype):
 
 @pytest.mark.parametrize("t_step", [1, 3])
 @pytest.mark.parametrize("case", ["parked-inactive", "active-to-max-len"])
-def test_decode_append_clamps_as_the_slab_policy_did(monkeypatch, case,
+def test_decode_append_clamps_as_the_slab_append_did(monkeypatch, case,
                                                      t_step):
     """The two ends of the clamp. A slot parked at ``max_len - 1`` that
     sits a step out has its start clamped to ``max_len - T``: it must
     write back the rows it read there, so its last rows stay bit for bit
     while the others decode. A slot at ``max_len - T`` that takes the step
     reaches exactly ``max_len``: its rows land at ``[max_len - T,
-    max_len)``, where the slab policy put them."""
+    max_len)``, where `slab_append` puts them."""
     max_len = 32
 
     def drive():
-        ex = family_engine("qwen2", "float32", max_len=max_len)
-        d = ex._slot_of["d"]
-        before = bits(ex.k)[:, d].copy(), bits(ex.v)[:, d].copy()
+        ex = family_engine("qwen2", "float32", max_len)
+        d, before = slot_rows(ex, "d")
         ids = np.asarray([[3, 9, 1][:t_step]], np.int32)
         if case == "parked-inactive":
             ex.lengths[d] = max_len - 1
@@ -1128,16 +1150,7 @@ def test_decode_append_clamps_as_the_slab_policy_did(monkeypatch, case,
     want, got = both_policies(monkeypatch, drive)
     np.testing.assert_array_equal(bits(got["k"]), bits(want["k"]))
     np.testing.assert_array_equal(bits(got["v"]), bits(want["v"]))
-    d = got["slot"]
-    tail = slice(max_len - t_step, max_len)
-    for stack, was in zip((got["k"], got["v"]), got["before"]):
-        now = bits(stack)[:, d]
-        if case == "parked-inactive":
-            np.testing.assert_array_equal(now, was)
-        else:
-            assert np.all(np.any(now[:, tail] != was[:, tail], axis=(2, 3)))
-            np.testing.assert_array_equal(now[:, :tail.start],
-                                          was[:, :tail.start])
+    check_clamped_slot(got, case, max_len - t_step)
 
 
 def _cache_writes_and_slabs(jaxpr, stack_shape):

@@ -41,7 +41,14 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     DeficitRoundRobin,
 )
 
-from test_batching import FAMILIES, bits, both_policies, family_engine
+from test_batching import (
+    FAMILIES,
+    bits,
+    both_policies,
+    check_clamped_slot,
+    family_engine,
+    slot_rows,
+)
 from test_runtime_pipeline import (
     build_cluster,
     kernel_cfg,
@@ -206,11 +213,12 @@ def _entry(token, seed, budget):
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("family", FAMILIES)
-def test_burst_caches_bit_equal_to_slab_policy(monkeypatch, family, dtype):
+def test_burst_caches_bit_equal_to_slab_round_trip(monkeypatch, family,
+                                                   dtype):
     """Tokens and the WHOLE K and V stacks after one 16-tick sampled burst
-    are bit for bit what the slab policy (the engine's until PR 32, kept in
-    tests/test_batching.py) leaves: two sessions run all 16 ticks, one
-    spends its budget after 7 and sits the rest out, one sits out all."""
+    are bit for bit what the slab's round trip (the engine's until PR 32,
+    kept in tests/test_batching.py) leaves: two sessions run all 16 ticks,
+    one spends its budget after 7 and sits the rest out, one sits out all."""
 
     def drive():
         ex = family_engine(family, dtype)
@@ -227,19 +235,18 @@ def test_burst_caches_bit_equal_to_slab_policy(monkeypatch, family, dtype):
 
 
 @pytest.mark.parametrize("case", ["parked-inactive", "active-to-max-len"])
-def test_burst_append_clamps_as_the_slab_policy_did(monkeypatch, case):
+def test_burst_append_clamps_as_the_slab_append_did(monkeypatch, case):
     """The clamp's two ends through a 16-tick burst in which other slots
     decode. A slot parked at ``max_len - 1`` and left out keeps its last
     rows bit for bit (every tick reads and writes back its row ``max_len -
     1``). A slot at ``max_len - 16`` with a budget of 16 reaches exactly
     ``max_len``: its last row lands at ``max_len - 1``, where the slab
-    policy wrote it, and the burst reports the full cache."""
+    append wrote it, and the burst reports the full cache."""
     max_len = 32
 
     def drive():
-        ex = family_engine("qwen2", "float32", max_len=max_len)
-        d = ex._slot_of["d"]
-        before = bits(ex.k)[:, d].copy(), bits(ex.v)[:, d].copy()
+        ex = family_engine("qwen2", "float32", max_len)
+        d, before = slot_rows(ex, "d")
         entries = {"a": _entry(5, 11, 16), "b": _entry(6, 12, 16)}
         if case == "parked-inactive":
             ex.lengths[d] = max_len - 1
@@ -253,16 +260,7 @@ def test_burst_append_clamps_as_the_slab_policy_did(monkeypatch, case):
     want, got = both_policies(monkeypatch, drive)
     np.testing.assert_array_equal(bits(got["k"]), bits(want["k"]))
     np.testing.assert_array_equal(bits(got["v"]), bits(want["v"]))
-    d = got["slot"]
-    for stack, was in zip((got["k"], got["v"]), got["before"]):
-        now = bits(stack)[:, d]
-        if case == "parked-inactive":
-            np.testing.assert_array_equal(now, was)
-        else:
-            assert np.all(np.any(now[:, max_len - 16:] != was[:, max_len - 16:],
-                                 axis=(2, 3)))
-            np.testing.assert_array_equal(now[:, :max_len - 16],
-                                          was[:, :max_len - 16])
+    check_clamped_slot(got, case, max_len - 16)
     if case == "active-to-max-len":
         assert got["len"] == max_len
         assert got["res"]["d"]["tokens"] == want["res"]["d"]["tokens"]
