@@ -980,7 +980,7 @@ def run_serve(args, cfg: ModelConfig, params) -> int:
             cfg, spec, _stage_params(args, cfg, params, spec),
             slots=args.slots, max_len=args.max_session_len, dtype=kv_dtype,
             prefix_cache_bytes=args.prefix_cache_mb << 20,
-            model=_model_id(args))
+            model=_model_id(args), consume_params=True)
         ex = BatchingStageAdapter(engine, peer_id=peer_id)
     else:
         ex = _SE(cfg, spec, _stage_params(args, cfg, params, spec),

@@ -16,7 +16,8 @@ from typing import Optional
 class ModelConfig:
     """Architecture hyperparameters for one decoder-only transformer family."""
 
-    model_type: str  # "gpt2" | "llama" | "mistral" | "mixtral" | "qwen2" | "gemma"
+    # "gpt2" | "llama" | "mistral" | "mixtral" | "qwen2" | "gemma" | "ouro"
+    model_type: str
     vocab_size: int
     hidden_size: int
     num_layers: int
@@ -81,6 +82,17 @@ class ModelConfig:
     # every engine's layer scan sees it. 0 = off.
     altern_window: int = 0
 
+    # Looped ("universal transformer") stack: the SAME num_layers layers run
+    # loop_steps times a token, the model's final norm closing every pass
+    # and feeding the next, each (pass, layer) with K/V rows of its own
+    # (cache depth loop_steps * num_layers for num_layers of weights). A
+    # learned gate read after every pass picks, per token, the pass whose
+    # state goes to the head: the first whose cumulative exit probability
+    # reaches exit_threshold, else the last. Every pass always runs and
+    # writes its K/V. 1 = the stack runs once (every other family).
+    loop_steps: int = 1
+    exit_threshold: float = 1.0
+
     @property
     def head_dim(self) -> int:
         return (self.head_dim_override
@@ -95,6 +107,7 @@ class ModelConfig:
         if self.head_dim_override is None:
             assert self.hidden_size % self.num_heads == 0
         assert self.num_heads % self.num_kv_heads == 0
+        assert self.loop_steps >= 1
 
 
 def gpt2_config(
@@ -201,6 +214,19 @@ def gemma2_config(head_dim: int = 256, query_pre_attn_scalar: float = 0.0,
         altern_window=sliding_window)
 
 
+def ouro_config(loop_steps: int = 4, exit_threshold: float = 1.0,
+                head_dim: int = 128, norm_eps: float = 1e-6,
+                **kw) -> ModelConfig:
+    """Ouro (looped LM): the LLaMA layer with sandwich norms (ln3 after
+    attention, ln4 after the MLP, plain RMSNorm scales), no biases, and the
+    whole stack run ``loop_steps`` times a token over one copy of its
+    weights (`ModelConfig.loop_steps`)."""
+    cfg = llama_config(norm_eps=norm_eps, **kw)
+    return dataclasses.replace(
+        cfg, model_type="ouro", post_norms=True, head_dim_override=head_dim,
+        loop_steps=loop_steps, exit_threshold=exit_threshold)
+
+
 def mixtral_config(num_experts: int = 8, num_experts_per_tok: int = 2, **kw) -> ModelConfig:
     cfg = llama_config(**kw)
     return dataclasses.replace(
@@ -282,12 +308,40 @@ PRESETS = {
         num_kv_heads=4, intermediate_size=18944, max_position_embeddings=32768,
         rope_theta=1000000.0,
     ),
+    # ByteDance/Ouro-2.6B config.json: 48 layers run total_ut_steps = 4
+    # times a token, early_exit_threshold 1.
+    "ouro-2.6b": lambda: ouro_config(
+        vocab_size=49152, hidden_size=2048, num_layers=48, num_heads=16,
+        num_kv_heads=16, intermediate_size=5632,
+        max_position_embeddings=65536, rope_theta=1000000.0,
+    ),
 }
 
 # Qwen2.5 shares the qwen2 architecture (HF model_type "qwen2") — alias
 # the existing entries so a hyperparameter fix can never silently diverge.
 PRESETS["qwen2.5-0.5b"] = PRESETS["qwen2-0.5b"]
 PRESETS["qwen2.5-7b"] = PRESETS["qwen2-7b"]
+
+
+def single_pass_unsupported(cfg: ModelConfig, what: str) -> Optional[str]:
+    """Reason ``what`` (an engine or a route that visits a span of layers
+    ONCE a token) cannot run this config, or None. A looped stack lives in
+    the full-span batched engine (runtime.batching) and the in-program
+    oracle (models.transformer.full_forward) only; everything else would
+    silently run one pass of several and must refuse instead."""
+    if cfg.loop_steps > 1:
+        return (f"layers run several times a token ({cfg.loop_steps} passes "
+                f"over one stack of {cfg.num_layers}, a K/V cache for every "
+                f"pass): {what} would run one pass; serve the model whole "
+                "on the batched engine (serve --stage 0 --batched)")
+    return None
+
+
+def refuse_single_pass(cfg: ModelConfig, what: str) -> None:
+    """Raise `single_pass_unsupported`'s reason, if it has one."""
+    reason = single_pass_unsupported(cfg, what)
+    if reason is not None:
+        raise NotImplementedError(reason)
 
 
 def custom_engine_unsupported(cfg: ModelConfig) -> Optional[str]:
@@ -297,6 +351,9 @@ def custom_engine_unsupported(cfg: ModelConfig) -> Optional[str]:
     in runtime.batching's gemma2-aware layer pieces (batched engine);
     the remaining custom-math engines must refuse rather than silently
     drop them."""
+    looped = single_pass_unsupported(cfg, "this engine")
+    if looped is not None:
+        return looped
     if (cfg.post_norms or cfg.attn_softcap or cfg.query_scale
             or cfg.altern_window):
         return ("gemma2 semantics (sandwich norms / softcap / per-layer "

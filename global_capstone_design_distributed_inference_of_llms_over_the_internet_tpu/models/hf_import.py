@@ -158,6 +158,20 @@ def config_from_hf(hf_cfg) -> ModelConfig:
                 getattr(hf_cfg, "final_logit_softcapping", 0.0) or 0.0),
             sliding_window=int(getattr(hf_cfg, "sliding_window", 0) or 0),
             **common)
+    if mt == "ouro":
+        from .config import ouro_config
+
+        if getattr(hf_cfg, "use_sliding_window", False):
+            raise ValueError("ouro checkpoint uses sliding windows — "
+                             "unsupported (every published layer is "
+                             "full_attention)")
+        return ouro_config(
+            loop_steps=int(hf_cfg.total_ut_steps),
+            exit_threshold=float(
+                getattr(hf_cfg, "early_exit_threshold", 1.0)),
+            head_dim=int(getattr(hf_cfg, "head_dim", None)
+                         or hf_cfg.hidden_size // hf_cfg.num_attention_heads),
+            **common)
     if mt == "mixtral":
         cfg = mixtral_config(
             num_experts=hf_cfg.num_local_experts,
@@ -173,7 +187,7 @@ def config_from_hf(hf_cfg) -> ModelConfig:
     # Mirrors the reference's model_type guard (src/llama_partition.py:82-83).
     raise ValueError(
         f"unsupported model_type: {mt} "
-        "(expected gpt2/llama/mistral/mixtral/qwen2/gemma)")
+        "(expected gpt2/llama/mistral/mixtral/qwen2/gemma/ouro)")
 
 
 def _gpt2_layer(sd: Mapping[str, Any], i: int) -> Params:
@@ -211,7 +225,13 @@ def _llama_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
             "wo": _np(sd[pre + "self_attn.o_proj.weight"]).T,
         },
     }
-    if cfg.post_norms:
+    if cfg.model_type == "ouro":
+        # Sandwich norms under the looped family's names: "_2" is the norm
+        # AFTER the sublayer (ln3 after attention, ln4 after the MLP).
+        p["ln2"] = {"w": _np(sd[pre + "post_attention_layernorm.weight"])}
+        p["ln3"] = {"w": _np(sd[pre + "input_layernorm_2.weight"])}
+        p["ln4"] = {"w": _np(sd[pre + "post_attention_layernorm_2.weight"])}
+    elif cfg.post_norms:
         # gemma2 sandwich norms: HF's "post_attention_layernorm" is the
         # POST-attn norm (our ln3); the pre-MLP norm is
         # "pre_feedforward_layernorm" (our ln2).
@@ -312,6 +332,13 @@ def convert_state_dict(
             params["final_norm"] = {
                 "w": jnp.asarray(_np(sd["model.norm.weight"]), dtype)
             }
+        if cfg.loop_steps > 1:
+            # Linear(hidden, 1): [1, D] -> our [in, out].
+            params["exit_gate"] = {
+                "w": jnp.asarray(
+                    _np(sd["model.early_exit_gate.weight"]).T, dtype),
+                "b": jnp.asarray(
+                    _np(sd["model.early_exit_gate.bias"]), dtype)}
         if not cfg.tie_word_embeddings:
             head = sd.get("lm_head.weight")
             if head is not None:

@@ -23,7 +23,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .config import ModelConfig
+from .config import ModelConfig, refuse_single_pass
 from .transformer import (
     embed_tokens,
     init_kv_cache,
@@ -176,6 +176,8 @@ def slice_stage_params(cfg: ModelConfig, params: Params, spec: StageSpec) -> Par
             out["embed"] = {**out.get("embed", {}), "wte": params["embed"]["wte"]}
         else:
             out["lm_head"] = params["lm_head"]
+        if "exit_gate" in params:    # looped stack: read with the final norm
+            out["exit_gate"] = params["exit_gate"]
     return out
 
 
@@ -208,6 +210,7 @@ def stage_forward(
     added at each block's entry (``petals/server/block_functions.py:57-65,
     171-226`` — the ptune serving path).
     """
+    refuse_single_pass(cfg, "a stage's forward")
     if spec.is_first:
         b, t = inputs.shape
         positions = cache_len + jnp.arange(t, dtype=jnp.int32)[None, :]

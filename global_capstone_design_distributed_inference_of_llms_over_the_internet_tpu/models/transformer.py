@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from ..ops.attention import cached_attention, update_kv_cache
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
-from .config import ModelConfig
+from .config import ModelConfig, refuse_single_pass
 
 Params = Dict[str, Any]
 
@@ -133,6 +133,14 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     params: Params = {"embed": embed, "layers": layers, "final_norm": final_norm}
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"w": _dense(k_head, (cfg.hidden_size, cfg.vocab_size), dtype)}
+    if cfg.loop_steps > 1:
+        # Looped stack: the early-exit gate, Linear(hidden, 1), read on the
+        # normed state that closes every pass (`close_pass`).
+        params["exit_gate"] = {
+            "w": _dense(jax.random.fold_in(k_head, 1), (cfg.hidden_size, 1),
+                        dtype),
+            "b": jnp.zeros((1,), dtype),
+        }
     return params
 
 
@@ -628,6 +636,7 @@ def stack_forward_train(
     prompts: optional [L, pre_seq, D] deep-prompt-tuning tensors, ADDED into
     the first pre_seq positions of each layer's input (the vendored semantics,
     ``petals/server/block_functions.py:57-65``)."""
+    refuse_single_pass(cfg, "the training forward")
     rope = make_rope(cfg, positions)
 
     if prompts is None:
@@ -656,9 +665,13 @@ def stack_forward_train(
     return x
 
 
-def lm_head(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
-    """Final norm + projection to vocab. x: [B,T,D] -> [B,T,V] float32."""
-    x = _norm(cfg, params["final_norm"], x)
+def lm_head(cfg: ModelConfig, params: Params, x: jnp.ndarray,
+            normed: bool = False) -> jnp.ndarray:
+    """Final norm + projection to vocab. x: [B,T,D] -> [B,T,V] float32.
+    ``normed``: x already passed the final norm (a looped stack's state,
+    which `close_pass` normed at the end of its pass): projection only."""
+    if not normed:
+        x = _norm(cfg, params["final_norm"], x)
     if cfg.tie_word_embeddings:
         w = params["embed"]["wte"].T
     else:
@@ -673,8 +686,88 @@ def lm_head(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
 def init_kv_cache(
     cfg: ModelConfig, num_layers: int, batch: int, max_len: int, dtype=jnp.float32
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    shape = (num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    """Zeroed K and V stacks for ``num_layers`` layers of WEIGHTS: a looped
+    stack keeps rows of its own for every (pass, layer), pass-major, so its
+    stacks are ``cfg.loop_steps`` times as deep."""
+    shape = (num_layers * cfg.loop_steps, batch, max_len, cfg.num_kv_heads,
+             cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# Looped stack (cfg.loop_steps > 1): what closes a pass, and the oracle
+# ---------------------------------------------------------------------------
+
+def exit_state(h: jnp.ndarray):
+    """The exit rule's state before the first pass, for hidden ``h``
+    ``[B, T, D]``: (chosen state, the passes it took or 0 while none is
+    chosen, probability of still being in the loop, cumulative exit
+    probability), per token."""
+    b, t, _ = h.shape
+    return (jnp.zeros_like(h), jnp.zeros((b, t), jnp.int32),
+            jnp.ones((b, t), jnp.float32), jnp.zeros((b, t), jnp.float32))
+
+
+def close_pass(cfg: ModelConfig, params: Params, x: jnp.ndarray, t, state):
+    """What closes pass ``t`` (a Python int or a traced index) of a looped
+    stack: ``(h, state, gate)``. ``h`` is the model's final norm of the
+    pass's output ``x``, the next pass's input; ``gate`` the exit gate's
+    logit on it (float32, ``[B, T]``). The exit rule: with ``lambda =
+    sigmoid(gate)`` the probability of leaving at pass t is ``lambda_t *
+    prod_{j<t}(1 - lambda_j)``, and a token's state for the head is that of
+    the FIRST pass at which the cumulative probability reaches
+    ``cfg.exit_threshold``, else the last pass's. ``state`` (`exit_state`)
+    carries the choice; every pass still runs for every token."""
+    with jax.named_scope("loop_norm"):
+        h = _norm(cfg, params["final_norm"], x)
+    with jax.named_scope("exit_gate"):
+        gp = params["exit_gate"]
+        gate = (h.astype(jnp.float32) @ gp["w"].astype(jnp.float32)[:, 0]
+                + gp["b"].astype(jnp.float32)[0])
+        lam = jax.nn.sigmoid(gate)
+        chosen, steps, stay, cum = state
+        cum = cum + lam * stay
+        take = (steps == 0) & ((cum >= cfg.exit_threshold)
+                               | (t == cfg.loop_steps - 1))
+        chosen = jnp.where(take[..., None], h, chosen)
+        steps = jnp.where(take, jnp.asarray(t, jnp.int32) + 1, steps)
+        state = (chosen, steps, stay * (1.0 - lam), cum)
+    return h, state, gate
+
+
+def looped_forward(
+    cfg: ModelConfig,
+    params: Params,
+    input_ids: jnp.ndarray,
+    k_caches: jnp.ndarray,
+    v_caches: jnp.ndarray,
+    cache_len: jnp.ndarray,
+):
+    """The looped model's oracle, pass by pass in Python: ``(h, k_caches,
+    v_caches, gates, steps)`` with ``h`` the chosen NORMED state per token
+    (``lm_head(..., normed=True)`` projects it), ``gates`` ``[loop_steps,
+    B, T]`` and ``steps`` the passes each token took. The caches hold
+    ``loop_steps * num_layers`` layers, pass-major (`init_kv_cache`)."""
+    n = cfg.num_layers
+    if k_caches.shape[0] != cfg.loop_steps * n:
+        raise ValueError(
+            f"a looped stack of {n} layers x {cfg.loop_steps} passes needs "
+            f"{cfg.loop_steps * n} cache layers, got {k_caches.shape[0]} "
+            "(init_kv_cache sizes them)")
+    t = input_ids.shape[1]
+    positions = cache_len + jnp.arange(t, dtype=jnp.int32)[None, :]
+    h = embed_tokens(cfg, params["embed"], input_ids, positions)
+    state, gates, ks, vs = exit_state(h), [], [], []
+    for p in range(cfg.loop_steps):
+        x, kc, vc = stack_forward(
+            cfg, params["layers"], h, positions, k_caches[p * n:(p + 1) * n],
+            v_caches[p * n:(p + 1) * n], cache_len)
+        h, state, gate = close_pass(cfg, params, x, p, state)
+        gates.append(gate)
+        ks.append(kc)
+        vs.append(vc)
+    return (state[0], jnp.concatenate(ks), jnp.concatenate(vs),
+            jnp.stack(gates), state[1])
 
 
 def full_forward(
@@ -689,7 +782,16 @@ def full_forward(
     """Whole unpartitioned model (the single-device oracle path, mirroring
     reference ``scripts/single_gpu_check.py``). Returns (logits, new caches).
     prompts: optional [num_layers, pre_seq, D] deep prompts (the monolithic
-    oracle for the distributed inference-time injection)."""
+    oracle for the distributed inference-time injection). A looped stack
+    (``cfg.loop_steps > 1``) goes through `looped_forward`."""
+    if cfg.loop_steps > 1:
+        if prompts is not None:
+            raise NotImplementedError(
+                "deep prompts are per layer of ONE pass; a looped stack has "
+                "no rule for them")
+        h, k_caches, v_caches, _, _ = looped_forward(
+            cfg, params, input_ids, k_caches, v_caches, cache_len)
+        return lm_head(cfg, params, h, normed=True), k_caches, v_caches
     b, t = input_ids.shape
     positions = cache_len + jnp.arange(t, dtype=jnp.int32)[None, :]
     x = embed_tokens(cfg, params["embed"], input_ids, positions)

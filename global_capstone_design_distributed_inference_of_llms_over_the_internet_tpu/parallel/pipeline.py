@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..models.transformer import embed_tokens, lm_head, stack_forward
 
 Params = Dict[str, Any]
@@ -210,6 +210,7 @@ class IciPipeline:
         mesh: Optional[Mesh] = None,
         tp: int = 1,
     ) -> "IciPipeline":
+        refuse_single_pass(cfg, "the ICI pipeline")
         if tp > 1:
             from .tensor_parallel import validate_tp
 

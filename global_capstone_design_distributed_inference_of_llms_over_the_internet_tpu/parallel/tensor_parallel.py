@@ -90,6 +90,10 @@ REPLICATED_LEAVES = (
     (r"^window$",
      "per-layer attention-window vector is [L] int32 config state, not a "
      "weight — every rank needs the whole thing"),
+    (r"^exit_gate/(w|b)$",
+     "a looped stack's exit gate is [hidden, 1] + [1], read on the "
+     "replicated normed state that closes a pass, beside final_norm: O(d), "
+     "and every rank needs the same exit decision"),
 )
 
 

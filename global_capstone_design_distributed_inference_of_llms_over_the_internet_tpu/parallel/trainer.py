@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..models.transformer import embed_tokens, lm_head, stack_forward_train
 from .pipeline import (
     _pipeline_layer_specs,
@@ -309,6 +309,7 @@ class PipelineTrainer:
         weight_decay: float = 0.0,
         virtual_stages: int = 1,
     ) -> "PipelineTrainer":
+        refuse_single_pass(cfg, "the pipeline trainer")
         if tp > 1:
             from .tensor_parallel import validate_tp
 

@@ -5,9 +5,11 @@ The reference serves each session's decode step as its own forward
 clients cost N sequential forwards per token. On TPU the idiomatic fix is
 STATIC-SHAPE slot batching (the shape-stable cousin of vLLM-style
 continuous batching): the server owns one slot-major KV cache
-``[L, S, max_len, Hkv, Dh]``, every live session occupies a slot, and one
-jitted step advances EVERY active slot at once — per-slot cache lengths, an
-active mask for empty slots. The cache stacks are the step's loop carry:
+``[L, S, max_len, Hkv, Dh]`` (a looped stack's: ``loop_steps * L`` deep
+for L layers of weights, rows of its own for every pass: `_run_passes`),
+every live session occupies a slot, and one jitted step advances EVERY
+active slot at once — per-slot cache lengths, an active mask for empty
+slots. The cache stacks are the step's loop carry:
 each layer scatters its T new rows a slot into them at the slots' own
 lengths (`_append_rows`; an inactive slot rewrites the rows it holds) and
 attends over its ``[S, max_len, Hkv, Dh]`` rows read straight out of the
@@ -52,14 +54,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..utils.platform import engine_donation
 from ..models.partition import StageSpec
 from ..models.transformer import (
     _dot,
     _mlp,
     _norm,
+    close_pass,
     embed_tokens,
+    exit_state,
     make_rope,
     qkv_proj,
 )
@@ -256,7 +260,10 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     the WHOLE ``[L, S, max_len, Hkv, Dh]`` stacks and its own index ``i``,
     writes its new rows into them and reads what it attends over out of
     them, so XLA updates the carried buffers in place and no layer's
-    ``[S, max_len, Hkv, Dh]`` slab is ever the operand of an update.
+    ``[S, max_len, Hkv, Dh]`` slab is ever the operand of an update. ``i``
+    indexes the WEIGHTS (``0 .. layers - 1``); which cache layer a weight
+    layer writes is the caller's (`_run_passes`: a looped stack's cache is
+    ``loop_steps`` times as deep as its weights).
 
     Two forms this replaced, and what each cost on the v5e. As scan xs/ys
     the stacks are rewritten into a second buffer every step, input and
@@ -277,10 +284,45 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
             h, (_layer_at(lp, held, i), i, k_all, v_all))
         return (h, k_all, v_all), None
 
+    n = jax.tree.leaves(layers)[0].shape[0]
     (h, k_all, v_all), _ = jax.lax.scan(
-        body, (h, k_all, v_all),
-        (rest, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
+        body, (h, k_all, v_all), (rest, jnp.arange(n, dtype=jnp.int32)))
     return h, k_all, v_all
+
+
+def _run_passes(cfg, params, h, one_pass, k_all, v_all):
+    """The span's layers over ``h``, as often as the model runs them:
+    ``(h, k_all, v_all, steps)``. ``one_pass(h, base, k_all, v_all) -> (h,
+    k_all, v_all)`` is a program's scan over the layers' weights with its
+    cache policy; weight layer ``i`` of it reads and writes cache layer
+    ``base + i``. A stack that runs once gets ``base`` None (cache layer ==
+    weight layer, the program every family had before there was a loop)
+    and ``steps`` None. A looped stack (``cfg.loop_steps`` passes) scans
+    the passes over the SAME stacked weights, pass ``t`` at ``base = t *
+    layers`` of the ``loop_steps * layers`` deep stacks, `close_pass`
+    after each (the final norm that feeds the next pass, the exit gate and
+    rule): ``h`` is then each token's chosen, already NORMED state and
+    ``steps`` ``[B, T]`` the passes it took."""
+    if cfg.loop_steps == 1:
+        return (*one_pass(h, None, k_all, v_all), None)
+    per_pass = k_all.shape[0] // cfg.loop_steps
+
+    def body(carry, t):
+        h, k_all, v_all, state = carry
+        x, k_all, v_all = one_pass(h, t * per_pass, k_all, v_all)
+        h, state, _ = close_pass(cfg, params, x, t, state)
+        return (h, k_all, v_all, state), None
+
+    (_, k_all, v_all, state), _ = jax.lax.scan(
+        body, (h, k_all, v_all, exit_state(h)),
+        jnp.arange(cfg.loop_steps, dtype=jnp.int32))
+    return state[0], k_all, v_all, state[1]
+
+
+def _at(base, i):
+    """Cache layer of weight layer ``i`` in the pass that starts at cache
+    layer ``base`` (None: the stack runs once, the two are one index)."""
+    return i if base is None else base + i
 
 
 def _append_rows(stack, i, new, lengths, active):
@@ -320,7 +362,8 @@ def _append_rows(stack, i, new, lengths, active):
 
 def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
                  k_all, v_all):
-    """The span's layers over ``T`` new tokens a slot: ``(h, k_all, v_all)``.
+    """The span's layers over ``T`` new tokens a slot: ``(h, k_all, v_all,
+    steps)`` as `_run_passes` gives them.
     The body of the decode step (T = 1 plain, T = K+1 speculative verify:
     the draft block enters as new tokens, causal within itself) and, at
     T = 1, of every burst tick. ``x``: ids ``[S, T]`` or hidden ``[S, T, D]``
@@ -340,29 +383,35 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
         # Window spans (qpos - window, qpos].
         allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
 
-    def layer(h, xs):
-        lp, i, k_all, v_all = xs
+    def one_pass(h, base, k_all, v_all):
+        def layer(h, xs):
+            lp, i, k_all, v_all = xs
+            at = _at(base, i)
 
-        def per_slot_append(k, v):
-            # Write the T new rows a slot into the stacks, THEN read this
-            # layer's keys and values out of them: the read's only
-            # consumers are the two attention products.
-            with jax.named_scope("kv_update"):
-                k_new = _append_rows(
-                    k_all, i, k.astype(k_all.dtype), lengths, active)
-                v_new = _append_rows(
-                    v_all, i, v.astype(v_all.dtype), lengths, active)
-            with jax.named_scope("attention"):
-                keys = jax.lax.dynamic_index_in_dim(
-                    k_new, i, 0, keepdims=False)
-                values = jax.lax.dynamic_index_in_dim(
-                    v_new, i, 0, keepdims=False)
-            return (keys, values, (allowed, qpos, pos_grid[None, None, :]),
-                    (k_new, v_new))
+            def per_slot_append(k, v):
+                # Write the T new rows a slot into the stacks, THEN read
+                # this layer's keys and values out of them: the read's
+                # only consumers are the two attention products.
+                with jax.named_scope("kv_update"):
+                    k_new = _append_rows(
+                        k_all, at, k.astype(k_all.dtype), lengths, active)
+                    v_new = _append_rows(
+                        v_all, at, v.astype(v_all.dtype), lengths, active)
+                with jax.named_scope("attention"):
+                    keys = jax.lax.dynamic_index_in_dim(
+                        k_new, at, 0, keepdims=False)
+                    values = jax.lax.dynamic_index_in_dim(
+                        v_new, at, 0, keepdims=False)
+                return (keys, values,
+                        (allowed, qpos, pos_grid[None, None, :]),
+                        (k_new, v_new))
 
-        return _decoder_layer(cfg, lp, h, rope, per_slot_append)
+            return _decoder_layer(cfg, lp, h, rope, per_slot_append)
 
-    return _scan_layers_in_place(layer, h, params["layers"], k_all, v_all)
+        return _scan_layers_in_place(
+            layer, h, params["layers"], k_all, v_all)
+
+    return _run_passes(cfg, params, h, one_pass, k_all, v_all)
 
 
 class BatchedStageExecutor:
@@ -379,7 +428,11 @@ class BatchedStageExecutor:
         dtype=jnp.float32,
         prefix_cache_bytes: int = 0,
         model: Optional[str] = None,
+        consume_params: bool = False,
     ):
+        if not (spec.is_first and spec.is_last):
+            refuse_single_pass(cfg, "a batched engine over part of the "
+                                    "stack")
         self.cfg = cfg
         self.spec = spec
         # Model tag for prefix-store digest coords: two models with the same
@@ -389,14 +442,22 @@ class BatchedStageExecutor:
         # bitwise-identical — models/transformer.fuse_qkv_params).
         from ..models.transformer import fuse_qkv_params
 
-        self.params = params = fuse_qkv_params(params)
+        self.params = fuse_qkv_params(params)
+        if consume_params:
+            # The caller hands its tree over (a server's: nothing else
+            # reads it). The projection stacks that the fused copies
+            # replace would stay resident beside them for the process's
+            # life (q, k, v and gate, up: 3.4 GB of ouro-2.6b's 5.3), so
+            # they go now, before the cache stacks are allocated.
+            held = {id(x) for x in jax.tree.leaves(self.params)}
+            for leaf in jax.tree.leaves(params):
+                if isinstance(leaf, jax.Array) and id(leaf) not in held:
+                    leaf.delete()
+        del params
         self.slots = slots
         self.max_len = max_len
         self.dtype = jnp.dtype(dtype)
-        l = max(spec.num_layers, 1)
-        shape = (l, slots, max_len, cfg.num_kv_heads, cfg.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        self._new_stacks()
         self.lengths = np.zeros((slots,), np.int32)   # host-side truth
         self._slot_of: Dict[str, int] = {}
         self._free: List[int] = list(range(slots))
@@ -411,6 +472,7 @@ class BatchedStageExecutor:
         self._m_burst_disp = _tm.get("server_burst_dispatches_total")
         self._m_burst_toks = _tm.get("server_burst_tokens_total")
         self._m_sampler = _tm.get("server_sampler_rounds_total")
+        self._m_exit_steps = _tm.get("server_loop_exit_steps_total")
         # Prompt-prefix KV reuse (runtime.prefix_cache), slot-layout
         # variant: entries hold [L, G, Hkv, Dh] KV segments (+ [1, G, D]
         # output rows off the final stage). Same grain-chained rolling
@@ -423,6 +485,16 @@ class BatchedStageExecutor:
         self._suffix_jit = None
         self._chain_write_jit = None
         self._grain_split_jits: Dict[tuple, Any] = {}
+
+    def _new_stacks(self) -> None:
+        """Zeroed K and V stacks ``[loop_steps * L, S, max_len, Hkv, Dh]``:
+        rows of its own for every (pass, layer), pass-major."""
+        shape = (max(self.spec.num_layers, 1) * self.cfg.loop_steps,
+                 self.slots, self.max_len, self.cfg.num_kv_heads,
+                 self.cfg.head_dim)
+        self.k = jnp.zeros(shape, self.dtype)
+        self.v = jnp.zeros(shape, self.dtype)
+        _tm.get("server_kv_stack_bytes").set(self.k.nbytes + self.v.nbytes)
 
     # ------------------------------------------------------------------
     # Slots
@@ -498,16 +570,19 @@ class BatchedStageExecutor:
                 h, (k, v) = _decoder_layer(cfg, lp, h, rope, fresh_prompt)
                 return h, (k[0], v[0])
 
-            h, (ks, vs) = _scan_layers(layer, h, params["layers"])
-            # ks/vs: [L, T, Hkv, Dh] -> write rows [slot, 0:T).
-            with jax.named_scope("kv_update"):
-                k_all = jax.lax.dynamic_update_slice(
-                    k_all, ks[:, None].astype(k_all.dtype),
-                    (0, slot, 0, 0, 0))
-                v_all = jax.lax.dynamic_update_slice(
-                    v_all, vs[:, None].astype(v_all.dtype),
-                    (0, slot, 0, 0, 0))
-            return h, k_all, v_all
+            def one_pass(h, base, k_all, v_all):
+                h, (ks, vs) = _scan_layers(layer, h, params["layers"])
+                # ks/vs: [L, T, Hkv, Dh] -> write rows [slot, 0:T).
+                with jax.named_scope("kv_update"):
+                    k_all = jax.lax.dynamic_update_slice(
+                        k_all, ks[:, None].astype(k_all.dtype),
+                        (_at(base, 0), slot, 0, 0, 0))
+                    v_all = jax.lax.dynamic_update_slice(
+                        v_all, vs[:, None].astype(v_all.dtype),
+                        (_at(base, 0), slot, 0, 0, 0))
+                return h, k_all, v_all
+
+            return _run_passes(cfg, params, h, one_pass, k_all, v_all)[:3]
 
         return prefill
 
@@ -549,15 +624,23 @@ class BatchedStageExecutor:
 
                 return _decoder_layer(cfg, lp, h, rope, slot_continuation)
 
-            h, (ks, vs) = _scan_layers(
-                layer, h, params["layers"], k_slot, v_slot)
-            with jax.named_scope("kv_update"):
-                k_all = jax.lax.dynamic_update_slice(
-                    k_all, ks, (0, slot, 0, 0, 0))
-                v_all = jax.lax.dynamic_update_slice(
-                    v_all, vs, (0, slot, 0, 0, 0))
+            def one_pass(h, base, k_all, v_all):
+                k_in, v_in = k_slot, v_slot
+                if base is not None:       # this pass's layers of the slot
+                    n = k_slot.shape[0] // cfg.loop_steps
+                    k_in = jax.lax.dynamic_slice_in_dim(k_slot, base, n, 0)
+                    v_in = jax.lax.dynamic_slice_in_dim(v_slot, base, n, 0)
+                h, (ks, vs) = _scan_layers(
+                    layer, h, params["layers"], k_in, v_in)
+                with jax.named_scope("kv_update"):
+                    k_all = jax.lax.dynamic_update_slice(
+                        k_all, ks, (_at(base, 0), slot, 0, 0, 0))
+                    v_all = jax.lax.dynamic_update_slice(
+                        v_all, vs, (_at(base, 0), slot, 0, 0, 0))
+                return h, k_all, v_all
+
             del t_real  # mask correctness needs only qpos; kept for parity
-            return h, k_all, v_all
+            return _run_passes(cfg, params, h, one_pass, k_all, v_all)[:3]
 
         return prefill_suffix
 
@@ -698,10 +781,7 @@ class BatchedStageExecutor:
         self.lengths[s] = 0
         self._free.append(s)
         if getattr(self.k, "is_deleted", lambda: False)():
-            shape = (max(self.spec.num_layers, 1), self.slots, self.max_len,
-                     self.cfg.num_kv_heads, self.cfg.head_dim)
-            self.k = jnp.zeros(shape, self.dtype)
-            self.v = jnp.zeros(shape, self.dtype)
+            self._new_stacks()
             self._slot_of.clear()
             self.lengths[:] = 0
             self._free = list(range(self.slots))
@@ -747,7 +827,7 @@ class BatchedStageExecutor:
             offs = jnp.arange(t_step, dtype=jnp.int32)
             positions = lengths[:, None] + offs[None, :]       # [S, T]
             pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
-            h, k_all, v_all = _decode_span(
+            h, k_all, v_all, _ = _decode_span(
                 cfg, spec, params, x, positions, pos_grid, lengths, active,
                 k_all, v_all)
             # Inactive slots produced garbage — zero them so nothing
@@ -835,8 +915,15 @@ class BatchedStageExecutor:
         Host stop rules are mirrored ON DEVICE, in the host's order (cap
         via the ``left`` budget counter, then eos, then the 5-run repeat
         heuristic), so the emitted count per slot always matches what the
-        sequential client would have accepted."""
+        sequential client would have accepted.
+
+        A looped stack's program (``cfg.loop_steps > 1``) carries one more
+        value through the ticks and returns it LAST: the passes taken by
+        the tokens the burst emitted, summed on the device
+        (``server_loop_exit_steps_total``). A program whose stack runs once
+        has the arguments and results it always had."""
         cfg, spec = self.cfg, self.spec
+        looped = cfg.loop_steps > 1
         S = self.slots
         N = n_ticks
         from ..models.transformer import lm_head
@@ -851,14 +938,15 @@ class BatchedStageExecutor:
 
             def tick(carry, i):
                 (tok, lengths, alive, recent, nvalid, run, left,
-                 stop, k_all, v_all) = carry
+                 stop, k_all, v_all, *passes) = carry
                 active = alive
-                h, k_all, v_all = _decode_span(
+                h, k_all, v_all, steps = _decode_span(
                     cfg, spec, params, tok[:, None], lengths[:, None],
                     pos_grid, lengths, active, k_all, v_all)
                 with jax.named_scope("head"):
                     h = jnp.where(active[:, None, None], h, 0.0)
-                    logits = lm_head(cfg, params, h)[:, 0]    # [S, V] fp32
+                    logits = lm_head(cfg, params, h,
+                                     normed=looped)[:, 0]     # [S, V] fp32
                 with jax.named_scope("sampler"):
                     keys = jax.vmap(jax.random.PRNGKey)(seeds + i)
                     sampled = sample_tokens(
@@ -886,22 +974,25 @@ class BatchedStageExecutor:
                     alive = active & ~eos_hit & ~rep_hit & (left_next > 0)
                     tok = jnp.where(active, sampled, tok)
                     out_tok = jnp.where(active, sampled, jnp.int32(-1))
+                    if looped:
+                        passes = [passes[0] + jnp.sum(
+                            jnp.where(active, steps[:, 0], 0))]
                 return (tok, lengths, alive, recent, nvalid, run_next,
-                        left_next, stop, k_all, v_all), out_tok
+                        left_next, stop, k_all, v_all, *passes), out_tok
 
             stop0 = jnp.zeros((S,), jnp.int32)
             carry, toks = jax.lax.scan(
                 tick,
                 (tok, lengths, alive, recent, nvalid, run, left, stop0,
-                 k_all, v_all),
+                 k_all, v_all, *([jnp.int32(0)] if looped else [])),
                 jnp.arange(N, dtype=jnp.int32))
             (tok, lengths, alive, recent, nvalid, run, left, stop,
-             k_all, v_all) = carry
+             k_all, v_all, *passes) = carry
             # Seed base for a CONTINUATION burst: one key was consumed per
             # emitted token (emitted ticks are a prefix of the scan).
             seeds = seeds + (lengths - len0)
             return (toks, stop, tok, lengths, alive, seeds, recent, nvalid,
-                    run, left, k_all, v_all)
+                    run, left, k_all, v_all, *passes)
 
         return burst_tick
 
@@ -985,8 +1076,11 @@ class BatchedStageExecutor:
     _BURST_STOPS = {0: None, 1: "eos", 2: "repeat"}
 
     def _burst_collect(self, rows: Dict[str, int], toks, stop,
-                       lengths_new) -> Dict[str, dict]:
-        """Read one burst's results back (the only host sync per burst)."""
+                       lengths_new, passes=()) -> Dict[str, dict]:
+        """Read one burst's results back (the only host sync per burst).
+        ``passes``: a looped stack's one extra result (`_build_burst`)."""
+        for n in passes:
+            self._m_exit_steps.inc(int(n))
         toks_np = np.asarray(toks)            # [N, S]
         stop_np = np.asarray(stop)
         len_np = np.asarray(lengths_new)
@@ -1027,13 +1121,14 @@ class BatchedStageExecutor:
                 jax.block_until_ready(out)
         toks, stop = out[0], out[1]
         lengths_new = out[3]
-        self.k, self.v = out[-2], out[-1]
+        self.k, self.v = out[10], out[11]
         self.decode_steps += 1
         self.burst_dispatches += 1
         self._m_burst_disp.inc()
         self._m_burst_ticks.observe(n_ticks)
         with prof.phase("readback", sessions=n):
-            return self._burst_collect(rows, toks, stop, lengths_new)
+            return self._burst_collect(rows, toks, stop, lengths_new,
+                                       out[12:])
 
     # ------------------------------------------------------------------
 
@@ -1041,7 +1136,8 @@ class BatchedStageExecutor:
         """Final-stage head over [1, T, D] -> [1, T, V] (fp32)."""
         from ..models.transformer import lm_head
 
-        return lm_head(self.cfg, self.params, hidden)
+        return lm_head(self.cfg, self.params, hidden,
+                       normed=self.cfg.loop_steps > 1)
 
 
 # ---------------------------------------------------------------------------

@@ -37,7 +37,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..models.partition import StagePlan, StageSpec
 from ..ops.sampling import SamplingParams
 from ..scheduling.registry import PlacementRegistry, ServerRecord
@@ -1435,6 +1435,8 @@ class PipelineClient:
         draft_fn,
         deadline_at: Optional[float] = None,
     ) -> Iterator[GenerationStep]:
+        # The classic chain visits each stage's span ONCE a token.
+        refuse_single_pass(self.cfg, "a route split over stages")
         sampling = sampling or SamplingParams()
         prompt_len = len(prompt_ids)
         dp = self._session_prompts.get(session_id)

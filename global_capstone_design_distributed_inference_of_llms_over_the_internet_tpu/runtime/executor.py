@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..models.partition import (
     ROLE_FULL,
     ROLE_LAST,
@@ -196,6 +196,7 @@ class StageExecutor:
         tp_axis: str = "tp",
         prefix_cache_bytes: int = 0,
     ):
+        refuse_single_pass(cfg, "the per-session executor")
         self.cfg = cfg
         self.spec = spec
         self.params = params

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..models.transformer import embed_tokens, lm_head, stack_forward_train
 from ..parallel.trainer import adamw_init, adamw_update, softmax_xent
 from .client import NoRouteError, PipelineClient
@@ -80,6 +80,7 @@ class DistributedFineTuner:
         lora_targets=None,
         seed: int = 0,
     ):
+        refuse_single_pass(cfg, "distributed fine-tuning")
         self.cfg = cfg
         self.client = client
         self.pre_seq = pre_seq

@@ -42,7 +42,7 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..models.transformer import _norm, embed_tokens, lm_head, stack_forward
 from ..ops.sampling import RECENT_WINDOW, push_recent, sample_token
 
@@ -79,6 +79,7 @@ def make_fused_decode(cfg: ModelConfig, max_steps: int, batch: int,
     head). The oracle baseline uses it; the default is the fast weight-dtype
     head (the measured ~1.5x).
     """
+    refuse_single_pass(cfg, "the fused decode engine")
     L = cfg.num_layers
 
     def head_argmax(params, h):
@@ -133,6 +134,7 @@ def make_fused_sample_decode(cfg: ModelConfig, max_steps: int):
     (toks, kc, vc, recent, nvalid)`` — recent/nvalid thread across chunked
     calls so stop-condition checks between chunks don't reset the window.
     """
+    refuse_single_pass(cfg, "the fused decode engine")
 
     @partial(jax.jit, donate_argnums=(2, 3))
     def fn(params, tok, kc, vc, start, n, seed0, recent, nvalid,

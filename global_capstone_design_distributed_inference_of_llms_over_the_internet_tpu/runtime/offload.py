@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ..utils.platform import engine_donation
-from ..models.config import ModelConfig
+from ..models.config import ModelConfig, refuse_single_pass
 from ..models.partition import StageSpec
 from ..models.transformer import (
     _apply_deep_prompt,
@@ -61,6 +61,7 @@ class OffloadedSpanRunner:
         host_device: Optional[jax.Device] = None,
         compute_device: Optional[jax.Device] = None,
     ):
+        refuse_single_pass(cfg, "the offloaded span runner")
         self.cfg = cfg
         self.spec = spec
         self.keep_resident = min(max(keep_resident, 0), spec.num_layers)

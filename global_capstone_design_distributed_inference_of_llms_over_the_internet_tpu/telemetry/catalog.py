@@ -201,6 +201,16 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "server_burst_dispatches_total by this for "
                  "dispatches-per-token (the amortization the burst engine "
                  "exists to win).", (), None),
+    "server_loop_exit_steps_total": (
+        COUNTER, "Looped stacks only: passes taken by the tokens burst "
+                 "dispatches emitted (the pass whose state went to the "
+                 "head, counted from 1), summed ON THE DEVICE inside the "
+                 "burst program; divide by server_burst_tokens_total for "
+                 "passes per token.", (), None),
+    "server_kv_stack_bytes": (
+        GAUGE, "Bytes of the batched engine's resident K and V cache "
+               "stacks (both together; a looped stack holds rows for "
+               "every pass of every layer).", (), None),
     "server_burst_ticks": (
         HISTOGRAM, "Configured tick count per burst dispatch (the N of "
                    "each lax.scan program).", (), FILL_BUCKETS),

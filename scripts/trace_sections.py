@@ -36,8 +36,8 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-SCOPES = ("embed", "attention", "kv_update", "mlp", "head", "sampler",
-          "stop_rules")
+SCOPES = ("embed", "attention", "kv_update", "mlp", "loop_norm",
+          "exit_gate", "head", "sampler", "stop_rules")
 SCOPE_RE = re.compile(r"(?:^|/)(%s)(?=/|$)" % "|".join(SCOPES))
 TICK_RE = re.compile(r"^jit_burst_tick")
 # Host events at least this long are kept, to show where a request's

@@ -967,7 +967,8 @@ def slab_policy_decode_span(cfg, spec, params, x, positions, pos_grid,
                             lengths, active, k_all, v_all):
     """`runtime.batching._decode_span` with the slab's round trip as it
     was: slab out of the stack, rows appended to the SLAB, attention over
-    the new slab, slab written back. Same signature, same
+    the new slab, slab written back. Same signature and results (a stack
+    that runs once: `_run_passes`'s ``steps`` is None), same
     `_decoder_layer`, same `_append_rows` (on a stack of one layer)."""
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
         batching as B,
@@ -1004,7 +1005,7 @@ def slab_policy_decode_span(cfg, spec, params, x, positions, pos_grid,
     (h, k_all, v_all), _ = jax.lax.scan(
         body, (h, k_all, v_all),
         (rest, jnp.arange(k_all.shape[0], dtype=jnp.int32)))
-    return h, k_all, v_all
+    return h, k_all, v_all, None    # one pass: no passes to count
 
 
 def bits(a):

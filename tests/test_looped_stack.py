@@ -1,0 +1,598 @@
+"""A looped stack (``ModelConfig.loop_steps > 1``: the same layers run
+several times a token over one copy of their weights, a K/V cache of its
+own for every pass) on the batched stage engine, against the benchmark's
+plain reference of the family (``perfbench/references/ouro_plain.py``: no
+cache, no pass index, nothing of the program).
+
+Tiny sizes with L = 3 layers and T = 4 passes, unlike on purpose: a cache
+index built from the wrong one of the two shows."""
+
+import dataclasses
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+    config as config_mod,
+    full_forward,
+    init_kv_cache,
+    init_params,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.hf_import import (
+    config_from_hf,
+    convert_state_dict,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
+    StagePlan,
+    slice_stage_params,
+    stage_forward,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
+    quantize_params,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
+    looped_forward,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
+    batching,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
+    BatchedStageExecutor,
+)
+from perfbench.harness.manifest import load_module
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LAYERS, PASSES, VOCAB = 3, 4, 97
+HF = {"model_type": "ouro", "hidden_size": 64, "intermediate_size": 96,
+      "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+      "num_hidden_layers": LAYERS, "vocab_size": VOCAB,
+      "max_position_embeddings": 128, "rms_norm_eps": 1e-6,
+      "rope_theta": 1000000, "rope_scaling": None,
+      "tie_word_embeddings": False, "total_ut_steps": PASSES,
+      "early_exit_threshold": 1, "use_sliding_window": False,
+      "sliding_window": None}
+
+# How far the engine may lie from the float32 reference, as relative RMS
+# over a row of logits. float32: rounding only. bfloat16: 12 layer-visits
+# of bf16 activations and cache rows, each sublayer re-scaled by its
+# sandwich norm (the CPU reads 0.02-0.04 at these sizes). int8: weight-only
+# quantisation with a scale per output column; at K = 64 a column's 64
+# weights share one scale, so a matrix adds ~1.5% (the CPU reads 0.05-0.07
+# over the 12 layer-visits). A wrong cache layer reads 0.3 and more (the
+# two tests below that break the index on purpose).
+TOLERANCE = {"float32": 1e-4, "bfloat16": 8e-2, "int8": 0.12}
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return load_module(os.path.join(ROOT, "perfbench/references/"
+                                          "ouro_plain.py"))
+
+
+def program_config(hf=HF):
+    return config_from_hf(types.SimpleNamespace(**hf))
+
+
+def build(ref, kind="float32", hf=HF, seed=5, gate_bias=None,
+          gate_scale=1.0, **engine_kw):
+    """(cfg, weights, engine) at the tiny sizes: the reference's seeded
+    checkpoint through the program's importer."""
+    dtype = jnp.bfloat16 if kind == "bfloat16" else jnp.float32
+    weights = dict(ref.make_weights(hf, LAYERS, seed, dtype))
+    if gate_bias is not None:
+        weights["model.early_exit_gate.bias"] = jnp.full((1,), gate_bias,
+                                                         dtype)
+    weights["model.early_exit_gate.weight"] = (
+        weights["model.early_exit_gate.weight"] * gate_scale).astype(dtype)
+    cfg = program_config(hf)
+    params = convert_state_dict(cfg, weights, dtype=dtype)
+    if kind == "int8":
+        params = quantize_params(params, "int8")
+    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
+    eng = BatchedStageExecutor(cfg, spec, params, slots=3, max_len=64,
+                               dtype=dtype, **engine_kw)
+    return cfg, weights, eng
+
+
+def rel_rms(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+def ids_of(n, seed=0):
+    return np.random.default_rng(seed).integers(0, VOCAB, (n,)).astype(
+        np.int32)
+
+
+def reference_logits(ref, weights, ids, hf=HF):
+    return np.asarray(ref.forward(hf, LAYERS, weights, jnp.asarray(ids)))
+
+
+def burst_entry(token, budget, generated=None):
+    return {"token": int(token), "seed": 0, "budget": budget, "eos": None,
+            "generated": generated or (int(token),), "temperature": 0.0,
+            "top_p": 1.0, "top_k": 0, "repetition_penalty": 1.0}
+
+
+# -- the five ways rows reach the cache, each against the reference ----------
+
+def drive_prefill_decode(eng, ids):
+    """prefill 12, then 5 single steps through the cache: rows 0..16."""
+    h = eng.prefill("s", ids[None, :12])
+    rows = [np.asarray(eng.logits(h))[0]]
+    for j in range(12, 17):
+        out = eng.decode_batch({"s": ids[None, j:j + 1]})
+        rows.append(np.asarray(eng.logits(out["s"]))[0])
+    return np.concatenate(rows), 0
+
+
+def drive_verify_step(eng, ids):
+    """prefill 10, one speculative verify step of K + 1 = 5 tokens."""
+    eng.prefill("s", ids[None, :10])
+    out = eng.decode_batch({"s": ids[None, 10:15]})
+    return np.asarray(eng.logits(out["s"]))[0], 10
+
+
+def drive_rewind(eng, ids):
+    """prefill 10, a 5-token step, rewind past 3 of them, then the rows
+    again one by one: the second visit overwrites every pass's rows."""
+    eng.prefill("s", ids[None, :10])
+    eng.decode_batch({"s": np.asarray([[1, 2, 3, 4, 5]], np.int32)})
+    eng.rewind("s", 10)
+    rows = []
+    for j in range(10, 15):
+        out = eng.decode_batch({"s": ids[None, j:j + 1]})
+        rows.append(np.asarray(eng.logits(out["s"]))[0])
+    return np.concatenate(rows), 10
+
+
+def drive_suffix_prefill(eng, ids):
+    """A prefix-store hit: session a registers the first 8 ids' grains,
+    session b shares them and prefills only its suffix over the copied
+    rows of EVERY pass."""
+    other = ids.copy()
+    other[8:] = (other[8:] + 1) % VOCAB
+    eng.prefill("a", other[None, :14], prefix_len=8)
+    h = eng.prefill("b", ids[None, :14], prefix_len=8)
+    assert eng.prefix_store.hits > 0
+    out = eng.decode_batch({"b": ids[None, 14:15]})
+    return np.concatenate([np.asarray(eng.logits(h))[0],
+                           np.asarray(eng.logits(out["b"]))[0]]), 8
+
+
+DRIVES = {"prefill_decode": drive_prefill_decode,
+          "verify_step": drive_verify_step, "rewind": drive_rewind,
+          "suffix_prefill": drive_suffix_prefill}
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("path", sorted(DRIVES))
+def test_engine_rows_match_the_reference(ref, path, kind):
+    kw = ({"prefix_cache_bytes": 1 << 22} if path == "suffix_prefill"
+          else {})
+    _, weights, eng = build(ref, kind, **kw)
+    if path == "suffix_prefill":
+        eng.prefix_store.grain = 4
+    ids = ids_of(20)
+    got, first = DRIVES[path](eng, ids)
+    want = reference_logits(ref, weights, ids)[first:first + len(got)]
+    worst = max(rel_rms(g, w) for g, w in zip(got, want))
+    assert worst <= TOLERANCE[kind], (path, kind, worst)
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+def test_the_16_tick_burst_emits_the_reference_s_tokens(ref, kind):
+    """Greedy, two sessions of unlike lengths in one program: every token
+    the burst emits is judged on the reference's row at its position (the
+    benchmark's burst_gap)."""
+    _, weights, eng = build(ref, kind)
+    seqs = {"a": ids_of(9, 1), "b": ids_of(13, 2)}
+    for sid, ids in seqs.items():
+        eng.prefill(sid, ids[None, :-1])
+    res = eng.decode_burst({sid: burst_entry(ids[-1], 16)
+                            for sid, ids in seqs.items()}, 16)
+    for sid, ids in seqs.items():
+        toks = res[sid]["tokens"]
+        assert len(toks) >= 5       # a greedy repeat may stop it, no sooner
+        consumed = np.concatenate([ids, toks[:-1]]).astype(np.int32)
+        want = reference_logits(ref, weights, consumed)[len(ids) - 1:]
+        gaps = [(row.max() - row[t]) / np.sqrt((row * row).mean())
+                for row, t in zip(want, toks)]
+        assert np.mean(gaps) <= {"float32": 1e-5, "bfloat16": 0.05,
+                                 "int8": 0.05}[kind], (kind, gaps)
+
+
+def test_burst_returns_the_passes_only_for_a_looped_stack(ref):
+    """The looped program's last result is the passes its tokens took;
+    a one-pass program has the twelve results it always had."""
+    _, _, eng = build(ref)
+    eng.prefill("s", ids_of(6)[None])
+    rows, args = eng._burst_prep({"s": burst_entry(3, 4)}, 4)
+    out = eng._get_burst_jit(4)(eng.params, *args, eng.k, eng.v)
+    assert len(out) == 13 and int(out[12]) == PASSES * 4
+    _, _, once = build(ref, hf=dict(HF, total_ut_steps=1))
+    once.prefill("s", ids_of(6)[None])
+    rows, args = once._burst_prep({"s": burst_entry(3, 4)}, 4)
+    assert len(once._get_burst_jit(4)(once.params, *args, once.k,
+                                      once.v)) == 12
+
+
+# -- the gate and the exit rule ----------------------------------------------
+
+def test_gates_match_the_reference(ref):
+    cfg, weights, eng = build(ref)
+    ids = ids_of(18)
+    kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, 32)
+    assert kc.shape[0] == PASSES * LAYERS
+    _, _, _, gates, steps = looped_forward(
+        cfg, eng.params, jnp.asarray(ids[None]), kc, vc, jnp.int32(0))
+    _, want = ref.passes(HF, LAYERS, weights, jnp.asarray(ids))
+    assert gates.shape == (PASSES, 1, 18)
+    np.testing.assert_allclose(np.asarray(gates[:, 0]), np.asarray(want),
+                               atol=2e-5)
+    assert np.asarray(steps).tolist() == [[PASSES] * 18]
+
+
+@pytest.mark.parametrize("bias", [0.0, -1.0, 3.0])
+def test_a_forced_early_exit_chooses_the_reference_s_pass(ref, bias):
+    """Threshold 0.5, the gate's weight drawn 5 x wider and its bias set so
+    that tokens leave at different passes (0.0: at the first or the
+    second; -1.0: later; 3.0: all at the first). The engine's logits are
+    the reference's, which picks each token's pass itself, through
+    prefill, decode and the burst, and the passes the burst counts on the
+    device are the reference's."""
+    hf = dict(HF, early_exit_threshold=0.5)
+    cfg, weights, eng = build(ref, hf=hf, gate_bias=bias, gate_scale=5.0)
+    assert cfg.exit_threshold == 0.5
+    ids = ids_of(20, 3)
+    _, gates = ref.passes(hf, LAYERS, weights, jnp.asarray(ids))
+    at = np.asarray(ref.exit_pass(hf, gates))
+    assert len(set(at.tolist())) >= (1 if bias == 3.0 else 2), at
+    got, _ = drive_prefill_decode(eng, ids)
+    want = reference_logits(ref, weights, ids, hf)[:len(got)]
+    assert max(rel_rms(g, w) for g, w in zip(got, want)) <= 1e-4
+    # and NOT the last pass's logits, where the reference left earlier
+    last = reference_logits(ref, weights, ids,
+                            dict(hf, early_exit_threshold=2.0))[:len(got)]
+    early = at[:len(got)] < PASSES - 1
+    assert early.any()
+    assert min(rel_rms(g, w) for g, w in zip(got[early], last[early])) > 1e-3
+    # the burst, straight from its program: greedy tokens, passes counted
+    eng.rewind("s", 12)
+    rows, args = eng._burst_prep({"s": burst_entry(ids[12], 6)}, 6)
+    out = eng._get_burst_jit(6)(eng.params, *args, eng.k, eng.v)
+    eng.k, eng.v = out[10], out[11]
+    toks = np.asarray(out[0])[:, rows["s"]]
+    toks = toks[toks >= 0]              # a greedy repeat may end it early
+    consumed = np.concatenate([ids[:13], toks[:-1]]).astype(np.int32)
+    _, g2 = ref.passes(hf, LAYERS, weights, jnp.asarray(consumed))
+    at2 = np.asarray(ref.exit_pass(hf, g2))[12:]
+    assert len(at2) == len(toks) >= 5
+    assert int(out[12]) == int((at2 + 1).sum())
+    rows_ref = reference_logits(ref, weights, consumed, hf)[12:]
+    assert toks.tolist() == rows_ref.argmax(-1).tolist()
+
+
+def test_a_cache_shared_across_passes_fails_the_comparison(ref, monkeypatch):
+    """The check that the comparison has teeth: with every pass reading and
+    writing pass 0's cache layers (weight index for cache index) the
+    decode rows leave the reference by far more than any tolerance; the
+    prefill's own rows, which attend over fresh keys, do not."""
+    monkeypatch.setattr(batching, "_at",
+                        lambda base, i: i if base is None else 0 * base + i)
+    _, weights, eng = build(ref)
+    ids = ids_of(20)
+    got, _ = drive_prefill_decode(eng, ids)
+    want = reference_logits(ref, weights, ids)
+    assert max(rel_rms(g, w) for g, w in zip(got[:12], want[:12])) <= 1e-4
+    assert min(rel_rms(g, w) for g, w in zip(got[12:], want[12:17])) > 1e-2
+
+
+def test_a_pass_reading_the_pass_before_fails_the_comparison(ref,
+                                                             monkeypatch):
+    """Pass t at pass t-1's cache layers (pass 0 at its own): passes 0 and
+    1 then share rows."""
+    per = LAYERS
+
+    def shifted(base, i):
+        return i if base is None else jnp.maximum(base - per, 0) + i
+
+    monkeypatch.setattr(batching, "_at", shifted)
+    _, weights, eng = build(ref)
+    ids = ids_of(20)
+    got, _ = drive_prefill_decode(eng, ids)
+    want = reference_logits(ref, weights, ids)
+    assert min(rel_rms(g, w) for g, w in zip(got[12:], want[12:17])) > 1e-2
+
+
+# -- one pass is the model the repo already ran -------------------------------
+
+def test_one_pass_is_the_sandwich_norm_llama(ref):
+    """total_ut_steps 1: no gate leaf, the head norms once, and oracle,
+    engine and reference agree with a llama layer with sandwich norms
+    (what ``post_norms`` has meant since gemma-2)."""
+    hf = dict(HF, total_ut_steps=1)
+    cfg, weights, eng = build(ref, hf=hf)
+    assert cfg.loop_steps == 1 and cfg.post_norms
+    sd = {k: v for k, v in weights.items() if "early_exit" not in k}
+    params = convert_state_dict(cfg, sd, dtype=jnp.float32)
+    assert "exit_gate" not in params
+    assert "exit_gate" not in init_params(jax.random.PRNGKey(0), cfg)
+    ids = ids_of(16)
+    kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, 32)
+    assert kc.shape[0] == LAYERS
+    oracle, _, _ = full_forward(cfg, params, jnp.asarray(ids[None]), kc, vc,
+                                jnp.int32(0))
+    want = reference_logits(ref, weights, ids, hf)
+    assert rel_rms(oracle[0], want) <= 1e-5
+    h = eng.prefill("s", ids[None])
+    assert rel_rms(np.asarray(eng.logits(h))[0], want) <= 1e-5
+    # the same layers as a plain llama config with the post_norms switch
+    plain = dataclasses.replace(
+        config_mod.llama_config(
+            vocab_size=VOCAB, hidden_size=64, num_layers=LAYERS, num_heads=4,
+            num_kv_heads=4, intermediate_size=96,
+            max_position_embeddings=128, rope_theta=1e6, norm_eps=1e-6),
+        post_norms=True)
+    again, _, _ = full_forward(plain, params, jnp.asarray(ids[None]), kc, vc,
+                               jnp.int32(0))
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(oracle))
+
+
+def test_the_looped_oracle_matches_the_reference(ref):
+    cfg, weights, eng = build(ref)
+    ids = ids_of(16)
+    kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, 32)
+    got, kc, vc = full_forward(cfg, eng.params, jnp.asarray(ids[None, :11]),
+                               kc, vc, jnp.int32(0))
+    rows = [np.asarray(got[0])]
+    for j in range(11, 16):          # T == 1 steps through the cache
+        got, kc, vc = full_forward(cfg, eng.params,
+                                   jnp.asarray(ids[None, j:j + 1]), kc, vc,
+                                   jnp.int32(j))
+        rows.append(np.asarray(got[0]))
+    assert rel_rms(np.concatenate(rows),
+                   reference_logits(ref, weights, ids)) <= 1e-5
+    with pytest.raises(ValueError, match="cache layers"):
+        full_forward(cfg, eng.params, jnp.asarray(ids[None]),
+                     kc[:LAYERS], vc[:LAYERS], jnp.int32(0))
+
+
+# -- the importer --------------------------------------------------------------
+
+def test_hf_import_round_trip_of_the_published_names(ref):
+    weights = ref.make_weights(HF, LAYERS, 9, jnp.float32)
+    cfg = program_config()
+    assert (cfg.model_type, cfg.loop_steps, cfg.exit_threshold,
+            cfg.head_dim, cfg.post_norms, cfg.tie_word_embeddings,
+            cfg.use_bias, cfg.attn_qkv_bias) == (
+        "ouro", PASSES, 1.0, 16, True, False, False, False)
+    p = convert_state_dict(cfg, weights, dtype=jnp.float32)
+    w = {k: np.asarray(v) for k, v in weights.items()}
+    pre = "model.layers.%d."
+    for i in range(LAYERS):
+        lay = jax.tree.map(lambda x: np.asarray(x[i]), p["layers"])
+        for ours, theirs in (("ln1", "input_layernorm"),
+                             ("ln3", "input_layernorm_2"),
+                             ("ln2", "post_attention_layernorm"),
+                             ("ln4", "post_attention_layernorm_2")):
+            np.testing.assert_array_equal(
+                lay[ours]["w"], w[pre % i + theirs + ".weight"])
+        for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                             ("wv", "v_proj"), ("wo", "o_proj")):
+            np.testing.assert_array_equal(
+                lay["attn"][ours],
+                w[pre % i + f"self_attn.{theirs}.weight"].T)
+        for ours, theirs in (("wg", "gate_proj"), ("wu", "up_proj"),
+                             ("wd", "down_proj")):
+            np.testing.assert_array_equal(
+                lay["mlp"][ours], w[pre % i + f"mlp.{theirs}.weight"].T)
+        assert set(lay["attn"]) == {"wq", "wk", "wv", "wo"}   # no biases
+    np.testing.assert_array_equal(np.asarray(p["exit_gate"]["w"]),
+                                  w["model.early_exit_gate.weight"].T)
+    np.testing.assert_array_equal(np.asarray(p["exit_gate"]["b"]),
+                                  w["model.early_exit_gate.bias"])
+    np.testing.assert_array_equal(np.asarray(p["final_norm"]["w"]),
+                                  w["model.norm.weight"])
+    np.testing.assert_array_equal(np.asarray(p["lm_head"]["w"]),
+                                  w["lm_head.weight"].T)
+    # random init has the same tree, and the full-span slice keeps the gate
+    init = init_params(jax.random.PRNGKey(0), cfg)
+    assert (jax.tree.structure(init) == jax.tree.structure(p)
+            and jax.tree.map(jnp.shape, init) == jax.tree.map(jnp.shape, p))
+    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
+    assert "exit_gate" in slice_stage_params(cfg, p, spec)
+    with pytest.raises(ValueError, match="sliding"):
+        program_config(dict(HF, use_sliding_window=True))
+
+
+def test_the_preset_holds_the_published_keys():
+    cfg = config_mod.get_config("ouro-2.6b")
+    assert (cfg.hidden_size, cfg.num_layers, cfg.num_heads,
+            cfg.num_kv_heads, cfg.head_dim, cfg.intermediate_size,
+            cfg.vocab_size, cfg.max_position_embeddings, cfg.rope_theta,
+            cfg.norm_eps, cfg.loop_steps, cfg.exit_threshold) == (
+        2048, 48, 16, 16, 128, 5632, 49152, 65536, 1e6, 1e-6, 4, 1.0)
+    assert cfg.post_norms and not cfg.tie_word_embeddings
+    assert cfg.rope_scaling is None and cfg.sliding_window is None
+    assert config_mod.get_config("ByteDance/Ouro-2.6B") == cfg
+    # every other preset runs its stack once
+    for name, make in config_mod.PRESETS.items():
+        assert (make().loop_steps > 1) == (name == "ouro-2.6b"), name
+
+
+def test_consumed_params_free_what_the_fused_copies_replace(ref):
+    """A server hands its tree over: the unfused projection stacks go, the
+    leaves the engine still holds stay, and the engine runs."""
+    weights = ref.make_weights(HF, LAYERS, 5, jnp.float32)
+    cfg = program_config()
+    params = convert_state_dict(cfg, weights, dtype=jnp.float32)
+    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
+    eng = BatchedStageExecutor(cfg, spec, params, slots=2, max_len=32,
+                               consume_params=True)
+    gone = [params["layers"]["attn"][k] for k in ("wq", "wk", "wv")] + [
+        params["layers"]["mlp"][k] for k in ("wg", "wu")]
+    assert all(x.is_deleted() for x in gone)
+    kept = [params["layers"]["attn"]["wo"], params["layers"]["mlp"]["wd"],
+            params["embed"]["wte"], params["exit_gate"]["w"]]
+    assert not any(x.is_deleted() for x in kept)
+    ids = ids_of(10)
+    h = eng.prefill("s", ids[None])
+    assert rel_rms(np.asarray(eng.logits(h))[0],
+                   reference_logits(ref, weights, ids)) <= 1e-4
+
+
+# -- everything that would run one pass refuses --------------------------------
+
+def looped_tiny():
+    cfg = program_config()
+    return cfg, init_params(jax.random.PRNGKey(0), cfg)
+
+
+def refuse_executor(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
+        StageExecutor,
+    )
+
+    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
+    StageExecutor(cfg, spec, params)
+
+
+def refuse_offload(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.offload import (
+        OffloadedSpanRunner,
+    )
+
+    OffloadedSpanRunner(cfg, StagePlan.even(cfg.num_layers, 1).stages[0],
+                        params)
+
+
+def refuse_fused_greedy(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.fused_decode import (
+        make_fused_decode,
+    )
+
+    make_fused_decode(cfg, 4, 1)
+
+
+def refuse_fused_sampled(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.fused_decode import (
+        make_fused_sample_decode,
+    )
+
+    make_fused_sample_decode(cfg, 4)
+
+
+def refuse_sp(cfg, params):
+    from jax.sharding import Mesh
+
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.sp_stage import (
+        SpStageRunner,
+    )
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("sp",))
+    SpStageRunner(cfg, StagePlan.even(cfg.num_layers, 1).stages[0], params,
+                  mesh)
+
+
+def refuse_tp(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.tensor_parallel import (
+        validate_tp,
+    )
+
+    validate_tp(cfg, 2)
+
+
+def refuse_pipeline(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.pipeline import (
+        IciPipeline,
+    )
+
+    IciPipeline.build(cfg, params, num_stages=1)
+
+
+def refuse_trainer(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.trainer import (
+        PipelineTrainer,
+    )
+
+    PipelineTrainer.build(cfg, params, num_stages=1)
+
+
+def refuse_training_forward(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.trainer import (
+        single_device_loss,
+    )
+
+    ids = jnp.zeros((1, 1, 4), jnp.int32)
+    single_device_loss(cfg, params, ids, ids)
+
+
+def refuse_finetune(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.finetune import (
+        DistributedFineTuner,
+    )
+
+    DistributedFineTuner(cfg, None, {})
+
+
+def refuse_stage_forward(cfg, params):
+    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
+    kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, 8)
+    stage_forward(cfg, spec, params, jnp.zeros((1, 4), jnp.int32), kc, vc,
+                  jnp.int32(0))
+
+
+def refuse_partial_batched(cfg, params):
+    spec = StagePlan.even(cfg.num_layers, 3).stages[1]
+    BatchedStageExecutor(cfg, spec, slice_stage_params(cfg, params, spec),
+                         slots=2, max_len=16)
+
+
+def refuse_split_route(cfg, params):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
+        PipelineClient,
+    )
+
+    client = object.__new__(PipelineClient)
+    client.cfg = cfg
+    next(PipelineClient._generate_steps(
+        client, [1, 2, 3], 2, sampling=None, eos_token_id=None,
+        session_id="s", max_length=None, speculative_k=0, draft_fn=None))
+
+
+REFUSALS = [refuse_executor, refuse_offload, refuse_fused_greedy,
+            refuse_fused_sampled, refuse_sp, refuse_tp, refuse_pipeline,
+            refuse_trainer, refuse_training_forward, refuse_finetune,
+            refuse_stage_forward, refuse_partial_batched, refuse_split_route]
+
+
+@pytest.mark.parametrize("attempt", REFUSALS,
+                         ids=[f.__name__[7:] for f in REFUSALS])
+def test_what_would_run_one_pass_refuses(attempt):
+    """One message, naming the mechanism and not the model."""
+    cfg, params = looped_tiny()
+    with pytest.raises((NotImplementedError, ValueError)) as err:
+        attempt(cfg, params)
+    text = str(err.value)
+    assert "layers run several times" in text and "one pass" in text
+    assert "ouro" not in text.lower()
+    assert config_mod.single_pass_unsupported(
+        dataclasses.replace(cfg, loop_steps=1), "x") is None
+
+
+def test_the_gate_leaf_has_a_replication_row():
+    import re
+
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.tensor_parallel import (
+        REPLICATED_LEAVES,
+    )
+
+    rows = [(rx, why) for rx, why in REPLICATED_LEAVES
+            if re.search(rx, "exit_gate/w") and re.search(rx, "exit_gate/b")]
+    assert len(rows) == 1 and len(rows[0][1]) > 20
+
