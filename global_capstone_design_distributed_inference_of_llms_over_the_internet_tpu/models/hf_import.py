@@ -214,6 +214,23 @@ def _gpt2_layer(sd: Mapping[str, Any], i: int) -> Params:
     }
 
 
+# Checkpoint names of a llama-shaped layer's norms after ``ln1``
+# (``input_layernorm`` in every family). ln2 is the norm BEFORE the MLP;
+# a family with sandwich norms (``post_norms``) adds ln3 AFTER attention
+# and ln4 AFTER the MLP, each before its residual add, under names of its
+# own: gemma2's "post_attention_layernorm" is the POST-attention norm,
+# and the looped family's "_2" is the norm after the sublayer.
+_PRE_NORMS = {"ln2": "post_attention_layernorm"}
+_SANDWICH_NORMS = {
+    "gemma2": {"ln2": "pre_feedforward_layernorm",
+               "ln3": "post_attention_layernorm",
+               "ln4": "post_feedforward_layernorm"},
+    "ouro": {"ln2": "post_attention_layernorm",
+             "ln3": "input_layernorm_2",
+             "ln4": "post_attention_layernorm_2"},
+}
+
+
 def _llama_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
     pre = f"model.layers.{i}."
     p: Params = {
@@ -225,21 +242,9 @@ def _llama_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
             "wo": _np(sd[pre + "self_attn.o_proj.weight"]).T,
         },
     }
-    if cfg.model_type == "ouro":
-        # Sandwich norms under the looped family's names: "_2" is the norm
-        # AFTER the sublayer (ln3 after attention, ln4 after the MLP).
-        p["ln2"] = {"w": _np(sd[pre + "post_attention_layernorm.weight"])}
-        p["ln3"] = {"w": _np(sd[pre + "input_layernorm_2.weight"])}
-        p["ln4"] = {"w": _np(sd[pre + "post_attention_layernorm_2.weight"])}
-    elif cfg.post_norms:
-        # gemma2 sandwich norms: HF's "post_attention_layernorm" is the
-        # POST-attn norm (our ln3); the pre-MLP norm is
-        # "pre_feedforward_layernorm" (our ln2).
-        p["ln2"] = {"w": _np(sd[pre + "pre_feedforward_layernorm.weight"])}
-        p["ln3"] = {"w": _np(sd[pre + "post_attention_layernorm.weight"])}
-        p["ln4"] = {"w": _np(sd[pre + "post_feedforward_layernorm.weight"])}
-    else:
-        p["ln2"] = {"w": _np(sd[pre + "post_attention_layernorm.weight"])}
+    names = _SANDWICH_NORMS[cfg.model_type] if cfg.post_norms else _PRE_NORMS
+    for ours, theirs in names.items():
+        p[ours] = {"w": _np(sd[pre + theirs + ".weight"])}
     if cfg.altern_window:
         # even layers windowed, odd global (HF Gemma2Attention layer_idx
         # rule) — the traced per-layer window leaf.

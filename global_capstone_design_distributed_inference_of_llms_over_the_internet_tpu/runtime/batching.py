@@ -428,7 +428,6 @@ class BatchedStageExecutor:
         dtype=jnp.float32,
         prefix_cache_bytes: int = 0,
         model: Optional[str] = None,
-        consume_params: bool = False,
     ):
         if not (spec.is_first and spec.is_last):
             refuse_single_pass(cfg, "a batched engine over part of the "
@@ -442,18 +441,7 @@ class BatchedStageExecutor:
         # bitwise-identical — models/transformer.fuse_qkv_params).
         from ..models.transformer import fuse_qkv_params
 
-        self.params = fuse_qkv_params(params)
-        if consume_params:
-            # The caller hands its tree over (a server's: nothing else
-            # reads it). The projection stacks that the fused copies
-            # replace would stay resident beside them for the process's
-            # life (q, k, v and gate, up: 3.4 GB of ouro-2.6b's 5.3), so
-            # they go now, before the cache stacks are allocated.
-            held = {id(x) for x in jax.tree.leaves(self.params)}
-            for leaf in jax.tree.leaves(params):
-                if isinstance(leaf, jax.Array) and id(leaf) not in held:
-                    leaf.delete()
-        del params
+        self.params = params = fuse_qkv_params(params)
         self.slots = slots
         self.max_len = max_len
         self.dtype = jnp.dtype(dtype)
@@ -1119,16 +1107,15 @@ class BatchedStageExecutor:
                 out = fn(self.params, *args, self.k, self.v)
             if prof.enabled:
                 jax.block_until_ready(out)
-        toks, stop = out[0], out[1]
-        lengths_new = out[3]
-        self.k, self.v = out[10], out[11]
+        (toks, stop, _tok, lengths_new, _alive, _seeds, _recent, _nvalid,
+         _run, _left, self.k, self.v, *passes) = out
         self.decode_steps += 1
         self.burst_dispatches += 1
         self._m_burst_disp.inc()
         self._m_burst_ticks.observe(n_ticks)
         with prof.phase("readback", sessions=n):
             return self._burst_collect(rows, toks, stop, lengths_new,
-                                       out[12:])
+                                       passes)
 
     # ------------------------------------------------------------------
 

@@ -425,21 +425,29 @@ def test_the_preset_holds_the_published_keys():
         assert (make().loop_steps > 1) == (name == "ouro-2.6b"), name
 
 
-def test_consumed_params_free_what_the_fused_copies_replace(ref):
-    """A server hands its tree over: the unfused projection stacks go, the
-    leaves the engine still holds stay, and the engine runs."""
+def test_a_server_frees_what_the_fused_copies_replace(ref):
+    """`main.run_serve` hands its batched engine the fused tree and owns the
+    staged one: the unfused projection stacks go before the engine is
+    built, the leaves the fused tree still holds stay, and the engine, built
+    as every other caller builds it, runs."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
+        main as main_mod,
+    )
+
     weights = ref.make_weights(HF, LAYERS, 5, jnp.float32)
     cfg = program_config()
     params = convert_state_dict(cfg, weights, dtype=jnp.float32)
     spec = StagePlan.even(cfg.num_layers, 1).stages[0]
-    eng = BatchedStageExecutor(cfg, spec, params, slots=2, max_len=32,
-                               consume_params=True)
-    gone = [params["layers"]["attn"][k] for k in ("wq", "wk", "wv")] + [
-        params["layers"]["mlp"][k] for k in ("wg", "wu")]
+    staged = slice_stage_params(cfg, params, spec)
+    fused = main_mod._fused_stage_params(
+        types.SimpleNamespace(quant="none", lora=None), cfg, params, spec)
+    gone = [staged["layers"]["attn"][k] for k in ("wq", "wk", "wv")] + [
+        staged["layers"]["mlp"][k] for k in ("wg", "wu")]
     assert all(x.is_deleted() for x in gone)
-    kept = [params["layers"]["attn"]["wo"], params["layers"]["mlp"]["wd"],
-            params["embed"]["wte"], params["exit_gate"]["w"]]
-    assert not any(x.is_deleted() for x in kept)
+    assert {"wqkv", "wo"} == set(fused["layers"]["attn"])
+    assert not any(x.is_deleted() for x in jax.tree.leaves(fused))
+    eng = BatchedStageExecutor(cfg, spec, fused, slots=2, max_len=32)
+    assert eng.params["layers"] is fused["layers"]
     ids = ids_of(10)
     h = eng.prefill("s", ids[None])
     assert rel_rms(np.asarray(eng.logits(h))[0],
