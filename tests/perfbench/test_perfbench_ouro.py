@@ -91,7 +91,7 @@ def ref(man, body):
     return load_module(man.reference_file(body))
 
 
-def test_the_benchmark_validates_with_the_new_cell(man, body):
+def test_the_benchmark_validates_with_the_cell_s_entries(man, body):
     man.validate()
     entry = man.config_entry(CONFIG)
     assert entry["reduced"] == body["reduced"] == []
