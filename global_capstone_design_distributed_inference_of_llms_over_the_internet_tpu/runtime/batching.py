@@ -95,7 +95,32 @@ def _burst_entry(rq) -> dict:
         "repetition_penalty": sp.repetition_penalty,
     }
 
+
+
+def _rider_entry(rq) -> dict:
+    """A prefill StageRequest as the engine's rider (`decode_burst`): the
+    prompt and what `executor._sample_rows` samples its first token with."""
+    sp = rq.sampling
+    return {
+        "session_id": rq.session_id,
+        "ids": np.asarray(rq.hidden).reshape(-1),
+        "seed": int(rq.step_seed),
+        "generated": rq.generated_tokens,
+        "temperature": sp.temperature,
+        "top_p": sp.top_p,
+        "top_k": sp.top_k,
+        "repetition_penalty": sp.repetition_penalty,
+    }
+
+
 PREFILL_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024)
+
+# Prompt rows a burst tick carries for the request that joins during the
+# burst (the rider lane of an engine whose prefill program would cost a
+# tick: `BatchedStageExecutor.rider_rows`). 16 ticks of 16 rows hold the
+# 256-row prefill bucket; with the slots' 8 rows the tick's matmuls stay
+# far under the rows at which a v5e stops being bound by the weights' read.
+RIDER_ROWS = 16
 
 # The client's repeat-stop heuristic (runtime.client.REPEAT_STOP), mirrored
 # on device so a burst truncates exactly where the sequential host loop
@@ -151,6 +176,26 @@ def _residual(cfg, lp, h, attn_out):
         return h + mlp_out
 
 
+def _attend(cfg, lp, q, keys, values, grid):
+    """Attention of ``q`` (``[B, T, H, Dh]``, rotated) over ``keys`` and
+    ``values`` (``[B, S, Hkv, Dh]``) under ``grid`` = ``(mask, q_pos,
+    k_pos)``: ``[B, T, H * Dh]``, before the output projection."""
+    mask, q_pos, k_pos = grid
+    b, t = q.shape[:2]
+    groups = cfg.num_heads // cfg.num_kv_heads
+    qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
+    scores = jnp.einsum(
+        "bthgd,bshd->bhgts", qg * _qscale(cfg), keys.astype(q.dtype),
+        preferred_element_type=jnp.float32)          # [B, Hkv, G, T, S]
+    m = _layer_mask(lp, mask, q_pos, k_pos)
+    m = m[:, None, None] if m.ndim == 3 else m[None, None, None]
+    scores = _softcap_and_mask(cfg, scores, m)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgts,bshd->bthgd", probs.astype(values.dtype),
+                     values.astype(q.dtype))
+    return out.reshape(b, t, -1)
+
+
 def _decoder_layer(cfg, lp, h, rope, cache_policy):
     """One decoder layer of every engine program: ``(h, state)``.
 
@@ -160,7 +205,15 @@ def _decoder_layer(cfg, lp, h, rope, cache_policy):
     (``[B, S, Hkv, Dh]``), the allowed grid (``[T, S]`` for one sequence or
     per row ``[B, T, S]``) with the position grids `_layer_mask` windows it
     by, and what the layer scan carries or stacks. Cache writes are the
-    policy's, under its own ``kv_update`` scope."""
+    policy's, under its own ``kv_update`` scope.
+
+    Rows of TWO shapes in one layer (a burst tick that carries a joining
+    request's prompt rows beside the slots' single rows: `_decode_span`):
+    ``h`` is then flat, ``[1, rows, D]``, so that every matmul of the layer
+    reads its weight ONCE for all of them, and the policy returns
+    ``keys``, ``values`` and the grid as TUPLES, one entry a group, each
+    mask ``[B_g, T_g, S_g]``: group g attends as ``B_g`` sequences of
+    ``T_g`` rows, the groups side by side along the flat row axis."""
     from ..models.quant import dequant_tree
 
     lp = dequant_tree(lp, keep_experts=cfg.is_moe)
@@ -170,21 +223,21 @@ def _decoder_layer(cfg, lp, h, rope, cache_policy):
         if rope is not None:
             q = apply_rope(q, *rope)
             k = apply_rope(k, *rope)
-    keys, values, (mask, q_pos, k_pos), state = cache_policy(k, v)
+    keys, values, grid, state = cache_policy(k, v)
     with jax.named_scope("attention"):
-        b, t = h.shape[:2]
-        groups = cfg.num_heads // cfg.num_kv_heads
-        qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
-        scores = jnp.einsum(
-            "bthgd,bshd->bhgts", qg * _qscale(cfg), keys.astype(q.dtype),
-            preferred_element_type=jnp.float32)          # [B, Hkv, G, T, S]
-        m = _layer_mask(lp, mask, q_pos, k_pos)
-        m = m[:, None, None] if m.ndim == 3 else m[None, None, None]
-        scores = _softcap_and_mask(cfg, scores, m)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhgts,bshd->bthgd", probs.astype(values.dtype),
-                         values.astype(q.dtype))
-        out = _dot(out.reshape(b, t, -1), lp["attn"]["wo"])
+        if isinstance(keys, tuple):
+            outs, row = [], 0
+            for k_g, v_g, grid_g in zip(keys, values, grid):
+                b_g, t_g = grid_g[0].shape[:2]
+                q_g = q[0, row:row + b_g * t_g].reshape(
+                    b_g, t_g, *q.shape[2:])
+                outs.append(_attend(cfg, lp, q_g, k_g, v_g, grid_g)
+                            .reshape(1, b_g * t_g, -1))
+                row += b_g * t_g
+            out = jnp.concatenate(outs, axis=1)
+        else:
+            out = _attend(cfg, lp, q, keys, values, grid)
+        out = _dot(out, lp["attn"]["wo"])
         if "bo" in lp["attn"]:
             out = out + lp["attn"]["bo"]
     return _residual(cfg, lp, h, out), state
@@ -325,7 +378,7 @@ def _at(base, i):
     return i if base is None else base + i
 
 
-def _append_rows(stack, i, new, lengths, active):
+def _append_rows(stack, i, new, lengths, active, slots=None):
     """``stack`` (``[L, S, max_len, Hkv, Dh]``) with ``new`` (``[S, T, Hkv,
     Dh]``) at ``stack[i, s, lengths[s] : lengths[s] + T]`` for every slot:
     ONE scatter of S x T rows, so the carried stack is updated in place
@@ -338,11 +391,15 @@ def _append_rows(stack, i, new, lengths, active):
     donated buffers). A row (``[Hkv, Dh]``, the scatter's window) at a
     ``(layer, slot, position)`` point is the form the TPU keeps as one
     native scatter; a window that spans the T positions is expanded into
-    a loop over the slots."""
-    slots, t = new.shape[:2]
+    a loop over the slots. ``slots`` (``[n]``; default every slot in
+    order) names the slot of each of ``new``'s ``n`` sequences, and
+    ``active`` may be per ROW (``[n, T]``): the rider's chunk
+    (`_decode_span`), whose last one ends inside its T rows."""
+    n, t = new.shape[:2]
     start = jnp.clip(lengths, 0, stack.shape[2] - t)
     at = jnp.stack(jnp.broadcast_arrays(
-        i, jnp.arange(slots, dtype=jnp.int32)[:, None],
+        i, (jnp.arange(n, dtype=jnp.int32) if slots is None
+            else slots)[:, None],
         start[:, None] + jnp.arange(t, dtype=jnp.int32)), axis=-1)
     old = jax.lax.gather(
         stack, at,
@@ -351,8 +408,10 @@ def _append_rows(stack, i, new, lengths, active):
             start_index_map=(0, 1, 2)),
         slice_sizes=(1, 1, 1) + new.shape[2:], mode="promise_in_bounds",
         unique_indices=True, indices_are_sorted=True)
+    keep = (active[:, None, None, None] if active.ndim == 1
+            else active[:, :, None, None])
     return jax.lax.scatter(
-        stack, at, jnp.where(active[:, None, None, None], new, old),
+        stack, at, jnp.where(keep, new, old),
         jax.lax.ScatterDimensionNumbers(
             update_window_dims=(2, 3), inserted_window_dims=(0, 1, 2),
             scatter_dims_to_operand_dims=(0, 1, 2)),
@@ -361,7 +420,7 @@ def _append_rows(stack, i, new, lengths, active):
 
 
 def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
-                 k_all, v_all):
+                 k_all, v_all, rider=None):
     """The span's layers over ``T`` new tokens a slot: ``(h, k_all, v_all,
     steps)`` as `_run_passes` gives them.
     The body of the decode step (T = 1 plain, T = K+1 speculative verify:
@@ -369,11 +428,29 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
     T = 1, of every burst tick. ``x``: ids ``[S, T]`` or hidden ``[S, T, D]``
     at ``positions`` (``lengths[:, None]`` + the offset in the block);
     ``pos_grid``: ``arange(max_len)``, the caller's so that a burst builds
-    it once for all its ticks."""
+    it once for all its ticks.
+
+    ``rider`` (a burst tick of an engine with a rider lane, T = 1): C prompt
+    rows of the ONE request that joins during this burst ride the tick
+    beside the slots' rows. ``{"ids": [C], "start": their first position,
+    "valid": [C] (rows of the prompt; a lane with no rider has none),
+    "slot", "rows": R}``: the rows enter the layers FLAT with the slots'
+    (``[1, S + C, D]``: one read of every weight for both), are written to
+    ``[slot, start : start + C)`` of every cache layer and attend over that
+    slot's first R rows, causally. ``h`` and ``steps`` come back flat, the
+    slots' S rows first."""
+    slots = x.shape[0]
+    if rider is not None:
+        r_pos = rider["start"] + jnp.arange(
+            rider["ids"].shape[0], dtype=jnp.int32)
+        x = jnp.concatenate([x[:, 0], rider["ids"]])[None]      # [1, S + C]
+        positions = jnp.concatenate([positions[:, 0], r_pos])[None]
     with jax.named_scope("embed"):
         h = (embed_tokens(cfg, params["embed"], x, positions)
              if spec.is_first else x)
         rope = make_rope(cfg, positions)
+    if rider is not None:
+        positions = positions[0, :slots, None]                  # [S, 1]
     # allowed[s, tq, m]: key position m visible to query token tq of slot s
     # — everything up to and including the query's own position (causal
     # within the new block too).
@@ -382,6 +459,12 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
     if cfg.sliding_window:
         # Window spans (qpos - window, qpos].
         allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
+    if rider is not None:
+        r_grid = pos_grid[None, None, :rider["rows"]]           # [1, 1, R]
+        r_qpos = r_pos[None, :, None]                           # [1, C, 1]
+        r_allowed = r_grid <= r_qpos
+        if cfg.sliding_window:
+            r_allowed &= r_grid > r_qpos - cfg.sliding_window
 
     def one_pass(h, base, k_all, v_all):
         def layer(h, xs):
@@ -392,19 +475,42 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
                 # Write the T new rows a slot into the stacks, THEN read
                 # this layer's keys and values out of them: the read's
                 # only consumers are the two attention products.
-                with jax.named_scope("kv_update"):
-                    k_new = _append_rows(
-                        k_all, at, k.astype(k_all.dtype), lengths, active)
-                    v_new = _append_rows(
-                        v_all, at, v.astype(v_all.dtype), lengths, active)
-                with jax.named_scope("attention"):
-                    keys = jax.lax.dynamic_index_in_dim(
-                        k_new, at, 0, keepdims=False)
-                    values = jax.lax.dynamic_index_in_dim(
-                        v_new, at, 0, keepdims=False)
-                return (keys, values,
-                        (allowed, qpos, pos_grid[None, None, :]),
-                        (k_new, v_new))
+                if rider is None:
+                    with jax.named_scope("kv_update"):
+                        k_new = _append_rows(
+                            k_all, at, k.astype(k_all.dtype), lengths,
+                            active)
+                        v_new = _append_rows(
+                            v_all, at, v.astype(v_all.dtype), lengths,
+                            active)
+                    with jax.named_scope("attention"):
+                        keys = jax.lax.dynamic_index_in_dim(
+                            k_new, at, 0, keepdims=False)
+                        values = jax.lax.dynamic_index_in_dim(
+                            v_new, at, 0, keepdims=False)
+                    return (keys, values,
+                            (allowed, qpos, pos_grid[None, None, :]),
+                            (k_new, v_new))
+                new, read = [], []
+                for stack, rows in ((k_all, k), (v_all, v)):
+                    rows = rows[0].astype(stack.dtype)      # [S + C, ..]
+                    with jax.named_scope("kv_update"):
+                        stack = _append_rows(
+                            stack, at, rows[:slots, None], lengths, active)
+                        stack = _append_rows(
+                            stack, at, rows[None, slots:],
+                            rider["start"][None], rider["valid"][None],
+                            slots=rider["slot"][None])
+                    with jax.named_scope("attention"):
+                        slab = jax.lax.dynamic_index_in_dim(
+                            stack, at, 0, keepdims=False)
+                        mine = jax.lax.dynamic_slice_in_dim(
+                            slab, rider["slot"], 1, 0)[:, :rider["rows"]]
+                    new.append(stack)
+                    read.append((slab, mine))
+                return (read[0], read[1],
+                        ((allowed, qpos, pos_grid[None, None, :]),
+                         (r_allowed, r_qpos, r_grid)), tuple(new))
 
             return _decoder_layer(cfg, lp, h, rope, per_slot_append)
 
@@ -461,6 +567,14 @@ class BatchedStageExecutor:
         self._m_burst_toks = _tm.get("server_burst_tokens_total")
         self._m_sampler = _tm.get("server_sampler_rounds_total")
         self._m_exit_steps = _tm.get("server_loop_exit_steps_total")
+        # The rider lane (`_decode_span`): a looped stack's prefill program
+        # streams the weights `loop_steps` times, as long as a whole tick of
+        # every OTHER session's burst, and which rounds pay it is chance;
+        # carried by the burst's own ticks the same prompt costs nothing
+        # that an idle lane does not. A stack that runs once prefills in a
+        # fraction of a tick and keeps the programs it had.
+        self.rider_rows = (RIDER_ROWS if cfg.loop_steps > 1
+                           and spec.is_first and spec.is_last else 0)
         # Prompt-prefix KV reuse (runtime.prefix_cache), slot-layout
         # variant: entries hold [L, G, Hkv, Dh] KV segments (+ [1, G, D]
         # output rows off the final stage). Same grain-chained rolling
@@ -906,12 +1020,19 @@ class BatchedStageExecutor:
         sequential client would have accepted.
 
         A looped stack's program (``cfg.loop_steps > 1``) carries one more
-        value through the ticks and returns it LAST: the passes taken by
-        the tokens the burst emitted, summed on the device
-        (``server_loop_exit_steps_total``). A program whose stack runs once
-        has the arguments and results it always had."""
+        value through the ticks and returns it after the twelve: the passes
+        taken by the tokens the burst emitted, summed on the device
+        (``server_loop_exit_steps_total``). With a rider lane
+        (``self.rider_rows``) the program takes one more ARGUMENT, the
+        rider (`_rider_args`), every tick carries the lane's rows through
+        the layers (`_decode_span`), the head and the sampler read one more
+        row (the chunk's row at which the prompt ends), and the rider's
+        first token is the last result. A lane without a rider does the
+        same work on rows that write nothing. A program whose stack runs
+        once has the arguments and results it always had."""
         cfg, spec = self.cfg, self.spec
         looped = cfg.loop_steps > 1
+        lane = self.rider_rows
         S = self.slots
         N = n_ticks
         from ..models.transformer import lm_head
@@ -920,26 +1041,60 @@ class BatchedStageExecutor:
         @partial(jax.jit, donate_argnums=engine_donation(14, 15))
         def burst_tick(params, tok, lengths, alive, seeds, recent, nvalid,
                        run, left, eos_id, temp, top_p, top_k, rp, k_all,
-                       v_all):
+                       v_all, *rider):
             pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
             len0 = lengths
+            if lane:
+                (rider,) = rider
+                last = rider["len"] - 1        # the prompt's last row
+                r_knobs = [jnp.concatenate([a, rider[name][None]])
+                           for a, name in ((temp, "temp"), (top_p, "top_p"),
+                                           (top_k, "top_k"), (rp, "rp"))]
 
             def tick(carry, i):
                 (tok, lengths, alive, recent, nvalid, run, left,
-                 stop, k_all, v_all, *passes) = carry
+                 stop, k_all, v_all, *more) = carry
                 active = alive
+                chunk = ()
+                if lane:
+                    at = i * lane + jnp.arange(lane, dtype=jnp.int32)
+                    chunk = ({"ids": rider["ids"][i], "start": i * lane,
+                              "valid": at < rider["len"],
+                              "slot": rider["slot"],
+                              "rows": min(N * lane, k_all.shape[2])},)
                 h, k_all, v_all, steps = _decode_span(
                     cfg, spec, params, tok[:, None], lengths[:, None],
-                    pos_grid, lengths, active, k_all, v_all)
+                    pos_grid, lengths, active, k_all, v_all, *chunk)
                 with jax.named_scope("head"):
-                    h = jnp.where(active[:, None, None], h, 0.0)
+                    if lane:
+                        # Flat rows: the slots', then the lane's; of those
+                        # the head takes the one the prompt may end at.
+                        h = jnp.concatenate([
+                            jnp.where(active[:, None], h[0, :S], 0.0),
+                            h[0, S + jnp.clip(last - i * lane, 0,
+                                              lane - 1)][None]])[:, None]
+                        steps = steps[0, :S, None]
+                    else:
+                        h = jnp.where(active[:, None, None], h, 0.0)
                     logits = lm_head(cfg, params, h,
                                      normed=looped)[:, 0]     # [S, V] fp32
                 with jax.named_scope("sampler"):
                     keys = jax.vmap(jax.random.PRNGKey)(seeds + i)
-                    sampled = sample_tokens(
-                        keys, logits, recent, nvalid, temp, top_p, top_k,
-                        rp)
+                    if lane:
+                        sampled = sample_tokens(
+                            jnp.concatenate([keys, jax.random.PRNGKey(
+                                rider["seed"])[None]]),
+                            logits,
+                            jnp.concatenate([recent, rider["recent"][None]]),
+                            jnp.concatenate([nvalid, rider["nvalid"][None]]),
+                            *r_knobs)
+                        more[-1] = jnp.where(i == last // lane, sampled[S],
+                                             more[-1])
+                        sampled = sampled[:S]
+                    else:
+                        sampled = sample_tokens(
+                            keys, logits, recent, nvalid, temp, top_p,
+                            top_k, rp)
                 # Host stop-rule mirror, in host order: the token is always
                 # EMITTED (the host appends before checking eos/repeat);
                 # stops only gate the NEXT tick.
@@ -963,24 +1118,25 @@ class BatchedStageExecutor:
                     tok = jnp.where(active, sampled, tok)
                     out_tok = jnp.where(active, sampled, jnp.int32(-1))
                     if looped:
-                        passes = [passes[0] + jnp.sum(
-                            jnp.where(active, steps[:, 0], 0))]
+                        more[0] = more[0] + jnp.sum(
+                            jnp.where(active, steps[:, 0], 0))
                 return (tok, lengths, alive, recent, nvalid, run_next,
-                        left_next, stop, k_all, v_all, *passes), out_tok
+                        left_next, stop, k_all, v_all, *more), out_tok
 
             stop0 = jnp.zeros((S,), jnp.int32)
             carry, toks = jax.lax.scan(
                 tick,
                 (tok, lengths, alive, recent, nvalid, run, left, stop0,
-                 k_all, v_all, *([jnp.int32(0)] if looped else [])),
+                 k_all, v_all, *([jnp.int32(0)] if looped else []),
+                 *([jnp.int32(-1)] if lane else [])),
                 jnp.arange(N, dtype=jnp.int32))
             (tok, lengths, alive, recent, nvalid, run, left, stop,
-             k_all, v_all, *passes) = carry
+             k_all, v_all, *more) = carry
             # Seed base for a CONTINUATION burst: one key was consumed per
             # emitted token (emitted ticks are a prefix of the scan).
             seeds = seeds + (lengths - len0)
             return (toks, stop, tok, lengths, alive, seeds, recent, nvalid,
-                    run, left, k_all, v_all, *passes)
+                    run, left, k_all, v_all, *more)
 
         return burst_tick
 
@@ -1064,11 +1220,11 @@ class BatchedStageExecutor:
     _BURST_STOPS = {0: None, 1: "eos", 2: "repeat"}
 
     def _burst_collect(self, rows: Dict[str, int], toks, stop,
-                       lengths_new, passes=()) -> Dict[str, dict]:
+                       lengths_new, passes=None) -> Dict[str, dict]:
         """Read one burst's results back (the only host sync per burst).
-        ``passes``: a looped stack's one extra result (`_build_burst`)."""
-        for n in passes:
-            self._m_exit_steps.inc(int(n))
+        ``passes``: a looped stack's count of passes (`_build_burst`)."""
+        if passes is not None:
+            self._m_exit_steps.inc(int(passes))
         toks_np = np.asarray(toks)            # [N, S]
         stop_np = np.asarray(stop)
         len_np = np.asarray(lengths_new)
@@ -1086,36 +1242,100 @@ class BatchedStageExecutor:
         self._m_burst_toks.inc(total)
         return out
 
-    def decode_burst(self, entries: Dict[str, dict],
-                     n_ticks: int) -> Dict[str, dict]:
+    def can_ride(self, t: int, n_ticks: int) -> bool:
+        """Whether a prompt of ``t`` rows fits the rider lane of ONE burst
+        of ``n_ticks`` ticks: whole chunks inside the slot (`_append_rows`
+        clamps a chunk that would end past ``max_len``)."""
+        c = self.rider_rows
+        return (bool(c) and 0 < t <= n_ticks * c
+                and -(-t // c) * c <= self.max_len)
+
+    def _rider_args(self, rider: Optional[dict], n_ticks: int) -> dict:
+        """The burst program's rider argument: the joining request's slot,
+        prompt ids as ``n_ticks`` chunks of `rider_rows`, their count, and
+        what its first token is sampled with (the key and the knobs
+        `executor._sample_rows` gives a prefill's token). None: a lane
+        that carries nothing (``len`` 0: no row of it is written)."""
+        from ..ops.sampling import RECENT_WINDOW
+
+        c = self.rider_rows
+        ids = np.zeros((n_ticks * c,), np.int32)
+        recent = np.zeros((RECENT_WINDOW,), np.int32)
+        r = rider or {"ids": (), "slot": 0, "seed": 0, "generated": (),
+                      "temperature": 0.0, "top_p": 1.0, "top_k": 0,
+                      "repetition_penalty": 1.0}
+        ids[:len(r["ids"])] = r["ids"]
+        win = tuple(int(t) for t in r["generated"])[-RECENT_WINDOW:]
+        recent[:len(win)] = win
+        return {"ids": jnp.asarray(ids.reshape(n_ticks, c)),
+                "len": jnp.int32(len(r["ids"])),
+                "slot": jnp.int32(r["slot"]),
+                "seed": jnp.int32(r["seed"]),
+                "recent": jnp.asarray(recent),
+                "nvalid": jnp.int32(len(win)),
+                "temp": jnp.float32(r["temperature"]),
+                "top_p": jnp.float32(r["top_p"]),
+                "top_k": jnp.int32(r["top_k"]),
+                "rp": jnp.float32(r["repetition_penalty"])}
+
+    def decode_burst(self, entries: Dict[str, dict], n_ticks: int,
+                     rider: Optional[dict] = None) -> Dict[str, dict]:
         """Run up to ``n_ticks`` decode ticks for every session in
         ``entries`` in ONE jitted dispatch. Returns {session_id: {tokens,
         stop, cache_len}} — ``tokens`` are the emitted ids (<= n_ticks;
         device-side eos/repeat/budget stops truncate), ``stop`` is
-        None/"eos"/"repeat". Sessions join/leave only between bursts."""
-        if not entries:
+        None/"eos"/"repeat". Sessions join/leave only between bursts.
+
+        ``rider`` (an engine with a rider lane; `can_ride`): the ONE request
+        that joins during this burst, ``{session_id, ids, seed, generated,
+        temperature, top_p, top_k, repetition_penalty}``. It gets a slot,
+        its prompt's K/V rows are written by the burst's ticks, and its
+        entry of the result is ``{token, cache_len}``: the first token,
+        sampled on the device as a prefill's is on the host."""
+        if not entries and rider is None:
             return {}
         prof = _get_profiler()
         n = len(entries)
+        extra = []
         with prof.phase("burst_build", sessions=n):
             rows, args = self._burst_prep(entries, n_ticks)
             fn = self._get_burst_jit(n_ticks)
+            if rider is not None:
+                if not self.can_ride(len(rider["ids"]), n_ticks):
+                    raise ValueError(
+                        f"a prompt of {len(rider['ids'])} rows does not ride "
+                        f"a burst of {n_ticks} ticks on this engine")
+                rider = {**rider, "slot": self._alloc(rider["session_id"])}
+            if self.rider_rows:
+                extra = [self._rider_args(rider, n_ticks)]
         # Profiled: a fenced dispatch. The device phase is dispatch-to-ready
         # and the bubble gauge charges idle time between successive readies.
-        with prof.device_phase(sessions=n):
-            with prof.phase("dispatch", sessions=n):
-                out = fn(self.params, *args, self.k, self.v)
-            if prof.enabled:
-                jax.block_until_ready(out)
+        try:
+            with prof.device_phase(sessions=n):
+                with prof.phase("dispatch", sessions=n):
+                    out = fn(self.params, *args, self.k, self.v, *extra)
+                if prof.enabled:
+                    jax.block_until_ready(out)
+        except Exception:
+            if rider is not None:
+                self._recover_slot(rider["session_id"], rider["slot"])
+            raise
         (toks, stop, _tok, lengths_new, _alive, _seeds, _recent, _nvalid,
-         _run, _left, self.k, self.v, *passes) = out
+         _run, _left, self.k, self.v, *more) = out
         self.decode_steps += 1
         self.burst_dispatches += 1
         self._m_burst_disp.inc()
         self._m_burst_ticks.observe(n_ticks)
         with prof.phase("readback", sessions=n):
-            return self._burst_collect(rows, toks, stop, lengths_new,
-                                       passes)
+            res = self._burst_collect(
+                rows, toks, stop, lengths_new,
+                more[0] if self.cfg.loop_steps > 1 else None)
+            if rider is not None:
+                t = len(rider["ids"])
+                self.lengths[rider["slot"]] = t
+                res[rider["session_id"]] = {"token": int(more[-1]),
+                                            "cache_len": t}
+            return res
 
     # ------------------------------------------------------------------
 
@@ -1139,7 +1359,7 @@ class _Round:
     T=K+1 speculative verify."""
 
     __slots__ = ("reqs", "outs", "err", "bad", "lengths", "spec", "event",
-                 "closed", "t_exec")
+                 "closed", "t_exec", "t_done", "rider")
 
     def __init__(self):
         self.reqs: Dict[str, Any] = {}
@@ -1151,6 +1371,8 @@ class _Round:
         self.event = threading.Event()
         self.closed = False
         self.t_exec = 0.0    # monotonic instant the round's step started
+        self.t_done = 0.0    # ... and the instant its results were read
+        self.rider = None    # a burst round's ONE joining request (prefill)
 
 
 class _SlotArenaView:
@@ -1207,6 +1429,9 @@ class BatchingStageAdapter:
         # speculative verify) or ('burst', N) (burst rounds never share a
         # compiled program with single-tick rounds).
         self._rounds: Dict[Any, _Round] = {}
+        # Ticks of the burst rounds a joining request may ride (`warmup`
+        # sets it to the burst it compiles; 0: prefills are programs).
+        self.burst_ticks = 0
         # Telemetry (global registry; strict no-op unless enabled). Step
         # latency itself is observed at the serving boundary (LocalTransport
         # / TcpStageServer) — the adapter owns the batching-specific signals.
@@ -1258,6 +1483,8 @@ class BatchingStageAdapter:
                                 "temperature": 0.0, "top_p": 1.0,
                                 "top_k": 0, "repetition_penalty": 1.0}},
                 burst)
+            if self.inner.rider_rows:
+                self.burst_ticks = burst
         self.inner.end_session("__warmup__")
 
     # -- protocol ----------------------------------------------------------
@@ -1334,6 +1561,8 @@ class BatchingStageAdapter:
 
         prof = _get_profiler()
         sid = req.session_id
+        if self._rides(req):
+            return self._prefill_riding(req)
         # A request's life up to its first token, as three phases: the wait
         # for the lock (a round leader holds it through its whole step,
         # readback included), the prefill under it, the first token after it.
@@ -1361,6 +1590,55 @@ class BatchingStageAdapter:
             return self._respond(req, h, cache_len)
         with prof.phase("first_token", session=sid):
             return self._respond(req, h, cache_len)
+
+    def _rides(self, req) -> bool:
+        """Whether this prefill joins a burst round as its rider instead of
+        running the prefill program: the engine has a lane, the prompt (ids,
+        whole, no stored prefix to copy) fits one burst of it, and ANOTHER
+        session holds a slot. A program between two rounds is paid by every
+        session that is decoding, in the gap it happens to fall into; on an
+        engine nobody else is using it is the shorter way to a first token
+        (a few tens of ms against a whole burst)."""
+        held = self.inner._slot_of
+        return (self.burst_ticks > 0
+                and np.ndim(req.hidden) == 2
+                and self.inner.can_ride(req.seq_len, self.burst_ticks)
+                and not (self.inner.prefix_store is not None
+                         and req.prefix_len > 0)
+                and len(held) > (req.session_id in held))
+
+    def _prefill_riding(self, req):
+        """A prefill as the rider of the next burst round (`_burst_round`).
+        Its three phases: ``prefill_wait`` from entry until that round's
+        step starts (the lock, the lane if another request has it, the
+        window), ``prefill`` the step under the lock, which writes the
+        prompt's rows and samples the token, ``first_token`` from the
+        results on the host to the response."""
+        from .messages import StageResponse
+
+        prof = _get_profiler()
+        t0 = time.monotonic()
+        r = self._burst_round(req, self.burst_ticks, rider=True)
+        out = r.outs[req.session_id]
+        prof.observe("prefill_wait", r.t_exec - t0)
+        prof.observe("prefill", r.t_done - r.t_exec)
+        prof.observe("first_token", time.monotonic() - r.t_done)
+        return StageResponse(session_id=req.session_id,
+                             token_id=out["token"],
+                             cache_len=out["cache_len"])
+
+    def _validate_rider(self, r: "_Round", n: int) -> Optional[str]:
+        """Admission of a round's rider (caller holds the lock)."""
+        req = r.rider
+        sid = req.session_id
+        if sid in r.reqs:
+            return f"session {sid}: prefill concurrent with its own decode"
+        if not self.inner.can_ride(req.seq_len, n):
+            return (f"session {sid}: a prompt of {req.seq_len} rows does "
+                    f"not ride a burst of {n} ticks")
+        if not self.inner._free and sid not in self.inner._slot_of:
+            return f"all {self.inner.slots} session slots in use"
+        return None
 
     def _validate(self, req) -> Optional[str]:
         """Per-session admission (caller holds the lock). Returns a refusal
@@ -1500,28 +1778,53 @@ class BatchingStageAdapter:
         ('burst', N) so classic single-tick rounds and burst rounds never
         mix widths. Sessions join/leave only at round (= burst)
         boundaries."""
-        from .executor import StageExecutionError
         from .messages import StageResponse
+
+        sid = req.session_id
+        r = self._burst_round(req, int(req.burst_len))
+        out = r.outs[sid]
+        return StageResponse(session_id=sid,
+                             burst_tokens=tuple(out["tokens"]),
+                             burst_stop=out["stop"],
+                             cache_len=r.lengths[sid])
+
+    def _burst_round(self, req, n: int, rider: bool = False) -> _Round:
+        """Take ``req`` through one burst round of ``n`` ticks, as its leader
+        (whoever creates it) or a follower, and return the round once its
+        step has run; raises what the round or this session failed with.
+        ``rider``: ``req`` is a PREFILL that joins as the round's rider
+        (`_rides`). A round carries one; a second waits for that round to
+        run and tries the next."""
+        from .executor import StageExecutionError
 
         prof = _get_profiler()
         sid = req.session_id
-        n = int(req.burst_len)
         key = ("burst", n)
         t_join = time.monotonic()
-        with self._lock:
-            reason = self._validate(req) or self._validate_burst(req)
-            if reason is not None:
-                raise StageExecutionError(reason)
-            r = self._rounds.get(key)
-            if r is None or r.closed:
-                r = self._rounds[key] = _Round()
-                leader = True
-            else:
-                leader = False
-            if sid in r.reqs:
-                raise StageExecutionError(
-                    f"session {sid}: concurrent decode for one session")
-            r.reqs[sid] = req
+        while True:
+            with self._lock:
+                reason = (None if rider else
+                          self._validate(req) or self._validate_burst(req))
+                if reason is not None:
+                    raise StageExecutionError(reason)
+                r = self._rounds.get(key)
+                if r is None or r.closed:
+                    r = self._rounds[key] = _Round()
+                    leader = True
+                else:
+                    leader = False
+                if not rider:
+                    if sid in r.reqs:
+                        raise StageExecutionError(
+                            f"session {sid}: concurrent decode for one "
+                            "session")
+                    r.reqs[sid] = req
+                    break
+                if r.rider is None:
+                    r.rider = req
+                    break
+            if not r.event.wait(self.step_timeout):   # the lane is taken
+                raise StageExecutionError("batched step timed out")
         if leader:
             try:
                 with prof.span("round_window", session=sid):
@@ -1538,22 +1841,31 @@ class BatchingStageAdapter:
                             good[s_id] = rq
                         else:
                             r.bad[s_id] = reason
-                    if good:
+                    riding = None
+                    if r.rider is not None:
+                        reason = self._validate_rider(r, n)
+                        if reason is None:
+                            riding = _rider_entry(r.rider)
+                        else:
+                            r.bad[r.rider.session_id] = reason
+                    if good or riding:
                         r.t_exec = time.monotonic()
-                        self._m_fill.observe(len(good))
-                        self._m_held.observe(len(self.inner._slot_of))
+                        if good:
+                            self._m_fill.observe(len(good))
+                            self._m_held.observe(len(self.inner._slot_of))
                         r.outs = self.inner.decode_burst(
                             {s_id: _burst_entry(rq)
-                             for s_id, rq in good.items()}, n)
+                             for s_id, rq in good.items()}, n, rider=riding)
                         r.lengths = {
                             s_id: int(
                                 self.inner.lengths[self.inner.slot(s_id)])
                             for s_id in good
                         }
-                        self._m_round.observe(time.monotonic() - r.t_exec)
+                        r.t_done = time.monotonic()
+                        self._m_round.observe(r.t_done - r.t_exec)
                         _ev.emit("burst_round", sessions=len(good), ticks=n,
-                                 tokens=sum(len(o["tokens"])
-                                            for o in r.outs.values()))
+                                 tokens=sum(len(r.outs[s_id]["tokens"])
+                                            for s_id in good))
             except Exception as exc:  # whole-round failure
                 r.err = exc
                 with self._lock:
@@ -1566,17 +1878,13 @@ class BatchingStageAdapter:
             with prof.span("round_wait", session=sid):
                 if not r.event.wait(self.step_timeout):
                     raise StageExecutionError("batched step timed out")
-        if r.t_exec:
+        if r.t_exec and not rider:
             self._m_queue_wait.observe(max(0.0, r.t_exec - t_join))
         if r.err is not None:
             raise StageExecutionError(str(r.err)) from r.err
         if sid in r.bad:
             raise StageExecutionError(r.bad[sid])
-        out = r.outs[sid]
-        return StageResponse(session_id=sid,
-                             burst_tokens=tuple(out["tokens"]),
-                             burst_stop=out["stop"],
-                             cache_len=r.lengths[sid])
+        return r
 
     def _verify_spec_rows(self, r: _Round, good: Dict[str, Any]) -> None:
         """Per-row speculative verification on the final stage (caller holds

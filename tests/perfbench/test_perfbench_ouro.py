@@ -1,18 +1,10 @@
-"""The Ouro-2.6B configuration and the cell prepared for it: the file holds
-the published config key for key with nothing reduced, the reference's
-count of a tick reads the weights once a PASS and the cache once a (pass,
-layer), the two readers the cell brings read their counters and find
-nothing in a program without them, and the whole harness rehearses on the
-CPU at the same preset cut to 2 layers (registry, server, load generator,
-traced window, the check with all 4 passes).
-
-``BENCHMARK.json`` does NOT name the cell: PR 34 left it out because its
-``gap_p75_ms`` is not steady enough to be admitted (PERF.md section 6). The
-files the cell needs are under ``perfbench/``; its entries are
-``fixtures/ouro_cell_entries.json``, laid here over a copy of
-``BENCHMARK.json`` in a throw-away root whose ``perfbench/`` and program
-are the checkout's (`cell_root`), which is also how the cell is run by
-hand until a ``benchmark`` issue admits it."""
+"""The Ouro-2.6B configuration and its cell: the file holds the published
+config key for key with nothing reduced, the reference's count of a tick
+reads the weights once a PASS and the cache once a (pass, layer), the two
+readers this cell brings read their counters and find nothing in a program
+without them, and the whole harness rehearses on the CPU at the same
+preset cut to 2 layers (registry, server, load generator, traced window,
+the check with all 4 passes)."""
 
 import json
 import os
@@ -43,42 +35,9 @@ PUBLISHED = {
 LAYER = 4 * 2048 * 2048 + 3 * 2048 * 5632        # q, k, v, o; gate, up, down
 
 
-PKG = "global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu"
-ENTRIES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "fixtures", "ouro_cell_entries.json")
-
-
-def cell_root(dst):
-    """A root at ``dst`` whose BENCHMARK.json is the checkout's with the
-    cell's entries appended, and whose benchmark and program are links to
-    the checkout's."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    with open(ENTRIES) as f:
-        gain = json.load(f)
-    for key in ("configs", "workloads"):
-        bench[key] += gain[key]
-    for metric in bench["per_layer"]:
-        if metric["name"] in gain["append_cell_to"]:
-            metric["workloads"].append(CELL)
-    bench["per_layer"] += gain["per_layer"]
-    os.makedirs(dst, exist_ok=True)
-    with open(os.path.join(dst, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f, indent=1)
-    for name in ("perfbench", PKG):
-        if not os.path.lexists(os.path.join(dst, name)):
-            os.symlink(os.path.join(ROOT, name), os.path.join(dst, name))
-    return str(dst)
-
-
 @pytest.fixture(scope="module")
-def root(tmp_path_factory):
-    return cell_root(tmp_path_factory.mktemp("cell_root"))
-
-
-@pytest.fixture(scope="module")
-def man(root):
-    return Manifest(root)
+def man():
+    return Manifest(ROOT)
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +50,7 @@ def ref(man, body):
     return load_module(man.reference_file(body))
 
 
-def test_the_benchmark_validates_with_the_cell_s_entries(man, body):
+def test_the_benchmark_validates_with_the_new_cell(man, body):
     man.validate()
     entry = man.config_entry(CONFIG)
     assert entry["reduced"] == body["reduced"] == []
@@ -259,19 +218,7 @@ def test_a_reader_finds_nothing_in_a_program_without_the_counters(
     assert readers.read_metric(man, name, ctx) is None
 
 
-def test_the_checkout_s_benchmark_does_not_name_the_cell():
-    """Until a ``benchmark`` issue admits it: a configuration that no cell
-    runs, or a cell whose end-to-end metric flips between two levels, is
-    not the benchmark's."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert CONFIG not in {c["name"] for c in bench["configs"]}
-    assert CELL not in {w["name"] for w in bench["workloads"]}
-    assert all(CELL not in m.get("workloads", ())
-               for m in bench["per_layer"] + bench["end_to_end"])
-
-
-def test_traced_dry_run_of_the_cell(tmp_path, root):
+def test_traced_dry_run_of_the_cell(tmp_path):
     """The whole harness on the CPU: the cell's preset at 2 layers serves,
     the check runs 6 layers x 4 passes at published widths against the
     reference and comes out ``correct``, and the cell's own metrics are on
@@ -280,11 +227,11 @@ def test_traced_dry_run_of_the_cell(tmp_path, root):
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     env.pop("XLA_FLAGS", None)
     res = subprocess.run(
-        [sys.executable, os.path.join(root, "perfbench", "run.py"),
+        [sys.executable, os.path.join(ROOT, "perfbench", "run.py"),
          "--workload", CELL, "--seed", str(2 ** 31 + 134),
          "--seconds", "4", "--trace", "1", "--dry-run-cpu",
          "--out", str(tmp_path / "out")],
-        cwd=root, env=env, capture_output=True, text=True, timeout=1200)
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=1200)
     assert res.returncode == 0, res.stdout[-3000:] + res.stderr[-2000:]
     lines = res.stdout.strip().splitlines()
     last = json.loads(lines[-1])
