@@ -41,6 +41,17 @@ seed schedule ``PRNGKey(step_seed + i)`` (bit-identical to the sequential
 ``_sample_rows`` path), and maintaining per-slot alive masks so eos /
 repeat / budget stops truncate mid-scan without a host round trip. The
 host pays one dispatch per N tokens instead of one per token.
+
+THE RIDER LANE (a looped stack's engine only: `BatchedStageExecutor
+.rider_rows`): there a prefill program streams the weights `loop_steps`
+times, as long as a whole tick, and every session that is decoding pays for
+it in whichever gap it falls into. So each burst tick also carries
+`RIDER_ROWS` prompt rows of the ONE request that joins during the burst,
+flat beside the slots' rows through every matmul, and samples that
+request's first token; a prefill that finds another session in a slot
+joins the next burst round as its rider (`BatchingStageAdapter._rides`). A
+lane with no rider does the same work and writes nothing: every round costs
+the same.
 """
 
 from __future__ import annotations
@@ -378,7 +389,7 @@ def _at(base, i):
     return i if base is None else base + i
 
 
-def _append_rows(stack, i, new, lengths, active, slots=None):
+def _append_rows(stack, i, new, lengths, active):
     """``stack`` (``[L, S, max_len, Hkv, Dh]``) with ``new`` (``[S, T, Hkv,
     Dh]``) at ``stack[i, s, lengths[s] : lengths[s] + T]`` for every slot:
     ONE scatter of S x T rows, so the carried stack is updated in place
@@ -391,15 +402,11 @@ def _append_rows(stack, i, new, lengths, active, slots=None):
     donated buffers). A row (``[Hkv, Dh]``, the scatter's window) at a
     ``(layer, slot, position)`` point is the form the TPU keeps as one
     native scatter; a window that spans the T positions is expanded into
-    a loop over the slots. ``slots`` (``[n]``; default every slot in
-    order) names the slot of each of ``new``'s ``n`` sequences, and
-    ``active`` may be per ROW (``[n, T]``): the rider's chunk
-    (`_decode_span`), whose last one ends inside its T rows."""
-    n, t = new.shape[:2]
+    a loop over the slots."""
+    slots, t = new.shape[:2]
     start = jnp.clip(lengths, 0, stack.shape[2] - t)
     at = jnp.stack(jnp.broadcast_arrays(
-        i, (jnp.arange(n, dtype=jnp.int32) if slots is None
-            else slots)[:, None],
+        i, jnp.arange(slots, dtype=jnp.int32)[:, None],
         start[:, None] + jnp.arange(t, dtype=jnp.int32)), axis=-1)
     old = jax.lax.gather(
         stack, at,
@@ -408,15 +415,33 @@ def _append_rows(stack, i, new, lengths, active, slots=None):
             start_index_map=(0, 1, 2)),
         slice_sizes=(1, 1, 1) + new.shape[2:], mode="promise_in_bounds",
         unique_indices=True, indices_are_sorted=True)
-    keep = (active[:, None, None, None] if active.ndim == 1
-            else active[:, :, None, None])
     return jax.lax.scatter(
-        stack, at, jnp.where(keep, new, old),
+        stack, at, jnp.where(active[:, None, None, None], new, old),
         jax.lax.ScatterDimensionNumbers(
             update_window_dims=(2, 3), inserted_window_dims=(0, 1, 2),
             scatter_dims_to_operand_dims=(0, 1, 2)),
         mode="promise_in_bounds", unique_indices=True,
         indices_are_sorted=True)
+
+
+def _write_rows(stack, i, new, slots, positions, keep):
+    """``stack`` with row r of ``new`` (``[n, Hkv, Dh]``) at ``stack[i,
+    slots[r], positions[r]]`` where ``keep[r]``: ONE scatter of n row
+    points; a row that is not kept goes to a point past ``max_len`` of its
+    own, which the scatter drops. The lane's form of `_append_rows`: S + C
+    points, no gather of what an inactive slot holds (on the v5e 1.4 ms a
+    tick of 192 layer-visits against 2.6 as two appends: PERF.md, PR 34);
+    an active slot's position is the caller's to keep inside the slot."""
+    n = new.shape[0]
+    out = stack.shape[2] + jnp.arange(n, dtype=jnp.int32)
+    at = jnp.stack(jnp.broadcast_arrays(
+        i, slots, jnp.where(keep, positions, out)), axis=-1)
+    return jax.lax.scatter(
+        stack, at, new,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2), inserted_window_dims=(0, 1, 2),
+            scatter_dims_to_operand_dims=(0, 1, 2)),
+        mode="drop", unique_indices=True)
 
 
 def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
@@ -436,9 +461,10 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
     "valid": [C] (rows of the prompt; a lane with no rider has none),
     "slot", "rows": R}``: the rows enter the layers FLAT with the slots'
     (``[1, S + C, D]``: one read of every weight for both), are written to
-    ``[slot, start : start + C)`` of every cache layer and attend over that
-    slot's first R rows, causally. ``h`` and ``steps`` come back flat, the
-    slots' S rows first."""
+    ``[slot, start : start + C)`` of every cache layer in the ONE scatter
+    that writes the slots' rows (`_write_rows`) and attend over that slot's
+    first R rows, causally. ``h`` and ``steps`` come back flat, the slots'
+    S rows first."""
     slots = x.shape[0]
     if rider is not None:
         r_pos = rider["start"] + jnp.arange(
@@ -465,6 +491,13 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
         r_allowed = r_grid <= r_qpos
         if cfg.sliding_window:
             r_allowed &= r_grid > r_qpos - cfg.sliding_window
+        # Where the flat rows go in a cache layer: (slot, position, kept).
+        points = (
+            jnp.concatenate([jnp.arange(slots, dtype=jnp.int32),
+                             jnp.broadcast_to(rider["slot"], r_pos.shape)]),
+            jnp.concatenate([jnp.clip(lengths, 0, k_all.shape[2] - 1),
+                             r_pos]),
+            jnp.concatenate([active, rider["valid"]]))
 
     def one_pass(h, base, k_all, v_all):
         def layer(h, xs):
@@ -495,17 +528,13 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
                 for stack, rows in ((k_all, k), (v_all, v)):
                     rows = rows[0].astype(stack.dtype)      # [S + C, ..]
                     with jax.named_scope("kv_update"):
-                        stack = _append_rows(
-                            stack, at, rows[:slots, None], lengths, active)
-                        stack = _append_rows(
-                            stack, at, rows[None, slots:],
-                            rider["start"][None], rider["valid"][None],
-                            slots=rider["slot"][None])
+                        stack = _write_rows(stack, at, rows, *points)
                     with jax.named_scope("attention"):
                         slab = jax.lax.dynamic_index_in_dim(
                             stack, at, 0, keepdims=False)
-                        mine = jax.lax.dynamic_slice_in_dim(
-                            slab, rider["slot"], 1, 0)[:, :rider["rows"]]
+                        mine = jax.lax.dynamic_slice(
+                            stack, (at, rider["slot"], 0, 0, 0),
+                            (1, 1, rider["rows"]) + stack.shape[3:])[0]
                     new.append(stack)
                     read.append((slab, mine))
                 return (read[0], read[1],
@@ -1267,16 +1296,20 @@ class BatchedStageExecutor:
         ids[:len(r["ids"])] = r["ids"]
         win = tuple(int(t) for t in r["generated"])[-RECENT_WINDOW:]
         recent[:len(win)] = win
-        return {"ids": jnp.asarray(ids.reshape(n_ticks, c)),
-                "len": jnp.int32(len(r["ids"])),
-                "slot": jnp.int32(r["slot"]),
-                "seed": jnp.int32(r["seed"]),
-                "recent": jnp.asarray(recent),
-                "nvalid": jnp.int32(len(win)),
-                "temp": jnp.float32(r["temperature"]),
-                "top_p": jnp.float32(r["top_p"]),
-                "top_k": jnp.int32(r["top_k"]),
-                "rp": jnp.float32(r["repetition_penalty"])}
+        # Each of the eight scalars still costs an eager convert program on
+        # the device (``jnp.asarray`` of a numpy SCALAR, as ``jnp.int32(n)``;
+        # a 0-d array would be a transfer): PERF.md section 7.
+        return {name: jnp.asarray(value) for name, value in (
+            ("ids", ids.reshape(n_ticks, c)),
+            ("len", np.int32(len(r["ids"]))),
+            ("slot", np.int32(r["slot"])),
+            ("seed", np.int32(r["seed"])),
+            ("recent", recent),
+            ("nvalid", np.int32(len(win))),
+            ("temp", np.float32(r["temperature"])),
+            ("top_p", np.float32(r["top_p"])),
+            ("top_k", np.int32(r["top_k"])),
+            ("rp", np.float32(r["repetition_penalty"])))}
 
     def decode_burst(self, entries: Dict[str, dict], n_ticks: int,
                      rider: Optional[dict] = None) -> Dict[str, dict]:

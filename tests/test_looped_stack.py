@@ -207,18 +207,190 @@ def test_the_16_tick_burst_emits_the_reference_s_tokens(ref, kind):
 
 
 def test_burst_returns_the_passes_only_for_a_looped_stack(ref):
-    """The looped program's last result is the passes its tokens took;
-    a one-pass program has the twelve results it always had."""
+    """The looped program returns, after the twelve, the passes its tokens
+    took and its rider's token (none: -1) and takes the rider as one more
+    argument; a one-pass program has the arguments and the twelve results
+    it always had, and no lane."""
     _, _, eng = build(ref)
     eng.prefill("s", ids_of(6)[None])
     rows, args = eng._burst_prep({"s": burst_entry(3, 4)}, 4)
-    out = eng._get_burst_jit(4)(eng.params, *args, eng.k, eng.v)
-    assert len(out) == 13 and int(out[12]) == PASSES * 4
+    out = eng._get_burst_jit(4)(eng.params, *args, eng.k, eng.v,
+                                eng._rider_args(None, 4))
+    assert len(out) == 14 and int(out[12]) == PASSES * 4
+    assert int(out[13]) == -1 and eng.rider_rows == batching.RIDER_ROWS
     _, _, once = build(ref, hf=dict(HF, total_ut_steps=1))
     once.prefill("s", ids_of(6)[None])
     rows, args = once._burst_prep({"s": burst_entry(3, 4)}, 4)
     assert len(once._get_burst_jit(4)(once.params, *args, once.k,
                                       once.v)) == 12
+    assert once.rider_rows == 0 and not once.can_ride(8, 4)
+
+
+# -- the rider lane: a joining request's prompt rows in the burst's ticks ----
+
+def rider_of(sid, ids, **knobs):
+    return {"session_id": sid, "ids": np.asarray(ids), "seed": 7,
+            "generated": (), "temperature": 0.0, "top_p": 1.0, "top_k": 0,
+            "repetition_penalty": 1.0, **knobs}
+
+
+def two_decoding(eng):
+    """Sessions x and y prefilled on ``eng``; their entries of a burst."""
+    seqs = {"x": ids_of(9, 1), "y": ids_of(13, 2)}
+    for sid, ids in seqs.items():
+        eng.prefill(sid, ids[None, :-1])
+    return {sid: burst_entry(ids[-1], 4) for sid, ids in seqs.items()}
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("t", [1, 16, 17, 40])
+def test_a_rider_s_rows_are_the_prefill_program_s(ref, t, kind):
+    """A prompt of t rows (less than a chunk, one chunk exactly, one row
+    into the second, three chunks with the last part full) riding a 4-tick
+    burst in which two other sessions decode, against the prefill program
+    on a twin engine after the same burst: the decoding sessions' tokens
+    are the twin's, the rider's first token is the twin's greedy one, its K
+    and V rows of every (pass, layer) are the program's, its length is t,
+    and its NEXT row through the cache is the reference's."""
+    _, weights, a = build(ref, kind)
+    _, _, b = build(ref, kind)
+    ids = ids_of(t + 1, 3)
+    ent_a, ent_b = two_decoding(a), two_decoding(b)
+    got = a.decode_burst(ent_a, 4, rider=rider_of("r", ids[:t]))
+    want = b.decode_burst(ent_b, 4)
+    h = b.prefill("r", ids[None, :t])
+    for sid in ent_a:
+        assert got[sid] == want[sid]
+    assert got["r"] == {
+        "token": int(np.argmax(np.asarray(b.logits(h))[0, -1])),
+        "cache_len": t}
+    assert a.lengths[a.slot("r")] == t
+    loose = {"float32": 1e-5, "bfloat16": 2e-2, "int8": 2e-2}[kind]
+    for mine, theirs in ((a.k, b.k), (a.v, b.v)):
+        assert rel_rms(mine[:, a.slot("r"), :t],
+                       theirs[:, b.slot("r"), :t]) <= loose
+    out = a.decode_batch({"r": ids[None, t:t + 1]})
+    row = np.asarray(a.logits(out["r"]))[0, 0]
+    assert rel_rms(row, reference_logits(ref, weights, ids)[t]) <= (
+        TOLERANCE[kind])
+
+
+def test_a_rider_s_sampled_token_is_the_host_s(ref):
+    """Sampled (temperature, top-p, a penalty over the tokens sent so far):
+    the device draws the rider's first token with the key and the knobs
+    `executor._sample_rows` gives a prefill's on the host."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
+        _sample_last,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+        SamplingParams,
+    )
+
+    _, _, a = build(ref)
+    _, _, b = build(ref)
+    ids = ids_of(21, 4)
+    knobs = {"temperature": 0.9, "top_p": 0.9, "top_k": 0,
+             "repetition_penalty": 1.3}
+    hit = 0
+    for seed in range(6):
+        got = a.decode_burst(two_decoding(a), 4, rider=rider_of(
+            "r", ids, seed=seed, generated=(5, 9), **knobs))
+        req = types.SimpleNamespace(
+            sampling=SamplingParams(**knobs), generated_tokens=(5, 9),
+            step_seed=seed)
+        want = _sample_last(b.logits(b.prefill("r", ids[None])), 21, req)
+        assert got["r"]["token"] == want, seed
+        hit += want != int(np.argmax(np.asarray(
+            b.logits(b.prefill("r", ids[None])))[0, -1]))
+    assert hit          # the draw is not the argmax on every seed
+
+
+def test_a_lane_without_a_rider_writes_nothing(ref):
+    """A burst with no rider leaves every slot that does not decode as it
+    was, bit for bit: the lane's rows point at slot 0 and write back what
+    they read."""
+    _, _, eng = build(ref)
+    eng.prefill("idle", ids_of(30, 5)[None])         # slot 0? whichever
+    entries = two_decoding(eng)
+    s = eng.slot("idle")
+    before = (np.asarray(eng.k[:, s]), np.asarray(eng.v[:, s]))
+    eng.decode_burst(entries, 4)
+    np.testing.assert_array_equal(np.asarray(eng.k[:, s]), before[0])
+    np.testing.assert_array_equal(np.asarray(eng.v[:, s]), before[1])
+    free = [f for f in range(eng.slots) if f not in eng._slot_of.values()]
+    assert not free or not np.asarray(eng.k[:, free[0]]).any()
+
+
+def test_what_does_not_fit_the_lane_does_not_ride(ref):
+    _, _, eng = build(ref)                           # 64-row slots
+    assert eng.can_ride(64, 4) and not eng.can_ride(65, 4)
+    assert not eng.can_ride(0, 4) and not eng.can_ride(17, 1)
+    with pytest.raises(ValueError, match="does not ride"):
+        eng.decode_burst({}, 1, rider=rider_of("r", ids_of(17)))
+    assert eng.slot("r") is None and len(eng._free) == eng.slots
+
+
+def stage_request(sid, ids, *, cur_len=0, burst=0, prefill=False, seed=0):
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+        SamplingParams,
+        StageRequest,
+    )
+
+    return StageRequest(
+        session_id=sid, hidden=jnp.asarray([ids], jnp.int32),
+        seq_len=len(ids), cur_len=cur_len, is_prefill=prefill,
+        max_length=64, sampling=SamplingParams(temperature=0.0),
+        step_seed=seed, burst_len=burst, burst_budget=burst)
+
+
+def test_a_prefill_rides_when_another_session_holds_a_slot(ref):
+    """Through the adapter. The first prefill finds the engine empty and
+    runs the prefill program; with that session in its slot, two more
+    prefills that arrive together each ride a burst round of their own (a
+    round carries one rider), beside the first session's burst; the tokens
+    are those of a twin engine that ran the program for all three; a
+    prefill with a stored prefix to copy, or one too long for the lane,
+    runs the program."""
+    import threading
+
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
+        BatchingStageAdapter,
+    )
+
+    _, _, eng = build(ref)
+    _, _, twin = build(ref)
+    ad = BatchingStageAdapter(eng, window_s=0.05)
+    ad.warmup(burst=2)
+    assert ad.burst_ticks == 2
+    prompts = {"a": ids_of(11, 1), "b": ids_of(19, 2), "c": ids_of(5, 3)}
+    want = {sid: int(np.argmax(np.asarray(
+        twin.logits(twin.prefill(sid, ids[None])))[0, -1]))
+        for sid, ids in prompts.items()}
+    first = ad.forward(stage_request("a", prompts["a"], prefill=True))
+    assert first.token_id == want["a"] and eng.burst_dispatches == 1
+    got = {}
+
+    def send(sid, req):
+        got[sid] = ad.forward(req)
+
+    threads = [threading.Thread(target=send, args=(sid, stage_request(
+        sid, prompts[sid], prefill=True))) for sid in "bc"]
+    threads.append(threading.Thread(target=send, args=("a", stage_request(
+        "a", [first.token_id], cur_len=11, burst=2))))
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(120)
+    assert {sid: got[sid].token_id for sid in "bc"} == {
+        "b": want["b"], "c": want["c"]}
+    assert (got["b"].cache_len, got["c"].cache_len) == (19, 5)
+    assert eng.burst_dispatches == 3        # warm-up's + one a rider
+    assert len(got["a"].burst_tokens) == 2
+    res = twin.decode_burst({"a": burst_entry(want["a"], 2)}, 2)
+    assert list(got["a"].burst_tokens) == res["a"]["tokens"]
+    ad.drop_session("c")
+    long = ad.forward(stage_request("d", ids_of(40, 4), prefill=True))
+    assert eng.burst_dispatches == 3 and long.cache_len == 40
 
 
 # -- the gate and the exit rule ----------------------------------------------
@@ -264,7 +436,8 @@ def test_a_forced_early_exit_chooses_the_reference_s_pass(ref, bias):
     # the burst, straight from its program: greedy tokens, passes counted
     eng.rewind("s", 12)
     rows, args = eng._burst_prep({"s": burst_entry(ids[12], 6)}, 6)
-    out = eng._get_burst_jit(6)(eng.params, *args, eng.k, eng.v)
+    out = eng._get_burst_jit(6)(eng.params, *args, eng.k, eng.v,
+                                eng._rider_args(None, 6))
     eng.k, eng.v = out[10], out[11]
     toks = np.asarray(out[0])[:, rows["s"]]
     toks = toks[toks >= 0]              # a greedy repeat may end it early
