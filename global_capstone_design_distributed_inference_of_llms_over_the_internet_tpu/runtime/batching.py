@@ -56,6 +56,7 @@ the same.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 from functools import partial
@@ -187,24 +188,161 @@ def _residual(cfg, lp, h, attn_out):
         return h + mlp_out
 
 
-def _attend(cfg, lp, q, keys, values, grid):
-    """Attention of ``q`` (``[B, T, H, Dh]``, rotated) over ``keys`` and
-    ``values`` (``[B, S, Hkv, Dh]``) under ``grid`` = ``(mask, q_pos,
-    k_pos)``: ``[B, T, H * Dh]``, before the output projection."""
+def _visible(cfg, q_pos, k_pos):
+    """Keys a query may see in a cache: everything up to and including its
+    own position (causal within a block of new rows too), inside the
+    family's window ``(q_pos - window, q_pos]`` where it has one."""
+    allowed = k_pos <= q_pos
+    if cfg.sliding_window:
+        allowed &= k_pos > q_pos - cfg.sliding_window
+    return allowed
+
+
+def _masked_scores(cfg, lp, qg, keys, grid):
+    """Float32 scores ``[B, Hkv, G, T, S]`` of the grouped, scaled queries
+    ``qg`` (``[B, T, Hkv, G, Dh]``) against ``keys`` (``[B, S, Hkv, Dh]``),
+    softcapped, with ``NEG_INF`` wherever ``grid`` = ``(mask, q_pos,
+    k_pos)`` and the layer's window forbid."""
     mask, q_pos, k_pos = grid
-    b, t = q.shape[:2]
-    groups = cfg.num_heads // cfg.num_kv_heads
-    qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
     scores = jnp.einsum(
-        "bthgd,bshd->bhgts", qg * _qscale(cfg), keys.astype(q.dtype),
+        "bthgd,bshd->bhgts", qg, keys.astype(qg.dtype),
         preferred_element_type=jnp.float32)          # [B, Hkv, G, T, S]
     m = _layer_mask(lp, mask, q_pos, k_pos)
     m = m[:, None, None] if m.ndim == 3 else m[None, None, None]
-    scores = _softcap_and_mask(cfg, scores, m)
+    return _softcap_and_mask(cfg, scores, m)
+
+
+def _attend(cfg, lp, q, keys, values, grid):
+    """Attention of ``q`` (``[B, T, H, Dh]``, rotated) over ``keys`` and
+    ``values`` (``[B, S, Hkv, Dh]``) under ``grid`` = ``(mask, q_pos,
+    k_pos)``: ``[B, T, H * Dh]``, before the output projection. Over a
+    `_CacheLayer` (of ``grid`` only ``q_pos`` then: the mask and ``k_pos``
+    are each block's own): `_attend_cached`."""
+    if isinstance(keys, _CacheLayer):
+        return _attend_cached(cfg, lp, q, keys, values, grid[1])
+    b, t = q.shape[:2]
+    groups = cfg.num_heads // cfg.num_kv_heads
+    qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
+    scores = _masked_scores(cfg, lp, qg * _qscale(cfg), keys, grid)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgts,bshd->bthgd", probs.astype(values.dtype),
                      values.astype(q.dtype))
     return out.reshape(b, t, -1)
+
+
+# Rows of a cache layer that a decode program reads in one piece
+# (`_attend_cached`): the served ``max_len``s are multiples of it. Chosen on
+# the v5e among 64, 128 and 256 (PERF.md section 6, PR 35).
+ATTN_BLOCK = 128
+
+
+def attn_block(max_len: int) -> int:
+    """The block of a ``max_len``-row slot: the largest divisor of
+    ``max_len`` that is at most `ATTN_BLOCK` (`ATTN_BLOCK` itself for every
+    multiple of it). A slot shorter than `ATTN_BLOCK`, or of a length with
+    no divisor above a quarter of it (a prime, say), is ONE block: the full
+    read, and not a thousand blocks of a row."""
+    return next((b for b in range(min(ATTN_BLOCK, max_len), ATTN_BLOCK // 4,
+                                  -1) if max_len % b == 0), max_len)
+
+
+def attn_blocks(lengths, active, t, max_len, xp=np):
+    """How many blocks of `attn_block` rows a step of ``t`` new rows a slot
+    reads of every cache layer: up to the last new row of the LONGEST
+    ACTIVE slot (along the last axis of ``lengths`` / ``active``), and none
+    where no slot is active. An inactive slot's rows are never needed: its
+    output is discarded. The ONE statement of the bound: the decode
+    programs call it on traced values (``xp=jnp``) and the host, for
+    ``server_attn_rows_read_total``, on the lengths a step began with."""
+    block = attn_block(max_len)
+    longest = xp.max(xp.where(active, lengths + t, 0), axis=-1)
+    return xp.minimum(-(-longest // block), max_len // block)
+
+
+@dataclasses.dataclass(frozen=True)
+class _CacheLayer:
+    """Layer ``at`` of a carried ``[L, S, max_len, Hkv, Dh]`` cache stack,
+    of which only the first ``blocks`` (traced) blocks of `attn_block` rows
+    are to be read, straight out of the stack."""
+    stack: Any
+    at: Any
+    blocks: Any
+
+    def rows(self, start, n: int):
+        """Rows ``[start, start + n)`` of every slot: ``[S, n, Hkv, Dh]``."""
+        return jax.lax.dynamic_slice(
+            self.stack, (self.at, 0, start, 0, 0),
+            (1, self.stack.shape[1], n) + self.stack.shape[3:])[0]
+
+
+def _attend_cached(cfg, lp, q, keys, values, q_pos):
+    """`_attend` of ``q`` (``[S, T, H, Dh]``, at ``q_pos`` ``[S, T, 1]``)
+    over the cache layers ``keys`` and ``values`` (`_CacheLayer`), reading
+    only their first ``keys.blocks`` blocks of `attn_block` rows. A row
+    past a query's position has probability exactly 0 in `_attend`, so
+    leaving the blocks past the longest active slot unread changes no term
+    of any sum. The count is a value of the program: ONE program serves
+    every occupancy. Two forms, by the shape of the products alone (chosen
+    on the v5e: PERF.md section 6, PR 35):
+
+    ONE query row a KV head (T = 1 and no grouped queries): the products
+    are matrix-vector, reductions on the VPU that XLA fuses with the slice
+    of the stack. A ``switch`` on the count picks `_attend` over a STATIC
+    prefix of 0, 1, 2, ... blocks: today's fused read, shorter; the last
+    branch is the full read at its old cost, and nothing is paid a block.
+
+    Several query rows a KV head (grouped queries, or T > 1: the
+    speculative verify): the products go to the MXU, which wants its
+    operand in a buffer of its own; inside a conditional's branch XLA
+    re-lays the WHOLE STACK for it (a stack-sized temporary and 1.9 x the
+    qwen2-7b tick). So: a loop with a traced trip count over the blocks,
+    online softmax (float32 running max, denominator and weighted sum),
+    one block-sized operand a trip; ~4 us a block a layer of loop and
+    statistics, which the VPU shapes need not pay."""
+    b, t = q.shape[:2]
+    hkv, dh = cfg.num_kv_heads, cfg.head_dim
+    groups = cfg.num_heads // hkv
+    max_len = keys.stack.shape[2]
+    rows = attn_block(max_len)
+    out_dtype = jnp.promote_types(values.stack.dtype, q.dtype)  # `_attend`'s
+
+    def grid(start, n):
+        k_pos = (start + jnp.arange(n, dtype=jnp.int32))[None, None, :]
+        return _visible(cfg, q_pos, k_pos), q_pos, k_pos
+
+    if groups * t == 1:
+        def prefix(n):
+            if not n:       # no active slot: nothing is read
+                return lambda: jnp.zeros((b, t, hkv * dh), out_dtype)
+            return lambda: _attend(cfg, lp, q, keys.rows(0, n),
+                                   values.rows(0, n), grid(0, n))
+
+        return jax.lax.switch(
+            keys.blocks, [prefix(rows * i)
+                          for i in range(max_len // rows + 1)])
+
+    qg = q.reshape(b, t, hkv, groups, dh) * _qscale(cfg)
+
+    def block(j, carry):
+        m, l, acc = carry
+        sc = _masked_scores(cfg, lp, qg, keys.rows(j * rows, rows),
+                            grid(j * rows, rows))
+        m2 = jnp.maximum(m, sc.max(-1))
+        corr = jnp.exp(m - m2)
+        w = jnp.exp(sc - m2[..., None])
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bhgts,bshd->bhgtd", w.astype(values.stack.dtype),
+            values.rows(j * rows, rows).astype(q.dtype),
+            preferred_element_type=jnp.float32)
+        return m2, l * corr + w.sum(-1), acc
+
+    stat = (b, hkv, groups, t)
+    _, l, acc = jax.lax.fori_loop(
+        0, keys.blocks, block,
+        (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+         jnp.zeros(stat + (dh,), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, -1).astype(out_dtype)
 
 
 def _decoder_layer(cfg, lp, h, rope, cache_policy):
@@ -239,7 +377,7 @@ def _decoder_layer(cfg, lp, h, rope, cache_policy):
         if isinstance(keys, tuple):
             outs, row = [], 0
             for k_g, v_g, grid_g in zip(keys, values, grid):
-                b_g, t_g = grid_g[0].shape[:2]
+                b_g, t_g = grid_g[1].shape[:2]
                 q_g = q[0, row:row + b_g * t_g].reshape(
                     b_g, t_g, *q.shape[2:])
                 outs.append(_attend(cfg, lp, q_g, k_g, v_g, grid_g)
@@ -444,8 +582,8 @@ def _write_rows(stack, i, new, slots, positions, keep):
         mode="drop", unique_indices=True)
 
 
-def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
-                 k_all, v_all, rider=None):
+def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
+                 v_all, rider=None):
     """The span's layers over ``T`` new tokens a slot: ``(h, k_all, v_all,
     steps)`` as `_run_passes` gives them.
     The body of the decode step (T = 1 plain, T = K+1 speculative verify:
@@ -477,20 +615,14 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
         rope = make_rope(cfg, positions)
     if rider is not None:
         positions = positions[0, :slots, None]                  # [S, 1]
-    # allowed[s, tq, m]: key position m visible to query token tq of slot s
-    # — everything up to and including the query's own position (causal
-    # within the new block too).
     qpos = positions[:, :, None]                            # [S, T, 1]
-    allowed = pos_grid[None, None, :] <= qpos               # [S, T, M]
-    if cfg.sliding_window:
-        # Window spans (qpos - window, qpos].
-        allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
+    # Blocks of a cache layer that hold a row some ACTIVE slot's queries
+    # may see: the layers read those and no more (`_attend_cached`).
+    blocks = attn_blocks(lengths, active, qpos.shape[1], k_all.shape[2], jnp)
     if rider is not None:
-        r_grid = pos_grid[None, None, :rider["rows"]]           # [1, 1, R]
+        r_grid = jnp.arange(rider["rows"], dtype=jnp.int32)[None, None, :]
         r_qpos = r_pos[None, :, None]                           # [1, C, 1]
-        r_allowed = r_grid <= r_qpos
-        if cfg.sliding_window:
-            r_allowed &= r_grid > r_qpos - cfg.sliding_window
+        r_allowed = _visible(cfg, r_qpos, r_grid)
         # Where the flat rows go in a cache layer: (slot, position, kept).
         points = (
             jnp.concatenate([jnp.arange(slots, dtype=jnp.int32),
@@ -505,9 +637,8 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
             at = _at(base, i)
 
             def per_slot_append(k, v):
-                # Write the T new rows a slot into the stacks, THEN read
-                # this layer's keys and values out of them: the read's
-                # only consumers are the two attention products.
+                # Write the T new rows a slot into the stacks, THEN hand
+                # attention this layer of them to read by blocks.
                 if rider is None:
                     with jax.named_scope("kv_update"):
                         k_new = _append_rows(
@@ -516,30 +647,23 @@ def _decode_span(cfg, spec, params, x, positions, pos_grid, lengths, active,
                         v_new = _append_rows(
                             v_all, at, v.astype(v_all.dtype), lengths,
                             active)
-                    with jax.named_scope("attention"):
-                        keys = jax.lax.dynamic_index_in_dim(
-                            k_new, at, 0, keepdims=False)
-                        values = jax.lax.dynamic_index_in_dim(
-                            v_new, at, 0, keepdims=False)
-                    return (keys, values,
-                            (allowed, qpos, pos_grid[None, None, :]),
-                            (k_new, v_new))
+                    return (_CacheLayer(k_new, at, blocks),
+                            _CacheLayer(v_new, at, blocks),
+                            (None, qpos, None), (k_new, v_new))
                 new, read = [], []
                 for stack, rows in ((k_all, k), (v_all, v)):
                     rows = rows[0].astype(stack.dtype)      # [S + C, ..]
                     with jax.named_scope("kv_update"):
                         stack = _write_rows(stack, at, rows, *points)
                     with jax.named_scope("attention"):
-                        slab = jax.lax.dynamic_index_in_dim(
-                            stack, at, 0, keepdims=False)
                         mine = jax.lax.dynamic_slice(
                             stack, (at, rider["slot"], 0, 0, 0),
                             (1, 1, rider["rows"]) + stack.shape[3:])[0]
                     new.append(stack)
-                    read.append((slab, mine))
+                    read.append((_CacheLayer(stack, at, blocks), mine))
                 return (read[0], read[1],
-                        ((allowed, qpos, pos_grid[None, None, :]),
-                         (r_allowed, r_qpos, r_grid)), tuple(new))
+                        ((None, qpos, None), (r_allowed, r_qpos, r_grid)),
+                        tuple(new))
 
             return _decoder_layer(cfg, lp, h, rope, per_slot_append)
 
@@ -596,6 +720,8 @@ class BatchedStageExecutor:
         self._m_burst_toks = _tm.get("server_burst_tokens_total")
         self._m_sampler = _tm.get("server_sampler_rounds_total")
         self._m_exit_steps = _tm.get("server_loop_exit_steps_total")
+        self._m_rows_read = _tm.get("server_attn_rows_read_total")
+        self._m_rows_span = _tm.get("server_attn_rows_span_total")
         # The rider lane (`_decode_span`): a looped stack's prefill program
         # streams the weights `loop_steps` times, as long as a whole tick of
         # every OTHER session's burst, and which rounds pay it is chance;
@@ -626,6 +752,16 @@ class BatchedStageExecutor:
         self.k = jnp.zeros(shape, self.dtype)
         self.v = jnp.zeros(shape, self.dtype)
         _tm.get("server_kv_stack_bytes").set(self.k.nbytes + self.v.nbytes)
+
+    def _count_attn_rows(self, lengths, active, t: int) -> None:
+        """Add the ticks whose slots began at ``lengths`` (``[ticks, S]``),
+        ``active`` of them taking ``t`` new rows, to the two counters of how
+        much of a cache layer the ticks' attention read: the bound is
+        `attn_blocks`, the function the programs call."""
+        blocks = attn_blocks(lengths, active, t, self.max_len)
+        self._m_rows_read.inc(
+            int(blocks.sum()) * attn_block(self.max_len) * self.slots)
+        self._m_rows_span.inc(len(lengths) * self.slots * self.max_len)
 
     # ------------------------------------------------------------------
     # Slots
@@ -957,10 +1093,9 @@ class BatchedStageExecutor:
             # x: ids [S, T] or hidden [S, T, D]; lengths/active: [S].
             offs = jnp.arange(t_step, dtype=jnp.int32)
             positions = lengths[:, None] + offs[None, :]       # [S, T]
-            pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
             h, k_all, v_all, _ = _decode_span(
-                cfg, spec, params, x, positions, pos_grid, lengths, active,
-                k_all, v_all)
+                cfg, spec, params, x, positions, lengths, active, k_all,
+                v_all)
             # Inactive slots produced garbage — zero them so nothing
             # downstream can mistake them for real activations.
             h = jnp.where(active[:, None, None], h, 0.0)
@@ -1022,6 +1157,7 @@ class BatchedStageExecutor:
         h, self.k, self.v = step(
             self.params, jnp.asarray(x), jnp.asarray(self.lengths.copy()),
             jnp.asarray(active), self.k, self.v)
+        self._count_attn_rows(self.lengths[None], active[None], t)
         for s in rows:
             self.lengths[s] += t
         self.decode_steps += 1
@@ -1071,7 +1207,6 @@ class BatchedStageExecutor:
         def burst_tick(params, tok, lengths, alive, seeds, recent, nvalid,
                        run, left, eos_id, temp, top_p, top_k, rp, k_all,
                        v_all, *rider):
-            pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
             len0 = lengths
             if lane:
                 (rider,) = rider
@@ -1093,7 +1228,7 @@ class BatchedStageExecutor:
                               "rows": min(N * lane, k_all.shape[2])},)
                 h, k_all, v_all, steps = _decode_span(
                     cfg, spec, params, tok[:, None], lengths[:, None],
-                    pos_grid, lengths, active, k_all, v_all, *chunk)
+                    lengths, active, k_all, v_all, *chunk)
                 with jax.named_scope("head"):
                     if lane:
                         # Flat rows: the slots', then the lane's; of those
@@ -1257,10 +1392,18 @@ class BatchedStageExecutor:
         toks_np = np.asarray(toks)            # [N, S]
         stop_np = np.asarray(stop)
         len_np = np.asarray(lengths_new)
+        # Tick i began with every slot i rows on, those still emitting
+        # active: a slot's emitted ticks are a prefix of the burst's.
+        grown = np.zeros((self.slots,), np.int64)     # tokens a slot emitted
+        held = list(rows.values())
+        grown[held] = len_np[held] - self.lengths[held]
+        tick = np.arange(len(toks_np))[:, None]
+        self._count_attn_rows(self.lengths[None] + tick, tick < grown[None],
+                              1)
         out: Dict[str, dict] = {}
         total = 0
         for sid, s in rows.items():
-            m = int(len_np[s] - self.lengths[s])
+            m = int(grown[s])
             emitted = [int(t) for t in toks_np[:m, s]]
             total += m
             out[sid] = {"tokens": emitted,

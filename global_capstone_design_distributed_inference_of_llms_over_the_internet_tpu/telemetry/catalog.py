@@ -207,6 +207,17 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "head, counted from 1), summed ON THE DEVICE inside the "
                  "burst program; divide by server_burst_tokens_total for "
                  "passes per token.", (), None),
+    "server_attn_rows_read_total": (
+        COUNTER, "Rows of ONE cache layer that the batched engine's decode "
+                 "steps and burst ticks read: per tick, the blocks up to "
+                 "the longest active slot (runtime.batching.attn_blocks) "
+                 "x the block's rows x slots; counted on the host from "
+                 "the lengths a step began and ended with.", (), None),
+    "server_attn_rows_span_total": (
+        COUNTER, "Rows of one cache layer those ticks would read in full: "
+                 "ticks x slots x max_session_len. server_attn_rows_read_"
+                 "total over this is the share of the cache a tick's "
+                 "attention streams.", (), None),
     "server_kv_stack_bytes": (
         GAUGE, "Bytes of the batched engine's resident K and V cache "
                "stacks (both together; a looped stack holds rows for "

@@ -87,6 +87,27 @@ def _short(name: str) -> str:
     return re.sub(r"\(.*", "", name)
 
 
+def _control_flow_scopes(order, scope_of) -> dict:
+    """Scopes for the operations that hold others and carry no scope name
+    of their own: a ``conditional`` or a ``while`` whose nested operations
+    ALL sit in one scope belongs to it (the ``switch`` over block counts
+    and the loop over blocks of `runtime.batching._attend_cached` are
+    ``attention``; the layer scan's ``while`` holds every scope and stays
+    unscoped). ``order`` is sorted by start, longest first."""
+    held = collections.defaultdict(set)         # metadata id -> scopes
+    stack = []                                  # (end, metadata id)
+    for mid, s, d in order:
+        while stack and stack[-1][0] <= s:
+            stack.pop()
+        for _, outer in stack:
+            if scope_of[outer][0] is None:
+                held[outer].add(scope_of[mid][0])
+        stack.append((s + d, mid))
+    return {mid: (next(iter(scopes)), "nested operations")
+            for mid, scopes in held.items()
+            if len(scopes) == 1 and None not in scopes}
+
+
 def reduce_file(path: str) -> dict:
     from perfbench.harness.trace import self_times
 
@@ -139,6 +160,7 @@ def reduce_file(path: str) -> dict:
             # self_times keeps the order of its sorted input
             order = sorted(ops, key=lambda ev: (ev[1], -ev[2]))
             selfs = self_times([(str(m), s, d) for m, s, d in order])
+            scope_of.update(_control_flow_scopes(order, scope_of))
             by_prog = collections.defaultdict(float)
             tick_scopes = collections.defaultdict(float)
             via_count = collections.Counter()

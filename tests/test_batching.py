@@ -6,6 +6,8 @@ The reference computes one forward per session per token
 slot in one jitted step over a slot-major KV cache.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -963,13 +965,20 @@ def slab_append(slab, new, start, active):
                 cache, at, t, 0)), at, 0))(slab, new, start, active)
 
 
-def slab_policy_decode_span(cfg, spec, params, x, positions, pos_grid,
-                            lengths, active, k_all, v_all):
+def slab_policy_decode_span(cfg, spec, params, x, positions, lengths, active,
+                            k_all, v_all, full_read=False):
     """`runtime.batching._decode_span` with the slab's round trip as it
     was: slab out of the stack, rows appended to the SLAB, attention over
     the new slab, slab written back. Same signature and results (a stack
     that runs once: `_run_passes`'s ``steps`` is None), same
-    `_decoder_layer`, same `_append_rows` (on a stack of one layer)."""
+    `_decoder_layer`, same `_append_rows` (on a stack of one layer).
+
+    The READ of the new slab is the engine's own (since PR 35 by blocks up
+    to the longest active slot, `_attend_cached`, here over the slab as a
+    stack of one layer), so that what the two policies can differ in is
+    the write alone; ``full_read`` reads all ``max_len`` rows under a mask
+    as every tick did until then: the oracle of the bounded read
+    (tests/test_bounded_attention.py)."""
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
         batching as B,
     )
@@ -978,9 +987,12 @@ def slab_policy_decode_span(cfg, spec, params, x, positions, pos_grid,
          if spec.is_first else x)
     rope = B.make_rope(cfg, positions)
     qpos = positions[:, :, None]
+    pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)
     allowed = pos_grid[None, None, :] <= qpos
     if cfg.sliding_window:
         allowed &= pos_grid[None, None, :] > qpos - cfg.sliding_window
+    blocks = B.attn_blocks(lengths, active, qpos.shape[1], k_all.shape[2],
+                           jnp)
     rest, held = B._split_stacks(params["layers"])
 
     def body(carry, xs):
@@ -994,8 +1006,13 @@ def slab_policy_decode_span(cfg, spec, params, x, positions, pos_grid,
                                    active)[0]
             v_new = B._append_rows(v_l, 0, v.astype(v_l.dtype), lengths,
                                    active)[0]
-            return (k_new, v_new, (allowed, qpos, pos_grid[None, None, :]),
-                    (k_new, v_new))
+            if full_read:
+                return (k_new, v_new,
+                        (allowed, qpos, pos_grid[None, None, :]),
+                        (k_new, v_new))
+            return (B._CacheLayer(k_new[None], 0, blocks),
+                    B._CacheLayer(v_new[None], 0, blocks),
+                    (None, qpos, None), (k_new, v_new))
 
         h, (k_new, v_new) = B._decoder_layer(
             cfg, B._layer_at(lp, held, i), h, rope, slab_round_trip)
@@ -1053,16 +1070,18 @@ def check_clamped_slot(got, case, first_new):
                                           was[:, :first_new])
 
 
-def both_policies(monkeypatch, drive):
-    """``drive()`` under the slab's round trip and under the engine's own:
-    ``(oracle's result, engine's result)``. Each run builds its engine and
-    its programs inside ``drive``, so each traces the policy in force."""
+def both_policies(monkeypatch, drive, full_read=False):
+    """``drive()`` under the slab's round trip (``full_read``: and the read
+    of all ``max_len`` rows) and under the engine's own: ``(oracle's
+    result, engine's result)``. Each run builds its engine and its
+    programs inside ``drive``, so each traces the policy in force."""
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
         batching as B,
     )
 
     with monkeypatch.context() as m:
-        m.setattr(B, "_decode_span", slab_policy_decode_span)
+        m.setattr(B, "_decode_span", partial(slab_policy_decode_span,
+                                             full_read=full_read))
         want = drive()
     return want, drive()
 
