@@ -134,6 +134,19 @@ PREFILL_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024)
 # far under the rows at which a v5e stops being bound by the weights' read.
 RIDER_ROWS = 16
 
+# How long a round's leader holds the round open for a session that the
+# last round of that key answered and that has not asked again yet, as a
+# share of that round's wall time R (`BatchingStageAdapter._close_round`;
+# never under `window_s`). Waiting w costs each joined session w; a session
+# that misses its round waits a whole R for the next. Set on the chip
+# (PERF.md section 6, PR 41): the way back takes 4-25 ms whatever the round
+# (reply, wire, the client's turnaround, the next request, this lock). 8
+# sessions of a 215 or 665 ms round were all in under R / 8; 16 sessions of
+# a 92 ms round come back over 5-25 ms, so that R / 8 = 11.5 ms closed 57%
+# of the rounds with somebody missing (two rounds a token again) where
+# R / 4 = 23 ms closed none, and R / 3 read as R / 4.
+REJOIN_SHARE = 1.0 / 4
+
 # The client's repeat-stop heuristic (runtime.client.REPEAT_STOP), mirrored
 # on device so a burst truncates exactly where the sequential host loop
 # would have stopped. Keep the two in lockstep.
@@ -1529,10 +1542,13 @@ class BatchedStageExecutor:
 # ---------------------------------------------------------------------------
 
 class _Round:
-    """One coalescing window: requests that arrive while it is open share a
-    single batched step. Rounds are keyed by step width T (seq_len), so a
-    round's sessions always share one compiled step: T=1 plain decode,
-    T=K+1 speculative verify."""
+    """One coalescing round: requests that arrive while it is open share a
+    single batched step. Its leader (whoever created it) holds it open for
+    the sessions the last round of its key has just answered, or for
+    ``window_s`` where there are none (`BatchingStageAdapter._close_round`).
+    Rounds are keyed by step width T (seq_len), so a round's sessions
+    always share one compiled step: T=1 plain decode, T=K+1 speculative
+    verify."""
 
     __slots__ = ("reqs", "outs", "err", "bad", "lengths", "spec", "event",
                  "closed", "t_exec", "t_done", "rider")
@@ -1579,8 +1595,11 @@ class BatchingStageAdapter:
     """Drop-in StageExecutor replacement for transports: plain
     prefill/decode AND speculative-verify requests ride the batched engine,
     with concurrent decode calls coalesced — the FIRST arrival leads its
-    width's round, waits ``window_s`` for followers, runs ONE
-    `decode_batch`, and every waiter picks up its own row. Draft steps
+    width's round, holds it open until the sessions that the last round of
+    that width answered are back (at most `REJOIN_SHARE` of that round's
+    wall time after their reply; ``window_s`` where nobody is on the way:
+    `_close_round`), runs ONE `decode_batch`, and every waiter picks up its
+    own row. Draft steps
     (width K+1) coalesce with each other; the final stage verifies each
     row and rewinds its slot past the rejected tail before releasing
     waiters. Beam/training/replay/sub-span requests are refused with a
@@ -1601,10 +1620,22 @@ class BatchingStageAdapter:
         self.step_timeout = step_timeout
         self.requests_served = 0
         self._lock = threading.Lock()
+        # What a round's leader waits on while its round is open: a join, a
+        # drop and a prefill of a session it waits for notify it.
+        self._cond = threading.Condition(self._lock)
         # Open coalescing rounds, keyed by step width T (classic decode /
         # speculative verify) or ('burst', N) (burst rounds never share a
         # compiled program with single-tick rounds).
         self._rounds: Dict[Any, _Round] = {}
+        # Per round key: when its last round's results were read, and that
+        # round's wall time (t_done - t_exec).
+        self._last_round: Dict[Any, Tuple[float, float]] = {}
+        # Per session holding a slot: (round key, instant) of its last
+        # reply, kept while that reply says the session asks again (a burst
+        # or step that did not end its request; a prefill's first token,
+        # key None: it may ask for any). Gone once it joins a round, is
+        # dropped or sends a new prompt, or its slot went (`_returning`).
+        self._replied: Dict[str, Tuple[Any, float]] = {}
         # Ticks of the burst rounds a joining request may ride (`warmup`
         # sets it to the burst it compiles; 0: prefills are programs).
         self.burst_ticks = 0
@@ -1615,6 +1646,8 @@ class BatchingStageAdapter:
         self._m_fill = _tm.get("server_batch_fill_sessions")
         self._m_held = _tm.get("server_batch_slots_held")
         self._m_round = _tm.get("server_decode_round_seconds")
+        self._m_closed = _tm.get("server_round_closed_total")
+        self._m_rejoin = _tm.get("server_round_rejoin_seconds")
         # TcpStageServer's info verb + heartbeat read `.arena.tokens_left()`
         # on whatever executor they serve; point that surface at the slot
         # tables so a batched server advertises real admission headroom.
@@ -1717,6 +1750,96 @@ class BatchingStageAdapter:
     def drop_session(self, session_id: str) -> None:
         with self._lock:
             self.inner.end_session(session_id)
+            self._forget_locked(session_id)
+
+    # -- when a round closes -------------------------------------------------
+
+    def _forget_locked(self, sid: str) -> None:
+        """``sid`` is not on its way back to a round (dropped, or it sent a
+        new prompt). Wakes a leader that may be waiting for it."""
+        if self._replied.pop(sid, None) is not None:
+            self._cond.notify_all()
+
+    def _replied_locked(self, sid: str, key, t: float) -> None:
+        """``sid``'s reply left at ``t`` and says it asks for a round of
+        ``key`` next (None: of any width)."""
+        self._replied[sid] = (key, t)
+
+    def _join(self, sid: str, key) -> None:
+        """Caller holds the lock and has put ``sid`` into the open round of
+        ``key``: whoever waits for it is woken. The delay is observed where
+        the last round of that key is the one that answered the session
+        (in between only a prefill or another width's round held the
+        lock)."""
+        rec = self._replied.pop(sid, None)
+        if rec is None:
+            return
+        last = self._last_round.get(key)
+        if last and rec == (key, last[0]):
+            self._m_rejoin.observe(time.monotonic() - rec[1])
+        self._cond.notify_all()
+
+    def _returning(self, key, now: float, bound: float) -> Dict[str, float]:
+        """Caller holds the lock. The sessions an open round of ``key`` is
+        held for, each with the instant it stops being waited for: it holds
+        a slot, its last reply says it asks again (`_replied`) and left
+        less than ``bound`` ago, and it has not joined (a join takes the
+        record)."""
+        out = {}
+        for s_id, (k, t) in list(self._replied.items()):
+            if s_id not in self.inner._slot_of:
+                del self._replied[s_id]      # evicted: the record goes too
+            elif k in (None, key) and t + bound > now:
+                out[s_id] = t + bound
+        return out
+
+    def _close_round(self, r: _Round, key, sid: str) -> None:
+        """The ONE place a round decides to close (its leader, holding the
+        lock; waits release it, so joins and prefills go on). The round
+        stays open while a session is on its way back (`_returning`) and
+        closes the moment the last of them is in: no sleep after it. The
+        bound on that wait is the engine's own measurement: `REJOIN_SHARE`
+        of the wall time of the last round of this key, never under
+        ``window_s``; a session that does not return costs one round that
+        much, once (its reply is then older than the bound). Where nobody
+        is on the way when the leader gets here (one session in flight, a
+        key that has not run yet) the round is open for ``window_s``, as
+        it always was. Counted by what closed it
+        (``server_round_closed_total{by}``)."""
+        with _get_profiler().span("round_window", session=sid):
+            now = time.monotonic()
+            last = self._last_round.get(key)
+            bound = (max(self.window_s, REJOIN_SHARE * last[1])
+                     if last else 0.0)
+            back = self._returning(key, now, bound)
+            by = "joined" if back else "window"
+            if not back:
+                until = now + self.window_s
+                while now < until:          # a notify cuts a wait short
+                    self._cond.wait(until - now)
+                    now = time.monotonic()
+            while back:
+                self._cond.wait(max(back.values()) - now)
+                now = time.monotonic()
+                still = self._returning(key, now, bound)
+                if any(s in self._replied for s in back if s not in still):
+                    by = "bound"       # somebody's time ran out, still away
+                back = still
+        self._m_closed.labels(by=by).inc()
+        r.closed = True
+        if self._rounds.get(key) is r:
+            del self._rounds[key]
+
+    def _answered(self, r: _Round, key, back) -> None:
+        """Caller holds the lock; the step of round ``r`` has run and its
+        results are on the host: time it, and note which sessions (``back``)
+        will ask for the next round of ``key``."""
+        r.t_done = time.monotonic()
+        wall = r.t_done - r.t_exec
+        self._m_round.observe(wall)
+        self._last_round[key] = (r.t_done, wall)
+        for s_id in back:
+            self._replied_locked(s_id, key, r.t_done)
 
     # -- phases ------------------------------------------------------------
 
@@ -1745,6 +1868,7 @@ class BatchingStageAdapter:
         with prof.phase("prefill_wait", session=sid):
             self._lock.acquire()  # slot tables + cache arrays: shared state
         try:
+            self._forget_locked(sid)   # a new prompt: not on its way back
             with prof.phase("prefill", session=sid):
                 try:
                     h = self.inner.prefill(sid, req.hidden,
@@ -1763,9 +1887,20 @@ class BatchingStageAdapter:
         finally:
             self._lock.release()
         if not self.spec.is_last:
-            return self._respond(req, h, cache_len)
-        with prof.phase("first_token", session=sid):
-            return self._respond(req, h, cache_len)
+            resp = self._respond(req, h, cache_len)
+        else:
+            with prof.phase("first_token", session=sid):
+                resp = self._respond(req, h, cache_len)
+        # The session asks for a round from now on: on record if the lock
+        # is free (a leader that is waiting then waits for this one too).
+        # Busy means a round is running, and whoever asks during one queues
+        # for the next anyway: a first token never waits for the lock.
+        if self._lock.acquire(blocking=False):
+            try:
+                self._replied_locked(sid, None, time.monotonic())
+            finally:
+                self._lock.release()
+        return resp
 
     def _rides(self, req) -> bool:
         """Whether this prefill joins a burst round as its rider instead of
@@ -1871,17 +2006,14 @@ class BatchingStageAdapter:
                 raise StageExecutionError(
                     f"session {sid}: concurrent decode for one session")
             r.reqs[sid] = req
+            self._join(sid, t)
         if leader:
             # The whole leader path runs under try/finally: an unexpected
             # exception anywhere (not just inside decode_batch) must still
             # release the followers, else they block for step_timeout.
             try:
-                with prof.span("round_window", session=sid):
-                    time.sleep(self.window_s)
                 with self._lock:
-                    r.closed = True
-                    if self._rounds.get(t) is r:
-                        del self._rounds[t]
+                    self._close_round(r, t, sid)
                     # Re-validate under the lock: a session may have been
                     # dropped (or otherwise invalidated) since it joined.
                     # Exclusions fail ONLY their own waiter.
@@ -1904,7 +2036,8 @@ class BatchingStageAdapter:
                             s_id: int(self.inner.lengths[self.inner.slot(s_id)])
                             for s_id in good
                         }
-                        self._m_round.observe(time.monotonic() - r.t_exec)
+                        # a step's reply never says it was the request's last
+                        self._answered(r, t, good)
             except Exception as exc:  # whole-round failure
                 r.err = exc
                 with self._lock:  # a dead round must not accept joiners
@@ -1995,20 +2128,18 @@ class BatchingStageAdapter:
                             f"session {sid}: concurrent decode for one "
                             "session")
                     r.reqs[sid] = req
+                    self._join(sid, key)
                     break
                 if r.rider is None:
                     r.rider = req
+                    self._forget_locked(sid)
                     break
             if not r.event.wait(self.step_timeout):   # the lane is taken
                 raise StageExecutionError("batched step timed out")
         if leader:
             try:
-                with prof.span("round_window", session=sid):
-                    time.sleep(self.window_s)
                 with self._lock:
-                    r.closed = True
-                    if self._rounds.get(key) is r:
-                        del self._rounds[key]
+                    self._close_round(r, key, sid)
                     good = {}
                     for s_id, rq in r.reqs.items():
                         reason = (self._validate(rq)
@@ -2037,8 +2168,17 @@ class BatchingStageAdapter:
                                 self.inner.lengths[self.inner.slot(s_id)])
                             for s_id in good
                         }
-                        r.t_done = time.monotonic()
-                        self._m_round.observe(r.t_done - r.t_exec)
+                        # Back for the next round: the rider, with its
+                        # first token, and every session whose burst did
+                        # not end its request (no stop, and a budget of a
+                        # whole burst: a client asks for min(burst, tokens
+                        # still wanted), so a short one is its last).
+                        back = [s_id for s_id, rq in good.items()
+                                if r.outs[s_id]["stop"] is None
+                                and rq.burst_budget >= rq.burst_len]
+                        if riding:
+                            back.append(riding["session_id"])
+                        self._answered(r, key, back)
                         _ev.emit("burst_round", sessions=len(good), ticks=n,
                                  tokens=sum(len(r.outs[s_id]["tokens"])
                                             for s_id in good))

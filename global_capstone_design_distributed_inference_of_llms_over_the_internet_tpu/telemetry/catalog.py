@@ -37,6 +37,12 @@ FAST_BUCKETS = (
 )
 # Batch occupancy (sessions coalesced per decode round).
 FILL_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0, 24.0, 32.0, 64.0)
+# A session's way back to the next round (reply -> its next request has
+# joined): a few ms on one host, resolved finely up to a burst round's length.
+REJOIN_BUCKETS = (
+    0.001, 0.002, 0.003, 0.004, 0.005, 0.006, 0.008, 0.01, 0.012, 0.015,
+    0.02, 0.025, 0.03, 0.04, 0.05, 0.075, 0.1, 0.15, 0.25, 0.5, 1.0,
+)
 # Route lengths (hops per planned pipeline).
 HOP_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0)
 # MoE expert load relative to perfectly balanced routing (1.0 = uniform;
@@ -64,6 +70,15 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
     "server_decode_round_seconds": (
         HISTOGRAM, "Wall time of one batched decode round (all slots).",
         (), FAST_BUCKETS),
+    "server_round_closed_total": (
+        COUNTER, "Batched rounds by what closed them: joined (every session "
+                 "the leader waited for came), bound (the wait ran out with "
+                 "one still away), window (nobody was on the way back: the "
+                 "leader slept window_s).", ("by",), None),
+    "server_round_rejoin_seconds": (
+        HISTOGRAM, "From the reply of the last round of a width to the "
+                   "join of the next one, per session that the one "
+                   "answered and the other took.", (), REJOIN_BUCKETS),
     "server_tokens_total": (
         COUNTER, "Tokens processed by this stage, per phase.",
         ("phase",), None),
