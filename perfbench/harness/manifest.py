@@ -128,9 +128,18 @@ class Manifest:
         return path if os.path.exists(path) else None
 
     def metrics_for(self, workload: str, kind: str) -> List[dict]:
-        """The ``end_to_end`` or ``per_layer`` metrics this cell reports."""
-        return [m for m in self.data[kind]
-                if "workloads" not in m or workload in m["workloads"]]
+        """The ``end_to_end`` or ``per_layer`` metrics this cell reports.
+        An end-to-end metric without ``workloads`` is every cell's; a
+        per-layer metric without it is reported in every cell that reports
+        the end-to-end metric it ``moves``."""
+        e2e = [m for m in self.data["end_to_end"]
+               if "workloads" not in m or workload in m["workloads"]]
+        if kind == "end_to_end":
+            return e2e
+        reported = {m["name"] for m in e2e}
+        return [m for m in self.data["per_layer"]
+                if (workload in m["workloads"] if "workloads" in m
+                    else m["moves"] in reported)]
 
     # -- validation (the contract's rules that a file can break) -----------
 
@@ -279,7 +288,7 @@ class Manifest:
                 raise ManifestError(f"{m['name']}: moves {m['moves']!r} is "
                                     "not an end-to-end metric")
             mover = e2e[m["moves"]]
-            for w in m.get("workloads", wl_names):
+            for w in m.get("workloads", ()):
                 if "workloads" in mover and w not in mover["workloads"]:
                     raise ManifestError(
                         f"{m['name']}: cell {w} does not report "

@@ -219,6 +219,11 @@ STOCK: Dict[str, Callable] = {
 
 def read_metric(manifest, name: str, ctx: dict) -> Optional[float]:
     desc = manifest.layer_metric(name)
+    if "as" in desc:
+        # one quantity, split by the end-to-end metric its cells report:
+        # read it as the other metric's file says
+        name = desc["as"]
+        desc = manifest.layer_metric(name)
     own = manifest.layer_reader_file(name)
     if own:
         fn = load_module(own).read

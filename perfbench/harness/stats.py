@@ -37,21 +37,38 @@ def tokens_per_s(records: Iterable[dict], w0: float, w1: float) -> float:
     return delivered_tokens(records, w0, w1) / (w1 - w0)
 
 
-def gap_samples_ms(records: Iterable[dict], w0: float,
-                   w1: float) -> List[float]:
-    """For every delivery after a request's first, inside the window: the
-    time since that request's previous delivery over the tokens in this
-    one (a burst reply carries up to N, a per-step reply 1), in ms/token."""
-    out = []
+def _gaps(records: Iterable[dict], w0: float, w1: float):
+    """(seconds since the request's previous delivery, tokens in this one)
+    for every delivery after a request's first that lands in the window."""
     for r in records:
         if r.get("error"):
             continue
         prev = None
         for t, n in r["deliveries"]:
             if prev is not None and n > 0 and w0 <= t < w1:
-                out.append((t - prev) * 1e3 / n)
+                yield t - prev, n
             prev = t
-    return out
+
+
+def gap_samples_ms(records: Iterable[dict], w0: float,
+                   w1: float) -> List[float]:
+    """For every delivery after a request's first, inside the window: the
+    time since that request's previous delivery over the tokens in this
+    one (a burst reply carries up to N, a per-step reply 1), in ms/token."""
+    return [dt * 1e3 / n for dt, n in _gaps(records, w0, w1)]
+
+
+def gap_mean_ms(records: Iterable[dict], w0: float,
+                w1: float) -> Optional[float]:
+    """The token-weighted mean of the same gaps: the time the window's
+    deliveries (after a request's first) waited for, over the tokens they
+    carried. Every gap counts by its tokens, so no rank sits on an edge
+    between two round lengths."""
+    waited = tokens = 0.0
+    for dt, n in _gaps(records, w0, w1):
+        waited += dt
+        tokens += n
+    return waited * 1e3 / tokens if tokens else None
 
 
 def ttft_samples_ms(records: Iterable[dict], w0: float, w1: float,
@@ -136,6 +153,7 @@ def summarize(records: List[dict], w0: float, w1: float,
         "gap_p50_ms": percentile(gaps, 50) if gaps else None,
         "gap_p75_ms": percentile(gaps, 75) if gaps else None,
         "gap_p95_ms": percentile(gaps, 95) if gaps else None,
+        "gap_mean_ms": gap_mean_ms(records, w0, w1),
         "ttft_samples": len(ttft),
         "ttft_mean_ms": statistics.fmean(ttft) if ttft else None,
         "ttft_p50_ms": percentile(ttft, 50) if ttft else None,

@@ -3,16 +3,22 @@
 A traffic file gives: ``kind`` (``closed``: N sessions, each sends its next
 request when the last one ended; ``open``: requests fall due at a fixed
 rate whether or not earlier ones have ended), the length tables, the
-sampling parameters and the route. Every seed gives the SAME multiset of
-(prompt length, token budget) per cycle of the tables — the seed only
-shuffles the order — so the seed never changes the amount of work, and the
-same seed gives the same schedule, token ids and sampling seeds."""
+sampling parameters and the route. The file STATES its work: pair ``j`` of
+a cycle is ``(prompt_lens[j], token_budgets[pairing[j]])``; without a
+``pairing`` key, ``j % len(token_budgets)``. Every seed offers the SAME
+multiset of (prompt length, token budget) PAIRS a cycle: the seed gives the
+order of the pairs, the token ids, the sampling seeds and (Poisson) the due
+times, and nothing else. "Work" means the pairs and not the two tables
+apart: a tick's attention reads prompt + tokens so far, so which prompt
+meets which budget is part of what a window costs. The same seed gives the
+same schedule, token ids and sampling seeds."""
 
 from __future__ import annotations
 
 import dataclasses
+import math
 import random
-from typing import List, Optional
+from typing import List, Optional, Sequence, Tuple
 
 KINDS = ("closed", "open")
 ARRIVALS = ("uniform", "poisson")
@@ -27,13 +33,68 @@ class Request:
     due_s: Optional[float]  # open loop: seconds after the start; else None
 
 
+_M64 = (1 << 64) - 1
+
+
 def _mix(seed: int, *parts: int) -> int:
-    """A deterministic 62-bit mix of whole numbers (any size of seed)."""
-    x = (seed & ((1 << 64) - 1)) ^ (seed >> 64)
+    """A deterministic 64-bit mix of whole numbers (any size of seed).
+    Every step is a bijection of the 64-bit state and no bit is dropped,
+    so two lists of parts that differ in their last part never meet; the
+    closing rounds spread a last part that moved by one over every bit
+    (``% (1 << 30)`` keeps the low ones)."""
+    x = (seed & _M64) ^ (seed >> 64)
     for p in parts:
-        x = (x * 6364136223846793005 + p + 1442695040888963407) % (1 << 64)
+        x = (x * 6364136223846793005 + p + 1442695040888963407) & _M64
         x ^= x >> 29
-    return x >> 2
+    x = (x * 0xBF58476D1CE4E5B9) & _M64
+    x ^= x >> 32
+    x = (x * 0x94D049BB133111EB) & _M64
+    return x ^ (x >> 29)
+
+
+def pairs_of(spec: dict) -> List[Tuple[int, int]]:
+    """The (prompt length, token budget) pairs of one cycle, as the file
+    states them."""
+    prompts, budgets = list(spec["prompt_lens"]), list(spec["token_budgets"])
+    if not prompts or not budgets:
+        raise ValueError("empty length table")
+    pairing = spec.get("pairing")
+    if pairing is None:
+        pairing = [j % len(budgets) for j in range(len(prompts))]
+    if len(pairing) != len(prompts) or not all(
+            isinstance(i, int) and 0 <= i < len(budgets) for i in pairing):
+        raise ValueError(f"pairing {pairing!r}: one index into "
+                         f"token_budgets for every entry of prompt_lens")
+    return [(n, budgets[i]) for n, i in zip(prompts, pairing)]
+
+
+def affine_pairings(prompts: Sequence[int], budgets: Sequence[int]
+                    ) -> List[Tuple[int, int, List[int]]]:
+    """The family a traffic file's ``pairing`` is taken from, in the order
+    the rule tries it: ``j -> ((a * j + c) mod n) mod m`` for ``a`` coprime
+    to ``n`` (n prompts, m budgets, both tables ascending), nearest first
+    to what independent shuffles of the two tables would offer,
+    sum(prompts) x mean(budget); ties to the smallest ``(a, c)``. A map
+    another ``(a, c)`` gave before is not listed again."""
+    n, m = len(prompts), len(budgets)
+    if list(prompts) != sorted(prompts) or list(budgets) != sorted(budgets):
+        raise ValueError("the rule reads both tables ascending")
+    ranked = []
+    for a in range(1, max(n, 2)):
+        if math.gcd(a, n) != 1:
+            continue
+        for c in range(n):
+            pairing = [((a * j + c) % n) % m for j in range(n)]
+            work = sum(p * budgets[i] for p, i in zip(prompts, pairing))
+            ranked.append((abs(m * work - sum(prompts) * sum(budgets)),
+                           a, c, pairing))
+    ranked.sort()
+    out, seen = [], set()
+    for _, a, c, pairing in ranked:
+        if tuple(pairing) not in seen:
+            seen.add(tuple(pairing))
+            out.append((a, c, pairing))
+    return out
 
 
 class Schedule:
@@ -46,12 +107,8 @@ class Schedule:
         self.spec = spec
         self.seed = int(seed)
         self.kind = spec["kind"]
-        self.prompt_lens = list(spec["prompt_lens"])
-        self.budgets = list(spec["token_budgets"])
-        if not self.prompt_lens or not self.budgets:
-            raise ValueError("empty length table")
-        random.Random(_mix(self.seed, 1)).shuffle(self.prompt_lens)
-        random.Random(_mix(self.seed, 2)).shuffle(self.budgets)
+        self.pairs = pairs_of(spec)
+        random.Random(_mix(self.seed, 1)).shuffle(self.pairs)
         self.sessions = int(spec["sessions"])
         self.rate = float(spec.get("rate_rps", 0.0))
         self.arrival = spec.get("arrival", "uniform")
@@ -74,10 +131,11 @@ class Schedule:
         return self._due[k]
 
     def request(self, k: int) -> Request:
+        prompt_len, budget = self.pairs[k % len(self.pairs)]
         return Request(
             index=k,
-            prompt_len=self.prompt_lens[k % len(self.prompt_lens)],
-            budget=self.budgets[k % len(self.budgets)],
+            prompt_len=prompt_len,
+            budget=budget,
             sampling_seed=_mix(self.seed, 4, k) % (1 << 30),
             due_s=self._due_s(k))
 
@@ -89,4 +147,4 @@ class Schedule:
     def warm_lengths(self) -> List[int]:
         """Every distinct prompt length, so each prefill shape the window
         can meet is built during set-up (whatever the program's buckets)."""
-        return sorted(set(self.prompt_lens))
+        return sorted({n for n, _ in self.pairs})

@@ -293,8 +293,12 @@ def main() -> int:
         **({"trace_recorded_share": trace["extent_s"] / trace["window_s"]}
            if trace else {}),
         "window_s": load["window_s"], "setup_parts_s": setup,
+        # the work the run was given: mean rows (prompt + tokens so far) a
+        # session held at its deliveries inside the window
+        "ctx_rows_in_use": load["ctx_rows_in_use"],
         "gap_samples": load["gap_samples"],
-        "gap_p50_ms": load["gap_p50_ms"], "gap_p95_ms": load["gap_p95_ms"],
+        "gap_p50_ms": load["gap_p50_ms"], "gap_p75_ms": load["gap_p75_ms"],
+        "gap_p95_ms": load["gap_p95_ms"], "gap_mean_ms": load["gap_mean_ms"],
         "tokens_per_s": load["tokens_per_s"],
         "ttft_samples": load["ttft_samples"],
         "ttft_mean_ms": load["ttft_mean_ms"],
@@ -321,6 +325,7 @@ def main() -> int:
     if not args.trace:
         values = {"tokens_per_s": load["tokens_per_s"],
                   "gap_p75_ms": load["gap_p75_ms"],
+                  "gap_mean_ms": load["gap_mean_ms"],
                   "ttft_mean_ms": load["ttft_mean_ms"],
                   "setup_s": setup["setup_s"]}
         for m in man.metrics_for(args.workload, "end_to_end"):

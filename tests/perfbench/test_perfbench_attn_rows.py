@@ -37,16 +37,17 @@ def series(read=None, span=None):
 
 def test_the_metric_is_listed_in_every_cell(man):
     man.validate()
-    entry = next(m for m in man.data["per_layer"] if m["name"] == NAME)
-    assert man.data["per_layer"][-1] is entry               # appended
+    (entry,) = [m for m in man.data["per_layer"] if m["name"] == NAME]
     assert (entry["unit"], entry["better"], entry["source"],
             entry["layer"], entry["moves"]) == (
         "%", "lower", "program_counter", "model step", "gap_p75_ms")
-    cells = [w["name"] for w in man.data["workloads"]]
-    assert sorted(entry["workloads"]) == sorted(cells)
-    for cell in cells:
-        assert NAME in {m["name"] for m in man.metrics_for(cell, "per_layer")}
-        assert "gap_p75_ms" in {
+    for cell in (w["name"] for w in man.data["workloads"]):
+        # under its own name where the cell reports gap_p75_ms, as
+        # `<name>.open` where the cell is decided by gap_mean_ms
+        (asked,) = [m for m in man.metrics_for(cell, "per_layer")
+                    if m["name"] in (NAME, NAME + ".open")]
+        assert (asked is entry) == (cell in entry["workloads"])
+        assert asked["moves"] in {                # a gap metric
             m["name"] for m in man.metrics_for(cell, "end_to_end")}
 
 
@@ -107,7 +108,8 @@ def test_traced_dry_run_prints_the_share(tmp_path):
     assert res.returncode == 0, res.stdout[-3000:] + res.stderr[-2000:]
     last = json.loads(res.stdout.strip().splitlines()[-1])
     assert last["cpu_dry_run"] is True and last["correct"] is True
-    share = last["metrics"]["cpu_dry_run." + NAME]
+    # the open loop reports the share as its split, read as NAME's file says
+    share = last["metrics"]["cpu_dry_run." + NAME + ".open"]
     assert share["unit"] == "%" and 0.0 < share["value"] <= 100.0
     with open(tmp_path / "out" / "metrics_after.jsonl") as f:
         total = readers.parse_prometheus(json.loads(f.readline())["text"])

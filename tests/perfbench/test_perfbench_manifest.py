@@ -27,7 +27,13 @@ def test_the_committed_manifest_is_valid():
     assert sum(1 for w in d["workloads"] if w["chips"] == 4) <= 1
     for w in d["workloads"]:
         e2e = {m["name"] for m in man.metrics_for(w["name"], "end_to_end")}
-        assert {"gap_p75_ms", "setup_s"} <= e2e
+        # the closed loops are decided by the 75th percentile: one full
+        # round a token; the open loop's window holds ~130 gaps on a
+        # lattice of block counts, and is decided by their mean
+        gap = ("gap_p75_ms" if man.traffic(w["traffic"])["kind"] == "closed"
+               else "gap_mean_ms")
+        assert {gap, "setup_s"} <= e2e
+        assert not {"gap_p75_ms", "gap_mean_ms"} - {gap} & e2e
         for m in man.metrics_for(w["name"], "per_layer"):
             assert m["moves"] in e2e
         cfg = man.config(w["config"])
@@ -40,6 +46,53 @@ def test_the_committed_manifest_is_valid():
             cfg["deployment"]["servers"][0]["args"][
                 cfg["deployment"]["servers"][0]["args"].index("--slots") + 1])
     assert len(json.dumps(d)) < 64 * 1024
+
+
+def split_names():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return sorted(m["name"] for m in json.load(f)["per_layer"]
+                      if m["name"].endswith(".open"))
+
+
+@pytest.mark.parametrize("name", split_names())
+def test_a_split_reads_as_the_metric_it_splits(name):
+    """One quantity, two entries: `<metric>` moves `gap_p75_ms` in the
+    closed loops, `<metric>.open` moves `gap_mean_ms` in the open loop. The
+    split's file names the other (`as`) and brings no reader or params of
+    its own, so both read the same number from the same run."""
+    man = Manifest(ROOT)
+    by_name = {m["name"]: m for m in man.data["per_layer"]}
+    split, base = by_name[name], by_name[name[:-len(".open")]]
+    desc = man.layer_metric(name)
+    assert desc["as"] == base["name"]
+    assert not {"reader", "params"} & set(desc)
+    assert man.layer_reader_file(name) is None
+    for key in ("unit", "better", "source", "layer"):
+        assert split[key] == base[key] == desc[key]
+    assert (split["moves"], base["moves"]) == ("gap_mean_ms", "gap_p75_ms")
+    assert split["workloads"] == ["gpt2xl-chat-open"]
+    assert "gpt2xl-chat-open" not in base.get("workloads", ())
+    # a keyless metric is every cell's that reports what it moves
+    for w in man.data["workloads"]:
+        asked = {m["name"] for m in man.metrics_for(w["name"], "per_layer")}
+        assert (name in asked) == (w["name"] == "gpt2xl-chat-open")
+        assert not (base["name"] in asked and name in asked)
+
+
+def test_a_split_and_its_metric_read_one_number():
+    man = Manifest(ROOT)
+    fam = "server_round_rejoin_seconds"
+    ctx = {"counters_before": {"p": {fam + "_sum": 1.0, fam + "_count": 100.0}},
+           "counters_after": {"p": {fam + "_sum": 1.9, fam + "_count": 300.0}}}
+    assert readers.read_metric(man, "round_rejoin_ms.open", ctx) == \
+        readers.read_metric(man, "round_rejoin_ms", ctx) == pytest.approx(4.5)
+    rows = {"counters_before": {"p": {}}, "counters_after": {"p": {
+        "server_attn_rows_read_total": 640.0,
+        "server_attn_rows_span_total": 1024.0}}}
+    assert readers.read_metric(man, "attn_rows_read_share.open", rows) == \
+        readers.read_metric(man, "attn_rows_read_share", rows) == \
+        pytest.approx(62.5)
+    assert readers.read_metric(man, "attn_rows_read_share.open", {}) is None
 
 
 def test_no_ttft_statistic_decides():
@@ -81,7 +134,7 @@ def test_new_cell_config_mix_and_metric_are_new_files_only(sandbox):
     (sandbox / "perfbench/traffic/chat-open.json").write_text(json.dumps(mix))
     (sandbox / "perfbench/layer_metrics/first_gap_ms.json").write_text(
         json.dumps({"layer": "client", "unit": "ms", "better": "lower",
-                    "source": "host_clock", "moves": "gap_p75_ms",
+                    "source": "host_clock", "moves": "gap_mean_ms",
                     "params": {"scale": 1000.0}}))
     (sandbox / "perfbench/layer_metrics/first_gap_ms.py").write_text(
         "def read(ctx, params):\n"
@@ -95,9 +148,11 @@ def test_new_cell_config_mix_and_metric_are_new_files_only(sandbox):
         d["workloads"].append({"name": "b-open", "config": "gpt2-xl-b",
                                "traffic": "chat-open", "chips": 1,
                                "why": "throw-away open-loop cell"})
+        next(m for m in d["end_to_end"] if m["name"] == "gap_mean_ms")[
+            "workloads"].append("b-open")
         d["per_layer"].append({"name": "first_gap_ms", "unit": "ms",
                                "better": "lower", "source": "host_clock",
-                               "layer": "client", "moves": "gap_p75_ms",
+                               "layer": "client", "moves": "gap_mean_ms",
                                "workloads": ["b-open"]})
 
     rewrite(sandbox, add)
@@ -122,8 +177,9 @@ BREAKS = {
     "moves names no end-to-end metric": lambda d: d["per_layer"][0].update(
         moves="ttft_p95_ms"),
     "bound over 0.1": lambda d: d["end_to_end"][1].update(bound=0.2),
-    "moves a metric the cell does not report": lambda d: d["per_layer"][
-        0].update(moves="tokens_per_s"),
+    "moves a metric the cell does not report": lambda d: next(
+        m for m in d["per_layer"] if m["name"] == "client_tokens_per_s"
+    ).update(moves="tokens_per_s"),
     "two four-chip cells": lambda d: [w.update(chips=4)
                                       for w in d["workloads"]],
     "unknown key on a metric": lambda d: d["per_layer"][0].update(why="x"),

@@ -43,6 +43,25 @@ def test_the_metric_files_say_what_the_benchmark_asks(man, name):
         man.layer_reader_file(name))
 
 
+@pytest.mark.parametrize("name", [SHARE, REJOIN])
+def test_the_benchmark_lists_the_metric_where_it_finds_something(man, name):
+    """The entry says what the metric's file says; every closed loop's
+    traced line is asked for it, and the open loop's for the rejoin alone
+    (all its rounds close by the window: the share finds nothing there)."""
+    man.validate()
+    (entry,) = [m for m in man.data["per_layer"] if m["name"] == name]
+    desc = man.layer_metric(name)
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert entry[key] == desc[key]
+    for w in man.data["workloads"]:
+        asked = {m["name"] for m in man.metrics_for(w["name"], "per_layer")}
+        if man.traffic(w["traffic"])["kind"] == "closed":
+            assert w["name"] in entry["workloads"] and name in asked
+        else:
+            assert w["name"] not in entry["workloads"]
+            assert (name + ".open" in asked) == (name == REJOIN)
+
+
 @pytest.mark.parametrize("before, after, want", [
     # 2 of 40 held rounds ran out of time; the 3 window closes do not count
     (closes(), closes(joined=38, bound=2, window=3), 5.0),

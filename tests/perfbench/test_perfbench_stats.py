@@ -49,6 +49,26 @@ def test_gap_samples_are_per_token_and_skip_first_deliveries():
     assert stats.percentile(gaps, 50) == pytest.approx(50.0)
 
 
+@pytest.mark.parametrize("records, w0, w1, want", [
+    # every in-window gap by its tokens: 6200 ms waited for 76 tokens
+    (RECORDS, W0, W1, 6200 / 76),
+    # a 16-token burst and a 4-token one weigh 4 : 1, not 1 : 1
+    ([rec(0, [(1.0, 1), (1.2, 16), (1.3, 4)])], 0, 2, 300 / 20),
+    # a gap counts where its delivery lands, whole, as in gap_samples_ms
+    ([rec(0, [(0.5, 1), (1.5, 16), (2.5, 16)])], 1, 2, 1000 / 16),
+    # first deliveries and failed requests give no gap: nothing to read
+    ([rec(0, [(1.0, 1)]), rec(0, [(1.0, 1), (1.5, 16)], error="E: x")],
+     0, 2, None),
+], ids=["records", "token-weighted", "window-edge", "nothing"])
+def test_gap_mean_is_time_waited_over_tokens_delivered(records, w0, w1, want):
+    got = stats.gap_mean_ms(records, w0, w1)
+    assert got == (pytest.approx(want) if want is not None else None)
+    assert stats.summarize(records, w0, w1, 120)["gap_mean_ms"] == got
+    gaps = stats.gap_samples_ms(records, w0, w1)
+    if gaps:        # a mean of the same samples: between their extremes
+        assert min(gaps) - 1e-9 <= got <= max(gaps) + 1e-9
+
+
 def test_percentile_is_nearest_rank():
     vals = list(range(1, 101))
     assert stats.percentile(vals, 95) == 95
