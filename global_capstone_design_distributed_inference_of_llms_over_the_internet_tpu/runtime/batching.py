@@ -57,6 +57,7 @@ the same.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import threading
 import time
 from functools import partial
@@ -109,6 +110,11 @@ def _burst_entry(rq) -> dict:
 
 
 
+def _gc_counts() -> List[int]:
+    """Collections the garbage collector has run, by generation."""
+    return [g["collections"] for g in gc.get_stats()]
+
+
 def _rider_entry(rq) -> dict:
     """A prefill StageRequest as the engine's rider (`decode_burst`): the
     prompt and what `executor._sample_rows` samples its first token with."""
@@ -146,6 +152,17 @@ RIDER_ROWS = 16
 # of the rounds with somebody missing (two rounds a token again) where
 # R / 4 = 23 ms closed none, and R / 3 read as R / 4.
 REJOIN_SHARE = 1.0 / 4
+
+# A round whose wall time is over this many times that of the last round of
+# its key is a STALL: counted, and its parts recorded
+# (`BatchingStageAdapter._stalled`). Against the engine's own last
+# measurement, as `REJOIN_SHARE`: no configuration is named. A round ten
+# times its predecessor shows about once in ten runs of a closed loop
+# (PERF.md section 6, PR 41); the rounds of one cell differ by under 1.1 x.
+STALL_FACTOR = 4.0
+# The parts of a burst round the phase profiler brackets
+# (`BatchedStageExecutor.burst_parts`); a stall's rest is ``other``.
+STALL_PARTS = ("build", "dispatch", "device", "readback")
 
 # The client's repeat-stop heuristic (runtime.client.REPEAT_STOP), mirrored
 # on device so a burst truncates exactly where the sequential host loop
@@ -688,6 +705,10 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
 
 class BatchedStageExecutor:
     """One stage span serving up to `slots` sessions with batched decode."""
+
+    # The last burst's seconds by `STALL_PARTS`, where the phase profiler
+    # measured them (`decode_burst`); None with it off.
+    burst_parts: Optional[Dict[str, float]] = None
 
     def __init__(
         self,
@@ -1486,7 +1507,7 @@ class BatchedStageExecutor:
         prof = _get_profiler()
         n = len(entries)
         extra = []
-        with prof.phase("burst_build", sessions=n):
+        with prof.phase("burst_build", sessions=n) as built:
             rows, args = self._burst_prep(entries, n_ticks)
             fn = self._get_burst_jit(n_ticks)
             if rider is not None:
@@ -1500,8 +1521,8 @@ class BatchedStageExecutor:
         # Profiled: a fenced dispatch. The device phase is dispatch-to-ready
         # and the bubble gauge charges idle time between successive readies.
         try:
-            with prof.device_phase(sessions=n):
-                with prof.phase("dispatch", sessions=n):
+            with prof.device_phase(sessions=n) as ran:
+                with prof.phase("dispatch", sessions=n) as issued:
                     out = fn(self.params, *args, self.k, self.v, *extra)
                 if prof.enabled:
                     jax.block_until_ready(out)
@@ -1515,7 +1536,7 @@ class BatchedStageExecutor:
         self.burst_dispatches += 1
         self._m_burst_disp.inc()
         self._m_burst_ticks.observe(n_ticks)
-        with prof.phase("readback", sessions=n):
+        with prof.phase("readback", sessions=n) as read:
             res = self._burst_collect(
                 rows, toks, stop, lengths_new,
                 more[0] if self.cfg.loop_steps > 1 else None)
@@ -1524,7 +1545,12 @@ class BatchedStageExecutor:
                 self.lengths[rider["slot"]] = t
                 res[rider["session_id"]] = {"token": int(more[-1]),
                                             "cache_len": t}
-            return res
+        # What this burst's wall time was made of, where the profiler has
+        # just measured it (``device``: enqueue returned -> results ready).
+        self.burst_parts = dict(zip(STALL_PARTS, (
+            built.seconds, issued.seconds, ran.seconds - issued.seconds,
+            read.seconds))) if prof.enabled else None
+        return res
 
     # ------------------------------------------------------------------
 
@@ -1551,7 +1577,8 @@ class _Round:
     verify."""
 
     __slots__ = ("reqs", "outs", "err", "bad", "lengths", "spec", "event",
-                 "closed", "t_exec", "t_done", "rider")
+                 "closed", "t_open", "t_exec", "t_done", "rider", "rejoined",
+                 "back")
 
     def __init__(self):
         self.reqs: Dict[str, Any] = {}
@@ -1562,9 +1589,16 @@ class _Round:
         self.bad: Dict[str, str] = {}             # per-session exclusions
         self.event = threading.Event()
         self.closed = False
+        self.t_open = time.monotonic()   # opened: its first session is in
         self.t_exec = 0.0    # monotonic instant the round's step started
         self.t_done = 0.0    # ... and the instant its results were read
         self.rider = None    # a burst round's ONE joining request (prefill)
+        # Holds a session that the last round of its key answered (`_join`):
+        # only then is the time since that round a PERIOD of the machine.
+        self.rejoined = False
+        # Sessions whose reply says they ask for the next round
+        # (`_answered`): their responses carry ``t_done``.
+        self.back: frozenset = frozenset()
 
 
 class _SlotArenaView:
@@ -1648,6 +1682,18 @@ class BatchingStageAdapter:
         self._m_round = _tm.get("server_decode_round_seconds")
         self._m_closed = _tm.get("server_round_closed_total")
         self._m_rejoin = _tm.get("server_round_rejoin_seconds")
+        self._m_period = _tm.get("server_round_period_seconds")
+        self._m_back = _tm.get("server_round_back_seconds")
+        self._m_hold = _tm.get("server_round_hold_seconds")
+        self._m_hold_prefill = _tm.get("server_round_hold_prefill_seconds")
+        self._m_request_leg = _tm.get("server_request_leg_seconds")
+        self._m_stalls = _tm.get("server_round_stalls_total")
+        self._m_stall_s = _tm.get("server_round_stall_seconds_total")
+        # The flight recorder (on under --telemetry), and the collections
+        # the garbage collector had counted when the last round ended
+        # while it was on (`_gc_counts`).
+        self._events = _ev.get_recorder()
+        self._gc_seen: Optional[List[int]] = None
         # TcpStageServer's info verb + heartbeat read `.arena.tokens_left()`
         # on whatever executor they serve; point that surface at the slot
         # tables so a batched server advertises real admission headroom.
@@ -1765,18 +1811,32 @@ class BatchingStageAdapter:
         ``key`` next (None: of any width)."""
         self._replied[sid] = (key, t)
 
-    def _join(self, sid: str, key) -> None:
-        """Caller holds the lock and has put ``sid`` into the open round of
-        ``key``: whoever waits for it is woken. The delay is observed where
-        the last round of that key is the one that answered the session
-        (in between only a prefill or another width's round held the
-        lock)."""
-        rec = self._replied.pop(sid, None)
+    def _in_a_hold_locked(self) -> float:
+        """Now, where a round of any key is open and not closed: its
+        leader's hold pays for what the caller does under the lock (a
+        prefill's program: ``server_round_hold_prefill_seconds``). Else
+        0.0."""
+        return time.monotonic() if self._rounds else 0.0
+
+    def _join(self, r: _Round, req, key) -> None:
+        """Caller holds the lock and has put ``req`` into ``r``, the open
+        round of ``key``: whoever waits for its session is woken. The delay
+        is observed where the last round of that key is the one that
+        answered the session (in between only a prefill or another width's
+        round held the lock): the whole way back, and the part of it from
+        the request's frame read off the socket (``req.t_recv``, where the
+        serving boundary gave one). Such a join makes ``r`` a round whose
+        distance from the last is a period (`_step_starts`)."""
+        rec = self._replied.pop(req.session_id, None)
         if rec is None:
             return
         last = self._last_round.get(key)
         if last and rec == (key, last[0]):
-            self._m_rejoin.observe(time.monotonic() - rec[1])
+            now = time.monotonic()
+            self._m_rejoin.observe(now - rec[1])
+            if req.t_recv:
+                self._m_request_leg.observe(now - req.t_recv)
+            r.rejoined = True
         self._cond.notify_all()
 
     def _returning(self, key, now: float, bound: float) -> Dict[str, float]:
@@ -1830,16 +1890,69 @@ class BatchingStageAdapter:
         if self._rounds.get(key) is r:
             del self._rounds[key]
 
-    def _answered(self, r: _Round, key, back) -> None:
+    def _step_starts(self, r: _Round, key) -> None:
+        """Caller holds the lock and runs the step of round ``r`` next. The
+        round's hold (opened -> now: `_close_round` with its lock waits,
+        and the re-validation) is observed for every round; where the round
+        holds a session that the LAST round of ``key`` answered, so are the
+        period of the round machine (that round's start -> now) and the way
+        back (its results on the host -> this round opened): round by
+        round, period = that round's wall time + back + hold."""
+        r.t_exec = time.monotonic()
+        self._m_hold.observe(r.t_exec - r.t_open)
+        last = self._last_round.get(key)
+        if r.rejoined and last:
+            t_done, wall = last
+            self._m_period.observe(r.t_exec - (t_done - wall))
+            self._m_back.observe(r.t_open - t_done)
+
+    def _answered(self, r: _Round, key, back, parts=None) -> None:
         """Caller holds the lock; the step of round ``r`` has run and its
         results are on the host: time it, and note which sessions (``back``)
-        will ask for the next round of ``key``."""
+        will ask for the next round of ``key``. ``parts``: what the engine
+        measured of the step (`BatchedStageExecutor.burst_parts`)."""
         r.t_done = time.monotonic()
         wall = r.t_done - r.t_exec
         self._m_round.observe(wall)
+        last = self._last_round.get(key)
+        if last and wall > STALL_FACTOR * last[1]:
+            self._stalled(r, key, wall, last[1], parts)
+        if self._events.enabled:
+            self._gc_seen = _gc_counts()
         self._last_round[key] = (r.t_done, wall)
+        r.back = frozenset(back)
         for s_id in back:
             self._replied_locked(s_id, key, r.t_done)
+
+    def _stalled(self, r: _Round, key, wall: float, last_wall: float,
+                 parts) -> None:
+        """Round ``r`` took over `STALL_FACTOR` x the last round of ``key``:
+        count it, add its seconds by part (``other``: what no phase of the
+        profiler covers; all of it with the profiler off) and leave ONE
+        event that says what it was made of."""
+        by_part = {p: (parts or {}).get(p, 0.0) for p in STALL_PARTS}
+        by_part["other"] = max(0.0, wall - sum(by_part.values()))
+        self._m_stalls.inc()
+        for part, seconds in by_part.items():
+            self._m_stall_s.labels(part=part).inc(seconds)
+        burst = isinstance(key, tuple)
+        self._events.emit(
+            "round_stall", wall_s=round(wall, 6),
+            last_wall_s=round(last_wall, 6), sessions=len(r.reqs),
+            ticks=key[1] if burst else 1, rider=r.rider is not None,
+            gc_collections=(None if self._gc_seen is None else [
+                now - was for now, was in zip(_gc_counts(), self._gc_seen)]),
+            **{p + "_s": round(s, 6) for p, s in by_part.items()})
+
+    @staticmethod
+    def _stamped(r: _Round, resp):
+        """``resp`` answers a session of round ``r``: where it says the
+        session asks for the next round, it carries the instant the round's
+        results were on the host, and the serving boundary observes the
+        reply's way out from it (``server_reply_leg_seconds``)."""
+        if resp.session_id in r.back:
+            resp.t_done = r.t_done
+        return resp
 
     # -- phases ------------------------------------------------------------
 
@@ -1867,6 +1980,7 @@ class BatchingStageAdapter:
         # readback included), the prefill under it, the first token after it.
         with prof.phase("prefill_wait", session=sid):
             self._lock.acquire()  # slot tables + cache arrays: shared state
+        t_held = self._in_a_hold_locked()
         try:
             self._forget_locked(sid)   # a new prompt: not on its way back
             with prof.phase("prefill", session=sid):
@@ -1885,6 +1999,8 @@ class BatchingStageAdapter:
                     raise StageExecutionError(str(exc)) from exc
                 cache_len = int(self.inner.lengths[self.inner.slot(sid)])
         finally:
+            if t_held:
+                self._m_hold_prefill.observe(time.monotonic() - t_held)
             self._lock.release()
         if not self.spec.is_last:
             resp = self._respond(req, h, cache_len)
@@ -1934,9 +2050,9 @@ class BatchingStageAdapter:
         prof.observe("prefill_wait", r.t_exec - t0)
         prof.observe("prefill", r.t_done - r.t_exec)
         prof.observe("first_token", time.monotonic() - r.t_done)
-        return StageResponse(session_id=req.session_id,
-                             token_id=out["token"],
-                             cache_len=out["cache_len"])
+        return self._stamped(r, StageResponse(
+            session_id=req.session_id, token_id=out["token"],
+            cache_len=out["cache_len"]))
 
     def _validate_rider(self, r: "_Round", n: int) -> Optional[str]:
         """Admission of a round's rider (caller holds the lock)."""
@@ -1988,7 +2104,6 @@ class BatchingStageAdapter:
         from .executor import StageExecutionError
         from .messages import StageResponse
 
-        prof = _get_profiler()
         sid = req.session_id
         t = req.seq_len
         t_join = time.monotonic()
@@ -2006,7 +2121,7 @@ class BatchingStageAdapter:
                 raise StageExecutionError(
                     f"session {sid}: concurrent decode for one session")
             r.reqs[sid] = req
-            self._join(sid, t)
+            self._join(r, req, t)
         if leader:
             # The whole leader path runs under try/finally: an unexpected
             # exception anywhere (not just inside decode_batch) must still
@@ -2025,7 +2140,7 @@ class BatchingStageAdapter:
                         else:
                             r.bad[s_id] = reason
                     if good:
-                        r.t_exec = time.monotonic()
+                        self._step_starts(r, t)
                         self._m_fill.observe(len(good))
                         self._m_held.observe(len(self.inner._slot_of))
                         r.outs = self.inner.decode_batch(
@@ -2046,10 +2161,8 @@ class BatchingStageAdapter:
                         del self._rounds[t]
             finally:
                 r.event.set()
-        else:
-            with prof.span("round_wait", session=sid):
-                if not r.event.wait(self.step_timeout):
-                    raise StageExecutionError("batched step timed out")
+        elif not r.event.wait(self.step_timeout):
+            raise StageExecutionError("batched step timed out")
         if r.t_exec:
             # Time this session spent parked before its round's step ran —
             # the coalescing window for the leader, window + leader overhead
@@ -2061,9 +2174,11 @@ class BatchingStageAdapter:
             raise StageExecutionError(r.bad[sid])
         if sid in r.spec:
             tokens, n_acc = r.spec[sid]
-            return StageResponse(session_id=sid, tokens=tokens,
-                                 n_accepted=n_acc, cache_len=r.lengths[sid])
-        return self._respond(req, r.outs[sid], r.lengths[sid])
+            return self._stamped(r, StageResponse(
+                session_id=sid, tokens=tokens, n_accepted=n_acc,
+                cache_len=r.lengths[sid]))
+        return self._stamped(r, self._respond(req, r.outs[sid],
+                                              r.lengths[sid]))
 
     def _validate_burst(self, req) -> Optional[str]:
         """Burst-specific admission on top of ``_validate`` (caller holds
@@ -2092,10 +2207,9 @@ class BatchingStageAdapter:
         sid = req.session_id
         r = self._burst_round(req, int(req.burst_len))
         out = r.outs[sid]
-        return StageResponse(session_id=sid,
-                             burst_tokens=tuple(out["tokens"]),
-                             burst_stop=out["stop"],
-                             cache_len=r.lengths[sid])
+        return self._stamped(r, StageResponse(
+            session_id=sid, burst_tokens=tuple(out["tokens"]),
+            burst_stop=out["stop"], cache_len=r.lengths[sid]))
 
     def _burst_round(self, req, n: int, rider: bool = False) -> _Round:
         """Take ``req`` through one burst round of ``n`` ticks, as its leader
@@ -2106,7 +2220,6 @@ class BatchingStageAdapter:
         run and tries the next."""
         from .executor import StageExecutionError
 
-        prof = _get_profiler()
         sid = req.session_id
         key = ("burst", n)
         t_join = time.monotonic()
@@ -2128,7 +2241,7 @@ class BatchingStageAdapter:
                             f"session {sid}: concurrent decode for one "
                             "session")
                     r.reqs[sid] = req
-                    self._join(sid, key)
+                    self._join(r, req, key)
                     break
                 if r.rider is None:
                     r.rider = req
@@ -2156,7 +2269,7 @@ class BatchingStageAdapter:
                         else:
                             r.bad[r.rider.session_id] = reason
                     if good or riding:
-                        r.t_exec = time.monotonic()
+                        self._step_starts(r, key)
                         if good:
                             self._m_fill.observe(len(good))
                             self._m_held.observe(len(self.inner._slot_of))
@@ -2178,10 +2291,13 @@ class BatchingStageAdapter:
                                 and rq.burst_budget >= rq.burst_len]
                         if riding:
                             back.append(riding["session_id"])
-                        self._answered(r, key, back)
-                        _ev.emit("burst_round", sessions=len(good), ticks=n,
-                                 tokens=sum(len(r.outs[s_id]["tokens"])
-                                            for s_id in good))
+                        self._answered(r, key, back,
+                                       self.inner.burst_parts)
+                        if self._events.enabled:
+                            self._events.emit(
+                                "burst_round", sessions=len(good), ticks=n,
+                                tokens=sum(len(r.outs[s_id]["tokens"])
+                                           for s_id in good))
             except Exception as exc:  # whole-round failure
                 r.err = exc
                 with self._lock:
@@ -2190,10 +2306,8 @@ class BatchingStageAdapter:
                         del self._rounds[key]
             finally:
                 r.event.set()
-        else:
-            with prof.span("round_wait", session=sid):
-                if not r.event.wait(self.step_timeout):
-                    raise StageExecutionError("batched step timed out")
+        elif not r.event.wait(self.step_timeout):
+            raise StageExecutionError("batched step timed out")
         if r.t_exec and not rider:
             self._m_queue_wait.observe(max(0.0, r.t_exec - t_join))
         if r.err is not None:

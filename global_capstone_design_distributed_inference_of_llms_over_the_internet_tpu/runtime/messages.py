@@ -146,6 +146,11 @@ class StageRequest:
     # client's host-side stop rule so emitted counts match). None = no eos
     # stop (the classic path never ships one).
     eos_token_id: Optional[int] = None
+    # HOST ONLY, never on the wire (no header is built from it): the
+    # monotonic instant the serving boundary read this request's frame off
+    # its socket, for ``server_request_leg_seconds``. 0.0 on every path
+    # that has no such instant (in-process transports, relays, a gateway).
+    t_recv: float = 0.0
 
 
 @dataclasses.dataclass
@@ -208,6 +213,12 @@ class StageResponse:
     # chain the relayed final response keeps the FINAL hop's span — each
     # intermediate hop still records its span into its local tracer.
     span: Optional[dict] = None
+    # HOST ONLY, never on the wire (no frame is built from it): the
+    # monotonic instant the batched round that produced this response had
+    # its results on the host, set where the reply says the session asks
+    # for the next round; the serving boundary observes
+    # ``server_reply_leg_seconds`` from it. 0.0 on every other path.
+    t_done: float = 0.0
 
     @property
     def is_token(self) -> bool:

@@ -397,6 +397,10 @@ class _FramedTcpServer:
     """
 
     def __init__(self, host: str, port: int):
+        # Per connection thread: ``t``, the monotonic instant its last
+        # frame was read off the socket (`TcpStageServer._run_forward`
+        # hands it to the request: ``server_request_leg_seconds``).
+        self._arrival = threading.local()
         active_lock = threading.Lock()
         active: set = set()
         self._active_lock, self._active = active_lock, active
@@ -419,6 +423,7 @@ class _FramedTcpServer:
                         header, payload = _recv_frame(sock)
                     except (ConnectionError, OSError):
                         return
+                    outer._arrival.t = time.monotonic()
                     plan = outer.fault_plan
                     if plan is not None:
                         if not isinstance(sock, FaultSocket):
@@ -1050,7 +1055,13 @@ class TcpStageServer(_FramedTcpServer):
             self._stream_step(sock, ex, header, payload)
             return
         if verb == "forward":
-            self._run_forward(sock, ex, _header_to_request(header, payload),
+            # The request's way in on this connection's thread, as the span
+            # stage.request on the profiler's clock: the frame (read a few
+            # checks ago) made a request, its tensor decoded and uploaded.
+            with _get_profiler().span("request",
+                                      session=header.get("session_id")):
+                req = _header_to_request(header, payload)
+            self._run_forward(sock, ex, req,
                               resp_wire_dtype=header.get("wire_dtype"))
         elif verb in ("train_forward", "backward"):
             self._train_verbs(sock, ex, verb, header, payload)
@@ -1187,26 +1198,28 @@ class TcpStageServer(_FramedTcpServer):
                                "stream_closed": True, "reason": "deadline",
                                "message": f"session {sid}: deadline exceeded"})
             return
-        req = StageRequest(
-            session_id=sid,
-            hidden=jnp.asarray(_decode_tensor(header["tensor"], payload)),
-            seq_len=header["seq_len"],
-            cur_len=header["cur_len"],
-            is_prefill=header.get("is_prefill", False),
-            max_length=state["max_length"],
-            sampling=state["sampling"],
-            generated_tokens=tuple(state["generated"]),
-            step_seed=header.get("step_seed", 0),
-            start_block=state["start_block"],
-            end_block=state["end_block"],
-            model=state["model"],
-            next_servers=state["next_servers"],
-            start_from_position=header.get("start_from_position"),
-            prefix_len=header.get("prefix_len", 0),
-            trace=header.get("trace"),
-            deadline_budget_s=header.get("deadline_budget_s"),
-            priority=header.get("priority"),
-        )
+        # stage.request, as for the `forward` verb (`_dispatch`)
+        with _get_profiler().span("request", session=sid):
+            req = StageRequest(
+                session_id=sid,
+                hidden=jnp.asarray(_decode_tensor(header["tensor"], payload)),
+                seq_len=header["seq_len"],
+                cur_len=header["cur_len"],
+                is_prefill=header.get("is_prefill", False),
+                max_length=state["max_length"],
+                sampling=state["sampling"],
+                generated_tokens=tuple(state["generated"]),
+                step_seed=header.get("step_seed", 0),
+                start_block=state["start_block"],
+                end_block=state["end_block"],
+                model=state["model"],
+                next_servers=state["next_servers"],
+                start_from_position=header.get("start_from_position"),
+                prefix_len=header.get("prefix_len", 0),
+                trace=header.get("trace"),
+                deadline_budget_s=header.get("deadline_budget_s"),
+                priority=header.get("priority"),
+            )
         self._run_forward(sock, ex, req, stream=state,
                           step_timeout=state["step_timeout"])
 
@@ -1214,6 +1227,9 @@ class TcpStageServer(_FramedTcpServer):
                      step_timeout: Optional[float] = None,
                      resp_wire_dtype: Optional[str] = None) -> None:
         t_req = time.monotonic()
+        # When this thread read the request's frame (`handle`); a caller
+        # that is no connection thread has no such instant.
+        req.t_recv = getattr(self._arrival, "t", 0.0)
         if resp_wire_dtype is None and stream is not None:
             resp_wire_dtype = stream.get("wire_dtype")
         resp_wire_dtype = resp_wire_dtype or self.wire_dtype
@@ -1321,108 +1337,114 @@ class TcpStageServer(_FramedTcpServer):
         span.set(cache_len=resp.cache_len,
                  queue_s=max(0.0, t_compute - t_req)).end()
         wire_span = span.to_wire() if req.trace is not None else None
-        if getattr(resp, "is_burst", False):
-            frame = {
-                "verb": "burst", "session_id": resp.session_id,
-                "tokens": list(resp.burst_tokens),
-                "stop": resp.burst_stop,
-                "cache_len": resp.cache_len,
-            }
-            if wire_span is not None:
-                frame["span"] = wire_span
-            _send_frame(sock, frame)
-        elif resp.is_token:
-            if stream is not None and resp.token_id is not None:
-                # Maintain the stream's server-side recent-token window
-                # (the client never re-ships it on the stream path).
-                stream["generated"].append(int(resp.token_id))
-                del stream["generated"][:-50]
-            frame = {
-                "verb": "token", "session_id": resp.session_id,
-                "token_id": resp.token_id, "cache_len": resp.cache_len,
-            }
-            if resp.token_ids is not None:   # batch>1 per-row sampling
-                frame["token_ids"] = list(resp.token_ids)
-            if wire_span is not None:
-                frame["span"] = wire_span
-            _send_frame(sock, frame)
-        elif resp.is_speculative:
-            frame = {
-                "verb": "spec", "session_id": resp.session_id,
-                "tokens": list(resp.tokens),
-                "n_accepted": resp.n_accepted,
-                "cache_len": resp.cache_len,
-            }
-            if wire_span is not None:
-                frame["span"] = wire_span
-            _send_frame(sock, frame)
-        elif resp.is_beam:
-            frame = {
-                "verb": "beam", "session_id": resp.session_id,
-                "cache_len": resp.cache_len,
-                "top_tokens": [list(r) for r in resp.top_tokens],
-                "top_logprobs": [list(r) for r in resp.top_logprobs],
-            }
-            if wire_span is not None:
-                frame["span"] = wire_span
-            _send_frame(sock, frame)
-        elif req.next_servers:
-            # Push chain (petals handler.py:320-350): ship our output
-            # straight to the next hop and relay its final response back
-            # upstream — the client sees ONE round trip per step.
-            nxt = req.next_servers[0]
-            nreq = dataclasses.replace(
-                req,
-                hidden=resp.hidden,
-                start_block=nxt.get("start_block"),
-                end_block=nxt.get("end_block"),
-                next_servers=tuple(req.next_servers[1:]),
-            )
-            if req.deadline_budget_s is not None:
-                # Forward the REMAINING budget: this hop's service time has
-                # already been spent from the caller's deadline, and the
-                # next hop must judge expiry against what's actually left.
-                nreq = dataclasses.replace(
-                    nreq,
-                    deadline_budget_s=(req.deadline_budget_s
-                                       - (time.monotonic() - t_req)))
-            try:
-                rh, rp = self._relay(nxt, nreq)
-            except (ConnectionError, OSError, TimeoutError) as exc:
-                m_requests.labels(outcome="error").inc()
-                err = {
-                    "verb": "error", "kind": "push",
-                    "peer": nxt.get("peer_id", "?"),
-                    "message": f"push to {nxt.get('peer_id')} failed: {exc}",
+        # The reply's way out on this connection's thread, `_compute`
+        # returned -> frame written, as the span stage.reply.
+        with _get_profiler().span("reply", session=req.session_id):
+            if getattr(resp, "is_burst", False):
+                frame = {
+                    "verb": "burst", "session_id": resp.session_id,
+                    "tokens": list(resp.burst_tokens),
+                    "stop": resp.burst_stop,
+                    "cache_len": resp.cache_len,
                 }
-                if nxt.get("relay_via"):
-                    # The dial that failed was to the next hop's relay
-                    # VOLUNTEER, not the hop itself: blame the hop for
-                    # routing (`peer` — the client routes around it) but the
-                    # volunteer for the circuit breaker, so one dead relay
-                    # doesn't blacklist every peer behind it.
-                    err["breaker_peer"] = nxt.get("relay_via")
-                _send_frame(sock, err)
-                return
-            if stream is not None and rh.get("verb") == "token" and (
-                    rh.get("token_id") is not None):
-                # Push chain on a stream: the token was sampled DOWNSTREAM
-                # and only relays through us — append it to this stream's
-                # window too, or the final stage's repetition penalty would
-                # run against the window as of stream_open forever.
-                stream["generated"].append(int(rh["token_id"]))
-                del stream["generated"][:-50]
-            _send_frame(sock, rh, rp)
-        else:
-            arr = np.asarray(resp.hidden)
-            meta, body = _encode_tensor(arr, resp_wire_dtype)
-            frame = {
-                "verb": "hidden", "session_id": resp.session_id,
-                "cache_len": resp.cache_len, "tensor": meta,
-            }
-            if wire_span is not None:
-                frame["span"] = wire_span
-            _send_frame(sock, frame, body)
+                if wire_span is not None:
+                    frame["span"] = wire_span
+                _send_frame(sock, frame)
+            elif resp.is_token:
+                if stream is not None and resp.token_id is not None:
+                    # Maintain the stream's server-side recent-token window
+                    # (the client never re-ships it on the stream path).
+                    stream["generated"].append(int(resp.token_id))
+                    del stream["generated"][:-50]
+                frame = {
+                    "verb": "token", "session_id": resp.session_id,
+                    "token_id": resp.token_id, "cache_len": resp.cache_len,
+                }
+                if resp.token_ids is not None:   # batch>1 per-row sampling
+                    frame["token_ids"] = list(resp.token_ids)
+                if wire_span is not None:
+                    frame["span"] = wire_span
+                _send_frame(sock, frame)
+            elif resp.is_speculative:
+                frame = {
+                    "verb": "spec", "session_id": resp.session_id,
+                    "tokens": list(resp.tokens),
+                    "n_accepted": resp.n_accepted,
+                    "cache_len": resp.cache_len,
+                }
+                if wire_span is not None:
+                    frame["span"] = wire_span
+                _send_frame(sock, frame)
+            elif resp.is_beam:
+                frame = {
+                    "verb": "beam", "session_id": resp.session_id,
+                    "cache_len": resp.cache_len,
+                    "top_tokens": [list(r) for r in resp.top_tokens],
+                    "top_logprobs": [list(r) for r in resp.top_logprobs],
+                }
+                if wire_span is not None:
+                    frame["span"] = wire_span
+                _send_frame(sock, frame)
+            elif req.next_servers:
+                # Push chain (petals handler.py:320-350): ship our output
+                # straight to the next hop and relay its final response back
+                # upstream — the client sees ONE round trip per step.
+                nxt = req.next_servers[0]
+                nreq = dataclasses.replace(
+                    req,
+                    hidden=resp.hidden,
+                    start_block=nxt.get("start_block"),
+                    end_block=nxt.get("end_block"),
+                    next_servers=tuple(req.next_servers[1:]),
+                )
+                if req.deadline_budget_s is not None:
+                    # Forward the REMAINING budget: this hop's service time
+                    # has already been spent from the caller's deadline, and
+                    # the next hop must judge expiry against what's actually
+                    # left.
+                    nreq = dataclasses.replace(
+                        nreq,
+                        deadline_budget_s=(req.deadline_budget_s
+                                           - (time.monotonic() - t_req)))
+                try:
+                    rh, rp = self._relay(nxt, nreq)
+                except (ConnectionError, OSError, TimeoutError) as exc:
+                    m_requests.labels(outcome="error").inc()
+                    err = {
+                        "verb": "error", "kind": "push",
+                        "peer": nxt.get("peer_id", "?"),
+                        "message": (f"push to {nxt.get('peer_id')} failed: "
+                                    f"{exc}"),
+                    }
+                    if nxt.get("relay_via"):
+                        # The dial that failed was to the next hop's relay
+                        # VOLUNTEER, not the hop itself: blame the hop for
+                        # routing (`peer` — the client routes around it) but
+                        # the volunteer for the circuit breaker, so one dead
+                        # relay doesn't blacklist every peer behind it.
+                        err["breaker_peer"] = nxt.get("relay_via")
+                    _send_frame(sock, err)
+                    return
+                if stream is not None and rh.get("verb") == "token" and (
+                        rh.get("token_id") is not None):
+                    # Push chain on a stream: the token was sampled
+                    # DOWNSTREAM and only relays through us — append it to
+                    # this stream's window too, or the final stage's
+                    # repetition penalty would run against the window as of
+                    # stream_open forever.
+                    stream["generated"].append(int(rh["token_id"]))
+                    del stream["generated"][:-50]
+                _send_frame(sock, rh, rp)
+            else:
+                arr = np.asarray(resp.hidden)
+                meta, body = _encode_tensor(arr, resp_wire_dtype)
+                frame = {
+                    "verb": "hidden", "session_id": resp.session_id,
+                    "cache_len": resp.cache_len, "tensor": meta,
+                }
+                if wire_span is not None:
+                    frame["span"] = wire_span
+                _send_frame(sock, frame, body)
         # Structured per-request record (petals _log_request,
         # handler.py:549-573 parity, exceeded: RequestLog also keeps the
         # bounded ring the info verb surfaces, and errors are recorded at
@@ -1435,6 +1457,11 @@ class TcpStageServer(_FramedTcpServer):
             phase=phase).observe(time.monotonic() - t_req)
         _tm.get("server_tokens_total").labels(phase=phase).inc(req.seq_len)
         m_requests.labels(outcome="ok").inc()
+        if resp.t_done:
+            # A batched round's reply that says the session asks again:
+            # its way out, from the round's results on the host.
+            _tm.get("server_reply_leg_seconds").observe(
+                time.monotonic() - resp.t_done)
         _log("ok")
 
     def _train_verbs(self, sock, ex, verb: str, header: dict,

@@ -79,6 +79,45 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
         HISTOGRAM, "From the reply of the last round of a width to the "
                    "join of the next one, per session that the one "
                    "answered and the other took.", (), REJOIN_BUCKETS),
+    "server_round_period_seconds": (
+        HISTOGRAM, "From the start of the last round's step to the start "
+                   "of this round's, per round that holds a session the "
+                   "last round of its width answered: "
+                   "server_decode_round_seconds of that round + "
+                   "server_round_back_seconds + server_round_hold_seconds.",
+        (), FAST_BUCKETS),
+    "server_round_back_seconds": (
+        HISTOGRAM, "From the last round's results on the host to this "
+                   "round OPENED (its first session in), for the rounds "
+                   "server_round_period_seconds counts.",
+        (), REJOIN_BUCKETS),
+    "server_round_hold_seconds": (
+        HISTOGRAM, "From a round opened to the start of its step: its "
+                   "leader's hold (the span stage.round_window), the "
+                   "waits for the lock and the re-validation included; "
+                   "every round whose step ran.", (), REJOIN_BUCKETS),
+    "server_round_hold_prefill_seconds": (
+        HISTOGRAM, "Per prefill program that took the batched engine's "
+                   "lock while a round was open: the time it held it (a "
+                   "rider runs no program and is not counted).",
+        (), REJOIN_BUCKETS),
+    "server_reply_leg_seconds": (
+        HISTOGRAM, "From a round's results on the host to one session's "
+                   "reply frame written, per reply that says the session "
+                   "asks for the next round.", (), REJOIN_BUCKETS),
+    "server_request_leg_seconds": (
+        HISTOGRAM, "From a request's frame read off the socket to its "
+                   "join of a round under the batched engine's lock, for "
+                   "the joins server_round_rejoin_seconds counts.",
+        (), REJOIN_BUCKETS),
+    "server_round_stalls_total": (
+        COUNTER, "Rounds whose wall time was over 4 x that of the last "
+                 "round of their width.", (), None),
+    "server_round_stall_seconds_total": (
+        COUNTER, "Wall time of those rounds by part (build|dispatch|"
+                 "device|readback|other): the burst's phases where the "
+                 "phase profiler measured them, else all of it other.",
+        ("part",), None),
     "server_tokens_total": (
         COUNTER, "Tokens processed by this stage, per phase.",
         ("phase",), None),

@@ -185,6 +185,14 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "server", DEBUG,
         "A batched final stage ran one multi-tick burst dispatch (fields: "
         "sessions, ticks, tokens)."),
+    "round_stall": (
+        "server", WARN,
+        "A batched round took over 4 x the wall time of the last round of "
+        "its width (fields: wall_s, last_wall_s, build_s, dispatch_s, "
+        "device_s = enqueue returned -> results ready, readback_s, other_s "
+        "= what no phase of the profiler covers, all of it with the "
+        "profiler off; sessions, ticks, rider, gc_collections = per "
+        "generation since the last round ended)."),
     "burst_fallback": (
         "client", WARN,
         "A burst-mode session fell back to per-step decode because no "

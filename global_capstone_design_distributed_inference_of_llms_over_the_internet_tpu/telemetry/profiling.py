@@ -30,9 +30,11 @@ ONE CLOCK: a live bracket also opens ``jax.profiler.TraceAnnotation``
 so while a profiler trace is running every phase is a host span in the same
 ``.xplane.pb`` as the device operations, and an idle gap of the device can
 be put down to what the program was doing. ``span()`` gives the annotation
-alone, for stretches that already have their statistic elsewhere (the round
-window, a follower's wait: ``server_queue_wait_seconds``). JAX is imported
-by the first live bracket, never by this module.
+alone, for stretches that have their statistic in a series of their own (the
+round window: ``server_round_hold_seconds``; a request's way in and a
+reply's way out at the serving boundary: ``server_request_leg_seconds``,
+``server_reply_leg_seconds``). JAX is imported by the first live bracket,
+never by this module.
 
 On top of the phases it keeps the **device bubble-fraction** gauge: the
 fraction of wall time the accelerator sat idle between burst dispatches.
@@ -111,6 +113,7 @@ class _NoopBracket:
     check and zero allocation."""
 
     __slots__ = ()
+    seconds = 0.0           # what a live bracket measured; dark: nothing
 
     def __enter__(self):
         return self
@@ -139,9 +142,9 @@ class _Bracket:
     phase's statistic and its ``stage.<phase>`` span on the profiler's
     clock. ``device=True`` accounts the interval as a fenced dispatch
     (``PhaseProfiler.device_interval``: the ``device`` phase + the bubble
-    gauge)."""
+    gauge). ``seconds`` is what it measured, once it has closed."""
 
-    __slots__ = ("_prof", "_name", "_t0", "_span", "_device")
+    __slots__ = ("_prof", "_name", "_t0", "_span", "_device", "seconds")
 
     def __init__(self, prof: "PhaseProfiler", name: str,
                  meta: Dict[str, object], device: bool = False):
@@ -150,6 +153,7 @@ class _Bracket:
         self._device = device
         self._span = _annotation(name, meta)
         self._t0 = time.perf_counter()
+        self.seconds = 0.0
 
     def __enter__(self):
         self._span.__enter__()
@@ -158,6 +162,7 @@ class _Bracket:
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self.seconds = t1 - self._t0
         if self._device:
             self._prof.device_interval(self._t0, t1)
         else:

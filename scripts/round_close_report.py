@@ -12,8 +12,15 @@ the median of the lower half), and the stalls (a request's deliveries over
 From the two scrapes of a traced run (``metrics_before/after.jsonl``):
 rounds by what closed them, `round_bound_share` and `round_rejoin_ms` by
 the benchmark's own readers, the rejoin histogram, fill beside slots held,
-and the rounds longer than each bucket of `server_decode_round_seconds`.
-One JSON line a run; reads files only."""
+and the rounds longer than each bucket of `server_decode_round_seconds`
+(`period_s`, `hold_s`: the same for the periods and the holds);
+the period of a round by its parts, as three identities whose two sides
+are measured apart (``identities``: period = exec + back + hold; rejoin =
+reply + away + request, away being the client's turnaround and the wire;
+exec = host phases + launch lag + ticks + rest; the last needs
+``trace_summary.json``, which a traced run leaves); and what the program
+recorded of its stalls (rounds over 4 x their predecessor, seconds by
+part). One JSON line a run; reads files only."""
 
 import json
 import os
@@ -78,13 +85,83 @@ def buckets(ctx: dict, family: str) -> dict:
     return out
 
 
-def rounds_report(run_dir: str, man: Manifest) -> dict:
+PERIOD_METRICS = ("round_period_ms", "round_exec_ms", "round_back_ms",
+                  "round_hold_ms", "hold_prefill_ms_per_round",
+                  "round_rejoin_ms", "reply_leg_ms", "request_leg_ms",
+                  "engine_host_ms_per_round", "burst_launch_lag_ms")
+
+
+def identities(ctx: dict, man: Manifest, gap_p50_ms=None) -> dict:
+    """The period of a round by its parts, each identity's two sides read
+    apart: ``[whole, [parts...], whole - sum(parts)]`` in ms, None where a
+    series is missing (an untraced run, a program without it)."""
+    v = {n: readers.read_metric(man, n, ctx) for n in PERIOD_METRICS}
+    prog = readers.tick_program(ctx)
+    if prog is not None:
+        v["ticks_ms"] = prog["mean_s"] * 1e3         # a burst's whole run
+    if None not in (v["round_rejoin_ms"], v["reply_leg_ms"],
+                    v["request_leg_ms"]):
+        # the client's turnaround and the wire: what the legs leave
+        v["away_ms"] = (v["round_rejoin_ms"] - v["reply_leg_ms"]
+                        - v["request_leg_ms"])
+
+    def line(whole, *parts):
+        vals = [v.get(n) for n in (whole,) + parts]
+        if any(x is None for x in vals):
+            return None
+        return [vals[0], vals[1:], vals[0] - sum(vals[1:])]
+
+    prefill_s = readers.counter_delta(
+        ctx, 'server_phase_seconds_sum{phase="prefill"}')
+    rounds = readers.counter_delta(ctx, "server_decode_round_seconds_count")
+    out = {"period = exec + back + hold": line(
+               "round_period_ms", "round_exec_ms", "round_back_ms",
+               "round_hold_ms"),
+           "rejoin = reply + away + request": line(
+               "round_rejoin_ms", "reply_leg_ms", "away_ms",
+               "request_leg_ms"),
+           "exec = host + lag + ticks + rest": line(
+               "round_exec_ms", "engine_host_ms_per_round",
+               "burst_launch_lag_ms", "ticks_ms"),
+           # every prefill program's time under the lock a round (traced
+           # runs: the phase), and the part of it that fell into a hold;
+           # the rest fell between a round's results and the next's opening
+           "prefill_ms_per_round": (
+               None if prefill_s is None or not rounds
+               else prefill_s / rounds * 1e3),
+           "hold_prefill_ms_per_round": v["hold_prefill_ms_per_round"]}
+    ticks = readers.histogram_mean(ctx, {"family": "server_burst_ticks"})
+    if v["round_period_ms"] is not None and ticks and gap_p50_ms:
+        # the program's clock against the client's: what a token costs
+        out["period / ticks over gap_p50_ms"] = (
+            v["round_period_ms"] / ticks / gap_p50_ms)
+    return out
+
+
+def stalls_report(ctx: dict) -> dict:
+    """What the program recorded of its stalls in the window."""
+    fam = "server_round_stall_seconds_total"
+    parts = sorted({re.search(r'part="([^"]+)"', k).group(1)
+                    for peer in ctx["counters_after"].values() for k in peer
+                    if k.startswith(fam + "{")})
+    return {"rounds": readers.counter_delta(
+                ctx, "server_round_stalls_total"),
+            "seconds_by_part": {
+                p: readers.counter_delta(ctx, f'{fam}{{part="{p}"}}')
+                for p in parts}}
+
+
+def rounds_report(run_dir: str, man: Manifest, gap_p50_ms=None) -> dict:
     ctx = {"counters_before": load_counters(
                os.path.join(run_dir, "metrics_before.jsonl")),
            "counters_after": load_counters(
                os.path.join(run_dir, "metrics_after.jsonl"))}
     if not ctx["counters_after"]:
         return {}
+    summary = os.path.join(run_dir, "trace_summary.json")
+    if os.path.exists(summary):
+        with open(summary) as f:
+            ctx["trace"] = json.load(f)
     out = {"closed_by": {
         by: readers.counter_delta(
             ctx, f'server_round_closed_total{{by="{by}"}}')
@@ -95,6 +172,12 @@ def rounds_report(run_dir: str, man: Manifest) -> dict:
         out[name] = readers.read_metric(man, name, ctx)
     out["rejoin_s"] = buckets(ctx, "server_round_rejoin_seconds")
     out["round_s"] = buckets(ctx, "server_decode_round_seconds")
+    # a stall outside a round's step (the process stood still while a
+    # leader held its round) is no long round: it shows here
+    out["period_s"] = buckets(ctx, "server_round_period_seconds")
+    out["hold_s"] = buckets(ctx, "server_round_hold_seconds")
+    out["identities"] = identities(ctx, man, gap_p50_ms)
+    out["stalls_recorded"] = stalls_report(ctx)
     return out
 
 
@@ -103,7 +186,7 @@ def main() -> int:
     for run_dir in sys.argv[1:]:
         line = {"run": os.path.basename(os.path.normpath(run_dir))}
         line.update(gaps_report(run_dir))
-        line.update(rounds_report(run_dir, man))
+        line.update(rounds_report(run_dir, man, line.get("gap_p50_ms")))
         print(json.dumps(line), flush=True)
     return 0
 

@@ -15,8 +15,11 @@
   * which stat carried the scope name (it is on the event's METADATA in the
     raw proto, so this reads ``*.xplane.pb`` with TensorFlow's
     ``xplane_pb2``; ``jax.profiler.ProfileData`` does not show it);
-  * the program's ``stage.*`` host spans (telemetry/profiling.py): count and
-    seconds per name, and ONE request's spans from ``stage.prefill_wait`` to
+  * the program's ``stage.*`` host spans (telemetry/profiling.py): count,
+    seconds and how many carry ``session=`` per name (``stage.round_window``,
+    ``stage.request``, ``stage.reply`` and a request's three phases do; a
+    round's phases carry ``sessions=``), and ONE request's spans from
+    ``stage.prefill_wait`` to
     the end of ``stage.first_token`` with the rounds and the device programs
     that ran meanwhile.
 
@@ -204,12 +207,14 @@ def reduce_file(path: str) -> dict:
                     elif d >= LONG_HOST_EVENT_S:
                         host_threads.setdefault(thread, []).append(
                             (name, s, d))
-    agg = collections.defaultdict(lambda: [0, 0.0])
+    agg = collections.defaultdict(lambda: [0, 0.0, 0])
     for ev in stage_events:
         agg[ev["name"]][0] += 1
         agg[ev["name"]][1] += ev["dur_s"]
-    out["host"]["stage_spans"] = {k: {"count": n, "seconds": s}
-                                  for k, (n, s) in sorted(agg.items())}
+        agg[ev["name"]][2] += "session" in ev["args"]
+    out["host"]["stage_spans"] = {
+        k: {"count": n, "seconds": s, "with_session": k_sid}
+        for k, (n, s, k_sid) in sorted(agg.items())}
     out["host"]["one_request"] = _one_request(stage_events, host_threads,
                                               out["devices"])
     for dev in out["devices"].values():

@@ -444,12 +444,15 @@ def test_stage_engine_dark_profiler_builds_nothing(monkeypatch, burst):
                  prof.phase("first_token", session="s"),
                  prof.device_phase(sessions=1),
                  prof.span("round_window", session="s"),
-                 prof.span("round_wait", session="s")):
+                 prof.span("request", session="s"),
+                 prof.span("reply", session="s")):
         assert site is prof.phase("dispatch")        # the ONE shared no-op
+    assert site.seconds == 0.0             # and it has measured nothing
     _prefill_then_round(adapter, prof, burst)
     assert spans.made == []                # no TraceAnnotation constructed
     assert prof.snapshot() == {}
     assert reg.get("server_phase_seconds") is None   # no series written
+    assert adapter.inner.burst_parts is None         # no parts kept
 
 
 def test_sampler_rounds_counted_by_stage(monkeypatch):
@@ -473,8 +476,12 @@ def test_sampler_rounds_counted_by_stage(monkeypatch):
 
 
 def test_round_follower_wait_is_a_span(monkeypatch):
-    """Two burst requests enter together: one leads (``round_window``), the
-    other waits for the leader's step (``round_wait``), on ONE round."""
+    """Two burst requests enter together, on ONE round: one leads
+    (``round_window``: the leader's hold, whose statistic is
+    ``server_round_hold_seconds``); the other waits for the leader's step
+    under NO span of its own (``server_queue_wait_seconds`` is its series;
+    a follower's span lay inside the leader's and took the label of the
+    hold in a trace's idle gaps)."""
     import threading
 
     adapter, prof, _, spans = _stage_adapter(monkeypatch, profiled=True,
@@ -505,8 +512,15 @@ def test_round_follower_wait_is_a_span(monkeypatch):
     assert set(out) == {"a", "b"}
     names = [n for n, _ in spans.made]
     assert names.count("stage.round_window") == 1
-    assert names.count("stage.round_wait") == 1
+    assert not [n for n in names if "wait" in n]
+    assert sorted(names) == sorted(
+        "stage." + p for p in ("round_window", "burst_build", "device",
+                               "dispatch", "readback"))
     assert ("stage.dispatch", {"sessions": 2}) in spans.made
+    # what the engine keeps of the burst for a stall's record
+    parts = adapter.inner.burst_parts
+    assert sorted(parts) == ["build", "device", "dispatch", "readback"]
+    assert min(parts.values()) >= 0.0
     assert (adapter._m_fill.sum, adapter._m_held.sum) == (2.0, 2.0)
 
 
