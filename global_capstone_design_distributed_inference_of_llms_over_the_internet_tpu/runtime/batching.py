@@ -57,18 +57,20 @@ the same.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
+import inspect
 import threading
 import time
-from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from ..models.config import ModelConfig, refuse_single_pass
-from ..utils.platform import engine_donation
+from ..utils.platform import engine_donation, layout_pin_refused
 from ..models.partition import StageSpec
 from ..models.transformer import (
     _dot,
@@ -703,12 +705,53 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     return _run_passes(cfg, params, h, one_pass, k_all, v_all)
 
 
+def layout_text(layout: Layout) -> str:
+    """A device layout as XLA spells it in a program's text and a trace's
+    operation names: dimensions minor to major, then the tiles
+    (``{4,3,2,1,0:T(8,128)(2,1)}``)."""
+    order = ",".join(str(d) for d in reversed(layout.major_to_minor))
+    tiles = "".join("(" + ",".join(str(n) for n in t) + ")"
+                    for t in layout.tiling or ())
+    return "{" + order + (":T" + tiles if tiles else "") + "}"
+
+
+@functools.lru_cache(maxsize=None)
+def _zeros_program(shape, dtype, fmt: Optional[Format]):
+    """A program that makes ``zeros(shape, dtype)`` ON the device in
+    ``fmt`` (None: the device's default layout, uncommitted, as
+    ``jnp.zeros`` gives it): an array made in the default layout and put
+    into another would stand twice in device memory while it is re-laid."""
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=fmt)
+
+
 class BatchedStageExecutor:
-    """One stage span serving up to `slots` sessions with batched decode."""
+    """One stage span serving up to `slots` sessions with batched decode.
+
+    THE STACKS' DEVICE LAYOUT (`_ask_kv_formats`, `_stack_program`,
+    `_keep`): a device array has a LAYOUT besides its shape (which
+    dimension is minor, how the two minor ones are tiled and so padded).
+    An array made by ``jnp.zeros`` gets the device's default, which on the
+    TPU is the order that pads least; a program compiled for an argument in
+    that order whose loops want another re-lays the WHOLE array on its way
+    in and again on its way out (gpt2-xl's ``[48, 8, 1024, 25, 64]``
+    stacks: four copies of 1.26 GB a burst, 2.1 ms of every tick: PERF.md
+    section 6, PR 45). So the engine asks the compiler ONCE, before it
+    makes the stacks, which layout the program that reads them most wants
+    them in, makes them in it, and states it on every edge a stack crosses:
+    the stack arguments and results of all six programs. The logical shape
+    is the same whatever the answer; no caller sees a layout. Where a
+    stated layout would not hold (`utils.platform.layout_pin_refused`: the
+    CPU; a process that loads its programs from the persistent compile
+    cache, which is every served one today) nothing is asked or stated and
+    the stacks and programs are the ones the engine always had."""
 
     # The last burst's seconds by `STALL_PARTS`, where the phase profiler
     # measured them (`decode_burst`); None with it off.
     burst_parts: Optional[Dict[str, float]] = None
+    # The K and the V stack's device format (layout + device) that
+    # `_stack_program` pins; None: the device's default, nothing pinned.
+    # Asked once (`_ask_kv_formats`), BEFORE the stacks exist.
+    kv_formats: Tuple[Optional[Format], Optional[Format]] = (None, None)
 
     def __init__(
         self,
@@ -738,7 +781,6 @@ class BatchedStageExecutor:
         self.slots = slots
         self.max_len = max_len
         self.dtype = jnp.dtype(dtype)
-        self._new_stacks()
         self.lengths = np.zeros((slots,), np.int32)   # host-side truth
         self._slot_of: Dict[str, int] = {}
         self._free: List[int] = list(range(slots))
@@ -776,16 +818,134 @@ class BatchedStageExecutor:
         self._suffix_jit = None
         self._chain_write_jit = None
         self._grain_split_jits: Dict[tuple, Any] = {}
+        self.kv_formats = self._ask_kv_formats()
+        # The programs that returned a stack in another layout than the
+        # resident one (`_keep`).
+        self._relaid: set = set()
+        self._m_relaid = _tm.get("server_kv_layout_mismatch_programs")
+        self._m_relaid.set(0)
+        self._new_stacks()
+        asked = self.kv_formats[0] is not None
+        _ev.emit(
+            "kv_layout", shape=list(self.k.shape), dtype=str(self.dtype),
+            k_layout=layout_text(self._kv_layouts[0]),
+            v_layout=layout_text(self._kv_layouts[1]),
+            asked=asked, not_asked_because=(
+                None if asked else layout_pin_refused()),
+            logical_bytes_a_stack=int(self.k.nbytes),
+            resident_bytes_a_stack=int(self.k.on_device_size_in_bytes()))
+
+    def _stack_shape(self) -> Tuple[int, ...]:
+        """``[loop_steps * L, S, max_len, Hkv, Dh]``: rows of its own for
+        every (pass, layer), pass-major."""
+        return (max(self.spec.num_layers, 1) * self.cfg.loop_steps,
+                self.slots, self.max_len, self.cfg.num_kv_heads,
+                self.cfg.head_dim)
 
     def _new_stacks(self) -> None:
-        """Zeroed K and V stacks ``[loop_steps * L, S, max_len, Hkv, Dh]``:
-        rows of its own for every (pass, layer), pass-major."""
-        shape = (max(self.spec.num_layers, 1) * self.cfg.loop_steps,
-                 self.slots, self.max_len, self.cfg.num_kv_heads,
-                 self.cfg.head_dim)
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
+        """Zeroed K and V stacks (`_stack_shape`), made on the device in
+        the engine's formats."""
+        shape = self._stack_shape()
+        self.k, self.v = (_zeros_program(shape, self.dtype, fmt)()
+                          for fmt in self.kv_formats)
+        # What is RESIDENT, read off the arrays: `_keep` holds every
+        # program's results to it.
+        self._kv_layouts = (self.k.format.layout, self.v.format.layout)
         _tm.get("server_kv_stack_bytes").set(self.k.nbytes + self.v.nbytes)
+
+    def _ask_kv_formats(self):
+        """The formats the compiler picks for the stacks of the program
+        that reads them most: a burst of ticks where the engine holds the
+        whole model (`_build_burst`; of one tick: the answer is that of any
+        count), the decode step where it holds a span. That program is
+        lowered with ``Layout.AUTO`` on its two stack arguments and results
+        over SHAPES (nothing is allocated or run) and the choice is read
+        off what the compiler built. Only the ANSWER is kept: the engine's
+        programs are compiled with it stated (`_stack_program`), because a
+        program compiled with the question open is not the one compiled
+        for the answer (the v5e compiler, given gpt2-xl's burst with AUTO,
+        answers ``{4,3,2,1,0}`` and then copies the whole stack to
+        ``{2,4,3,1,0}`` inside every branch of the attention's ``switch``;
+        told ``{4,3,2,1,0}`` it copies nothing). Where the answer is the
+        device's default (``Hkv x Dh`` of 4 x 128 or 16 x 128) the pinned
+        programs are the ones an unpinned ``jax.jit`` builds.
+        ``(None, None)`` where a program may not state a layout
+        (`layout_pin_refused`: the CPU, whose compiler has nothing to
+        choose, and a process whose programs come from the persistent
+        compile cache, which drops a program's entry layouts)."""
+        if layout_pin_refused():
+            return None, None
+        # Compiled for the device the weights are on.
+        on = getattr(jax.tree.leaves(self.params)[0], "sharding", None)
+        shape_of = lambda a: jax.ShapeDtypeStruct(      # noqa: E731
+            a.shape, a.dtype, sharding=on)
+        stack = jax.ShapeDtypeStruct(self._stack_shape(), self.dtype)
+        if self.spec.is_first and self.spec.is_last:
+            program, at, out, n_out = (
+                self._build_burst(1), 14, 10, self._burst_results())
+            args = [self.params, *self._burst_blank().values(), stack, stack,
+                    *([jax.eval_shape(lambda: self._rider_args(None, 1))]
+                      if self.rider_rows else [])]
+        else:
+            program, at, out, n_out = self._build_decode(1), 4, 1, 3
+            x = (np.zeros((self.slots, 1), np.int32) if self.spec.is_first
+                 else np.zeros((self.slots, 1, self.cfg.hidden_size),
+                               np.float32))
+            args = [self.params, x, self.lengths,
+                    np.zeros((self.slots,), bool), stack, stack]
+        auto = Format(Layout.AUTO)
+        asked = jax.jit(
+            program.__wrapped__,
+            in_shardings=tuple(auto if at <= i <= at + 1 else None
+                               for i in range(len(args))),
+            out_shardings=tuple(auto if out <= i <= out + 1 else None
+                                for i in range(n_out)),
+        ).lower(*jax.tree.map(shape_of, args)).compile()
+        return tuple(asked.input_formats[0][at:at + 2])
+
+    def _stack_program(self, stacks: int, results: Optional[int] = None,
+                       of: int = 0, more_args: int = 0, donate: bool = True):
+        """``jax.jit`` for a program of the engine that takes the K and V
+        stacks as arguments ``stacks`` and ``stacks + 1`` and (``results``
+        not None) returns them as results ``results`` and ``results + 1``
+        of ``of``: the ONE place that says how a stack crosses a program's
+        edge. Donated (`engine_donation`), and arriving and leaving in the
+        engine's formats, so that no program, however rarely it runs,
+        re-lays a stack on its way in or out. ``more_args``: arguments
+        passed beyond the function's named ones (a burst's rider). With no
+        format to state (`_ask_kv_formats`) this is the ``jax.jit`` the
+        engine always built."""
+        def build(fn):
+            pins = {}
+            if self.kv_formats[0] is not None:
+                named = sum(p.kind is not p.VAR_POSITIONAL for p in
+                            inspect.signature(fn).parameters.values())
+                at = dict(zip((stacks, stacks + 1), self.kv_formats))
+                pins["in_shardings"] = tuple(
+                    at.get(i) for i in range(named + more_args))
+                if results is not None:
+                    at = dict(zip((results, results + 1), self.kv_formats))
+                    pins["out_shardings"] = tuple(
+                        at.get(i) for i in range(of))
+            return jax.jit(
+                fn, donate_argnums=(engine_donation(stacks, stacks + 1)
+                                    if donate else ()), **pins)
+        return build
+
+    def _keep(self, program, k, v) -> None:
+        """``k`` and ``v``, the stacks ``program`` has just returned, are
+        the resident ones from here on, and are held against the layouts
+        the stacks were made in (4 us a call): a program that returns a
+        stack in another (one built without `_stack_program`, which gives
+        its results the device's default) has re-laid it on the way out,
+        and the next program re-lays it back or is compiled anew for what
+        it is handed. ``server_kv_layout_mismatch_programs`` counts such
+        programs; 0 on every engine."""
+        self.k, self.v = k, v
+        if ((k.format.layout, v.format.layout) != self._kv_layouts
+                and program not in self._relaid):
+            self._relaid.add(program)
+            self._m_relaid.set(len(self._relaid))
 
     def _count_attn_rows(self, lengths, active, t: int) -> None:
         """Add the ticks whose slots began at ``lengths`` (``[ticks, S]``),
@@ -841,7 +1001,7 @@ class BatchedStageExecutor:
     def _build_prefill(self):
         cfg, spec = self.cfg, self.spec
 
-        @partial(jax.jit, donate_argnums=engine_donation(3, 4))
+        @self._stack_program(stacks=3, results=1, of=3)
         def prefill(params, x, slot, k_all, v_all, t_real):
             t = x.shape[1]
             positions = jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -894,7 +1054,7 @@ class BatchedStageExecutor:
         session executor's chunked continuation."""
         cfg, spec = self.cfg, self.spec
 
-        @partial(jax.jit, donate_argnums=engine_donation(3, 4))
+        @self._stack_program(stacks=3, results=1, of=3)
         def prefill_suffix(params, x, slot, k_all, v_all, p_len, t_real):
             t = x.shape[1]
             positions = p_len + jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -949,7 +1109,7 @@ class BatchedStageExecutor:
         """Write a chain's KV segments into the slot's leading cache rows
         in ONE jitted dispatch (specialized per chain length)."""
         if self._chain_write_jit is None:
-            @partial(jax.jit, donate_argnums=engine_donation(0, 1))
+            @self._stack_program(stacks=0, results=0, of=2)
             def prefix_chain_write(k_all, v_all, slot, segs_k, segs_v):
                 kc = (segs_k[0] if len(segs_k) == 1
                       else jnp.concatenate(segs_k, axis=1))
@@ -962,9 +1122,9 @@ class BatchedStageExecutor:
                             v_all, vc[:, None].astype(v_all.dtype), start))
 
             self._chain_write_jit = prefix_chain_write
-        self.k, self.v = self._chain_write_jit(
+        self._keep(self._chain_write_jit, *self._chain_write_jit(
             self.k, self.v, jnp.int32(slot),
-            [e.k for e in chain], [e.v for e in chain])
+            [e.k for e in chain], [e.v for e in chain]))
 
     def _split_grains(self, slot: int, n_grains: int, grain: int):
         """All grain KV segments of a slot's leading rows as one jitted
@@ -973,7 +1133,7 @@ class BatchedStageExecutor:
         key = (n_grains, grain)
         fn = self._grain_split_jits.get(key)
         if fn is None:
-            @jax.jit
+            @self._stack_program(stacks=0, donate=False)
             def grain_split(k_all, v_all, slot):
                 k_s = jax.lax.dynamic_index_in_dim(k_all, slot, 1,
                                                    keepdims=False)
@@ -1047,9 +1207,10 @@ class BatchedStageExecutor:
             self._suffix_jit = self._build_prefill_suffix()
         try:
             self._write_prefix_chain(s, chain)
-            h, self.k, self.v = self._suffix_jit(
+            h, k, v = self._suffix_jit(
                 self.params, jnp.asarray(suffix), jnp.int32(s), self.k,
                 self.v, jnp.int32(p), jnp.int32(ts))
+            self._keep(self._suffix_jit, k, v)
         except Exception:
             self._recover_slot(session_id, s)
             raise
@@ -1104,8 +1265,9 @@ class BatchedStageExecutor:
         if self._prefill_jit is None:
             self._prefill_jit = self._build_prefill()
         try:
-            h, self.k, self.v = self._prefill_jit(
+            h, k, v = self._prefill_jit(
                 self.params, x, jnp.int32(s), self.k, self.v, jnp.int32(t))
+            self._keep(self._prefill_jit, k, v)
         except Exception:
             self._recover_slot(session_id, s)
             raise
@@ -1122,7 +1284,7 @@ class BatchedStageExecutor:
         draft block enters as new tokens, causal within itself)."""
         cfg, spec = self.cfg, self.spec
 
-        @partial(jax.jit, donate_argnums=engine_donation(4, 5))
+        @self._stack_program(stacks=4, results=1, of=3)
         def decode_step(params, x, lengths, active, k_all, v_all):
             # x: ids [S, T] or hidden [S, T, D]; lengths/active: [S].
             offs = jnp.arange(t_step, dtype=jnp.int32)
@@ -1188,9 +1350,10 @@ class BatchedStageExecutor:
         # lengths is COPIED: jnp.asarray may alias a numpy buffer (the CPU
         # client does, zero-copy, whenever it is 64-byte aligned) and the
         # host bumps self.lengths below while the step is still in flight.
-        h, self.k, self.v = step(
+        h, k, v = step(
             self.params, jnp.asarray(x), jnp.asarray(self.lengths.copy()),
             jnp.asarray(active), self.k, self.v)
+        self._keep(step, k, v)
         self._count_attn_rows(self.lengths[None], active[None], t)
         for s in rows:
             self.lengths[s] += t
@@ -1237,7 +1400,8 @@ class BatchedStageExecutor:
         from ..models.transformer import lm_head
         from ..ops.sampling import push_recent, sample_tokens
 
-        @partial(jax.jit, donate_argnums=engine_donation(14, 15))
+        @self._stack_program(stacks=14, results=10,
+                             of=self._burst_results(), more_args=bool(lane))
         def burst_tick(params, tok, lengths, alive, seeds, recent, nvalid,
                        run, left, eos_id, temp, top_p, top_k, rp, k_all,
                        v_all, *rider):
@@ -1344,6 +1508,29 @@ class BatchedStageExecutor:
             fn = self._burst_jits[n_ticks] = self._build_burst(n_ticks)
         return fn
 
+    def _burst_blank(self) -> Dict[str, np.ndarray]:
+        """The burst program's thirteen ``[S]``-shaped arguments, in its
+        order, for a round nobody is in (`_burst_prep` fills its sessions
+        in; `_ask_kv_formats` takes the shapes). ``lengths`` is a COPY, for
+        the same reason as in `decode_batch`."""
+        from ..ops.sampling import RECENT_WINDOW
+
+        S = self.slots
+        i32 = lambda *shape: np.zeros(shape, np.int32)    # noqa: E731
+        return {"tok": i32(S), "lengths": self.lengths.copy(),
+                "alive": np.zeros((S,), bool), "seeds": i32(S),
+                "recent": i32(S, RECENT_WINDOW), "nvalid": i32(S),
+                "run": i32(S), "left": i32(S),
+                "eos_id": np.full((S,), -1, np.int32),
+                "temp": np.zeros((S,), np.float32),
+                "top_p": np.ones((S,), np.float32), "top_k": i32(S),
+                "rp": np.ones((S,), np.float32)}
+
+    def _burst_results(self) -> int:
+        """How many values the burst program returns (`_build_burst`): the
+        twelve, a looped stack's passes, a rider lane's first token."""
+        return 12 + (self.cfg.loop_steps > 1) + bool(self.rider_rows)
+
     def _burst_prep(self, entries: Dict[str, dict], n_ticks: int):
         """Pack per-session burst specs into the jit's [S]-shaped args.
 
@@ -1359,19 +1546,9 @@ class BatchedStageExecutor:
                 "sampling feeds tokens straight back into the embedding)")
         if n_ticks < 1:
             raise ValueError(f"burst of {n_ticks} ticks")
-        S = self.slots
-        tok0 = np.zeros((S,), np.int32)
-        seeds = np.zeros((S,), np.int32)
-        recent = np.zeros((S, RECENT_WINDOW), np.int32)
-        nvalid = np.zeros((S,), np.int32)
-        run0 = np.zeros((S,), np.int32)
-        left = np.zeros((S,), np.int32)
-        eos = np.full((S,), -1, np.int32)
-        temp = np.zeros((S,), np.float32)
-        top_p = np.ones((S,), np.float32)
-        top_k = np.zeros((S,), np.int32)
-        rp = np.ones((S,), np.float32)
-        alive = np.zeros((S,), bool)
+        blank = self._burst_blank()
+        (tok0, _, alive, seeds, recent, nvalid, run0, left, eos, temp, top_p,
+         top_k, rp) = blank.values()
         rows: Dict[str, int] = {}
         for sid, e in entries.items():
             s = self._slot_of.get(sid)
@@ -1407,13 +1584,7 @@ class BatchedStageExecutor:
             rows[sid] = s
         self._m_sampler.labels(stages=sampler_stages(
             temp, top_p, top_k, rp, self.cfg.vocab_size)).inc()
-        # lengths copied for the same reason as in decode_batch.
-        args = (jnp.asarray(tok0), jnp.asarray(self.lengths.copy()),
-                jnp.asarray(alive), jnp.asarray(seeds), jnp.asarray(recent),
-                jnp.asarray(nvalid), jnp.asarray(run0), jnp.asarray(left),
-                jnp.asarray(eos), jnp.asarray(temp), jnp.asarray(top_p),
-                jnp.asarray(top_k), jnp.asarray(rp))
-        return rows, args
+        return rows, tuple(jnp.asarray(a) for a in blank.values())
 
     _BURST_STOPS = {0: None, 1: "eos", 2: "repeat"}
 
@@ -1531,7 +1702,8 @@ class BatchedStageExecutor:
                 self._recover_slot(rider["session_id"], rider["slot"])
             raise
         (toks, stop, _tok, lengths_new, _alive, _seeds, _recent, _nvalid,
-         _run, _left, self.k, self.v, *more) = out
+         _run, _left, k, v, *more) = out
+        self._keep(fn, k, v)
         self.decode_steps += 1
         self.burst_dispatches += 1
         self._m_burst_disp.inc()

@@ -193,6 +193,16 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "= what no phase of the profiler covers, all of it with the "
         "profiler off; sessions, ticks, rider, gc_collections = per "
         "generation since the last round ended)."),
+    "kv_layout": (
+        "server", INFO,
+        "A batched engine made its K and V cache stacks, once at its start "
+        "(fields: shape, dtype, k_layout and v_layout = the device layout "
+        "as XLA spells it, minor to major with its tiles; asked = the "
+        "compiler was asked for it (Layout.AUTO on the program that reads "
+        "the stacks most) and not the device's default taken; "
+        "not_asked_because = cpu | compile_cache (utils.platform."
+        "layout_pin_refused) where it was not; logical_bytes_a_stack; resident_bytes_a_stack = as laid out, with "
+        "the padding of its tiles)."),
     "burst_fallback": (
         "client", WARN,
         "A burst-mode session fell back to per-step decode because no "
