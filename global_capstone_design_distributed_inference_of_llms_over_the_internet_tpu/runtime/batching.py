@@ -16,7 +16,10 @@ attends over its ``[S, max_len, Hkv, Dh]`` rows read straight out of the
 stack, so a step moves the new rows and nothing else of the cache.
 Compute scales with the slot count S (the server's intended concurrency),
 not with how many requests happen to arrive, and the step is one compiled
-program replayed forever.
+program replayed forever. Where the backend would not keep ``Dh`` minor in
+such an array, a row is held FOLDED (its heads side by side in one minor
+dim, ``[L, S, max_len, W]``: `kv_fold_width`); every program tells by the
+stack's rank, and nothing outside the programs reads a row.
 
 Sessions join at prefill (slot allocated, prompt written into the slot's
 rows), decode via `decode_batch` (whatever subset of sessions has a token
@@ -57,20 +60,18 @@ the same.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import gc
-import inspect
 import threading
 import time
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.layout import Format, Layout
 
 from ..models.config import ModelConfig, refuse_single_pass
-from ..utils.platform import engine_donation, layout_pin_refused
+from ..utils.platform import engine_donation
 from ..models.partition import StageSpec
 from ..models.transformer import (
     _dot,
@@ -291,19 +292,83 @@ def attn_blocks(lengths, active, t, max_len, xp=np):
     return xp.minimum(-(-longest // block), max_len // block)
 
 
+def kv_fold_width(layout, hkv: int, dh: int) -> Optional[int]:
+    """Lanes of a FOLDED cache row, or None where a row stays ``[Hkv, Dh]``.
+
+    ``layout`` is the backend's answer to how it holds a ``[.., max_len,
+    Hkv, Dh]`` array (`BatchedStageExecutor._new_stacks` asks). Where it
+    keeps ``Dh`` minor (every backend with one layout: the CPU; the TPU
+    for a ``head_dim`` that fills its lanes) nothing is folded and every
+    program is the one it always was. Where it does not (the v5e holds
+    ``[48, 8, 1024, 25, 64]`` with ``max_len`` minor, to pad nothing),
+    every decode program computes in ANOTHER layout than the stacks rest
+    in: it re-lays both whole stacks on its way in and out, and reads them
+    padded to full tiles in between (2.56 x at 25 x 64). No layout can be
+    stated at a program's edge to prevent it: a serialized executable,
+    i.e. one from the compile cache, has lost it (jax 0.9.0; PERF.md
+    section 6, PR 45). So the SHAPE is changed: a row's heads side by side
+    in ONE minor dim, padded with zeros to a whole number of the layout's
+    own lane tile (``Hkv * Dh`` = 1600 -> 1664 = 13 x 128), which the
+    device does keep minor (asked of the v5e: 1600 itself it would not)."""
+    order = layout.major_to_minor
+    if order[-1] == len(order) - 1 or not layout.tiling:
+        return None
+    lanes = layout.tiling[0][-1]
+    return -(-hkv * dh // lanes) * lanes
+
+
+def layout_text(layout) -> str:
+    """A device layout as XLA spells it in a program's text and a trace's
+    operation names: dimensions minor to major, then the tiles
+    (``{3,2,1,0:T(8,128)(2,1)}``)."""
+    order = ",".join(str(d) for d in reversed(layout.major_to_minor))
+    tiles = "".join("(" + ",".join(str(n) for n in t) + ")"
+                    for t in layout.tiling or ())
+    return "{" + order + (":T" + tiles if tiles else "") + "}"
+
+
+def _fold(stack, rows):
+    """``rows`` (``[.., Hkv, Dh]``) as ``stack`` holds a row: as they are
+    for a ``[L, S, max_len, Hkv, Dh]`` stack; for a folded one (``[L, S,
+    max_len, W]``: `kv_fold_width`) the heads side by side, zeros up to
+    ``W``."""
+    if stack.ndim == 5:
+        return rows
+    flat = rows.reshape(*rows.shape[:-2], -1)
+    return jnp.pad(flat, [(0, 0)] * (flat.ndim - 1)
+                   + [(0, stack.shape[-1] - flat.shape[-1])])
+
+
+def _unfold(stack, cfg, rows):
+    """`_fold`'s inverse on rows read back out of ``stack``: ``[.., Hkv,
+    Dh]``."""
+    if stack.ndim == 5:
+        return rows
+    hkv, dh = cfg.num_kv_heads, cfg.head_dim
+    return rows[..., :hkv * dh].reshape(*rows.shape[:-1], hkv, dh)
+
+
+def _origin(stack, *lead):
+    """Start indices into ``stack``: ``lead``, then 0 along every other
+    dim (a row's dims: two, or one where rows are folded)."""
+    return lead + (0,) * (stack.ndim - len(lead))
+
+
 @dataclasses.dataclass(frozen=True)
 class _CacheLayer:
-    """Layer ``at`` of a carried ``[L, S, max_len, Hkv, Dh]`` cache stack,
-    of which only the first ``blocks`` (traced) blocks of `attn_block` rows
-    are to be read, straight out of the stack."""
+    """Layer ``at`` of a carried ``[L, S, max_len, Hkv, Dh]`` cache stack
+    (folded: ``[L, S, max_len, W]``), of which only the first ``blocks``
+    (traced) blocks of `attn_block` rows are to be read, straight out of
+    the stack."""
     stack: Any
     at: Any
     blocks: Any
 
     def rows(self, start, n: int):
-        """Rows ``[start, start + n)`` of every slot: ``[S, n, Hkv, Dh]``."""
+        """Rows ``[start, start + n)`` of every slot: ``[S, n, Hkv, Dh]``
+        (folded: ``[S, n, W]``)."""
         return jax.lax.dynamic_slice(
-            self.stack, (self.at, 0, start, 0, 0),
+            self.stack, _origin(self.stack, self.at, 0, start),
             (1, self.stack.shape[1], n) + self.stack.shape[3:])[0]
 
 
@@ -330,10 +395,22 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
     qwen2-7b tick). So: a loop with a traced trip count over the blocks,
     online softmax (float32 running max, denominator and weighted sum),
     one block-sized operand a trip; ~4 us a block a layer of loop and
-    statistics, which the VPU shapes need not pay."""
+    statistics, which the VPU shapes need not pay.
+
+    FOLDED stacks (``[L, S, max_len, W]``: `kv_fold_width`) take the loop
+    whatever the shape of the products: every query head against ONE key
+    "head" as wide as a folded row. A head's query sits in the lanes of
+    its own KV head and is zero in all others (block-diagonal: the zeros
+    add nothing to a score), and of the ``W`` lanes its weighted sum comes
+    back in, its KV head's are kept. The MXU multiplies ``Hkv`` times the
+    zeros it needs not (31 GFLOP a gpt2-xl tick, 0.2 ms of its peak) and
+    the stack is read ONCE, dense, in the layout it rests in: on the v5e
+    8.16 ms a gpt2-xl tick where `_attend`'s prefixes over ``[.., 25, 64]``
+    rows took 11.48 (PERF.md section 6, PR 45)."""
     b, t = q.shape[:2]
     hkv, dh = cfg.num_kv_heads, cfg.head_dim
     groups = cfg.num_heads // hkv
+    folded = keys.stack.ndim == 4
     max_len = keys.stack.shape[2]
     rows = attn_block(max_len)
     out_dtype = jnp.promote_types(values.stack.dtype, q.dtype)  # `_attend`'s
@@ -342,7 +419,7 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
         k_pos = (start + jnp.arange(n, dtype=jnp.int32))[None, None, :]
         return _visible(cfg, q_pos, k_pos), q_pos, k_pos
 
-    if groups * t == 1:
+    if groups * t == 1 and not folded:
         def prefix(n):
             if not n:       # no active slot: nothing is read
                 return lambda: jnp.zeros((b, t, hkv * dh), out_dtype)
@@ -353,18 +430,29 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
             keys.blocks, [prefix(rows * i)
                           for i in range(max_len // rows + 1)])
 
-    qg = q.reshape(b, t, hkv, groups, dh) * _qscale(cfg)
+    if folded:
+        mine = (jnp.arange(cfg.num_heads)[:, None] // groups
+                == jnp.arange(hkv)[None, :])                    # [H, Hkv]
+        qg = _fold(keys.stack, q[:, :, :, None]
+                   * mine[:, :, None].astype(q.dtype))[:, :, None]
+        hkv, groups, dh = 1, cfg.num_heads, keys.stack.shape[-1]
+    else:
+        qg = q.reshape(b, t, hkv, groups, dh)
+    qg = qg * _qscale(cfg)          # [S, T, Hkv, G, Dh]; folded [S, T, 1, H, W]
+
+    def read(layer, j):
+        got = layer.rows(j * rows, rows)
+        return got[:, :, None] if folded else got
 
     def block(j, carry):
         m, l, acc = carry
-        sc = _masked_scores(cfg, lp, qg, keys.rows(j * rows, rows),
-                            grid(j * rows, rows))
+        sc = _masked_scores(cfg, lp, qg, read(keys, j), grid(j * rows, rows))
         m2 = jnp.maximum(m, sc.max(-1))
         corr = jnp.exp(m - m2)
         w = jnp.exp(sc - m2[..., None])
         acc = acc * corr[..., None] + jnp.einsum(
             "bhgts,bshd->bhgtd", w.astype(values.stack.dtype),
-            values.rows(j * rows, rows).astype(q.dtype),
+            read(values, j).astype(q.dtype),
             preferred_element_type=jnp.float32)
         return m2, l * corr + w.sum(-1), acc
 
@@ -374,6 +462,11 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
         (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
          jnp.zeros(stat + (dh,), jnp.float32)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
+    if folded:
+        out = jnp.einsum("bhtkd,hk->bthd",
+                         _unfold(keys.stack, cfg, out[:, 0]),
+                         mine.astype(out.dtype))
+        return out.reshape(b, t, -1).astype(out_dtype)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, -1).astype(out_dtype)
 
 
@@ -569,11 +662,14 @@ def _append_rows(stack, i, new, lengths, active):
     would clamp its start and clobber that session's last real KV rows, so
     its write value is what the gather reads at the SAME clamped start (T
     rows; the round trip is a no-op, and cheaper than a select over the
-    donated buffers). A row (``[Hkv, Dh]``, the scatter's window) at a
-    ``(layer, slot, position)`` point is the form the TPU keeps as one
-    native scatter; a window that spans the T positions is expanded into
-    a loop over the slots."""
+    donated buffers). A row (``[Hkv, Dh]``, the scatter's window; `_fold`ed
+    first where the stack holds its rows so) at a ``(layer, slot,
+    position)`` point is the form the TPU keeps as one native scatter; a
+    window that spans the T positions is expanded into a loop over the
+    slots."""
+    new = _fold(stack, new)
     slots, t = new.shape[:2]
+    row = tuple(range(2, new.ndim))         # a row's dims: (2, 3), folded (2,)
     start = jnp.clip(lengths, 0, stack.shape[2] - t)
     at = jnp.stack(jnp.broadcast_arrays(
         i, jnp.arange(slots, dtype=jnp.int32)[:, None],
@@ -581,14 +677,16 @@ def _append_rows(stack, i, new, lengths, active):
     old = jax.lax.gather(
         stack, at,
         jax.lax.GatherDimensionNumbers(
-            offset_dims=(2, 3), collapsed_slice_dims=(0, 1, 2),
+            offset_dims=row, collapsed_slice_dims=(0, 1, 2),
             start_index_map=(0, 1, 2)),
         slice_sizes=(1, 1, 1) + new.shape[2:], mode="promise_in_bounds",
         unique_indices=True, indices_are_sorted=True)
     return jax.lax.scatter(
-        stack, at, jnp.where(active[:, None, None, None], new, old),
+        stack, at,
+        jnp.where(active[(slice(None),) + (None,) * (new.ndim - 1)], new,
+                  old),
         jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(2, 3), inserted_window_dims=(0, 1, 2),
+            update_window_dims=row, inserted_window_dims=(0, 1, 2),
             scatter_dims_to_operand_dims=(0, 1, 2)),
         mode="promise_in_bounds", unique_indices=True,
         indices_are_sorted=True)
@@ -602,6 +700,7 @@ def _write_rows(stack, i, new, slots, positions, keep):
     points, no gather of what an inactive slot holds (on the v5e 1.4 ms a
     tick of 192 layer-visits against 2.6 as two appends: PERF.md, PR 34);
     an active slot's position is the caller's to keep inside the slot."""
+    new = _fold(stack, new)
     n = new.shape[0]
     out = stack.shape[2] + jnp.arange(n, dtype=jnp.int32)
     at = jnp.stack(jnp.broadcast_arrays(
@@ -609,7 +708,8 @@ def _write_rows(stack, i, new, slots, positions, keep):
     return jax.lax.scatter(
         stack, at, new,
         jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(1, 2), inserted_window_dims=(0, 1, 2),
+            update_window_dims=tuple(range(1, new.ndim)),
+            inserted_window_dims=(0, 1, 2),
             scatter_dims_to_operand_dims=(0, 1, 2)),
         mode="drop", unique_indices=True)
 
@@ -688,9 +788,9 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
                     with jax.named_scope("kv_update"):
                         stack = _write_rows(stack, at, rows, *points)
                     with jax.named_scope("attention"):
-                        mine = jax.lax.dynamic_slice(
-                            stack, (at, rider["slot"], 0, 0, 0),
-                            (1, 1, rider["rows"]) + stack.shape[3:])[0]
+                        mine = _unfold(stack, cfg, jax.lax.dynamic_slice(
+                            stack, _origin(stack, at, rider["slot"]),
+                            (1, 1, rider["rows"]) + stack.shape[3:])[0])
                     new.append(stack)
                     read.append((_CacheLayer(stack, at, blocks), mine))
                 return (read[0], read[1],
@@ -705,53 +805,12 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     return _run_passes(cfg, params, h, one_pass, k_all, v_all)
 
 
-def layout_text(layout: Layout) -> str:
-    """A device layout as XLA spells it in a program's text and a trace's
-    operation names: dimensions minor to major, then the tiles
-    (``{4,3,2,1,0:T(8,128)(2,1)}``)."""
-    order = ",".join(str(d) for d in reversed(layout.major_to_minor))
-    tiles = "".join("(" + ",".join(str(n) for n in t) + ")"
-                    for t in layout.tiling or ())
-    return "{" + order + (":T" + tiles if tiles else "") + "}"
-
-
-@functools.lru_cache(maxsize=None)
-def _zeros_program(shape, dtype, fmt: Optional[Format]):
-    """A program that makes ``zeros(shape, dtype)`` ON the device in
-    ``fmt`` (None: the device's default layout, uncommitted, as
-    ``jnp.zeros`` gives it): an array made in the default layout and put
-    into another would stand twice in device memory while it is re-laid."""
-    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=fmt)
-
-
 class BatchedStageExecutor:
-    """One stage span serving up to `slots` sessions with batched decode.
-
-    THE STACKS' DEVICE LAYOUT (`_ask_kv_formats`, `_stack_program`,
-    `_keep`): a device array has a LAYOUT besides its shape (which
-    dimension is minor, how the two minor ones are tiled and so padded).
-    An array made by ``jnp.zeros`` gets the device's default, which on the
-    TPU is the order that pads least; a program compiled for an argument in
-    that order whose loops want another re-lays the WHOLE array on its way
-    in and again on its way out (gpt2-xl's ``[48, 8, 1024, 25, 64]``
-    stacks: four copies of 1.26 GB a burst, 2.1 ms of every tick: PERF.md
-    section 6, PR 45). So the engine asks the compiler ONCE, before it
-    makes the stacks, which layout the program that reads them most wants
-    them in, makes them in it, and states it on every edge a stack crosses:
-    the stack arguments and results of all six programs. The logical shape
-    is the same whatever the answer; no caller sees a layout. Where a
-    stated layout would not hold (`utils.platform.layout_pin_refused`: the
-    CPU; a process that loads its programs from the persistent compile
-    cache, which is every served one today) nothing is asked or stated and
-    the stacks and programs are the ones the engine always had."""
+    """One stage span serving up to `slots` sessions with batched decode."""
 
     # The last burst's seconds by `STALL_PARTS`, where the phase profiler
     # measured them (`decode_burst`); None with it off.
     burst_parts: Optional[Dict[str, float]] = None
-    # The K and the V stack's device format (layout + device) that
-    # `_stack_program` pins; None: the device's default, nothing pinned.
-    # Asked once (`_ask_kv_formats`), BEFORE the stacks exist.
-    kv_formats: Tuple[Optional[Format], Optional[Format]] = (None, None)
 
     def __init__(
         self,
@@ -781,6 +840,7 @@ class BatchedStageExecutor:
         self.slots = slots
         self.max_len = max_len
         self.dtype = jnp.dtype(dtype)
+        self._new_stacks()
         self.lengths = np.zeros((slots,), np.int32)   # host-side truth
         self._slot_of: Dict[str, int] = {}
         self._free: List[int] = list(range(slots))
@@ -807,8 +867,9 @@ class BatchedStageExecutor:
         self.rider_rows = (RIDER_ROWS if cfg.loop_steps > 1
                            and spec.is_first and spec.is_last else 0)
         # Prompt-prefix KV reuse (runtime.prefix_cache), slot-layout
-        # variant: entries hold [L, G, Hkv, Dh] KV segments (+ [1, G, D]
-        # output rows off the final stage). Same grain-chained rolling
+        # variant: entries hold [L, G, Hkv, Dh] KV segments, rows as the
+        # stacks hold them (folded: [L, G, W]) (+ [1, G, D] output rows
+        # off the final stage). Same grain-chained rolling
         # digests as the session executor's store.
         self.prefix_store = None
         if prefix_cache_bytes > 0:
@@ -818,134 +879,28 @@ class BatchedStageExecutor:
         self._suffix_jit = None
         self._chain_write_jit = None
         self._grain_split_jits: Dict[tuple, Any] = {}
-        self.kv_formats = self._ask_kv_formats()
-        # The programs that returned a stack in another layout than the
-        # resident one (`_keep`).
-        self._relaid: set = set()
-        self._m_relaid = _tm.get("server_kv_layout_mismatch_programs")
-        self._m_relaid.set(0)
-        self._new_stacks()
-        asked = self.kv_formats[0] is not None
-        _ev.emit(
-            "kv_layout", shape=list(self.k.shape), dtype=str(self.dtype),
-            k_layout=layout_text(self._kv_layouts[0]),
-            v_layout=layout_text(self._kv_layouts[1]),
-            asked=asked, not_asked_because=(
-                None if asked else layout_pin_refused()),
-            logical_bytes_a_stack=int(self.k.nbytes),
-            resident_bytes_a_stack=int(self.k.on_device_size_in_bytes()))
-
-    def _stack_shape(self) -> Tuple[int, ...]:
-        """``[loop_steps * L, S, max_len, Hkv, Dh]``: rows of its own for
-        every (pass, layer), pass-major."""
-        return (max(self.spec.num_layers, 1) * self.cfg.loop_steps,
-                self.slots, self.max_len, self.cfg.num_kv_heads,
-                self.cfg.head_dim)
 
     def _new_stacks(self) -> None:
-        """Zeroed K and V stacks (`_stack_shape`), made on the device in
-        the engine's formats."""
-        shape = self._stack_shape()
-        self.k, self.v = (_zeros_program(shape, self.dtype, fmt)()
-                          for fmt in self.kv_formats)
-        # What is RESIDENT, read off the arrays: `_keep` holds every
-        # program's results to it.
-        self._kv_layouts = (self.k.format.layout, self.v.format.layout)
+        """Zeroed K and V stacks ``[loop_steps * L, S, max_len, Hkv, Dh]``:
+        rows of its own for every (pass, layer), pass-major. Where the
+        backend would not keep ``Dh`` minor a row is FOLDED: ``[.., W]``
+        (`kv_fold_width`); the programs tell by the stack's rank."""
+        row = (self.cfg.num_kv_heads, self.cfg.head_dim)
+        asked = jnp.zeros((1, 1, self.max_len) + row,
+                          self.dtype).format.layout
+        width = kv_fold_width(asked, *row)
+        shape = (max(self.spec.num_layers, 1) * self.cfg.loop_steps,
+                 self.slots, self.max_len) + (row if width is None
+                                              else (width,))
+        self.k = jnp.zeros(shape, self.dtype)
+        self.v = jnp.zeros(shape, self.dtype)
         _tm.get("server_kv_stack_bytes").set(self.k.nbytes + self.v.nbytes)
-
-    def _ask_kv_formats(self):
-        """The formats the compiler picks for the stacks of the program
-        that reads them most: a burst of ticks where the engine holds the
-        whole model (`_build_burst`; of one tick: the answer is that of any
-        count), the decode step where it holds a span. That program is
-        lowered with ``Layout.AUTO`` on its two stack arguments and results
-        over SHAPES (nothing is allocated or run) and the choice is read
-        off what the compiler built. Only the ANSWER is kept: the engine's
-        programs are compiled with it stated (`_stack_program`), because a
-        program compiled with the question open is not the one compiled
-        for the answer (the v5e compiler, given gpt2-xl's burst with AUTO,
-        answers ``{4,3,2,1,0}`` and then copies the whole stack to
-        ``{2,4,3,1,0}`` inside every branch of the attention's ``switch``;
-        told ``{4,3,2,1,0}`` it copies nothing). Where the answer is the
-        device's default (``Hkv x Dh`` of 4 x 128 or 16 x 128) the pinned
-        programs are the ones an unpinned ``jax.jit`` builds.
-        ``(None, None)`` where a program may not state a layout
-        (`layout_pin_refused`: the CPU, whose compiler has nothing to
-        choose, and a process whose programs come from the persistent
-        compile cache, which drops a program's entry layouts)."""
-        if layout_pin_refused():
-            return None, None
-        # Compiled for the device the weights are on.
-        on = getattr(jax.tree.leaves(self.params)[0], "sharding", None)
-        shape_of = lambda a: jax.ShapeDtypeStruct(      # noqa: E731
-            a.shape, a.dtype, sharding=on)
-        stack = jax.ShapeDtypeStruct(self._stack_shape(), self.dtype)
-        if self.spec.is_first and self.spec.is_last:
-            program, at, out, n_out = (
-                self._build_burst(1), 14, 10, self._burst_results())
-            args = [self.params, *self._burst_blank().values(), stack, stack,
-                    *([jax.eval_shape(lambda: self._rider_args(None, 1))]
-                      if self.rider_rows else [])]
-        else:
-            program, at, out, n_out = self._build_decode(1), 4, 1, 3
-            x = (np.zeros((self.slots, 1), np.int32) if self.spec.is_first
-                 else np.zeros((self.slots, 1, self.cfg.hidden_size),
-                               np.float32))
-            args = [self.params, x, self.lengths,
-                    np.zeros((self.slots,), bool), stack, stack]
-        auto = Format(Layout.AUTO)
-        asked = jax.jit(
-            program.__wrapped__,
-            in_shardings=tuple(auto if at <= i <= at + 1 else None
-                               for i in range(len(args))),
-            out_shardings=tuple(auto if out <= i <= out + 1 else None
-                                for i in range(n_out)),
-        ).lower(*jax.tree.map(shape_of, args)).compile()
-        return tuple(asked.input_formats[0][at:at + 2])
-
-    def _stack_program(self, stacks: int, results: Optional[int] = None,
-                       of: int = 0, more_args: int = 0, donate: bool = True):
-        """``jax.jit`` for a program of the engine that takes the K and V
-        stacks as arguments ``stacks`` and ``stacks + 1`` and (``results``
-        not None) returns them as results ``results`` and ``results + 1``
-        of ``of``: the ONE place that says how a stack crosses a program's
-        edge. Donated (`engine_donation`), and arriving and leaving in the
-        engine's formats, so that no program, however rarely it runs,
-        re-lays a stack on its way in or out. ``more_args``: arguments
-        passed beyond the function's named ones (a burst's rider). With no
-        format to state (`_ask_kv_formats`) this is the ``jax.jit`` the
-        engine always built."""
-        def build(fn):
-            pins = {}
-            if self.kv_formats[0] is not None:
-                named = sum(p.kind is not p.VAR_POSITIONAL for p in
-                            inspect.signature(fn).parameters.values())
-                at = dict(zip((stacks, stacks + 1), self.kv_formats))
-                pins["in_shardings"] = tuple(
-                    at.get(i) for i in range(named + more_args))
-                if results is not None:
-                    at = dict(zip((results, results + 1), self.kv_formats))
-                    pins["out_shardings"] = tuple(
-                        at.get(i) for i in range(of))
-            return jax.jit(
-                fn, donate_argnums=(engine_donation(stacks, stacks + 1)
-                                    if donate else ()), **pins)
-        return build
-
-    def _keep(self, program, k, v) -> None:
-        """``k`` and ``v``, the stacks ``program`` has just returned, are
-        the resident ones from here on, and are held against the layouts
-        the stacks were made in (4 us a call): a program that returns a
-        stack in another (one built without `_stack_program`, which gives
-        its results the device's default) has re-laid it on the way out,
-        and the next program re-lays it back or is compiled anew for what
-        it is handed. ``server_kv_layout_mismatch_programs`` counts such
-        programs; 0 on every engine."""
-        self.k, self.v = k, v
-        if ((k.format.layout, v.format.layout) != self._kv_layouts
-                and program not in self._relaid):
-            self._relaid.add(program)
-            self._m_relaid.set(len(self._relaid))
+        _ev.emit(
+            "kv_layout", shape=list(shape), dtype=str(self.k.dtype),
+            layout=layout_text(self.k.format.layout), row=list(row),
+            row_layout=layout_text(asked), folded_to=width,
+            logical_bytes_a_stack=int(self.k.nbytes),
+            resident_bytes_a_stack=int(self.k.on_device_size_in_bytes()))
 
     def _count_attn_rows(self, lengths, active, t: int) -> None:
         """Add the ticks whose slots began at ``lengths`` (``[ticks, S]``),
@@ -1001,7 +956,7 @@ class BatchedStageExecutor:
     def _build_prefill(self):
         cfg, spec = self.cfg, self.spec
 
-        @self._stack_program(stacks=3, results=1, of=3)
+        @partial(jax.jit, donate_argnums=engine_donation(3, 4))
         def prefill(params, x, slot, k_all, v_all, t_real):
             t = x.shape[1]
             positions = jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -1036,11 +991,11 @@ class BatchedStageExecutor:
                 # ks/vs: [L, T, Hkv, Dh] -> write rows [slot, 0:T).
                 with jax.named_scope("kv_update"):
                     k_all = jax.lax.dynamic_update_slice(
-                        k_all, ks[:, None].astype(k_all.dtype),
-                        (_at(base, 0), slot, 0, 0, 0))
+                        k_all, _fold(k_all, ks)[:, None].astype(k_all.dtype),
+                        _origin(k_all, _at(base, 0), slot))
                     v_all = jax.lax.dynamic_update_slice(
-                        v_all, vs[:, None].astype(v_all.dtype),
-                        (_at(base, 0), slot, 0, 0, 0))
+                        v_all, _fold(v_all, vs)[:, None].astype(v_all.dtype),
+                        _origin(v_all, _at(base, 0), slot))
                 return h, k_all, v_all
 
             return _run_passes(cfg, params, h, one_pass, k_all, v_all)[:3]
@@ -1054,7 +1009,7 @@ class BatchedStageExecutor:
         session executor's chunked continuation."""
         cfg, spec = self.cfg, self.spec
 
-        @self._stack_program(stacks=3, results=1, of=3)
+        @partial(jax.jit, donate_argnums=engine_donation(3, 4))
         def prefill_suffix(params, x, slot, k_all, v_all, p_len, t_real):
             t = x.shape[1]
             positions = p_len + jnp.arange(t, dtype=jnp.int32)[None, :]
@@ -1072,16 +1027,17 @@ class BatchedStageExecutor:
                 v_slot = jax.lax.dynamic_slice_in_dim(v_all, slot, 1, 1)
 
             def layer(h, xs):
-                lp, k_l, v_l = xs                    # k_l: [1, M, Hkv, Dh]
+                lp, k_l, v_l = xs       # k_l: [1, M, Hkv, Dh] or [1, M, W]
 
                 def slot_continuation(k, v):
                     with jax.named_scope("kv_update"):
                         k_new = jax.lax.dynamic_update_slice_in_dim(
-                            k_l, k.astype(k_l.dtype), p_len, 1)
+                            k_l, _fold(k_all, k).astype(k_l.dtype), p_len, 1)
                         v_new = jax.lax.dynamic_update_slice_in_dim(
-                            v_l, v.astype(v_l.dtype), p_len, 1)
-                    return (k_new, v_new, (allowed, qpos, pos_grid),
-                            (k_new, v_new))
+                            v_l, _fold(v_all, v).astype(v_l.dtype), p_len, 1)
+                    return (_unfold(k_all, cfg, k_new),
+                            _unfold(v_all, cfg, v_new),
+                            (allowed, qpos, pos_grid), (k_new, v_new))
 
                 return _decoder_layer(cfg, lp, h, rope, slot_continuation)
 
@@ -1095,9 +1051,9 @@ class BatchedStageExecutor:
                     layer, h, params["layers"], k_in, v_in)
                 with jax.named_scope("kv_update"):
                     k_all = jax.lax.dynamic_update_slice(
-                        k_all, ks, (_at(base, 0), slot, 0, 0, 0))
+                        k_all, ks, _origin(k_all, _at(base, 0), slot))
                     v_all = jax.lax.dynamic_update_slice(
-                        v_all, vs, (_at(base, 0), slot, 0, 0, 0))
+                        v_all, vs, _origin(v_all, _at(base, 0), slot))
                 return h, k_all, v_all
 
             del t_real  # mask correctness needs only qpos; kept for parity
@@ -1109,22 +1065,22 @@ class BatchedStageExecutor:
         """Write a chain's KV segments into the slot's leading cache rows
         in ONE jitted dispatch (specialized per chain length)."""
         if self._chain_write_jit is None:
-            @self._stack_program(stacks=0, results=0, of=2)
+            @partial(jax.jit, donate_argnums=engine_donation(0, 1))
             def prefix_chain_write(k_all, v_all, slot, segs_k, segs_v):
                 kc = (segs_k[0] if len(segs_k) == 1
                       else jnp.concatenate(segs_k, axis=1))
                 vc = (segs_v[0] if len(segs_v) == 1
                       else jnp.concatenate(segs_v, axis=1))
-                start = (0, slot, 0, 0, 0)
+                start = _origin(k_all, 0, slot)
                 return (jax.lax.dynamic_update_slice(
                             k_all, kc[:, None].astype(k_all.dtype), start),
                         jax.lax.dynamic_update_slice(
                             v_all, vc[:, None].astype(v_all.dtype), start))
 
             self._chain_write_jit = prefix_chain_write
-        self._keep(self._chain_write_jit, *self._chain_write_jit(
+        self.k, self.v = self._chain_write_jit(
             self.k, self.v, jnp.int32(slot),
-            [e.k for e in chain], [e.v for e in chain]))
+            [e.k for e in chain], [e.v for e in chain])
 
     def _split_grains(self, slot: int, n_grains: int, grain: int):
         """All grain KV segments of a slot's leading rows as one jitted
@@ -1133,7 +1089,7 @@ class BatchedStageExecutor:
         key = (n_grains, grain)
         fn = self._grain_split_jits.get(key)
         if fn is None:
-            @self._stack_program(stacks=0, donate=False)
+            @jax.jit
             def grain_split(k_all, v_all, slot):
                 k_s = jax.lax.dynamic_index_in_dim(k_all, slot, 1,
                                                    keepdims=False)
@@ -1207,10 +1163,9 @@ class BatchedStageExecutor:
             self._suffix_jit = self._build_prefill_suffix()
         try:
             self._write_prefix_chain(s, chain)
-            h, k, v = self._suffix_jit(
+            h, self.k, self.v = self._suffix_jit(
                 self.params, jnp.asarray(suffix), jnp.int32(s), self.k,
                 self.v, jnp.int32(p), jnp.int32(ts))
-            self._keep(self._suffix_jit, k, v)
         except Exception:
             self._recover_slot(session_id, s)
             raise
@@ -1265,9 +1220,8 @@ class BatchedStageExecutor:
         if self._prefill_jit is None:
             self._prefill_jit = self._build_prefill()
         try:
-            h, k, v = self._prefill_jit(
+            h, self.k, self.v = self._prefill_jit(
                 self.params, x, jnp.int32(s), self.k, self.v, jnp.int32(t))
-            self._keep(self._prefill_jit, k, v)
         except Exception:
             self._recover_slot(session_id, s)
             raise
@@ -1284,7 +1238,7 @@ class BatchedStageExecutor:
         draft block enters as new tokens, causal within itself)."""
         cfg, spec = self.cfg, self.spec
 
-        @self._stack_program(stacks=4, results=1, of=3)
+        @partial(jax.jit, donate_argnums=engine_donation(4, 5))
         def decode_step(params, x, lengths, active, k_all, v_all):
             # x: ids [S, T] or hidden [S, T, D]; lengths/active: [S].
             offs = jnp.arange(t_step, dtype=jnp.int32)
@@ -1350,10 +1304,9 @@ class BatchedStageExecutor:
         # lengths is COPIED: jnp.asarray may alias a numpy buffer (the CPU
         # client does, zero-copy, whenever it is 64-byte aligned) and the
         # host bumps self.lengths below while the step is still in flight.
-        h, k, v = step(
+        h, self.k, self.v = step(
             self.params, jnp.asarray(x), jnp.asarray(self.lengths.copy()),
             jnp.asarray(active), self.k, self.v)
-        self._keep(step, k, v)
         self._count_attn_rows(self.lengths[None], active[None], t)
         for s in rows:
             self.lengths[s] += t
@@ -1400,8 +1353,7 @@ class BatchedStageExecutor:
         from ..models.transformer import lm_head
         from ..ops.sampling import push_recent, sample_tokens
 
-        @self._stack_program(stacks=14, results=10,
-                             of=self._burst_results(), more_args=bool(lane))
+        @partial(jax.jit, donate_argnums=engine_donation(14, 15))
         def burst_tick(params, tok, lengths, alive, seeds, recent, nvalid,
                        run, left, eos_id, temp, top_p, top_k, rp, k_all,
                        v_all, *rider):
@@ -1508,29 +1460,6 @@ class BatchedStageExecutor:
             fn = self._burst_jits[n_ticks] = self._build_burst(n_ticks)
         return fn
 
-    def _burst_blank(self) -> Dict[str, np.ndarray]:
-        """The burst program's thirteen ``[S]``-shaped arguments, in its
-        order, for a round nobody is in (`_burst_prep` fills its sessions
-        in; `_ask_kv_formats` takes the shapes). ``lengths`` is a COPY, for
-        the same reason as in `decode_batch`."""
-        from ..ops.sampling import RECENT_WINDOW
-
-        S = self.slots
-        i32 = lambda *shape: np.zeros(shape, np.int32)    # noqa: E731
-        return {"tok": i32(S), "lengths": self.lengths.copy(),
-                "alive": np.zeros((S,), bool), "seeds": i32(S),
-                "recent": i32(S, RECENT_WINDOW), "nvalid": i32(S),
-                "run": i32(S), "left": i32(S),
-                "eos_id": np.full((S,), -1, np.int32),
-                "temp": np.zeros((S,), np.float32),
-                "top_p": np.ones((S,), np.float32), "top_k": i32(S),
-                "rp": np.ones((S,), np.float32)}
-
-    def _burst_results(self) -> int:
-        """How many values the burst program returns (`_build_burst`): the
-        twelve, a looped stack's passes, a rider lane's first token."""
-        return 12 + (self.cfg.loop_steps > 1) + bool(self.rider_rows)
-
     def _burst_prep(self, entries: Dict[str, dict], n_ticks: int):
         """Pack per-session burst specs into the jit's [S]-shaped args.
 
@@ -1546,9 +1475,19 @@ class BatchedStageExecutor:
                 "sampling feeds tokens straight back into the embedding)")
         if n_ticks < 1:
             raise ValueError(f"burst of {n_ticks} ticks")
-        blank = self._burst_blank()
-        (tok0, _, alive, seeds, recent, nvalid, run0, left, eos, temp, top_p,
-         top_k, rp) = blank.values()
+        S = self.slots
+        tok0 = np.zeros((S,), np.int32)
+        seeds = np.zeros((S,), np.int32)
+        recent = np.zeros((S, RECENT_WINDOW), np.int32)
+        nvalid = np.zeros((S,), np.int32)
+        run0 = np.zeros((S,), np.int32)
+        left = np.zeros((S,), np.int32)
+        eos = np.full((S,), -1, np.int32)
+        temp = np.zeros((S,), np.float32)
+        top_p = np.ones((S,), np.float32)
+        top_k = np.zeros((S,), np.int32)
+        rp = np.ones((S,), np.float32)
+        alive = np.zeros((S,), bool)
         rows: Dict[str, int] = {}
         for sid, e in entries.items():
             s = self._slot_of.get(sid)
@@ -1584,7 +1523,13 @@ class BatchedStageExecutor:
             rows[sid] = s
         self._m_sampler.labels(stages=sampler_stages(
             temp, top_p, top_k, rp, self.cfg.vocab_size)).inc()
-        return rows, tuple(jnp.asarray(a) for a in blank.values())
+        # lengths copied for the same reason as in decode_batch.
+        args = (jnp.asarray(tok0), jnp.asarray(self.lengths.copy()),
+                jnp.asarray(alive), jnp.asarray(seeds), jnp.asarray(recent),
+                jnp.asarray(nvalid), jnp.asarray(run0), jnp.asarray(left),
+                jnp.asarray(eos), jnp.asarray(temp), jnp.asarray(top_p),
+                jnp.asarray(top_k), jnp.asarray(rp))
+        return rows, args
 
     _BURST_STOPS = {0: None, 1: "eos", 2: "repeat"}
 
@@ -1702,8 +1647,7 @@ class BatchedStageExecutor:
                 self._recover_slot(rider["session_id"], rider["slot"])
             raise
         (toks, stop, _tok, lengths_new, _alive, _seeds, _recent, _nvalid,
-         _run, _left, k, v, *more) = out
-        self._keep(fn, k, v)
+         _run, _left, self.k, self.v, *more) = out
         self.decode_steps += 1
         self.burst_dispatches += 1
         self._m_burst_disp.inc()

@@ -276,15 +276,6 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
         GAUGE, "Bytes of the batched engine's resident K and V cache "
                "stacks (both together; a looped stack holds rows for "
                "every pass of every layer).", (), None),
-    "server_kv_layout_mismatch_programs": (
-        GAUGE, "Compiled programs of the batched engine that returned a K "
-               "or V stack in another device layout than the one the "
-               "stacks are resident in (runtime.batching."
-               "BatchedStageExecutor._keep): such a program re-lays the "
-               "whole stack on its way out and the next one re-lays it "
-               "back. 0 on every engine: every program states the "
-               "resident layout on its stack arguments and results "
-               "(_stack_program).", (), None),
     "server_burst_ticks": (
         HISTOGRAM, "Configured tick count per burst dispatch (the N of "
                    "each lax.scan program).", (), FILL_BUCKETS),

@@ -195,14 +195,16 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "generation since the last round ended)."),
     "kv_layout": (
         "server", INFO,
-        "A batched engine made its K and V cache stacks, once at its start "
-        "(fields: shape, dtype, k_layout and v_layout = the device layout "
-        "as XLA spells it, minor to major with its tiles; asked = the "
-        "compiler was asked for it (Layout.AUTO on the program that reads "
-        "the stacks most) and not the device's default taken; "
-        "not_asked_because = cpu | compile_cache (utils.platform."
-        "layout_pin_refused) where it was not; logical_bytes_a_stack; resident_bytes_a_stack = as laid out, with "
-        "the padding of its tiles)."),
+        "A batched engine made its K and V cache stacks: at its start, and "
+        "again if a failed dispatch lost them (fields: shape, dtype, layout "
+        "= the stacks' device layout as XLA spells it, minor to major with "
+        "its tiles; row = [kv_heads, head_dim]; row_layout = how the "
+        "backend holds a [1, 1, max_len, kv_heads, head_dim] array; "
+        "folded_to = lanes of a row where the backend would not keep "
+        "head_dim minor and the heads are held side by side in one dim "
+        "(runtime.batching.kv_fold_width), else null; "
+        "logical_bytes_a_stack; resident_bytes_a_stack = as laid out, "
+        "with the padding of its tiles)."),
     "burst_fallback": (
         "client", WARN,
         "A burst-mode session fell back to per-step decode because no "

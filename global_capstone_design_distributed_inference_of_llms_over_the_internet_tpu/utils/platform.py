@@ -1,6 +1,6 @@
 """Host-platform plumbing shared by the CLI, tests, the driver dry-run, and
-tools: which device a process runs on, where its compile cache lives, the
-donation rule for engine jits and whether a program may state a layout.
+tools: which device a process runs on, where its compile cache lives, and
+the donation rule for engine jits.
 
 One process owns a chip. ``serve``/``fused``/``oracle``/``local`` (and a
 client that runs a classic stage 0) initialise the accelerator; launchers
@@ -119,38 +119,3 @@ def engine_donation(*idx: int):
     import jax
 
     return idx if jax.default_backend() != "cpu" else ()
-
-
-def layout_pin_refused() -> Optional[str]:
-    """Why a program of this process may NOT state a device layout on its
-    arguments and results (``jax.experimental.layout.Format``; the batched
-    engine's cache stacks: `runtime.batching.BatchedStageExecutor
-    ._ask_kv_formats`), or None where it may.
-
-    ``"cpu"``: the CPU compiler keeps every array dense, major to minor, and
-    answers exactly that whatever it is asked, so the question costs a
-    compile (of every engine a test builds) and can change nothing.
-
-    ``"compile_cache"``: this process loads its programs from JAX's
-    persistent compile cache, and an executable that has been SERIALIZED no
-    longer has the entry layouts it was compiled with (jax / jaxlib 0.9.0,
-    libtpu 0.0.34; measured on the v5e and on the CPU, PERF.md section 6,
-    PR 45: a program compiled with ``{4,3,2,1,0:T(8,128)(2,1)}`` stated on
-    its result returns it so in the process that compiled it and in the
-    device's default ``{2,4,3,1,0}`` in the next process, which loads it;
-    the same through ``jax.experimental.serialize_executable`` inside one
-    process; the layouts travel as ``mhlo.layout_mode`` attributes of the
-    module and are not in the compile options a load is given). A pinned
-    program would have to be compiled anew in every process: 7.4 s for the
-    seven programs a gpt2-xl server warms against 0.45 s to load them
-    (my chip runs, PR 45), more than a server's start may cost. So the
-    stacks stay in the device's default layout there and the programs are
-    the ones a bare ``jax.jit`` builds."""
-    import jax
-
-    if jax.default_backend() == "cpu":
-        return "cpu"
-    if (jax.config.jax_enable_compilation_cache
-            and jax.config.jax_compilation_cache_dir):
-        return "compile_cache"
-    return None
