@@ -81,6 +81,17 @@ def server_args(config: dict) -> list:
     return config["deployment"]["servers"][0]["args"]
 
 
+def slot_len(config: dict) -> int:
+    """A cell's slot length: what its configuration's first server is
+    started with. The check's engine and the slot test read it here, and
+    no file of the harness holds a row count of its own."""
+    rows = server_arg(server_args(config), "--max_session_len")
+    if rows is None:
+        raise ValueError(f"config {config.get('name')}: its first server "
+                         f"states no --max_session_len")
+    return int(rows)
+
+
 def check_lengths(traffic: dict, n: int) -> list:
     """n prompt lengths spread over the cell's own table."""
     table = sorted(set(traffic["prompt_lens"]))
@@ -117,8 +128,12 @@ def build(config: dict, traffic: dict, seed: int, *, control: bool,
           dry: bool) -> dict:
     """Everything up to "the engine exists": the sizes the check runs at,
     the seeded checkpoint, its conversion (the control's quantisation
-    where asked) and the program's engine, sized by the drive's
-    ``rows_needed``."""
+    where asked) and the program's engine, its slots as long as the
+    SERVED ones (so the check runs the shapes the servers ran, and a fault
+    that depends on a slot's length is in what decides ``correct``); the
+    rehearsal's are the drive's ``rows_needed`` and a margin. A drive that
+    needs more rows than the cell serves is refused before anything is
+    made."""
     import jax.numpy as jnp
 
     reference = reference_of(config)
@@ -136,6 +151,17 @@ def build(config: dict, traffic: dict, seed: int, *, control: bool,
         args = list(args)
         args[args.index("--burst") + 1] = "4"
         burst = 4
+    slots = int(server_arg(args, "--slots", 8))
+    table = check_lengths(traffic, 3)   # shortest, middle, longest prompt
+    lens = [table[i % len(table)] for i in range(int(chk["sessions"]))]
+    if dry:
+        lens = [max(4, n // 8) for n in lens]
+    rows = getattr(drive_mod, "rows_needed", stock_drive.rows_needed)(
+        chk, args, lens, dry)
+    max_len = rows + 8 if dry else slot_len(config)
+    if rows > max_len:
+        raise ValueError(f"config {config.get('name')}: the check needs "
+                         f"{rows} rows, the cell serves {max_len}")
     layers = int(chk["layers"])
     if dry:
         hf, cut = chk["dry_run_hf_config"], {}
@@ -152,15 +178,13 @@ def build(config: dict, traffic: dict, seed: int, *, control: bool,
     sizes = {"check": dict(cut, layers=layers),
              "cell": dict({k: config["hf_config"][k] for k in cut},
                           layers=cell_cfg.num_layers)}
+    if not dry:
+        sizes["check"]["max_session_len"] = \
+            sizes["cell"]["max_session_len"] = max_len
     if check_args != cell_args:
         sizes["check"]["model_args"] = check_args
         sizes["cell"]["model_args"] = cell_args
     quant = chk["control"] if control else server_arg(args, "--quant", "none")
-    slots = int(server_arg(args, "--slots", 8))
-    table = check_lengths(traffic, 3)   # shortest, middle, longest prompt
-    lens = [table[i % len(table)] for i in range(int(chk["sessions"]))]
-    if dry:
-        lens = [max(4, n // 8) for n in lens]
     dtype = jnp.bfloat16 if server_arg(
         args, "--dtype", "bfloat16") == "bfloat16" else jnp.float32
 
@@ -169,13 +193,8 @@ def build(config: dict, traffic: dict, seed: int, *, control: bool,
     if quant != "none":
         params = quant_mod.quantize_params(params, quant)
     spec = partition.StagePlan.even(cfg.num_layers, 1).stages[0]
-    rows = getattr(drive_mod, "rows_needed", stock_drive.rows_needed)(
-        chk, args, lens, dry)
-    eng = batching.BatchedStageExecutor(
-        cfg, spec, params, slots=slots,
-        max_len=min(rows + 8 if dry else int(
-            server_arg(args, "--max_session_len", rows + 8)), 1024),
-        dtype=dtype)
+    eng = batching.BatchedStageExecutor(cfg, spec, params, slots=slots,
+                                        max_len=max_len, dtype=dtype)
     return {"eng": eng, "cfg": cfg, "reference": reference, "hf": hf,
             "layers": layers, "weights": weights, "lens": lens,
             "drive": drive_mod.drive, "drive_file": drive_file,

@@ -31,8 +31,9 @@ A drive computes no number: the harness runs the reference once an episode
 and takes every statistic itself. A drive may call the program's engine;
 the configuration's reference may not. A drive may also define
 ``rows_needed(chk, server_args, lens, dry)``, the cache rows a session
-needs, so that the harness can size the engine; without it the harness
-takes this file's.
+needs: the harness refuses a drive that needs more than the cell's slots
+hold (the engine's are as long as the served ones; the CPU rehearsal's
+are sized by it); without it the harness takes this file's.
 
 This one: prefill, ``decode_steps`` single-id steps through the cache at
 the served slot count, then ``burst_rounds`` greedy rounds of the served

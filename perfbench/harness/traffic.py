@@ -68,6 +68,35 @@ def pairs_of(spec: dict) -> List[Tuple[int, int]]:
     return [(n, budgets[i]) for n, i in zip(prompts, pairing)]
 
 
+# The program reads a slot by blocks of this many rows
+# (``runtime.batching.ATTN_BLOCK``): a served slot is a whole number of them.
+SLOT_BLOCK = 128
+
+
+def slot_rows(spec: dict) -> int:
+    """What a mix needs of a slot: the most cache rows the program writes
+    for a session this file can send. A session of the pair (P, B) writes
+    P rows at its prefill and one row for every token FED BACK, B - 1 of
+    them: the last token is emitted and never written. A last burst
+    writes nothing past its budget (``_burst_prep`` cuts a burst to
+    ``min(budget, ticks)``, the client asks ``min(burst, tokens left)``,
+    and ``_burst_collect`` grows a slot by the tokens emitted and no
+    more), and the engine admits a burst where ``length + budget`` is at
+    most the slot: P + B - 1 at the last one. The load generator's first
+    warm-up request (``loadgen.Load.warm``) is the shortest prompt with a
+    budget of burst + 2. It is the longest STATED pair that counts, not
+    max + max of the two tables, which no session sends."""
+    pairs = pairs_of(spec)
+    burst = max(1, int(spec.get("route", {}).get("burst", 0)))
+    return max(max(p + b - 1 for p, b in pairs),
+               min(p for p, _ in pairs) + burst + 1)
+
+
+def least_slot(spec: dict) -> int:
+    """The shortest slot, in whole blocks, that holds the mix."""
+    return -(-slot_rows(spec) // SLOT_BLOCK) * SLOT_BLOCK
+
+
 def affine_pairings(prompts: Sequence[int], budgets: Sequence[int]
                     ) -> List[Tuple[int, int, List[int]]]:
     """The family a traffic file's ``pairing`` is taken from, in the order

@@ -37,6 +37,7 @@ sys.path.insert(0, ROOT)
 from perfbench.harness import procs, readers  # noqa: E402
 from perfbench.harness.manifest import Manifest  # noqa: E402
 from perfbench.harness.procs import BenchFailure  # noqa: E402
+from perfbench.harness.traffic import least_slot  # noqa: E402
 
 # The program's own launch convention (JAX-free): host-side roles on the
 # CPU, chip owners on JAX_PLATFORMS=tpu so that JAX raises where there is
@@ -86,11 +87,13 @@ def dry_traffic(traffic: dict) -> dict:
 
 def server_argv(server: dict, model_args, reg_addr: str, seed: int,
                 trace: bool, dry: bool, traffic: dict) -> list:
+    """``traffic`` is what the servers will be offered: under ``dry`` the
+    rehearsal's mix, and the slot is the shortest that holds IT."""
     args = list(server["args"])
     if dry:
-        for flag, val in (("--max_session_len", "128"), ("--slots", str(
-                traffic["sessions"])), ("--burst", str(
-                    traffic["route"].get("burst", 0)))):
+        for flag, val in (("--max_session_len", str(least_slot(traffic))),
+                          ("--slots", str(traffic["sessions"])),
+                          ("--burst", str(traffic["route"].get("burst", 0)))):
             if flag in args:
                 args[args.index(flag) + 1] = val
     args += ["--registry_addr", reg_addr, "--seed", str(seed), *model_args]

@@ -125,11 +125,12 @@ def test_the_module_a_configuration_names_decides(family_root, module,
         assert row["logit_rel_rms"] > 0.5 and row["burst_gap"] > 0.1
     # the line says how far the comparison stood from the cell's own size
     args = ["--model", "qwen2-0.5b", "--num_layers"]
+    # ... and that its slots were as long as the served ones
     assert row["sizes"] == {
         "check": {"num_hidden_layers": 2, "layers": 2,
-                  "model_args": args + ["2"]},
+                  "max_session_len": 64, "model_args": args + ["2"]},
         "cell": {"num_hidden_layers": 4, "layers": 4,
-                 "model_args": args + ["4"]}}
+                 "max_session_len": 64, "model_args": args + ["4"]}}
 
 
 def half_a_module(body, root):

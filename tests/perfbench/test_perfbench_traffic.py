@@ -9,13 +9,15 @@ import random
 
 import pytest
 
-from perfbench.harness import traffic
+from perfbench.harness import check, traffic
+from perfbench.harness.manifest import Manifest
 from perfbench.harness.traffic import Schedule, affine_pairings, pairs_of
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 TRAFFIC = os.path.join(ROOT, "perfbench", "traffic")
 FILES = sorted(f for f in os.listdir(TRAFFIC) if f.endswith(".json"))
+MANIFEST = Manifest(ROOT)
 MINI8 = os.path.join(ROOT, "tests", "perfbench", "fixtures", "family",
                      "perfbench", "traffic", "mini8.json")
 # every residue modulo 4 (the bits the old mix dropped), small and large
@@ -205,10 +207,18 @@ def test_same_seed_same_schedule_and_ids(name):
     assert a.warm_lengths() == sorted(set(spec["prompt_lens"]))
 
 
-def test_prompt_plus_budget_fits_the_slot():
-    for name in FILES:
-        spec = load(name)
-        assert max(spec["prompt_lens"]) + max(spec["token_budgets"]) <= 1024
+@pytest.mark.parametrize("cell",
+                         [w["name"] for w in MANIFEST.data["workloads"]])
+def test_prompt_plus_budget_fits_the_slot(cell):
+    """A mix is held to the slot of the configuration its CELL names:
+    the rows its longest stated pair writes, against that configuration's
+    own ``--max_session_len``. A mix that no cell names has no slot to be
+    held to, so every file of ``perfbench/traffic/`` is named by one."""
+    w = MANIFEST.workload(cell)
+    assert traffic.slot_rows(MANIFEST.traffic(w["traffic"])) <= \
+        check.slot_len(MANIFEST.config(w["config"]))
+    assert set(FILES) == {c["traffic"] + ".json"
+                          for c in MANIFEST.data["workloads"]}
 
 
 @pytest.mark.parametrize("arrival", ["uniform", "poisson"])
