@@ -151,6 +151,37 @@ def load_config(args) -> ModelConfig:
     return _preset_config(args)
 
 
+def _refuse_unheld_state(args, cfg: ModelConfig) -> None:
+    """Where a weight-holding mode CHOOSES its engine, before a weight is
+    made: the one predicate on per-session state
+    (`models.config.single_pass_unsupported`) asked of the engine the
+    arguments name. The full-span batched server (``serve --stage 0
+    --batched``) holds every kind of state; the in-program oracle runs a
+    looped stack and nothing else; every other engine keeps one K/V row a
+    position and one pass a token. A client or gateway is host-side and
+    meets the same predicate where a route makes it build stage 0
+    (`runtime.client`); the engines' own constructors ask it too."""
+    from .models.config import single_pass_unsupported
+
+    if args.mode == "serve" and args.stage == 0 and args.batched:
+        what = None
+        if cfg.eva_window and args.prefix_cache_mb:
+            what = "the prefix cache (a stored prefix is a slice of rows)"
+        elif cfg.eva_window and getattr(args, "speculative_k", 0):
+            what = ("speculative verify (a block of draft rows may cross "
+                    "a window's edge)")
+    elif args.mode == "serve":
+        what = ("a stage server over part of the stack" if args.batched
+                else "the per-session executor")
+    elif args.mode == "oracle" and not cfg.eva_window:
+        what = None
+    else:
+        what = f"--mode {args.mode}"
+    reason = what and single_pass_unsupported(cfg, what)
+    if reason:
+        raise SystemExit(reason)
+
+
 def load_model(args) -> Tuple[ModelConfig, dict]:
     if args.checkpoint and args.num_layers:
         raise SystemExit("--num_layers cuts a random-init preset; a "
@@ -3367,6 +3398,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         # Host-side until a classic route needs stage 0 (_lazy_stage0).
         cfg, params = load_config(args), None
     else:
+        _refuse_unheld_state(args, load_config(args))
         cfg, params = load_model(args)
     run = {"local": run_local, "fused": run_fused, "oracle": run_oracle,
            "serve": run_serve, "client": run_client,

@@ -17,6 +17,7 @@ class ModelConfig:
     """Architecture hyperparameters for one decoder-only transformer family."""
 
     # "gpt2" | "llama" | "mistral" | "mixtral" | "qwen2" | "gemma" | "ouro"
+    # | "evabyte"
     model_type: str
     vocab_size: int
     hidden_size: int
@@ -93,6 +94,26 @@ class ModelConfig:
     loop_steps: int = 1
     exit_threshold: float = 1.0
 
+    # Windowed attention whose older rows are summaries (EVA, as EvaByte
+    # specialises it): positions fall into windows of eva_window and chunks
+    # of eva_chunk; a query takes ONE softmax over the exact keys of its
+    # OWN window up to itself and one summary row per chunk of every
+    # EARLIER window. A complete chunk's summary is two learned poolings of
+    # its rotated keys and its values, by the layer's per-head vectors
+    # ``attn.mu`` (keys) and ``attn.phi`` (values), each ``[H, Dh]``. A
+    # session holds, a layer, eva_window exact rows and one summary row per
+    # eva_chunk earlier positions. 0 = every position keeps its own row.
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # The residual stream is carried in float32 whatever the weights'
+    # type (EvaByte's fp32_skip_add); the norms hand the matmuls the
+    # weights' type back.
+    fp32_residual: bool = False
+    # Prediction heads the untied head holds, ``[D, pred_heads * V]`` with
+    # head 0 first (EvaByte's multi-byte heads). Plain next-token decoding
+    # projects by head 0's V columns only; the others are held, not served.
+    pred_heads: int = 1
+
     @property
     def head_dim(self) -> int:
         return (self.head_dim_override
@@ -108,6 +129,11 @@ class ModelConfig:
             assert self.hidden_size % self.num_heads == 0
         assert self.num_heads % self.num_kv_heads == 0
         assert self.loop_steps >= 1
+        if self.eva_window:
+            assert self.eva_chunk > 0 and self.eva_window % self.eva_chunk == 0
+            # a summary is per KV head and pooled by a vector per query head
+            assert self.num_kv_heads == self.num_heads
+            assert self.loop_steps == 1 and not self.sliding_window
 
 
 def gpt2_config(
@@ -227,6 +253,20 @@ def ouro_config(loop_steps: int = 4, exit_threshold: float = 1.0,
         loop_steps=loop_steps, exit_threshold=exit_threshold)
 
 
+def evabyte_config(window_size: int = 2048, chunk_size: int = 16,
+                   num_pred_heads: int = 8, **kw) -> ModelConfig:
+    """EvaByte (byte-level LM with EVA attention): the LLaMA layer, RMSNorm
+    scales stored as offsets from one, no biases, an untied head of
+    ``num_pred_heads`` prediction heads, the residual stream in float32,
+    and attention over ``window_size`` exact rows and one learned summary
+    row per ``chunk_size`` earlier positions (`ModelConfig.eva_window`)."""
+    cfg = llama_config(**kw)
+    return dataclasses.replace(
+        cfg, model_type="evabyte", norm_offset=True, fp32_residual=True,
+        eva_window=window_size, eva_chunk=chunk_size,
+        pred_heads=num_pred_heads)
+
+
 def mixtral_config(num_experts: int = 8, num_experts_per_tok: int = 2, **kw) -> ModelConfig:
     cfg = llama_config(**kw)
     return dataclasses.replace(
@@ -315,6 +355,25 @@ PRESETS = {
         num_kv_heads=16, intermediate_size=5632,
         max_position_embeddings=65536, rope_theta=1000000.0,
     ),
+    # EvaByte/EvaByte config.json: 32 layers, 32 heads of 128, vocabulary
+    # 320 (bytes + specials), window_size 2048, chunk_size 16, 8
+    # prediction heads, rope_theta 1e5.
+    "evabyte": lambda: evabyte_config(
+        vocab_size=320, hidden_size=4096, num_layers=32, num_heads=32,
+        num_kv_heads=32, intermediate_size=11008,
+        max_position_embeddings=32768, rope_theta=100000.0,
+    ),
+    # The same code path for the benchmark's CPU rehearsal, which runs an
+    # eighth of every length of a cell whose prompts are 2-14 K rows: a
+    # 256-row window, so that it still crosses windows, and a quarter of
+    # every width (8 heads of 128), so that 7 K rows of prompts through the
+    # engine and the float32 reference take seconds on a CPU, not minutes.
+    "evabyte-rehearsal": lambda: evabyte_config(
+        vocab_size=320, hidden_size=1024, num_layers=32, num_heads=8,
+        num_kv_heads=8, intermediate_size=2752,
+        max_position_embeddings=32768, rope_theta=100000.0,
+        window_size=256,
+    ),
 }
 
 # Qwen2.5 shares the qwen2 architecture (HF model_type "qwen2") — alias
@@ -324,11 +383,22 @@ PRESETS["qwen2.5-7b"] = PRESETS["qwen2-7b"]
 
 
 def single_pass_unsupported(cfg: ModelConfig, what: str) -> Optional[str]:
-    """Reason ``what`` (an engine or a route that visits a span of layers
-    ONCE a token) cannot run this config, or None. A looped stack lives in
-    the full-span batched engine (runtime.batching) and the in-program
-    oracle (models.transformer.full_forward) only; everything else would
-    silently run one pass of several and must refuse instead."""
+    """Reason ``what`` (an engine or a route that keeps ONE K/V row a
+    position and visits a span of layers ONCE a token) cannot run this
+    config, or None: the ONE predicate on the per-session state a family
+    needs. A looped stack lives in the full-span batched engine
+    (runtime.batching) and the in-program oracle
+    (models.transformer.full_forward) only; a family whose older rows are
+    summaries (``eva_window``) in the full-span batched engine only.
+    Everything else would silently run one pass of several, or plain
+    causal attention past the first window, and must refuse instead."""
+    if cfg.eva_window:
+        return (f"older rows are summaries ({cfg.eva_window} exact K/V rows "
+                f"a layer and one learned summary row per {cfg.eva_chunk} "
+                f"earlier positions, one softmax over both): {what} keeps "
+                "one row a position and would attend as if no window had "
+                "closed; serve the model whole on the batched engine "
+                "(serve --stage 0 --batched)")
     if cfg.loop_steps > 1:
         return (f"layers run several times a token ({cfg.loop_steps} passes "
                 f"over one stack of {cfg.num_layers}, a K/V cache for every "

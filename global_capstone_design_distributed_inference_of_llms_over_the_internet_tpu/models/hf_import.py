@@ -172,6 +172,18 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             head_dim=int(getattr(hf_cfg, "head_dim", None)
                          or hf_cfg.hidden_size // hf_cfg.num_attention_heads),
             **common)
+    if mt == "evabyte":
+        from .config import evabyte_config
+
+        if getattr(hf_cfg, "attention_class", "eva") != "eva":
+            raise ValueError(
+                f"evabyte checkpoint with attention_class "
+                f"{hf_cfg.attention_class!r} — unsupported (eva only)")
+        return evabyte_config(
+            window_size=int(hf_cfg.window_size),
+            chunk_size=int(hf_cfg.chunk_size),
+            num_pred_heads=int(getattr(hf_cfg, "num_pred_heads", 1)),
+            **common)
     if mt == "mixtral":
         cfg = mixtral_config(
             num_experts=hf_cfg.num_local_experts,
@@ -187,7 +199,7 @@ def config_from_hf(hf_cfg) -> ModelConfig:
     # Mirrors the reference's model_type guard (src/llama_partition.py:82-83).
     raise ValueError(
         f"unsupported model_type: {mt} "
-        "(expected gpt2/llama/mistral/mixtral/qwen2/gemma/ouro)")
+        "(expected gpt2/llama/mistral/mixtral/qwen2/gemma/ouro/evabyte)")
 
 
 def _gpt2_layer(sd: Mapping[str, Any], i: int) -> Params:
@@ -245,6 +257,12 @@ def _llama_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
     names = _SANDWICH_NORMS[cfg.model_type] if cfg.post_norms else _PRE_NORMS
     for ours, theirs in names.items():
         p[ours] = {"w": _np(sd[pre + theirs + ".weight"])}
+    if cfg.eva_window:
+        # One vector of head_dim a head each, whatever singleton dims the
+        # checkpoint keeps around them: ``[H, Dh]``.
+        for ours, theirs in (("mu", "adaptive_mu_k"), ("phi", "adaptive_phi")):
+            p["attn"][ours] = _np(sd[pre + "self_attn." + theirs]).reshape(
+                cfg.num_heads, cfg.head_dim)
     if cfg.altern_window:
         # even layers windowed, odd global (HF Gemma2Attention layer_idx
         # rule) — the traced per-layer window leaf.

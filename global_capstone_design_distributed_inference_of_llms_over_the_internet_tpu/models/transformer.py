@@ -69,6 +69,14 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Pa
         if cfg.post_norms:  # gemma2 sandwich norms
             p["ln3"] = {"w": one}
             p["ln4"] = {"w": one}
+    if cfg.eva_window:
+        # The two per-head pooling vectors of a chunk's summary, drawn as
+        # the published initialiser draws them: clip(N(0, 1), -1, 1) *
+        # head_dim ** -0.5.
+        for name, key in (("mu", ks[8]), ("phi", ks[9])):
+            p["attn"][name] = (jnp.clip(jax.random.normal(
+                key, (h, dh), jnp.float32), -1.0, 1.0)
+                * dh ** -0.5).astype(dtype)
     if cfg.use_bias or cfg.attn_qkv_bias:
         p["attn"]["bq"] = jnp.zeros((h * dh,), dtype)
         p["attn"]["bk"] = jnp.zeros((hkv * dh,), dtype)
@@ -132,7 +140,9 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
 
     params: Params = {"embed": embed, "layers": layers, "final_norm": final_norm}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = {"w": _dense(k_head, (cfg.hidden_size, cfg.vocab_size), dtype)}
+        params["lm_head"] = {"w": _dense(
+            k_head, (cfg.hidden_size, cfg.pred_heads * cfg.vocab_size),
+            dtype)}
     if cfg.loop_steps > 1:
         # Looped stack: the early-exit gate, Linear(hidden, 1), read on the
         # normed state that closes every pass (`close_pass`).
@@ -164,6 +174,8 @@ def embed_tokens(cfg: ModelConfig, embed: Params, input_ids: jnp.ndarray,
         # update_kv_cache.
         pos = jnp.clip(positions, 0, cfg.max_position_embeddings - 1)
         h = h + jnp.take(embed["wpe"], pos, axis=0)
+    if cfg.fp32_residual:
+        h = h.astype(jnp.float32)
     return h
 
 
@@ -676,6 +688,8 @@ def lm_head(cfg: ModelConfig, params: Params, x: jnp.ndarray,
         w = params["embed"]["wte"].T
     else:
         w = params["lm_head"]["w"]
+        if cfg.pred_heads > 1:      # next-token decoding: head 0 alone
+            w = w[:, :cfg.vocab_size]
     logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
     if cfg.final_softcap:
         # gemma2 final-logit softcapping.
@@ -784,6 +798,8 @@ def full_forward(
     prompts: optional [num_layers, pre_seq, D] deep prompts (the monolithic
     oracle for the distributed inference-time injection). A looped stack
     (``cfg.loop_steps > 1``) goes through `looped_forward`."""
+    if cfg.eva_window:
+        refuse_single_pass(cfg, "the in-program oracle")
     if cfg.loop_steps > 1:
         if prompts is not None:
             raise NotImplementedError(

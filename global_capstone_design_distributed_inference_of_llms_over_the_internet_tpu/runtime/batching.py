@@ -64,7 +64,7 @@ import gc
 import threading
 import time
 from functools import partial
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -178,6 +178,13 @@ class SlotFull(RuntimeError):
     """No free slot (admission control — the caller queues or fails over)."""
 
 
+@_catalog
+class WindowGone(ValueError):
+    """A rewind across a window edge of a family whose older rows are
+    summaries: the exact rows are gone (the client's journal replay
+    rebuilds the slot through prefill)."""
+
+
 # -- the decoder layer of the four engine programs --------------------------
 
 def _qscale(cfg) -> float:
@@ -207,6 +214,15 @@ def _softcap_and_mask(cfg, scores, allowed):
     return jnp.where(allowed, scores, NEG_INF)
 
 
+def _normed(cfg, p, h):
+    """`_norm` of the residual stream as the layer's matmuls take it: where
+    the family carries the stream in float32 (``cfg.fp32_residual``), in
+    the type of the weights again (the norm's own scale is never
+    quantised)."""
+    a = _norm(cfg, p, h)
+    return a.astype(p["w"].dtype) if cfg.fp32_residual else a
+
+
 def _residual(cfg, lp, h, attn_out):
     """Residual + MLP with optional sandwich norms (gemma2 post_norms:
     ln3 after attention, ln4 after the MLP, before each residual add): the
@@ -215,7 +231,7 @@ def _residual(cfg, lp, h, attn_out):
         if cfg.post_norms:
             attn_out = _norm(cfg, lp["ln3"], attn_out)
         h = h + attn_out
-        mlp_out = _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], h), None)
+        mlp_out = _mlp(cfg, lp["mlp"], _normed(cfg, lp["ln2"], h), None)
         if cfg.post_norms:
             mlp_out = _norm(cfg, lp["ln4"], mlp_out)
         return h + mlp_out
@@ -253,6 +269,8 @@ def _attend(cfg, lp, q, keys, values, grid):
     are each block's own): `_attend_cached`."""
     if isinstance(keys, _CacheLayer):
         return _attend_cached(cfg, lp, q, keys, values, grid[1])
+    if isinstance(keys, _WindowedRead):
+        return _attend_windowed(cfg, lp, q, keys, values, grid[1])
     b, t = q.shape[:2]
     groups = cfg.num_heads // cfg.num_kv_heads
     qg = q.reshape(b, t, cfg.num_kv_heads, groups, cfg.head_dim)
@@ -470,6 +488,236 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, -1).astype(out_dtype)
 
 
+# -- a family whose older rows are summaries (``cfg.eva_window``) -----------
+
+class _WindowedStacks(NamedTuple):
+    """The K (or V) state of a family whose older rows are summaries, where
+    every other family has ONE stack: ``exact`` ``[L, S, W, Hkv, Dh]``, the
+    rows of each slot's CURRENT window (position p at row ``p % W``), and
+    ``sums`` ``[L, S, R, Hkv, Dh]``, one summary row per chunk of its
+    earlier windows (chunk c at row c). A pytree: the programs carry,
+    donate and return it where they carry a stack."""
+    exact: Any
+    sums: Any
+
+
+def windowed_rows(cfg, max_len: int) -> Tuple[int, int]:
+    """Rows a slot of ``max_len`` positions holds a layer: ``(W, R)``, the
+    exact rows of one window (the whole slot where it is shorter) and one
+    summary row per chunk of every window but the slot's last, which no
+    later window can ask for (16384 positions at 2048 / 16: 2048 and
+    896)."""
+    w = cfg.eva_window
+    return (min(w, max_len),
+            max(-(-max_len // w) - 1, 0) * (w // cfg.eva_chunk))
+
+
+def windowed_blocks(cfg, lengths, active, rows: Tuple[int, int], xp=np):
+    """`attn_blocks` for the two stacks of a windowed family, for a step of
+    ONE new row a slot: ``(exact, sums)`` block counts. A slot at length p
+    reads rows ``0 .. p % W`` of its window stack and the first ``(W / C)
+    * (p // W)`` rows of its summary stack; every slot's read is bounded
+    by the largest such count over the ACTIVE slots, whole blocks of
+    `attn_block` rows of each stack (``rows``: the two stacks' rows a
+    slot, `windowed_rows`). Called on traced values by the decode programs
+    and on the host for the counters."""
+    rows_e, rows_s = rows
+
+    def blocks(need, rows):
+        if not rows:
+            return xp.zeros(need.shape[:-1], need.dtype)
+        block = attn_block(rows)
+        most = xp.max(xp.where(active, need, 0), axis=-1)
+        return xp.minimum(-(-most // block), rows // block)
+
+    return (blocks(lengths % cfg.eva_window + 1, rows_e),
+            blocks(_summaries_visible(cfg, lengths), rows_s))
+
+
+def _summaries_visible(cfg, q_pos):
+    """How many rows of its slot's summary stack the query at ``q_pos``
+    may see: those of every chunk of EARLIER windows, none of its own."""
+    return q_pos // cfg.eva_window * (cfg.eva_window // cfg.eva_chunk)
+
+
+def _pool_chunks(cfg, lp, k, v):
+    """The summaries of whole chunks: ``k``, ``v`` ``[.., C, H, Dh]``
+    (rotated keys) -> ``[.., H, Dh]`` each. ``k~ = sum_i softmax_i(s k_i .
+    mu) k_i``, ``v~ = sum_i softmax_i(s k_i . phi) v_i`` over the chunk's C
+    positions, ``s = Dh ** -0.5``, per head, in float32. The ONE place the
+    ``kv_summarise`` scope is opened."""
+    with jax.named_scope("kv_summarise"):
+        k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+        s = cfg.head_dim ** -0.5
+
+        def weights(vec):
+            return jax.nn.softmax(
+                s * jnp.einsum("...chd,hd->...ch", k32,
+                               vec.astype(jnp.float32)), axis=-2)[..., None]
+
+        return ((weights(lp["attn"]["mu"]) * k32).sum(-3).astype(k.dtype),
+                (weights(lp["attn"]["phi"]) * v32).sum(-3).astype(v.dtype))
+
+
+@dataclasses.dataclass(frozen=True)
+class _WindowedRead:
+    """What a windowed family's queries attend over in one layer: ``own``,
+    the rows of their own window (a `_CacheLayer` of the window stack for a
+    decode step; a prefill's fresh rows ``[1, T, Hkv, Dh]``, all of ONE
+    window and starting at its first position), and ``sums``, summary
+    rows: a `_CacheLayer` of the summary stack, or one slot's rows ``[1,
+    R, Hkv, Dh]``."""
+    own: Any
+    sums: Any
+
+
+# Query rows a windowed prefill scores at once: a 2048-row window against
+# its 2048 + 896 keys would be 0.77 GB of float32 scores a layer at 32
+# heads, and as much again for their exponentials, beside 12.7 GB resident.
+PREFILL_QUERY_ROWS = 512
+
+
+def _attend_windowed(cfg, lp, q, keys, values, q_pos):
+    """`_attend` for a family whose older rows are summaries: ONE softmax,
+    per query, over the exact keys of its own window up to itself and the
+    summaries of every chunk of earlier windows (``c // (W / C) < p //
+    W``); the same weights over the values and their summaries.
+
+    A decode step (``own`` a `_CacheLayer`, one query row a slot): two
+    bounded reads, each `_attend_cached`'s first form (a ``switch`` on the
+    stack's block count over static prefixes: ``W / 128 + 1`` and ``R /
+    128 + 1`` branches, 17 and 8 at 2048 / 896 rows, whatever the slot's
+    length in positions), each returning its softmax's running statistics
+    (max, denominator, weighted sum; float32) and not a result; the two
+    are merged as the blocks of an online softmax are, which IS the one
+    softmax over both. The window stack always holds a visible row (the
+    query's own), so a summary read in which a slot sees nothing (its
+    first window) is weighted exp(-1e30 - max) = 0.
+
+    A prefill (``own`` the fresh rows): queries in blocks of
+    `PREFILL_QUERY_ROWS`, each one `_attend` over the window's fresh keys
+    (causal) beside the slot's summary rows (those of earlier windows):
+    no mask is larger than ``[512, W + R]`` whatever the prompt."""
+    w_rows = cfg.eva_window
+    b, t = q.shape[:2]
+    hkv, dh = cfg.num_kv_heads, cfg.head_dim
+    groups = cfg.num_heads // hkv
+    if not isinstance(keys.own, _CacheLayer):
+        n_sum = keys.sums.shape[1]
+        k_cat = jnp.concatenate([keys.own, keys.sums], axis=1)
+        v_cat = jnp.concatenate([values.own, values.sums], axis=1)
+        cols = jnp.arange(t + n_sum, dtype=jnp.int32)[None, :]
+        seen = _summaries_visible(cfg, q_pos[0, 0, 0])  # ONE window's rows
+        qb = next(n for n in range(min(t, PREFILL_QUERY_ROWS), 0, -1)
+                  if t % n == 0)
+
+        def block(i):
+            rows = (i * qb + jnp.arange(qb, dtype=jnp.int32))[:, None]
+            mask = jnp.where(cols < t, cols <= rows, cols - t < seen)
+            return _attend(cfg, lp,
+                           jax.lax.dynamic_slice_in_dim(q, i * qb, qb, 1),
+                           k_cat, v_cat, (mask, rows, cols))
+
+        out = jax.lax.map(block, jnp.arange(t // qb, dtype=jnp.int32))
+        return out.transpose(1, 0, 2, 3).reshape(b, t, -1)
+
+    qg = q.reshape(b, t, hkv, groups, dh) * _qscale(cfg)
+
+    def stats(k_layer, v_layer, allowed_of):
+        """Softmax statistics over the first ``k_layer.blocks`` blocks."""
+        rows_all = k_layer.stack.shape[2]
+        stat = (b, hkv, groups, t)
+        nothing = (jnp.full(stat, NEG_INF, jnp.float32),
+                   jnp.zeros(stat, jnp.float32),
+                   jnp.zeros(stat + (dh,), jnp.float32))
+        if not rows_all:
+            return nothing
+        rows = attn_block(rows_all)
+
+        def prefix(n):
+            if not n:
+                return lambda: nothing
+
+            def read():
+                at = jnp.arange(n, dtype=jnp.int32)[None, None, :]
+                sc = jnp.einsum(
+                    "bthgd,bshd->bhgts", qg,
+                    k_layer.rows(0, n).astype(qg.dtype),
+                    preferred_element_type=jnp.float32)
+                sc = jnp.where(allowed_of(at)[:, None, None], sc, NEG_INF)
+                m = sc.max(-1)
+                p = jnp.exp(sc - m[..., None])
+                acc = jnp.einsum(
+                    "bhgts,bshd->bhgtd", p.astype(v_layer.stack.dtype),
+                    v_layer.rows(0, n).astype(q.dtype),
+                    preferred_element_type=jnp.float32)
+                return m, p.sum(-1), acc
+
+            return read
+
+        return jax.lax.switch(
+            k_layer.blocks,
+            [prefix(rows * i) for i in range(rows_all // rows + 1)])
+
+    m1, l1, a1 = stats(keys.own, values.own,
+                       lambda at: at <= q_pos % w_rows)
+    m2, l2, a2 = stats(keys.sums, values.sums,
+                       lambda at: at < _summaries_visible(cfg, q_pos))
+    m = jnp.maximum(m1, m2)
+    c1, c2 = jnp.exp(m1 - m), jnp.exp(m2 - m)
+    out = ((a1 * c1[..., None] + a2 * c2[..., None])
+           / jnp.maximum(l1 * c1 + l2 * c2, 1e-30)[..., None])
+    out_dtype = jnp.promote_types(values.own.stack.dtype, q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, -1).astype(out_dtype)
+
+
+def _append_windowed(cfg, lp, at, k, v, k_all, v_all, lengths, active,
+                     qpos, blocks):
+    """A decode step's cache policy for a windowed family (ONE new row a
+    slot): write the row at ``p % W`` of the window stacks; where it closes
+    a chunk (``p % C == C - 1``), pool the chunk's C exact rows, the new
+    one among them, into its summary row ``p // C`` (a chunk of the slot's
+    last window has no row and is dropped: nothing can ask for it); hand
+    attention both stacks to read by blocks. Every tick pools (S x C rows a
+    layer: 1 MB against the GBs a tick streams) and only the closing
+    slots' rows are kept, so that no conditional holds a stack."""
+    w_rows, c_rows = cfg.eva_window, cfg.eva_chunk
+    slots = k.shape[0]
+    with jax.named_scope("kv_update"):
+        row = lengths % w_rows
+        new = [_append_rows(stack.exact, at, rows.astype(stack.exact.dtype),
+                            row, active)
+               for stack, rows in ((k_all, k), (v_all, v))]
+        if k_all.sums.shape[2]:
+            first = jnp.clip(row // c_rows * c_rows, 0,
+                             k_all.exact.shape[2] - c_rows)
+            pick = jnp.stack(jnp.broadcast_arrays(
+                at, jnp.arange(slots, dtype=jnp.int32)[:, None],
+                first[:, None] + jnp.arange(c_rows, dtype=jnp.int32)),
+                axis=-1)
+            chunk = [jax.lax.gather(
+                stack, pick,
+                jax.lax.GatherDimensionNumbers(
+                    offset_dims=(2, 3), collapsed_slice_dims=(0, 1, 2),
+                    start_index_map=(0, 1, 2)),
+                slice_sizes=(1, 1, 1) + stack.shape[3:],
+                mode="promise_in_bounds") for stack in new]
+            pooled = _pool_chunks(cfg, lp, *chunk)       # [S, Hkv, Dh] each
+            closes = (active & (lengths % c_rows == c_rows - 1)
+                      & (lengths // c_rows < k_all.sums.shape[2]))
+            sums = [_write_rows(stack.sums, at, rows,
+                                jnp.arange(slots, dtype=jnp.int32),
+                                lengths // c_rows, closes)
+                    for stack, rows in zip((k_all, v_all), pooled)]
+        else:
+            sums = [k_all.sums, v_all.sums]
+        state = tuple(_WindowedStacks(e, s) for e, s in zip(new, sums))
+    reads = [_WindowedRead(_CacheLayer(st.exact, at, blocks[0]),
+                           _CacheLayer(st.sums, at, blocks[1]))
+             for st in state]
+    return reads[0], reads[1], (None, qpos, None), state
+
+
 def _decoder_layer(cfg, lp, h, rope, cache_policy):
     """One decoder layer of every engine program: ``(h, state)``.
 
@@ -492,7 +740,7 @@ def _decoder_layer(cfg, lp, h, rope, cache_policy):
 
     lp = dequant_tree(lp, keep_experts=cfg.is_moe)
     with jax.named_scope("attention"):
-        a = _norm(cfg, lp["ln1"], h)
+        a = _normed(cfg, lp["ln1"], h)
         q, k, v = qkv_proj(cfg, lp["attn"], a)          # [B, T, H/Hkv, Dh]
         if rope is not None:
             q = apply_rope(q, *rope)
@@ -734,7 +982,11 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     ``[slot, start : start + C)`` of every cache layer in the ONE scatter
     that writes the slots' rows (`_write_rows`) and attend over that slot's
     first R rows, causally. ``h`` and ``steps`` come back flat, the slots'
-    S rows first."""
+    S rows first.
+
+    A family whose older rows are summaries (``cfg.eva_window``; T = 1):
+    ``k_all`` and ``v_all`` are `_WindowedStacks`, the policy
+    `_append_windowed`."""
     slots = x.shape[0]
     if rider is not None:
         r_pos = rider["start"] + jnp.arange(
@@ -750,7 +1002,13 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     qpos = positions[:, :, None]                            # [S, T, 1]
     # Blocks of a cache layer that hold a row some ACTIVE slot's queries
     # may see: the layers read those and no more (`_attend_cached`).
-    blocks = attn_blocks(lengths, active, qpos.shape[1], k_all.shape[2], jnp)
+    if cfg.eva_window:
+        blocks = windowed_blocks(
+            cfg, lengths, active,
+            (k_all.exact.shape[2], k_all.sums.shape[2]), jnp)
+    else:
+        blocks = attn_blocks(lengths, active, qpos.shape[1], k_all.shape[2],
+                             jnp)
     if rider is not None:
         r_grid = jnp.arange(rider["rows"], dtype=jnp.int32)[None, None, :]
         r_qpos = r_pos[None, :, None]                           # [1, C, 1]
@@ -771,6 +1029,9 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
             def per_slot_append(k, v):
                 # Write the T new rows a slot into the stacks, THEN hand
                 # attention this layer of them to read by blocks.
+                if cfg.eva_window:
+                    return _append_windowed(cfg, lp, at, k, v, k_all, v_all,
+                                            lengths, active, qpos, blocks)
                 if rider is None:
                     with jax.named_scope("kv_update"):
                         k_new = _append_rows(
@@ -858,6 +1119,11 @@ class BatchedStageExecutor:
         self._m_exit_steps = _tm.get("server_loop_exit_steps_total")
         self._m_rows_read = _tm.get("server_attn_rows_read_total")
         self._m_rows_span = _tm.get("server_attn_rows_span_total")
+        self._m_sum_rows_read = _tm.get("server_attn_summary_rows_read_total")
+        self._m_chunks = _tm.get("server_kv_chunks_summarised_total")
+        self._m_written = _tm.get("server_kv_positions_written_total")
+        self._m_rows_held = _tm.get("server_state_rows_held_total")
+        self._m_pos_held = _tm.get("server_positions_held_total")
         # The rider lane (`_decode_span`): a looped stack's prefill program
         # streams the weights `loop_steps` times, as long as a whole tick of
         # every OTHER session's burst, and which rounds pay it is chance;
@@ -873,6 +1139,9 @@ class BatchedStageExecutor:
         # digests as the session executor's store.
         self.prefix_store = None
         if prefix_cache_bytes > 0:
+            if cfg.eva_window:
+                refuse_single_pass(cfg, "the prefix cache (a stored prefix "
+                                        "is a slice of rows)")
             from .prefix_cache import PrefixStore
 
             self.prefix_store = PrefixStore(prefix_cache_bytes)
@@ -884,33 +1153,81 @@ class BatchedStageExecutor:
         """Zeroed K and V stacks ``[loop_steps * L, S, max_len, Hkv, Dh]``:
         rows of its own for every (pass, layer), pass-major. Where the
         backend would not keep ``Dh`` minor a row is FOLDED: ``[.., W]``
-        (`kv_fold_width`); the programs tell by the stack's rank."""
+        (`kv_fold_width`); the programs tell by the stack's rank. A family
+        whose older rows are summaries holds TWO stacks each
+        (`_WindowedStacks`): the current window's exact rows and one
+        summary row a chunk of the earlier ones (`windowed_rows`)."""
         row = (self.cfg.num_kv_heads, self.cfg.head_dim)
         asked = jnp.zeros((1, 1, self.max_len) + row,
                           self.dtype).format.layout
         width = kv_fold_width(asked, *row)
-        shape = (max(self.spec.num_layers, 1) * self.cfg.loop_steps,
-                 self.slots, self.max_len) + (row if width is None
-                                              else (width,))
-        self.k = jnp.zeros(shape, self.dtype)
-        self.v = jnp.zeros(shape, self.dtype)
-        _tm.get("server_kv_stack_bytes").set(self.k.nbytes + self.v.nbytes)
+        windowed = bool(self.cfg.eva_window)
+        if windowed and width is not None:
+            raise NotImplementedError(
+                "a summary is pooled per head: rows folded into the lanes "
+                f"(head_dim {row[1]} on this backend) are not implemented "
+                "for a family whose older rows are summaries")
+        depth = max(self.spec.num_layers, 1) * self.cfg.loop_steps
+        counts = (windowed_rows(self.cfg, self.max_len) if windowed
+                  else (self.max_len,))
+        shapes = [(depth, self.slots, n) + (row if width is None
+                                            else (width,)) for n in counts]
+
+        def stacks():
+            made = [jnp.zeros(shape, self.dtype) for shape in shapes]
+            return _WindowedStacks(*made) if windowed else made[0]
+
+        self.k, self.v = stacks(), stacks()
+        leaves = jax.tree.leaves((self.k, self.v))
+        _tm.get("server_kv_stack_bytes").set(sum(x.nbytes for x in leaves))
+        shape, first = shapes[0], leaves[0]
         _ev.emit(
-            "kv_layout", shape=list(shape), dtype=str(self.k.dtype),
-            layout=layout_text(self.k.format.layout), row=list(row),
+            "kv_layout", shape=list(shape), dtype=str(first.dtype),
+            layout=layout_text(first.format.layout), row=list(row),
             row_layout=layout_text(asked), folded_to=width,
-            logical_bytes_a_stack=int(self.k.nbytes),
-            resident_bytes_a_stack=int(self.k.on_device_size_in_bytes()))
+            logical_bytes_a_stack=int(first.nbytes),
+            resident_bytes_a_stack=int(first.on_device_size_in_bytes()),
+            **({"rows": list(counts), "summary_shape": list(shapes[1])}
+               if windowed else {}))
 
     def _count_attn_rows(self, lengths, active, t: int) -> None:
         """Add the ticks whose slots began at ``lengths`` (``[ticks, S]``),
         ``active`` of them taking ``t`` new rows, to the two counters of how
         much of a cache layer the ticks' attention read: the bound is
-        `attn_blocks`, the function the programs call."""
-        blocks = attn_blocks(lengths, active, t, self.max_len)
-        self._m_rows_read.inc(
-            int(blocks.sum()) * attn_block(self.max_len) * self.slots)
+        `attn_blocks`, the function the programs call. A windowed family's
+        bounds are `windowed_blocks`' two: its exact rows go to the same
+        counter, its summary rows to one of their own, and the chunks its
+        ticks closed and pooled to a third."""
+        if self.cfg.eva_window:
+            rows_e, rows_s = rows = windowed_rows(self.cfg, self.max_len)
+            exact, sums = windowed_blocks(self.cfg, lengths, active, rows)
+            self._m_rows_read.inc(
+                int(exact.sum()) * attn_block(rows_e) * self.slots)
+            if rows_s:
+                self._m_sum_rows_read.inc(
+                    int(sums.sum()) * attn_block(rows_s) * self.slots)
+            c = self.cfg.eva_chunk
+            self._m_chunks.inc(int((active & (lengths % c == c - 1)).sum()))
+        else:
+            blocks = attn_blocks(lengths, active, t, self.max_len)
+            self._m_rows_read.inc(
+                int(blocks.sum()) * attn_block(self.max_len) * self.slots)
         self._m_rows_span.inc(len(lengths) * self.slots * self.max_len)
+        self._m_written.inc(int(np.sum(active)) * t)
+
+    def _count_rows_held(self, held: Sequence[int]) -> None:
+        """Once a round, over the slots in it (``held``): the cache rows a
+        layer holds for those sessions against the positions they have
+        sent. One row a position everywhere but in a windowed family: the
+        rows of the current window and one a chunk of the earlier ones."""
+        n = self.lengths[list(held)].astype(np.int64)
+        rows = n
+        if self.cfg.eva_window:
+            last = np.maximum(n - 1, 0)     # the newest position held
+            rows = np.where(n > 0, last % self.cfg.eva_window + 1
+                            + _summaries_visible(self.cfg, last), 0)
+        self._m_rows_held.inc(int(rows.sum()))
+        self._m_pos_held.inc(int(n.sum()))
 
     # ------------------------------------------------------------------
     # Slots
@@ -944,9 +1261,19 @@ class BatchedStageExecutor:
         s = self._slot_of.get(session_id)
         if s is None:
             raise KeyError(f"unknown session {session_id}")
-        if not 0 <= pos <= int(self.lengths[s]):
-            raise ValueError(
-                f"rewind to {pos} outside [0, {int(self.lengths[s])}]")
+        cur = int(self.lengths[s])
+        if not 0 <= pos <= cur:
+            raise ValueError(f"rewind to {pos} outside [0, {cur}]")
+        w = self.cfg.eva_window
+        if w and pos < cur and pos // w != (cur - 1) // w:
+            # Back inside the current window is a length (its later rows
+            # are masked until rewritten, and every chunk past ``pos`` is
+            # pooled again as it closes again); an earlier window's exact
+            # rows have been overwritten.
+            raise WindowGone(
+                f"rewind to {pos} from {cur}: the exact rows of window "
+                f"{pos // w} ({w} rows a window) are gone, only their "
+                "summaries are held; replay the session through prefill")
         self.lengths[s] = pos
 
     # ------------------------------------------------------------------
@@ -1001,6 +1328,113 @@ class BatchedStageExecutor:
             return _run_passes(cfg, params, h, one_pass, k_all, v_all)[:3]
 
         return prefill
+
+    def _build_prefill_window(self):
+        """The prefill program of a family whose older rows are summaries:
+        ONE window's share of a prompt, ``x`` ``[1, T]`` at positions ``win
+        * W ..`` (T a bucket, or W for a whole window). Its queries need
+        their own causal block and the ``(W / C) * win`` summary rows the
+        windows before it left in the slot (`_attend_windowed`), so a
+        prompt of any length runs through the bounded set of shapes
+        `window_shapes` lists, no mask is larger than ``[512, W + R]``,
+        and the cost of a prompt grows as ``T x (W + T / C)``. It writes
+        the window's rows to ``[slot, 0 : T)`` of the exact stacks (rows
+        past the prompt's end are masked until a later token overwrites
+        them) and
+        the summaries of its T / C chunks to their rows (those of a chunk
+        the prompt leaves open are made again when a decode tick closes
+        it, `_append_windowed`; those of the slot's last window have no
+        row: the write is a no-op)."""
+        cfg, spec = self.cfg, self.spec
+        w_rows, c_rows = cfg.eva_window, cfg.eva_chunk
+        per = w_rows // c_rows
+
+        @partial(jax.jit, donate_argnums=engine_donation(3, 4))
+        def prefill_window(params, x, slot, k_all, v_all, win):
+            t = x.shape[1]
+            positions = win * w_rows + jnp.arange(t, dtype=jnp.int32)[None, :]
+            with jax.named_scope("embed"):
+                h = embed_tokens(cfg, params["embed"], x, positions)
+                rope = make_rope(cfg, positions)
+            qpos = positions[:, :, None]
+            with jax.named_scope("attention"):
+                k_sums, v_sums = (jax.lax.dynamic_index_in_dim(
+                    st.sums, slot, 1, keepdims=False) for st in (k_all, v_all))
+
+            def layer(h, xs):
+                lp, k_sum, v_sum = xs               # [R, Hkv, Dh]
+
+                def fresh_window(k, v):
+                    k, v = (k.astype(k_all.exact.dtype),
+                            v.astype(v_all.exact.dtype))
+                    pooled = (() if t < c_rows else _pool_chunks(
+                        cfg, lp, *(a[0, :t // c_rows * c_rows].reshape(
+                            t // c_rows, c_rows, *a.shape[2:])
+                            for a in (k, v))))
+                    return (_WindowedRead(k, k_sum[None]),
+                            _WindowedRead(v, v_sum[None]),
+                            (None, qpos, None), (k[0], v[0], *pooled))
+
+                return _decoder_layer(cfg, lp, h, rope, fresh_window)
+
+            h, (ks, vs, *pooled) = _scan_layers(
+                layer, h, params["layers"], k_sums, v_sums)
+            with jax.named_scope("kv_update"):
+                exact = [jax.lax.dynamic_update_slice(
+                    st.exact, rows[:, None], _origin(st.exact, 0, slot))
+                    for st, rows in ((k_all, ks), (v_all, vs))]
+                sums = [k_all.sums, v_all.sums]
+                if pooled and sums[0].shape[2]:
+                    # ``dynamic_update_slice`` clamps a start past the end:
+                    # there, write back what the clamped slice holds.
+                    n = pooled[0].shape[1]
+                    at = _origin(sums[0], 0, slot, win * per)
+                    fits = win * per + n <= sums[0].shape[2]
+                    sums = [jax.lax.dynamic_update_slice(
+                        st, jnp.where(fits, new[:, None], jax.lax.dynamic_slice(
+                            st, at, (st.shape[0], 1, n) + st.shape[3:])), at)
+                        for st, new in zip(sums, pooled)]
+            return (h, _WindowedStacks(exact[0], sums[0]),
+                    _WindowedStacks(exact[1], sums[1]))
+
+        return prefill_window
+
+    def window_shapes(self) -> List[int]:
+        """Every ``T`` the windowed prefill program runs at: the buckets
+        under a window's rows, and the window."""
+        rows = windowed_rows(self.cfg, self.max_len)[0]
+        return [n for n in PREFILL_BUCKETS if n < rows] + [rows]
+
+    def _prefill_windows(self, session_id: str, x) -> jnp.ndarray:
+        """`_prefill_full` for a family whose older rows are summaries:
+        the prompt's whole windows, then its tail padded to a bucket, each
+        through `_build_prefill_window`'s program in turn (window ``w``
+        reads the summaries windows ``0 .. w - 1`` have just written)."""
+        x = np.asarray(x)
+        t = x.shape[1]
+        if t > self.max_len:
+            raise ValueError(f"prompt {t} exceeds slot max_len {self.max_len}")
+        s = self._alloc(session_id)
+        if self._prefill_jit is None:
+            self._prefill_jit = self._build_prefill_window()
+        rows, shapes = self.cfg.eva_window, self.window_shapes()
+        outs = []
+        try:
+            for win in range(-(-t // rows)):
+                part = x[:, win * rows:(win + 1) * rows]
+                n = part.shape[1]
+                tb = round_to_bucket(n, shapes)
+                h, self.k, self.v = self._prefill_jit(
+                    self.params, np.pad(part, ((0, 0), (0, tb - n))),
+                    np.int32(s), self.k, self.v, np.int32(win))
+                outs.append(h if tb == n else h[:, :n])
+        except Exception:
+            self._recover_slot(session_id, s)
+            raise
+        self.lengths[s] = t
+        self._m_chunks.inc(t // self.cfg.eva_chunk)
+        self._m_written.inc(t)
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
     def _build_prefill_suffix(self):
         """Prefill CONTINUATION for a prefix-cache hit: the suffix enters at
@@ -1197,13 +1631,16 @@ class BatchedStageExecutor:
         self._slot_of.pop(session_id, None)
         self.lengths[s] = 0
         self._free.append(s)
-        if getattr(self.k, "is_deleted", lambda: False)():
+        if any(getattr(x, "is_deleted", lambda: False)()
+               for x in jax.tree.leaves(self.k)):
             self._new_stacks()
             self._slot_of.clear()
             self.lengths[:] = 0
             self._free = list(range(self.slots))
 
     def _prefill_full(self, session_id: str, x) -> jnp.ndarray:
+        if self.cfg.eva_window:
+            return self._prefill_windows(session_id, x)
         x = jnp.asarray(x)
         t = x.shape[1]
         if t > self.max_len:
@@ -1226,6 +1663,7 @@ class BatchedStageExecutor:
             self._recover_slot(session_id, s)
             raise
         self.lengths[s] = t
+        self._m_written.inc(t)
         return h[:, :t]
 
     # ------------------------------------------------------------------
@@ -1272,6 +1710,13 @@ class BatchedStageExecutor:
             return {}
         sids = list(inputs)
         t = int(np.asarray(inputs[sids[0]]).shape[1])
+        if t > 1 and self.cfg.eva_window:
+            raise NotImplementedError(
+                f"a step of {t} rows a slot on a family whose older rows "
+                "are summaries (a block of rows may cross a window's edge "
+                "and overwrite the exact rows its own first rows read): "
+                "speculative verify is not served, and a journal replay "
+                "rebuilds the slot through prefill")
         rows = []
         for sid in sids:
             if int(np.asarray(inputs[sid]).shape[1]) != t:
@@ -1310,6 +1755,7 @@ class BatchedStageExecutor:
         self._count_attn_rows(self.lengths[None], active[None], t)
         for s in rows:
             self.lengths[s] += t
+        self._count_rows_held(rows)
         self.decode_steps += 1
         return {sid: h[s:s + 1] for sid, s in zip(sids, rows)}
 
@@ -1560,6 +2006,7 @@ class BatchedStageExecutor:
                         "stop": self._BURST_STOPS[int(stop_np[s])],
                         "cache_len": int(len_np[s])}
             self.lengths[s] = int(len_np[s])
+        self._count_rows_held(held)
         self.burst_tokens += total
         self._m_burst_toks.inc(total)
         return out
@@ -1830,6 +2277,12 @@ class BatchingStageAdapter:
         d = self.cfg.hidden_size
         x = (np.zeros((1, 4), np.int32) if first
              else np.zeros((1, 4, d), np.float32))
+        if self.cfg.eva_window:
+            # A windowed family's prompts run through a bounded set of
+            # shapes (`window_shapes`): build them all now, so that no
+            # prompt length compiles a prefill program while serving.
+            for n in self.inner.window_shapes():
+                self.inner.prefill("__warmup__", np.zeros((1, n), np.int32))
         self.inner.prefill("__warmup__", x)
         widths = [1] + list(range(2, speculative_k + 2))
         for t in widths:
@@ -1882,6 +2335,14 @@ class BatchingStageAdapter:
                 "batched peer serves its full span only")
         if req.is_prefill:
             return self._prefill(req)
+        if self.cfg.eva_window and req.seq_len != 1:
+            _ev.emit("task_rejected", session_id=req.session_id,
+                     pool="batched", reason="rows across a window's edge")
+            raise StageExecutionError(
+                f"a step of {req.seq_len} rows on a family whose older rows "
+                "are summaries (it may cross a window's edge): speculative "
+                "verify is not served, and a journal replay rebuilds the "
+                "slot through prefill")
         if req.burst_len:
             if not (self.spec.is_first and self.spec.is_last):
                 _ev.emit("task_rejected", session_id=req.session_id,

@@ -148,6 +148,13 @@ TAXONOMY: Dict[str, ErrorPolicy] = {p.name: p for p in (
         doc="KV arena could not satisfy an allocation within its timeout; "
             "a replacement peer with free cache is the right response."),
     ErrorPolicy(
+        name="WindowGone", policy=RETRYABLE, blame=BLAME_NONE,
+        wire=None, scope="server",
+        doc="Batched engine, a family whose older rows are summaries: a "
+            "rewind across a window's edge, whose exact rows are gone. "
+            "Converts to a kind=stage frame — the client's journal replay "
+            "rebuilds the slot through prefill; no peer is at fault."),
+    ErrorPolicy(
         name="AdmissionDenied", policy=PERMANENT, blame=BLAME_NONE,
         wire=None, scope="server",
         doc="A step would exceed the session's DECLARED max_length — the "

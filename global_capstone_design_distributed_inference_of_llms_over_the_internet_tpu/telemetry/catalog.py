@@ -272,10 +272,38 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "ticks x slots x max_session_len. server_attn_rows_read_"
                  "total over this is the share of the cache a tick's "
                  "attention streams.", (), None),
+    "server_attn_summary_rows_read_total": (
+        COUNTER, "Summary rows of ONE cache layer those ticks read, where "
+                 "the family's older rows are summaries: per tick, the "
+                 "blocks of the summary stack up to the most earlier "
+                 "windows of an active slot (runtime.batching."
+                 "windowed_blocks) x the block's rows x slots. "
+                 "server_attn_rows_read_total keeps the exact rows.",
+        (), None),
+    "server_kv_chunks_summarised_total": (
+        COUNTER, "Chunks of positions whose exact K/V rows were pooled into "
+                 "a summary row: by a prefill (its whole chunks) and by the "
+                 "decode tick that closes one; counted on the host from "
+                 "the lengths.", (), None),
+    "server_kv_positions_written_total": (
+        COUNTER, "Positions whose K/V rows the batched engine wrote: a "
+                 "prefill's prompt rows and a row for every active slot of "
+                 "every tick.", (), None),
+    "server_state_rows_held_total": (
+        COUNTER, "Per decode round, over the slots in it: the cache rows "
+                 "ONE layer holds for those sessions (a row a position; "
+                 "where older rows are summaries, the current window's "
+                 "exact rows in use + the summary rows in use).", (), None),
+    "server_positions_held_total": (
+        COUNTER, "Per decode round, over the slots in it: the positions "
+                 "those sessions have sent. server_state_rows_held_total "
+                 "over this is what the sessions hold against one row a "
+                 "position.", (), None),
     "server_kv_stack_bytes": (
         GAUGE, "Bytes of the batched engine's resident K and V cache "
-               "stacks (both together; a looped stack holds rows for "
-               "every pass of every layer).", (), None),
+               "stacks (all of them together: a looped stack holds rows "
+               "for every pass of every layer; a family whose older rows "
+               "are summaries two stacks each for K and V).", (), None),
     "server_burst_ticks": (
         HISTOGRAM, "Configured tick count per burst dispatch (the N of "
                    "each lax.scan program).", (), FILL_BUCKETS),

@@ -204,7 +204,9 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "head_dim minor and the heads are held side by side in one dim "
         "(runtime.batching.kv_fold_width), else null; "
         "logical_bytes_a_stack; resident_bytes_a_stack = as laid out, "
-        "with the padding of its tiles)."),
+        "with the padding of its tiles; for a family whose older rows are "
+        "summaries also rows = [exact rows, summary rows] a slot and "
+        "summary_shape, shape then being the window stack's)."),
     "burst_fallback": (
         "client", WARN,
         "A burst-mode session fell back to per-step decode because no "

@@ -65,7 +65,8 @@ class SlotsOnly(batching.BatchedStageExecutor):
     def __init__(self, *, burst, slots=8, round_s=ROUND_S, rider_rows=0):
         self.spec = types.SimpleNamespace(is_first=True, is_last=burst,
                                           start=0, end=1)
-        self.cfg = types.SimpleNamespace(hidden_size=HID, loop_steps=1)
+        self.cfg = types.SimpleNamespace(hidden_size=HID, loop_steps=1,
+                                         eva_window=0)
         self.slots, self.max_len = slots, 1 << 16
         self._slot_of, self._free = {}, list(range(slots))
         self.lengths = np.zeros((slots,), np.int64)

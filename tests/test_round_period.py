@@ -198,13 +198,54 @@ def test_a_rider_runs_no_program_and_is_not_a_prefill_in_the_hold():
 PARTS = {"build": 0.004, "dispatch": 0.002, "device": 0.25, "readback": 0.01}
 
 
+class SkewedClock:
+    """``time`` as `batching` reads it, with a clock the test moves: an
+    engine's round ADDS its length to ``monotonic()`` instead of sleeping
+    it, so a round's wall time is the length the test set plus the
+    microseconds of Python around it, whatever else the machine runs
+    (under six test workers a 0.6 s sleep overslept its 0.1 s of room)."""
+
+    def __init__(self):
+        self.skew = 0.0
+
+    def monotonic(self):
+        return time.monotonic() + self.skew
+
+    sleep = staticmethod(time.sleep)
+
+
+class Skips(SlotsOnly):
+    """`SlotsOnly` whose rounds move the test's clock and do not sleep."""
+
+    clock = None
+
+    def _round(self, run, *args, **kw):
+        length, self.round_s = self.round_s, 0.0
+        try:
+            return run(*args, **kw)
+        finally:
+            self.round_s = length
+            self.clock.skew += length
+
+    def decode_batch(self, hidden):
+        return self._round(super().decode_batch, hidden)
+
+    def decode_burst(self, entries, n_ticks, rider=None):
+        return self._round(super().decode_burst, entries, n_ticks, rider)
+
+
 @pytest.mark.parametrize("kind, profiled", [
     ("burst", True), ("burst", False), ("step", False)])
-def test_a_stalled_round_says_what_it_was_made_of(kind, profiled):
+def test_a_stalled_round_says_what_it_was_made_of(monkeypatch, kind,
+                                                  profiled):
     """A round over 4 x the last of its width: one count, its seconds by
     part (the burst's four phases where the profiler measured them, the
-    rest ``other``; everything ``other`` without them), ONE event."""
-    ad, eng, reg = make(kind)
+    rest ``other``; everything ``other`` without them), ONE event. The
+    rounds' lengths are on a clock of the test's own (`SkewedClock`)."""
+    clock = SkewedClock()
+    monkeypatch.setattr(batching, "time", clock)
+    ad, eng, reg = make(kind, engine=Skips)
+    eng.clock = clock
     seat(ad, "a", "b")
     for _ in range(2):
         ask(ad, "a", kind)                        # rounds of 0.12 s
