@@ -94,13 +94,24 @@ from .kv_cache import round_to_bucket
 Params = Dict[str, Any]
 
 
-def _burst_entry(rq) -> dict:
+def _host_ids(x, reads) -> np.ndarray:
+    """A request's token ids on the host, flat. Off a wire frame they are
+    there already (`net._stage_input` keeps an integer tensor as decoded);
+    a caller that hands a device array pays a read per request, under the
+    round's lock, and ``reads`` (the round's count of transfers down) says
+    so."""
+    if isinstance(x, jax.Array):
+        reads.inc()
+    return np.asarray(x).reshape(-1)
+
+
+def _burst_entry(rq, reads) -> dict:
     """A StageRequest's burst spec in the engine's stateless per-burst form
     (everything the wire ships every step, so failover needs no server-side
     sampler state — the module-docstring contract)."""
     sp = rq.sampling
     return {
-        "token": int(np.asarray(rq.hidden).reshape(-1)[0]),
+        "token": int(_host_ids(rq.hidden, reads)[0]),
         "seed": int(rq.step_seed),
         "budget": int(rq.burst_budget),
         "eos": rq.eos_token_id,
@@ -118,13 +129,13 @@ def _gc_counts() -> List[int]:
     return [g["collections"] for g in gc.get_stats()]
 
 
-def _rider_entry(rq) -> dict:
+def _rider_entry(rq, reads) -> dict:
     """A prefill StageRequest as the engine's rider (`decode_burst`): the
     prompt and what `executor._sample_rows` samples its first token with."""
     sp = rq.sampling
     return {
         "session_id": rq.session_id,
-        "ids": np.asarray(rq.hidden).reshape(-1),
+        "ids": _host_ids(rq.hidden, reads),
         "seed": int(rq.step_seed),
         "generated": rq.generated_tokens,
         "temperature": sp.temperature,
@@ -171,6 +182,31 @@ STALL_PARTS = ("build", "dispatch", "device", "readback")
 # on device so a burst truncates exactly where the sequential host loop
 # would have stopped. Keep the two in lockstep.
 BURST_REPEAT_STOP = 5
+
+# What a burst round sends across the host-device boundary, and how it is
+# packed: its per-slot values go up as ONE int32 array ``[rows, S]``, a row
+# a name below and then the `RECENT_WINDOW` columns of the recent-token
+# window, and ONE float32 array ``[3, S]`` (`_burst_prep`); a rider lane's
+# request as ONE int32 vector, these scalars, then its recent window and its
+# prompt ids, its three float knobs riding as their bits (`_rider_args`);
+# the results the host reads come back as ONE int32 vector
+# (`_burst_collect`). The burst program slices them apart as its first
+# operations and packs the results as its last (`_build_burst`): every
+# array of its own is an allocation, a linearisation and a transfer with
+# the chip idle, 0.3-0.5 ms each (PERF.md section 6, PR 49).
+BURST_INTS = ("tok", "lengths", "alive", "seeds", "nvalid", "run", "left",
+              "eos", "top_k")
+BURST_FLOATS = ("temp", "top_p", "rp")
+RIDER_INTS = ("len", "slot", "seed", "nvalid", "top_k")
+
+
+def _rider_fields(window: int):
+    """The rider's vector by part, as slices: its `RIDER_INTS`, its recent
+    window, the bits of its `BURST_FLOATS`, its prompt ids (the rest)."""
+    a = len(RIDER_INTS)
+    b = a + window
+    c = b + len(BURST_FLOATS)
+    return slice(a), slice(a, b), slice(b, c), slice(c, None)
 
 
 @_catalog
@@ -1115,6 +1151,7 @@ class BatchedStageExecutor:
         self._m_burst_ticks = _tm.get("server_burst_ticks")
         self._m_burst_disp = _tm.get("server_burst_dispatches_total")
         self._m_burst_toks = _tm.get("server_burst_tokens_total")
+        self._m_transfers = _tm.get("server_burst_transfers_total")
         self._m_sampler = _tm.get("server_sampler_rounds_total")
         self._m_exit_steps = _tm.get("server_loop_exit_steps_total")
         self._m_rows_read = _tm.get("server_attn_rows_read_total")
@@ -1641,19 +1678,23 @@ class BatchedStageExecutor:
     def _prefill_full(self, session_id: str, x) -> jnp.ndarray:
         if self.cfg.eva_window:
             return self._prefill_windows(session_id, x)
-        x = jnp.asarray(x)
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
         t = x.shape[1]
         if t > self.max_len:
             raise ValueError(f"prompt {t} exceeds slot max_len {self.max_len}")
         s = self._alloc(session_id)
         # Bucket-pad the prompt so an epoch of varied lengths compiles a
         # handful of shapes; beyond the bucket table, exact length (one
-        # compile) beats failing.
+        # compile) beats failing. Ids off the wire are a host array: padded
+        # there, they go up once, at the bucket's shape; hidden states are
+        # on the device already and are padded where they are.
         tb = (t if t > PREFILL_BUCKETS[-1]
               else min(round_to_bucket(t, PREFILL_BUCKETS), self.max_len))
         if tb != t:
             pad = ((0, 0), (0, tb - t)) + (((0, 0),) if x.ndim == 3 else ())
-            x = jnp.pad(x, pad)
+            x = (np if isinstance(x, np.ndarray) else jnp).pad(x, pad)
+        x = jnp.asarray(x)
         if self._prefill_jit is None:
             self._prefill_jit = self._build_prefill()
         try:
@@ -1781,31 +1822,45 @@ class BatchedStageExecutor:
         sequential client would have accepted.
 
         A looped stack's program (``cfg.loop_steps > 1``) carries one more
-        value through the ticks and returns it after the twelve: the passes
-        taken by the tokens the burst emitted, summed on the device
-        (``server_loop_exit_steps_total``). With a rider lane
-        (``self.rider_rows``) the program takes one more ARGUMENT, the
-        rider (`_rider_args`), every tick carries the lane's rows through
-        the layers (`_decode_span`), the head and the sampler read one more
-        row (the chunk's row at which the prompt ends), and the rider's
-        first token is the last result. A lane without a rider does the
-        same work on rows that write nothing. A program whose stack runs
-        once has the arguments and results it always had."""
+        value through the ticks: the passes taken by the tokens the burst
+        emitted, summed on the device (``server_loop_exit_steps_total``).
+        With a rider lane (``self.rider_rows``) the program takes one more
+        ARGUMENT, the rider (`_rider_args`), every tick carries the lane's
+        rows through the layers (`_decode_span`), the head and the sampler
+        read one more row (the chunk's row at which the prompt ends), and
+        the rider's first token is carried too. A lane without a rider
+        does the same work on rows that write nothing.
+
+        Arguments and results are PACKED (`BURST_INTS`): the per-slot
+        values arrive as one int32 and one float32 array, the rider as one
+        int32 vector, and what the host reads leaves as one int32 vector
+        beside the stacks: ``toks [N, S]`` flat, ``stop [S]``, the new
+        lengths ``[S]``, then the pass count (looped) and the rider's
+        token (lane). The ticks compute on the values and dtypes the
+        separate arrays held."""
         cfg, spec = self.cfg, self.spec
         looped = cfg.loop_steps > 1
         lane = self.rider_rows
         S = self.slots
         N = n_ticks
         from ..models.transformer import lm_head
-        from ..ops.sampling import push_recent, sample_tokens
+        from ..ops.sampling import RECENT_WINDOW, push_recent, sample_tokens
 
-        @partial(jax.jit, donate_argnums=engine_donation(14, 15))
-        def burst_tick(params, tok, lengths, alive, seeds, recent, nvalid,
-                       run, left, eos_id, temp, top_p, top_k, rp, k_all,
-                       v_all, *rider):
-            len0 = lengths
+        @partial(jax.jit, donate_argnums=engine_donation(3, 4))
+        def burst_tick(params, ints, floats, k_all, v_all, *rider):
+            (tok, lengths, alive, seeds, nvalid, run, left, eos_id,
+             top_k) = ints[:len(BURST_INTS)]
+            alive = alive != 0
+            recent = ints[len(BURST_INTS):].T                  # [S, window]
+            temp, top_p, rp = floats
             if lane:
-                (rider,) = rider
+                (packed,) = rider
+                ints_, recent_, knobs_, ids_ = _rider_fields(RECENT_WINDOW)
+                rider = dict(zip(RIDER_INTS, packed[ints_]))
+                rider["recent"] = packed[recent_]
+                rider.update(zip(BURST_FLOATS, jax.lax.bitcast_convert_type(
+                    packed[knobs_], jnp.float32)))
+                rider["ids"] = packed[ids_].reshape(N, lane)
                 last = rider["len"] - 1        # the prompt's last row
                 r_knobs = [jnp.concatenate([a, rider[name][None]])
                            for a, name in ((temp, "temp"), (top_p, "top_p"),
@@ -1890,13 +1945,10 @@ class BatchedStageExecutor:
                  k_all, v_all, *([jnp.int32(0)] if looped else []),
                  *([jnp.int32(-1)] if lane else [])),
                 jnp.arange(N, dtype=jnp.int32))
-            (tok, lengths, alive, recent, nvalid, run, left, stop,
-             k_all, v_all, *more) = carry
-            # Seed base for a CONTINUATION burst: one key was consumed per
-            # emitted token (emitted ticks are a prefix of the scan).
-            seeds = seeds + (lengths - len0)
-            return (toks, stop, tok, lengths, alive, seeds, recent, nvalid,
-                    run, left, k_all, v_all, *more)
+            (_, lengths, _, _, _, _, _, stop, k_all, v_all, *more) = carry
+            return (jnp.concatenate([toks.reshape(-1), stop, lengths,
+                                     *(m[None] for m in more)]),
+                    k_all, v_all)
 
         return burst_tick
 
@@ -1907,7 +1959,10 @@ class BatchedStageExecutor:
         return fn
 
     def _burst_prep(self, entries: Dict[str, dict], n_ticks: int):
-        """Pack per-session burst specs into the jit's [S]-shaped args.
+        """Pack per-session burst specs into the burst program's two
+        packed arguments (`BURST_INTS`), as host arrays: they go up where
+        the program is called (`decode_burst`), and nothing writes to them
+        after (a backend may alias a host buffer it is handed).
 
         entries[sid]: {token, seed, budget, eos (-1 = none), generated,
         temperature, top_p, top_k, repetition_penalty} — the stateless
@@ -1922,18 +1977,18 @@ class BatchedStageExecutor:
         if n_ticks < 1:
             raise ValueError(f"burst of {n_ticks} ticks")
         S = self.slots
-        tok0 = np.zeros((S,), np.int32)
-        seeds = np.zeros((S,), np.int32)
-        recent = np.zeros((S, RECENT_WINDOW), np.int32)
-        nvalid = np.zeros((S,), np.int32)
-        run0 = np.zeros((S,), np.int32)
-        left = np.zeros((S,), np.int32)
-        eos = np.full((S,), -1, np.int32)
-        temp = np.zeros((S,), np.float32)
-        top_p = np.ones((S,), np.float32)
-        top_k = np.zeros((S,), np.int32)
-        rp = np.ones((S,), np.float32)
-        alive = np.zeros((S,), bool)
+        # The round's two uploads, filled through views: a name's row of
+        # the int32 array, the recent window's columns under them, the
+        # three rows of the float32 array (`BURST_INTS`).
+        ints = np.zeros((len(BURST_INTS) + RECENT_WINDOW, S), np.int32)
+        (tok0, lengths, alive, seeds, nvalid, run0, left, eos,
+         top_k) = ints[:len(BURST_INTS)]
+        recent = ints[len(BURST_INTS):].T                      # [S, window]
+        floats = np.ones((len(BURST_FLOATS), S), np.float32)
+        temp, top_p, rp = floats
+        lengths[:] = self.lengths    # a copy: the host bumps its own below
+        eos[:] = -1
+        temp[:] = 0.0
         rows: Dict[str, int] = {}
         for sid, e in entries.items():
             s = self._slot_of.get(sid)
@@ -1965,29 +2020,26 @@ class BatchedStageExecutor:
             top_p[s] = float(e["top_p"])
             top_k[s] = int(e["top_k"])
             rp[s] = float(e["repetition_penalty"])
-            alive[s] = True
+            alive[s] = 1
             rows[sid] = s
         self._m_sampler.labels(stages=sampler_stages(
             temp, top_p, top_k, rp, self.cfg.vocab_size)).inc()
-        # lengths copied for the same reason as in decode_batch.
-        args = (jnp.asarray(tok0), jnp.asarray(self.lengths.copy()),
-                jnp.asarray(alive), jnp.asarray(seeds), jnp.asarray(recent),
-                jnp.asarray(nvalid), jnp.asarray(run0), jnp.asarray(left),
-                jnp.asarray(eos), jnp.asarray(temp), jnp.asarray(top_p),
-                jnp.asarray(top_k), jnp.asarray(rp))
-        return rows, args
+        return rows, (ints, floats)
 
     _BURST_STOPS = {0: None, 1: "eos", 2: "repeat"}
 
-    def _burst_collect(self, rows: Dict[str, int], toks, stop,
-                       lengths_new, passes=None) -> Dict[str, dict]:
-        """Read one burst's results back (the only host sync per burst).
-        ``passes``: a looped stack's count of passes (`_build_burst`)."""
-        if passes is not None:
-            self._m_exit_steps.inc(int(passes))
-        toks_np = np.asarray(toks)            # [N, S]
-        stop_np = np.asarray(stop)
-        len_np = np.asarray(lengths_new)
+    def _burst_collect(self, rows: Dict[str, int], packed, n_ticks: int):
+        """Read one burst's packed results back (`_build_burst`): the only
+        host sync per burst, and ONE read. Returns the sessions' results
+        and the rider's first token (None: an engine without a lane)."""
+        S = self.slots
+        flat = np.asarray(packed)
+        self._m_transfers.labels(dir="down").inc()
+        toks_np = flat[:n_ticks * S].reshape(n_ticks, S)
+        stop_np, len_np = flat[n_ticks * S:(n_ticks + 2) * S].reshape(2, S)
+        tail = flat[(n_ticks + 2) * S:]     # passes (looped), token (lane)
+        if self.cfg.loop_steps > 1:
+            self._m_exit_steps.inc(int(tail[0]))
         # Tick i began with every slot i rows on, those still emitting
         # active: a slot's emitted ticks are a prefix of the burst's.
         grown = np.zeros((self.slots,), np.int64)     # tokens a slot emitted
@@ -2009,7 +2061,7 @@ class BatchedStageExecutor:
         self._count_rows_held(held)
         self.burst_tokens += total
         self._m_burst_toks.inc(total)
-        return out
+        return out, (int(tail[-1]) if self.rider_rows else None)
 
     def can_ride(self, t: int, n_ticks: int) -> bool:
         """Whether a prompt of ``t`` rows fits the rider lane of ONE burst
@@ -2019,37 +2071,33 @@ class BatchedStageExecutor:
         return (bool(c) and 0 < t <= n_ticks * c
                 and -(-t // c) * c <= self.max_len)
 
-    def _rider_args(self, rider: Optional[dict], n_ticks: int) -> dict:
-        """The burst program's rider argument: the joining request's slot,
-        prompt ids as ``n_ticks`` chunks of `rider_rows`, their count, and
-        what its first token is sampled with (the key and the knobs
-        `executor._sample_rows` gives a prefill's token). None: a lane
-        that carries nothing (``len`` 0: no row of it is written)."""
+    def _rider_args(self, rider: Optional[dict], n_ticks: int) -> np.ndarray:
+        """The burst program's rider argument, ONE int32 host vector
+        (`RIDER_INTS`): the joining request's prompt length, slot, and what
+        its first token is sampled with (the key and the knobs
+        `executor._sample_rows` gives a prefill's token: seed, the recent
+        window and its count, ``top_k``, and the bits of temperature,
+        ``top_p`` and the repetition penalty), then its prompt ids as
+        ``n_ticks`` chunks of `rider_rows`. None: a lane that carries
+        nothing (``len`` 0: no row of it is written). No scalar goes up on
+        its own (each would run an eager convert program on the device)."""
         from ..ops.sampling import RECENT_WINDOW
 
-        c = self.rider_rows
-        ids = np.zeros((n_ticks * c,), np.int32)
-        recent = np.zeros((RECENT_WINDOW,), np.int32)
         r = rider or {"ids": (), "slot": 0, "seed": 0, "generated": (),
                       "temperature": 0.0, "top_p": 1.0, "top_k": 0,
                       "repetition_penalty": 1.0}
-        ids[:len(r["ids"])] = r["ids"]
         win = tuple(int(t) for t in r["generated"])[-RECENT_WINDOW:]
-        recent[:len(win)] = win
-        # Each of the eight scalars still costs an eager convert program on
-        # the device (``jnp.asarray`` of a numpy SCALAR, as ``jnp.int32(n)``;
-        # a 0-d array would be a transfer): PERF.md section 7.
-        return {name: jnp.asarray(value) for name, value in (
-            ("ids", ids.reshape(n_ticks, c)),
-            ("len", np.int32(len(r["ids"]))),
-            ("slot", np.int32(r["slot"])),
-            ("seed", np.int32(r["seed"])),
-            ("recent", recent),
-            ("nvalid", np.int32(len(win))),
-            ("temp", np.float32(r["temperature"])),
-            ("top_p", np.float32(r["top_p"])),
-            ("top_k", np.int32(r["top_k"])),
-            ("rp", np.float32(r["repetition_penalty"])))}
+        ints, recent, knobs, ids = _rider_fields(RECENT_WINDOW)
+        packed = np.zeros((knobs.stop + n_ticks * self.rider_rows,),
+                          np.int32)
+        packed[ints] = (len(r["ids"]), r["slot"], r["seed"], len(win),
+                        r["top_k"])
+        packed[recent][:len(win)] = win
+        packed[knobs] = np.asarray(
+            [r["temperature"], r["top_p"], r["repetition_penalty"]],
+            np.float32).view(np.int32)
+        packed[ids][:len(r["ids"])] = r["ids"]
+        return packed
 
     def decode_burst(self, entries: Dict[str, dict], n_ticks: int,
                      rider: Optional[dict] = None) -> Dict[str, dict]:
@@ -2093,21 +2141,18 @@ class BatchedStageExecutor:
             if rider is not None:
                 self._recover_slot(rider["session_id"], rider["slot"])
             raise
-        (toks, stop, _tok, lengths_new, _alive, _seeds, _recent, _nvalid,
-         _run, _left, self.k, self.v, *more) = out
+        packed, self.k, self.v = out
+        self._m_transfers.labels(dir="up").inc(len(args) + len(extra))
         self.decode_steps += 1
         self.burst_dispatches += 1
         self._m_burst_disp.inc()
         self._m_burst_ticks.observe(n_ticks)
         with prof.phase("readback", sessions=n) as read:
-            res = self._burst_collect(
-                rows, toks, stop, lengths_new,
-                more[0] if self.cfg.loop_steps > 1 else None)
+            res, token = self._burst_collect(rows, packed, n_ticks)
             if rider is not None:
                 t = len(rider["ids"])
                 self.lengths[rider["slot"]] = t
-                res[rider["session_id"]] = {"token": int(more[-1]),
-                                            "cache_len": t}
+                res[rider["session_id"]] = {"token": token, "cache_len": t}
         # What this burst's wall time was made of, where the profiler has
         # just measured it (``device``: enqueue returned -> results ready).
         self.burst_parts = dict(zip(STALL_PARTS, (
@@ -2242,6 +2287,9 @@ class BatchingStageAdapter:
         self._m_queue_wait = _tm.get("server_queue_wait_seconds")
         self._m_fill = _tm.get("server_batch_fill_sessions")
         self._m_held = _tm.get("server_batch_slots_held")
+        # a burst request whose ids came as a device array (`_host_ids`)
+        self._m_ids_read = _tm.get("server_burst_transfers_total").labels(
+            dir="down")
         self._m_round = _tm.get("server_decode_round_seconds")
         self._m_closed = _tm.get("server_round_closed_total")
         self._m_rejoin = _tm.get("server_round_rejoin_seconds")
@@ -2842,7 +2890,8 @@ class BatchingStageAdapter:
                     if r.rider is not None:
                         reason = self._validate_rider(r, n)
                         if reason is None:
-                            riding = _rider_entry(r.rider)
+                            riding = _rider_entry(r.rider,
+                                                  self._m_ids_read)
                         else:
                             r.bad[r.rider.session_id] = reason
                     if good or riding:
@@ -2851,7 +2900,7 @@ class BatchingStageAdapter:
                             self._m_fill.observe(len(good))
                             self._m_held.observe(len(self.inner._slot_of))
                         r.outs = self.inner.decode_burst(
-                            {s_id: _burst_entry(rq)
+                            {s_id: _burst_entry(rq, self._m_ids_read)
                              for s_id, rq in good.items()}, n, rider=riding)
                         r.lengths = {
                             s_id: int(

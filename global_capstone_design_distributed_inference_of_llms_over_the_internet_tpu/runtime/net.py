@@ -328,6 +328,20 @@ def _request_header(req: StageRequest, tensor_meta: dict,
     return hdr
 
 
+def _stage_input(arr: np.ndarray):
+    """A decoded frame's tensor as a stage takes it. Token ids (an INTEGER
+    tensor: the first stage's input, a burst request's one token, a rider's
+    prompt) stay the host array the frame decoded to: the engines read ids
+    on the host (`batching._burst_entry` packs them into the round's one
+    upload; a prefill uploads its prompt once, padded), so an upload here
+    would be read straight back under the adapter's lock with the chip
+    idle. Hidden states entering a later stage (a FLOAT tensor) go up
+    here, off the lock. The rule is the tensor's dtype, nothing else."""
+    if np.issubdtype(arr.dtype, np.integer):
+        return arr
+    return jnp.asarray(arr)
+
+
 def _header_to_request(h: dict, payload: bytes) -> StageRequest:
     pr = None
     if h.get("prompts_tensor") is not None:
@@ -337,7 +351,7 @@ def _header_to_request(h: dict, payload: bytes) -> StageRequest:
         arr = _decode_tensor(h["tensor"], payload)
     return StageRequest(
         session_id=h["session_id"],
-        hidden=jnp.asarray(arr),
+        hidden=_stage_input(arr),
         seq_len=h["seq_len"],
         cur_len=h["cur_len"],
         is_prefill=h["is_prefill"],
@@ -1202,7 +1216,8 @@ class TcpStageServer(_FramedTcpServer):
         with _get_profiler().span("request", session=sid):
             req = StageRequest(
                 session_id=sid,
-                hidden=jnp.asarray(_decode_tensor(header["tensor"], payload)),
+                hidden=_stage_input(
+                    _decode_tensor(header["tensor"], payload)),
                 seq_len=header["seq_len"],
                 cur_len=header["cur_len"],
                 is_prefill=header.get("is_prefill", False),
@@ -2055,7 +2070,8 @@ class TcpTransport(Transport):
         if verb == "hidden":
             return StageResponse(
                 session_id=header["session_id"],
-                hidden=jnp.asarray(_decode_tensor(header["tensor"], payload)),
+                hidden=_stage_input(
+                    _decode_tensor(header["tensor"], payload)),
                 cache_len=header["cache_len"],
                 span=span,
             )

@@ -255,6 +255,14 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "server_burst_dispatches_total by this for "
                  "dispatches-per-token (the amortization the burst engine "
                  "exists to win).", (), None),
+    "server_burst_transfers_total": (
+        COUNTER, "Host-device transfers a burst round issued, per direction "
+                 "(up|down): the burst program's packed arguments going up "
+                 "(two arrays, three with a rider lane), its one packed "
+                 "result read back, and a read for every request whose "
+                 "token ids arrived as a device array (a wire frame's ids "
+                 "stay on the host). Over server_burst_dispatches_total: "
+                 "2-3 up and 1 down a round.", ("dir",), None),
     "server_loop_exit_steps_total": (
         COUNTER, "Looped stacks only: passes taken by the tokens burst "
                  "dispatches emitted (the pass whose state went to the "

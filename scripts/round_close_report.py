@@ -18,9 +18,10 @@ the period of a round by its parts, as three identities whose two sides
 are measured apart (``identities``: period = exec + back + hold; rejoin =
 reply + away + request, away being the client's turnaround and the wire;
 exec = host phases + launch lag + ticks + rest; the last needs
-``trace_summary.json``, which a traced run leaves); and what the program
+``trace_summary.json``, which a traced run leaves); what the program
 recorded of its stalls (rounds over 4 x their predecessor, seconds by
-part). One JSON line a run; reads files only."""
+part); and the host-device transfers a burst round issued, up and down
+(``transfers_per_round``). One JSON line a run; reads files only."""
 
 import json
 import os
@@ -151,6 +152,19 @@ def stalls_report(ctx: dict) -> dict:
                 for p in parts}}
 
 
+def transfers_report(ctx: dict) -> dict:
+    """Host-device transfers the burst rounds of the window issued, per
+    direction, over the burst programs dispatched: 2 up (3 on an engine
+    with a rider lane) and 1 down a round; a request whose ids arrived on
+    the device adds a read (`server_burst_transfers_total`)."""
+    rounds = readers.counter_delta(ctx, "server_burst_dispatches_total")
+    moved = {d: readers.counter_delta(
+                 ctx, f'server_burst_transfers_total{{dir="{d}"}}')
+             for d in ("up", "down")}
+    return {d: None if n is None or not rounds else n / rounds
+            for d, n in moved.items()}
+
+
 def rounds_report(run_dir: str, man: Manifest, gap_p50_ms=None) -> dict:
     ctx = {"counters_before": load_counters(
                os.path.join(run_dir, "metrics_before.jsonl")),
@@ -178,6 +192,7 @@ def rounds_report(run_dir: str, man: Manifest, gap_p50_ms=None) -> dict:
     out["hold_s"] = buckets(ctx, "server_round_hold_seconds")
     out["identities"] = identities(ctx, man, gap_p50_ms)
     out["stalls_recorded"] = stalls_report(ctx)
+    out["transfers_per_round"] = transfers_report(ctx)
     return out
 
 

@@ -26,11 +26,39 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     slice_stage_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
+    BURST_FLOATS,
+    BURST_INTS,
     BatchedStageExecutor,
     SlotFull,
 )
 
 from test_runtime_pipeline import kernel_cfg, tiny_cfg
+
+def transfer_counts(eng, ad=None):
+    """The round path's transfer and dispatch counters on a registry that
+    counts (the process's own is off in tests), for an engine and, where
+    given, its adapter: a function that reads (up, down, dispatches)."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry import (
+        catalog,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry.metrics import (
+        MetricsRegistry,
+    )
+
+    reg = MetricsRegistry(enabled=True)
+    moved = eng._m_transfers = catalog.get("server_burst_transfers_total",
+                                           reg)
+    rounds = eng._m_burst_disp = catalog.get("server_burst_dispatches_total",
+                                             reg)
+    if ad is not None:
+        ad._m_ids_read = moved.labels(dir="down")
+
+    def read():
+        by = {dict(c.labels)["dir"]: int(c.value) for c in moved.children()}
+        return by.get("up", 0), by.get("down", 0), int(rounds.value)
+
+    return read
+
 
 # Quarantine-with-teeth (tests/conftest.py pytest_runtest_protocol): the
 # DETERMINISTIC single-threaded token-parity tests below carry
@@ -915,9 +943,8 @@ def test_int8_programs_hand_the_kernel_the_whole_stack(monkeypatch, program):
     on = jnp.ones((S,), bool)
     fn, args = {
         "burst_tick": (ex._build_burst(2), (
-            ex.params, i32(S), i32(S), on, i32(S), i32(S, RECENT_WINDOW),
-            i32(S), i32(S), i32(S) + 2, i32(S) - 1, f32(S), f32(S), i32(S),
-            f32(S), ex.k, ex.v)),
+            ex.params, i32(len(BURST_INTS) + RECENT_WINDOW, S),
+            f32(len(BURST_FLOATS), S), ex.k, ex.v)),
         "decode_step": (ex._build_decode(1), (
             ex.params, i32(S, 1), i32(S), on, ex.k, ex.v)),
         "prefill": (ex._build_prefill(), (
@@ -1223,9 +1250,8 @@ def test_tick_writes_rows_and_never_a_slab(program, tree):
     if program == "burst_tick":
         T = 1
         fn, args = ex._build_burst(2), (
-            ex.params, i32(S), i32(S), on, i32(S), i32(S, RECENT_WINDOW),
-            i32(S), i32(S), i32(S) + 2, i32(S) - 1, f32(S), f32(S), i32(S),
-            f32(S), ex.k, ex.v)
+            ex.params, i32(len(BURST_INTS) + RECENT_WINDOW, S),
+            f32(len(BURST_FLOATS), S), ex.k, ex.v)
     else:
         T = int(program[-1])
         fn, args = ex._build_decode(T), (

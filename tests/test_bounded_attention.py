@@ -344,9 +344,8 @@ def test_the_program_bounds_its_read_inside_the_layer_scan(
     if program == "burst_tick":
         depth = 2 + (family == "looped")
         fn, args = ex._build_burst(2), [
-            ex.params, i32(S), i32(S), on, i32(S), i32(S, RECENT_WINDOW),
-            i32(S), i32(S), i32(S) + 2, i32(S) - 1, f32(S), f32(S), i32(S),
-            f32(S), ex.k, ex.v]
+            ex.params, i32(len(B.BURST_INTS) + RECENT_WINDOW, S),
+            f32(len(B.BURST_FLOATS), S), ex.k, ex.v]
         if ex.rider_rows:
             args.append(ex._rider_args(None, 2))
     else:

@@ -48,6 +48,7 @@ from test_batching import (
     check_clamped_slot,
     family_engine,
     slot_rows,
+    transfer_counts,
 )
 from test_runtime_pipeline import (
     build_cluster,
@@ -385,6 +386,198 @@ def test_burst_dispatch_budget_guard(cfg, params):
     assert res.tokens == ref, (res.tokens, ref)
     assert len(calls) == math.ceil((12 - 1) / 4), len(calls)
     assert ex.burst_dispatches == len(calls)
+
+
+# -- what a round sends across the host-device boundary -----------------------
+
+def _round_of(ad, tokens, cur, as_array=np.asarray):
+    """One burst round of the adapter with a request a session in
+    ``tokens``, all in flight together; -> {session: response}."""
+    import threading
+
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+        StageRequest,
+    )
+
+    got = {}
+
+    def ask(sid):
+        got[sid] = ad.forward(StageRequest(
+            session_id=sid, hidden=as_array([[tokens[sid]]], np.int32),
+            seq_len=1, cur_len=cur[sid], is_prefill=False, max_length=64,
+            sampling=SAMPLED, generated_tokens=(tokens[sid],), step_seed=3,
+            burst_len=4, burst_budget=4))
+
+    threads = [threading.Thread(target=ask, args=(sid,)) for sid in tokens]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(120)
+    assert set(got) == set(tokens)
+    return got
+
+
+@pytest.mark.parametrize("sessions", [1, 4], ids=["one", "every-slot"])
+def test_a_burst_round_crosses_the_boundary_three_times(cfg, params,
+                                                        sessions):
+    """Through the adapter, requests as the wire decodes them (ids on the
+    host): a round of S sessions sends TWO arrays up and reads ONE back
+    whatever S, by `server_burst_transfers_total` over
+    `server_burst_dispatches_total`; a request whose ids come as a device
+    array costs the round one more read each, and the count says so."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+        StageRequest,
+    )
+
+    ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
+                              max_len=64)
+    ad = BatchingStageAdapter(ex, window_s=0.5)
+    read = transfer_counts(ex, ad)
+    sids = "abcd"[:sessions]
+    first = {sid: ad.forward(StageRequest(
+        session_id=sid,
+        hidden=np.asarray([(PROMPT + PROMPT)[:3 + i]], np.int32),
+        seq_len=3 + i, cur_len=0, is_prefill=True, max_length=64,
+        sampling=SAMPLED, step_seed=2)).token_id
+        for i, sid in enumerate(sids)}
+    cur = {sid: 3 + i for i, sid in enumerate(sids)}
+    assert read() == (0, 0, 0)              # a prefill is not a round
+    got = _round_of(ad, first, cur)
+    assert read() == (2, 1, 1), read()
+    assert all(len(r.burst_tokens) == 4 for r in got.values())
+    again = _round_of(ad, {s: r.burst_tokens[-1] for s, r in got.items()},
+                      {s: r.cache_len for s, r in got.items()},
+                      as_array=jnp.asarray)
+    assert read() == (4, 2 + sessions, 2), read()
+    assert all(len(r.burst_tokens) == 4 for r in again.values())
+
+
+def _thirteen_arrays(ex, entries, n_ticks):
+    """`BatchedStageExecutor._burst_prep` as it was before a round's
+    arguments were packed (PR 49): thirteen ``[S]``-shaped arrays, each its
+    own upload. Kept here, and only here, as the oracle of the packing."""
+    S = ex.slots
+    tok0 = np.zeros((S,), np.int32)
+    seeds = np.zeros((S,), np.int32)
+    recent = np.zeros((S, RECENT_WINDOW), np.int32)
+    nvalid = np.zeros((S,), np.int32)
+    run0 = np.zeros((S,), np.int32)
+    left = np.zeros((S,), np.int32)
+    eos = np.full((S,), -1, np.int32)
+    temp = np.zeros((S,), np.float32)
+    top_p = np.ones((S,), np.float32)
+    top_k = np.zeros((S,), np.int32)
+    rp = np.ones((S,), np.float32)
+    alive = np.zeros((S,), bool)
+    rows = {}
+    for sid, e in entries.items():
+        s = ex._slot_of[sid]
+        gen = tuple(int(t) for t in e["generated"])
+        win = gen[-RECENT_WINDOW:]
+        if win:
+            recent[s, :len(win)] = win
+        nvalid[s] = len(win)
+        r = 0
+        for t in reversed(gen):
+            if t != gen[-1]:
+                break
+            r += 1
+        run0[s] = r
+        tok0[s] = int(e["token"])
+        seeds[s] = int(e["seed"])
+        left[s] = min(int(e["budget"]), n_ticks)
+        eos[s] = int(e.get("eos", -1) if e.get("eos") is not None else -1)
+        temp[s] = float(e["temperature"])
+        top_p[s] = float(e["top_p"])
+        top_k[s] = int(e["top_k"])
+        rp[s] = float(e["repetition_penalty"])
+        alive[s] = True
+        rows[sid] = s
+    return rows, (tok0, ex.lengths.copy(), alive, seeds, recent, nvalid,
+                  run0, left, eos, temp, top_p, top_k, rp)
+
+
+def _unpacked_burst(ex, entries, n_ticks):
+    """A burst from the thirteen arrays: laid into the program's two
+    arguments by the layout the program's docstring states, the program
+    run, its one result read apart by hand; -> what `decode_burst` returns,
+    and the two arguments."""
+    rows, (tok0, lengths, alive, seeds, recent, nvalid, run0, left, eos,
+           temp, top_p, top_k, rp) = _thirteen_arrays(ex, entries, n_ticks)
+    ints = np.concatenate([np.stack([
+        tok0, lengths, alive.astype(np.int32), seeds, nvalid, run0, left,
+        eos, top_k]), recent.T])
+    floats = np.stack([temp, top_p, rp])
+    packed, ex.k, ex.v = ex._get_burst_jit(n_ticks)(
+        ex.params, ints, floats, ex.k, ex.v)
+    flat, S = np.asarray(packed), ex.slots
+    toks = flat[:n_ticks * S].reshape(n_ticks, S)
+    stop = flat[n_ticks * S:(n_ticks + 1) * S]
+    grown = flat[(n_ticks + 1) * S:(n_ticks + 2) * S]
+    assert flat.shape == ((n_ticks + 2) * S,) and flat.dtype == np.int32
+    out = {}
+    for sid, s in rows.items():
+        m = int(grown[s] - ex.lengths[s])
+        out[sid] = {"tokens": toks[:m, s].tolist(),
+                    "stop": {0: None, 1: "eos", 2: "repeat"}[int(stop[s])],
+                    "cache_len": int(grown[s])}
+        ex.lengths[s] = grown[s]
+    return out, (ints, floats)
+
+
+@pytest.fixture(scope="module")
+def twins(cfg, params):
+    return [BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
+                                 max_len=64) for _ in range(2)]
+
+
+def _knob_cases():
+    from test_sampling_parity import KNOBS
+
+    return sorted(KNOBS.items())
+
+
+@pytest.mark.parametrize("name,knobs", _knob_cases(),
+                         ids=[k for k, _ in _knob_cases()])
+def test_the_packed_burst_is_the_thirteen_arrays(cfg, twins, name, knobs):
+    """For every knob combination `tests/test_sampling_parity.py` drives
+    (greedy, sampled, every filter, mixed in one round): the two packed
+    arguments hold the thirteen arrays' values to the bit, in their dtypes,
+    and three bursts of twin engines (a budget that ends mid-burst, an eos
+    that one session's second token hits, the recent window carried
+    along) agree on tokens, stops and ``cache_len``."""
+    packed_ex, oracle_ex = twins
+    sids = ["a", "b", "c", "d"]
+    gen = {}
+    for i, sid in enumerate(sids):
+        ids = np.asarray([(PROMPT + PROMPT)[i:i + 4 + i]], np.int32)
+        for ex in twins:
+            ex.end_session(sid)
+            ex.prefill(sid, ids)
+        gen[sid] = [int(ids[0, -1])]
+    for burst in range(3):
+        entries = {}
+        for i, sid in enumerate(sids):
+            t, p, k, rp = knobs["abcd".index(sid) % len(knobs)]
+            entries[sid] = {
+                "token": gen[sid][-1], "seed": 11 * i + len(gen[sid]),
+                "budget": 3 if (sid, burst) == ("b", 1) else 9,
+                # from its second burst on, c ends at a token it has drawn
+                "eos": gen["c"][2] if sid == "c" and burst else None,
+                "generated": tuple(gen[sid]), "temperature": t, "top_p": p,
+                "top_k": k, "repetition_penalty": rp}
+        _, args = packed_ex._burst_prep(entries, 4)
+        want, laid = _unpacked_burst(oracle_ex, entries, 4)
+        for a, b in zip(args, laid):
+            assert type(a) is np.ndarray and a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+        got = packed_ex.decode_burst(entries, 4)
+        assert got == want, (name, burst, got, want)
+        assert len(got["b"]["tokens"]) <= (3 if burst == 1 else 4)
+        for sid in sids:
+            gen[sid].extend(got[sid]["tokens"])
+        sids = [s for s in sids if got[s]["stop"] is None]
+    assert len(gen["a"]) > 4
 
 
 # -- client: burst generation over the stage protocol -------------------------

@@ -7,7 +7,11 @@ qwen2 (bf16 and int8) and looped engines on the CPU, the SHA-256 of each
 program's StableHLO as the commit BEFORE that family lowered it (made with
 this file's own `programs` on a checkout of it, under the suite's
 ``highest`` matmul precision). A PR that means to change a shared program
-makes the table again the same way and says so."""
+makes the table again the same way and says so. PR 49 did, for the four
+``burst_tick`` rows alone: the burst program takes its per-slot arguments
+packed in two arrays (the rider in one) and returns what the host reads as
+one, so its text changed by design; the ticks inside it, and the twelve
+``decode_step`` / ``prefill`` / ``prefill_suffix`` rows, are PR 48's."""
 
 import hashlib
 
@@ -45,19 +49,19 @@ FAMILIES = {
 }
 PROGRAMS = ("burst_tick", "decode_step", "prefill", "prefill_suffix")
 GOLDEN = {
-    ("gpt2", "burst_tick"): "cae8da600df189c1",
+    ("gpt2", "burst_tick"): "c8b206e4cdffe369",
     ("gpt2", "decode_step"): "9b226ead3f186411",
     ("gpt2", "prefill"): "82d8bf1dfeec7242",
     ("gpt2", "prefill_suffix"): "f5e5953cab9b54d3",
-    ("looped", "burst_tick"): "b71fdb8e20a89eca",
+    ("looped", "burst_tick"): "ab2b7980009a799d",
     ("looped", "decode_step"): "96d22cce4b450a3e",
     ("looped", "prefill"): "464d7c5d40d72a37",
     ("looped", "prefill_suffix"): "a276ce113fbc69c5",
-    ("qwen2", "burst_tick"): "f523fb7cbb2c1cac",
+    ("qwen2", "burst_tick"): "b1d78407810684b8",
     ("qwen2", "decode_step"): "6598710f757744f6",
     ("qwen2", "prefill"): "60df83f486901e1d",
     ("qwen2", "prefill_suffix"): "4c8114aad5a7be82",
-    ("qwen2-int8", "burst_tick"): "e16fd88825cadc08",
+    ("qwen2-int8", "burst_tick"): "6ff14e1f8c9d9dd4",
     ("qwen2-int8", "decode_step"): "4d289ac3720e8763",
     ("qwen2-int8", "prefill"): "58941d2a5d9b91c7",
     ("qwen2-int8", "prefill_suffix"): "f97a3184e87b391f",

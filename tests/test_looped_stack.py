@@ -45,6 +45,8 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 )
 from perfbench.harness.manifest import load_module
 
+from test_batching import transfer_counts
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LAYERS, PASSES, VOCAB = 3, 4, 97
 HF = {"model_type": "ouro", "hidden_size": 64, "intermediate_size": 96,
@@ -207,22 +209,24 @@ def test_the_16_tick_burst_emits_the_reference_s_tokens(ref, kind):
 
 
 def test_burst_returns_the_passes_only_for_a_looped_stack(ref):
-    """The looped program returns, after the twelve, the passes its tokens
-    took and its rider's token (none: -1) and takes the rider as one more
-    argument; a one-pass program has the arguments and the twelve results
-    it always had, and no lane."""
+    """The looped program's packed result ends, after the tokens, the stops
+    and the lengths, in the passes its tokens took and its rider's token
+    (none: -1), and it takes the rider as one more argument; a one-pass
+    program's ends at the lengths, and it has no lane."""
     _, _, eng = build(ref)
     eng.prefill("s", ids_of(6)[None])
     rows, args = eng._burst_prep({"s": burst_entry(3, 4)}, 4)
     out = eng._get_burst_jit(4)(eng.params, *args, eng.k, eng.v,
                                 eng._rider_args(None, 4))
-    assert len(out) == 14 and int(out[12]) == PASSES * 4
-    assert int(out[13]) == -1 and eng.rider_rows == batching.RIDER_ROWS
+    packed = np.asarray(out[0])
+    assert len(out) == 3 and packed.shape == ((4 + 2) * eng.slots + 2,)
+    assert packed[-2] == PASSES * 4
+    assert packed[-1] == -1 and eng.rider_rows == batching.RIDER_ROWS
     _, _, once = build(ref, hf=dict(HF, total_ut_steps=1))
     once.prefill("s", ids_of(6)[None])
     rows, args = once._burst_prep({"s": burst_entry(3, 4)}, 4)
-    assert len(once._get_burst_jit(4)(once.params, *args, once.k,
-                                      once.v)) == 12
+    out = once._get_burst_jit(4)(once.params, *args, once.k, once.v)
+    assert len(out) == 3 and out[0].shape == ((4 + 2) * once.slots,)
     assert once.rider_rows == 0 and not once.can_ride(8, 4)
 
 
@@ -393,6 +397,63 @@ def test_a_prefill_rides_when_another_session_holds_a_slot(ref):
     assert eng.burst_dispatches == 3 and long.cache_len == 40
 
 
+@pytest.mark.parametrize("sessions", [1, 2])
+def test_a_round_with_a_lane_crosses_the_boundary_four_times(ref, sessions):
+    """A burst round of an engine with a rider lane, with and without a
+    rider, for one session and for every slot but the rider's: three
+    arrays up (the slots' int32 and float32, the lane's int32 vector) and
+    ONE read, by `server_burst_transfers_total` over
+    `server_burst_dispatches_total`; every argument but the parameters and
+    the stacks is a HOST array when the program is called (so nothing ran
+    on the device to make it)."""
+    _, _, eng = build(ref)
+    read = transfer_counts(eng)
+    for i in range(sessions):
+        eng.prefill(f"s{i}", ids_of(6 + i, i)[None])
+    real, seen = eng._get_burst_jit(2), []
+
+    def recording(params, *args):
+        seen.append([type(a) for a in args if not isinstance(a, tuple)])
+        return real(params, *args)
+
+    eng._burst_jits[2] = recording
+    entries = {f"s{i}": burst_entry(3 + i, 2) for i in range(sessions)}
+    eng.decode_burst(entries, 2)
+    assert read() == (3, 1, 1)
+    res = eng.decode_burst(
+        {sid: burst_entry(4, 2) for sid in entries}, 2,
+        rider=rider_of("r", ids_of(9, 7)))
+    assert read() == (6, 2, 2) and res["r"]["cache_len"] == 9
+    for types_ in seen:
+        host = [t for t in types_ if t is np.ndarray]
+        assert len(host) == 3 and len(types_) == 3 + len(
+            jax.tree.leaves((eng.k, eng.v))), types_
+
+
+def test_no_eager_program_runs_between_two_bursts(ref, caplog):
+    """A looped engine with a rider, every compiled program forgotten
+    after its first burst: the second burst, rider and all, compiles the
+    burst program and NOTHING else (a scalar handed to ``jnp.asarray`` on
+    its own, as the rider's eight once were, would compile a convert
+    program here; so would any eager slice, pad or cast of a result)."""
+    import logging
+
+    _, _, eng = build(ref)
+    eng.prefill("s", ids_of(6)[None])
+    first = eng.decode_burst({"s": burst_entry(3, 2)}, 2)["s"]["tokens"]
+    jax.clear_caches()
+    with jax.log_compiles(), caplog.at_level(logging.WARNING):
+        caplog.clear()
+        res = eng.decode_burst(
+            {"s": burst_entry(first[-1], 2, generated=first)}, 2,
+            rider=rider_of("r", ids_of(9, 7), temperature=0.8, top_p=0.9,
+                           top_k=5, repetition_penalty=1.2))
+    built = [r.getMessage().split()[1] for r in caplog.records
+             if r.getMessage().startswith("Compiling ")]
+    assert built == ["jit(burst_tick)"], built
+    assert len(res["s"]["tokens"]) == 2 and res["r"]["cache_len"] == 9
+
+
 # -- the gate and the exit rule ----------------------------------------------
 
 def test_gates_match_the_reference(ref):
@@ -438,14 +499,15 @@ def test_a_forced_early_exit_chooses_the_reference_s_pass(ref, bias):
     rows, args = eng._burst_prep({"s": burst_entry(ids[12], 6)}, 6)
     out = eng._get_burst_jit(6)(eng.params, *args, eng.k, eng.v,
                                 eng._rider_args(None, 6))
-    eng.k, eng.v = out[10], out[11]
-    toks = np.asarray(out[0])[:, rows["s"]]
+    packed, eng.k, eng.v = out
+    packed = np.asarray(packed)
+    toks = packed[:6 * eng.slots].reshape(6, eng.slots)[:, rows["s"]]
     toks = toks[toks >= 0]              # a greedy repeat may end it early
     consumed = np.concatenate([ids[:13], toks[:-1]]).astype(np.int32)
     _, g2 = ref.passes(hf, LAYERS, weights, jnp.asarray(consumed))
     at2 = np.asarray(ref.exit_pass(hf, g2))[12:]
     assert len(at2) == len(toks) >= 5
-    assert int(out[12]) == int((at2 + 1).sum())
+    assert int(packed[-2]) == int((at2 + 1).sum())
     rows_ref = reference_logits(ref, weights, consumed, hf)[12:]
     assert toks.tolist() == rows_ref.argmax(-1).tolist()
 

@@ -98,6 +98,42 @@ def test_tensor_codec_roundtrip():
     np.testing.assert_array_equal(_decode_tensor(meta, body), ids)
 
 
+@pytest.mark.parametrize("wire", ["f32", "bf16"])
+def test_a_frame_s_ids_stay_on_the_host_and_its_states_go_up(wire):
+    """What a server does with a decoded `forward` frame: an INTEGER tensor
+    (token ids: a prompt, a burst request's one token) is the host array
+    the frame decoded to in `StageRequest.hidden`, a FLOAT tensor (hidden
+    states entering a later stage) a device array; the rule is the
+    tensor's dtype, whatever the wire's."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+        StageRequest,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
+        _header_to_request,
+        _request_header,
+    )
+
+    def over_the_wire(hidden, **kw):
+        req = StageRequest(session_id="s", hidden=hidden,
+                           seq_len=hidden.shape[1], cur_len=0,
+                           max_length=32, **kw)
+        meta, body = _encode_tensor(np.asarray(hidden), wire)
+        return _header_to_request(_request_header(req, meta), body)
+
+    ids = np.asarray([[5, 9, 23]], np.int32)
+    got = over_the_wire(ids, is_prefill=True)
+    assert type(got.hidden) is np.ndarray and got.hidden.dtype == np.int32
+    np.testing.assert_array_equal(got.hidden, ids)
+    one = over_the_wire(np.asarray([[7]], np.int32), is_prefill=False,
+                        burst_len=4, burst_budget=4)
+    assert type(one.hidden) is np.ndarray and one.hidden.tolist() == [[7]]
+    states = np.linspace(-1, 1, 24, dtype=np.float32).reshape(1, 3, 8)
+    got = over_the_wire(states, is_prefill=True)
+    assert isinstance(got.hidden, jax.Array)
+    assert got.hidden.dtype == jnp.float32 and got.hidden.shape == (1, 3, 8)
+    np.testing.assert_allclose(np.asarray(got.hidden), states, atol=0.01)
+
+
 def test_generation_over_tcp_matches_oracle(swarm):
     cfg, params, client, _, _, _ = swarm
     sampling = SamplingParams(temperature=0.0)

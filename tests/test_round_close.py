@@ -481,3 +481,33 @@ def test_the_reply_record_goes_with_the_slot(kind):
     eng._slot_of.clear()                            # ... that took the stacks
     assert ad._returning(1, time.monotonic(), 1.0) == {}
     assert ad._replied == {}
+
+
+def test_the_report_prints_a_round_s_transfers_over_its_dispatches():
+    """`scripts/round_close_report.py` ``transfers_per_round``: what moved
+    of `server_burst_transfers_total` in the window, per direction, over
+    the burst programs dispatched in it, summed over the servers; None
+    where a run's scrapes lack the series (a program before PR 49) or no
+    burst ran."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "round_close_report.py")
+    spec = importlib.util.spec_from_file_location("round_close_report", path)
+    report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(report)
+    up, down = ('server_burst_transfers_total{dir="up"}',
+                'server_burst_transfers_total{dir="down"}')
+    rounds = "server_burst_dispatches_total"
+    ctx = {"counters_before": {"p": {rounds: 10.0, up: 20.0, down: 10.0},
+                               "q": {rounds: 0.0, up: 0.0, down: 0.0}},
+           "counters_after": {"p": {rounds: 60.0, up: 120.0, down: 75.0},
+                              "q": {rounds: 50.0, up: 150.0, down: 50.0}}}
+    assert report.transfers_report(ctx) == {"up": 2.5, "down": 1.15}
+    old = {"counters_before": {"p": {rounds: 10.0}},
+           "counters_after": {"p": {rounds: 60.0}}}
+    assert report.transfers_report(old) == {"up": None, "down": None}
+    idle = {"counters_before": ctx["counters_after"],
+            "counters_after": ctx["counters_after"]}
+    assert report.transfers_report(idle) == {"up": None, "down": None}
