@@ -48,7 +48,7 @@ from .runtime.executor import StageExecutor
 from .runtime.server import ElasticStageServer
 from .runtime.transport import LocalTransport
 from .scheduling.registry import PlacementRegistry
-from .utils.platform import compile_cache_dir, device_line
+from .utils.platform import compile_cache_dir, device_line, start_backend
 
 logger = logging.getLogger("mini_petals_tpu")
 
@@ -3399,6 +3399,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         cfg, params = load_config(args), None
     else:
         _refuse_unheld_state(args, load_config(args))
+        start_backend()
         cfg, params = load_model(args)
     run = {"local": run_local, "fused": run_fused, "oracle": run_oracle,
            "serve": run_serve, "client": run_client,

@@ -83,6 +83,7 @@ from ..models.transformer import (
     make_rope,
     qkv_proj,
 )
+from ..ops.folded_attention import folded_attention, read_plan
 from ..ops.rotary import apply_rope
 from ..parallel.ring_attention import NEG_INF
 from ..telemetry import catalog as _tm
@@ -333,17 +334,45 @@ def attn_block(max_len: int) -> int:
                                   -1) if max_len % b == 0), max_len)
 
 
-def attn_blocks(lengths, active, t, max_len, xp=np):
+def attn_blocks(lengths, active, t, max_len, xp=np, per_slot=False):
     """How many blocks of `attn_block` rows a step of ``t`` new rows a slot
     reads of every cache layer: up to the last new row of the LONGEST
     ACTIVE slot (along the last axis of ``lengths`` / ``active``), and none
     where no slot is active. An inactive slot's rows are never needed: its
-    output is discarded. The ONE statement of the bound: the decode
-    programs call it on traced values (``xp=jnp``) and the host, for
-    ``server_attn_rows_read_total``, on the lengths a step began with."""
+    output is discarded. ``per_slot``: a slot at a time, each up to its OWN
+    last new row and an inactive one 0, for a program that reads each slot
+    by itself (`cache_read`: the kernel). The ONE statement of the bound:
+    the decode programs call it on traced values (``xp=jnp``) and the host,
+    for ``server_attn_rows_read_total``, on the lengths a step began
+    with."""
     block = attn_block(max_len)
-    longest = xp.max(xp.where(active, lengths + t, 0), axis=-1)
-    return xp.minimum(-(-longest // block), max_len // block)
+    need = xp.where(active, lengths + t, 0)
+    if not per_slot:
+        need = xp.max(need, axis=-1)
+    return xp.minimum(-(-need // block), max_len // block)
+
+
+def cache_read(cfg, layers, folded: bool, t: int = 1,
+               rider: bool = False) -> str:
+    """The form in which a decode program of ``t`` new rows a slot reads a
+    cache layer (`_attend_cached`, `_attend_windowed`), by what it is handed
+    alone: ``folded`` stacks (`kv_fold_width`), the configuration and the
+    layer tree ``layers``, a ``rider`` group beside the slots' rows.
+    ``"kernel"``: `ops.folded_attention`, each slot up to its own last
+    block; it has one query row a slot and no mask but the causal one, so
+    a step of several rows, a rider lane, softcapped scores or a window of
+    any kind keep the ``"loop"`` over the blocks up to the longest active
+    slot. Unfolded rows: that loop where several query rows share a KV
+    head, else a ``"switch"`` over static prefixes. The programs, the
+    counter of the rows they read (`_count_attn_rows`) and the
+    ``kv_layout`` event all ask here."""
+    if folded:
+        plain = not (cfg.attn_softcap or cfg.sliding_window
+                     or "window" in layers)
+        return "kernel" if t == 1 and plain and not rider else "loop"
+    if cfg.eva_window or t * (cfg.num_heads // cfg.num_kv_heads) == 1:
+        return "switch"
+    return "loop"
 
 
 def kv_fold_width(layout, hkv: int, dh: int) -> Optional[int]:
@@ -413,7 +442,8 @@ class _CacheLayer:
     """Layer ``at`` of a carried ``[L, S, max_len, Hkv, Dh]`` cache stack
     (folded: ``[L, S, max_len, W]``), of which only the first ``blocks``
     (traced) blocks of `attn_block` rows are to be read, straight out of
-    the stack."""
+    the stack: ONE count for every slot or, for the kernel, the slots' own
+    as its `read_plan` (a vector)."""
     stack: Any
     at: Any
     blocks: Any
@@ -460,13 +490,22 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
     zeros it needs not (31 GFLOP a gpt2-xl tick, 0.2 ms of its peak) and
     the stack is read ONCE, dense, in the layout it rests in: on the v5e
     8.16 ms a gpt2-xl tick where `_attend`'s prefixes over ``[.., 25, 64]``
-    rows took 11.48 (PERF.md section 6, PR 45)."""
+    rows took 11.48 (PERF.md section 6, PR 45).
+
+    Folded stacks whose ``blocks`` are a `read_plan` (`cache_read`'s
+    ``"kernel"``): `ops.folded_attention`, the same block-diagonal products
+    and online softmax in ONE kernel that reads each slot's own blocks and
+    keeps its running sum in VMEM (PERF.md section 6, PR 52)."""
     b, t = q.shape[:2]
     hkv, dh = cfg.num_kv_heads, cfg.head_dim
     groups = cfg.num_heads // hkv
     folded = keys.stack.ndim == 4
     max_len = keys.stack.shape[2]
     rows = attn_block(max_len)
+    if keys.blocks.ndim:
+        return folded_attention(
+            q[:, 0] * _qscale(cfg), keys.stack, values.stack, keys.at,
+            keys.blocks, rows=rows, hkv=hkv)[:, None]
     out_dtype = jnp.promote_types(values.stack.dtype, q.dtype)  # `_attend`'s
 
     def grid(start, n):
@@ -1043,8 +1082,14 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
             cfg, lengths, active,
             (k_all.exact.shape[2], k_all.sums.shape[2]), jnp)
     else:
-        blocks = attn_blocks(lengths, active, qpos.shape[1], k_all.shape[2],
-                             jnp)
+        max_len = k_all.shape[2]
+        kernel = cache_read(cfg, params["layers"], k_all.ndim == 4,
+                            qpos.shape[1], rider is not None) == "kernel"
+        blocks = attn_blocks(lengths, active, qpos.shape[1], max_len, jnp,
+                             per_slot=kernel)
+        if kernel:
+            blocks = read_plan(blocks, qpos[:, 0, 0],
+                               max_len // attn_block(max_len))
     if rider is not None:
         r_grid = jnp.arange(rider["rows"], dtype=jnp.int32)[None, None, :]
         r_qpos = r_pos[None, :, None]                           # [1, C, 1]
@@ -1137,6 +1182,14 @@ class BatchedStageExecutor:
         self.slots = slots
         self.max_len = max_len
         self.dtype = jnp.dtype(dtype)
+        # The rider lane (`_decode_span`): a looped stack's prefill program
+        # streams the weights `loop_steps` times, as long as a whole tick of
+        # every OTHER session's burst, and which rounds pay it is chance;
+        # carried by the burst's own ticks the same prompt costs nothing
+        # that an idle lane does not. A stack that runs once prefills in a
+        # fraction of a tick and keeps the programs it had.
+        self.rider_rows = (RIDER_ROWS if cfg.loop_steps > 1
+                           and spec.is_first and spec.is_last else 0)
         self._new_stacks()
         self.lengths = np.zeros((slots,), np.int32)   # host-side truth
         self._slot_of: Dict[str, int] = {}
@@ -1161,14 +1214,6 @@ class BatchedStageExecutor:
         self._m_written = _tm.get("server_kv_positions_written_total")
         self._m_rows_held = _tm.get("server_state_rows_held_total")
         self._m_pos_held = _tm.get("server_positions_held_total")
-        # The rider lane (`_decode_span`): a looped stack's prefill program
-        # streams the weights `loop_steps` times, as long as a whole tick of
-        # every OTHER session's burst, and which rounds pay it is chance;
-        # carried by the burst's own ticks the same prompt costs nothing
-        # that an idle lane does not. A stack that runs once prefills in a
-        # fraction of a tick and keeps the programs it had.
-        self.rider_rows = (RIDER_ROWS if cfg.loop_steps > 1
-                           and spec.is_first and spec.is_last else 0)
         # Prompt-prefix KV reuse (runtime.prefix_cache), slot-layout
         # variant: entries hold [L, G, Hkv, Dh] KV segments, rows as the
         # stacks hold them (folded: [L, G, W]) (+ [1, G, D] output rows
@@ -1222,16 +1267,27 @@ class BatchedStageExecutor:
             "kv_layout", shape=list(shape), dtype=str(first.dtype),
             layout=layout_text(first.format.layout), row=list(row),
             row_layout=layout_text(asked), folded_to=width,
+            read=self._cache_read(1, bool(self.rider_rows)),
             logical_bytes_a_stack=int(first.nbytes),
             resident_bytes_a_stack=int(first.on_device_size_in_bytes()),
             **({"rows": list(counts), "summary_shape": list(shapes[1])}
                if windowed else {}))
 
-    def _count_attn_rows(self, lengths, active, t: int) -> None:
+    def _cache_read(self, t: int, rider: bool) -> str:
+        """`cache_read` of this engine's decode program of ``t`` new rows a
+        slot, with or without a ``rider`` group."""
+        return cache_read(self.cfg, self.params["layers"],
+                          jax.tree.leaves(self.k)[0].ndim == 4, t, rider)
+
+    def _count_attn_rows(self, lengths, active, t: int,
+                         rider: bool = False) -> None:
         """Add the ticks whose slots began at ``lengths`` (``[ticks, S]``),
-        ``active`` of them taking ``t`` new rows, to the two counters of how
-        much of a cache layer the ticks' attention read: the bound is
-        `attn_blocks`, the function the programs call. A windowed family's
+        ``active`` of them taking ``t`` new rows (beside a ``rider`` group
+        or not), to the two counters of how much of a cache layer the
+        ticks' attention read: the bound is `attn_blocks`, the function the
+        programs call, every slot to the longest active one's or, where the
+        program reads by the kernel (`cache_read`), each slot to its own
+        and an inactive one not at all. A windowed family's
         bounds are `windowed_blocks`' two: its exact rows go to the same
         counter, its summary rows to one of their own, and the chunks its
         ticks closed and pooled to a third."""
@@ -1245,6 +1301,10 @@ class BatchedStageExecutor:
                     int(sums.sum()) * attn_block(rows_s) * self.slots)
             c = self.cfg.eva_chunk
             self._m_chunks.inc(int((active & (lengths % c == c - 1)).sum()))
+        elif self._cache_read(t, rider) == "kernel":
+            own = attn_blocks(lengths, active, t, self.max_len,
+                              per_slot=True)
+            self._m_rows_read.inc(int(own.sum()) * attn_block(self.max_len))
         else:
             blocks = attn_blocks(lengths, active, t, self.max_len)
             self._m_rows_read.inc(
@@ -2047,7 +2107,7 @@ class BatchedStageExecutor:
         grown[held] = len_np[held] - self.lengths[held]
         tick = np.arange(len(toks_np))[:, None]
         self._count_attn_rows(self.lengths[None] + tick, tick < grown[None],
-                              1)
+                              1, bool(self.rider_rows))
         out: Dict[str, dict] = {}
         total = 0
         for sid, s in rows.items():

@@ -273,8 +273,12 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
         COUNTER, "Rows of ONE cache layer that the batched engine's decode "
                  "steps and burst ticks read: per tick, the blocks up to "
                  "the longest active slot (runtime.batching.attn_blocks) "
-                 "x the block's rows x slots; counted on the host from "
-                 "the lengths a step began and ended with.", (), None),
+                 "x the block's rows x slots; where the program reads by "
+                 "the kernel (ops.folded_attention: folded rows, one new "
+                 "row a slot; runtime.batching.cache_read), the SUM of the "
+                 "slots' own blocks x the block's rows, an idle slot none; "
+                 "counted on the host from the lengths a step began and "
+                 "ended with.", (), None),
     "server_attn_rows_span_total": (
         COUNTER, "Rows of one cache layer those ticks would read in full: "
                  "ticks x slots x max_session_len. server_attn_rows_read_"

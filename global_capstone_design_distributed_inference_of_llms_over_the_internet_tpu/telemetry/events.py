@@ -202,7 +202,11 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "backend holds a [1, 1, max_len, kv_heads, head_dim] array; "
         "folded_to = lanes of a row where the backend would not keep "
         "head_dim minor and the heads are held side by side in one dim "
-        "(runtime.batching.kv_fold_width), else null; "
+        "(runtime.batching.kv_fold_width), else null; read = the form in "
+        "which the engine's burst ticks read a cache layer "
+        "(runtime.batching.cache_read): kernel (ops.folded_attention, "
+        "each slot up to its own last block), loop or switch (both up to "
+        "the longest active slot); "
         "logical_bytes_a_stack; resident_bytes_a_stack = as laid out, "
         "with the padding of its tiles; for a family whose older rows are "
         "summaries also rows = [exact rows, summary rows] a slot and "

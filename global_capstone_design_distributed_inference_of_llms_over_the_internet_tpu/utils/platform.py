@@ -80,6 +80,39 @@ def compile_cache_dir() -> str:
     return DEFAULT_COMPILE_CACHE
 
 
+def start_backend() -> None:
+    """Start the accelerator's client, with Pallas imported MEANWHILE.
+
+    A chip-owning process waits seconds for the backend's start-up
+    (``jax.devices()``, outside the interpreter) and, where one of its
+    programs holds a Pallas kernel (`ops.folded_attention` in every engine
+    over folded K/V rows, `ops.int8_kernel` / `ops.nf4_kernel` in a
+    quantised one), spends 1.3 s importing Pallas the first time it traces
+    one: pure Python, and on the v5e's host all of what the kernel added to
+    a server's warm-up (PERF.md section 6, PR 52). So the import runs on a
+    thread of its own while this thread waits for the backend, and is
+    JOINED before this returns: nothing else of the program ever runs
+    beside it. A process that chose the CPU (``JAX_PLATFORMS=cpu``: tests,
+    CPU drives) starts its backend in milliseconds and would only wait for
+    the import: it does neither here, and a kernel module imports Pallas
+    when it is first traced, as everywhere."""
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return
+    import importlib
+    import threading
+
+    import jax
+
+    beside = threading.Thread(
+        target=importlib.import_module,
+        args=("jax.experimental.pallas.tpu",), name="import-pallas")
+    beside.start()
+    try:
+        jax.devices()
+    finally:
+        beside.join()
+
+
 def device_line() -> str:
     """``platform=… device_kind="…" device_id=… device_count=…`` for a
     serving role's handshake line — the device JAX reports, whichever it

@@ -167,18 +167,21 @@ def reduce_file(path: str) -> dict:
             by_prog = collections.defaultdict(float)
             tick_scopes = collections.defaultdict(float)
             via_count = collections.Counter()
-            unscoped = collections.defaultdict(float)
+            # (scope, operation @ source line) -> [runs, self seconds]
+            tick_ops = collections.defaultdict(lambda: [0, 0.0])
             for (mid, s, d), (_, self_s) in zip(order, selfs):
                 prog = module_at(s + d / 2)
                 by_prog[_short(prog)] += self_s
                 if TICK_RE.match(prog):
                     scope, via = scope_of[mid]
                     tick_scopes[scope or "none"] += self_s
+                    op = tick_ops[scope, unscoped_label[mid]]
+                    op[0] += 1
+                    op[1] += self_s
                     if scope:
                         via_count[via] += 1
-                    else:
-                        unscoped[unscoped_label[mid]] += self_s
             tick_total = sum(tick_scopes.values())
+            longest = sorted(tick_ops.items(), key=lambda kv: -kv[1][1])
             out["devices"][plane.name] = {
                 "module_runs": dict(collections.Counter(
                     _short(m[0]) for m in mods)),
@@ -188,8 +191,14 @@ def reduce_file(path: str) -> dict:
                 "burst_tick_share_by_scope": {
                     k: v / tick_total for k, v in tick_scopes.items()}
                 if tick_total else {},
-                "burst_tick_unscoped_top": dict(sorted(
-                    unscoped.items(), key=lambda kv: -kv[1])[:8]),
+                "burst_tick_unscoped_top": dict(
+                    [(label, secs) for (scope, label), (_, secs) in longest
+                     if scope is None][:8]),
+                # "<scope>: <operation> @ <source line>" -> [runs, self
+                # seconds]: a kernel (a custom call) keeps its own name.
+                "burst_tick_top_ops": {
+                    f"{scope or 'none'}: {label}": runs
+                    for (scope, label), runs in longest[:16]},
                 "scope_carried_by_stat": dict(via_count),
                 "op_metadata_stat_names": dict(stat_names),
                 "example_scoped_op": example,
@@ -283,7 +292,8 @@ def cmd_reduce(trace_dir: str, out_path: str) -> int:
                     d["burst_tick_seconds_by_scope"],
                 "burst_tick_share_by_scope": d["burst_tick_share_by_scope"],
                 "scope_carried_by_stat": d["scope_carried_by_stat"],
-                "unscoped_top": d["burst_tick_unscoped_top"]}))
+                "unscoped_top": d["burst_tick_unscoped_top"],
+                "top_ops": d["burst_tick_top_ops"]}))
         print("SPANS", json.dumps(r["host"]["stage_spans"]))
         print("REQUEST", json.dumps(r["host"]["one_request"]))
     return 0

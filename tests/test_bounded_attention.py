@@ -148,13 +148,33 @@ def test_rows_past_the_bound_are_never_read(small_blocks, t):
     assert np.isfinite(np.asarray(nobody)).all()
 
 
-def test_the_bound_is_the_longest_active_slot(small_blocks):
+@pytest.mark.parametrize("per_slot", [False, True])
+def test_the_bound_is_the_longest_active_slot(small_blocks, per_slot):
     """`attn_blocks`, the one statement of the bound, on the host's arrays
     and on the device's: blocks up to the last new row of the longest
     ACTIVE slot, never more than the slot holds, none with nobody active;
-    a leading axis of ticks is reduced row by row."""
+    a leading axis of ticks is reduced row by row. ``per_slot`` (the
+    kernel's bound): each slot up to its OWN last new row, an inactive one
+    0, the same clamp."""
     lengths = np.asarray([7, 8, 9, 31], np.int32)
     on = np.asarray([True, True, True, False])
+    if per_slot:
+        for t, want in ((1, [1, 2, 2, 0]), (7, [2, 2, 2, 0]),
+                        (8, [2, 2, 3, 0])):
+            for xp, wrap in ((np, np.asarray), (jnp, jnp.asarray)):
+                got = B.attn_blocks(wrap(lengths), wrap(on), t, MAX_LEN, xp,
+                                    per_slot=True)
+                assert got.shape == (4,) and list(np.asarray(got)) == want
+        own = partial(B.attn_blocks, per_slot=True)
+        assert list(own(lengths, ~on, 1, MAX_LEN)) == [0, 0, 0, 4]
+        assert list(own(lengths, ~on, 5, MAX_LEN)) == [0, 0, 0, 4]  # clamped
+        assert not own(lengths, on & False, 1, MAX_LEN).any()
+        ticks = np.arange(3)[:, None]
+        lengths = np.asarray([1, 8, 9, 31], np.int32)
+        np.testing.assert_array_equal(
+            own(lengths[None] + ticks, ticks < np.asarray([3, 1, 0, 0]), 1,
+                MAX_LEN), [[1, 2, 0, 0], [1, 0, 0, 0], [1, 0, 0, 0]])
+        return
     for t, want in ((1, 2), (7, 2), (8, 3)):
         assert B.attn_blocks(lengths, on, t, MAX_LEN) == want
         assert int(B.attn_blocks(jnp.asarray(lengths), jnp.asarray(on), t,
@@ -266,17 +286,27 @@ def test_a_looped_stack_with_a_rider_reads_by_blocks(ref, monkeypatch, kind):
 
 # -- the two counters ---------------------------------------------------------
 
-def test_the_counters_against_a_hand_count(small_blocks):
+@pytest.mark.parametrize("folded", [False, True])
+def test_the_counters_against_a_hand_count(small_blocks, monkeypatch, folded):
     """4 slots of 32 rows, 8-row blocks. A 4-tick burst of "a" (5 rows,
     budget 4) and "b" (3 rows, budget 2: it stops after tick 1): the ticks
     begin at longest active lengths 5, 6, 7, 8, so they read 1, 1, 1 and 2
     blocks of every layer = 5 x 8 rows x 4 slots of 4 x 4 x 32. A verify
     step of 3 rows on "a" (now 9 rows: 2 blocks) adds 2 x 8 x 4 of 4 x 32.
     A burst in which nobody is left after tick 0 reads one block and spans
-    two ticks."""
+    two ticks.
+
+    A ``folded`` engine's bursts read by the kernel, each slot its OWN
+    blocks and an idle slot none: "a" 1 + 1 + 1 + 2 and "b" 1 + 1 = 7
+    blocks of 8 rows, no factor of the slots; the verify step (three rows a
+    slot) keeps the loop and the shared bound; the last burst reads ONE
+    slot's one block."""
+    if folded:
+        monkeypatch.setattr(B, "kv_fold_width", lambda layout, hkv, dh: 128)
     telemetry.enable()
     try:
         ex = family_engine("gpt2", "float32", MAX_LEN)
+        assert (ex.k.ndim == 4) == folded
         read = catalog.get("server_attn_rows_read_total")
         span = catalog.get("server_attn_rows_span_total")
         r0, s0 = read.value, span.value
@@ -284,13 +314,15 @@ def test_the_counters_against_a_hand_count(small_blocks):
         out = ex.decode_burst({"a": greedy(3), "b": {**greedy(4),
                                                       "budget": 2}}, 4)
         assert [len(out[s]["tokens"]) for s in "ab"] == [4, 2]
-        assert (read.value - r0, span.value - s0) == (5 * 8 * 4, 4 * 4 * 32)
+        burst = 7 * 8 if folded else 5 * 8 * 4
+        assert (read.value - r0, span.value - s0) == (burst, 4 * 4 * 32)
         ex.decode_batch({"a": jnp.asarray([[3, 9, 1]], jnp.int32)})
         assert (read.value - r0, span.value - s0) == (
-            5 * 8 * 4 + 2 * 8 * 4, 4 * 4 * 32 + 4 * 32)
+            burst + 2 * 8 * 4, 4 * 4 * 32 + 4 * 32)
         r1, s1 = read.value, span.value
         ex.decode_burst({"b": {**greedy(4), "budget": 1}}, 2)
-        assert (read.value - r1, span.value - s1) == (1 * 8 * 4, 2 * 4 * 32)
+        assert (read.value - r1, span.value - s1) == (
+            1 * 8 if folded else 1 * 8 * 4, 2 * 4 * 32)
     finally:
         telemetry.disable()
 

@@ -94,6 +94,40 @@ def test_compile_cache_can_be_placed_from_outside(monkeypatch, tmp_path):
         jax.config.update("jax_compilation_cache_dir", before)
 
 
+def test_the_backend_starts_with_pallas_imported_meanwhile(monkeypatch):
+    """`start_backend`: a chip owner's backend start-up hides the import of
+    Pallas (a thread of its own, still importing while the backend starts,
+    JOINED before the call returns); a process that chose the CPU does
+    neither."""
+    import importlib
+    import threading
+
+    imported, seen, real = [], [], jax.devices
+    importing, started = threading.Event(), threading.Event()
+
+    def import_module(name):
+        imported.append((name, threading.current_thread().name))
+        importing.set()
+        assert started.wait(10)
+
+    def devices():
+        assert importing.wait(10)       # the import is under way ...
+        seen.append(len(imported))
+        started.set()                   # ... and ends after the backend
+        return real()
+
+    monkeypatch.setattr(importlib, "import_module", import_module)
+    monkeypatch.setattr(jax, "devices", devices)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    plat.start_backend()
+    assert imported == [] and seen == []
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    plat.start_backend()
+    assert imported == [("jax.experimental.pallas.tpu", "import-pallas")]
+    assert seen == [1]
+    assert not [t for t in threading.enumerate() if t.name == "import-pallas"]
+
+
 @pytest.mark.parametrize("mod", [IK, NK], ids=["int8", "nf4"])
 def test_tiles_never_exceed_their_own_budget(mod):
     """Every tile the picker returns fits its own estimate and divides the

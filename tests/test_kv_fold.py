@@ -106,6 +106,35 @@ def rows_of(ex):
     return out, pad
 
 
+# The decode programs that read a folded layer by the kernel
+# (`ops.folded_attention`): one new row a slot, no rider group beside them,
+# no softcap, no window. A verify step (three rows a slot), gemma2 and a
+# burst with a rider lane keep the loop over the blocks.
+KERNEL_READS = {"gpt2": {"burst", "step"}, "qwen2": {"burst", "step"},
+                "gemma2": set(), "looped": {"step"}}
+
+
+def reads_by_kernel(ex):
+    """Which of the engine's compiled decode programs hold the kernel: the
+    burst, the single step, the verify step (`drive` ran all three)."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
+        RECENT_WINDOW,
+    )
+
+    s = ex.slots
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)         # noqa: E731
+    burst = [ex.params, i32(len(batching.BURST_INTS) + RECENT_WINDOW, s),
+             jnp.ones((len(batching.BURST_FLOATS), s), jnp.float32), ex.k,
+             ex.v] + ([ex._rider_args(None, TICKS)] if ex.rider_rows else [])
+    step = lambda t: [ex.params, i32(s, t), i32(s),          # noqa: E731
+                      jnp.ones((s,), bool), ex.k, ex.v]
+    programs = {"burst": (ex._burst_jits[TICKS], burst),
+                "step": (ex._decode_jits[1], step(1)),
+                "verify": (ex._decode_jits[3], step(3))}
+    return {name for name, (fn, args) in programs.items()
+            if "folded_attention" in fn.lower(*args).as_text(debug_info=True)}
+
+
 def drive(ex):
     """prefill -> burst -> rewind -> suffix prefill (a prefix-chain write
     and a grain split on the way) -> burst, eight bursts in all, a verify
@@ -168,6 +197,7 @@ def test_a_folded_engine_is_the_unfolded_one(monkeypatch, family, lanes):
         ex.cfg.num_layers * ex.cfg.loop_steps, SLOTS, MAX_LEN, width)
     assert (ex.rider_rows > 0) == (family == "looped")
     got = drive(ex)
+    assert reads_by_kernel(ex) == KERNEL_READS[family]
     assert [w for w, *_ in got] == [w for w, *_ in want]
     for (what, tokens, hidden, (rows, pad)), (_, t0, h0, (r0, _)) in zip(
             got, want):
@@ -269,6 +299,9 @@ def test_one_event_says_how_the_stacks_are_held(monkeypatch):
     assert first["layout"] == first["row_layout"] == "{4,3,2,1,0}"
     assert second["shape"] == list(ex.k.shape) == [2, SLOTS, MAX_LEN, 128]
     assert second["folded_to"] == 128 and second["row"] == [4, 16]
+    # how the burst ticks read a layer: one query row a KV head over
+    # unfolded rows, the kernel over folded ones
+    assert (first["read"], second["read"]) == ("switch", "kernel")
     assert second["layout"] == "{3,2,1,0}"
     assert second["logical_bytes_a_stack"] == ex.k.nbytes
     assert second["resident_bytes_a_stack"] >= ex.k.nbytes
