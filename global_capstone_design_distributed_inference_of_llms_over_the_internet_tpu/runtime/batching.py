@@ -83,7 +83,11 @@ from ..models.transformer import (
     make_rope,
     qkv_proj,
 )
-from ..ops.folded_attention import folded_attention, read_plan
+from ..ops.slot_attention import (
+    engaged as kernel_engaged,
+    read_plan,
+    slot_attention,
+)
 from ..ops.rotary import apply_rope
 from ..parallel.ring_attention import NEG_INF
 from ..telemetry import catalog as _tm
@@ -358,18 +362,23 @@ def cache_read(cfg, layers, folded: bool, t: int = 1,
     cache layer (`_attend_cached`, `_attend_windowed`), by what it is handed
     alone: ``folded`` stacks (`kv_fold_width`), the configuration and the
     layer tree ``layers``, a ``rider`` group beside the slots' rows.
-    ``"kernel"``: `ops.folded_attention`, each slot up to its own last
-    block; it has one query row a slot and no mask but the causal one, so
-    a step of several rows, a rider lane, softcapped scores or a window of
-    any kind keep the ``"loop"`` over the blocks up to the longest active
-    slot. Unfolded rows: that loop where several query rows share a KV
-    head, else a ``"switch"`` over static prefixes. The programs, the
-    counter of the rows they read (`_count_attn_rows`) and the
-    ``kv_layout`` event all ask here."""
+    ``"kernel"``: `ops.slot_attention`, each slot up to its own last
+    block; it has one query row a slot and no mask but a row limit, so a
+    step of several rows, softcapped scores or a masked window of any kind
+    keep the ``"loop"`` over the blocks up to the longest active slot (a
+    folded stack beside a rider lane too: no engine holds one). Rows that
+    stay ``[Hkv, Dh]`` go by the kernel where it is the chip's and a row
+    fills its lanes (``Dh`` whole tiles of 128: `kernel_engaged`; every
+    other backend keeps the program it had); else by that loop where
+    several query rows share a KV head, else by a ``"switch"`` over static
+    prefixes. The programs, the counter of the rows they read
+    (`_count_attn_rows`) and the ``kv_layout`` event all ask here."""
+    plain = not (cfg.attn_softcap or cfg.sliding_window
+                 or "window" in layers)
     if folded:
-        plain = not (cfg.attn_softcap or cfg.sliding_window
-                     or "window" in layers)
         return "kernel" if t == 1 and plain and not rider else "loop"
+    if t == 1 and plain and cfg.head_dim % 128 == 0 and kernel_engaged():
+        return "kernel"
     if cfg.eva_window or t * (cfg.num_heads // cfg.num_kv_heads) == 1:
         return "switch"
     return "loop"
@@ -492,10 +501,11 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
     8.16 ms a gpt2-xl tick where `_attend`'s prefixes over ``[.., 25, 64]``
     rows took 11.48 (PERF.md section 6, PR 45).
 
-    Folded stacks whose ``blocks`` are a `read_plan` (`cache_read`'s
-    ``"kernel"``): `ops.folded_attention`, the same block-diagonal products
-    and online softmax in ONE kernel that reads each slot's own blocks and
-    keeps its running sum in VMEM (PERF.md section 6, PR 52)."""
+    Stacks whose ``blocks`` are a `read_plan` (`cache_read`'s
+    ``"kernel"``), folded or not: `ops.slot_attention`, a block as ONE MXU
+    operand and the online softmax in ONE kernel that reads each slot's own
+    blocks and keeps its running sum in VMEM (PERF.md section 6, PRs 52
+    and 53)."""
     b, t = q.shape[:2]
     hkv, dh = cfg.num_kv_heads, cfg.head_dim
     groups = cfg.num_heads // hkv
@@ -503,7 +513,7 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
     max_len = keys.stack.shape[2]
     rows = attn_block(max_len)
     if keys.blocks.ndim:
-        return folded_attention(
+        return slot_attention(
             q[:, 0] * _qscale(cfg), keys.stack, values.stack, keys.at,
             keys.blocks, rows=rows, hkv=hkv)[:, None]
     out_dtype = jnp.promote_types(values.stack.dtype, q.dtype)  # `_attend`'s
@@ -587,23 +597,27 @@ def windowed_rows(cfg, max_len: int) -> Tuple[int, int]:
             max(-(-max_len // w) - 1, 0) * (w // cfg.eva_chunk))
 
 
-def windowed_blocks(cfg, lengths, active, rows: Tuple[int, int], xp=np):
+def windowed_blocks(cfg, lengths, active, rows: Tuple[int, int], xp=np,
+                    per_slot=False):
     """`attn_blocks` for the two stacks of a windowed family, for a step of
     ONE new row a slot: ``(exact, sums)`` block counts. A slot at length p
     reads rows ``0 .. p % W`` of its window stack and the first ``(W / C)
     * (p // W)`` rows of its summary stack; every slot's read is bounded
     by the largest such count over the ACTIVE slots, whole blocks of
     `attn_block` rows of each stack (``rows``: the two stacks' rows a
-    slot, `windowed_rows`). Called on traced values by the decode programs
-    and on the host for the counters."""
+    slot, `windowed_rows`), or (``per_slot``, as `attn_blocks`') each
+    slot's by its own and an inactive slot's by 0. Called on traced values
+    by the decode programs and on the host for the counters."""
     rows_e, rows_s = rows
 
     def blocks(need, rows):
+        need = xp.where(active, need, 0)
+        if not per_slot:
+            need = xp.max(need, axis=-1)
         if not rows:
-            return xp.zeros(need.shape[:-1], need.dtype)
+            return xp.zeros_like(need)
         block = attn_block(rows)
-        most = xp.max(xp.where(active, need, 0), axis=-1)
-        return xp.minimum(-(-most // block), rows // block)
+        return xp.minimum(-(-need // block), rows // block)
 
     return (blocks(lengths % cfg.eva_window + 1, rows_e),
             blocks(_summaries_visible(cfg, lengths), rows_s))
@@ -662,7 +676,9 @@ def _attend_windowed(cfg, lp, q, keys, values, q_pos):
     bounded reads, each `_attend_cached`'s first form (a ``switch`` on the
     stack's block count over static prefixes: ``W / 128 + 1`` and ``R /
     128 + 1`` branches, 17 and 8 at 2048 / 896 rows, whatever the slot's
-    length in positions), each returning its softmax's running statistics
+    length in positions) or, where the counts are `read_plan`s
+    (`cache_read`'s ``"kernel"``), `ops.slot_attention` over each slot's
+    own blocks; each returning its softmax's running statistics
     (max, denominator, weighted sum; float32) and not a result; the two
     are merged as the blocks of an online softmax are, which IS the one
     softmax over both. The window stack always holds a visible row (the
@@ -708,6 +724,11 @@ def _attend_windowed(cfg, lp, q, keys, values, q_pos):
         if not rows_all:
             return nothing
         rows = attn_block(rows_all)
+        if k_layer.blocks.ndim:     # a `read_plan`, the limits in it
+            m, l, acc = slot_attention(
+                qg[:, 0].reshape(b, -1, dh), k_layer.stack, v_layer.stack,
+                k_layer.at, k_layer.blocks, rows=rows, hkv=hkv, stats=True)
+            return m.reshape(stat), l.reshape(stat), acc.reshape(stat + (dh,))
 
         def prefix(n):
             if not n:
@@ -1077,18 +1098,28 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     qpos = positions[:, :, None]                            # [S, T, 1]
     # Blocks of a cache layer that hold a row some ACTIVE slot's queries
     # may see: the layers read those and no more (`_attend_cached`).
+    # By the kernel (`cache_read`): each slot's own blocks and its row
+    # limit, as ONE plan a stack.
+    kernel = cache_read(
+        cfg, params["layers"], jax.tree.leaves(k_all)[0].ndim == 4,
+        qpos.shape[1], rider is not None) == "kernel"
     if cfg.eva_window:
-        blocks = windowed_blocks(
-            cfg, lengths, active,
-            (k_all.exact.shape[2], k_all.sums.shape[2]), jnp)
+        rows = (k_all.exact.shape[2], k_all.sums.shape[2])
+        blocks = windowed_blocks(cfg, lengths, active, rows, jnp,
+                                 per_slot=kernel)
+        if kernel:
+            p = qpos[:, 0, 0]
+            blocks = tuple(
+                read_plan(own, limit, n // attn_block(n) if n else 0)
+                for own, limit, n in zip(
+                    blocks, (p % cfg.eva_window + 1,
+                             _summaries_visible(cfg, p)), rows))
     else:
         max_len = k_all.shape[2]
-        kernel = cache_read(cfg, params["layers"], k_all.ndim == 4,
-                            qpos.shape[1], rider is not None) == "kernel"
         blocks = attn_blocks(lengths, active, qpos.shape[1], max_len, jnp,
                              per_slot=kernel)
         if kernel:
-            blocks = read_plan(blocks, qpos[:, 0, 0],
+            blocks = read_plan(blocks, qpos[:, 0, 0] + 1,
                                max_len // attn_block(max_len))
     if rider is not None:
         r_grid = jnp.arange(rider["rows"], dtype=jnp.int32)[None, None, :]
@@ -1288,27 +1319,26 @@ class BatchedStageExecutor:
         programs call, every slot to the longest active one's or, where the
         program reads by the kernel (`cache_read`), each slot to its own
         and an inactive one not at all. A windowed family's
-        bounds are `windowed_blocks`' two: its exact rows go to the same
-        counter, its summary rows to one of their own, and the chunks its
-        ticks closed and pooled to a third."""
+        bounds are `windowed_blocks`' two, shared or per slot likewise: its
+        exact rows go to the same counter, its summary rows to one of
+        their own, and the chunks its ticks closed and pooled to a third."""
+        kernel = self._cache_read(t, rider) == "kernel"
+        each = 1 if kernel else self.slots      # slots that read a count
         if self.cfg.eva_window:
             rows_e, rows_s = rows = windowed_rows(self.cfg, self.max_len)
-            exact, sums = windowed_blocks(self.cfg, lengths, active, rows)
-            self._m_rows_read.inc(
-                int(exact.sum()) * attn_block(rows_e) * self.slots)
+            exact, sums = windowed_blocks(self.cfg, lengths, active, rows,
+                                          per_slot=kernel)
+            self._m_rows_read.inc(int(exact.sum()) * attn_block(rows_e) * each)
             if rows_s:
                 self._m_sum_rows_read.inc(
-                    int(sums.sum()) * attn_block(rows_s) * self.slots)
+                    int(sums.sum()) * attn_block(rows_s) * each)
             c = self.cfg.eva_chunk
             self._m_chunks.inc(int((active & (lengths % c == c - 1)).sum()))
-        elif self._cache_read(t, rider) == "kernel":
-            own = attn_blocks(lengths, active, t, self.max_len,
-                              per_slot=True)
-            self._m_rows_read.inc(int(own.sum()) * attn_block(self.max_len))
         else:
-            blocks = attn_blocks(lengths, active, t, self.max_len)
+            blocks = attn_blocks(lengths, active, t, self.max_len,
+                                 per_slot=kernel)
             self._m_rows_read.inc(
-                int(blocks.sum()) * attn_block(self.max_len) * self.slots)
+                int(blocks.sum()) * attn_block(self.max_len) * each)
         self._m_rows_span.inc(len(lengths) * self.slots * self.max_len)
         self._m_written.inc(int(np.sum(active)) * t)
 

@@ -204,9 +204,10 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "head_dim minor and the heads are held side by side in one dim "
         "(runtime.batching.kv_fold_width), else null; read = the form in "
         "which the engine's burst ticks read a cache layer "
-        "(runtime.batching.cache_read): kernel (ops.folded_attention, "
-        "each slot up to its own last block), loop or switch (both up to "
-        "the longest active slot); "
+        "(runtime.batching.cache_read): kernel (ops.slot_attention, "
+        "each slot up to its own last block: folded rows anywhere, rows "
+        "whose head_dim fills the lanes on a TPU, one query row a KV "
+        "head), loop or switch (both up to the longest active slot); "
         "logical_bytes_a_stack; resident_bytes_a_stack = as laid out, "
         "with the padding of its tiles; for a family whose older rows are "
         "summaries also rows = [exact rows, summary rows] a slot and "

@@ -85,8 +85,8 @@ def start_backend() -> None:
 
     A chip-owning process waits seconds for the backend's start-up
     (``jax.devices()``, outside the interpreter) and, where one of its
-    programs holds a Pallas kernel (`ops.folded_attention` in every engine
-    over folded K/V rows, `ops.int8_kernel` / `ops.nf4_kernel` in a
+    programs holds a Pallas kernel (`ops.slot_attention` in every engine
+    whose decode ticks read by it, `ops.int8_kernel` / `ops.nf4_kernel` in a
     quantised one), spends 1.3 s importing Pallas the first time it traces
     one: pure Python, and on the v5e's host all of what the kernel added to
     a server's warm-up (PERF.md section 6, PR 52). So the import runs on a

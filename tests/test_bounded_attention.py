@@ -327,6 +327,44 @@ def test_the_counters_against_a_hand_count(small_blocks, monkeypatch, folded):
         telemetry.disable()
 
 
+def test_a_looped_burst_with_a_rider_reads_its_slots_by_the_kernel(
+        ref, small_blocks, monkeypatch):
+    """A looped stack whose heads fill the lanes (``head_dim`` 128), with
+    the kernel engaged: the slots' group of a burst tick goes by the kernel
+    BESIDE the rider's rows, which keep their slice of one slot. The tokens
+    of the two decoding sessions and the rider's first are those of the
+    step path (a twin engine that reads by the ``switch``), the stacks
+    agree, and the counter reads the slots' OWN blocks: x begins the four
+    ticks at 8..11 rows and y at 12..15, two 8-row blocks each a tick = 16
+    blocks of 8 rows, where the shared bound reads 2 x 8 x 3 slots x 4."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
+        slot_attention,
+    )
+    from test_looped_stack import HF
+
+    hf = {**HF, "hidden_size": 256, "num_attention_heads": 2,
+          "num_key_value_heads": 2, "head_dim": 128}
+    telemetry.enable()
+    try:
+        read = catalog.get("server_attn_rows_read_total")
+        seen = []
+        for hook in (True, None):
+            monkeypatch.setattr(slot_attention, "_INTERPRET", hook)
+            _, _, eng = build(ref, hf=hf)
+            assert eng._cache_read(1, True) == (
+                "kernel" if hook else "switch")
+            r0 = read.value
+            got = eng.decode_burst(two_decoding(eng), 4,
+                                   rider=rider_of("r", ids_of(21, 3)))
+            seen.append((eng, got, read.value - r0))
+    finally:
+        telemetry.disable()
+    (a, got, by_kernel), (b, want, shared) = seen
+    assert got == want and set(got) == {"x", "y", "r"}
+    assert rel_rms(a.k, b.k) <= 1e-5 and rel_rms(a.v, b.v) <= 1e-5
+    assert (by_kernel, shared) == (16 * 8, 2 * 8 * 3 * 4)
+
+
 # -- the lowered programs -------------------------------------------------------
 
 def _in_scans(jaxpr, names, depth=0):

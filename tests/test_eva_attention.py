@@ -388,6 +388,17 @@ def test_the_read_bounds_are_one_function_for_program_and_host():
     assert [int(x[0]) for x in traced] == [16, 7]
     none = windowed_blocks(big, lengths, np.zeros_like(active), ROWS)
     assert [int(x[0]) for x in none] == [0, 0]
+    # a slot at a time (the kernel's plan): ceil((p % 2048 + 1) / 128) of
+    # the window stack, p // 2048 blocks of 128 summaries, the idle slot 0
+    own = [[1, 16, 1, 8, 16, 0, 7, 1], [0, 0, 1, 2, 7, 0, 4, 2]]
+    exact, sums = windowed_blocks(big, lengths, active, ROWS, per_slot=True)
+    assert [list(exact[0]), list(sums[0])] == own
+    traced = jax.jit(lambda l, a: windowed_blocks(
+        big, l, a, ROWS, jnp, per_slot=True))(lengths, active)
+    assert [list(np.asarray(x[0])) for x in traced] == own
+    short = windowed_blocks(big, lengths % 2048, active, (2048, 0),
+                            per_slot=True)
+    assert list(short[0][0]) == own[0] and not short[1].any()
 
 
 def test_the_decode_switch_holds_a_window_s_branches_not_the_slot_s():
@@ -444,6 +455,62 @@ def _counters_follow_the_bounds():
     assert moved("server_state_rows_held_total") == held
     assert moved("server_positions_held_total") == sum(
         n + 1 + j for j in range(3))
+
+
+def test_the_counters_follow_each_slot_s_own_blocks_under_the_kernel(
+        monkeypatch):
+    """Heads that fill the lanes (``head_dim`` 128) and the kernel engaged:
+    "a" is two rows into its third window and sees 16 summaries, "b" is in
+    its first window and sees none. ONE 32-row block a stack: every tick
+    reads a's and b's window block (2 x 32) and a's summary block (32),
+    where the shared bound reads 2 slots x 32 of each; the rows that come
+    back are the ``switch`` engine's."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
+        slot_attention,
+    )
+
+    cfg = config_mod.evabyte_config(
+        vocab_size=VOCAB, hidden_size=256, num_layers=LAYERS, num_heads=2,
+        num_kv_heads=2, intermediate_size=96, max_position_embeddings=4096,
+        rope_theta=100000.0, window_size=W, chunk_size=C, num_pred_heads=2)
+    params = init_params(jax.random.PRNGKey(5), cfg, jnp.float32)
+    spec = StagePlan.even(LAYERS, 1).stages[0]
+    got = lambda name: tm.get(name).value                    # noqa: E731
+    names = ("server_attn_rows_read_total",
+             "server_attn_summary_rows_read_total")
+    telemetry.enable()
+    try:
+        seen = []
+        for hook in (True, None):
+            monkeypatch.setattr(slot_attention, "_INTERPRET", hook)
+            eng = BatchedStageExecutor(cfg, spec, params, slots=2,
+                                       max_len=MAX_LEN, dtype=jnp.float32)
+            assert eng._cache_read(1, False) == (
+                "kernel" if hook else "switch")
+            eng.prefill("a", ids_of(2 * W + 2)[None])
+            eng.prefill("b", ids_of(5, 1)[None])
+            base = [got(n) for n in names]
+            rows = [eng.decode_batch({"a": ids_of(1, j)[None],
+                                      "b": ids_of(1, j + 9)[None]})
+                    for j in range(3)]
+            moved = [got(n) - b for n, b in zip(names, base)]
+            # then a 16-tick burst in which a new "b" (W - 3 = 29 rows)
+            # crosses its window's edge and "a" closes four chunks
+            eng.end_session("b")
+            eng.prefill("b", ids_of(W - 3, 1)[None])
+            seen.append((rows, moved, burst_through(
+                eng, {"a": ids_of(2 * W + 5), "b": ids_of(W - 3, 1)}, 1)))
+    finally:
+        telemetry.disable()
+    (rows, by_kernel, burst), (want, shared, burst_want) = seen
+    assert burst == burst_want
+    assert by_kernel == [3 * 2 * W, 3 * 1 * 32]
+    assert shared == [3 * 2 * W, 3 * 2 * 32]
+    for tick, ref_tick in zip(rows, want):
+        for sid in "ab":
+            np.testing.assert_allclose(np.asarray(tick[sid]),
+                                       np.asarray(ref_tick[sid]),
+                                       atol=2e-5, rtol=2e-5)
 
 
 # -- the importer, the control, the refusals -------------------------------------

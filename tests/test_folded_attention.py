@@ -1,12 +1,13 @@
-"""`ops.folded_attention`: a decode step's attention over one layer of a
-FOLDED cache stack, each slot read up to its own last block.
+"""`ops.slot_attention`: a decode step's attention over one layer of a
+cache stack, FOLDED or with its rows as ``[Hkv, Dh]``, each slot read up to
+its own last block.
 
 On the CPU the kernel runs through the Pallas interpreter; the oracle is
 `runtime.batching._attend` over the dense rows of the same layer. The
 shapes are tiny (16-row blocks, 64-row slots) and every case of a shape
-runs ONE compiled program: the whole file stays well under 30 s. The last
-test compiles the kernel at gpt2-xl's served widths for a DESCRIBED v5e (no
-chip): what Mosaic refuses, it refuses there."""
+runs ONE compiled program: the whole file stays well under a minute. The
+last tests compile the kernel at the five cells' served widths for a
+DESCRIBED v5e (no chip): what Mosaic refuses, it refuses there."""
 
 import functools
 import types
@@ -17,7 +18,7 @@ import numpy as np
 import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
-    folded_attention as FA,
+    slot_attention as FA,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
     batching as B,
@@ -34,6 +35,7 @@ SHAPES = {
 
 
 def head_cfg(heads, hkv, dh):
+    """What `_attend` reads of a configuration."""
     return types.SimpleNamespace(
         num_kv_heads=hkv, num_heads=heads, head_dim=dh, query_scale=0.0,
         attn_softcap=0.0, sliding_window=None)
@@ -52,20 +54,24 @@ def stacks(shape, dtype="float32", seed=0):
 
 
 @functools.lru_cache(maxsize=None)
-def kernel_read(shape):
-    """`_attend_cached` over folded stacks by the kernel's plan, jitted
-    once a shape."""
-    cfg = head_cfg(*SHAPES[shape][:3])
+def plan_read(heads, hkv, dh):
+    """`_attend_cached` over stacks of either form by the kernel's plan,
+    jitted once a configuration."""
+    cfg = head_cfg(heads, hkv, dh)
 
     @jax.jit
     def read(q, k, v, at, lengths, active):
         own = B.attn_blocks(lengths, active, 1, MAX_LEN, jnp, per_slot=True)
-        plan = FA.read_plan(own, lengths, MAX_LEN // BLOCK)
+        plan = FA.read_plan(own, lengths + 1, MAX_LEN // BLOCK)
         return B._attend_cached(cfg, {}, q, B._CacheLayer(k, at, plan),
                                 B._CacheLayer(v, at, plan),
                                 lengths[:, None, None])
 
     return read
+
+
+def kernel_read(shape):
+    return plan_read(*SHAPES[shape][:3])
 
 
 def dense_read(shape, q, k_layer, v_layer, lengths):
@@ -167,9 +173,12 @@ def test_the_plan_by_hand():
     assert none[0] == 0
 
 
-def test_which_programs_read_by_the_kernel():
-    """`cache_read`, the one rule: a folded stack, one new row a slot, no
-    rider group, no softcap and no window of any kind."""
+def test_which_programs_read_by_the_kernel(monkeypatch):
+    """`cache_read`, the one rule: one new row a slot, no softcap and no
+    window of the masked kind; a folded stack on every backend and with no
+    rider group, rows that stay ``[Hkv, Dh]`` where the kernel is the
+    chip's (here: the tests' hook) and ``Dh`` is whole lane tiles, a rider
+    group or not."""
     cfg = head_cfg(4, 2, 16)
     cfg.eva_window = 0
     assert B.cache_read(cfg, {}, True) == "kernel"
@@ -183,6 +192,209 @@ def test_which_programs_read_by_the_kernel():
     cfg.num_kv_heads = 4
     assert B.cache_read(cfg, {}, False) == "switch"
     assert B.cache_read(cfg, {}, False, t=2) == "loop"
+    # rows that stay [Hkv, Dh], a head filling the lanes
+    wide = types.SimpleNamespace(**{**vars(cfg), "head_dim": 128})
+    grouped = types.SimpleNamespace(**{**vars(wide), "num_kv_heads": 2})
+    windowed = types.SimpleNamespace(**{**vars(wide), "eva_window": 32})
+    assert not FA.engaged()                                 # not a TPU
+    assert B.cache_read(wide, {}, False) == "switch"
+    assert B.cache_read(grouped, {}, False) == "loop"
+    assert B.cache_read(windowed, {}, False) == "switch"
+    monkeypatch.setattr(FA, "_INTERPRET", True)
+    assert FA.engaged()
+    for rider in (False, True):
+        assert B.cache_read(wide, {}, False, rider=rider) == "kernel"
+    assert B.cache_read(windowed, {}, False) == "kernel"
+    assert B.cache_read(grouped, {}, False) == "kernel"
+    assert B.cache_read(grouped, {}, False, t=2) == "loop"
+    assert B.cache_read(wide, {}, False, t=2) == "loop"     # a verify step
+    assert B.cache_read(wide, {"window": 0}, False) == "switch"
+    for key in ("attn_softcap", "sliding_window"):
+        other = types.SimpleNamespace(**{**vars(wide), key: 4})
+        assert B.cache_read(other, {}, False) == "switch"
+    assert B.cache_read(cfg, {}, False) == "switch"         # head_dim 16
+    assert B.cache_read(cfg, {}, True, rider=True) == "loop"
+
+
+# -- rows that stay [Hkv, Dh]: the same kernel, a block as it rests --------------
+
+# heads, KV heads of the three cells whose stacks are not folded, in small
+# (``head_dim`` 128 as theirs: a row fills its lanes).
+WIDE = {"ouro": (16, 16), "evabyte": (32, 32), "qwen2": (28, 4)}
+DH = 128
+
+
+def wide_stacks(shape, dtype="float32", seed=0, rows=MAX_LEN):
+    """``(q [S, 1, H, Dh], K and V [L, S, rows, Hkv, Dh])``."""
+    heads, hkv = WIDE[shape]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (SLOTS, 1, heads, DH)).astype(dtype)
+    k = jax.random.normal(kk, (LAYERS, SLOTS, rows, hkv, DH)).astype(dtype)
+    v = jax.random.normal(kv, (LAYERS, SLOTS, rows, hkv, DH)).astype(dtype)
+    return q, k, v
+
+
+def wide_read(shape):
+    return plan_read(*WIDE[shape], DH)
+
+
+def wide_dense(shape, q, k_layer, v_layer, lengths):
+    cfg = head_cfg(*WIDE[shape], DH)
+    q_pos = lengths[:, None, None]
+    k_pos = jnp.arange(k_layer.shape[1], dtype=jnp.int32)[None, None, :]
+    return B._attend(cfg, {}, q, k_layer, v_layer,
+                     (B._visible(cfg, q_pos, k_pos), q_pos, k_pos))
+
+
+@pytest.mark.parametrize("shape", sorted(WIDE))
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_rows_as_they_rest_against_attend_over_the_dense_rows(case, shape):
+    """The cells' three shapes (16 KV heads of one query head, 32 of one, 4
+    of seven): every active slot's row is `_attend`'s over the whole layer
+    to float32's last digits; an idle slot's row is zeros."""
+    lengths, active = (jnp.asarray(x, jnp.int32) for x in CASES[case])
+    active = active.astype(bool)
+    q, k, v = wide_stacks(shape)
+    got = wide_read(shape)(q, k, v, jnp.int32(1), lengths, active)
+    want = wide_dense(shape, q, k[1], v[1], lengths)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    live = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-6, rtol=2e-6)
+    assert not np.asarray(got)[~live].any()
+
+
+@pytest.mark.parametrize("shape", sorted(WIDE))
+def test_rows_as_they_rest_in_bfloat16_to_its_rounding(shape):
+    lengths = jnp.asarray([BLOCK - 1, BLOCK, 50, 3], jnp.int32)
+    active = jnp.asarray([True, True, True, False])
+    q, k, v = wide_stacks(shape, "bfloat16")
+    got = wide_read(shape)(q, k, v, jnp.int32(2), lengths, active)
+    want = wide_dense(shape, q, k[2], v[2], lengths)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:3],
+                               np.asarray(want, np.float32)[:3],
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("shape", sorted(WIDE))
+def test_no_row_past_a_slot_s_own_blocks_is_read_as_it_rests(shape):
+    """`test_no_row_past_a_slot_s_own_blocks_is_read` on unfolded stacks:
+    NaN past each slot's OWN blocks changes no bit, and the idle slot's
+    output is zeros."""
+    lens, on = [BLOCK - 2, 3 * BLOCK + 1, BLOCK, 2 * BLOCK + 3], [1, 1, 1, 0]
+    lengths, active = jnp.asarray(lens, jnp.int32), jnp.asarray(on, bool)
+    q, k, v = wide_stacks(shape, seed=3)
+    own = B.attn_blocks(np.asarray(lens), np.asarray(on, bool), 1, MAX_LEN,
+                        per_slot=True)
+    bad = jnp.asarray(np.arange(MAX_LEN)[None, :]
+                      >= (own * BLOCK)[:, None])[None, :, :, None, None]
+    k_bad, v_bad = jnp.where(bad, jnp.nan, k), jnp.where(bad, jnp.nan, v)
+    read = wide_read(shape)
+    clean = read(q, k, v, jnp.int32(0), lengths, active)
+    dirty = read(q, k_bad, v_bad, jnp.int32(0), lengths, active)
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+    assert not np.asarray(dirty)[3].any()
+    assert np.isnan(np.asarray(
+        wide_dense(shape, q, k_bad[0], v_bad[0], lengths))[:3]).any()
+
+
+# A family whose older rows are summaries: a 32-row window of exact rows and
+# one summary row a chunk of 4 earlier positions, TWO stacks under ONE
+# softmax. Positions: in its first window (no summary), at its window's
+# last row, at the first row of its second window, deep in its fourth, and
+# an idle slot past everything.
+EVA_W, EVA_C, EVA_R = 32, 4, 32
+EVA_AT = ([5, EVA_W - 1, EVA_W, 3 * EVA_W + 4, 4 * EVA_W + 20],
+          [1, 1, 1, 1, 0])
+
+
+def eva_cfg():
+    cfg = head_cfg(*WIDE["evabyte"], DH)
+    cfg.eva_window, cfg.eva_chunk = EVA_W, EVA_C
+    return cfg
+
+
+@functools.lru_cache(maxsize=None)
+def eva_read():
+    cfg = eva_cfg()
+
+    @jax.jit
+    def read(q, ke, ve, ks, vs, at, p, active):
+        rows = (EVA_W, EVA_R)
+        own = B.windowed_blocks(cfg, p, active, rows, jnp, per_slot=True)
+        limits = (p % EVA_W + 1, B._summaries_visible(cfg, p))
+        plan = [FA.read_plan(o, lim, n // BLOCK)
+                for o, lim, n in zip(own, limits, rows)]
+        layer = lambda ex, su: B._WindowedRead(             # noqa: E731
+            B._CacheLayer(ex, at, plan[0]), B._CacheLayer(su, at, plan[1]))
+        return B._attend_windowed(cfg, {}, q, layer(ke, ks), layer(ve, vs),
+                                  p[:, None, None])
+
+    return read
+
+
+def eva_stacks(dtype, seed=0):
+    s = len(EVA_AT[0])
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = lambda n: (LAYERS, s, n) + (WIDE["evabyte"][1], DH)  # noqa: E731
+    q = jax.random.normal(ks[0], (s, 1, WIDE["evabyte"][0], DH))
+    made = [jax.random.normal(key, shape(n)) for key, n in zip(
+        ks[1:], (EVA_W, EVA_W, EVA_R, EVA_R))]
+    return [x.astype(dtype) for x in [q] + made]
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6), ("bfloat16", 2e-2)])
+def test_a_window_stack_and_a_summary_stack_under_one_softmax(dtype, tol):
+    """Each stack by the kernel with its statistics out, the two merged by
+    `_attend_windowed`: `_attend` over the window's rows up to the query's
+    own beside the summaries of EARLIER windows, in one softmax. A slot in
+    its first window reads no summary block and weighs none; NaN past each
+    slot's own blocks of either stack is never read."""
+    cfg = eva_cfg()
+    p, active = (np.asarray(x) for x in EVA_AT)
+    active = active.astype(bool)
+    q, ke, ve, ks, vs = eva_stacks(dtype)
+    own = B.windowed_blocks(cfg, p, active, (EVA_W, EVA_R), per_slot=True)
+    assert [list(x) for x in own] == [[1, 2, 1, 1, 0], [0, 0, 1, 2, 0]]
+    cols = jnp.arange(EVA_W + EVA_R, dtype=jnp.int32)[None, None, :]
+    at = jnp.asarray(p, jnp.int32)[:, None, None]
+    mask = jnp.where(cols < EVA_W, cols <= at % EVA_W,
+                     cols - EVA_W < B._summaries_visible(cfg, at))
+    want = B._attend(cfg, {}, q, jnp.concatenate([ke[1], ks[1]], 1),
+                     jnp.concatenate([ve[1], vs[1]], 1), (mask, at, cols))
+
+    def poisoned(stack, blocks):
+        bad = np.arange(stack.shape[2])[None, :] >= (blocks * BLOCK)[:, None]
+        return jnp.where(jnp.asarray(bad)[None, :, :, None, None], jnp.nan,
+                         stack)
+
+    got = eva_read()(q, poisoned(ke, own[0]), poisoned(ve, own[0]),
+                     poisoned(ks, own[1]), poisoned(vs, own[1]),
+                     jnp.int32(1), jnp.asarray(p, jnp.int32),
+                     jnp.asarray(active))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32)[active],
+                               np.asarray(want, np.float32)[active],
+                               atol=tol, rtol=tol)
+    assert not np.asarray(got, np.float32)[~active].any()
+
+
+def test_a_slot_the_plan_leaves_out_has_statistics_that_weigh_nothing():
+    q, k, v = wide_stacks("ouro")
+    lengths = jnp.asarray([9, 40, 0, 17], jnp.int32)
+    own = jnp.asarray([1, 3, 0, 0], jnp.int32)
+    plan = FA.read_plan(own, lengths + 1, MAX_LEN // BLOCK)
+    m, l, acc = FA.slot_attention(q[:, 0], k, v, 0, plan, rows=BLOCK,
+                                  hkv=WIDE["ouro"][1], stats=True)
+    assert m.shape == l.shape == (SLOTS, 16) and acc.shape == (SLOTS, 16, DH)
+    assert (np.asarray(m)[2:] == FA.NEG_INF).all()
+    assert not np.asarray(l)[2:].any() and not np.asarray(acc)[2:].any()
+    whole = FA.slot_attention(q[:, 0], k, v, 0, plan, rows=BLOCK,
+                              hkv=WIDE["ouro"][1])
+    np.testing.assert_allclose(
+        np.asarray(acc / l[..., None])[:2].reshape(2, -1),
+        np.asarray(whole)[:2], atol=1e-6, rtol=1e-6)
 
 
 # -- the served widths, compiled for the chip without the chip ----------------
@@ -215,8 +427,8 @@ def test_the_kernel_compiles_for_the_v5e_at_gpt2_xl_s_widths(
 
     def read(q, k, v, at, lengths, active):
         own = B.attn_blocks(lengths, active, 1, max_len, jnp, per_slot=True)
-        plan = FA.read_plan(own, lengths, max_len // 128)
-        return FA.folded_attention(q, k, v, at, plan, rows=128, hkv=heads)
+        plan = FA.read_plan(own, lengths + 1, max_len // 128)
+        return FA.slot_attention(q, k, v, at, plan, rows=128, hkv=heads)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -226,6 +438,47 @@ def test_the_kernel_compiles_for_the_v5e_at_gpt2_xl_s_widths(
         arg((s, heads, dh), jnp.bfloat16), stack, stack, arg((), jnp.int32),
         arg((s,), jnp.int32), arg((s,), bool)).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    assert "folded_attention" in compiled.as_text()
+    assert "slot_attention" in compiled.as_text()
     assert (compiled.memory_analysis().temp_size_in_bytes
             < s * max_len * width * 2)
+
+
+# slots, heads, KV heads, cache layers, rows a slot, statistics out: the
+# three cells whose rows stay [Hkv, 128], as served (bfloat16).
+SERVED = {
+    "ouro": (8, 16, 16, 192, 512, False),
+    "evabyte-window": (8, 32, 32, 16, 2048, True),
+    "evabyte-summaries": (8, 32, 32, 16, 896, True),
+    "qwen2": (16, 28, 4, 28, 1024, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVED))
+def test_the_kernel_compiles_for_the_v5e_on_rows_as_they_rest(
+        cell, one_chip, monkeypatch):
+    """The stacks in the layout the v5e holds them in (a row one or two
+    whole bfloat16 tiles; qwen2's four heads a tile of four sublanes), a
+    block landed as ``[128, Hkv, 128]`` and read as ``[128 x Hkv, 128]``:
+    Mosaic takes the kernel, and the program around it holds no temporary
+    the size of a layer."""
+    monkeypatch.setattr(FA, "_INTERPRET", False)
+    monkeypatch.setattr(B, "ATTN_BLOCK", 128)
+    s, heads, hkv, layers, n, stats = SERVED[cell]
+
+    def read(q, k, v, at, lengths, active):
+        own = B.attn_blocks(lengths, active, 1, n, jnp, per_slot=True)
+        plan = FA.read_plan(own, lengths + 1, n // 128)
+        return FA.slot_attention(q, k, v, at, plan, rows=128, hkv=hkv,
+                                 stats=stats)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stack = arg((layers, s, n, hkv, 128), jnp.bfloat16)
+    compiled = jax.jit(read).lower(
+        arg((s, heads, 128), jnp.bfloat16), stack, stack, arg((), jnp.int32),
+        arg((s,), jnp.int32), arg((s,), bool)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "slot_attention" in compiled.as_text()
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < s * n * hkv * 128 * 2)

@@ -107,7 +107,7 @@ def rows_of(ex):
 
 
 # The decode programs that read a folded layer by the kernel
-# (`ops.folded_attention`): one new row a slot, no rider group beside them,
+# (`ops.slot_attention`): one new row a slot, no rider group beside them,
 # no softcap, no window. A verify step (three rows a slot), gemma2 and a
 # burst with a rider lane keep the loop over the blocks.
 KERNEL_READS = {"gpt2": {"burst", "step"}, "qwen2": {"burst", "step"},
@@ -131,8 +131,14 @@ def reads_by_kernel(ex):
     programs = {"burst": (ex._burst_jits[TICKS], burst),
                 "step": (ex._decode_jits[1], step(1)),
                 "verify": (ex._decode_jits[3], step(3))}
+    from test_batching import _all_eqns
+
+    # By the equation, not by a name in the text: a cached inner jaxpr
+    # (`jnp.pad`'s) keeps the source line of whoever traced it first.
     return {name for name, (fn, args) in programs.items()
-            if "folded_attention" in fn.lower(*args).as_text(debug_info=True)}
+            if any(e.primitive.name == "pallas_call"
+                   and e.params["name"] == "slot_attention"
+                   for e in _all_eqns(jax.make_jaxpr(fn)(*args).jaxpr))}
 
 
 def drive(ex):
