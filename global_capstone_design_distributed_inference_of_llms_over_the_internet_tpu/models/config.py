@@ -43,12 +43,6 @@ class ModelConfig:
     rope_scaling: Optional[tuple] = None
     norm_eps: float = 1e-5
     sliding_window: Optional[int] = None  # mistral
-    # Decode-step KV paging (ops.attention.paged_decode_attention): > 0
-    # makes T == 1 steps read only cache pages holding real rows (online-
-    # softmax over a dynamic page count) instead of streaming the whole
-    # static bucket — HBM reads then track occupancy (docs/PERFORMANCE.md
-    # "Paged KV reads"). 0 = one-pass attention.
-    decode_kv_page: int = 0
 
     # MoE (mixtral)
     num_experts: int = 0

@@ -454,21 +454,11 @@ def _attention(
         )
     else:
         k_cache, v_cache = update_kv_cache(k_cache, v_cache, k, v, cache_len)
-        if (cfg.decode_kv_page and t == 1 and window is None
-                and not cfg.attn_softcap and not cfg.query_scale
-                and k_cache.shape[1] % cfg.decode_kv_page == 0):
-            # Occupancy-tracking decode reads (VERDICT r4 item 5): only
-            # pages holding real rows stream from HBM.
-            from ..ops.attention import paged_decode_attention
-
-            out = paged_decode_attention(q, k_cache, v_cache, cache_len,
-                                         cfg.decode_kv_page)
-        else:
-            out = cached_attention(
-                q, k_cache, v_cache, cache_len,
-                sliding_window=window,
-                scale=cfg.query_scale, logit_softcap=cfg.attn_softcap,
-            )
+        out = cached_attention(
+            q, k_cache, v_cache, cache_len,
+            sliding_window=window,
+            scale=cfg.query_scale, logit_softcap=cfg.attn_softcap,
+        )
     y = _dot(out.reshape(b, t, h_local * dh), p["wo"])
     y = _psum_if(y, tp_axis)
     if "bo" in p:

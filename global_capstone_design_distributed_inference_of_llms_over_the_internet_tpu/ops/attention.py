@@ -55,63 +55,6 @@ def update_kv_cache(
     return k_cache, v_cache
 
 
-def paged_decode_attention(
-    q: jnp.ndarray,
-    k_cache: jnp.ndarray,
-    v_cache: jnp.ndarray,
-    cache_len: jnp.ndarray,
-    page: int,
-) -> jnp.ndarray:
-    """Single-token decode attention whose HBM reads track OCCUPANCY.
-
-    `cached_attention` streams the whole static cache bucket every step,
-    occupied or not (a 512-row bucket at a mean occupancy of 288 reads
-    ~1.8x the occupied rows). This variant runs the classic online-softmax (flash) accumulation over
-    PAGES of the cache with a DYNAMIC trip count ``ceil((cache_len+1)/
-    page)`` — lax.fori_loop with a traced bound — so a step reads only
-    pages holding real rows. Same math: fp32 running max/denominator,
-    masked tail page; bitwise it differs from one-pass softmax only in
-    accumulation order.
-
-    q: [B, 1, H, Dh]; k_cache/v_cache: [B, S, Hkv, Dh] with the new key
-    already written at position cache_len; S % page must be 0 (the jit
-    caller pads the bucket). Returns [B, 1, H, Dh].
-    """
-    b, t, h, dh = q.shape
-    assert t == 1, "paged path is the T == 1 decode step"
-    s = k_cache.shape[1]
-    hkv = k_cache.shape[2]
-    groups = h // hkv
-    if s % page:
-        raise ValueError(f"cache bucket {s} not divisible by page {page}")
-    qg = (q * (dh ** -0.5)).reshape(b, hkv, groups, dh)
-    n_pages = (cache_len + page) // page   # keys 0..cache_len inclusive
-
-    def body(j, carry):
-        m, l, acc = carry
-        kp = jax.lax.dynamic_slice_in_dim(k_cache, j * page, page, axis=1)
-        vp = jax.lax.dynamic_slice_in_dim(v_cache, j * page, page, axis=1)
-        sc = jnp.einsum("bhgd,bphd->bhgp", qg, kp,
-                        preferred_element_type=jnp.float32)
-        pos = j * page + jnp.arange(page, dtype=jnp.int32)
-        sc = jnp.where((pos <= cache_len)[None, None, None, :], sc, NEG_INF)
-        m2 = jnp.maximum(m, sc.max(-1))
-        corr = jnp.exp(m - m2)
-        w = jnp.exp(sc - m2[..., None])
-        l2 = l * corr + w.sum(-1)
-        acc2 = acc * corr[..., None] + jnp.einsum(
-            "bhgp,bphd->bhgd", w.astype(vp.dtype), vp,
-            preferred_element_type=jnp.float32)
-        return m2, l2, acc2
-
-    m0 = jnp.full((b, hkv, groups), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((b, hkv, groups), jnp.float32)
-    acc0 = jnp.zeros((b, hkv, groups, dh), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, acc0))
-    out = acc / jnp.maximum(l, 1e-20)[..., None]
-    return out.reshape(b, 1, h, dh).astype(q.dtype)
-
-
 def cached_attention(
     q: jnp.ndarray,
     k_cache: jnp.ndarray,

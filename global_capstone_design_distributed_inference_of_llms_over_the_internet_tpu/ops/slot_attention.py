@@ -2,10 +2,10 @@
 read up to its own last block.
 
 A decode step has one query row a slot, and the slots differ in length. XLA
-can bound a read by a value only through a loop whose trips each cost a few
-microseconds and whose running sum goes through HBM, or through a
-``switch`` over static prefixes: either way its read stops where the
-LONGEST active slot stops, for every slot, active or not. This kernel takes
+bounds a read by a value through a loop whose trips each cost a few
+microseconds and whose running sum goes through HBM
+(`runtime.batching._block_stats`), and its read stops where the LONGEST
+active slot stops, for every slot, active or not. This kernel takes
 the slots' OWN block counts and row limits (`read_plan`) and reads, for each
 slot, that slot's blocks and no others:
 

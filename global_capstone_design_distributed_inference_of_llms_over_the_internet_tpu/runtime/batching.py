@@ -362,25 +362,22 @@ def cache_read(cfg, layers, folded: bool, t: int = 1,
     cache layer (`_attend_cached`, `_attend_windowed`), by what it is handed
     alone: ``folded`` stacks (`kv_fold_width`), the configuration and the
     layer tree ``layers``, a ``rider`` group beside the slots' rows.
-    ``"kernel"``: `ops.slot_attention`, each slot up to its own last
-    block; it has one query row a slot and no mask but a row limit, so a
-    step of several rows, softcapped scores or a masked window of any kind
-    keep the ``"loop"`` over the blocks up to the longest active slot (a
-    folded stack beside a rider lane too: no engine holds one). Rows that
-    stay ``[Hkv, Dh]`` go by the kernel where it is the chip's and a row
-    fills its lanes (``Dh`` whole tiles of 128: `kernel_engaged`; every
-    other backend keeps the program it had); else by that loop where
-    several query rows share a KV head, else by a ``"switch"`` over static
-    prefixes. The programs, the counter of the rows they read
-    (`_count_attn_rows`) and the ``kv_layout`` event all ask here."""
+    ``"kernel"`` (`ops.slot_attention`, each slot up to its own last block)
+    where the kernel takes the input, else the ``"loop"`` over the blocks up
+    to the longest active slot (`_block_stats`). The kernel has one query
+    row a slot and no mask but a row limit, so a step of several rows,
+    softcapped scores or a masked window of any kind are the loop's (a
+    folded stack beside a rider lane too: no engine holds one); rows that
+    stay ``[Hkv, Dh]`` are the kernel's where it is the chip's and a row
+    fills its lanes (``Dh`` whole tiles of 128: `kernel_engaged`). The
+    programs, the counter of the rows they read (`_count_attn_rows`) and
+    the ``kv_layout`` event all ask here."""
     plain = not (cfg.attn_softcap or cfg.sliding_window
                  or "window" in layers)
     if folded:
         return "kernel" if t == 1 and plain and not rider else "loop"
     if t == 1 and plain and cfg.head_dim % 128 == 0 and kernel_engaged():
         return "kernel"
-    if cfg.eva_window or t * (cfg.num_heads // cfg.num_kv_heads) == 1:
-        return "switch"
     return "loop"
 
 
@@ -457,12 +454,53 @@ class _CacheLayer:
     at: Any
     blocks: Any
 
-    def rows(self, start, n: int):
-        """Rows ``[start, start + n)`` of every slot: ``[S, n, Hkv, Dh]``
-        (folded: ``[S, n, W]``)."""
-        return jax.lax.dynamic_slice(
-            self.stack, _origin(self.stack, self.at, 0, start),
-            (1, self.stack.shape[1], n) + self.stack.shape[3:])[0]
+
+def _block_stats(cfg, lp, qg, keys, values, q_pos, allowed_of):
+    """The softmax of the grouped, scaled queries ``qg`` (``[S, T, Hkv, G,
+    Dh]``; against FOLDED stacks ``[S, T, 1, H, W]``) over the first
+    ``keys.blocks`` blocks of `attn_block` rows of the cache layers ``keys``
+    and ``values``, as its running statistics, undivided: ``(m, l, acc)``,
+    the maximum score ``[S, Hkv, G, T]``, the denominator and the weighted
+    sum of the values (``[.., Dh]``), float32. ``allowed_of(at)``: which of
+    the rows ``at`` (``[1, 1, n]``) each query may see (``[S, T, n]``); the
+    layer's own window (by ``q_pos``) and the softcap are `_masked_scores`'.
+    A loop with a traced trip count, one block-sized operand of each stack
+    a trip (~4 us a block a layer on the v5e: PERF.md section 6, PR 35):
+    no trip with no active slot, none over a stack without rows. The ONE
+    place a block of a stack is sliced for a read that is not the kernel's."""
+    b, t, hkv, groups, dh = qg.shape
+    stat = (b, hkv, groups, t)
+    nothing = (jnp.full(stat, NEG_INF, jnp.float32),
+               jnp.zeros(stat, jnp.float32),
+               jnp.zeros(stat + (dh,), jnp.float32))
+    if not keys.stack.shape[2]:
+        return nothing
+    rows = attn_block(keys.stack.shape[2])
+    folded = keys.stack.ndim == 4
+
+    def read(layer, j):
+        """Block ``j`` of every slot: ``[S, rows, Hkv, Dh]`` (folded: ``[S,
+        rows, 1, W]``)."""
+        got = jax.lax.dynamic_slice(
+            layer.stack, _origin(layer.stack, layer.at, 0, j * rows),
+            (1, layer.stack.shape[1], rows) + layer.stack.shape[3:])[0]
+        return got[:, :, None] if folded else got
+
+    def block(j, carry):
+        m, l, acc = carry
+        k_rows = read(keys, j)
+        at = (j * rows + jnp.arange(rows, dtype=jnp.int32))[None, None, :]
+        sc = _masked_scores(cfg, lp, qg, k_rows, (allowed_of(at), q_pos, at))
+        m2 = jnp.maximum(m, sc.max(-1))
+        corr = jnp.exp(m - m2)
+        w = jnp.exp(sc - m2[..., None])
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bhgts,bshd->bhgtd", w.astype(values.stack.dtype),
+            read(values, j).astype(qg.dtype),
+            preferred_element_type=jnp.float32)
+        return m2, l * corr + w.sum(-1), acc
+
+    return jax.lax.fori_loop(0, keys.blocks, block, nothing)
 
 
 def _attend_cached(cfg, lp, q, keys, values, q_pos):
@@ -471,35 +509,17 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
     only their first ``keys.blocks`` blocks of `attn_block` rows. A row
     past a query's position has probability exactly 0 in `_attend`, so
     leaving the blocks past the longest active slot unread changes no term
-    of any sum. The count is a value of the program: ONE program serves
-    every occupancy. Two forms, by the shape of the products alone (chosen
-    on the v5e: PERF.md section 6, PR 35):
+    of any sum. The count is a value of the program: `_block_stats`' loop,
+    divided here.
 
-    ONE query row a KV head (T = 1 and no grouped queries): the products
-    are matrix-vector, reductions on the VPU that XLA fuses with the slice
-    of the stack. A ``switch`` on the count picks `_attend` over a STATIC
-    prefix of 0, 1, 2, ... blocks: today's fused read, shorter; the last
-    branch is the full read at its old cost, and nothing is paid a block.
-
-    Several query rows a KV head (grouped queries, or T > 1: the
-    speculative verify): the products go to the MXU, which wants its
-    operand in a buffer of its own; inside a conditional's branch XLA
-    re-lays the WHOLE STACK for it (a stack-sized temporary and 1.9 x the
-    qwen2-7b tick). So: a loop with a traced trip count over the blocks,
-    online softmax (float32 running max, denominator and weighted sum),
-    one block-sized operand a trip; ~4 us a block a layer of loop and
-    statistics, which the VPU shapes need not pay.
-
-    FOLDED stacks (``[L, S, max_len, W]``: `kv_fold_width`) take the loop
-    whatever the shape of the products: every query head against ONE key
-    "head" as wide as a folded row. A head's query sits in the lanes of
-    its own KV head and is zero in all others (block-diagonal: the zeros
-    add nothing to a score), and of the ``W`` lanes its weighted sum comes
-    back in, its KV head's are kept. The MXU multiplies ``Hkv`` times the
-    zeros it needs not (31 GFLOP a gpt2-xl tick, 0.2 ms of its peak) and
-    the stack is read ONCE, dense, in the layout it rests in: on the v5e
-    8.16 ms a gpt2-xl tick where `_attend`'s prefixes over ``[.., 25, 64]``
-    rows took 11.48 (PERF.md section 6, PR 45).
+    FOLDED stacks (``[L, S, max_len, W]``: `kv_fold_width`): every query
+    head against ONE key "head" as wide as a folded row. A head's query
+    sits in the lanes of its own KV head and is zero in all others
+    (block-diagonal: the zeros add nothing to a score), and of the ``W``
+    lanes its weighted sum comes back in, its KV head's are kept. The MXU
+    multiplies ``Hkv`` times the zeros it needs not (31 GFLOP a gpt2-xl
+    tick, 0.2 ms of its peak) and the stack is read ONCE, dense, in the
+    layout it rests in (PERF.md section 6, PR 45).
 
     Stacks whose ``blocks`` are a `read_plan` (`cache_read`'s
     ``"kernel"``), folded or not: `ops.slot_attention`, a block as ONE MXU
@@ -510,60 +530,22 @@ def _attend_cached(cfg, lp, q, keys, values, q_pos):
     hkv, dh = cfg.num_kv_heads, cfg.head_dim
     groups = cfg.num_heads // hkv
     folded = keys.stack.ndim == 4
-    max_len = keys.stack.shape[2]
-    rows = attn_block(max_len)
     if keys.blocks.ndim:
         return slot_attention(
             q[:, 0] * _qscale(cfg), keys.stack, values.stack, keys.at,
-            keys.blocks, rows=rows, hkv=hkv)[:, None]
+            keys.blocks, rows=attn_block(keys.stack.shape[2]),
+            hkv=hkv)[:, None]
     out_dtype = jnp.promote_types(values.stack.dtype, q.dtype)  # `_attend`'s
-
-    def grid(start, n):
-        k_pos = (start + jnp.arange(n, dtype=jnp.int32))[None, None, :]
-        return _visible(cfg, q_pos, k_pos), q_pos, k_pos
-
-    if groups * t == 1 and not folded:
-        def prefix(n):
-            if not n:       # no active slot: nothing is read
-                return lambda: jnp.zeros((b, t, hkv * dh), out_dtype)
-            return lambda: _attend(cfg, lp, q, keys.rows(0, n),
-                                   values.rows(0, n), grid(0, n))
-
-        return jax.lax.switch(
-            keys.blocks, [prefix(rows * i)
-                          for i in range(max_len // rows + 1)])
-
     if folded:
         mine = (jnp.arange(cfg.num_heads)[:, None] // groups
                 == jnp.arange(hkv)[None, :])                    # [H, Hkv]
         qg = _fold(keys.stack, q[:, :, :, None]
                    * mine[:, :, None].astype(q.dtype))[:, :, None]
-        hkv, groups, dh = 1, cfg.num_heads, keys.stack.shape[-1]
     else:
         qg = q.reshape(b, t, hkv, groups, dh)
-    qg = qg * _qscale(cfg)          # [S, T, Hkv, G, Dh]; folded [S, T, 1, H, W]
-
-    def read(layer, j):
-        got = layer.rows(j * rows, rows)
-        return got[:, :, None] if folded else got
-
-    def block(j, carry):
-        m, l, acc = carry
-        sc = _masked_scores(cfg, lp, qg, read(keys, j), grid(j * rows, rows))
-        m2 = jnp.maximum(m, sc.max(-1))
-        corr = jnp.exp(m - m2)
-        w = jnp.exp(sc - m2[..., None])
-        acc = acc * corr[..., None] + jnp.einsum(
-            "bhgts,bshd->bhgtd", w.astype(values.stack.dtype),
-            read(values, j).astype(q.dtype),
-            preferred_element_type=jnp.float32)
-        return m2, l * corr + w.sum(-1), acc
-
-    stat = (b, hkv, groups, t)
-    _, l, acc = jax.lax.fori_loop(
-        0, keys.blocks, block,
-        (jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
-         jnp.zeros(stat + (dh,), jnp.float32)))
+    _, l, acc = _block_stats(
+        cfg, lp, qg * _qscale(cfg), keys, values, q_pos,
+        lambda k_pos: _visible(cfg, q_pos, k_pos))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     if folded:
         out = jnp.einsum("bhtkd,hk->bthd",
@@ -673,17 +655,17 @@ def _attend_windowed(cfg, lp, q, keys, values, q_pos):
     W``); the same weights over the values and their summaries.
 
     A decode step (``own`` a `_CacheLayer`, one query row a slot): two
-    bounded reads, each `_attend_cached`'s first form (a ``switch`` on the
-    stack's block count over static prefixes: ``W / 128 + 1`` and ``R /
-    128 + 1`` branches, 17 and 8 at 2048 / 896 rows, whatever the slot's
-    length in positions) or, where the counts are `read_plan`s
-    (`cache_read`'s ``"kernel"``), `ops.slot_attention` over each slot's
-    own blocks; each returning its softmax's running statistics
-    (max, denominator, weighted sum; float32) and not a result; the two
-    are merged as the blocks of an online softmax are, which IS the one
-    softmax over both. The window stack always holds a visible row (the
-    query's own), so a summary read in which a slot sees nothing (its
-    first window) is weighted exp(-1e30 - max) = 0.
+    bounded reads, one of each stack: `_block_stats`' loop over the stack's
+    blocks up to the longest active slot's (at most ``W / 128`` and ``R /
+    128`` trips, 16 and 7 at 2048 / 896 rows, whatever the slot's length
+    in positions) or, where the counts are `read_plan`s (`cache_read`'s
+    ``"kernel"``), `ops.slot_attention` over each slot's own blocks; each
+    returning its softmax's running statistics (max, denominator, weighted
+    sum; float32) and not a result; the two are merged as the blocks of an
+    online softmax are, which IS the one softmax over both. The window
+    stack always holds a visible row (the query's own), so a summary read
+    in which a slot sees nothing (its first window) is weighted
+    exp(-1e30 - max) = 0.
 
     A prefill (``own`` the fresh rows): queries in blocks of
     `PREFILL_QUERY_ROWS`, each one `_attend` over the window's fresh keys
@@ -717,43 +699,15 @@ def _attend_windowed(cfg, lp, q, keys, values, q_pos):
     def stats(k_layer, v_layer, allowed_of):
         """Softmax statistics over the first ``k_layer.blocks`` blocks."""
         rows_all = k_layer.stack.shape[2]
-        stat = (b, hkv, groups, t)
-        nothing = (jnp.full(stat, NEG_INF, jnp.float32),
-                   jnp.zeros(stat, jnp.float32),
-                   jnp.zeros(stat + (dh,), jnp.float32))
-        if not rows_all:
-            return nothing
-        rows = attn_block(rows_all)
-        if k_layer.blocks.ndim:     # a `read_plan`, the limits in it
+        if k_layer.blocks.ndim and rows_all:  # a `read_plan`, the limits in it
+            stat = (b, hkv, groups, t)
             m, l, acc = slot_attention(
                 qg[:, 0].reshape(b, -1, dh), k_layer.stack, v_layer.stack,
-                k_layer.at, k_layer.blocks, rows=rows, hkv=hkv, stats=True)
-            return m.reshape(stat), l.reshape(stat), acc.reshape(stat + (dh,))
-
-        def prefix(n):
-            if not n:
-                return lambda: nothing
-
-            def read():
-                at = jnp.arange(n, dtype=jnp.int32)[None, None, :]
-                sc = jnp.einsum(
-                    "bthgd,bshd->bhgts", qg,
-                    k_layer.rows(0, n).astype(qg.dtype),
-                    preferred_element_type=jnp.float32)
-                sc = jnp.where(allowed_of(at)[:, None, None], sc, NEG_INF)
-                m = sc.max(-1)
-                p = jnp.exp(sc - m[..., None])
-                acc = jnp.einsum(
-                    "bhgts,bshd->bhgtd", p.astype(v_layer.stack.dtype),
-                    v_layer.rows(0, n).astype(q.dtype),
-                    preferred_element_type=jnp.float32)
-                return m, p.sum(-1), acc
-
-            return read
-
-        return jax.lax.switch(
-            k_layer.blocks,
-            [prefix(rows * i) for i in range(rows_all // rows + 1)])
+                k_layer.at, k_layer.blocks, rows=attn_block(rows_all),
+                hkv=hkv, stats=True)
+            return (m.reshape(stat), l.reshape(stat),
+                    acc.reshape(stat + (dh,)))
+        return _block_stats(cfg, lp, qg, k_layer, v_layer, q_pos, allowed_of)
 
     m1, l1, a1 = stats(keys.own, values.own,
                        lambda at: at <= q_pos % w_rows)
@@ -2815,70 +2769,98 @@ class BatchingStageAdapter:
                     f"server {cur} (stale retry?)")
         return None
 
-    def _decode(self, req):
+    def _round(self, req, key, validate, step, rider: bool = False) -> _Round:
+        """Take ``req`` through one round of ``key`` (a step's width T, or
+        ``("burst", N)``) as its leader (whoever CREATES it) or a follower,
+        and return the round once its step has run; raises what the round
+        or this session failed with. The ONE leader/follower machine. Its
+        caller states ``validate(rq)``, a member's admission under the lock
+        (a refusal reason or None; the leader asks again once the round has
+        closed: a session may have been dropped since it joined, and an
+        exclusion fails ONLY its own waiter), and ``step(r, good, riding)``,
+        the engine's call on the admitted members and the rider's entry (or
+        None) into ``r.outs``, under the lock: ``(back, parts)``, what
+        `_answered` takes. ``rider``: ``req`` is a PREFILL that joins a
+        burst round as its rider (`_rides`); a round carries one, a second
+        waits for that round to run and tries the next."""
         from .executor import StageExecutionError
-        from .messages import StageResponse
 
         sid = req.session_id
-        t = req.seq_len
         t_join = time.monotonic()
-        with self._lock:
-            reason = self._validate(req)
-            if reason is not None:
-                raise StageExecutionError(reason)
-            r = self._rounds.get(t)
-            if r is None or r.closed:
-                r = self._rounds[t] = _Round()
-                leader = True       # explicit: whoever CREATES the round
-            else:
-                leader = False
-            if sid in r.reqs:
-                raise StageExecutionError(
-                    f"session {sid}: concurrent decode for one session")
-            r.reqs[sid] = req
-            self._join(r, req, t)
+        while True:
+            with self._lock:
+                reason = None if rider else validate(req)
+                if reason is not None:
+                    raise StageExecutionError(reason)
+                r = self._rounds.get(key)
+                leader = r is None or r.closed
+                if leader:
+                    r = self._rounds[key] = _Round()
+                if not rider:
+                    if sid in r.reqs:
+                        raise StageExecutionError(
+                            f"session {sid}: concurrent decode for one "
+                            "session")
+                    r.reqs[sid] = req
+                    self._join(r, req, key)
+                    break
+                if r.rider is None:
+                    r.rider = req
+                    self._forget_locked(sid)
+                    break
+            if not r.event.wait(self.step_timeout):   # the lane is taken
+                raise StageExecutionError("batched step timed out")
         if leader:
             # The whole leader path runs under try/finally: an unexpected
-            # exception anywhere (not just inside decode_batch) must still
-            # release the followers, else they block for step_timeout.
+            # exception anywhere (not just inside the engine's call) must
+            # still release the followers, else they block for step_timeout.
             try:
                 with self._lock:
-                    self._close_round(r, t, sid)
-                    # Re-validate under the lock: a session may have been
-                    # dropped (or otherwise invalidated) since it joined.
-                    # Exclusions fail ONLY their own waiter.
+                    self._close_round(r, key, sid)
                     good = {}
                     for s_id, rq in r.reqs.items():
-                        reason = self._validate(rq)
+                        reason = validate(rq)
                         if reason is None:
                             good[s_id] = rq
                         else:
                             r.bad[s_id] = reason
-                    if good:
-                        self._step_starts(r, t)
-                        self._m_fill.observe(len(good))
-                        self._m_held.observe(len(self.inner._slot_of))
-                        r.outs = self.inner.decode_batch(
-                            {s_id: rq.hidden for s_id, rq in good.items()})
-                        if self.spec.is_last:
-                            self._verify_spec_rows(r, good)
+                    riding = None
+                    if r.rider is not None:
+                        reason = self._validate_rider(r, key[1])
+                        if reason is None:
+                            riding = _rider_entry(r.rider,
+                                                  self._m_ids_read)
+                        else:
+                            r.bad[r.rider.session_id] = reason
+                    if good or riding:
+                        self._step_starts(r, key)
+                        if good:
+                            self._m_fill.observe(len(good))
+                            self._m_held.observe(len(self.inner._slot_of))
+                        back, parts = step(r, good, riding)
                         r.lengths = {
-                            s_id: int(self.inner.lengths[self.inner.slot(s_id)])
+                            s_id: int(
+                                self.inner.lengths[self.inner.slot(s_id)])
                             for s_id in good
                         }
-                        # a step's reply never says it was the request's last
-                        self._answered(r, t, good)
+                        self._answered(r, key, back, parts)
+                        if self._events.enabled and isinstance(key, tuple):
+                            self._events.emit(
+                                "burst_round", sessions=len(good),
+                                ticks=key[1],
+                                tokens=sum(len(r.outs[s_id]["tokens"])
+                                           for s_id in good))
             except Exception as exc:  # whole-round failure
                 r.err = exc
                 with self._lock:  # a dead round must not accept joiners
                     r.closed = True
-                    if self._rounds.get(t) is r:
-                        del self._rounds[t]
+                    if self._rounds.get(key) is r:
+                        del self._rounds[key]
             finally:
                 r.event.set()
         elif not r.event.wait(self.step_timeout):
             raise StageExecutionError("batched step timed out")
-        if r.t_exec:
+        if r.t_exec and not rider:
             # Time this session spent parked before its round's step ran —
             # the coalescing window for the leader, window + leader overhead
             # for followers.
@@ -2887,6 +2869,22 @@ class BatchingStageAdapter:
             raise StageExecutionError(str(r.err)) from r.err
         if sid in r.bad:
             raise StageExecutionError(r.bad[sid])
+        return r
+
+    def _decode(self, req):
+        """A step of width T = ``req.seq_len`` (plain decode, a speculative
+        verify, a replay chunk) through the round of that width."""
+        from .messages import StageResponse
+
+        def step(r, good, riding):
+            r.outs = self.inner.decode_batch(
+                {s_id: rq.hidden for s_id, rq in good.items()})
+            if self.spec.is_last:
+                self._verify_spec_rows(r, good)
+            return good, None   # a step's reply never says it was the last
+
+        sid = req.session_id
+        r = self._round(req, req.seq_len, self._validate, step)
         if sid in r.spec:
             tokens, n_acc = r.spec[sid]
             return self._stamped(r, StageResponse(
@@ -2912,11 +2910,9 @@ class BatchingStageAdapter:
         return None
 
     def _decode_burst(self, req):
-        """Coalesce concurrent burst requests into ONE N-tick dispatch —
-        the same leader/follower round machinery as ``_decode``, keyed by
-        ('burst', N) so classic single-tick rounds and burst rounds never
-        mix widths. Sessions join/leave only at round (= burst)
-        boundaries."""
+        """Concurrent burst requests coalesce into ONE N-tick dispatch: a
+        round keyed by ('burst', N), so that single-tick rounds and bursts
+        never mix widths. Sessions join and leave at round boundaries."""
         from .messages import StageResponse
 
         sid = req.session_id
@@ -2927,110 +2923,29 @@ class BatchingStageAdapter:
             burst_stop=out["stop"], cache_len=r.lengths[sid]))
 
     def _burst_round(self, req, n: int, rider: bool = False) -> _Round:
-        """Take ``req`` through one burst round of ``n`` ticks, as its leader
-        (whoever creates it) or a follower, and return the round once its
-        step has run; raises what the round or this session failed with.
-        ``rider``: ``req`` is a PREFILL that joins as the round's rider
-        (`_rides`). A round carries one; a second waits for that round to
-        run and tries the next."""
-        from .executor import StageExecutionError
+        """``req`` through the burst round of ``n`` ticks: a member
+        (`_decode_burst`) or, ``rider``, the prefill that rides it
+        (`_prefill_riding`)."""
 
-        sid = req.session_id
-        key = ("burst", n)
-        t_join = time.monotonic()
-        while True:
-            with self._lock:
-                reason = (None if rider else
-                          self._validate(req) or self._validate_burst(req))
-                if reason is not None:
-                    raise StageExecutionError(reason)
-                r = self._rounds.get(key)
-                if r is None or r.closed:
-                    r = self._rounds[key] = _Round()
-                    leader = True
-                else:
-                    leader = False
-                if not rider:
-                    if sid in r.reqs:
-                        raise StageExecutionError(
-                            f"session {sid}: concurrent decode for one "
-                            "session")
-                    r.reqs[sid] = req
-                    self._join(r, req, key)
-                    break
-                if r.rider is None:
-                    r.rider = req
-                    self._forget_locked(sid)
-                    break
-            if not r.event.wait(self.step_timeout):   # the lane is taken
-                raise StageExecutionError("batched step timed out")
-        if leader:
-            try:
-                with self._lock:
-                    self._close_round(r, key, sid)
-                    good = {}
-                    for s_id, rq in r.reqs.items():
-                        reason = (self._validate(rq)
-                                  or self._validate_burst(rq))
-                        if reason is None:
-                            good[s_id] = rq
-                        else:
-                            r.bad[s_id] = reason
-                    riding = None
-                    if r.rider is not None:
-                        reason = self._validate_rider(r, n)
-                        if reason is None:
-                            riding = _rider_entry(r.rider,
-                                                  self._m_ids_read)
-                        else:
-                            r.bad[r.rider.session_id] = reason
-                    if good or riding:
-                        self._step_starts(r, key)
-                        if good:
-                            self._m_fill.observe(len(good))
-                            self._m_held.observe(len(self.inner._slot_of))
-                        r.outs = self.inner.decode_burst(
-                            {s_id: _burst_entry(rq, self._m_ids_read)
-                             for s_id, rq in good.items()}, n, rider=riding)
-                        r.lengths = {
-                            s_id: int(
-                                self.inner.lengths[self.inner.slot(s_id)])
-                            for s_id in good
-                        }
-                        # Back for the next round: the rider, with its
-                        # first token, and every session whose burst did
-                        # not end its request (no stop, and a budget of a
-                        # whole burst: a client asks for min(burst, tokens
-                        # still wanted), so a short one is its last).
-                        back = [s_id for s_id, rq in good.items()
-                                if r.outs[s_id]["stop"] is None
-                                and rq.burst_budget >= rq.burst_len]
-                        if riding:
-                            back.append(riding["session_id"])
-                        self._answered(r, key, back,
-                                       self.inner.burst_parts)
-                        if self._events.enabled:
-                            self._events.emit(
-                                "burst_round", sessions=len(good), ticks=n,
-                                tokens=sum(len(r.outs[s_id]["tokens"])
-                                           for s_id in good))
-            except Exception as exc:  # whole-round failure
-                r.err = exc
-                with self._lock:
-                    r.closed = True
-                    if self._rounds.get(key) is r:
-                        del self._rounds[key]
-            finally:
-                r.event.set()
-        elif not r.event.wait(self.step_timeout):
-            raise StageExecutionError("batched step timed out")
-        if r.t_exec and not rider:
-            self._m_queue_wait.observe(max(0.0, r.t_exec - t_join))
-        if r.err is not None:
-            raise StageExecutionError(str(r.err)) from r.err
-        if sid in r.bad:
-            raise StageExecutionError(r.bad[sid])
-        return r
+        def validate(rq):
+            return self._validate(rq) or self._validate_burst(rq)
+
+        def step(r, good, riding):
+            r.outs = self.inner.decode_burst(
+                {s_id: _burst_entry(rq, self._m_ids_read)
+                 for s_id, rq in good.items()}, n, rider=riding)
+            # Back for the next round: the rider, with its first token, and
+            # every session whose burst did not end its request (no stop,
+            # and a budget of a whole burst: a client asks for min(burst,
+            # tokens still wanted), so a short one is its last).
+            back = [s_id for s_id, rq in good.items()
+                    if r.outs[s_id]["stop"] is None
+                    and rq.burst_budget >= rq.burst_len]
+            if riding:
+                back.append(riding["session_id"])
+            return back, self.inner.burst_parts
+
+        return self._round(req, ("burst", n), validate, step, rider)
 
     def _verify_spec_rows(self, r: _Round, good: Dict[str, Any]) -> None:
         """Per-row speculative verification on the final stage (caller holds

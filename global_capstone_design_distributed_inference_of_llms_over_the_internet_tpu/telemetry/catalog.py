@@ -272,14 +272,14 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
     "server_attn_rows_read_total": (
         COUNTER, "Rows of ONE cache layer that the batched engine's decode "
                  "steps and burst ticks read: per tick, the blocks up to "
-                 "the longest active slot (runtime.batching.attn_blocks) "
-                 "x the block's rows x slots; where the program reads by "
-                 "the kernel (ops.slot_attention: one new row a slot, over "
-                 "folded rows or, on a TPU, rows whose head_dim fills the "
-                 "lanes; runtime.batching.cache_read), the SUM of the "
-                 "slots' own blocks x the block's rows, an idle slot none; "
-                 "counted on the host from the lengths a step began and "
-                 "ended with.", (), None),
+                 "the longest active slot (runtime.batching.attn_blocks: "
+                 "the loop) x the block's rows x slots; where the program "
+                 "reads by the kernel (ops.slot_attention: one new row a "
+                 "slot, over folded rows or, on a TPU, rows whose head_dim "
+                 "fills the lanes; runtime.batching.cache_read), the SUM "
+                 "of the slots' own blocks x the block's rows, an idle "
+                 "slot none; counted on the host from the lengths a step "
+                 "began and ended with.", (), None),
     "server_attn_rows_span_total": (
         COUNTER, "Rows of one cache layer those ticks would read in full: "
                  "ticks x slots x max_session_len. server_attn_rows_read_"
@@ -290,8 +290,9 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "the family's older rows are summaries: per tick, the "
                  "blocks of the summary stack up to the most earlier "
                  "windows of an active slot (runtime.batching."
-                 "windowed_blocks) x the block's rows x slots; where the "
-                 "program reads by the kernel (runtime.batching.cache_read) "
+                 "windowed_blocks: the loop) x the block's rows x slots; "
+                 "where the program reads by the kernel "
+                 "(runtime.batching.cache_read) "
                  "the SUM of the slots' own summary blocks x the block's "
                  "rows, a slot in its first window none. "
                  "server_attn_rows_read_total keeps the exact rows.",

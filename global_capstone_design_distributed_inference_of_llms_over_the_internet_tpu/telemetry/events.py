@@ -206,8 +206,8 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "which the engine's burst ticks read a cache layer "
         "(runtime.batching.cache_read): kernel (ops.slot_attention, "
         "each slot up to its own last block: folded rows anywhere, rows "
-        "whose head_dim fills the lanes on a TPU, one query row a KV "
-        "head), loop or switch (both up to the longest active slot); "
+        "whose head_dim fills the lanes on a TPU) or loop (every slot up "
+        "to the longest active slot's last block); "
         "logical_bytes_a_stack; resident_bytes_a_stack = as laid out, "
         "with the padding of its tiles; for a family whose older rows are "
         "summaries also rows = [exact rows, summary rows] a slot and "

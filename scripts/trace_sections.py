@@ -93,10 +93,10 @@ def _short(name: str) -> str:
 def _control_flow_scopes(order, scope_of) -> dict:
     """Scopes for the operations that hold others and carry no scope name
     of their own: a ``conditional`` or a ``while`` whose nested operations
-    ALL sit in one scope belongs to it (the ``switch`` over block counts
-    and the loop over blocks of `runtime.batching._attend_cached` are
-    ``attention``; the layer scan's ``while`` holds every scope and stays
-    unscoped). ``order`` is sorted by start, longest first."""
+    ALL sit in one scope belongs to it (the loop over blocks of
+    `runtime.batching._block_stats` is ``attention``; the layer scan's
+    ``while`` holds every scope and stays unscoped). ``order`` is sorted by
+    start, longest first."""
     held = collections.defaultdict(set)         # metadata id -> scopes
     stack = []                                  # (end, metadata id)
     for mid, s, d in order:

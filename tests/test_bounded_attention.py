@@ -148,6 +148,65 @@ def test_rows_past_the_bound_are_never_read(small_blocks, t):
     assert np.isfinite(np.asarray(nobody)).all()
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("slots", ["one-idle", "none-active"])
+def test_the_loop_s_statistics_are_the_full_read_s(small_blocks, slots,
+                                                   dtype):
+    """`_block_stats`, the one read beside the kernel, at ONE query row a
+    KV head, over stacks that are NaN past the bound: the maximum, the
+    denominator and the weighted sum of `_attend`'s softmax over the dense
+    rows, undivided (float32 to 2e-6, bfloat16 to its rounding), on every
+    active slot beside an idle one parked past the bound; with NO active
+    slot the loop makes no trip: ``NEG_INF``, zeros, and nothing read."""
+    cfg = head_cfg()
+    lengths, active = slots_for(1)
+    if slots == "none-active":
+        active = jnp.zeros_like(active)
+    s = lengths.shape[0]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(kq, (s, 1, 2, 8)).astype(dtype)
+    k = jax.random.normal(kk, (2, s, MAX_LEN, 2, 8)).astype(dtype)
+    v = jax.random.normal(kv, (2, s, MAX_LEN, 2, 8)).astype(dtype)
+    q_pos = lengths[:, None, None]
+    bound = int(B.attn_blocks(np.asarray(lengths), np.asarray(active), 1,
+                              MAX_LEN)) * BLOCK
+    assert bound == (2 * BLOCK if slots == "one-idle" else 0)
+
+    qg = q.reshape(s, 1, 2, 1, 8) * B._qscale(cfg)
+
+    @jax.jit
+    def stats(qg, k, v):
+        blocks = B.attn_blocks(lengths, active, 1, MAX_LEN, jnp)
+        return B._block_stats(
+            cfg, {}, qg, B._CacheLayer(k, jnp.int32(1), blocks),
+            B._CacheLayer(v, jnp.int32(1), blocks), q_pos,
+            lambda k_pos: B._visible(cfg, q_pos, k_pos))
+
+    m, l, acc = stats(qg, k.at[:, :, bound:].set(jnp.nan),
+                      v.at[:, :, bound:].set(jnp.nan))
+    assert m.shape == l.shape == (s, 2, 1, 1) and acc.shape == (s, 2, 1, 1, 8)
+    assert m.dtype == l.dtype == acc.dtype == jnp.float32
+    if slots == "none-active":
+        assert (np.asarray(m) == B.NEG_INF).all()
+        assert not np.asarray(l).any() and not np.asarray(acc).any()
+        return
+    live = np.asarray(active)
+    tol = 2e-6 if dtype == "float32" else 2e-2
+    k_pos = jnp.arange(MAX_LEN, dtype=jnp.int32)[None, None, :]
+    sc = np.asarray(B._masked_scores(
+        cfg, {}, qg, k[1],
+        (B._visible(cfg, q_pos, k_pos), q_pos, k_pos)))      # [S, 2, 1, 1, 32]
+    np.testing.assert_array_equal(np.asarray(m)[live], sc.max(-1)[live])
+    np.testing.assert_allclose(
+        np.asarray(l)[live], np.exp(sc - sc.max(-1, keepdims=True))
+        .sum(-1)[live], atol=tol, rtol=tol)
+    want = full_read(cfg, {}, q, k[1], v[1], q_pos)          # [S, 1, 2 * 8]
+    got = (acc / l[..., None]).transpose(0, 3, 1, 2, 4).reshape(s, 1, -1)
+    np.testing.assert_allclose(
+        np.asarray(got)[live], np.asarray(want, np.float32)[live],
+        atol=tol, rtol=tol)
+
+
 @pytest.mark.parametrize("per_slot", [False, True])
 def test_the_bound_is_the_longest_active_slot(small_blocks, per_slot):
     """`attn_blocks`, the one statement of the bound, on the host's arrays
@@ -333,8 +392,7 @@ def test_a_looped_burst_with_a_rider_reads_its_slots_by_the_kernel(
     the kernel engaged: the slots' group of a burst tick goes by the kernel
     BESIDE the rider's rows, which keep their slice of one slot. The tokens
     of the two decoding sessions and the rider's first are those of the
-    step path (a twin engine that reads by the ``switch``), the stacks
-    agree, and the counter reads the slots' OWN blocks: x begins the four
+    step path (a twin engine that reads by the loop), the stacks agree, and the counter reads the slots' OWN blocks: x begins the four
     ticks at 8..11 rows and y at 12..15, two 8-row blocks each a tick = 16
     blocks of 8 rows, where the shared bound reads 2 x 8 x 3 slots x 4."""
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
@@ -352,7 +410,7 @@ def test_a_looped_burst_with_a_rider_reads_its_slots_by_the_kernel(
             monkeypatch.setattr(slot_attention, "_INTERPRET", hook)
             _, _, eng = build(ref, hf=hf)
             assert eng._cache_read(1, True) == (
-                "kernel" if hook else "switch")
+                "kernel" if hook else "loop")
             r0 = read.value
             got = eng.decode_burst(two_decoding(eng), 4,
                                    rider=rider_of("r", ids_of(21, 3)))
@@ -381,24 +439,22 @@ def _in_scans(jaxpr, names, depth=0):
                         sub, names, depth + (e.primitive.name == "scan"))
 
 
-@pytest.mark.parametrize("family,program,form", [
-    ("qwen2", "burst_tick", "loop"), ("qwen2", "decode_step-1", "loop"),
-    ("gpt2", "decode_step-3", "loop"), ("gpt2", "burst_tick", "switch"),
-    ("gpt2", "decode_step-1", "switch"), ("looped", "burst_tick", "switch")])
+@pytest.mark.parametrize("family,program", [
+    ("qwen2", "burst_tick"), ("qwen2", "decode_step-1"),
+    ("gpt2", "decode_step-3"), ("gpt2", "burst_tick"),
+    ("gpt2", "decode_step-1"), ("looped", "burst_tick")])
 def test_the_program_bounds_its_read_inside_the_layer_scan(
-        small_blocks, ref, family, program, form):
+        small_blocks, ref, family, program):
     """ONE program a tick count or a step width, whatever the lengths. In
     its jaxpr the read of a cache layer sits inside the layer scan (inside
     the tick scan in a burst, inside the pass scan of a looped stack) and
-    is bounded by a TRACED value there: several query rows a KV head
-    (grouped queries, T > 1): ONE loop with a traced trip count that
+    is bounded by a TRACED value there, whether several query rows share a
+    KV head (grouped queries, T > 1) or each has its own (gpt2, a looped
+    stack beside its rider lane): ONE loop with a traced trip count that
     carries the softmax statistics and never a stack or a layer, around two
-    block-sized slices of the carried stacks, and no equation anywhere
-    takes or yields a layer's ``[S, max_len, Hkv, Dh]``; one query row a KV
-    head: ONE conditional on the block count with a branch for every
-    count 0 .. ``max_len / block``, branch n slicing n blocks of K and of V
-    out of the carried stacks (the last alone yields a whole layer: the
-    full read as it was). No write takes a slab in either."""
+    block-sized slices of the carried stacks; no conditional with a branch
+    a block count, and no equation anywhere takes or yields a layer's
+    ``[S, max_len, Hkv, Dh]``. No write takes a slab."""
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
         RECENT_WINDOW,
     )
@@ -423,7 +479,6 @@ def test_the_program_bounds_its_read_inside_the_layer_scan(
         fn, args = ex._build_decode(int(program[-1])), [
             ex.params, i32(S, int(program[-1])), i32(S), on, ex.k, ex.v]
     jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
-    n_blocks = ex.max_len // BLOCK
     reads = sorted(
         e.outvars[0].aval.shape[2] for e in _all_eqns(jaxpr)
         if e.primitive.name == "dynamic_slice"
@@ -432,23 +487,16 @@ def test_the_program_bounds_its_read_inside_the_layer_scan(
     writes, slabs = _cache_writes_and_slabs(jaxpr, ex.k.shape)
     assert {name for name, _ in writes} == {"scatter"}
     whiles = list(_in_scans(jaxpr, ("while",)))
-    switches = [(d, e) for d, e in _in_scans(jaxpr, ("cond",))
-                if len(e.params["branches"]) == n_blocks + 1]
-    if form == "loop":
-        assert [d for d, _ in whiles] == [depth] and not switches
-        carried = [v.aval.shape for v in whiles[0][1].outvars]
-        assert all(len(shape) <= 5 and ex.max_len not in shape
-                   for shape in carried), carried
-        assert reads == [BLOCK, BLOCK] and slabs == []
-        layer = ex.k.shape[1:]
-        for e in _all_eqns(jaxpr):
-            assert all(getattr(v.aval, "shape", None) != layer
-                       for v in e.invars), e.primitive.name
-    else:
-        assert [d for d, _ in switches] == [depth] and not whiles
-        assert reads == sorted(2 * [BLOCK * n
-                                    for n in range(1, n_blocks + 1)])
-        assert slabs == ["squeeze", "squeeze"]      # the last branch's
+    conds = [d for d, _ in _in_scans(jaxpr, ("cond",)) if d >= depth]
+    assert [d for d, _ in whiles] == [depth] and not conds
+    carried = [v.aval.shape for v in whiles[0][1].outvars]
+    assert all(len(shape) <= 5 and ex.max_len not in shape
+               for shape in carried), carried
+    assert reads == [BLOCK, BLOCK] and slabs == []
+    layer = ex.k.shape[1:]
+    for e in _all_eqns(jaxpr):
+        assert all(getattr(v.aval, "shape", None) != layer
+                   for v in e.invars), e.primitive.name
 
 
 def test_one_program_for_every_length(small_blocks):

@@ -401,10 +401,77 @@ def test_the_read_bounds_are_one_function_for_program_and_host():
     assert list(short[0][0]) == own[0] and not short[1].any()
 
 
-def test_the_decode_switch_holds_a_window_s_branches_not_the_slot_s():
-    """One query row a head takes the ``switch`` over static prefixes:
-    W / block + 1 branches for the window stack and R / block + 1 for the
-    summaries, whatever the slot's length in positions."""
+# Positions of four slots: all in their first window (no summary is
+# visible: the summary read makes no trip), in the middle of later windows,
+# and on the two sides of a window's edge.
+PHASES = {"first-window": [0, 5, 17, W - 1],
+          "mid-window": [W + 9, 2 * W + 17, 11, 2 * W + 8],
+          "window-edge": [W - 1, W, 2 * W - 1, 2 * W]}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("phase", sorted(PHASES))
+def test_the_two_loops_merge_into_one_softmax(monkeypatch, phase, dtype):
+    """`_attend_windowed`'s decode read at 8-row blocks (four of the window
+    stack, two of 16 summary rows), both stacks NaN past their bound:
+    ONE softmax per query over rows ``0 .. p % W`` of its window and the
+    summaries of its earlier windows, against that softmax over the dense
+    rows in float64."""
+    import types
+
+    monkeypatch.setattr(batching, "ATTN_BLOCK", 8)
+    cfg = types.SimpleNamespace(
+        num_heads=2, num_kv_heads=2, head_dim=8, query_scale=0.0,
+        attn_softcap=0.0, sliding_window=None, eva_window=W, eva_chunk=C)
+    rows = (W, 2 * (W // C))
+    pos = np.asarray(PHASES[phase], np.int32)
+    active = np.asarray([True] * 4)
+    s = len(pos)
+    kq, *keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    q = jax.random.normal(kq, (s, 1, 2, 8)).astype(dtype)
+    stacks = [jax.random.normal(key, (2, s, n, 2, 8)).astype(dtype)
+              for key, n in zip(keys, rows + rows)]      # K, K~, V, V~
+    bounds = [int(n) * 8 for n in windowed_blocks(cfg, pos, active, rows)]
+    assert bounds == {"first-window": [W, 0], "mid-window": [24, 16],
+                      "window-edge": [W, 16]}[phase]
+    dirty = [x.at[:, :, bound:].set(jnp.nan)
+             for x, bound in zip(stacks, bounds + bounds)]
+
+    @jax.jit
+    def read(q, k, ks, v, vs):
+        blocks = windowed_blocks(cfg, jnp.asarray(pos), jnp.asarray(active),
+                                 rows, jnp)
+        layer = lambda own, sums: batching._WindowedRead(   # noqa: E731
+            batching._CacheLayer(own, jnp.int32(1), blocks[0]),
+            batching._CacheLayer(sums, jnp.int32(1), blocks[1]))
+        return batching._attend_windowed(
+            cfg, {}, q, layer(k, ks), layer(v, vs),
+            jnp.asarray(pos)[:, None, None])
+
+    got = np.asarray(read(q, *dirty), np.float32)
+    assert got.shape == (s, 1, 16) and np.isfinite(got).all()
+    k, ks, v, vs = (np.asarray(x[1], np.float64) for x in stacks)
+    for i, p in enumerate(pos):
+        n_own, n_sum = p % W + 1, p // W * (W // C)
+        keys_i = np.concatenate([k[i, :n_own], ks[i, :n_sum]])   # [n, 2, 8]
+        vals_i = np.concatenate([v[i, :n_own], vs[i, :n_sum]])
+        sc = np.einsum("hd,nhd->hn", np.asarray(q[i, 0], np.float64),
+                       keys_i) * 8 ** -0.5
+        w = np.exp(sc - sc.max(-1, keepdims=True))
+        want = np.einsum("hn,nhd->hd", w / w.sum(-1, keepdims=True), vals_i)
+        tol = 2e-6 if dtype == "float32" else 2e-2
+        np.testing.assert_allclose(got[i, 0], want.reshape(-1), atol=tol,
+                                   rtol=tol, err_msg=f"slot {i} at {p}")
+
+
+def test_the_decode_loops_run_a_window_s_blocks_not_the_slot_s():
+    """One query row a head at the published rows (a 16384-position slot:
+    2048 exact rows and 896 summary rows a layer): the decode step holds
+    TWO loops over blocks beside the layer scan and no conditional, one
+    around 128-row slices of the window stacks and one around 128-row
+    slices of the summary stacks, their trip counts `windowed_blocks`'
+    (at most 16 and 7, whatever the slot's length in positions: nothing in
+    the program is 16384 long)."""
     cfg = dataclasses.replace(config_mod.get_config("evabyte"),
                               num_layers=1, hidden_size=256, num_heads=2,
                               num_kv_heads=2, intermediate_size=64)
@@ -412,13 +479,23 @@ def test_the_decode_switch_holds_a_window_s_branches_not_the_slot_s():
     spec = StagePlan.even(1, 1).stages[0]
     eng = BatchedStageExecutor(cfg, spec, params, slots=2, max_len=16384,
                                dtype=jnp.bfloat16)
+    assert eng._cache_read(1, False) == "loop"
     text = eng._build_decode(1).lower(
         eng.params, jnp.zeros((2, 1), jnp.int32),
         jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool), eng.k,
         eng.v).as_text()
-    assert text.count("stablehlo.case") == 2
-    heads = [line for line in text.splitlines() if "stablehlo.case" in line]
-    assert heads, text[:2000]
+    assert text.count("stablehlo.case") == 0
+    assert text.count("stablehlo.while") == 3       # the layers, two reads
+    assert "16384" not in text
+    reads = [line.split(":", 1)[1] for line in text.splitlines()
+             if "stablehlo.dynamic_slice" in line and "x2x128x2x128x" in line]
+    for rows in (2048, 896):        # a block of K and of V out of each stack
+        assert sum(f"x{rows}x2x128x" in line for line in reads) == 2, reads
+    assert len(reads) == 4
+    big = config_mod.get_config("evabyte")
+    last = np.asarray([[16383, 2047]])
+    assert [int(n[0]) for n in windowed_blocks(
+        big, last, last > 0, windowed_rows(big, 16384))] == [16, 7]
 
 
 def test_the_counters_follow_the_bounds():
@@ -464,7 +541,7 @@ def test_the_counters_follow_each_slot_s_own_blocks_under_the_kernel(
     its first window and sees none. ONE 32-row block a stack: every tick
     reads a's and b's window block (2 x 32) and a's summary block (32),
     where the shared bound reads 2 slots x 32 of each; the rows that come
-    back are the ``switch`` engine's."""
+    back are the loop engine's."""
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
         slot_attention,
     )
@@ -486,7 +563,7 @@ def test_the_counters_follow_each_slot_s_own_blocks_under_the_kernel(
             eng = BatchedStageExecutor(cfg, spec, params, slots=2,
                                        max_len=MAX_LEN, dtype=jnp.float32)
             assert eng._cache_read(1, False) == (
-                "kernel" if hook else "switch")
+                "kernel" if hook else "loop")
             eng.prefill("a", ids_of(2 * W + 2)[None])
             eng.prefill("b", ids_of(5, 1)[None])
             base = [got(n) for n in names]

@@ -174,11 +174,12 @@ def test_the_plan_by_hand():
 
 
 def test_which_programs_read_by_the_kernel(monkeypatch):
-    """`cache_read`, the one rule: one new row a slot, no softcap and no
-    window of the masked kind; a folded stack on every backend and with no
-    rider group, rows that stay ``[Hkv, Dh]`` where the kernel is the
-    chip's (here: the tests' hook) and ``Dh`` is whole lane tiles, a rider
-    group or not."""
+    """`cache_read`, the one rule, two answers. The kernel: one new row a
+    slot, no softcap and no window of the masked kind; a folded stack on
+    every backend and with no rider group, rows that stay ``[Hkv, Dh]``
+    where the kernel is the chip's (here: the tests' hook) and ``Dh`` is
+    whole lane tiles, a rider group or not. Every other input: the loop,
+    one query row a KV head or several."""
     cfg = head_cfg(4, 2, 16)
     cfg.eva_window = 0
     assert B.cache_read(cfg, {}, True) == "kernel"
@@ -190,16 +191,15 @@ def test_which_programs_read_by_the_kernel(monkeypatch):
         assert B.cache_read(other, {}, True) == "loop"
     assert B.cache_read(cfg, {}, False) == "loop"           # grouped queries
     cfg.num_kv_heads = 4
-    assert B.cache_read(cfg, {}, False) == "switch"
+    assert B.cache_read(cfg, {}, False) == "loop"   # one query row a head
     assert B.cache_read(cfg, {}, False, t=2) == "loop"
     # rows that stay [Hkv, Dh], a head filling the lanes
     wide = types.SimpleNamespace(**{**vars(cfg), "head_dim": 128})
     grouped = types.SimpleNamespace(**{**vars(wide), "num_kv_heads": 2})
     windowed = types.SimpleNamespace(**{**vars(wide), "eva_window": 32})
     assert not FA.engaged()                                 # not a TPU
-    assert B.cache_read(wide, {}, False) == "switch"
-    assert B.cache_read(grouped, {}, False) == "loop"
-    assert B.cache_read(windowed, {}, False) == "switch"
+    for off_the_chip in (wide, grouped, windowed):
+        assert B.cache_read(off_the_chip, {}, False) == "loop"
     monkeypatch.setattr(FA, "_INTERPRET", True)
     assert FA.engaged()
     for rider in (False, True):
@@ -208,11 +208,11 @@ def test_which_programs_read_by_the_kernel(monkeypatch):
     assert B.cache_read(grouped, {}, False) == "kernel"
     assert B.cache_read(grouped, {}, False, t=2) == "loop"
     assert B.cache_read(wide, {}, False, t=2) == "loop"     # a verify step
-    assert B.cache_read(wide, {"window": 0}, False) == "switch"
+    assert B.cache_read(wide, {"window": 0}, False) == "loop"
     for key in ("attn_softcap", "sliding_window"):
         other = types.SimpleNamespace(**{**vars(wide), key: 4})
-        assert B.cache_read(other, {}, False) == "switch"
-    assert B.cache_read(cfg, {}, False) == "switch"         # head_dim 16
+        assert B.cache_read(other, {}, False) == "loop"
+    assert B.cache_read(cfg, {}, False) == "loop"           # head_dim 16
     assert B.cache_read(cfg, {}, True, rider=True) == "loop"
 
 

@@ -40,7 +40,7 @@ TICKS = 4
 _TINY = dict(vocab_size=131, hidden_size=64, num_layers=2, num_heads=4,
              max_position_embeddings=128)
 FAMILIES = {
-    # one query head a KV head: unfolded, the VPU's `switch` of prefixes
+    # one query head a KV head: unfolded, the loop over blocks
     "gpt2": lambda: config_mod.gpt2_config(**_TINY),
     # grouped queries: two query heads share a KV head's lanes
     "qwen2": lambda: config_mod.qwen2_config(
@@ -305,9 +305,9 @@ def test_one_event_says_how_the_stacks_are_held(monkeypatch):
     assert first["layout"] == first["row_layout"] == "{4,3,2,1,0}"
     assert second["shape"] == list(ex.k.shape) == [2, SLOTS, MAX_LEN, 128]
     assert second["folded_to"] == 128 and second["row"] == [4, 16]
-    # how the burst ticks read a layer: one query row a KV head over
-    # unfolded rows, the kernel over folded ones
-    assert (first["read"], second["read"]) == ("switch", "kernel")
+    # how the burst ticks read a layer: the loop over unfolded rows (not
+    # a TPU), the kernel over folded ones
+    assert (first["read"], second["read"]) == ("loop", "kernel")
     assert second["layout"] == "{3,2,1,0}"
     assert second["logical_bytes_a_stack"] == ex.k.nbytes
     assert second["resident_bytes_a_stack"] >= ex.k.nbytes

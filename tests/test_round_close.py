@@ -55,6 +55,11 @@ TICKS, HID, VOCAB = 4, 8, 32
 ROUND_S, WINDOW_S, TURN_S = 0.8, 0.02, 0.04
 BOUND_S = batching.REJOIN_SHARE * ROUND_S
 SLACK_S = 0.06           # a loaded machine's scheduling, either way
+# A window no thread's start outlasts, for the rounds whose MEMBERS a case
+# asserts: whoever is to share a round is in it however late it was
+# scheduled, and a round that waits for sessions on their way back still
+# closes at the last join.
+MEET_S = 1.0
 
 
 class SlotsOnly(batching.BatchedStageExecutor):
@@ -74,7 +79,19 @@ class SlotsOnly(batching.BatchedStageExecutor):
         self.k = None
         self.round_s = round_s
         self.rounds = []            # (start instant, sessions, rider or None)
+        self._ran = threading.Condition()
         self.stops = {}             # session -> the stop its burst reports
+
+    def _begins(self, sessions, rider=None):
+        with self._ran:
+            self.rounds.append((time.monotonic(), sorted(sessions), rider))
+            self._ran.notify_all()
+
+    def running(self, i, timeout=30.0):
+        """Returns once round ``i`` (from 0) has begun: the adapter's lock
+        is its leader's until ``round_s`` later."""
+        with self._ran:
+            assert self._ran.wait_for(lambda: len(self.rounds) > i, timeout)
 
     def prefill(self, sid, x, prefix_len=0):
         s = self._alloc(sid)
@@ -87,15 +104,14 @@ class SlotsOnly(batching.BatchedStageExecutor):
         return out
 
     def decode_batch(self, hidden):
-        self.rounds.append((time.monotonic(), sorted(hidden), None))
+        self._begins(hidden)
         time.sleep(self.round_s)
         for sid, h in hidden.items():
             self.lengths[self._slot_of[sid]] += np.shape(h)[1]
         return dict(hidden)
 
     def decode_burst(self, entries, n_ticks, rider=None):
-        self.rounds.append((time.monotonic(), sorted(entries),
-                            rider and rider["session_id"]))
+        self._begins(entries, rider and rider["session_id"])
         time.sleep(self.round_s)
         out = {}
         for sid, e in entries.items():
@@ -156,18 +172,22 @@ def ask(ad, sid, kind, budget=TICKS):
 
 class Client(threading.Thread):
     """One session's requests: before its i-th it waits ``delays[i]`` (its
-    turnaround, counted from the reply before), and it notes when it sent
-    and when the reply came back."""
+    turnaround, counted from the reply before) and then for ``gates[i]()``
+    where there is one (`SlotsOnly.running`: a round, not an instant), and
+    it notes when it sent and when the reply came back."""
 
-    def __init__(self, ad, sid, kind, delays, budgets=None):
+    def __init__(self, ad, sid, kind, delays, budgets=None, gates=None):
         super().__init__(daemon=True)
         self.ad, self.sid, self.kind = ad, sid, kind
         self.delays, self.budgets = delays, budgets or {}
+        self.gates = gates or {}
         self.sent, self.back, self.error = [], [], None
 
     def run(self):
         for i, delay in enumerate(self.delays):
             time.sleep(delay)
+            if i in self.gates:
+                self.gates[i]()
             self.sent.append(time.monotonic())
             try:
                 ask(self.ad, self.sid, self.kind,
@@ -188,22 +208,27 @@ def run_all(*clients, timeout=60.0):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_two_cohorts_merge_into_one_round(kind):
-    """(1) Two sessions run a round; two more ask during it and wait for
-    the lock. Whoever leads the next round holds it for the two that round
-    answered: every round from the second on runs all four."""
-    ad, eng = make(kind)
+    """(1) Two sessions run a round; two more ask during it (once the
+    engine says it runs) and wait for the lock. Whoever leads the next
+    round holds it for the two that round answered: every round from the
+    second on runs all four. No instant decides who is in a round: the
+    window and the bound are `MEET_S`."""
+    ad, eng = make(kind, window_s=MEET_S)
     seat(ad, "a", "b", "c", "d")
     early = [Client(ad, s, kind, [0.0, TURN_S, TURN_S]) for s in "ab"]
-    late = [Client(ad, s, kind, [ROUND_S / 4, TURN_S]) for s in "cd"]
+    late = [Client(ad, s, kind, [0.0, TURN_S],
+                   gates={0: lambda: eng.running(0)}) for s in "cd"]
     run_all(*early, *late)
     assert [r[1] for r in eng.rounds] == [
         ["a", "b"], ["a", "b", "c", "d"], ["a", "b", "c", "d"]]
     fill = ad._m_fill
     assert (fill.count, fill.sum) == (3, 2.0 + 4.0 + 4.0)
     assert closed(ad) == {"window": 1, "joined": 2}
-    # a, b twice and c, d once came back from the round before
+    # a, b twice and c, d once came back from the round before: a way
+    # back is a turnaround, counted from the reply (from the round's start
+    # it would be `ROUND_S` more)
     assert ad._m_rejoin.count == 6
-    assert TURN_S <= ad._m_rejoin.sum / 6 < TURN_S + SLACK_S
+    assert TURN_S <= ad._m_rejoin.sum / 6 < TURN_S + ROUND_S / 2
 
 
 @pytest.mark.parametrize("kind", KINDS)

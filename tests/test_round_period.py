@@ -21,6 +21,7 @@ import pytest
 
 from test_round_close import (
     KINDS,
+    MEET_S,
     TICKS,
     Client,
     SlotsOnly,
@@ -254,7 +255,13 @@ def test_a_stalled_round_says_what_it_was_made_of(monkeypatch, kind,
     eng.round_s = 5 * ROUND_S
     if profiled:
         eng.burst_parts = dict(PARTS)
+    # The round the two are to share is open until the later of them is in
+    # (``a`` is on its way back: the round closes at its join; where ``a``
+    # leads, for a window no thread's start outlasts), not for 0.01 s.
+    ad.window_s = MEET_S
     run_all(Client(ad, "a", kind, [0.0]), Client(ad, "b", kind, [0.0]))
+    ad.window_s = WINDOW_S
+    assert eng.rounds[2][1] == ["a", "b"]
     eng.round_s = ROUND_S
     ask(ad, "a", kind)                 # 1/5 of the last: no stall either
     assert ad._m_stalls.value == 1
