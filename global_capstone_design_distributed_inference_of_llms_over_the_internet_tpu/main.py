@@ -165,15 +165,17 @@ def _refuse_unheld_state(args, cfg: ModelConfig) -> None:
 
     if args.mode == "serve" and args.stage == 0 and args.batched:
         what = None
-        if cfg.eva_window and args.prefix_cache_mb:
+        own_state = cfg.eva_window or cfg.kv_lora_rank
+        if own_state and args.prefix_cache_mb:
             what = "the prefix cache (a stored prefix is a slice of rows)"
-        elif cfg.eva_window and getattr(args, "speculative_k", 0):
+        elif own_state and getattr(args, "speculative_k", 0):
             what = ("speculative verify (a block of draft rows may cross "
-                    "a window's edge)")
+                    "a window's edge, and a selection is made for one "
+                    "query row a slot)")
     elif args.mode == "serve":
         what = ("a stage server over part of the stack" if args.batched
                 else "the per-session executor")
-    elif args.mode == "oracle" and not cfg.eva_window:
+    elif args.mode == "oracle" and not (cfg.eva_window or cfg.kv_lora_rank):
         what = None
     else:
         what = f"--mode {args.mode}"

@@ -17,7 +17,7 @@ class ModelConfig:
     """Architecture hyperparameters for one decoder-only transformer family."""
 
     # "gpt2" | "llama" | "mistral" | "mixtral" | "qwen2" | "gemma" | "ouro"
-    # | "evabyte"
+    # | "evabyte" | "glm_moe_dsa"
     model_type: str
     vocab_size: int
     hidden_size: int
@@ -108,6 +108,43 @@ class ModelConfig:
     # projects by head 0's V columns only; the others are held, not served.
     pred_heads: int = 1
 
+    # Latent attention (MLA) under a learned sparse selection, as GLM-5
+    # (``glm_moe_dsa``) publishes it. ``kv_lora_rank`` > 0: a position keeps,
+    # a layer, ONE latent row of ``kv_lora_rank + qk_rope_head_dim`` numbers
+    # (the normed compressed K/V and the one rotated key all heads share)
+    # and ONE index key of ``index_head_dim``; queries come through a
+    # ``q_lora_rank`` bottleneck, a head is ``qk_nope_head_dim +
+    # qk_rope_head_dim`` wide against keys and ``v_head_dim`` against values;
+    # an indexer of ``index_n_heads`` heads scores every earlier position
+    # and the query attends to the ``index_topk`` best (all, while it has no
+    # more). Only the full-span batched engine holds that state.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # Sigmoid-routed experts beside a shared one (``moe_intermediate_size``
+    # > 0; ``num_experts`` routed, ``num_experts_per_tok`` taken by score +
+    # bias, their scores normalised and scaled by
+    # ``routed_scaling_factor``), behind ``first_k_dense`` leading layers
+    # whose MLP is the dense ``intermediate_size`` SwiGLU. ``experts_held``
+    # = ``(first, count)``: the routed experts THIS deployment's chip holds;
+    # the router scores all ``num_experts`` and a token's assignments to an
+    # expert held elsewhere add nothing here. None = all.
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_k_dense: int = 0
+    experts_held: Optional[tuple] = None
+
+    @property
+    def held_experts(self) -> tuple:
+        """``(first, count)`` of the routed experts this config holds."""
+        return self.experts_held or (0, self.num_experts)
+
     @property
     def head_dim(self) -> int:
         return (self.head_dim_override
@@ -128,6 +165,13 @@ class ModelConfig:
             # a summary is per KV head and pooled by a vector per query head
             assert self.num_kv_heads == self.num_heads
             assert self.loop_steps == 1 and not self.sliding_window
+        if self.kv_lora_rank:
+            # the one read of latent rows the engine has is the selected one
+            assert self.index_topk > 0
+            assert self.loop_steps == 1 and not self.eva_window
+        if self.moe_intermediate_size:
+            first, count = self.held_experts
+            assert 0 <= first and first + count <= self.num_experts
 
 
 def gpt2_config(
@@ -261,6 +305,34 @@ def evabyte_config(window_size: int = 2048, chunk_size: int = 16,
         pred_heads=num_pred_heads)
 
 
+def glm5_config(q_lora_rank: int = 2048, kv_lora_rank: int = 512,
+                qk_nope_head_dim: int = 192, qk_rope_head_dim: int = 64,
+                v_head_dim: int = 256, index_n_heads: int = 32,
+                index_head_dim: int = 128, index_topk: int = 2048,
+                n_routed_experts: int = 256, num_experts_per_tok: int = 8,
+                moe_intermediate_size: int = 2048, n_shared_experts: int = 1,
+                routed_scaling_factor: float = 2.5, first_k_dense: int = 3,
+                experts_held: Optional[tuple] = None, **kw) -> ModelConfig:
+    """GLM-5 (HF ``glm_moe_dsa``): RMSNorm, no biases, untied head; latent
+    attention under a learned top-``index_topk`` selection
+    (`ModelConfig.kv_lora_rank`); sigmoid-routed experts beside a shared
+    one behind ``first_k_dense`` dense layers. The multi-token-prediction
+    layer the checkpoint carries past ``num_layers`` is not held."""
+    cfg = llama_config(num_kv_heads=kw["num_heads"], **kw)
+    return dataclasses.replace(
+        cfg, model_type="glm_moe_dsa",
+        head_dim_override=qk_nope_head_dim + qk_rope_head_dim,
+        q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+        qk_nope_head_dim=qk_nope_head_dim, qk_rope_head_dim=qk_rope_head_dim,
+        v_head_dim=v_head_dim, index_n_heads=index_n_heads,
+        index_head_dim=index_head_dim, index_topk=index_topk,
+        num_experts=n_routed_experts, num_experts_per_tok=num_experts_per_tok,
+        moe_intermediate_size=moe_intermediate_size,
+        n_shared_experts=n_shared_experts,
+        routed_scaling_factor=routed_scaling_factor,
+        first_k_dense=first_k_dense, experts_held=experts_held)
+
+
 def mixtral_config(num_experts: int = 8, num_experts_per_tok: int = 2, **kw) -> ModelConfig:
     cfg = llama_config(**kw)
     return dataclasses.replace(
@@ -368,6 +440,31 @@ PRESETS = {
         max_position_embeddings=32768, rope_theta=100000.0,
         window_size=256,
     ),
+    # zai-org/GLM-5 config.json as ONE chip of the stated deployment serves
+    # it (perfbench/configs/glm-5.json ``deployment``): 16 chips share a
+    # layer, each holding attention, the shared expert and 16 of the 256
+    # routed experts (this one: 0 .. 15; the router scores all 256), the
+    # vocabulary over 8 chips (154880 -> 19360 rows of embedding and head),
+    # ONE leading dense layer of the published three. Every width is the
+    # published one. ``--num_layers 6`` cuts the depth.
+    "glm5": lambda: glm5_config(
+        vocab_size=19360, hidden_size=6144, num_layers=78, num_heads=64,
+        intermediate_size=12288, max_position_embeddings=202752,
+        rope_theta=1000000.0, first_k_dense=1, experts_held=(0, 16),
+    ),
+    # The same code path for the benchmark's CPU rehearsal (an eighth of
+    # every length: prompts of 255-1750 rows): ``index_topk`` 256, so that
+    # they cross the selection's edge, a quarter of every width, 8 of 32
+    # experts held.
+    "glm5-rehearsal": lambda: glm5_config(
+        vocab_size=2420, hidden_size=1536, num_layers=78, num_heads=16,
+        intermediate_size=3072, max_position_embeddings=202752,
+        rope_theta=1000000.0, q_lora_rank=512, kv_lora_rank=128,
+        qk_nope_head_dim=48, qk_rope_head_dim=16, v_head_dim=64,
+        index_n_heads=8, index_head_dim=32, index_topk=256,
+        n_routed_experts=32, num_experts_per_tok=8,
+        moe_intermediate_size=512, first_k_dense=1, experts_held=(0, 8),
+    ),
 }
 
 # Qwen2.5 shares the qwen2 architecture (HF model_type "qwen2") — alias
@@ -383,9 +480,19 @@ def single_pass_unsupported(cfg: ModelConfig, what: str) -> Optional[str]:
     needs. A looped stack lives in the full-span batched engine
     (runtime.batching) and the in-program oracle
     (models.transformer.full_forward) only; a family whose older rows are
-    summaries (``eva_window``) in the full-span batched engine only.
+    summaries (``eva_window``), and one whose rows are latent and read
+    through a learned selection (``kv_lora_rank``), in the full-span
+    batched engine only.
     Everything else would silently run one pass of several, or plain
     causal attention past the first window, and must refuse instead."""
+    if cfg.kv_lora_rank:
+        return (f"a position keeps a latent row of {cfg.kv_lora_rank} + "
+                f"{cfg.qk_rope_head_dim} numbers and an index key of "
+                f"{cfg.index_head_dim}, and a query reads the "
+                f"{cfg.index_topk} rows a learned indexer selects: {what} "
+                "keeps one K and one V row a position and has no "
+                "selection; serve the model whole on the batched engine "
+                "(serve --stage 0 --batched)")
     if cfg.eva_window:
         return (f"older rows are summaries ({cfg.eva_window} exact K/V rows "
                 f"a layer and one learned summary row per {cfg.eva_chunk} "

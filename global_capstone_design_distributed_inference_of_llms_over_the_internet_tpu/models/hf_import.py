@@ -184,6 +184,29 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             chunk_size=int(hf_cfg.chunk_size),
             num_pred_heads=int(getattr(hf_cfg, "num_pred_heads", 1)),
             **common)
+    if mt == "glm_moe_dsa":
+        from .config import glm5_config
+
+        rope = getattr(hf_cfg, "rope_parameters", None) or {}
+        common.pop("num_kv_heads")
+        common["rope_theta"] = float(
+            rope.get("rope_theta", common["rope_theta"]))
+        return glm5_config(
+            **common,
+            q_lora_rank=hf_cfg.q_lora_rank,
+            kv_lora_rank=hf_cfg.kv_lora_rank,
+            qk_nope_head_dim=hf_cfg.qk_nope_head_dim,
+            qk_rope_head_dim=hf_cfg.qk_rope_head_dim,
+            v_head_dim=hf_cfg.v_head_dim,
+            index_n_heads=hf_cfg.index_n_heads,
+            index_head_dim=hf_cfg.index_head_dim,
+            index_topk=hf_cfg.index_topk,
+            n_routed_experts=hf_cfg.n_routed_experts,
+            num_experts_per_tok=hf_cfg.num_experts_per_tok,
+            moe_intermediate_size=hf_cfg.moe_intermediate_size,
+            n_shared_experts=hf_cfg.n_shared_experts,
+            routed_scaling_factor=float(hf_cfg.routed_scaling_factor),
+            first_k_dense=hf_cfg.first_k_dense_replace)
     if mt == "mixtral":
         cfg = mixtral_config(
             num_experts=hf_cfg.num_local_experts,
@@ -199,7 +222,8 @@ def config_from_hf(hf_cfg) -> ModelConfig:
     # Mirrors the reference's model_type guard (src/llama_partition.py:82-83).
     raise ValueError(
         f"unsupported model_type: {mt} "
-        "(expected gpt2/llama/mistral/mixtral/qwen2/gemma/ouro/evabyte)")
+        "(expected gpt2/llama/mistral/mixtral/qwen2/gemma/ouro/evabyte/"
+        "glm_moe_dsa)")
 
 
 def _gpt2_layer(sd: Mapping[str, Any], i: int) -> Params:
@@ -295,6 +319,75 @@ def _llama_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
     return p
 
 
+def _half_layout(n: int) -> np.ndarray:
+    """Where each of ``n`` rotated dims sits when INTERLEAVED pairs (2i,
+    2i + 1) are held as the halves (i, i + n/2) `ops.rotary` rotates: the
+    permutation applied to q and k alike leaves every q . k as it is."""
+    return np.concatenate([np.arange(0, n, 2), np.arange(1, n, 2)])
+
+
+def _glm5_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
+    """One layer of a ``glm_moe_dsa`` checkpoint (HF Linear weights are
+    [out, in]; ours [in, out]). The dims the published model rotates as
+    interleaved pairs (``rope_interleave``, ``indexer_rope_interleave``)
+    are permuted to the half layout here, once: the rope part of every
+    head of ``q_b_proj``, the shared key of ``kv_a_proj_with_mqa``, and
+    the first ``qk_rope_head_dim`` of the indexer's queries, key and the
+    key's LayerNorm. Of the routed experts only those held
+    (``cfg.held_experts``) are read; the router keeps every output."""
+    pre = f"model.layers.{i}."
+    att = pre + "self_attn."
+    h, r = cfg.num_heads, cfg.qk_rope_head_dim
+    nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    half = _half_layout(r)
+    t = lambda name: _np(sd[name])
+    head = np.concatenate([np.arange(nope), nope + half])
+    q_rows = (np.arange(h)[:, None] * (nope + r) + head[None]).reshape(-1)
+    di = cfg.index_head_dim
+    ikey = np.concatenate([half, np.arange(r, di)])
+    iq_rows = (np.arange(cfg.index_n_heads)[:, None] * di
+               + ikey[None]).reshape(-1)
+    kva_rows = np.concatenate([np.arange(kl), kl + half])
+    p: Params = {
+        "ln1": {"w": t(pre + "input_layernorm.weight")},
+        "ln2": {"w": t(pre + "post_attention_layernorm.weight")},
+        "attn": {
+            "wqa": t(att + "q_a_proj.weight").T,
+            "q_norm": {"w": t(att + "q_a_layernorm.weight")},
+            "wqb": t(att + "q_b_proj.weight")[q_rows].T,
+            "wkva": t(att + "kv_a_proj_with_mqa.weight")[kva_rows].T,
+            "kv_norm": {"w": t(att + "kv_a_layernorm.weight")},
+            "wkvb": t(att + "kv_b_proj.weight").T,
+            "wo": t(att + "o_proj.weight").T,
+            "wiq": t(att + "indexer.wq_b.weight")[iq_rows].T,
+            "wik": t(att + "indexer.wk.weight")[ikey].T,
+            "ik_norm": {"w": t(att + "indexer.k_norm.weight")[ikey],
+                        "b": t(att + "indexer.k_norm.bias")[ikey]},
+            "wiw": t(att + "indexer.weights_proj.weight").T,
+        },
+    }
+    mlp = pre + "mlp."
+
+    def swiglu(owner):
+        return {ours: t(mlp + owner + theirs + ".weight").T
+                for ours, theirs in (("wg", "gate_proj"), ("wu", "up_proj"),
+                                     ("wd", "down_proj"))}
+
+    if i < cfg.first_k_dense:
+        p["mlp"] = swiglu("")
+        return p
+    first, held = cfg.held_experts
+    experts = [swiglu(f"experts.{e}.") for e in range(first, first + held)]
+    p["mlp"] = {
+        "router": t(mlp + "gate.weight").T,
+        "router_bias": np.asarray(
+            t(mlp + "gate.e_score_correction_bias"), np.float32),
+        **{k: np.stack([e[k] for e in experts]) for k in ("wg", "wu", "wd")},
+        "shared": swiglu("shared_experts."),
+    }
+    return p
+
+
 def _stack(layer_params: Iterable[Params]) -> Params:
     layer_params = list(layer_params)
     return jax.tree.map(lambda *xs: np.stack(xs), *layer_params)
@@ -320,11 +413,24 @@ def convert_state_dict(
 
     if is_gpt2:
         layers = [_gpt2_layer(sd, i) for i in range(start, end)]
+    elif cfg.kv_lora_rank:
+        layers = [_glm5_layer(sd, i, cfg) for i in range(start, end)]
     else:
         layers = [_llama_layer(sd, i, cfg) for i in range(start, end)]
 
     params: Params = {}
-    if layers:
+    if cfg.kv_lora_rank and layers:
+        # Leading dense layers are another kind than the rest: two stacks.
+        # The router's score bias stays float32, as published.
+        k = max(0, min(cfg.first_k_dense, end) - start)
+        to_device = lambda path, x: jnp.asarray(
+            x, jnp.float32 if path[-1].key == "router_bias" else dtype)
+        for name, group in (("dense_layers", layers[:k]),
+                            ("layers", layers[k:])):
+            if group:
+                params[name] = jax.tree_util.tree_map_with_path(
+                    to_device, _stack(group))
+    elif layers:
         # Cast FLOAT leaves only: the gemma2 per-layer "window" leaf is
         # int32 position arithmetic — sweeping it to bf16 would mis-mask
         # keys past position ~256 (bf16 integers lose exactness there).

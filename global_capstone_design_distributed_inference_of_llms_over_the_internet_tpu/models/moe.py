@@ -212,6 +212,105 @@ def sparse_moe_mlp(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     return out
 
 
+# Rows a held expert takes in ONE round of `held_moe_mlp`, at least: a step
+# of up to this many rows runs every held expert over every row (one round,
+# each expert's weights streamed once: a decode tick); a longer one gives
+# each expert this many row slots a round, or twice its balanced share
+# where that is more, and takes as many rounds as the fullest expert needs.
+MOE_ROUND_ROWS = 128
+
+
+def route_sigmoid(cfg: ModelConfig, p: Params, xf: jnp.ndarray):
+    """Sigmoid routing over ALL ``cfg.num_experts`` (the ``noaux_tc``
+    method with one group): scores ``sigmoid(x W_g)``, the
+    ``num_experts_per_tok`` largest of ``score + bias`` chosen, the chosen
+    SCORES (without the bias) normalised to sum 1 and scaled by
+    ``routed_scaling_factor``. float32 at the highest matmul precision: a
+    near-tie for the last place decides which expert runs. xf ``[N, D]``
+    -> ``(expert ids [N, K], weights [N, K])``. The ONE place the
+    ``router`` scope is opened."""
+    with jax.named_scope("router"):
+        scores = jax.nn.sigmoid(jnp.dot(
+            xf.astype(jnp.float32), p["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, topi = jax.lax.top_k(
+            scores + p["router_bias"].astype(jnp.float32),
+            cfg.num_experts_per_tok)
+        w = jnp.take_along_axis(scores, topi, axis=-1)
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        return topi, w * cfg.routed_scaling_factor
+
+
+def _swiglu_experts(buf: jnp.ndarray, p: Params) -> jnp.ndarray:
+    """``[E, C, D]`` through the ``E`` SwiGLU experts of ``p``."""
+    gate = jax.nn.silu(_expert_dot(buf, p["wg"]))
+    return _expert_dot(gate * _expert_dot(buf, p["wu"]), p["wd"])
+
+
+def held_moe_mlp(cfg: ModelConfig, p: Params, x: jnp.ndarray):
+    """The expert layer of ONE chip of a deployment that shares a layer's
+    routed experts among chips: ``(y, assigned)``. ``p`` holds the router
+    over ALL experts (``router`` ``[D, E]``, ``router_bias`` ``[E]``), the
+    stacks of the experts THIS chip holds (``wg``/``wu``/``wd``, ``[held,
+    ..]``: experts ``cfg.held_experts[0] ..``) and the ``shared`` expert.
+    Every token is routed over all ``E``; ``y`` is its held experts' part
+    (weights as routed: the other chips' parts add to it where they live)
+    plus the shared expert, which every chip's data-parallel tokens take
+    whole. ``assigned`` ``[N, held]``: which rows chose which held expert
+    (the burst program's counters).
+
+    Drop-free at static shapes. A step of at most `MOE_ROUND_ROWS` rows
+    runs every held expert over every row, weighted: each expert's
+    weights are read ONCE whatever the routing, so a decode tick's bytes
+    do not follow its tokens. A longer step (a prefill) compacts: row
+    ``n``'s rank among the rows that chose expert ``e`` is a running count,
+    round ``r`` takes ranks ``[r C, (r + 1) C)`` of every expert through
+    one-hot dispatch and combine products (MXU work, no scatter), and the
+    rounds run until the fullest expert is done (a traced trip count: one
+    round at balanced routing, more under skew, never a dropped row)."""
+    b, t, d = x.shape
+    n = b * t
+    xf = x.reshape(n, d)
+    first, held = cfg.held_experts
+    topi, w = route_sigmoid(cfg, p, xf)
+    with jax.named_scope("experts"):
+        hit = topi[:, :, None] == first + jnp.arange(held, dtype=topi.dtype)
+        assigned = hit.any(1)                                   # [N, held]
+        held_w = jnp.where(hit, w[:, :, None], 0.0).sum(1)      # [N, held]
+        if n <= MOE_ROUND_ROWS:
+            y = _swiglu_experts(jnp.broadcast_to(xf, (held, n, d)), p)
+            out = jnp.einsum("ne,end->nd", held_w.astype(x.dtype), y,
+                             preferred_element_type=jnp.float32)
+        else:
+            cap = max(MOE_ROUND_ROWS, -(-2 * n * cfg.num_experts_per_tok
+                                        // cfg.num_experts))
+            rank = jnp.cumsum(assigned.astype(jnp.int32), axis=0) - 1
+            rounds = -(-assigned.sum(0).max() // cap)
+            lane = jnp.arange(cap, dtype=jnp.int32)
+
+            def one_round(r, acc):
+                slot = rank - r * cap
+                onehot = (assigned[:, :, None]
+                          & (slot[:, :, None] == lane)).astype(x.dtype)
+                y = _swiglu_experts(
+                    jnp.einsum("nec,nd->ecd", onehot, xf), p)
+                return acc + jnp.einsum(
+                    "nec,ecd->nd",
+                    onehot * held_w[:, :, None].astype(x.dtype), y,
+                    preferred_element_type=jnp.float32)
+
+            out = jax.lax.fori_loop(0, rounds, one_round,
+                                    jnp.zeros((n, d), jnp.float32))
+    with jax.named_scope("shared_expert"):
+        from .transformer import _dot
+
+        sh = p["shared"]
+        shared = _dot(jax.nn.silu(_dot(xf, sh["wg"])) * _dot(xf, sh["wu"]),
+                      sh["wd"])
+    return ((out + shared.astype(jnp.float32)).astype(x.dtype)
+            .reshape(b, t, d), assigned)
+
+
 def dispatch_stats(cfg: ModelConfig, router: jnp.ndarray, x: jnp.ndarray
                    ) -> Tuple[jnp.ndarray, int, int]:
     """Host-visible routing stats for a batch — the SAME math the sparse

@@ -244,7 +244,9 @@ def _quantize_leaf(w: jnp.ndarray) -> QuantizedTensor:
 # The matmul weight names of models/transformer.py's layer schema. Norms,
 # biases, and the MoE "router" are deliberately absent (full precision).
 _MATMUL_KEYS = frozenset(
-    {"wq", "wk", "wv", "wqkv", "wo", "wg", "wu", "wgu", "wd", "wi"})
+    {"wq", "wk", "wv", "wqkv", "wo", "wg", "wu", "wgu", "wd", "wi",
+     # a latent-attention family's bottlenecks (models.config kv_lora_rank)
+     "wqa", "wqb", "wkva", "wkvb", "wiq"})
 
 
 def quantize_layers(layers: Params, quant: str = "int8") -> Params:
@@ -273,8 +275,9 @@ def quantize_params(params: Params, quant: str = "int8") -> Params:
     """Quantize a full/stage param tree: blocks only (embed/head/norm full
     precision, matching the reference's block-scoped quantization)."""
     out = dict(params)
-    if "layers" in params:
-        out["layers"] = quantize_layers(params["layers"], quant)
+    for key in ("layers", "dense_layers"):
+        if key in params:
+            out[key] = quantize_layers(params[key], quant)
     return out
 
 

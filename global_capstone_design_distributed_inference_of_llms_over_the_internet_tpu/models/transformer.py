@@ -108,11 +108,75 @@ def init_layer_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Pa
     return p
 
 
+def init_latent_layer_params(rng: jax.Array, cfg: ModelConfig, dtype,
+                             dense: bool) -> Params:
+    """Random init for ONE layer of a latent-attention family
+    (``cfg.kv_lora_rank``): the query and key/value bottlenecks with their
+    norms, the indexer (``wiq`` from the query bottleneck, ``wik`` and its
+    LayerNorm, the per-head weights ``wiw``), and either the dense SwiGLU
+    (``dense``: a leading layer) or the router with its score bias, the
+    HELD routed experts and the shared expert."""
+    d, h = cfg.hidden_size, cfg.num_heads
+    ql, kl, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    ih, idh = cfg.index_n_heads, cfg.index_head_dim
+    ks = jax.random.split(rng, 16)
+    one = lambda n: jnp.ones((n,), dtype)
+    p: Params = {
+        "ln1": {"w": one(d)}, "ln2": {"w": one(d)},
+        "attn": {
+            "wqa": _dense(ks[0], (d, ql), dtype), "q_norm": {"w": one(ql)},
+            "wqb": _dense(ks[1], (ql, h * cfg.head_dim), dtype),
+            "wkva": _dense(ks[2], (d, kl + rope), dtype),
+            "kv_norm": {"w": one(kl)},
+            "wkvb": _dense(ks[3], (kl, h * (cfg.qk_nope_head_dim
+                                            + cfg.v_head_dim)), dtype),
+            "wo": _dense(ks[4], (h * cfg.v_head_dim, d), dtype),
+            "wiq": _dense(ks[5], (ql, ih * idh), dtype),
+            "wik": _dense(ks[6], (d, idh), dtype),
+            "ik_norm": {"w": one(idh), "b": jnp.zeros((idh,), dtype)},
+            "wiw": _dense(ks[7], (d, ih), dtype),
+        },
+    }
+    if dense:
+        i = cfg.intermediate_size
+        p["mlp"] = {"wg": _dense(ks[8], (d, i), dtype),
+                    "wu": _dense(ks[9], (d, i), dtype),
+                    "wd": _dense(ks[10], (i, d), dtype)}
+        return p
+    f, held = cfg.moe_intermediate_size, cfg.held_experts[1]
+    fs = f * cfg.n_shared_experts
+    p["mlp"] = {
+        "router": _dense(ks[8], (d, cfg.num_experts), dtype),
+        "router_bias": jnp.zeros((cfg.num_experts,), jnp.float32),
+        "wg": _dense(ks[9], (held, d, f), dtype),
+        "wu": _dense(ks[10], (held, d, f), dtype),
+        "wd": _dense(ks[11], (held, f, d), dtype),
+        "shared": {"wg": _dense(ks[12], (d, fs), dtype),
+                   "wu": _dense(ks[13], (d, fs), dtype),
+                   "wd": _dense(ks[14], (fs, d), dtype)},
+    }
+    return p
+
+
 def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
-    """Random init of the FULL model with stacked layers."""
+    """Random init of the FULL model with stacked layers. A family with
+    leading dense layers (``cfg.first_k_dense``) holds TWO stacks: those
+    under ``dense_layers``, the expert layers under ``layers``."""
     k_emb, k_layers, k_head = jax.random.split(rng, 3)
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    layers = jax.vmap(lambda k: init_layer_params(k, cfg, dtype))(layer_keys)
+    dense_layers = None
+    if cfg.kv_lora_rank:
+        k = min(cfg.first_k_dense, cfg.num_layers)
+        # a layer at a time: drawn all at once, the float32 normals of five
+        # layers' held experts are 12 GB beside the 9.5 GB they are cast to
+        stack = lambda keys, dense: jax.lax.map(
+            lambda key: init_latent_layer_params(key, cfg, dtype, dense),
+            keys)
+        dense_layers = stack(layer_keys[:k], True) if k else None
+        layers = stack(layer_keys[k:], False)
+    else:
+        layers = jax.vmap(lambda k: init_layer_params(k, cfg, dtype))(
+            layer_keys)
     if cfg.altern_window:
         # gemma2: even layer indices are windowed, odd attend globally
         # (HF Gemma2Attention's layer_idx % 2 rule); 0 disables per layer.
@@ -139,6 +203,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
         final_norm = {"w": jnp.ones((cfg.hidden_size,), dtype)}
 
     params: Params = {"embed": embed, "layers": layers, "final_norm": final_norm}
+    if dense_layers is not None:
+        params["dense_layers"] = dense_layers
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"w": _dense(
             k_head, (cfg.hidden_size, cfg.pred_heads * cfg.vocab_size),
@@ -336,7 +402,7 @@ def fuse_qkv_params(params: Params) -> Params:
 
 
 def _mlp(cfg: ModelConfig, p: Params, x: jnp.ndarray, tp_axis: Optional[str]) -> jnp.ndarray:
-    if cfg.is_moe:
+    if cfg.is_moe and "router" in p:     # not a leading dense layer's
         return _moe_mlp(cfg, p, x, tp_axis)
     if cfg.mlp == "swiglu":
         # Gate activation: silu (llama family) or tanh-gelu (gemma GeGLU).
@@ -419,8 +485,9 @@ def make_rope(cfg: ModelConfig, positions: jnp.ndarray):
     layer would cost num_layers rebuilds (80x for llama-3-70b)."""
     if cfg.positional != "rope":
         return None
-    return rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta,
-                        cfg.rope_scaling)
+    # a latent-attention family rotates ``qk_rope_head_dim`` of a head's dims
+    return rope_cos_sin(positions, cfg.qk_rope_head_dim or cfg.head_dim,
+                        cfg.rope_theta, cfg.rope_scaling)
 
 
 def _attention(

@@ -88,6 +88,7 @@ from ..ops.slot_attention import (
     read_plan,
     slot_attention,
 )
+from ..ops.norms import layer_norm, rms_norm
 from ..ops.rotary import apply_rope
 from ..parallel.ring_attention import NEG_INF
 from ..telemetry import catalog as _tm
@@ -203,6 +204,23 @@ BURST_INTS = ("tok", "lengths", "alive", "seeds", "nvalid", "run", "left",
               "eos", "top_k")
 BURST_FLOATS = ("temp", "top_p", "rp")
 RIDER_INTS = ("len", "slot", "seed", "nvalid", "top_k")
+# What a burst program of a family whose expert layers hold a share of the
+# routed experts sums on the device and returns after its lengths, by
+# series: a tick's rows' assignments over ALL experts, those that fell on a
+# held expert, the held experts at least one row chose, the held experts
+# there were (a layer a tick); `_burst_collect` unpacks them by these names.
+MOE_COUNTERS = ("server_moe_assignments_total",
+                "server_moe_assignments_held_total",
+                "server_moe_experts_hit_total",
+                "server_moe_expert_slots_total")
+
+
+def holds_expert_share(cfg) -> bool:
+    """Whether the span has expert layers that hold a share of the routed
+    experts: their burst program carries `MOE_COUNTERS`. Asked of the
+    expert layer's own keys, not of the attention's."""
+    return (cfg.moe_intermediate_size > 0
+            and cfg.num_layers > cfg.first_k_dense)
 
 
 def _rider_fields(window: int):
@@ -267,15 +285,26 @@ def _normed(cfg, p, h):
 def _residual(cfg, lp, h, attn_out):
     """Residual + MLP with optional sandwich norms (gemma2 post_norms:
     ln3 after attention, ln4 after the MLP, before each residual add): the
-    ``mlp`` section of every engine program on the device trace."""
+    ``mlp`` section of every engine program on the device trace. ``(h,
+    extra)``: ``extra`` is what the layer adds to its scan's state beside
+    the cache rows, nothing but for an expert layer that holds a share."""
     with jax.named_scope("mlp"):
         if cfg.post_norms:
             attn_out = _norm(cfg, lp["ln3"], attn_out)
         h = h + attn_out
+        if "router_bias" in lp["mlp"]:
+            # one chip's share of a sigmoid-routed expert layer: what the
+            # layer scan stacks beside the cache rows is which rows chose
+            # which held expert (the burst program's counters)
+            from ..models.moe import held_moe_mlp
+
+            mlp_out, assigned = held_moe_mlp(
+                cfg, lp["mlp"], _normed(cfg, lp["ln2"], h))
+            return h + mlp_out, (assigned,)
         mlp_out = _mlp(cfg, lp["mlp"], _normed(cfg, lp["ln2"], h), None)
         if cfg.post_norms:
             mlp_out = _norm(cfg, lp["ln4"], mlp_out)
-        return h + mlp_out
+        return h + mlp_out, ()
 
 
 def _visible(cfg, q_pos, k_pos):
@@ -371,7 +400,12 @@ def cache_read(cfg, layers, folded: bool, t: int = 1,
     stay ``[Hkv, Dh]`` are the kernel's where it is the chip's and a row
     fills its lanes (``Dh`` whole tiles of 128: `kernel_engaged`). The
     programs, the counter of the rows they read (`_count_attn_rows`) and
-    the ``kv_layout`` event all ask here."""
+    the ``kv_layout`` event all ask here. A latent family under a learned
+    selection (``cfg.kv_lora_rank``) reads in a form of its own,
+    ``"select"``: index scores over a slot's index keys, the top
+    ``index_topk``, a gather of those latent rows (`_attend_latent`)."""
+    if cfg.kv_lora_rank:
+        return "select"
     plain = not (cfg.attn_softcap or cfg.sliding_window
                  or "window" in layers)
     if folded:
@@ -768,6 +802,260 @@ def _append_windowed(cfg, lp, at, k, v, k_all, v_all, lengths, active,
     return reads[0], reads[1], (None, qpos, None), state
 
 
+# -- a family whose rows are latent, read through a learned selection -------
+# (``cfg.kv_lora_rank``: MLA under an indexer's top ``index_topk``)
+
+# Rows of a slot's index keys a decode tick scores in one piece, and keys a
+# prefill chunk scores and attends over in one piece (`_attend_latent`).
+INDEX_BLOCK = 2048
+# Prompt rows a latent family's prefill program takes at once
+# (`BatchedStageExecutor._prefill_chunks`): each chunk attends over the
+# slot's rows so far, so a prompt of any length runs through ONE program
+# shape and the buckets of its tail.
+LATENT_CHUNK = 1024
+
+
+def index_block(max_len: int) -> int:
+    """The block of a ``max_len``-row slot's index keys and latent rows:
+    `INDEX_BLOCK`, or the slot where it is shorter."""
+    return min(INDEX_BLOCK, max_len)
+
+
+def index_blocks(lengths, active, max_len, xp=np):
+    """How many blocks of `index_block` rows of its index keys a decode
+    tick scores a slot: up to the new row of the LONGEST ACTIVE slot (along
+    the last axis), none where no slot is active. As `attn_blocks`: traced
+    in the programs, on the host for ``server_index_rows_scored_total``."""
+    block = index_block(max_len)
+    need = xp.max(xp.where(active, lengths + 1, 0), axis=-1)
+    return xp.minimum(-(-need // block), -(-max_len // block))
+
+
+def _plain(w):
+    """A weight as an array (a quantised leaf dequantised): for the
+    products that take it reshaped by head."""
+    return w.dequant() if hasattr(w, "dequant") else w
+
+
+def _latent_proj(cfg, p, a, rope):
+    """A latent family's attention projections of the normed stream ``a``
+    (``[B, T, D]``): ``(q, row, key)``. ``q``: the queries, a dict of
+    ``nope`` / ``rope`` (``[B, T, H, .]``, the rope part rotated) and the
+    indexer's ``iq`` (``[B, T, Hi, Di]``, its first ``qk_rope_head_dim``
+    rotated) and per-head weights ``iw`` (``[B, T, Hi]`` float32, scaled by
+    ``Hi ** -0.5 * Di ** -0.5``). ``row`` ``[B, T, 1, kv_lora_rank +
+    qk_rope_head_dim]``: the position's latent cache row, the normed
+    compressed K/V beside the ONE rotated key every head shares. ``key``
+    ``[B, T, 1, Di]``: its index key (LayerNorm, rotated likewise)."""
+    b, t, _ = a.shape
+    kl, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+
+    def rotated(x, n):
+        """RoPE on the first ``n`` dims of ``[B, T, heads, .]``."""
+        return jnp.concatenate(
+            [apply_rope(x[..., :n], *rope), x[..., n:]], axis=-1)
+
+    c_q = rms_norm(_dot(a, p["wqa"]), p["q_norm"]["w"], cfg.norm_eps)
+    q = _dot(c_q, p["wqb"]).reshape(b, t, cfg.num_heads, cfg.head_dim)
+    kv = _dot(a, p["wkva"])
+    c_kv = rms_norm(kv[..., :kl], p["kv_norm"]["w"], cfg.norm_eps)
+    k_r = apply_rope(kv[..., None, kl:], *rope)               # [B, T, 1, r]
+    iq = rotated(_dot(c_q, p["wiq"]).reshape(
+        b, t, cfg.index_n_heads, cfg.index_head_dim), r)
+    key = rotated(layer_norm(_dot(a, p["wik"]), p["ik_norm"]["w"],
+                             p["ik_norm"]["b"], 1e-6)[:, :, None], r)
+    iw = (jnp.dot(a.astype(jnp.float32), p["wiw"].astype(jnp.float32))
+          * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5))
+    nope = cfg.qk_nope_head_dim
+    return ({"nope": q[..., :nope], "rope": apply_rope(q[..., nope:], *rope),
+             "iq": iq, "iw": iw},
+            jnp.concatenate([c_kv[:, :, None], k_r], axis=-1), key)
+
+
+def _index_scores(q, keys):
+    """The indexer's score of every key for every query, float32: ``I(t, s)
+    = sum_h w_h(t) ReLU(q_h(t) . k(s))``. ``q``: `_latent_proj`'s (``iq``
+    ``[.., T, Hi, Di]``, ``iw`` ``[.., T, Hi]``); ``keys`` ``[.., n, Di]``
+    -> ``[.., T, n]``. The ONE place the ``indexer`` scope is opened."""
+    with jax.named_scope("indexer"):
+        sc = jnp.einsum("...thd,...kd->...thk", q["iq"],
+                        keys.astype(q["iq"].dtype),
+                        preferred_element_type=jnp.float32)
+        return (jax.nn.relu(sc) * q["iw"][..., None]).sum(-2)
+
+
+def _scores_by_block(q, read, n_blocks, m: int):
+    """`_index_scores` of the queries ``q`` (``[B, T, ..]``) against the
+    first ``n_blocks`` (traced) blocks of `index_block` index keys of an
+    ``m``-row slot, ``read(start)`` giving a block's keys ``[B, blk, Di]``:
+    ``[B, T, m]`` float32, `NEG_INF` past the blocks read. A last block
+    that would pass the slot's end starts early and scores rows again."""
+    blk = index_block(m)
+
+    def one(j, acc):
+        start = jnp.minimum(j * blk, m - blk)
+        return jax.lax.dynamic_update_slice(
+            acc, _index_scores(q, read(start)), (0, 0, start))
+
+    return jax.lax.fori_loop(0, n_blocks, one, jnp.full(
+        q["iw"].shape[:2] + (m,), NEG_INF, jnp.float32))
+
+
+def select_topk(scores, k: int):
+    """Which ``k`` entries of every row of ``scores`` (``[.., n]`` float32)
+    are its largest, as a mask: EXACTLY what ``jax.lax.top_k`` picks, ties
+    to the lower position, with no sort of a ``[T, n]`` array. The k-th
+    largest value is found by bisection on the scores' integer image (a
+    float's bits, made monotone, as an unsigned number: 32 compares and
+    counts whatever ``n``); entries above it are in, and of those equal to
+    it the first ``k - (number above)`` by position. ``k >= n``: every
+    entry. The prefill's form of the selection: a chunk's ``[T, n]`` scores
+    need a MASK over the blocks it attends by, and a sort of them is what
+    this avoids; a decode tick needs the ``k`` INDICES of one row a slot
+    for its gather, which a mask would have to be compacted into (a sort
+    or a scatter of ``n`` entries again), so it takes ``jax.lax.top_k``
+    itself (`_attend_latent`)."""
+    n = scores.shape[-1]
+    if k >= n:
+        return jnp.ones(scores.shape, bool)
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    img = jnp.where(bits >= 0, bits, bits ^ jnp.int32(0x7FFFFFFF))
+    img = jax.lax.bitcast_convert_type(img, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+    def bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = (img >= cand[..., None]).sum(-1) >= k
+        return jnp.where(enough, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = img > kth[..., None]
+    tie = img == kth[..., None]
+    rank = jnp.cumsum(tie.astype(jnp.int32), axis=-1) - 1
+    return above | (tie & (rank < (k - above.sum(-1))[..., None]))
+
+
+def _attend_latent(cfg, lp, q, rows, keys, q_pos):
+    """Attention of a latent family under its learned selection: ``[B, T,
+    H * v_head_dim]``, before the output projection. Every query scores
+    the index keys of the positions up to its own (`_index_scores`),
+    attends to the ``index_topk`` best (all, while it has no more) and
+    reads only those positions' latent rows. ONE function of the rows,
+    in the two forms the programs need:
+
+    A decode step (``rows`` / ``keys`` `_CacheLayer`s of the two stacks,
+    one query row a slot): scores by blocks of `index_block` index keys up
+    to the longest active slot's length (``keys.blocks``, traced),
+    ``jax.lax.top_k``, a gather of the selected rows, and the ABSORBED
+    products: ``q_nope W_kvb,k^T`` against the row's compressed part and
+    ``q_rope`` against its rotated key, the weighted sum of compressed
+    parts through ``W_kvb,v`` after it. No key or value of a head is ever
+    made for a cached position.
+
+    A prefill chunk (``rows`` ``[1, M, .]`` / ``keys`` ``[1, M, Di]``: the
+    slot's layer with the chunk's own rows written, ``q_pos`` ``[T, 1]``):
+    the EXPANDED form. Scores of all T queries against the blocks of index
+    keys up to the chunk's end, the selection as a mask (`select_topk`;
+    skipped while no query of the chunk is past row ``index_topk``), then
+    an online softmax over the same blocks, each block's rows expanded
+    through ``W_kvb`` to the heads' keys and values once for all T
+    queries."""
+    kl, nope, vd = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+    heads, topk = cfg.num_heads, cfg.index_topk
+    wkvb = _plain(lp["attn"]["wkvb"]).reshape(kl, heads, nope + vd)
+    scale = cfg.head_dim ** -0.5
+    if isinstance(rows, _CacheLayer):
+        _, slots, m = rows.stack.shape[:3]
+        at = jnp.arange(m, dtype=jnp.int32)[None, :]
+        p = q_pos[:, 0]                                        # [S, 1]
+        scores = jnp.where(at <= p, _scores_by_block(
+            q, lambda start: jax.lax.dynamic_slice(
+                keys.stack, (keys.at, 0, start, 0),
+                (1, slots, index_block(m), keys.stack.shape[3]))[0],
+            keys.blocks, m)[:, 0], NEG_INF)
+        with jax.named_scope("topk_select"):
+            # INDICES, for the gather below: ``jax.lax.top_k``'s pick is
+            # the definition of the selection (ties to the lower position)
+            # and `select_topk`, the prefill's mask, is held to it
+            _, sel = jax.lax.top_k(scores, min(topk, m))       # [S, k]
+        with jax.named_scope("latent_read"):
+            pick = jnp.stack(jnp.broadcast_arrays(
+                rows.at, jnp.arange(slots, dtype=jnp.int32)[:, None], sel),
+                axis=-1)
+            got = jax.lax.gather(
+                rows.stack, pick,
+                jax.lax.GatherDimensionNumbers(
+                    offset_dims=(2,), collapsed_slice_dims=(0, 1, 2),
+                    start_index_map=(0, 1, 2)),
+                slice_sizes=(1, 1, 1, rows.stack.shape[3]),
+                mode="promise_in_bounds")                      # [S, k, .]
+        dt = q["nope"].dtype
+        c_kv = got[..., :kl].astype(dt)
+        k_r = got[..., kl:kl + q["rope"].shape[-1]].astype(dt)
+        q_abs = jnp.einsum("shn,lhn->shl", q["nope"][:, 0],
+                           wkvb[..., :nope].astype(dt))
+        sc = (jnp.einsum("shl,skl->shk", q_abs, c_kv,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("shr,skr->shk", q["rope"][:, 0], k_r,
+                           preferred_element_type=jnp.float32)) * scale
+        probs = jax.nn.softmax(
+            jnp.where((sel <= p)[:, None, :], sc, NEG_INF), axis=-1)
+        o_lat = jnp.einsum("shk,skl->shl", probs.astype(dt), c_kv)
+        out = jnp.einsum("shl,lhv->shv", o_lat, wkvb[..., nope:].astype(dt))
+        return out.reshape(slots, 1, -1)
+
+    t, m = q["nope"].shape[1], rows.shape[1]
+    blk = index_block(m)
+    last = q_pos.max()
+    n_blocks = last // blk + 1
+
+    def start_of(j):
+        return jnp.minimum(j * blk, m - blk)
+
+    at = jnp.arange(m, dtype=jnp.int32)[None, :]
+    causal = at <= q_pos                                       # [T, M]
+    scores = jnp.where(causal, _scores_by_block(
+        q, lambda start: jax.lax.dynamic_slice_in_dim(keys, start, blk, 1),
+        n_blocks, m)[0], NEG_INF)
+    with jax.named_scope("topk_select"):
+        chosen = (causal if topk >= m else jax.lax.cond(
+            last >= topk, lambda: causal & select_topk(scores, topk),
+            lambda: causal))
+    dt = q["nope"].dtype
+    q_n, q_r = q["nope"][0], q["rope"][0]                      # [T, H, .]
+
+    def block(j, carry):
+        mx, l, acc = carry
+        with jax.named_scope("latent_read"):
+            got = jax.lax.dynamic_slice_in_dim(
+                rows[0], start_of(j), blk, 0).astype(dt)       # [blk, .]
+        kv = jnp.einsum("kl,lhe->khe", got[:, :kl], wkvb.astype(dt))
+        sc = (jnp.einsum("thn,khn->htk", q_n, kv[..., :nope],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("thr,kr->htk", q_r,
+                           got[:, kl:kl + q_r.shape[-1]],
+                           preferred_element_type=jnp.float32)) * scale
+        # a last block that starts early (``m`` no multiple of the block)
+        # holds rows an earlier block has counted
+        ok = (jax.lax.dynamic_slice_in_dim(chosen, start_of(j), blk, 1)
+              & (start_of(j) + jnp.arange(blk) >= j * blk)[None, :])[None]
+        sc = jnp.where(ok, sc, NEG_INF)
+        m2 = jnp.maximum(mx, sc.max(-1))
+        corr = jnp.exp(mx - m2)
+        w = jnp.where(ok, jnp.exp(sc - m2[..., None]), 0.0)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "htk,khv->htv", w.astype(dt), kv[..., nope:],
+            preferred_element_type=jnp.float32)
+        return m2, l * corr + w.sum(-1), acc
+
+    stat = (heads, t)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, block, (
+        jnp.full(stat, NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+        jnp.zeros(stat + (vd,), jnp.float32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]               # [H, T, v]
+    return out.transpose(1, 0, 2).reshape(1, t, -1).astype(dt)
+
+
 def _decoder_layer(cfg, lp, h, rope, cache_policy):
     """One decoder layer of every engine program: ``(h, state)``.
 
@@ -791,13 +1079,20 @@ def _decoder_layer(cfg, lp, h, rope, cache_policy):
     lp = dequant_tree(lp, keep_experts=cfg.is_moe)
     with jax.named_scope("attention"):
         a = _normed(cfg, lp["ln1"], h)
-        q, k, v = qkv_proj(cfg, lp["attn"], a)          # [B, T, H/Hkv, Dh]
-        if rope is not None:
-            q = apply_rope(q, *rope)
-            k = apply_rope(k, *rope)
+        if cfg.kv_lora_rank:
+            # the policy is handed the position's TWO rows, latent row and
+            # index key, where every other family's gets k and v
+            q, k, v = _latent_proj(cfg, lp["attn"], a, rope)
+        else:
+            q, k, v = qkv_proj(cfg, lp["attn"], a)      # [B, T, H/Hkv, Dh]
+            if rope is not None:
+                q = apply_rope(q, *rope)
+                k = apply_rope(k, *rope)
     keys, values, grid, state = cache_policy(k, v)
     with jax.named_scope("attention"):
-        if isinstance(keys, tuple):
+        if cfg.kv_lora_rank:
+            out = _attend_latent(cfg, lp, q, keys, values, grid[1])
+        elif isinstance(keys, tuple):
             outs, row = [], 0
             for k_g, v_g, grid_g in zip(keys, values, grid):
                 b_g, t_g = grid_g[1].shape[:2]
@@ -812,7 +1107,8 @@ def _decoder_layer(cfg, lp, h, rope, cache_policy):
         out = _dot(out, lp["attn"]["wo"])
         if "bo" in lp["attn"]:
             out = out + lp["attn"]["bo"]
-    return _residual(cfg, lp, h, out), state
+    h, extra = _residual(cfg, lp, h, out)
+    return h, (*state, *extra)
 
 
 def _split_stacks(layers):
@@ -879,13 +1175,44 @@ def _scan_layers(layer, h, layers, *xs):
         body, h, (jnp.arange(n, dtype=jnp.int32), rest, *xs))
 
 
+def _layer_groups(params):
+    """The span's layers as the scans take them: ``[(stacked tree, index of
+    its first layer)]``. ONE stack for every family but one with leading
+    dense layers (``cfg.first_k_dense``), whose first layers are another
+    kind than the rest and stacked apart (``dense_layers``): the same layer
+    body scans each group in turn, inside ONE program."""
+    if "dense_layers" not in params:
+        return [(params["layers"], 0)]
+    n = jax.tree.leaves(params["dense_layers"])[0].shape[0]
+    return [(params["dense_layers"], 0), (params["layers"], n)]
+
+
+def _scan_groups(params, layer, h, *xs):
+    """`_scan_layers` over every group of `_layer_groups` in turn, each
+    handed its own layers' share of ``xs``: ``(h, ys)`` with every stacked
+    output's first two members (a prefill's new cache rows) concatenated
+    over the groups. One group: `_scan_layers` as it is."""
+    groups = _layer_groups(params)
+    if len(groups) == 1:
+        return _scan_layers(layer, h, params["layers"], *xs)
+    outs = []
+    for layers, first in groups:
+        n = jax.tree.leaves(layers)[0].shape[0]
+        h, ys = _scan_layers(layer, h, layers,
+                             *(x[first:first + n] for x in xs))
+        outs.append(ys[:2])
+    return h, tuple(jnp.concatenate(rows) for rows in zip(*outs))
+
+
 def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     """``lax.scan`` over the span's layers with the cache stacks as CARRY:
     ``layer(h, (lp, i, k_all, v_all)) -> (h, (k_all, v_all))`` is handed
     the WHOLE ``[L, S, max_len, Hkv, Dh]`` stacks and its own index ``i``,
     writes its new rows into them and reads what it attends over out of
     them, so XLA updates the carried buffers in place and no layer's
-    ``[S, max_len, Hkv, Dh]`` slab is ever the operand of an update. ``i``
+    ``[S, max_len, Hkv, Dh]`` slab is ever the operand of an update. What a
+    layer returns beside the stacks (an expert layer's held assignments)
+    comes back stacked, fourth. ``i``
     indexes the WEIGHTS (``0 .. layers - 1``); which cache layer a weight
     layer writes is the caller's (`_run_passes`: a looped stack's cache is
     ``loop_steps`` times as deep as its weights).
@@ -905,14 +1232,14 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     def body(carry, xs):
         h, k_all, v_all = carry
         lp, i = xs
-        h, (k_all, v_all) = layer(
+        h, (k_all, v_all, *more) = layer(
             h, (_layer_at(lp, held, i), i, k_all, v_all))
-        return (h, k_all, v_all), None
+        return (h, k_all, v_all), tuple(more)
 
     n = jax.tree.leaves(layers)[0].shape[0]
-    (h, k_all, v_all), _ = jax.lax.scan(
+    (h, k_all, v_all), more = jax.lax.scan(
         body, (h, k_all, v_all), (rest, jnp.arange(n, dtype=jnp.int32)))
-    return h, k_all, v_all
+    return h, k_all, v_all, more
 
 
 def _run_passes(cfg, params, h, one_pass, k_all, v_all):
@@ -922,19 +1249,20 @@ def _run_passes(cfg, params, h, one_pass, k_all, v_all):
     cache policy; weight layer ``i`` of it reads and writes cache layer
     ``base + i``. A stack that runs once gets ``base`` None (cache layer ==
     weight layer, the program every family had before there was a loop)
-    and ``steps`` None. A looped stack (``cfg.loop_steps`` passes) scans
+    and ``steps`` None, or what ``one_pass`` returns fourth (the held
+    assignments of a family with expert layers). A looped stack (``cfg.loop_steps`` passes) scans
     the passes over the SAME stacked weights, pass ``t`` at ``base = t *
     layers`` of the ``loop_steps * layers`` deep stacks, `close_pass`
     after each (the final norm that feeds the next pass, the exit gate and
     rule): ``h`` is then each token's chosen, already NORMED state and
     ``steps`` ``[B, T]`` the passes it took."""
     if cfg.loop_steps == 1:
-        return (*one_pass(h, None, k_all, v_all), None)
+        return (*one_pass(h, None, k_all, v_all), None)[:4]
     per_pass = k_all.shape[0] // cfg.loop_steps
 
     def body(carry, t):
         h, k_all, v_all, state = carry
-        x, k_all, v_all = one_pass(h, t * per_pass, k_all, v_all)
+        x, k_all, v_all = one_pass(h, t * per_pass, k_all, v_all)[:3]
         h, state, _ = close_pass(cfg, params, x, t, state)
         return (h, k_all, v_all, state), None
 
@@ -1036,7 +1364,12 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
 
     A family whose older rows are summaries (``cfg.eva_window``; T = 1):
     ``k_all`` and ``v_all`` are `_WindowedStacks`, the policy
-    `_append_windowed`."""
+    `_append_windowed`.
+
+    A latent family (``cfg.kv_lora_rank``; T = 1): ``k_all`` is the stack
+    of latent rows, ``v_all`` that of index keys, a row of each written a
+    position a layer by the same `_append_rows`; ``steps`` is then which
+    rows chose which held expert, ``[expert layers, S, held]``."""
     slots = x.shape[0]
     if rider is not None:
         r_pos = rider["start"] + jnp.arange(
@@ -1057,7 +1390,9 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     kernel = cache_read(
         cfg, params["layers"], jax.tree.leaves(k_all)[0].ndim == 4,
         qpos.shape[1], rider is not None) == "kernel"
-    if cfg.eva_window:
+    if cfg.kv_lora_rank:
+        blocks = index_blocks(lengths, active, k_all.shape[2], jnp)
+    elif cfg.eva_window:
         rows = (k_all.exact.shape[2], k_all.sums.shape[2])
         blocks = windowed_blocks(cfg, lengths, active, rows, jnp,
                                  per_slot=kernel)
@@ -1088,9 +1423,10 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
             jnp.concatenate([active, rider["valid"]]))
 
     def one_pass(h, base, k_all, v_all):
-        def layer(h, xs):
+        def layer(first, h, xs):
+            # weight layer ``i`` of the group that starts at layer ``first``
             lp, i, k_all, v_all = xs
-            at = _at(base, i)
+            at = _at(base, i + first if first else i)
 
             def per_slot_append(k, v):
                 # Write the T new rows a slot into the stacks, THEN hand
@@ -1126,8 +1462,11 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
 
             return _decoder_layer(cfg, lp, h, rope, per_slot_append)
 
-        return _scan_layers_in_place(
-            layer, h, params["layers"], k_all, v_all)
+        more = ()
+        for layers, first in _layer_groups(params):
+            h, k_all, v_all, more = _scan_layers_in_place(
+                partial(layer, first), h, layers, k_all, v_all)
+        return (h, k_all, v_all, *more)
 
     return _run_passes(cfg, params, h, one_pass, k_all, v_all)
 
@@ -1197,6 +1536,8 @@ class BatchedStageExecutor:
         self._m_sum_rows_read = _tm.get("server_attn_summary_rows_read_total")
         self._m_chunks = _tm.get("server_kv_chunks_summarised_total")
         self._m_written = _tm.get("server_kv_positions_written_total")
+        self._m_index_scored = _tm.get("server_index_rows_scored_total")
+        self._m_moe = [_tm.get(name) for name in MOE_COUNTERS]
         self._m_rows_held = _tm.get("server_state_rows_held_total")
         self._m_pos_held = _tm.get("server_positions_held_total")
         # Prompt-prefix KV reuse (runtime.prefix_cache), slot-layout
@@ -1206,7 +1547,7 @@ class BatchedStageExecutor:
         # digests as the session executor's store.
         self.prefix_store = None
         if prefix_cache_bytes > 0:
-            if cfg.eva_window:
+            if cfg.eva_window or cfg.kv_lora_rank:
                 refuse_single_pass(cfg, "the prefix cache (a stored prefix "
                                         "is a slice of rows)")
             from .prefix_cache import PrefixStore
@@ -1223,7 +1564,10 @@ class BatchedStageExecutor:
         (`kv_fold_width`); the programs tell by the stack's rank. A family
         whose older rows are summaries holds TWO stacks each
         (`_WindowedStacks`): the current window's exact rows and one
-        summary row a chunk of the earlier ones (`windowed_rows`)."""
+        summary row a chunk of the earlier ones (`windowed_rows`). A latent
+        family holds other rows altogether: `_new_latent_stacks`."""
+        if self.cfg.kv_lora_rank:
+            return self._new_latent_stacks()
         row = (self.cfg.num_kv_heads, self.cfg.head_dim)
         asked = jnp.zeros((1, 1, self.max_len) + row,
                           self.dtype).format.layout
@@ -1258,6 +1602,40 @@ class BatchedStageExecutor:
             **({"rows": list(counts), "summary_shape": list(shapes[1])}
                if windowed else {}))
 
+    def _new_latent_stacks(self) -> None:
+        """`_new_stacks` for a latent family under a learned selection:
+        ``self.k`` is the stack of latent rows ``[L, S, max_len,
+        kv_lora_rank + qk_rope_head_dim]``, ``self.v`` that of index keys
+        ``[L, S, max_len, index_head_dim]``: TWO rows a position a layer,
+        of different widths, neither per head (a row's numbers are its
+        minor dim already; the programs read a latent row's first
+        ``kv_lora_rank + qk_rope_head_dim`` numbers whatever its pad)."""
+        cfg = self.cfg
+        depth = max(self.spec.num_layers, 1)
+        row = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        # Where the backend would not keep a row of that many numbers minor
+        # (the v5e holds 576 with ``max_len`` minor, to pad nothing, and
+        # every program then re-lays the stack at its edges: PERF.md
+        # section 6, PRs 45 and 55), the row is held padded with zeros to
+        # whole lane tiles, as a folded row is (`kv_fold_width`: 640).
+        asked = jnp.zeros((1, 1, self.max_len, row), self.dtype).format.layout
+        widths = (kv_fold_width(asked, 1, row) or row, cfg.index_head_dim)
+        self.k, self.v = (jnp.zeros((depth, self.slots, self.max_len, w),
+                                    self.dtype) for w in widths)
+        _tm.get("server_kv_stack_bytes").set(self.k.nbytes + self.v.nbytes)
+        _ev.emit(
+            "kv_layout", shape=list(self.k.shape), dtype=str(self.k.dtype),
+            layout=layout_text(self.k.format.layout), row=[row],
+            row_layout=layout_text(asked),
+            folded_to=widths[0] if widths[0] != row else None,
+            read=self._cache_read(1, False),
+            logical_bytes_a_stack=int(self.k.nbytes),
+            resident_bytes_a_stack=int(self.k.on_device_size_in_bytes()),
+            index_shape=list(self.v.shape), index_row=[widths[1]],
+            index_layout=layout_text(self.v.format.layout),
+            index_resident_bytes=int(self.v.on_device_size_in_bytes()),
+            selected_rows=min(cfg.index_topk, self.max_len))
+
     def _cache_read(self, t: int, rider: bool) -> str:
         """`cache_read` of this engine's decode program of ``t`` new rows a
         slot, with or without a ``rider`` group."""
@@ -1275,10 +1653,23 @@ class BatchedStageExecutor:
         and an inactive one not at all. A windowed family's
         bounds are `windowed_blocks`' two, shared or per slot likewise: its
         exact rows go to the same counter, its summary rows to one of
-        their own, and the chunks its ticks closed and pooled to a third."""
+        their own, and the chunks its ticks closed and pooled to a third.
+        A latent family under a learned selection: the index keys it
+        scored to ``server_index_rows_scored_total``, the latent rows it
+        SELECTED and read to the rows-read counter."""
         kernel = self._cache_read(t, rider) == "kernel"
         each = 1 if kernel else self.slots      # slots that read a count
-        if self.cfg.eva_window:
+        if self.cfg.kv_lora_rank:
+            # index keys scored: every slot's blocks to the longest active
+            # one's; latent rows read: those SELECTED, an active slot's
+            # positions up to ``index_topk``
+            self._m_index_scored.inc(
+                int(index_blocks(lengths, active, self.max_len).sum())
+                * index_block(self.max_len) * self.slots)
+            self._m_rows_read.inc(int(np.where(
+                active, np.minimum(lengths + 1, self.cfg.index_topk),
+                0).sum()))
+        elif self.cfg.eva_window:
             rows_e, rows_s = rows = windowed_rows(self.cfg, self.max_len)
             exact, sums = windowed_blocks(self.cfg, lengths, active, rows,
                                           per_slot=kernel)
@@ -1521,7 +1912,10 @@ class BatchedStageExecutor:
         """Prefill CONTINUATION for a prefix-cache hit: the suffix enters at
         position p_len and attends over the slot's cache rows (the copied
         prefix) plus its own fresh keys — the slot-batched analogue of the
-        session executor's chunked continuation."""
+        session executor's chunked continuation. For a latent family
+        under a learned selection it is THE prefill program: every chunk
+        of a prompt is a continuation of the chunks before it
+        (`_prefill_chunks`)."""
         cfg, spec = self.cfg, self.spec
 
         @partial(jax.jit, donate_argnums=engine_donation(3, 4))
@@ -1550,6 +1944,9 @@ class BatchedStageExecutor:
                             k_l, _fold(k_all, k).astype(k_l.dtype), p_len, 1)
                         v_new = jax.lax.dynamic_update_slice_in_dim(
                             v_l, _fold(v_all, v).astype(v_l.dtype), p_len, 1)
+                    if cfg.kv_lora_rank:    # the slot's two rows, as held
+                        return (k_new, v_new, (None, qpos, None),
+                                (k_new, v_new))
                     return (_unfold(k_all, cfg, k_new),
                             _unfold(v_all, cfg, v_new),
                             (allowed, qpos, pos_grid), (k_new, v_new))
@@ -1562,8 +1959,7 @@ class BatchedStageExecutor:
                     n = k_slot.shape[0] // cfg.loop_steps
                     k_in = jax.lax.dynamic_slice_in_dim(k_slot, base, n, 0)
                     v_in = jax.lax.dynamic_slice_in_dim(v_slot, base, n, 0)
-                h, (ks, vs) = _scan_layers(
-                    layer, h, params["layers"], k_in, v_in)
+                h, (ks, vs) = _scan_groups(params, layer, h, k_in, v_in)
                 with jax.named_scope("kv_update"):
                     k_all = jax.lax.dynamic_update_slice(
                         k_all, ks, _origin(k_all, _at(base, 0), slot))
@@ -1719,7 +2115,42 @@ class BatchedStageExecutor:
             self.lengths[:] = 0
             self._free = list(range(self.slots))
 
+    def _prefill_chunks(self, session_id: str, x) -> jnp.ndarray:
+        """`_prefill_full` for a latent family under a learned selection:
+        the prompt in chunks of `LATENT_CHUNK` rows, its tail padded to a
+        bucket, each through the suffix program in turn (a chunk's queries
+        score and attend over the slot's rows so far, its own among them:
+        `_attend_latent`). ONE program shape and the tail's buckets serve
+        a prompt of any length, and no array grows with the square of
+        it."""
+        x = np.asarray(x)
+        t = x.shape[1]
+        if t > self.max_len:
+            raise ValueError(f"prompt {t} exceeds slot max_len {self.max_len}")
+        s = self._alloc(session_id)
+        if self._suffix_jit is None:
+            self._suffix_jit = self._build_prefill_suffix()
+        outs = []
+        try:
+            for p0 in range(0, t, LATENT_CHUNK):
+                part = x[:, p0:p0 + LATENT_CHUNK]
+                n = part.shape[1]
+                tb = (n if n == LATENT_CHUNK else min(
+                    round_to_bucket(n, PREFILL_BUCKETS), self.max_len - p0))
+                h, self.k, self.v = self._suffix_jit(
+                    self.params, np.pad(part, ((0, 0), (0, tb - n))),
+                    np.int32(s), self.k, self.v, np.int32(p0), np.int32(n))
+                outs.append(h if tb == n else h[:, :n])
+        except Exception:
+            self._recover_slot(session_id, s)
+            raise
+        self.lengths[s] = t
+        self._m_written.inc(t)
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
     def _prefill_full(self, session_id: str, x) -> jnp.ndarray:
+        if self.cfg.kv_lora_rank:
+            return self._prefill_chunks(session_id, x)
         if self.cfg.eva_window:
             return self._prefill_windows(session_id, x)
         if not isinstance(x, jax.Array):
@@ -1795,6 +2226,12 @@ class BatchedStageExecutor:
             return {}
         sids = list(inputs)
         t = int(np.asarray(inputs[sids[0]]).shape[1])
+        if t > 1 and self.cfg.kv_lora_rank:
+            raise NotImplementedError(
+                f"a step of {t} rows a slot on a latent family under a "
+                "learned selection (the decode step selects for ONE query "
+                "row a slot): speculative verify is not served, and a "
+                "journal replay rebuilds the slot through prefill")
         if t > 1 and self.cfg.eva_window:
             raise NotImplementedError(
                 f"a step of {t} rows a slot on a family whose older rows "
@@ -1868,6 +2305,10 @@ class BatchedStageExecutor:
         A looped stack's program (``cfg.loop_steps > 1``) carries one more
         value through the ticks: the passes taken by the tokens the burst
         emitted, summed on the device (``server_loop_exit_steps_total``).
+        A family with expert layers that hold a share carries four
+        (`MOE_COUNTERS`): the ticks' routed assignments, those to a held
+        expert, the held experts some row chose and the held experts there
+        were, summed over the burst's ticks and expert layers.
         With a rider lane (``self.rider_rows``) the program takes one more
         ARGUMENT, the rider (`_rider_args`), every tick carries the lane's
         rows through the layers (`_decode_span`), the head and the sampler
@@ -1884,6 +2325,7 @@ class BatchedStageExecutor:
         separate arrays held."""
         cfg, spec = self.cfg, self.spec
         looped = cfg.loop_steps > 1
+        routed = holds_expert_share(cfg)
         lane = self.rider_rows
         S = self.slots
         N = n_ticks
@@ -1979,6 +2421,15 @@ class BatchedStageExecutor:
                     if looped:
                         more[0] = more[0] + jnp.sum(
                             jnp.where(active, steps[:, 0], 0))
+                    if routed:      # `MOE_COUNTERS`, this tick's share
+                        took = steps & active[None, :, None]
+                        n_layers, _, n_held = steps.shape
+                        live = active.any().astype(jnp.int32)
+                        more[0] = more[0] + jnp.stack([
+                            active.sum() * n_layers
+                            * cfg.num_experts_per_tok,
+                            took.sum(), took.any(1).sum(),
+                            live * n_layers * n_held]).astype(jnp.int32)
                 return (tok, lengths, alive, recent, nvalid, run_next,
                         left_next, stop, k_all, v_all, *more), out_tok
 
@@ -1987,11 +2438,14 @@ class BatchedStageExecutor:
                 tick,
                 (tok, lengths, alive, recent, nvalid, run, left, stop0,
                  k_all, v_all, *([jnp.int32(0)] if looped else []),
+                 *([jnp.zeros((len(MOE_COUNTERS),), jnp.int32)]
+                   if routed else []),
                  *([jnp.int32(-1)] if lane else [])),
                 jnp.arange(N, dtype=jnp.int32))
             (_, lengths, _, _, _, _, _, stop, k_all, v_all, *more) = carry
             return (jnp.concatenate([toks.reshape(-1), stop, lengths,
-                                     *(m[None] for m in more)]),
+                                     *(m if m.ndim else m[None]
+                                       for m in more)]),
                     k_all, v_all)
 
         return burst_tick
@@ -2084,6 +2538,9 @@ class BatchedStageExecutor:
         tail = flat[(n_ticks + 2) * S:]     # passes (looped), token (lane)
         if self.cfg.loop_steps > 1:
             self._m_exit_steps.inc(int(tail[0]))
+        if holds_expert_share(self.cfg):
+            for series, n in zip(self._m_moe, tail):
+                series.inc(int(n))
         # Tick i began with every slot i rows on, those still emitting
         # active: a slot's emitted ticks are a prefix of the burst's.
         grown = np.zeros((self.slots,), np.int64)     # tokens a slot emitted
@@ -2630,8 +3087,11 @@ class BatchingStageAdapter:
         from .messages import StageResponse
 
         if self.spec.is_last:
-            logits = self.inner.logits(hidden_row)
-            token = _sample_last(logits, hidden_row.shape[1], req)
+            # the head over the ONE row the token is sampled from: a
+            # 14000-row prompt's other rows are 1.1 GB of float32 logits
+            # (and as much again of normed rows) that nothing reads
+            logits = self.inner.logits(hidden_row[:, -1:])
+            token = _sample_last(logits, 1, req)
             return StageResponse(session_id=req.session_id, token_id=token,
                                  cache_len=cache_len)
         return StageResponse(session_id=req.session_id, hidden=hidden_row,

@@ -297,6 +297,34 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "rows, a slot in its first window none. "
                  "server_attn_rows_read_total keeps the exact rows.",
         (), None),
+    "server_index_rows_scored_total": (
+        COUNTER, "Index keys of ONE cache layer that the decode steps and "
+                 "burst ticks of a latent family under a learned selection "
+                 "scored: per tick, the blocks of runtime.batching."
+                 "index_block rows up to the longest active slot "
+                 "(index_blocks) x the block's rows x slots; counted on "
+                 "the host from the lengths a step began and ended with. "
+                 "server_attn_rows_read_total keeps the latent rows it then "
+                 "SELECTED and read: min(positions, index_topk) an active "
+                 "slot a tick.", (), None),
+    "server_moe_assignments_total": (
+        COUNTER, "Routed assignments (rows x num_experts_per_tok x expert "
+                 "layers) of the burst ticks' active rows, over ALL "
+                 "experts, where the expert layers hold a share "
+                 "(models.moe.held_moe_mlp); summed ON THE DEVICE inside "
+                 "the burst program, as the three series below.", (), None),
+    "server_moe_assignments_held_total": (
+        COUNTER, "Of those, the assignments to an expert this server "
+                 "holds: over server_moe_assignments_total the share of "
+                 "the routed work that is done here (held / all experts "
+                 "under even routing).", (), None),
+    "server_moe_experts_hit_total": (
+        COUNTER, "Held experts that at least one active row of a tick "
+                 "chose, summed over ticks and expert layers.", (), None),
+    "server_moe_expert_slots_total": (
+        COUNTER, "Held experts there were to choose (held x expert layers "
+                 "a tick with an active row): every one is streamed every "
+                 "tick whether hit or not.", (), None),
     "server_kv_chunks_summarised_total": (
         COUNTER, "Chunks of positions whose exact K/V rows were pooled into "
                  "a summary row: by a prefill (its whole chunks) and by the "
@@ -320,7 +348,9 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
         GAUGE, "Bytes of the batched engine's resident K and V cache "
                "stacks (all of them together: a looped stack holds rows "
                "for every pass of every layer; a family whose older rows "
-               "are summaries two stacks each for K and V).", (), None),
+               "are summaries two stacks each for K and V; a latent "
+               "family under a learned selection the stack of latent rows "
+               "and the stack of index keys).", (), None),
     "server_burst_ticks": (
         HISTOGRAM, "Configured tick count per burst dispatch (the N of "
                    "each lax.scan program).", (), FILL_BUCKETS),

@@ -211,7 +211,11 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "logical_bytes_a_stack; resident_bytes_a_stack = as laid out, "
         "with the padding of its tiles; for a family whose older rows are "
         "summaries also rows = [exact rows, summary rows] a slot and "
-        "summary_shape, shape then being the window stack's)."),
+        "summary_shape, shape then being the window stack's; for a latent "
+        "family under a learned selection shape and row are the latent "
+        "rows' stack, index_shape / index_row / index_layout / "
+        "index_resident_bytes the index keys', read = select and "
+        "selected_rows = the rows a query reads at most)."),
     "burst_fallback": (
         "client", WARN,
         "A burst-mode session fell back to per-step decode because no "

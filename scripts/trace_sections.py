@@ -40,8 +40,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 SCOPES = ("embed", "attention", "kv_update", "mlp", "loop_norm",
-          "exit_gate", "head", "sampler", "stop_rules")
+          "exit_gate", "head", "sampler", "stop_rules",
+          # opened INSIDE ``attention`` / ``mlp`` by a latent family under a
+          # learned selection with expert layers: the innermost name counts
+          "indexer", "topk_select", "latent_read", "router", "experts",
+          "shared_expert")
 SCOPE_RE = re.compile(r"(?:^|/)(%s)(?=/|$)" % "|".join(SCOPES))
+
+
+def _scope_in(op_name: str):
+    """The innermost of `SCOPES` in an operation's ``op_name`` path."""
+    found = SCOPE_RE.findall(op_name)
+    return found[-1] if found else None
 TICK_RE = re.compile(r"^jit_burst_tick")
 # Host events at least this long are kept, to show where a request's
 # thread stood still inside stage.first_token.
@@ -144,9 +154,9 @@ def reduce_file(path: str) -> dict:
                            or meta[mid].name[:40]),
                     os.path.basename(str(st.get("source", "-"))))
                 for key, val in st.items():
-                    m = isinstance(val, str) and SCOPE_RE.search(val)
+                    m = isinstance(val, str) and _scope_in(val)
                     if m:
-                        scope_of[mid] = (m.group(1), key)
+                        scope_of[mid] = (m, key)
                         example = example or {
                             "name": meta[mid].name[:160],
                             "stats": {k: str(v)[:160] for k, v in st.items()}}

@@ -396,6 +396,40 @@ def test_adapter_refuses_non_batchable_requests():
         adapter.forward(StageRequest(**base))
 
 
+def test_a_first_token_s_head_runs_over_the_last_row_alone():
+    """A prefill's first token is sampled from the prompt's last row: the
+    adapter hands the head that ONE row (a 14000-row prompt's other rows
+    were 1.1 GB of float32 logits nobody read), and the token is the one
+    the head over every row gives."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
+        BatchingStageAdapter,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
+        SamplingParams,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+        StageRequest,
+    )
+
+    cfg = tiny_cfg()
+    params = init_params(jax.random.PRNGKey(8), cfg)
+    inner = BatchedStageExecutor(cfg, full_spec(cfg), params,
+                                 slots=2, max_len=32)
+    adapter = BatchingStageAdapter(inner)
+    seen = []
+    head = inner.logits
+    inner.logits = lambda h: seen.append(h.shape) or head(h)
+    ids = jnp.asarray([[5, 9, 2, 7, 11, 3, 8]], jnp.int32)
+    resp = adapter.forward(StageRequest(
+        session_id="s", hidden=ids, seq_len=7, cur_len=0, is_prefill=True,
+        max_length=32, sampling=SamplingParams(temperature=0.0)))
+    assert seen == [(1, 1, cfg.hidden_size)]
+    twin = BatchedStageExecutor(cfg, full_spec(cfg), params,
+                                slots=2, max_len=32)
+    every = twin.logits(twin.prefill("s", ids))
+    assert resp.token_id == int(jnp.argmax(every[0, -1]))
+
+
 def test_adapter_refuses_stale_cur_len_and_round_survives():
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
         BatchingStageAdapter,

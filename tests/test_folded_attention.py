@@ -38,7 +38,7 @@ def head_cfg(heads, hkv, dh):
     """What `_attend` reads of a configuration."""
     return types.SimpleNamespace(
         num_kv_heads=hkv, num_heads=heads, head_dim=dh, query_scale=0.0,
-        attn_softcap=0.0, sliding_window=None)
+        attn_softcap=0.0, sliding_window=None, kv_lora_rank=0)
 
 
 def stacks(shape, dtype="float32", seed=0):

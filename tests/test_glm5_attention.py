@@ -1,0 +1,309 @@
+"""A latent family under a learned selection (GLM-5, ``glm_moe_dsa``) on the
+batched stage engine, at a small size with seeded weights, against the
+benchmark's plain reference (``perfbench/references/glm5_plain.py``, which
+imports nothing of the program): chunked prefill, decode steps and burst
+rounds through BOTH stacks (a latent row and an index key a position a
+layer) across the selection's edge at row ``index_topk``, across prefill
+chunks and index blocks, a rewind; the absorbed form of a decode step
+against the expanded form of a prefill chunk; the exact top-k mask against
+``jax.lax.top_k`` with ties; the counters; the importer's permutation; and
+every engine that cannot hold the state refusing the family by name."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+    config,
+    hf_import,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
+    StagePlan,
+    slice_stage_params,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
+    batching,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
+    telemetry,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry import (
+    catalog as tm,
+)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOPK = 16
+HF = dict(
+    model_type="glm_moe_dsa", hidden_size=64, intermediate_size=96,
+    moe_intermediate_size=32, num_attention_heads=4, q_lora_rank=32,
+    kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16,
+    index_n_heads=2, index_head_dim=8, index_topk=TOPK, n_routed_experts=8,
+    num_experts_per_tok=2, n_shared_experts=1, vocab_size=97,
+    first_k_dense_replace=1, rms_norm_eps=1e-5, routed_scaling_factor=2.5,
+    rope_parameters={"rope_theta": 1e6}, experts_held=4)
+LAYERS = 3
+
+
+def _ref():
+    spec = importlib.util.spec_from_file_location(
+        "glm5_plain", os.path.join(ROOT, "perfbench", "references",
+                                   "glm5_plain.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _ref()
+
+
+def small_config(**kw):
+    return config.glm5_config(**{**dict(
+        vocab_size=97, hidden_size=64, num_layers=LAYERS, num_heads=4,
+        intermediate_size=96, max_position_embeddings=512, rope_theta=1e6,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=12,
+        qk_rope_head_dim=4, v_head_dim=16, index_n_heads=2, index_head_dim=8,
+        index_topk=TOPK, n_routed_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=32, first_k_dense=1, experts_held=(0, 4)),
+        **kw})
+
+
+@pytest.fixture(scope="module")
+def weights():
+    return REF.make_weights(HF, LAYERS, 7, jnp.float32)
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Chunks of 16 prompt rows and blocks of 8 index keys: a 37-row prompt
+    is three chunks, its last one a bucket, over five blocks."""
+    monkeypatch.setattr(batching, "LATENT_CHUNK", 16)
+    monkeypatch.setattr(batching, "INDEX_BLOCK", 8)
+
+
+def engine(weights, *, slots=2, max_len=64, cfg=None):
+    cfg = cfg or small_config()
+    params = hf_import.convert_state_dict(cfg, weights, dtype=jnp.float32)
+    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
+    return batching.BatchedStageExecutor(
+        cfg, spec, slice_stage_params(cfg, params, spec), slots=slots,
+        max_len=max_len, dtype=jnp.float32)
+
+
+def logits_of(eng, h):
+    return np.asarray(eng.logits(h), np.float32)[0]
+
+
+def burst_entry(token, generated=()):
+    return {"token": int(token), "seed": 0, "budget": 4, "eos": None,
+            "generated": tuple(generated), "temperature": 0.0, "top_p": 1.0,
+            "top_k": 0, "repetition_penalty": 1.0}
+
+
+@pytest.mark.parametrize("n", [5, 15, 16, 37])
+def test_prefill_and_decode_steps_agree_with_the_reference(
+        weights, small_blocks, n):
+    """Prompts under, at and past the selection's edge (row 16) and the
+    chunk's (16 rows), then decode steps that cross both."""
+    ids = np.random.default_rng(n).integers(0, 97, (n + 6,)).astype(np.int32)
+    want = np.asarray(REF.forward(HF, LAYERS, weights, jnp.asarray(ids)))
+    eng = engine(weights)
+    got = logits_of(eng, eng.prefill("a", ids[None, :n]))
+    np.testing.assert_allclose(got, want[:n], atol=2e-5)
+    for j in range(6):
+        out = eng.decode_batch({"a": ids[None, n + j:n + j + 1]})
+        np.testing.assert_allclose(logits_of(eng, out["a"])[0], want[n + j],
+                                   atol=2e-5)
+
+
+def test_burst_rounds_two_slots_and_a_rewind(weights, small_blocks):
+    """Two sessions of different lengths side by side (one under the edge,
+    one past it), greedy burst rounds judged on the reference's rows, then
+    a rewind and the same tokens again."""
+    rng = np.random.default_rng(3)
+    lens = {"a": 11, "b": 29}
+    seqs = {k: rng.integers(0, 97, (n,)).astype(np.int32)
+            for k, n in lens.items()}
+    eng = engine(weights)
+    for sid, seq in seqs.items():
+        eng.prefill(sid, seq[None])
+    consumed = {k: [int(t) for t in v] for k, v in seqs.items()}
+    fed = {"a": 5, "b": 9}
+    emitted = {k: [] for k in seqs}
+    for _ in range(2):                      # 8 ticks: "a" crosses row 16
+        res = eng.decode_burst(
+            {sid: burst_entry(tok) for sid, tok in fed.items()}, 4)
+        for sid, r in res.items():
+            assert len(r["tokens"]) == 4
+            consumed[sid] += [fed[sid]] + r["tokens"][:-1]
+            emitted[sid] += r["tokens"]
+            fed[sid] = r["tokens"][-1]
+    for sid, ids in consumed.items():
+        want = np.asarray(REF.forward(
+            HF, LAYERS, weights, jnp.asarray(ids, jnp.int32)))
+        picked = want[lens[sid]:].argmax(-1)
+        assert list(picked) == emitted[sid], sid
+    # a rewind is a length: the rows past it are masked until rewritten
+    eng.rewind("a", lens["a"])
+    eng.rewind("b", lens["b"])
+    again = eng.decode_burst({"a": burst_entry(5), "b": burst_entry(9)}, 4)
+    assert again["a"]["tokens"] == emitted["a"][:4]
+    assert again["b"]["tokens"] == emitted["b"][:4]
+    assert again["b"]["cache_len"] == lens["b"] + 4
+
+
+def test_the_absorbed_step_is_the_expanded_chunk(weights, small_blocks):
+    """One function of the rows: the last row of a prompt through the
+    prefill chunk's EXPANDED form equals the same token through a decode
+    step's ABSORBED form, under and past the selection's edge."""
+    ids = np.random.default_rng(1).integers(0, 97, (40,)).astype(np.int32)
+    for n in (9, 33):
+        whole, stepped = engine(weights), engine(weights)
+        expanded = logits_of(whole, whole.prefill("a", ids[None, :n]))[-1]
+        stepped.prefill("a", ids[None, :n - 1])
+        out = stepped.decode_batch({"a": ids[None, n - 1:n]})
+        np.testing.assert_allclose(logits_of(stepped, out["a"])[0], expanded,
+                                   atol=2e-5)
+
+
+def test_a_latent_row_padded_to_lane_tiles_changes_nothing(
+        weights, small_blocks, monkeypatch):
+    """Where the backend would not keep a 20-number row minor the stack
+    holds it padded (the v5e: 576 -> 640): the programs read its first
+    ``kv_lora_rank + qk_rope_head_dim`` numbers whatever the pad."""
+    ids = np.random.default_rng(2).integers(0, 97, (30,)).astype(np.int32)
+    plain = engine(weights)
+    monkeypatch.setattr(batching, "kv_fold_width", lambda *a: 24)
+    padded = engine(weights)
+    assert plain.k.shape[-1] == 20 and padded.k.shape[-1] == 24
+    assert padded.v.shape == plain.v.shape
+    a = logits_of(plain, plain.prefill("a", ids[None, :26]))
+    b = logits_of(padded, padded.prefill("a", ids[None, :26]))
+    np.testing.assert_array_equal(a, b)
+    for j in range(26, 30):
+        x, y = (logits_of(e, e.decode_batch({"a": ids[None, j:j + 1]})["a"])
+                for e in (plain, padded))
+        np.testing.assert_array_equal(x, y)
+
+
+@pytest.mark.parametrize("k", [1, 3, 8, 31, 32, 40])
+def test_select_topk_is_lax_top_k_with_ties(k):
+    """The mask form against ``jax.lax.top_k``: many equal values (ties go
+    to the lower position), negatives, and rows of one value."""
+    rng = np.random.default_rng(k)
+    scores = rng.integers(-3, 4, (64, 32)).astype(np.float32) * 0.25
+    scores[5] = 1.5                                    # every entry ties
+    scores[6, :20] = batching.NEG_INF                  # masked entries
+    scores[7] = rng.standard_normal(32) * 1e-30        # tiny, both signs
+    mask = np.asarray(batching.select_topk(jnp.asarray(scores), k))
+    if k >= 32:
+        assert mask.all()
+        return
+    _, top = jax.lax.top_k(jnp.asarray(scores), k)
+    want = np.zeros_like(mask)
+    np.put_along_axis(want, np.asarray(top), True, axis=-1)
+    np.testing.assert_array_equal(mask, want)
+    assert (mask.sum(-1) == k).all()
+
+
+def test_the_slot_holds_two_rows_a_position_and_counts_what_it_reads(
+        weights, small_blocks):
+    telemetry.enable()
+    try:
+        _counts_what_it_reads(weights)
+    finally:
+        telemetry.disable()
+
+
+def _counts_what_it_reads(weights):
+    eng = engine(weights, slots=2, max_len=64)
+    assert tm.get("server_kv_stack_bytes").value == \
+        eng.k.nbytes + eng.v.nbytes
+    cfg = eng.cfg
+    assert eng.k.shape == (LAYERS, 2, 64, 16 + 4)
+    assert eng.v.shape == (LAYERS, 2, 64, 8)
+    assert batching.cache_read(cfg, eng.params["layers"], True) == "select"
+    scored, read = (tm.get(n) for n in ("server_index_rows_scored_total",
+                                        "server_attn_rows_read_total"))
+    moe = [tm.get(n) for n in batching.MOE_COUNTERS]
+    eng.prefill("a", np.arange(20, dtype=np.int32)[None])
+    s0, r0, m0 = scored.value, read.value, [m.value for m in moe]
+    eng.decode_burst({"a": burst_entry(3)}, 4)
+    # four ticks at lengths 20 .. 23: three blocks of 8 keys to the longest
+    # (and only) active slot, both slots computing; 16 rows selected a tick
+    assert scored.value - s0 == 4 * 3 * 8 * 2
+    assert read.value - r0 == 4 * TOPK
+    total, held, hit, slots = (m.value - b for m, b in zip(moe, m0))
+    # one active row a tick, two expert layers, two choices of eight
+    assert total == 4 * 2 * 2 and slots == 4 * 2 * 4
+    assert 0 <= hit <= held <= total and hit <= slots
+
+
+def test_the_importer_permutes_interleaved_pairs_to_halves():
+    half = hf_import._half_layout(8)
+    assert list(half) == [0, 2, 4, 6, 1, 3, 5, 7]
+    cfg = hf_import.config_from_hf(type("C", (), dict(
+        HF, num_hidden_layers=5, max_position_embeddings=512,
+        num_key_value_heads=4))())
+    assert (cfg.kv_lora_rank, cfg.index_topk, cfg.first_k_dense,
+            cfg.num_experts, cfg.head_dim, cfg.rope_theta,
+            cfg.held_experts) == (16, TOPK, 1, 8, 16, 1e6, (0, 8))
+
+
+def test_every_other_engine_refuses_the_family_by_name(weights):
+    cfg = small_config()
+    why = config.single_pass_unsupported(cfg, "this engine")
+    assert "latent row of 16 + 4" in why and "index key of 8" in why
+    assert config.custom_engine_unsupported(cfg) == why
+    for name in ("glm5", "glm5-rehearsal"):
+        assert config.single_pass_unsupported(
+            config.get_config(name), "x") is not None
+    params = hf_import.convert_state_dict(cfg, weights, dtype=jnp.float32)
+    part = StagePlan.even(cfg.num_layers, 3).stages[0]
+    with pytest.raises(NotImplementedError, match="latent row"):
+        slice_stage_params(cfg, params, part)
+    with pytest.raises(NotImplementedError, match="latent row"):
+        batching.BatchedStageExecutor(cfg, part, params, slots=1, max_len=32)
+    whole = StagePlan.even(cfg.num_layers, 1).stages[0]
+    with pytest.raises(NotImplementedError, match="prefix cache"):
+        batching.BatchedStageExecutor(cfg, whole, params, slots=1,
+                                      max_len=32, prefix_cache_bytes=1 << 20)
+    eng = engine(weights, slots=1, max_len=32)
+    eng.prefill("a", np.arange(4, dtype=np.int32)[None])
+    with pytest.raises(NotImplementedError, match="speculative verify"):
+        eng.decode_batch({"a": np.zeros((1, 3), np.int32)})
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
+        StageExecutor,
+    )
+    with pytest.raises(NotImplementedError, match="latent row"):
+        StageExecutor(cfg, whole, params)
+
+
+def test_main_refuses_before_a_weight_is_made():
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
+        main,
+    )
+    base = ["--model", "glm5-rehearsal", "--num_layers", "2"]
+    parse = main.build_parser().parse_args
+    ok = parse(base + ["--mode", "serve", "--stage", "0", "--batched"])
+    main._refuse_unheld_state(ok, main.load_config(ok))      # the one home
+    for more in (["--mode", "serve", "--stage", "0"],
+                 ["--mode", "serve", "--stage", "1", "--batched"],
+                 ["--mode", "serve", "--stage", "0", "--batched",
+                  "--prefix_cache_mb", "8"],
+                 ["--mode", "local"], ["--mode", "oracle"],
+                 ["--mode", "fused"]):
+        args = parse(base + more)
+        with pytest.raises(SystemExit, match="latent row"):
+            main._refuse_unheld_state(args, main.load_config(args))
+    with pytest.raises(SystemExit):
+        main.load_config(parse(["--model", "glm5", "--num_layers", "79"]))
+    cfg = main.load_config(parse(["--model", "glm5", "--num_layers", "6"]))
+    assert (cfg.num_layers, cfg.first_k_dense, cfg.vocab_size,
+            cfg.held_experts, cfg.num_experts, cfg.hidden_size) == (
+        6, 1, 19360, (0, 16), 256, 6144)
+    assert dataclasses.replace(cfg, num_layers=2).num_layers == 2
