@@ -41,13 +41,39 @@ slot, that slot's blocks and no others:
     denominator is written out, or (``stats``) the three as they stand,
     for a caller that merges two stacks' reads into one softmax.
 
+A LATENT row under a learned selection (`runtime.batching._attend_latent`'s
+decode read; ``v_stack=None``, ``select``) is the same walk with one operand
+fewer and one mask term more:
+
+  * a latent row is ONE row every head shares, its keys the row and its
+    values the row's first lanes: as it rests in ``[L, S, n, W]`` it is the
+    row of one KV head, so ONE stack is keys and values both, a pair's
+    block is copied once and is the operand of both products, and the
+    caller keeps the value lanes of the float32 sums it is handed;
+  * the selection is a MASK, made here: the slots' float32 index scores
+    ``[S, n]`` arrive whole in VMEM and, while the first block is on its
+    way, `_threshold` finds every slot's k-th largest score by bisection on
+    the scores' integer image and, among the entries equal to it, the
+    position up to which they are admitted. That is ``jax.lax.top_k``'s
+    pick entry for entry, ties to the lower position, with no sort and no
+    index. A row is in iff it is under the slot's limit AND picked; an
+    unselected row has probability exactly 0, as in the sum over the
+    gathered rows. XLA's gather moves a 1280-byte row in ~15 ns, a tenth
+    of the HBM rate, whoever issues it: a slot's own blocks, contiguous,
+    arrive at eight times that, so while a slot holds under ~16 times the
+    rows it selects the dense read is the shorter one
+    (`runtime.batching.LATENT_DENSE`);
+  * the call names the stack FIRST among its array operands and has one
+    result: a trace knows an operation by the first characters of its text,
+    and this read is found there by the stack it reads.
+
 The arithmetic is `runtime.batching._attend`'s: operands in their own
 dtype, float32 scores, statistics and sums; a row past a query's limit has
 probability exactly 0 there, so leaving it unread drops no term.
 
 Off the TPU the call runs through the Pallas interpreter: where the stack
 is folded always (a test that folds on the CPU), where it is not only for
-a test that asks (`engaged`). PERF.md section 6, PRs 52 and 53.
+a test that asks (`engaged`). PERF.md section 6, PRs 52, 53 and 56.
 """
 
 from __future__ import annotations
@@ -114,15 +140,72 @@ def _precision(dtype):
             else jax.lax.Precision.DEFAULT)
 
 
-def _kernel(at_ref, plan_ref, q_ref, k_hbm, v_hbm, *refs, pairs, rows, hkv,
-            folded, groups, dh, dtype, stats):
+def _threshold(pick_ref, sel_ref, topk: int):
+    """``pick_ref`` ``[S, n]`` int32 := which ``topk`` entries of every row
+    of the float32 scores ``sel_ref`` are its largest, 1 or 0: EXACTLY
+    ``jax.lax.top_k``'s pick, ties to the lower position
+    (`runtime.batching.select_topk`'s definition, and its method). The
+    k-th largest value by bisection on the scores' monotone integer image
+    (32 compares and counts, all slots at once: a slot is a sublane), then,
+    among the entries EQUAL to it, the position up to which the first
+    ``topk - (number above)`` of them lie (a second bisection, over
+    positions). No sort, and no index is made."""
+    slots, n = sel_ref.shape
+    low = jnp.int32(-2 ** 31)
+
+    def count(cond):                                 # [S, 1], exact in f32
+        return jnp.where(cond, 1.0, 0.0).sum(-1, keepdims=True)
+
+    bits = jax.lax.bitcast_convert_type(sel_ref[...], jnp.int32)
+    # monotone as a SIGNED number; ``image ^ low`` is the same order unsigned
+    pick_ref[...] = jnp.where(bits >= 0, bits, bits ^ jnp.int32(2 ** 31 - 1))
+
+    def value_bit(i, kth):                           # kth: unsigned, as bits
+        cand = kth | jax.lax.shift_left(jnp.int32(1), jnp.int32(31) - i)
+        enough = count(pick_ref[...] >= (cand ^ low)) >= topk
+        return jnp.where(enough, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, value_bit,
+                            jnp.zeros((slots, 1), jnp.int32)) ^ low
+    wanted = topk - count(pick_ref[...] > kth)       # ties to admit: >= 1
+    width = max((n - 1).bit_length(), 1)
+
+    def position_bit(i, cut):
+        # the largest position with fewer than ``wanted`` ties BEFORE it:
+        # that of the last tie admitted
+        cand = cut | jax.lax.shift_left(jnp.int32(1), jnp.int32(width - 1) - i)
+        at = jax.lax.broadcasted_iota(jnp.int32, (slots, n), 1)
+        few = count((pick_ref[...] == kth) & (at < cand)) < wanted
+        return jnp.where(few, cand, cut)
+
+    cut = jax.lax.fori_loop(0, width, position_bit,
+                            jnp.zeros((slots, 1), jnp.int32))
+    image = pick_ref[...]
+    at = jax.lax.broadcasted_iota(jnp.int32, (slots, n), 1)
+    pick_ref[...] = ((image > kth) | ((image == kth) & (at <= cut))).astype(
+        jnp.int32)
+
+
+def _kernel(at_ref, plan_ref, *refs, pairs, rows, hkv, folded, groups, dh,
+            dtype, stats, shared, topk):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    # The operands: the queries and the two stacks; ONE stack that is keys
+    # and values both (``shared``) comes first, then the queries; the
+    # selection's scores (``topk``) last.
+    refs = list(refs)
+    if shared:
+        stacks, q_ref = (refs.pop(0),), refs.pop(0)
+    else:
+        q_ref, stacks = refs.pop(0), (refs.pop(0), refs.pop(0))
+    sel_ref = refs.pop(0) if topk else None
     n_out = 3 if stats else 1           # the sums (, the max, the denominator)
     outs, scratch = refs[:n_out], refs[n_out:]
     out_ref = outs[0]
-    k_buf, v_buf, sem, q_wide, m_ref, l_ref, acc_ref, seen_ref = scratch[:8]
+    k_buf = scratch.pop(0)
+    v_buf = k_buf if shared else scratch.pop(0)
+    sem, q_wide, m_ref, l_ref, acc_ref, seen_ref = scratch[:6]
     slots = q_ref.shape[0]
     total, at = plan_ref[0], at_ref[0]
 
@@ -141,17 +224,35 @@ def _kernel(at_ref, plan_ref, q_ref, k_hbm, v_hbm, *refs, pairs, rows, hkv,
         return plan_ref[1 + 2 * pairs + slots + slot]
 
     def copies(i, buf):
-        """Pair i's K and V rows into buffer ``buf``."""
+        """Pair i's K and V rows into buffer ``buf`` (ONE copy where one
+        stack is both)."""
         at_rows = pl.ds(pl.multiple_of(block_of(i) * rows, rows), rows)
         return [pltpu.make_async_copy(
             stack.at[at, slot_of(i), at_rows], dst.at[buf], sem.at[n, buf])
-            for n, (stack, dst) in enumerate(((k_hbm, k_buf),
-                                              (v_hbm, v_buf)))]
+            for n, (stack, dst) in enumerate(zip(stacks, (k_buf, v_buf)))]
 
     @pl.when(total > 0)
     def _():
         for copy in copies(0, 0):
             copy.start()
+
+    if topk:
+        # Which rows of a slot the selection admits, for all slots at once
+        # while the first block is on its way. A slot whose limit is at most
+        # ``topk`` sees every causal row: where no slot is past it, nothing
+        # is searched.
+        pick_ref = scratch[-1]
+        past = functools.reduce(
+            jnp.logical_or, [(blocks_of(s) > 0) & (limit_of(s) > topk)
+                             for s in range(slots)])
+
+        @pl.when(past)
+        def _():
+            _threshold(pick_ref, sel_ref, topk)
+
+        @pl.when(jnp.logical_not(past))
+        def _():
+            pick_ref[...] = jnp.ones_like(pick_ref)
 
     # The kernel's rows are the heads, KV head k's j-th query head at row
     # ``j * Hkv + k`` (then zero rows up to whole tiles). Which row of its
@@ -174,7 +275,7 @@ def _kernel(at_ref, plan_ref, q_ref, k_hbm, v_hbm, *refs, pairs, rows, hkv,
         # ``j * Hkv + k`` of the block-diagonal operand is row j of that
         # where ``own == j``, and each output row j collects the sums'
         # lanes where ``own == j``.
-        own_ref = scratch[8]
+        own_ref = scratch[6]
         row = jax.lax.broadcasted_iota(jnp.int32, own_ref.shape, 0)
         lane = jax.lax.broadcasted_iota(jnp.int32, own_ref.shape, 1)
         own = jnp.full(own_ref.shape, -1, jnp.int32)
@@ -225,8 +326,11 @@ def _kernel(at_ref, plan_ref, q_ref, k_hbm, v_hbm, *refs, pairs, rows, hkv,
             q, operand(k_buf, buf).astype(q.dtype), (((1,), (1,)), ((), ())),
             precision=_precision(q.dtype),
             preferred_element_type=jnp.float32)     # [HP, block (x Hkv)]
-        scores = jnp.where(
-            seen_ref[...] < limit_of(slot) - block * rows, scores, NEG_INF)
+        ok = seen_ref[...] < limit_of(slot) - block * rows
+        if topk:    # a row is in iff under the limit AND of the selection
+            ok &= pick_ref[pl.ds(slot, 1), pl.ds(
+                pl.multiple_of(block * rows, rows), rows)] != 0
+        scores = jnp.where(ok, scores, NEG_INF)
         m = m_ref[...]
         m2 = jnp.maximum(m, scores.max(-1, keepdims=True))
         corr = jnp.exp(m - m2)
@@ -259,7 +363,7 @@ def _kernel(at_ref, plan_ref, q_ref, k_hbm, v_hbm, *refs, pairs, rows, hkv,
 
 
 def slot_attention(q, k_stack, v_stack, at, plan, *, rows: int, hkv: int,
-                   stats: bool = False):
+                   stats: bool = False, select=None):
     """Attention of one query row a slot, ``q`` ``[S, H, Dh]`` (rotated and
     scaled), over layer ``at`` of the stacks ``[L, S, n, Hkv, Dh]`` (folded:
     ``[L, S, n, W]``) by ``plan`` (`read_plan`, blocks of ``rows`` rows):
@@ -267,16 +371,28 @@ def slot_attention(q, k_stack, v_stack, at, plan, *, rows: int, hkv: int,
     a slot the plan does not read. ``stats``: the softmax's float32
     statistics instead, ``(max [S, H], denominator [S, H], weighted sum
     [S, H, Dh])``, not yet divided; ``(NEG_INF, 0, 0)`` for a slot that is
-    not read."""
+    not read.
+
+    ``v_stack=None``: ONE stack ``[L, S, n, W]`` whose row every head
+    shares (``hkv`` 1) is keys and values both, ``q`` ``[S, H, W]`` against
+    the row as it rests: ``[S, H, W]`` float32, the normalised sums as the
+    scratch holds them (the caller keeps the lanes that are values and
+    projects them on). ``select=(scores, k)``: of a slot's rows under its
+    limit only the ``k`` whose ``scores`` (``[S, n]`` float32) are largest,
+    as ``jax.lax.top_k`` picks them (`_threshold`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     slots, heads, dh = q.shape
     groups = heads // hkv
-    folded = k_stack.ndim == 4
+    shared = v_stack is None
+    # A row of ONE KV head is both forms at once: the row as it rests is the
+    # ``[block, W]`` operand and no lane is another head's, so it takes the
+    # walk of rows that stay ``[Hkv, Dh]``, with nothing block-diagonal.
+    folded = k_stack.ndim == 4 and not shared
     width = k_stack.shape[-1]
     pairs = slots * (k_stack.shape[2] // rows)
-    dtype = jnp.promote_types(v_stack.dtype, q.dtype)
+    dtype = jnp.promote_types((k_stack if shared else v_stack).dtype, q.dtype)
     padded = _padded_heads(heads, q.dtype)
     # Head (j, k) of KV head k: [S, G, Hkv, Dh].
     mine = q.reshape(slots, hkv, groups, dh).transpose(0, 2, 1, 3)
@@ -291,39 +407,48 @@ def slot_attention(q, k_stack, v_stack, at, plan, *, rows: int, hkv: int,
                  else _INTERPRET)
     whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
     out_shapes = [jax.ShapeDtypeStruct(
-        (slots, out_rows, width), jnp.float32 if stats else dtype)]
+        (slots, out_rows, width),
+        jnp.float32 if stats or shared else dtype)]
     if stats:
         out_shapes += [jax.ShapeDtypeStruct((slots, padded, 1),
                                             jnp.float32)] * 2
+    stacks = [k_stack] if shared else [k_stack, v_stack]
+    scores, topk = select or (None, 0)
+    # (operand, its spec): the stack that is both goes FIRST (a trace names
+    # a call by its first operands, and this read is found by the stack it
+    # reads), the selection's scores last
+    ins = [(x, pl.BlockSpec(memory_space=pl.ANY)) for x in stacks]
+    ins.insert(len(ins) if shared else 0, (mine, whole(mine.shape)))
+    if topk:
+        ins.append((scores, whole(scores.shape)))
+    operands, in_specs = zip(*ins)
     got = pl.pallas_call(
         functools.partial(_kernel, pairs=pairs, rows=rows, hkv=hkv,
                           folded=folded, groups=groups, dh=dh, dtype=dtype,
-                          stats=stats),
+                          stats=stats, shared=shared, topk=topk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[whole(mine.shape),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=list(in_specs),
             out_specs=[whole(s.shape) for s in out_shapes],
             scratch_shapes=[
-                pltpu.VMEM((2, rows) + k_stack.shape[3:], k_stack.dtype),
-                pltpu.VMEM((2, rows) + v_stack.shape[3:], v_stack.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((padded, width), q.dtype),
-                pltpu.VMEM((padded, 1), jnp.float32),
-                pltpu.VMEM((padded, 1), jnp.float32),
-                pltpu.VMEM((padded, width), jnp.float32),
-                pltpu.VMEM((padded, rows * (1 if folded else hkv)),
-                           jnp.int32)]
-            + ([pltpu.VMEM((padded, width), jnp.int32)] if folded else [])),
+                pltpu.VMEM((2, rows) + x.shape[3:], x.dtype) for x in stacks]
+            + [pltpu.SemaphoreType.DMA((len(stacks), 2)),
+               pltpu.VMEM((padded, width), q.dtype),
+               pltpu.VMEM((padded, 1), jnp.float32),
+               pltpu.VMEM((padded, 1), jnp.float32),
+               pltpu.VMEM((padded, width), jnp.float32),
+               pltpu.VMEM((padded, rows * (1 if folded else hkv)),
+                          jnp.int32)]
+            + ([pltpu.VMEM((padded, width), jnp.int32)] if folded else [])
+            + ([pltpu.VMEM(scores.shape, jnp.int32)] if topk else [])),
         out_shape=out_shapes,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="slot_attention",
-    )(jnp.asarray(at, jnp.int32).reshape(1), plan, mine, k_stack, v_stack)
+    )(jnp.asarray(at, jnp.int32).reshape(1), plan, *operands)
 
     def by_head(x, last):
         """Kernel rows ``j * Hkv + k`` -> heads in their own order, ``k * G
@@ -332,6 +457,8 @@ def slot_attention(q, k_stack, v_stack, at, plan, *, rows: int, hkv: int,
             1, 2).reshape(slots, heads, *last)
 
     out = got[0]
+    if shared:
+        return out[:, :heads]
     if folded:      # [S, G, Hkv x Dh (+ pad)]: rows j, KV heads in the lanes
         out = out[:, :, :hkv * dh].reshape(slots, groups * hkv, dh)
     out = by_head(out, (dh,))

@@ -367,8 +367,10 @@ def attn_block(max_len: int) -> int:
                                   -1) if max_len % b == 0), max_len)
 
 
-def attn_blocks(lengths, active, t, max_len, xp=np, per_slot=False):
-    """How many blocks of `attn_block` rows a step of ``t`` new rows a slot
+def attn_blocks(lengths, active, t, max_len, xp=np, per_slot=False,
+                block: int = 0):
+    """How many blocks of `attn_block` rows (of ``block`` rows, for a read
+    with a block of its own: `latent_block`) a step of ``t`` new rows a slot
     reads of every cache layer: up to the last new row of the LONGEST
     ACTIVE slot (along the last axis of ``lengths`` / ``active``), and none
     where no slot is active. An inactive slot's rows are never needed: its
@@ -378,7 +380,7 @@ def attn_blocks(lengths, active, t, max_len, xp=np, per_slot=False):
     the decode programs call it on traced values (``xp=jnp``) and the host,
     for ``server_attn_rows_read_total``, on the lengths a step began
     with."""
-    block = attn_block(max_len)
+    block = block or attn_block(max_len)
     need = xp.where(active, lengths + t, 0)
     if not per_slot:
         need = xp.max(need, axis=-1)
@@ -386,7 +388,7 @@ def attn_blocks(lengths, active, t, max_len, xp=np, per_slot=False):
 
 
 def cache_read(cfg, layers, folded: bool, t: int = 1,
-               rider: bool = False) -> str:
+               rider: bool = False, max_len: int = 0) -> str:
     """The form in which a decode program of ``t`` new rows a slot reads a
     cache layer (`_attend_cached`, `_attend_windowed`), by what it is handed
     alone: ``folded`` stacks (`kv_fold_width`), the configuration and the
@@ -401,11 +403,20 @@ def cache_read(cfg, layers, folded: bool, t: int = 1,
     fills its lanes (``Dh`` whole tiles of 128: `kernel_engaged`). The
     programs, the counter of the rows they read (`_count_attn_rows`) and
     the ``kv_layout`` event all ask here. A latent family under a learned
-    selection (``cfg.kv_lora_rank``) reads in a form of its own,
-    ``"select"``: index scores over a slot's index keys, the top
-    ``index_topk``, a gather of those latent rows (`_attend_latent`)."""
+    selection (``cfg.kv_lora_rank``) scores a slot's index keys and attends
+    to the top ``index_topk`` positions' latent rows (`_attend_latent`), in
+    one of two forms. ``"select"``, the definition: ``jax.lax.top_k``, then
+    a gather of those rows. ``"kernel"``, where the kernel is the chip's
+    and a slot of ``max_len`` rows holds at most `LATENT_DENSE` times
+    ``index_topk``: every row of a slot's own blocks, streamed, under the
+    selection as a mask (a gather moves ``index_topk`` rows at a tenth of
+    the rate a slot's contiguous blocks arrive at)."""
     if cfg.kv_lora_rank:
-        return "select"
+        dense = (t == 1 and kernel_engaged()
+                 and max_len <= LATENT_DENSE * cfg.index_topk
+                 and (latent_block(max_len) % 128 == 0
+                      or jax.default_backend() != "tpu"))
+        return "kernel" if dense else "select"
     plain = not (cfg.attn_softcap or cfg.sliding_window
                  or "window" in layers)
     if folded:
@@ -813,6 +824,25 @@ INDEX_BLOCK = 2048
 # slot's rows so far, so a prompt of any length runs through ONE program
 # shape and the buckets of its tail.
 LATENT_CHUNK = 1024
+# Rows of a slot's latent layer the kernel copies in one piece (the decode
+# read of `cache_read`'s ``"kernel"``: 1.3 MB of 640-lane rows on the v5e,
+# where 1024 rows a copy read a layer 12% faster than 512 and twice 256).
+LATENT_BLOCK = 1024
+# The longest slot, in multiples of ``index_topk``, whose latent rows a
+# decode tick streams WHOLE under the selection as a mask rather than
+# gather the selected ones (`cache_read`). Measured on the v5e, a layer of
+# eight slots at ``index_topk`` 2048 (PERF.md section 6, PR 56): with EVERY
+# slot full the kernel takes 0.25 ms where top_k + gather take 0.36 at 8 x
+# (16384 rows) and ties them at 16 x (0.49 against 0.47); any slot shorter
+# than full and it is ahead (a mix half full at 16 x: 0.29 against 0.47).
+LATENT_DENSE = 16
+
+
+def latent_block(max_len: int) -> int:
+    """The kernel's block of a ``max_len``-row slot's latent rows: the
+    largest divisor of ``max_len`` that is at most `LATENT_BLOCK`."""
+    return next((b for b in range(min(LATENT_BLOCK, max_len), 0, -1)
+                 if max_len % b == 0), 1)
 
 
 def index_block(max_len: int) -> int:
@@ -935,6 +965,14 @@ def select_topk(scores, k: int):
     return above | (tie & (rank < (k - above.sum(-1))[..., None]))
 
 
+def _absorbed(q, w_k):
+    """A decode step's queries against a latent row's compressed part,
+    ``q_nope W_kvb,k^T``: ``[S, H, kv_lora_rank]`` (``w_k`` ``[kv_lora_rank,
+    H, qk_nope_head_dim]``)."""
+    dt = q["nope"].dtype
+    return jnp.einsum("shn,lhn->shl", q["nope"][:, 0], w_k.astype(dt))
+
+
 def _attend_latent(cfg, lp, q, rows, keys, q_pos):
     """Attention of a latent family under its learned selection: ``[B, T,
     H * v_head_dim]``, before the output projection. Every query scores
@@ -950,7 +988,11 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
     products: ``q_nope W_kvb,k^T`` against the row's compressed part and
     ``q_rope`` against its rotated key, the weighted sum of compressed
     parts through ``W_kvb,v`` after it. No key or value of a head is ever
-    made for a cached position.
+    made for a cached position. Where ``rows.blocks`` is a `read_plan`
+    (`cache_read`'s ``"kernel"``) the same sum is taken over every row of
+    a slot's own blocks with the unselected ones at probability exactly 0:
+    `ops.slot_attention` streams the blocks as they rest and finds the
+    selection, ``jax.lax.top_k``'s entry for entry, as a mask.
 
     A prefill chunk (``rows`` ``[1, M, .]`` / ``keys`` ``[1, M, Di]``: the
     slot's layer with the chunk's own rows written, ``q_pos`` ``[T, 1]``):
@@ -973,10 +1015,30 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
                 keys.stack, (keys.at, 0, start, 0),
                 (1, slots, index_block(m), keys.stack.shape[3]))[0],
             keys.blocks, m)[:, 0], NEG_INF)
+        dt = q["nope"].dtype
+        if rows.blocks.ndim:
+            # a `read_plan` (`cache_read`'s ``"kernel"``): a latent row as
+            # it rests is the folded row of ONE KV head, its keys the row,
+            # its values the row's compressed part. Each slot's own blocks,
+            # streamed, the selection a mask made in the kernel: no sort,
+            # no index, no gathered copy
+            mine = jnp.concatenate([_absorbed(q, wkvb[..., :nope]),
+                                    q["rope"][:, 0]], -1) * scale
+            mine = jnp.pad(mine, ((0, 0), (0, 0), (
+                0, rows.stack.shape[3] - mine.shape[-1]))).astype(dt)
+            with jax.named_scope("latent_read"):
+                o_lat = slot_attention(
+                    mine, rows.stack, None, rows.at, rows.blocks,
+                    rows=latent_block(m), hkv=1,
+                    select=(scores, min(topk, m)))[..., :kl].astype(dt)
+            out = jnp.einsum("shl,lhv->shv", o_lat,
+                             wkvb[..., nope:].astype(dt))
+            return out.reshape(slots, 1, -1)
         with jax.named_scope("topk_select"):
             # INDICES, for the gather below: ``jax.lax.top_k``'s pick is
             # the definition of the selection (ties to the lower position)
-            # and `select_topk`, the prefill's mask, is held to it
+            # and `select_topk`, the prefill's mask, and the kernel's are
+            # held to it
             _, sel = jax.lax.top_k(scores, min(topk, m))       # [S, k]
         with jax.named_scope("latent_read"):
             pick = jnp.stack(jnp.broadcast_arrays(
@@ -989,11 +1051,9 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
                     start_index_map=(0, 1, 2)),
                 slice_sizes=(1, 1, 1, rows.stack.shape[3]),
                 mode="promise_in_bounds")                      # [S, k, .]
-        dt = q["nope"].dtype
         c_kv = got[..., :kl].astype(dt)
         k_r = got[..., kl:kl + q["rope"].shape[-1]].astype(dt)
-        q_abs = jnp.einsum("shn,lhn->shl", q["nope"][:, 0],
-                           wkvb[..., :nope].astype(dt))
+        q_abs = _absorbed(q, wkvb[..., :nope])
         sc = (jnp.einsum("shl,skl->shk", q_abs, c_kv,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("shr,skr->shk", q["rope"][:, 0], k_r,
@@ -1389,9 +1449,19 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     # limit, as ONE plan a stack.
     kernel = cache_read(
         cfg, params["layers"], jax.tree.leaves(k_all)[0].ndim == 4,
-        qpos.shape[1], rider is not None) == "kernel"
+        qpos.shape[1], rider is not None,
+        jax.tree.leaves(k_all)[0].shape[2]) == "kernel"
     if cfg.kv_lora_rank:
-        blocks = index_blocks(lengths, active, k_all.shape[2], jnp)
+        # (latent rows, index keys): the keys are scored to the longest
+        # active slot's block; the rows are read as far or, by the kernel,
+        # each slot's own blocks under its limit
+        max_len = k_all.shape[2]
+        scored = index_blocks(lengths, active, max_len, jnp)
+        rows = latent_block(max_len)
+        blocks = (read_plan(
+            attn_blocks(lengths, active, 1, max_len, jnp, per_slot=True,
+                        block=rows),
+            qpos[:, 0, 0] + 1, max_len // rows) if kernel else scored, scored)
     elif cfg.eva_window:
         rows = (k_all.exact.shape[2], k_all.sums.shape[2])
         blocks = windowed_blocks(cfg, lengths, active, rows, jnp,
@@ -1442,8 +1512,10 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
                         v_new = _append_rows(
                             v_all, at, v.astype(v_all.dtype), lengths,
                             active)
-                    return (_CacheLayer(k_new, at, blocks),
-                            _CacheLayer(v_new, at, blocks),
+                    k_blocks, v_blocks = (
+                        blocks if cfg.kv_lora_rank else (blocks, blocks))
+                    return (_CacheLayer(k_new, at, k_blocks),
+                            _CacheLayer(v_new, at, v_blocks),
                             (None, qpos, None), (k_new, v_new))
                 new, read = [], []
                 for stack, rows in ((k_all, k), (v_all, v)):
@@ -1537,6 +1609,7 @@ class BatchedStageExecutor:
         self._m_chunks = _tm.get("server_kv_chunks_summarised_total")
         self._m_written = _tm.get("server_kv_positions_written_total")
         self._m_index_scored = _tm.get("server_index_rows_scored_total")
+        self._m_streamed = _tm.get("server_latent_rows_streamed_total")
         self._m_moe = [_tm.get(name) for name in MOE_COUNTERS]
         self._m_rows_held = _tm.get("server_state_rows_held_total")
         self._m_pos_held = _tm.get("server_positions_held_total")
@@ -1640,7 +1713,8 @@ class BatchedStageExecutor:
         """`cache_read` of this engine's decode program of ``t`` new rows a
         slot, with or without a ``rider`` group."""
         return cache_read(self.cfg, self.params["layers"],
-                          jax.tree.leaves(self.k)[0].ndim == 4, t, rider)
+                          jax.tree.leaves(self.k)[0].ndim == 4, t, rider,
+                          self.max_len)
 
     def _count_attn_rows(self, lengths, active, t: int,
                          rider: bool = False) -> None:
@@ -1656,7 +1730,10 @@ class BatchedStageExecutor:
         their own, and the chunks its ticks closed and pooled to a third.
         A latent family under a learned selection: the index keys it
         scored to ``server_index_rows_scored_total``, the latent rows it
-        SELECTED and read to the rows-read counter."""
+        SELECTED and read to the rows-read counter, and those it STREAMED
+        to read them (the kernel: each active slot's own blocks of
+        `latent_block` rows; the gather none) to
+        ``server_latent_rows_streamed_total``."""
         kernel = self._cache_read(t, rider) == "kernel"
         each = 1 if kernel else self.slots      # slots that read a count
         if self.cfg.kv_lora_rank:
@@ -1669,6 +1746,11 @@ class BatchedStageExecutor:
             self._m_rows_read.inc(int(np.where(
                 active, np.minimum(lengths + 1, self.cfg.index_topk),
                 0).sum()))
+            if kernel:
+                rows = latent_block(self.max_len)
+                self._m_streamed.inc(int(attn_blocks(
+                    lengths, active, 1, self.max_len, per_slot=True,
+                    block=rows).sum()) * rows)
         elif self.cfg.eva_window:
             rows_e, rows_s = rows = windowed_rows(self.cfg, self.max_len)
             exact, sums = windowed_blocks(self.cfg, lengths, active, rows,

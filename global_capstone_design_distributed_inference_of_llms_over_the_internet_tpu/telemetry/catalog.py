@@ -307,6 +307,17 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "server_attn_rows_read_total keeps the latent rows it then "
                  "SELECTED and read: min(positions, index_topk) an active "
                  "slot a tick.", (), None),
+    "server_latent_rows_streamed_total": (
+        COUNTER, "Latent rows of ONE cache layer that the decode steps and "
+                 "burst ticks of a latent family under a learned selection "
+                 "STREAMED to read the rows they selected: where the tick "
+                 "reads by the kernel (runtime.batching.cache_read: "
+                 "ops.slot_attention under the selection as a mask), an "
+                 "active slot's own blocks x runtime.batching.latent_block "
+                 "rows; 0 where it gathers the selected rows. Counted on "
+                 "the host from the lengths a step began with. "
+                 "server_attn_rows_read_total keeps the rows SELECTED.",
+        (), None),
     "server_moe_assignments_total": (
         COUNTER, "Routed assignments (rows x num_experts_per_tok x expert "
                  "layers) of the burst ticks' active rows, over ALL "

@@ -214,8 +214,11 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "summary_shape, shape then being the window stack's; for a latent "
         "family under a learned selection shape and row are the latent "
         "rows' stack, index_shape / index_row / index_layout / "
-        "index_resident_bytes the index keys', read = select and "
-        "selected_rows = the rows a query reads at most)."),
+        "index_resident_bytes the index keys', selected_rows = the rows a "
+        "query reads at most, and read = kernel (the latent rows of each "
+        "slot's own blocks streamed under the selection as a mask: a TPU, "
+        "a slot of at most LATENT_DENSE x index_topk rows) or select "
+        "(top_k, then a gather of the selected rows))."),
     "burst_fallback": (
         "client", WARN,
         "A burst-mode session fell back to per-step decode because no "

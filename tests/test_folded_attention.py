@@ -397,6 +397,43 @@ def test_a_slot_the_plan_leaves_out_has_statistics_that_weigh_nothing():
         np.asarray(whole)[:2], atol=1e-6, rtol=1e-6)
 
 
+# -- the kernel the three accepted forms build, pinned -----------------------
+
+# The jaxpr of `slot_attention` (the wrapper's re-laying and the kernel's
+# body, locations stripped), by form: what PR 53 left. A later form of the
+# call (PR 56: one stack for keys and values, a selection as a mask) is a
+# Python branch on an argument these calls do not pass, and must leave them
+# the program they had.
+KERNEL_FORMS = {
+    "folded": (dict(hkv=5), (4, 5, 8), (3, 4, 64, 128), "float32",
+               "8ae36b07ee0efbcf"),
+    "rows": (dict(hkv=2), (4, 4, 128), (3, 4, 64, 2, 128), "bfloat16",
+             "b4734738f6ea91f8"),
+    "stats": (dict(hkv=4, stats=True), (4, 4, 128), (3, 4, 64, 4, 128),
+              "bfloat16", "f3e216c8e5134d37"),
+}
+
+
+@pytest.mark.parametrize("form", sorted(KERNEL_FORMS))
+def test_the_accepted_forms_build_the_kernel_they_had(form, monkeypatch):
+    import hashlib
+    import re
+
+    monkeypatch.setattr(FA, "_INTERPRET", False)
+    kw, q, stack, dtype, want = KERNEL_FORMS[form]
+    sd = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, at, plan: FA.slot_attention(
+            q, k, v, at, plan, rows=BLOCK, **kw))(
+                sd(q, dtype), sd(stack, dtype), sd(stack, dtype),
+                sd((), jnp.int32),
+                sd((1 + 2 * q[0] * (MAX_LEN // BLOCK) + 2 * q[0],),
+                   jnp.int32)))
+    text = re.sub(r" at [^\s:]+:\d+", "", text)
+    assert "/" + "tests" not in text and "slot_attention.py" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
 # -- the served widths, compiled for the chip without the chip ----------------
 
 @pytest.fixture(scope="module")
@@ -482,3 +519,42 @@ def test_the_kernel_compiles_for_the_v5e_on_rows_as_they_rest(
     assert "slot_attention" in compiled.as_text()
     assert (compiled.memory_analysis().temp_size_in_bytes
             < s * n * hkv * 128 * 2)
+
+
+def test_the_kernel_compiles_for_the_v5e_on_a_latent_layer_under_its_selection(
+        one_chip, monkeypatch):
+    """glm-5 as served: 8 slots of 16384 latent rows of 640 lanes, 6
+    layers, 64 heads, the top 2048 of a slot's float32 index scores, blocks
+    of `LATENT_BLOCK` rows. Mosaic takes the kernel with its threshold
+    search; the call names the stack FIRST among its array operands and
+    has ONE float32 result whose leading dimension is the slots (a trace
+    finds the read by both: `perfbench/layer_metrics/
+    sparse_attn_roofline_share.json`); nothing the size of a layer is
+    staged around it."""
+    monkeypatch.setattr(FA, "_INTERPRET", False)
+    s, heads, layers, n, width, topk = 8, 64, 6, 16384, 640, 2048
+    rows = B.latent_block(n)
+
+    def read(q, stack, scores, at, lengths, active):
+        own = B.attn_blocks(lengths, active, 1, n, jnp, per_slot=True,
+                            block=rows)
+        plan = FA.read_plan(own, lengths + 1, n // rows)
+        return FA.slot_attention(q, stack, None, at, plan, rows=rows, hkv=1,
+                                 select=(scores, topk))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(read).lower(
+        arg((s, heads, width), jnp.bfloat16),
+        arg((layers, s, n, width), jnp.bfloat16), arg((s, n), jnp.float32),
+        arg((), jnp.int32), arg((s,), jnp.int32), arg((s,), bool)).compile()
+    call = next(line for line in compiled.as_text().splitlines()
+                if "tpu_custom_call" in line and "slot_attention" in line)
+    assert f" = f32[{s},{heads},{width}]" in call
+    operands = call[call.index("operand_layout_constraints={"):]
+    assert operands.index(f"bf16[{layers},{s},{n},{width}]") \
+        < operands.index(f"bf16[{s},{heads},{width}]") \
+        < operands.index(f"f32[{s},{n}]")
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < s * n * width * 2)

@@ -26,6 +26,9 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     StagePlan,
     slice_stage_params,
 )
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
+    slot_attention as FA,
+)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
     batching,
 )
@@ -190,15 +193,26 @@ def test_a_latent_row_padded_to_lane_tiles_changes_nothing(
         np.testing.assert_array_equal(x, y)
 
 
-@pytest.mark.parametrize("k", [1, 3, 8, 31, 32, 40])
-def test_select_topk_is_lax_top_k_with_ties(k):
-    """The mask form against ``jax.lax.top_k``: many equal values (ties go
-    to the lower position), negatives, and rows of one value."""
+TIE_CASES = [1, 3, 8, 31, 32, 40]
+
+
+def tied_scores(k):
+    """Rows with many equal values (ties go to the lower position),
+    negatives, a row of one value, masked entries, tiny values of both
+    signs: whatever ``k``, some row's k-th value is shared."""
     rng = np.random.default_rng(k)
     scores = rng.integers(-3, 4, (64, 32)).astype(np.float32) * 0.25
     scores[5] = 1.5                                    # every entry ties
     scores[6, :20] = batching.NEG_INF                  # masked entries
     scores[7] = rng.standard_normal(32) * 1e-30        # tiny, both signs
+    return scores
+
+
+@pytest.mark.parametrize("k", TIE_CASES)
+def test_select_topk_is_lax_top_k_with_ties(k):
+    """The mask form against ``jax.lax.top_k``: many equal values (ties go
+    to the lower position), negatives, and rows of one value."""
+    scores = tied_scores(k)
     mask = np.asarray(batching.select_topk(jnp.asarray(scores), k))
     if k >= 32:
         assert mask.all()
@@ -208,6 +222,180 @@ def test_select_topk_is_lax_top_k_with_ties(k):
     np.put_along_axis(want, np.asarray(top), True, axis=-1)
     np.testing.assert_array_equal(mask, want)
     assert (mask.sum(-1) == k).all()
+
+
+@pytest.mark.parametrize("k", TIE_CASES)
+def test_the_kernel_s_selection_is_lax_top_k_with_ties(k, monkeypatch):
+    """The selection as the kernel makes it (`ops.slot_attention._threshold`)
+    against ``jax.lax.top_k``, entry for entry, on
+    `test_select_topk_is_lax_top_k_with_ties`'s rows: 64 slots of 32 rows
+    whose row i is the unit vector i, under a zero query, so that lane i of
+    a slot's sum is 1 / (rows admitted) where row i was admitted and 0
+    where it was not."""
+    monkeypatch.setattr(FA, "_INTERPRET", True)
+    scores = tied_scores(k)
+    slots, n = scores.shape
+    stack = jnp.broadcast_to(jnp.eye(n, dtype=jnp.float32), (1, slots, n, n))
+    plan = FA.read_plan(jnp.full((slots,), n // 16, jnp.int32),
+                        jnp.full((slots,), n, jnp.int32), n // 16)
+    out = np.asarray(FA.slot_attention(
+        jnp.zeros((slots, 8, n), jnp.float32), stack, None, 0, plan, rows=16,
+        hkv=1, select=(jnp.asarray(scores), min(k, n))))
+    _, top = jax.lax.top_k(jnp.asarray(scores), min(k, n))
+    want = np.zeros(scores.shape, bool)
+    np.put_along_axis(want, np.asarray(top), True, axis=-1)
+    np.testing.assert_array_equal(out[:, 0] > 0, want)
+    np.testing.assert_allclose(out.sum(-1), 1.0, atol=1e-6)
+
+
+# the last position held (the query's own) of eight slots of 64 rows, blocks
+# of 16: under ``index_topk`` = 16, exactly at it (16 rows seen), one row
+# past it, a last block partly filled, an inactive slot, the slot's end
+LAST = np.array([3, 15, 16, 17, 40, 63, 30, 50], np.int32)
+ACTIVE = np.array([1, 1, 1, 1, 1, 1, 0, 1], bool)
+
+
+@pytest.mark.parametrize("width", [20, 24])
+def test_the_masked_read_is_the_top_k_and_gather_arm(
+        width, small_blocks, monkeypatch):
+    """`_attend_latent`'s decode arm in its two forms on the same stacks:
+    the kernel over each slot's own blocks under the selection as a mask,
+    and ``jax.lax.top_k`` + gather, the definition. ``width`` 24: the row
+    padded as the v5e holds it (576 -> 640)."""
+    monkeypatch.setattr(FA, "_INTERPRET", True)
+    monkeypatch.setattr(batching, "LATENT_BLOCK", 16)
+    cfg = small_config()
+    slots, m, layers = 8, 64, 2
+    rng = np.random.default_rng(width)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    row = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    stack = normal(layers, slots, m, width) * (np.arange(width) < row)
+    index = normal(layers, slots, m, cfg.index_head_dim)
+    # index keys in steps of a quarter: exact ties at the k-th value
+    index = jnp.round(index * 2) / 2
+    q = {"nope": normal(slots, 1, cfg.num_heads, cfg.qk_nope_head_dim),
+         "rope": normal(slots, 1, cfg.num_heads, cfg.qk_rope_head_dim),
+         "iq": jnp.round(normal(slots, 1, cfg.index_n_heads,
+                                cfg.index_head_dim)),
+         "iw": jnp.ones((slots, 1, cfg.index_n_heads), jnp.float32)}
+    lp = {"attn": {"wkvb": normal(cfg.kv_lora_rank, cfg.num_heads * (
+        cfg.qk_nope_head_dim + cfg.v_head_dim))}}
+    lengths, active = jnp.asarray(LAST), jnp.asarray(ACTIVE)
+    keys = batching._CacheLayer(index, 1, batching.index_blocks(
+        lengths, active, m, jnp))
+    plan = FA.read_plan(batching.attn_blocks(
+        lengths, active, 1, m, jnp, per_slot=True, block=16), lengths + 1,
+        m // 16)
+
+    @jax.jit
+    def both(q, stack):
+        return [batching._attend_latent(
+            cfg, lp, q, batching._CacheLayer(stack, 1, blocks), keys,
+            lengths[:, None, None]) for blocks in (plan, keys.blocks)]
+
+    streamed, gathered = both(q, stack)
+    np.testing.assert_allclose(np.asarray(streamed)[ACTIVE],
+                               np.asarray(gathered)[ACTIVE], atol=2e-5,
+                               rtol=2e-5)
+    # exact ties at the edge were there to break
+    scores = np.asarray(batching._index_scores(q, index[1]))[:, 0]
+    assert any(np.sum(scores[s, :LAST[s] + 1] == np.sort(
+        scores[s, :LAST[s] + 1])[-TOPK]) > 1 for s in (3, 4, 5, 7))
+
+
+def test_a_burst_round_by_the_kernel_is_the_round_without_it(
+        weights, small_blocks, monkeypatch):
+    """The small engine's burst rounds with the kernel engaged (the hook
+    `ops.slot_attention.engaged` reads; blocks of 16 latent rows) against
+    the same rounds through ``jax.lax.top_k`` + gather: the same tokens,
+    slot by slot, across row ``index_topk``."""
+    seqs = {"a": np.arange(11, dtype=np.int32) * 7 % 97,
+            "b": np.arange(29, dtype=np.int32) * 5 % 97}
+    emitted = []
+    for hook in (None, True):
+        monkeypatch.setattr(FA, "_INTERPRET", hook)
+        monkeypatch.setattr(batching, "LATENT_BLOCK", 16)
+        eng = engine(weights)
+        assert eng._cache_read(1, False) == ("kernel" if hook else "select")
+        for sid, seq in seqs.items():
+            eng.prefill(sid, seq[None])
+        fed, got = {"a": 5, "b": 9}, {"a": [], "b": []}
+        for _ in range(2):                  # 8 ticks: "a" crosses row 16
+            res = eng.decode_burst(
+                {sid: burst_entry(tok) for sid, tok in fed.items()}, 4)
+            for sid, r in res.items():
+                got[sid] += r["tokens"]
+                fed[sid] = r["tokens"][-1]
+        emitted.append(got)
+    assert emitted[0] == emitted[1]
+
+
+def test_one_stack_for_keys_and_values_is_copied_once_a_pair():
+    """``v_stack=None``: a pair's block is BOTH operands, so the kernel
+    starts one copy where two stacks start two (the first pair's, and the
+    next pair's one ahead), and its call names the stack first."""
+    def starts(v_given):
+        sd = jax.ShapeDtypeStruct
+        stack = sd((2, 4, 64, 128), jnp.float32)
+        text = str(jax.make_jaxpr(lambda q, k, v, plan: FA.slot_attention(
+            q, k, v if v_given else None, 0, plan, rows=16, hkv=1))(
+                sd((4, 8, 128), jnp.float32), stack, stack,
+                sd((1 + 2 * 16 + 8,), jnp.int32)))
+        return text.count("dma_start"), text.count("dma_wait")
+
+    assert starts(True) == (4, 2)
+    assert starts(False) == (2, 1)
+
+
+def test_which_slots_stream_their_rows_and_which_gather(monkeypatch):
+    """`cache_read` for a latent family: the kernel where it is the chip's
+    (or a test's hook) and a slot holds at most `LATENT_DENSE` x
+    ``index_topk`` rows; the definition, ``jax.lax.top_k`` + gather,
+    everywhere else."""
+    cfg = small_config()
+    assert batching.cache_read(cfg, {}, True, max_len=64) == "select"
+    monkeypatch.setattr(FA, "_INTERPRET", True)
+    edge = batching.LATENT_DENSE * TOPK
+    assert batching.cache_read(cfg, {}, True, max_len=64) == "kernel"
+    assert batching.cache_read(cfg, {}, True, max_len=edge) == "kernel"
+    assert batching.cache_read(cfg, {}, True, max_len=edge + 16) == "select"
+    assert batching.cache_read(cfg, {}, True, t=2, max_len=64) == "select"
+    # glm-5 as served: 16384 = 8 x 2048 rows a slot
+    served = dataclasses.replace(cfg, index_topk=2048)
+    assert batching.cache_read(served, {}, True, max_len=16384) == "kernel"
+    assert batching.latent_block(16384) == batching.LATENT_BLOCK == 1024
+    assert batching.latent_block(64) == 64 and batching.latent_block(96) == 96
+
+
+@pytest.mark.parametrize("hook", [None, True])
+def test_the_rows_streamed_are_counted_from_the_lengths(
+        weights, small_blocks, monkeypatch, hook):
+    """``server_latent_rows_streamed_total``: where the tick reads by the
+    kernel, each active slot's own blocks of `latent_block` rows; where it
+    gathers, nothing. The rows SELECTED keep their counter either way."""
+    monkeypatch.setattr(FA, "_INTERPRET", hook)
+    monkeypatch.setattr(batching, "LATENT_BLOCK", 16)
+    telemetry.enable()
+    try:
+        eng = engine(weights, slots=2, max_len=64)
+        streamed, read = (tm.get(n) for n in (
+            "server_latent_rows_streamed_total",
+            "server_attn_rows_read_total"))
+        s0, r0 = streamed.value, read.value
+        # three ticks; slot 0 begins them at 14, 15, 16 rows (its query one
+        # more: 15, 16, 17 seen: 1, 1, 2 blocks of 16), slot 1 is inactive
+        # in the last and begins at 40, 41 (41, 42 seen: 3 blocks each)
+        lengths = np.array([[14, 40], [15, 41], [16, 42]], np.int32)
+        active = np.array([[1, 1], [1, 1], [1, 0]], bool)
+        eng._count_attn_rows(lengths, active, 1)
+        assert streamed.value - s0 == ((1 + 1 + 2 + 3 + 3) * 16 if hook
+                                       else 0)
+        assert read.value - r0 == 15 + 16 + 16 + 16 + 16
+    finally:
+        telemetry.disable()
 
 
 def test_the_slot_holds_two_rows_a_position_and_counts_what_it_reads(
