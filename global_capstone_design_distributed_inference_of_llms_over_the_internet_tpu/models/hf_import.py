@@ -328,9 +328,16 @@ def _half_layout(n: int) -> np.ndarray:
 
 def _glm5_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
     """One layer of a ``glm_moe_dsa`` checkpoint (HF Linear weights are
-    [out, in]; ours [in, out]). The dims the published model rotates as
-    interleaved pairs (``rope_interleave``, ``indexer_rope_interleave``)
-    are permuted to the half layout here, once: the rope part of every
+    [out, in]; ours [in, out], but for four of the attention's that keep
+    the PUBLISHED orientation, the contracted axis last
+    (`models.transformer._dot_t`): ``wqb_t``, ``wkvb_t`` and ``wiq_t``,
+    whose outputs are grouped by head straight after the product, as
+    ``[heads, rows a head, in]``, and ``wkva_t`` ``[kv_lora_rank +
+    qk_rope_head_dim, in]``, whose 576 outputs are no whole number of the
+    v5e's lane tiles. That is the form the chip reads a layer's slice of
+    their stacks in. The dims the published model rotates as interleaved
+    pairs (``rope_interleave``, ``indexer_rope_interleave``) are permuted
+    to the half layout here, once: the rope part of every
     head of ``q_b_proj``, the shared key of ``kv_a_proj_with_mqa``, and
     the first ``qk_rope_head_dim`` of the indexer's queries, key and the
     key's LayerNorm. Of the routed experts only those held
@@ -354,12 +361,14 @@ def _glm5_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
         "attn": {
             "wqa": t(att + "q_a_proj.weight").T,
             "q_norm": {"w": t(att + "q_a_layernorm.weight")},
-            "wqb": t(att + "q_b_proj.weight")[q_rows].T,
-            "wkva": t(att + "kv_a_proj_with_mqa.weight")[kva_rows].T,
+            "wqb_t": t(att + "q_b_proj.weight")[q_rows].reshape(
+                h, nope + r, -1),
+            "wkva_t": t(att + "kv_a_proj_with_mqa.weight")[kva_rows],
             "kv_norm": {"w": t(att + "kv_a_layernorm.weight")},
-            "wkvb": t(att + "kv_b_proj.weight").T,
+            "wkvb_t": t(att + "kv_b_proj.weight").reshape(h, -1, kl),
             "wo": t(att + "o_proj.weight").T,
-            "wiq": t(att + "indexer.wq_b.weight")[iq_rows].T,
+            "wiq_t": t(att + "indexer.wq_b.weight")[iq_rows].reshape(
+                cfg.index_n_heads, di, -1),
             "wik": t(att + "indexer.wk.weight")[ikey].T,
             "ik_norm": {"w": t(att + "indexer.k_norm.weight")[ikey],
                         "b": t(att + "indexer.k_norm.bias")[ikey]},

@@ -23,6 +23,7 @@ TPU-native design:
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -80,20 +81,26 @@ class QuantizedTensor:
 
     Layout: q has the original weight shape [..., in, out]; s broadcasts as
     [..., 1, out] so ``q * s`` reconstructs. `dtype` records the original
-    dtype for reconstruction.
+    dtype for reconstruction. ``axis`` is the axis a product CONTRACTS, the
+    one a scale was taken along: -2 for every ``[in, out]`` weight, -1 for
+    one that rests ``[.., out, in]`` (`_MATMUL_KEYS_T`: s is then [..,
+    out, 1], and the int8 kernel, which reads ``[in, out]``, is not for
+    it).
     """
 
-    def __init__(self, q: jnp.ndarray, s: jnp.ndarray, dtype: str = "float32"):
+    def __init__(self, q: jnp.ndarray, s: jnp.ndarray, dtype: str = "float32",
+                 axis: int = -2):
         self.q = q
         self.s = s
         self.dtype = dtype
+        self.axis = axis
 
     def tree_flatten(self):
-        return (self.q, self.s), self.dtype
+        return (self.q, self.s), (self.dtype, self.axis)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], children[1], aux)
+        return cls(*children, *aux)
 
     @property
     def shape(self):
@@ -103,7 +110,8 @@ class QuantizedTensor:
         return (self.q.astype(jnp.float32) * self.s).astype(self.dtype)
 
     def __repr__(self):
-        return f"QuantizedTensor(shape={tuple(self.q.shape)}, dtype={self.dtype})"
+        return (f"QuantizedTensor(shape={tuple(self.q.shape)}, "
+                f"dtype={self.dtype}, axis={self.axis})")
 
 
 class QuantizedLayerView:
@@ -131,12 +139,16 @@ class QuantizedLayerView:
     def dtype(self):
         return self.stack.dtype
 
+    @property
+    def axis(self):
+        return self.stack.axis
+
     def layer(self) -> QuantizedTensor:
         """The 2-D leaf a scan over the stack would have been handed."""
         q, s = (jax.lax.dynamic_index_in_dim(a, self.index, 0,
                                              keepdims=False)
                 for a in (self.stack.q, self.stack.s))
-        return QuantizedTensor(q, s, self.stack.dtype)
+        return QuantizedTensor(q, s, self.dtype, self.axis)
 
     def dequant(self) -> jnp.ndarray:
         return self.layer().dequant()
@@ -230,15 +242,18 @@ def _quantize_leaf_nf4(w) -> NF4Tensor:
                      str(jnp.asarray(w).dtype))
 
 
-def _quantize_leaf(w: jnp.ndarray) -> QuantizedTensor:
-    """Per-output-channel absmax int8: channel axis = last, reduce over the
-    input axis (-2). Works for [in, out], stacked [L, in, out], and expert
-    [E, in, out] weights alike."""
+def _quantize_leaf(w: jnp.ndarray, axis: int = -2) -> QuantizedTensor:
+    """Per-output-channel absmax int8: reduce over the input axis (``axis``:
+    -2, the channel axis last, for [in, out], stacked [L, in, out] and
+    expert [E, in, out] weights alike; -1 for a weight that rests [..,
+    out, in], whose scales are then the SAME numbers, one an output
+    row)."""
     w32 = jnp.asarray(w, jnp.float32)
-    absmax = jnp.max(jnp.abs(w32), axis=-2, keepdims=True)
+    absmax = jnp.max(jnp.abs(w32), axis=axis, keepdims=True)
     s = jnp.where(absmax > 0, absmax / 127.0, 1.0)
     q = jnp.clip(jnp.round(w32 / s), -127, 127).astype(jnp.int8)
-    return QuantizedTensor(q, s.astype(jnp.float32), str(jnp.asarray(w).dtype))
+    return QuantizedTensor(q, s.astype(jnp.float32),
+                           str(jnp.asarray(w).dtype), axis)
 
 
 # The matmul weight names of models/transformer.py's layer schema. Norms,
@@ -246,7 +261,15 @@ def _quantize_leaf(w: jnp.ndarray) -> QuantizedTensor:
 _MATMUL_KEYS = frozenset(
     {"wq", "wk", "wv", "wqkv", "wo", "wg", "wu", "wgu", "wd", "wi",
      # a latent-attention family's bottlenecks (models.config kv_lora_rank)
-     "wqa", "wqb", "wkva", "wkvb", "wiq"})
+     "wqa"})
+# The same family's four weights that rest with the contracted axis LAST,
+# [out, in] or by head [heads, out a head, in] (models.hf_import._glm5_layer;
+# read by models.transformer._dot_t and runtime.batching._attend_latent,
+# dequantised there). int8 takes their scales along that axis: the numbers
+# the [in, out] form had. NF4 blocks every leaf's axis -2 by 64, which for
+# these is 64 output rows of one input column: 4.25 bits a weight either
+# way.
+_MATMUL_KEYS_T = frozenset({"wqb_t", "wkva_t", "wkvb_t", "wiq_t"})
 
 
 def quantize_layers(layers: Params, quant: str = "int8") -> Params:
@@ -259,12 +282,18 @@ def quantize_layers(layers: Params, quant: str = "int8") -> Params:
         raise NotImplementedError(
             f"quant={quant!r}: int8 and nf4 execution are implemented")
     leaf = _quantize_leaf if quant == "int8" else _quantize_leaf_nf4
+    # a weight that rests [.., out, in]: int8's scales run along its LAST axis
+    leaf_t = partial(_quantize_leaf, axis=-1) if quant == "int8" else leaf
 
     def walk(tree, key=None):
         if isinstance(tree, dict):
             return {k: walk(v, k) for k, v in tree.items()}
-        if key in _MATMUL_KEYS and getattr(tree, "ndim", 0) >= 2:
+        if getattr(tree, "ndim", 0) < 2:
+            return tree
+        if key in _MATMUL_KEYS:
             return leaf(tree)
+        if key in _MATMUL_KEYS_T:
+            return leaf_t(tree)
         return tree
 
     # dict-walk instead of tree_map: the selection is name-dependent.

@@ -112,10 +112,13 @@ def init_latent_layer_params(rng: jax.Array, cfg: ModelConfig, dtype,
                              dense: bool) -> Params:
     """Random init for ONE layer of a latent-attention family
     (``cfg.kv_lora_rank``): the query and key/value bottlenecks with their
-    norms, the indexer (``wiq`` from the query bottleneck, ``wik`` and its
+    norms, the indexer (``wiq_t`` from the query bottleneck, ``wik`` and its
     LayerNorm, the per-head weights ``wiw``), and either the dense SwiGLU
     (``dense``: a leading layer) or the router with its score bias, the
-    HELD routed experts and the shared expert."""
+    HELD routed experts and the shared expert. Four weights rest with the
+    contracted axis LAST, as `models.hf_import` leaves them (`_dot_t`):
+    ``wqb_t``, ``wkvb_t``, ``wiq_t`` ``[heads, out a head, in]`` and
+    ``wkva_t`` ``[out, in]``; every other is ``[in, out]``."""
     d, h = cfg.hidden_size, cfg.num_heads
     ql, kl, rope = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     ih, idh = cfg.index_n_heads, cfg.index_head_dim
@@ -125,13 +128,13 @@ def init_latent_layer_params(rng: jax.Array, cfg: ModelConfig, dtype,
         "ln1": {"w": one(d)}, "ln2": {"w": one(d)},
         "attn": {
             "wqa": _dense(ks[0], (d, ql), dtype), "q_norm": {"w": one(ql)},
-            "wqb": _dense(ks[1], (ql, h * cfg.head_dim), dtype),
-            "wkva": _dense(ks[2], (d, kl + rope), dtype),
+            "wqb_t": _dense(ks[1], (h, cfg.head_dim, ql), dtype),
+            "wkva_t": _dense(ks[2], (kl + rope, d), dtype),
             "kv_norm": {"w": one(kl)},
-            "wkvb": _dense(ks[3], (kl, h * (cfg.qk_nope_head_dim
-                                            + cfg.v_head_dim)), dtype),
+            "wkvb_t": _dense(ks[3], (h, cfg.qk_nope_head_dim
+                                     + cfg.v_head_dim, kl), dtype),
             "wo": _dense(ks[4], (h * cfg.v_head_dim, d), dtype),
-            "wiq": _dense(ks[5], (ql, ih * idh), dtype),
+            "wiq_t": _dense(ks[5], (ih, idh, ql), dtype),
             "wik": _dense(ks[6], (d, idh), dtype),
             "ik_norm": {"w": one(idh), "b": jnp.zeros((idh,), dtype)},
             "wiw": _dense(ks[7], (d, ih), dtype),
@@ -268,6 +271,32 @@ def _dot(x: jnp.ndarray, w) -> jnp.ndarray:
 
         return int8_dot(x, w)
     return x @ w
+
+
+def _plain(w):
+    """A weight as an array (a quantised leaf dequantised): for the
+    products that take it whole or reshaped by head."""
+    return w.dequant() if hasattr(w, "dequant") else w
+
+
+def _dot_t(x: jnp.ndarray, w) -> jnp.ndarray:
+    """``x [..., K]`` against a weight that rests with the contracted axis
+    LAST, a checkpoint's own ``[out, in]``: ``w [N, K]`` gives ``[..., N]``,
+    ``w [H, Dh, K]`` (a head's rows together) ``[..., H, Dh]``, every
+    element the dot product ``x @ w.reshape(-1, K).T`` holds. For the
+    weights the TPU does not read as ``[K, N]`` where they rest (PERF.md
+    section 6, PR 57). One whose output is split inside a head straight
+    after (a latent family's ``wqb_t``, ``wiq_t``): the compiler makes the
+    product head-grouped and wants a head's rows together with K minor;
+    handed ``[K, H * Dh]`` it re-lays the whole stack once a burst (a
+    layer's slice once a layer in every other program) and stages each
+    layer's slice in VMEM before its product. One whose N is no whole
+    number of lane tiles (``wkva_t``: 576): the v5e holds ``[L, K, N]``
+    with K minor, to pad nothing, and the program re-lays that stack too.
+    A quantised leaf (its scales one an output ROW:
+    `models.quant.quantize_layers`) is dequantised here; the int8 and NF4
+    kernels are `_dot`'s."""
+    return jnp.tensordot(x, _plain(w), axes=(-1, -1))
 
 
 def qkv_proj(cfg: ModelConfig, p: Params, x: jnp.ndarray):

@@ -245,6 +245,9 @@ def int8_dot(x: jnp.ndarray,
     m_pad = -(-max(m, 8) // 8) * 8
     n = w.shape[-1]
     view = isinstance(w, QuantizedLayerView)
+    if w.axis != -2:
+        raise ValueError("a leaf that rests [out, in] is "
+                         "models.transformer._dot_t's, not the kernel's")
     if ((view or w.q.ndim == 2)           # one layer's weight, not a stack
             and _supported(m_pad, k, n, x.dtype.itemsize)):
         _launches += 1

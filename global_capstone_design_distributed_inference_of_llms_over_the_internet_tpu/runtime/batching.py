@@ -75,6 +75,8 @@ from ..utils.platform import engine_donation
 from ..models.partition import StageSpec
 from ..models.transformer import (
     _dot,
+    _dot_t,
+    _plain,
     _mlp,
     _norm,
     close_pass,
@@ -861,12 +863,6 @@ def index_blocks(lengths, active, max_len, xp=np):
     return xp.minimum(-(-need // block), -(-max_len // block))
 
 
-def _plain(w):
-    """A weight as an array (a quantised leaf dequantised): for the
-    products that take it reshaped by head."""
-    return w.dequant() if hasattr(w, "dequant") else w
-
-
 def _latent_proj(cfg, p, a, rope):
     """A latent family's attention projections of the normed stream ``a``
     (``[B, T, D]``): ``(q, row, key)``. ``q``: the queries, a dict of
@@ -877,7 +873,6 @@ def _latent_proj(cfg, p, a, rope):
     qk_rope_head_dim]``: the position's latent cache row, the normed
     compressed K/V beside the ONE rotated key every head shares. ``key``
     ``[B, T, 1, Di]``: its index key (LayerNorm, rotated likewise)."""
-    b, t, _ = a.shape
     kl, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
 
     def rotated(x, n):
@@ -886,12 +881,11 @@ def _latent_proj(cfg, p, a, rope):
             [apply_rope(x[..., :n], *rope), x[..., n:]], axis=-1)
 
     c_q = rms_norm(_dot(a, p["wqa"]), p["q_norm"]["w"], cfg.norm_eps)
-    q = _dot(c_q, p["wqb"]).reshape(b, t, cfg.num_heads, cfg.head_dim)
-    kv = _dot(a, p["wkva"])
+    q = _dot_t(c_q, p["wqb_t"])                              # [B, T, H, Dh]
+    kv = _dot_t(a, p["wkva_t"])
     c_kv = rms_norm(kv[..., :kl], p["kv_norm"]["w"], cfg.norm_eps)
     k_r = apply_rope(kv[..., None, kl:], *rope)               # [B, T, 1, r]
-    iq = rotated(_dot(c_q, p["wiq"]).reshape(
-        b, t, cfg.index_n_heads, cfg.index_head_dim), r)
+    iq = rotated(_dot_t(c_q, p["wiq_t"]), r)
     key = rotated(layer_norm(_dot(a, p["wik"]), p["ik_norm"]["w"],
                              p["ik_norm"]["b"], 1e-6)[:, :, None], r)
     iw = (jnp.dot(a.astype(jnp.float32), p["wiw"].astype(jnp.float32))
@@ -967,10 +961,10 @@ def select_topk(scores, k: int):
 
 def _absorbed(q, w_k):
     """A decode step's queries against a latent row's compressed part,
-    ``q_nope W_kvb,k^T``: ``[S, H, kv_lora_rank]`` (``w_k`` ``[kv_lora_rank,
-    H, qk_nope_head_dim]``)."""
+    ``q_nope W_kvb,k^T``: ``[S, H, kv_lora_rank]`` (``w_k`` ``[H,
+    qk_nope_head_dim, kv_lora_rank]``: the heads' key ROWS of ``wkvb_t``)."""
     dt = q["nope"].dtype
-    return jnp.einsum("shn,lhn->shl", q["nope"][:, 0], w_k.astype(dt))
+    return jnp.einsum("shn,hnl->shl", q["nope"][:, 0], w_k.astype(dt))
 
 
 def _attend_latent(cfg, lp, q, rows, keys, q_pos):
@@ -1004,7 +998,9 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
     queries."""
     kl, nope, vd = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
     heads, topk = cfg.num_heads, cfg.index_topk
-    wkvb = _plain(lp["attn"]["wkvb"]).reshape(kl, heads, nope + vd)
+    # ``[H, nope + vd, kl]`` as it rests: a head's key rows, then its value
+    # rows, the compressed axis LAST (a slice of rows, not of lanes)
+    wkvb = _plain(lp["attn"]["wkvb_t"])
     scale = cfg.head_dim ** -0.5
     if isinstance(rows, _CacheLayer):
         _, slots, m = rows.stack.shape[:3]
@@ -1022,7 +1018,7 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
             # its values the row's compressed part. Each slot's own blocks,
             # streamed, the selection a mask made in the kernel: no sort,
             # no index, no gathered copy
-            mine = jnp.concatenate([_absorbed(q, wkvb[..., :nope]),
+            mine = jnp.concatenate([_absorbed(q, wkvb[:, :nope]),
                                     q["rope"][:, 0]], -1) * scale
             mine = jnp.pad(mine, ((0, 0), (0, 0), (
                 0, rows.stack.shape[3] - mine.shape[-1]))).astype(dt)
@@ -1031,8 +1027,8 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
                     mine, rows.stack, None, rows.at, rows.blocks,
                     rows=latent_block(m), hkv=1,
                     select=(scores, min(topk, m)))[..., :kl].astype(dt)
-            out = jnp.einsum("shl,lhv->shv", o_lat,
-                             wkvb[..., nope:].astype(dt))
+            out = jnp.einsum("shl,hvl->shv", o_lat,
+                             wkvb[:, nope:].astype(dt))
             return out.reshape(slots, 1, -1)
         with jax.named_scope("topk_select"):
             # INDICES, for the gather below: ``jax.lax.top_k``'s pick is
@@ -1053,7 +1049,7 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
                 mode="promise_in_bounds")                      # [S, k, .]
         c_kv = got[..., :kl].astype(dt)
         k_r = got[..., kl:kl + q["rope"].shape[-1]].astype(dt)
-        q_abs = _absorbed(q, wkvb[..., :nope])
+        q_abs = _absorbed(q, wkvb[:, :nope])
         sc = (jnp.einsum("shl,skl->shk", q_abs, c_kv,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("shr,skr->shk", q["rope"][:, 0], k_r,
@@ -1061,7 +1057,7 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
         probs = jax.nn.softmax(
             jnp.where((sel <= p)[:, None, :], sc, NEG_INF), axis=-1)
         o_lat = jnp.einsum("shk,skl->shl", probs.astype(dt), c_kv)
-        out = jnp.einsum("shl,lhv->shv", o_lat, wkvb[..., nope:].astype(dt))
+        out = jnp.einsum("shl,hvl->shv", o_lat, wkvb[:, nope:].astype(dt))
         return out.reshape(slots, 1, -1)
 
     t, m = q["nope"].shape[1], rows.shape[1]
@@ -1089,7 +1085,7 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
         with jax.named_scope("latent_read"):
             got = jax.lax.dynamic_slice_in_dim(
                 rows[0], start_of(j), blk, 0).astype(dt)       # [blk, .]
-        kv = jnp.einsum("kl,lhe->khe", got[:, :kl], wkvb.astype(dt))
+        kv = jnp.einsum("kl,hel->khe", got[:, :kl], wkvb.astype(dt))
         sc = (jnp.einsum("thn,khn->htk", q_n, kv[..., :nope],
                          preferred_element_type=jnp.float32)
               + jnp.einsum("thr,kr->htk", q_r,

@@ -10,6 +10,7 @@ last tests compile the kernel at the five cells' served widths for a
 DESCRIBED v5e (no chip): what Mosaic refuses, it refuses there."""
 
 import functools
+import re
 import types
 
 import jax
@@ -558,3 +559,104 @@ def test_the_kernel_compiles_for_the_v5e_on_a_latent_layer_under_its_selection(
         < operands.index(f"f32[{s},{n}]")
     assert (compiled.memory_analysis().temp_size_in_bytes
             < s * n * width * 2)
+
+
+# -- glm-5's burst program: every weight of a latent layer read where it rests
+
+_ELEMENT_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4,
+                  "s8": 1, "u8": 1, "pred": 1}
+
+
+def large_results(text, least, vmem=False):
+    """``[(name, shape with layout, opcode)]`` of every operation of an
+    optimised HLO text that WRITES at least ``least`` bytes: outside fused
+    computations (what a fusion computes inside is never written out) and
+    the custom calls (the kernels), and no view of another buffer (a
+    parameter, a tuple's member, a bitcast, a loop). To the device's main
+    memory only, unless ``vmem``: a result the compiler placed in VMEM
+    (``S(1)`` in its layout) is the staging of an operand, its one read."""
+    fused = set(re.findall(r"fusion\([^\n]*?calls=(%[\w.\-]+)", text))
+    views = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+             "custom-call"}
+    inside, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        op = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]+)\]"
+                      r"(\{[^}]*\})? ([\w\-]+)\(", line)
+        if not op or inside in fused:
+            continue
+        name, dtype, dims, layout, opcode = op.groups()
+        size = _ELEMENT_BYTES.get(dtype, 0)
+        for d in dims.split(","):
+            size *= int(d)
+        if (size >= least and opcode not in views
+                and (vmem or "S(1)" not in (layout or ""))):
+            out.append((name, f"{dtype}[{dims}]{layout or ''}", opcode))
+    return out
+
+
+def test_glm5_s_burst_program_reads_every_latent_weight_where_it_rests(
+        one_chip, monkeypatch):
+    """`BatchedStageExecutor._build_burst(16)` as `glm5-doc-sat8` serves it
+    (six layers at the published widths, 8 slots of 16384 rows, bfloat16,
+    the decode read by the kernel), compiled for a described v5e: outside
+    the kernel NO operation writes a result the size of a layer's ``wiq_t``
+    (the smallest of the three weights that rest by head, 16.8 MB) to the
+    device's memory but the two cache stacks' own in-place updates, no
+    weight is re-laid by a ``copy`` anywhere, VMEM included, and the
+    program's temporaries are smaller than that slice. Until PR 57 the
+    ``[in, out]`` forms of ``wqb`` / ``wkvb`` / ``wiq`` were re-laid whole
+    once a burst (`copy` ``bf16[5,2048,16384]{1,2,0}`` ..: 0.73 GB of
+    temporaries) and ``wkva``'s ``[6144, 576]`` stack, which the v5e holds
+    with 6144 minor, likewise."""
+    import dataclasses
+
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+        config,
+        transformer,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
+        ROLE_FULL,
+        StageSpec,
+    )
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
+        RECENT_WINDOW,
+    )
+
+    monkeypatch.setattr(FA, "_INTERPRET", False)
+    monkeypatch.setattr(B, "kernel_engaged", lambda: True)
+    layers, s, n, ticks = 6, 8, 16384, 16
+    cfg = dataclasses.replace(config.get_config("glm5"), num_layers=layers)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda: transformer.init_params(
+            jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    attn = params["layers"]["attn"]
+    assert attn["wqb_t"].shape == (layers - 1, 64, 256, 2048)
+    assert attn["wkvb_t"].shape == (layers - 1, 64, 448, 512)
+    assert attn["wiq_t"].shape == (layers - 1, 32, 128, 2048)
+    least = 32 * 128 * 2048 * 2
+    stacks = (arg((layers, s, n, 640), jnp.bfloat16),
+              arg((layers, s, n, 128), jnp.bfloat16))
+    burst = B.BatchedStageExecutor._build_burst(types.SimpleNamespace(
+        cfg=cfg, spec=StageSpec(0, ROLE_FULL, 0, layers), rider_rows=0,
+        slots=s), ticks)
+    compiled = burst.lower(
+        params, arg((len(B.BURST_INTS) + RECENT_WINDOW, s), jnp.int32),
+        arg((3, s), jnp.float32), *stacks).compile()
+    text = compiled.as_text()
+    assert "slot_attention" in text and "tpu_custom_call" in text
+    of_a_stack = tuple(
+        "bf16[" + ",".join(str(d) for d in x.shape) + "]" for x in stacks)
+    large = [r for r in large_results(text, least, vmem=True)
+             if not r[1].startswith(of_a_stack)]
+    assert [r for r in large if "S(1)" not in r[1]] == []    # written to HBM
+    assert [r for r in large if r[2] == "copy"] == []        # re-laid at all
+    assert compiled.memory_analysis().temp_size_in_bytes < least

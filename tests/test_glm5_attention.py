@@ -281,8 +281,9 @@ def test_the_masked_read_is_the_top_k_and_gather_arm(
          "iq": jnp.round(normal(slots, 1, cfg.index_n_heads,
                                 cfg.index_head_dim)),
          "iw": jnp.ones((slots, 1, cfg.index_n_heads), jnp.float32)}
-    lp = {"attn": {"wkvb": normal(cfg.kv_lora_rank, cfg.num_heads * (
-        cfg.qk_nope_head_dim + cfg.v_head_dim))}}
+    lp = {"attn": {"wkvb_t": normal(
+        cfg.num_heads, cfg.qk_nope_head_dim + cfg.v_head_dim,
+        cfg.kv_lora_rank)}}
     lengths, active = jnp.asarray(LAST), jnp.asarray(ACTIVE)
     keys = batching._CacheLayer(index, 1, batching.index_blocks(
         lengths, active, m, jnp))
@@ -429,6 +430,198 @@ def _counts_what_it_reads(weights):
     # one active row a tick, two expert layers, two choices of eight
     assert total == 4 * 2 * 2 and slots == 4 * 2 * 4
     assert 0 <= hit <= held <= total and hit <= slots
+
+
+# -- the four weights that rest with the contracted axis last ----------------
+
+def published_kn(weights, cfg, layer):
+    """``wqb`` / ``wkva`` / ``wkvb`` / ``wiq`` of ``layer`` as the importer
+    held them until PR 57: ``[in, out]``, the published matrix TURNED OVER,
+    the rotated dims (interleaved pairs as published) moved to the halves
+    `ops.rotary` rotates."""
+    att = f"model.layers.{layer}.self_attn."
+    nope, r, di = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.index_head_dim
+    halves = lambda n: list(range(0, n, 2)) + list(range(1, n, 2))
+    q_rows = [h * (nope + r) + j for h in range(cfg.num_heads)
+              for j in list(range(nope)) + [nope + j for j in halves(r)]]
+    iq_rows = [h * di + j for h in range(cfg.index_n_heads)
+               for j in halves(r) + list(range(r, di))]
+    kl = cfg.kv_lora_rank
+    kva_rows = list(range(kl)) + [kl + j for j in halves(r)]
+    w = lambda name: np.asarray(weights[att + name], np.float32)
+    return {"wqb": w("q_b_proj.weight")[q_rows].T,
+            "wkva": w("kv_a_proj_with_mqa.weight")[kva_rows].T,
+            "wkvb": w("kv_b_proj.weight").T,
+            "wiq": w("indexer.wq_b.weight")[iq_rows].T}
+
+
+def old_decode_read(cfg, wkvb, q, rows, scores, p):
+    """A decode step's read as `_attend_latent` wrote it until PR 57
+    (``wkvb`` ``[kv_lora_rank, H x (nope + v)]``, sliced on its minor axis):
+    top_k, the selected rows, the absorbed products."""
+    kl, nope, vd = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+    w = wkvb.reshape(kl, cfg.num_heads, nope + vd)
+    dt = q["nope"].dtype
+    _, sel = jax.lax.top_k(scores, min(cfg.index_topk, rows.shape[1]))
+    got = jnp.take_along_axis(rows, sel[..., None], axis=1)   # [S, k, .]
+    c_kv = got[..., :kl].astype(dt)
+    k_r = got[..., kl:kl + q["rope"].shape[-1]].astype(dt)
+    q_abs = jnp.einsum("shn,lhn->shl", q["nope"][:, 0],
+                       w[..., :nope].astype(dt))
+    sc = (jnp.einsum("shl,skl->shk", q_abs, c_kv,
+                     preferred_element_type=jnp.float32)
+          + jnp.einsum("shr,skr->shk", q["rope"][:, 0], k_r,
+                       preferred_element_type=jnp.float32)
+          ) * cfg.head_dim ** -0.5
+    probs = jax.nn.softmax(jnp.where(
+        (sel <= p)[:, None, :], sc, batching.NEG_INF), axis=-1)
+    o_lat = jnp.einsum("shk,skl->shl", probs.astype(dt), c_kv)
+    out = jnp.einsum("shl,lhv->shv", o_lat, w[..., nope:].astype(dt))
+    return out.reshape(rows.shape[0], 1, -1)
+
+
+def old_chunk_read(cfg, wkvb, q, rows, chosen):
+    """A prefill chunk's EXPANDED form with the old weight, dense: every row
+    of the slot through ``"kl,lhe->khe"``, one softmax under ``chosen``."""
+    kl, nope, vd = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+    dt = q["nope"].dtype
+    w = wkvb.reshape(kl, cfg.num_heads, nope + vd).astype(dt)
+    got = rows[0].astype(dt)
+    kv = jnp.einsum("kl,lhe->khe", got[:, :kl], w)
+    sc = (jnp.einsum("thn,khn->htk", q["nope"][0], kv[..., :nope],
+                     preferred_element_type=jnp.float32)
+          + jnp.einsum("thr,kr->htk", q["rope"][0],
+                       got[:, kl:kl + q["rope"].shape[-1]],
+                       preferred_element_type=jnp.float32)
+          ) * cfg.head_dim ** -0.5
+    probs = jax.nn.softmax(jnp.where(chosen[None], sc, batching.NEG_INF), -1)
+    out = jnp.einsum("htk,khv->htv", probs.astype(dt), kv[..., nope:],
+                     preferred_element_type=jnp.float32)
+    return out.transpose(1, 0, 2).reshape(1, chosen.shape[0], -1).astype(dt)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 0.0), ("bfloat16", 2e-2)])
+def test_the_weights_as_published_give_what_the_turned_over_ones_gave(
+        weights, small_blocks, monkeypatch, dtype, tol):
+    """The import of a seeded checkpoint, whose ``wqb_t`` / ``wkvb_t`` /
+    ``wiq_t`` rest ``[heads, rows a head, in]`` and ``wkva_t`` ``[out,
+    in]``, against the SAME checkpoint's ``[in, out]`` forms pushed through
+    the expressions the layer had until PR 57
+    (written out above and here): `_latent_proj`'s outputs, a decode step's
+    read on BOTH arms, a prefill chunk's. ``qk_nope_head_dim`` is 12: a
+    head's key rows end in the middle of a tile. float32: the queries and
+    the index key bit-equal; the latent row (``wkva_t``'s 20 outputs, which
+    the CPU's product sums in another order than ``[in, out]``'s) and the
+    reads to float32's rounding; bfloat16: inside its rounding."""
+    monkeypatch.setattr(FA, "_INTERPRET", True)
+    monkeypatch.setattr(batching, "LATENT_BLOCK", 16)
+    cfg = small_config()
+    params = hf_import.convert_state_dict(cfg, weights, dtype=dtype)
+    layer = 1                                   # the first expert layer
+    attn = jax.tree.map(lambda x: x[0], params["layers"]["attn"])
+    old = {k: jnp.asarray(v, dtype)
+           for k, v in published_kn(weights, cfg, layer).items()}
+    heads = {cfg.num_heads * cfg.head_dim: cfg.num_heads,
+             cfg.index_n_heads * cfg.index_head_dim: cfg.index_n_heads}
+    rng = np.random.default_rng(57)
+    normal = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    close = lambda got, want: np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=max(tol, 2e-6) * float(jnp.abs(want.astype(jnp.float32)).max()))
+
+    def projections(a, positions, turned_over):
+        """`_latent_proj`, or the same with the three products as they
+        were: ``x @ w`` of the ``[in, out]`` weights, ``wqb``'s and
+        ``wiq``'s ``.reshape(.., H, Dh)``."""
+        rope = batching.make_rope(cfg, positions)
+        if not turned_over:
+            return batching._latent_proj(cfg, attn, a, rope)
+        with monkeypatch.context() as m:
+            m.setattr(batching, "_dot_t", lambda x, w: (
+                (x @ w).reshape(*x.shape[:-1], heads[w.shape[-1]], -1)
+                if w.shape[-1] in heads else x @ w))
+            return batching._latent_proj(
+                cfg, {**attn, "wqb_t": old["wqb"], "wiq_t": old["wiq"],
+                      "wkva_t": old["wkva"]}, a, rope)
+
+    # a decode step: 8 slots of 64 rows, one query row a slot
+    slots, m = len(LAST), 64
+    lengths, active = jnp.asarray(LAST), jnp.asarray(ACTIVE)
+    a = normal(slots, 1, cfg.hidden_size)
+    q, row, key = projections(a, lengths[:, None], False)
+    q0, row0, key0 = projections(a, lengths[:, None], True)
+    for exact, got, want in ((True, q, q0), (False, row, row0),
+                             (True, key, key0)):
+        for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert x.shape == y.shape and x.dtype == y.dtype
+            if tol or not exact:
+                close(x, y)
+            else:
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    stack = normal(2, slots, m, width)
+    index = normal(2, slots, m, cfg.index_head_dim)
+    keys = batching._CacheLayer(index, 1, batching.index_blocks(
+        lengths, active, m, jnp))
+    plan = FA.read_plan(batching.attn_blocks(
+        lengths, active, 1, m, jnp, per_slot=True, block=16), lengths + 1,
+        m // 16)
+    scores = jnp.where(
+        jnp.arange(m)[None, :] <= lengths[:, None],
+        batching._index_scores(q, index[1])[:, 0], batching.NEG_INF)
+    want = old_decode_read(cfg, old["wkvb"], q0, stack[1], scores,
+                           lengths[:, None])
+    for blocks in (plan, keys.blocks):      # the kernel's arm, the gather's
+        got = batching._attend_latent(
+            cfg, {"attn": attn}, q, batching._CacheLayer(stack, 1, blocks),
+            keys, lengths[:, None, None])
+        assert got.shape == want.shape and got.dtype == want.dtype
+        close(got[ACTIVE], want[ACTIVE])
+
+    # a prefill chunk: 16 rows at positions 30 .. 45 of a 64-row slot
+    t, p0 = 16, 30
+    positions = p0 + jnp.arange(t, dtype=jnp.int32)[None]
+    a = normal(1, t, cfg.hidden_size)
+    q, _, _ = projections(a, positions, False)
+    q0, _, _ = projections(a, positions, True)
+    rows, ikeys = stack[0, :1], index[0, :1]
+    causal = jnp.arange(m)[None, :] <= positions[0][:, None]
+    scores = jnp.where(causal, batching._index_scores(q, ikeys)[0],
+                       batching.NEG_INF)
+    want = old_chunk_read(cfg, old["wkvb"], q0, rows,
+                          causal & batching.select_topk(scores, TOPK))
+    got = batching._attend_latent(cfg, {"attn": attn}, q, rows, ikeys,
+                                  positions[0][:, None])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    close(got, want)
+
+
+def test_random_init_and_the_import_build_the_same_tree(weights):
+    """Keys, shapes and dtypes, both layer stacks: what `init_params` draws
+    is what `convert_state_dict` makes of a checkpoint, the three weights
+    by head among them."""
+    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
+        init_params,
+    )
+    cfg = small_config()
+    drawn = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg, jnp.float32))
+    made = hf_import.convert_state_dict(cfg, weights, dtype=jnp.float32)
+    form = lambda tree: jax.tree.map(lambda x: (x.shape, str(x.dtype)), tree)
+    for stack in ("dense_layers", "layers"):
+        assert form(drawn[stack]) == form(made[stack]), stack
+    attn = made["layers"]["attn"]
+    h, hi = cfg.num_heads, cfg.index_n_heads
+    assert attn["wqb_t"].shape == (LAYERS - 1, h, cfg.head_dim,
+                                   cfg.q_lora_rank)
+    assert attn["wkvb_t"].shape == (
+        LAYERS - 1, h, cfg.qk_nope_head_dim + cfg.v_head_dim,
+        cfg.kv_lora_rank)
+    assert attn["wiq_t"].shape == (LAYERS - 1, hi, cfg.index_head_dim,
+                                   cfg.q_lora_rank)
+    assert attn["wkva_t"].shape == (
+        LAYERS - 1, cfg.kv_lora_rank + cfg.qk_rope_head_dim, cfg.hidden_size)
+    assert not {"wqb", "wkva", "wkvb", "wiq"} & set(attn)
 
 
 def test_the_importer_permutes_interleaved_pairs_to_halves():
