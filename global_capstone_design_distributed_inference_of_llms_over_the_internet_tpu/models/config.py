@@ -17,7 +17,7 @@ class ModelConfig:
     """Architecture hyperparameters for one decoder-only transformer family."""
 
     # "gpt2" | "llama" | "mistral" | "mixtral" | "qwen2" | "gemma" | "ouro"
-    # | "evabyte" | "glm_moe_dsa"
+    # | "evabyte" | "glm_moe_dsa" | "dots3_note"
     model_type: str
     vocab_size: int
     hidden_size: int
@@ -126,6 +126,32 @@ class ModelConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # Two kinds of latent layer ALTERNATING in one stack, as ``dots3_note``
+    # publishes them. ``layer_types`` names every layer of the published
+    # stack ``"full_attention"`` (the geometry above, under the selection)
+    # or ``"sliding_attention"``: latent attention of ANOTHER geometry (the
+    # ``swa_*`` keys: its own head count, ranks, head widths and RoPE base),
+    # no indexer, each query reading the newest ``sliding_window_size``
+    # positions, its own among them. A sliding layer's slot holds a RING of
+    # that many latent rows (position p at row ``p % sliding_window_size``)
+    # and never a row a position; a full layer's holds the slot's length.
+    # `sliding_kind` is the configuration a sliding layer's body runs
+    # under. ``attention_gate`` / ``swa_attention_gate``: head h's output
+    # times ``sigmoid(a W_g)_h`` before the output projection;
+    # ``lora_rescale``: the normed bottlenecks times ``(hidden_size /
+    # rank) ** 0.5``.
+    layer_types: Optional[tuple] = None
+    sliding_window_size: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    attention_gate: bool = False
+    swa_attention_gate: bool = False
+    lora_rescale: bool = False
     # Sigmoid-routed experts beside a shared one (``moe_intermediate_size``
     # > 0; ``num_experts`` routed, ``num_experts_per_tok`` taken by score +
     # bias, their scores normalised and scaled by
@@ -144,6 +170,55 @@ class ModelConfig:
     def held_experts(self) -> tuple:
         """``(first, count)`` of the routed experts this config holds."""
         return self.experts_held or (0, self.num_experts)
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """``"full"`` or ``"sliding"`` for each of the ``num_layers`` layers
+        held (the first of ``layer_types``; all full without it)."""
+        types = (self.layer_types or ())[:self.num_layers]
+        return tuple("sliding" if t == "sliding_attention" else "full"
+                     for t in types) or ("full",) * self.num_layers
+
+    @property
+    def layer_period(self) -> tuple:
+        """``(leading, periods, sliding a period)`` of the layers held: the
+        ``first_k_dense`` leading layers (full attention, a dense MLP), then
+        ``periods`` times ONE full layer and ``sliding a period`` sliding
+        ones. The form the engine's scans take the stack in
+        (`runtime.batching._layer_groups`); any other order is refused."""
+        kinds = self.layer_kinds
+        lead = min(self.first_k_dense, len(kinds))
+        rest = kinds[lead:]
+        n = rest[1:].index("full") if "full" in rest[1:] else len(rest) - 1
+        periods = len(rest) // (n + 1) if rest else 0
+        if ("sliding" in kinds[:lead]
+                or rest != (("full",) + ("sliding",) * n) * periods):
+            raise NotImplementedError(
+                f"layers {list(kinds)} behind {lead} leading dense "
+                "layer(s): the stack is taken as whole periods of one full "
+                "layer and the sliding layers behind it (cut --num_layers "
+                "to a whole number of periods)")
+        return lead, periods, n
+
+    @property
+    def sliding_kind(self) -> "ModelConfig":
+        """The configuration a ``"sliding_attention"`` layer's body runs
+        under: the ``swa_*`` geometry in the keys every latent layer reads,
+        no indexer (``index_topk`` 0) and ``sliding_window`` rows visible.
+        Only `runtime.batching`'s layer body is ever handed it."""
+        return dataclasses.replace(
+            self, layer_types=None, num_heads=self.swa_num_heads,
+            num_kv_heads=self.swa_num_heads,
+            head_dim_override=(self.swa_qk_nope_head_dim
+                               + self.swa_qk_rope_head_dim),
+            q_lora_rank=self.swa_q_lora_rank,
+            kv_lora_rank=self.swa_kv_lora_rank,
+            qk_nope_head_dim=self.swa_qk_nope_head_dim,
+            qk_rope_head_dim=self.swa_qk_rope_head_dim,
+            v_head_dim=self.swa_v_head_dim, rope_theta=self.swa_rope_theta,
+            attention_gate=self.swa_attention_gate, index_n_heads=0,
+            index_head_dim=0, index_topk=0,
+            sliding_window=self.sliding_window_size)
 
     @property
     def head_dim(self) -> int:
@@ -166,9 +241,14 @@ class ModelConfig:
             assert self.num_kv_heads == self.num_heads
             assert self.loop_steps == 1 and not self.sliding_window
         if self.kv_lora_rank:
-            # the one read of latent rows the engine has is the selected one
-            assert self.index_topk > 0
+            # a latent layer is read under the learned selection or, a
+            # sliding one, over its window: the engine has no third read
+            assert self.index_topk > 0 or (self.sliding_window or 0) > 0
             assert self.loop_steps == 1 and not self.eva_window
+        if self.layer_types and "sliding_attention" in self.layer_types:
+            assert self.kv_lora_rank and self.swa_kv_lora_rank
+            assert self.sliding_window_size > 0 and not self.sliding_window
+            assert len(self.layer_types) >= self.num_layers
         if self.moe_intermediate_size:
             first, count = self.held_experts
             assert 0 <= first and first + count <= self.num_experts
@@ -333,6 +413,55 @@ def glm5_config(q_lora_rank: int = 2048, kv_lora_rank: int = 512,
         first_k_dense=first_k_dense, experts_held=experts_held)
 
 
+def dots3_config(layer_types: tuple, q_lora_rank: int = 1024,
+                 kv_lora_rank: int = 512, qk_nope_head_dim: int = 128,
+                 qk_rope_head_dim: int = 64, v_head_dim: int = 128,
+                 index_n_heads: int = 64, index_head_dim: int = 128,
+                 index_topk: int = 2048, sliding_window_size: int = 513,
+                 swa_num_heads: int = 64, swa_q_lora_rank: int = 1024,
+                 swa_kv_lora_rank: int = 1024,
+                 swa_qk_nope_head_dim: int = 192,
+                 swa_qk_rope_head_dim: int = 64, swa_v_head_dim: int = 128,
+                 swa_rope_theta: float = 50000.0,
+                 attention_gate: bool = True, swa_attention_gate: bool = True,
+                 lora_rescale: bool = True, n_routed_experts: int = 256,
+                 moe_intermediate_size: int = 1536,
+                 routed_scaling_factor: float = 1.0, **kw) -> ModelConfig:
+    """dots3-note-prev (HF ``dots3_note``): GLM-5's function (`glm5_config`:
+    latent attention under a learned selection, sigmoid-routed experts
+    beside a shared one behind a dense layer) on the ``"full_attention"``
+    layers of ``layer_types``, and on the ``"sliding_attention"`` ones
+    latent attention of a second geometry over a window
+    (`ModelConfig.layer_types`); a headwise gate on both kinds' output and
+    the normed bottlenecks rescaled. The vision tower, the audio encoder
+    and the multi-token-prediction module of the checkpoint are not held."""
+    cfg = glm5_config(
+        q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+        qk_nope_head_dim=qk_nope_head_dim, qk_rope_head_dim=qk_rope_head_dim,
+        v_head_dim=v_head_dim, index_n_heads=index_n_heads,
+        index_head_dim=index_head_dim, index_topk=index_topk,
+        n_routed_experts=n_routed_experts,
+        moe_intermediate_size=moe_intermediate_size,
+        routed_scaling_factor=routed_scaling_factor, **kw)
+    return dataclasses.replace(
+        cfg, model_type="dots3_note", layer_types=tuple(layer_types),
+        sliding_window_size=sliding_window_size, swa_num_heads=swa_num_heads,
+        swa_q_lora_rank=swa_q_lora_rank, swa_kv_lora_rank=swa_kv_lora_rank,
+        swa_qk_nope_head_dim=swa_qk_nope_head_dim,
+        swa_qk_rope_head_dim=swa_qk_rope_head_dim,
+        swa_v_head_dim=swa_v_head_dim, swa_rope_theta=swa_rope_theta,
+        attention_gate=attention_gate, swa_attention_gate=swa_attention_gate,
+        lora_rescale=lora_rescale)
+
+
+def dots3_layer_types(num_layers: int, period: int = 4) -> tuple:
+    """``layer_types`` as dots3-note-prev publishes them: layer 0 full, then
+    from layer 1 one full layer and ``period - 1`` sliding ones, over and
+    over."""
+    return tuple("full_attention" if i == 0 or (i - 1) % period == 0
+                 else "sliding_attention" for i in range(num_layers))
+
+
 def mixtral_config(num_experts: int = 8, num_experts_per_tok: int = 2, **kw) -> ModelConfig:
     cfg = llama_config(**kw)
     return dataclasses.replace(
@@ -465,6 +594,37 @@ PRESETS = {
         n_routed_experts=32, num_experts_per_tok=8,
         moe_intermediate_size=512, first_k_dense=1, experts_held=(0, 8),
     ),
+    # dots-studio/dots3-note-prev config.json (the language model) as ONE
+    # chip of the stated deployment serves it
+    # (perfbench/configs/dots3-note-prev.json ``deployment``): 16 chips
+    # share a layer, each holding attention, the gate, the indexer of a full
+    # layer, the shared expert and 16 of the 256 routed experts (this one:
+    # 0 .. 15; the router scores all 256), the vocabulary over 8 chips
+    # (152064 -> 19008 rows of embedding and head). Every width, the
+    # window, ``index_topk`` and the layers' order are the published ones.
+    # ``--num_layers 9`` cuts the depth to the dense layer and two periods.
+    "dots3": lambda: dots3_config(
+        dots3_layer_types(46), vocab_size=19008, hidden_size=5120,
+        num_layers=46, num_heads=128, intermediate_size=13824,
+        max_position_embeddings=524288, rope_theta=80000000.0,
+        first_k_dense=1, experts_held=(0, 16),
+    ),
+    # The same code path for the benchmark's CPU rehearsal (an eighth of
+    # every length: prompts of 255-1750 rows): ``index_topk`` 256 and a
+    # window of 65 rows, so that they cross the selection's edge and wrap
+    # the ring, a quarter of every width, 8 of 32 experts held.
+    "dots3-rehearsal": lambda: dots3_config(
+        dots3_layer_types(46), vocab_size=2376, hidden_size=1280,
+        num_layers=46, num_heads=32, intermediate_size=3456,
+        max_position_embeddings=524288, rope_theta=80000000.0,
+        q_lora_rank=256, kv_lora_rank=128, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, index_n_heads=16,
+        index_head_dim=32, index_topk=256, sliding_window_size=65,
+        swa_num_heads=16, swa_q_lora_rank=256, swa_kv_lora_rank=256,
+        swa_qk_nope_head_dim=48, swa_qk_rope_head_dim=16, swa_v_head_dim=32,
+        n_routed_experts=32, num_experts_per_tok=8,
+        moe_intermediate_size=384, first_k_dense=1, experts_held=(0, 8),
+    ),
 }
 
 # Qwen2.5 shares the qwen2 architecture (HF model_type "qwen2") — alias
@@ -485,6 +645,18 @@ def single_pass_unsupported(cfg: ModelConfig, what: str) -> Optional[str]:
     batched engine only.
     Everything else would silently run one pass of several, or plain
     causal attention past the first window, and must refuse instead."""
+    if cfg.layer_types and "sliding" in cfg.layer_kinds:
+        return (f"layers of two kinds alternate ({list(cfg.layer_kinds)}): "
+                f"a full layer keeps a latent row of {cfg.kv_lora_rank} + "
+                f"{cfg.qk_rope_head_dim} numbers and an index key of "
+                f"{cfg.index_head_dim} a position and reads the "
+                f"{cfg.index_topk} rows a learned indexer selects, a "
+                f"sliding layer keeps a ring of {cfg.sliding_window_size} "
+                f"latent rows of {cfg.swa_kv_lora_rank} + "
+                f"{cfg.swa_qk_rope_head_dim} numbers: {what} keeps one K "
+                "and one V row a position in every layer and has neither a "
+                "selection nor a ring; serve the model whole on the batched "
+                "engine (serve --stage 0 --batched)")
     if cfg.kv_lora_rank:
         return (f"a position keeps a latent row of {cfg.kv_lora_rank} + "
                 f"{cfg.qk_rope_head_dim} numbers and an index key of "

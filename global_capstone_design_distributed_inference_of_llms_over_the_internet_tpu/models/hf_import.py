@@ -207,6 +207,27 @@ def config_from_hf(hf_cfg) -> ModelConfig:
             n_shared_experts=hf_cfg.n_shared_experts,
             routed_scaling_factor=float(hf_cfg.routed_scaling_factor),
             first_k_dense=hf_cfg.first_k_dense_replace)
+    if mt == "dots3_note":
+        from .config import dots3_config
+
+        common.pop("num_kv_heads")
+        return dots3_config(
+            tuple(hf_cfg.layer_types), **common,
+            **{k: getattr(hf_cfg, k) for k in (
+                "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim", "v_head_dim", "index_n_heads",
+                "index_head_dim", "index_topk", "sliding_window_size",
+                "swa_q_lora_rank", "swa_kv_lora_rank",
+                "swa_qk_nope_head_dim", "swa_qk_rope_head_dim",
+                "swa_v_head_dim", "n_routed_experts", "num_experts_per_tok",
+                "moe_intermediate_size", "n_shared_experts")},
+            swa_num_heads=hf_cfg.swa_num_attention_heads,
+            swa_rope_theta=float(hf_cfg.swa_rope_theta),
+            attention_gate=hf_cfg.attention_gate_type == "headwise",
+            swa_attention_gate=hf_cfg.swa_attention_gate_type == "headwise",
+            lora_rescale=bool(hf_cfg.apply_mla_qkv_lora_rescale),
+            routed_scaling_factor=float(hf_cfg.routed_scaling_factor),
+            first_k_dense=hf_cfg.first_k_dense_replace)
     if mt == "mixtral":
         cfg = mixtral_config(
             num_experts=hf_cfg.num_local_experts,
@@ -223,7 +244,7 @@ def config_from_hf(hf_cfg) -> ModelConfig:
     raise ValueError(
         f"unsupported model_type: {mt} "
         "(expected gpt2/llama/mistral/mixtral/qwen2/gemma/ouro/evabyte/"
-        "glm_moe_dsa)")
+        "glm_moe_dsa/dots3_note)")
 
 
 def _gpt2_layer(sd: Mapping[str, Any], i: int) -> Params:
@@ -341,18 +362,27 @@ def _glm5_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
     head of ``q_b_proj``, the shared key of ``kv_a_proj_with_mqa``, and
     the first ``qk_rope_head_dim`` of the indexer's queries, key and the
     key's LayerNorm. Of the routed experts only those held
-    (``cfg.held_experts``) are read; the router keeps every output."""
+    (``cfg.held_experts``) are read; the router keeps every output.
+
+    A ``dots3_note`` checkpoint: the same names; a ``"sliding_attention"``
+    layer (``cfg.layer_kinds``) has the ``swa_*`` geometry under them and no
+    ``indexer.*``; both kinds add the headwise gate ``self_attn.g_proj``;
+    its rotated dims are held as halves already (no ``rope_interleave``
+    key: the rotate-half convention), so nothing is permuted."""
     pre = f"model.layers.{i}."
     att = pre + "self_attn."
-    h, r = cfg.num_heads, cfg.qk_rope_head_dim
-    nope, kl = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    half = _half_layout(r)
+    # the attention's sizes are its KIND's; the MLP's are the family's
+    kind = cfg.sliding_kind if cfg.layer_kinds[i] == "sliding" else cfg
+    h, r = kind.num_heads, kind.qk_rope_head_dim
+    nope, kl = kind.qk_nope_head_dim, kind.kv_lora_rank
+    half = (_half_layout(r) if kind.model_type == "glm_moe_dsa"
+            else np.arange(r))
     t = lambda name: _np(sd[name])
     head = np.concatenate([np.arange(nope), nope + half])
     q_rows = (np.arange(h)[:, None] * (nope + r) + head[None]).reshape(-1)
-    di = cfg.index_head_dim
+    di = kind.index_head_dim
     ikey = np.concatenate([half, np.arange(r, di)])
-    iq_rows = (np.arange(cfg.index_n_heads)[:, None] * di
+    iq_rows = (np.arange(kind.index_n_heads)[:, None] * di
                + ikey[None]).reshape(-1)
     kva_rows = np.concatenate([np.arange(kl), kl + half])
     p: Params = {
@@ -367,14 +397,19 @@ def _glm5_layer(sd: Mapping[str, Any], i: int, cfg: ModelConfig) -> Params:
             "kv_norm": {"w": t(att + "kv_a_layernorm.weight")},
             "wkvb_t": t(att + "kv_b_proj.weight").reshape(h, -1, kl),
             "wo": t(att + "o_proj.weight").T,
+        },
+    }
+    if kind.index_topk:
+        p["attn"].update({
             "wiq_t": t(att + "indexer.wq_b.weight")[iq_rows].reshape(
-                cfg.index_n_heads, di, -1),
+                kind.index_n_heads, di, -1),
             "wik": t(att + "indexer.wk.weight")[ikey].T,
             "ik_norm": {"w": t(att + "indexer.k_norm.weight")[ikey],
                         "b": t(att + "indexer.k_norm.bias")[ikey]},
             "wiw": t(att + "indexer.weights_proj.weight").T,
-        },
-    }
+        })
+    if kind.attention_gate:
+        p["attn"]["wgate"] = t(att + "g_proj.weight").T
     mlp = pre + "mlp."
 
     def swiglu(owner):
@@ -434,8 +469,15 @@ def convert_state_dict(
         k = max(0, min(cfg.first_k_dense, end) - start)
         to_device = lambda path, x: jnp.asarray(
             x, jnp.float32 if path[-1].key == "router_bias" else dtype)
-        for name, group in (("dense_layers", layers[:k]),
-                            ("layers", layers[k:])):
+        kinds = cfg.layer_kinds[start:end][k:]
+        for name, group in (
+                ("dense_layers", layers[:k]),
+                ("layers", [p for p, kind in zip(layers[k:], kinds)
+                            if kind == "full"]),
+                # layers of another kind again, whose weights have other
+                # shapes (``cfg.layer_types``): a third stack
+                ("sliding_layers", [p for p, kind in zip(layers[k:], kinds)
+                                    if kind == "sliding"])):
             if group:
                 params[name] = jax.tree_util.tree_map_with_path(
                     to_device, _stack(group))

@@ -168,14 +168,16 @@ def slice_stage_params(cfg: ModelConfig, params: Params, spec: StageSpec) -> Par
     out: Params = {}
     if "dense_layers" in params:
         # Leading layers of another kind, stacked apart (a family with
-        # ``first_k_dense``): the full span holds both stacks whole, and no
-        # engine serves part of such a stack.
+        # ``first_k_dense``; one whose layers alternate between two kinds
+        # holds ``sliding_layers`` too): the full span holds every stack
+        # whole, and no engine serves part of such a stack.
         if not (spec.is_first and spec.is_last):
             from .config import refuse_single_pass
 
             refuse_single_pass(cfg, "a stage over part of the stack")
-        out["dense_layers"] = params["dense_layers"]
-        out["layers"] = params["layers"]
+        for key in ("dense_layers", "layers", "sliding_layers"):
+            if key in params:
+                out[key] = params[key]
     elif spec.num_layers > 0:
         out["layers"] = jax.tree.map(lambda x: x[spec.start : spec.end], params["layers"])
     if spec.is_first:

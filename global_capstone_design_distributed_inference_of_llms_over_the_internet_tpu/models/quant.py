@@ -304,7 +304,7 @@ def quantize_params(params: Params, quant: str = "int8") -> Params:
     """Quantize a full/stage param tree: blocks only (embed/head/norm full
     precision, matching the reference's block-scoped quantization)."""
     out = dict(params)
-    for key in ("layers", "dense_layers"):
+    for key in ("layers", "dense_layers", "sliding_layers"):
         if key in params:
             out[key] = quantize_layers(params[key], quant)
     return out

@@ -113,7 +113,9 @@ def init_latent_layer_params(rng: jax.Array, cfg: ModelConfig, dtype,
     """Random init for ONE layer of a latent-attention family
     (``cfg.kv_lora_rank``): the query and key/value bottlenecks with their
     norms, the indexer (``wiq_t`` from the query bottleneck, ``wik`` and its
-    LayerNorm, the per-head weights ``wiw``), and either the dense SwiGLU
+    LayerNorm, the per-head weights ``wiw``; none for a sliding layer,
+    ``cfg.index_topk`` 0), the headwise gate ``wgate`` where the family has
+    one (``cfg.attention_gate``), and either the dense SwiGLU
     (``dense``: a leading layer) or the router with its score bias, the
     HELD routed experts and the shared expert. Four weights rest with the
     contracted axis LAST, as `models.hf_import` leaves them (`_dot_t`):
@@ -134,12 +136,18 @@ def init_latent_layer_params(rng: jax.Array, cfg: ModelConfig, dtype,
             "wkvb_t": _dense(ks[3], (h, cfg.qk_nope_head_dim
                                      + cfg.v_head_dim, kl), dtype),
             "wo": _dense(ks[4], (h * cfg.v_head_dim, d), dtype),
+        },
+    }
+    if cfg.index_topk:      # a layer read under the learned selection
+        p["attn"].update({
             "wiq_t": _dense(ks[5], (ih, idh, ql), dtype),
             "wik": _dense(ks[6], (d, idh), dtype),
             "ik_norm": {"w": one(idh), "b": jnp.zeros((idh,), dtype)},
             "wiw": _dense(ks[7], (d, ih), dtype),
-        },
-    }
+        })
+    if cfg.attention_gate:
+        p["attn"]["wgate"] = _dense(jax.random.fold_in(rng, 16), (d, h),
+                                    dtype)
     if dense:
         i = cfg.intermediate_size
         p["mlp"] = {"wg": _dense(ks[8], (d, i), dtype),
@@ -164,19 +172,32 @@ def init_latent_layer_params(rng: jax.Array, cfg: ModelConfig, dtype,
 def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     """Random init of the FULL model with stacked layers. A family with
     leading dense layers (``cfg.first_k_dense``) holds TWO stacks: those
-    under ``dense_layers``, the expert layers under ``layers``."""
+    under ``dense_layers``, the expert layers under ``layers``. One whose
+    layers are of two kinds (``cfg.layer_types``) holds the sliding ones,
+    whose weights have other shapes, under ``sliding_layers``, in their
+    order in the stack (``cfg.layer_period``)."""
     k_emb, k_layers, k_head = jax.random.split(rng, 3)
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    dense_layers = None
+    dense_layers = sliding_layers = None
     if cfg.kv_lora_rank:
         k = min(cfg.first_k_dense, cfg.num_layers)
         # a layer at a time: drawn all at once, the float32 normals of five
         # layers' held experts are 12 GB beside the 9.5 GB they are cast to
-        stack = lambda keys, dense: jax.lax.map(
-            lambda key: init_latent_layer_params(key, cfg, dtype, dense),
+        stack = lambda keys, dense, kind=cfg: jax.lax.map(
+            lambda key: init_latent_layer_params(key, kind, dtype, dense),
             keys)
         dense_layers = stack(layer_keys[:k], True) if k else None
-        layers = stack(layer_keys[k:], False)
+        if "sliding" in cfg.layer_kinds:
+            import numpy as np
+
+            cfg.layer_period        # refuses an order the scans do not take
+            sliding = np.asarray(
+                [kind == "sliding" for kind in cfg.layer_kinds[k:]])
+            sliding_layers = stack(layer_keys[k:][sliding], False,
+                                   cfg.sliding_kind)
+            layers = stack(layer_keys[k:][~sliding], False)
+        else:
+            layers = stack(layer_keys[k:], False)
     else:
         layers = jax.vmap(lambda k: init_layer_params(k, cfg, dtype))(
             layer_keys)
@@ -208,6 +229,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> Params:
     params: Params = {"embed": embed, "layers": layers, "final_norm": final_norm}
     if dense_layers is not None:
         params["dense_layers"] = dense_layers
+    if sliding_layers is not None:
+        params["sliding_layers"] = sliding_layers
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"w": _dense(
             k_head, (cfg.hidden_size, cfg.pred_heads * cfg.vocab_size),

@@ -872,7 +872,10 @@ def _latent_proj(cfg, p, a, rope):
     ``Hi ** -0.5 * Di ** -0.5``). ``row`` ``[B, T, 1, kv_lora_rank +
     qk_rope_head_dim]``: the position's latent cache row, the normed
     compressed K/V beside the ONE rotated key every head shares. ``key``
-    ``[B, T, 1, Di]``: its index key (LayerNorm, rotated likewise)."""
+    ``[B, T, 1, Di]``: its index key (LayerNorm, rotated likewise). A layer
+    with no indexer (``cfg.index_topk`` 0: a sliding one, `_attend_window`)
+    has neither ``iq`` / ``iw`` nor a key (None). ``cfg.lora_rescale``: both
+    normed bottlenecks times ``(hidden_size / rank) ** 0.5``."""
     kl, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
 
     def rotated(x, n):
@@ -880,19 +883,28 @@ def _latent_proj(cfg, p, a, rope):
         return jnp.concatenate(
             [apply_rope(x[..., :n], *rope), x[..., n:]], axis=-1)
 
-    c_q = rms_norm(_dot(a, p["wqa"]), p["q_norm"]["w"], cfg.norm_eps)
+    def rescale(x, rank):
+        return x * (cfg.hidden_size / rank) ** 0.5 if cfg.lora_rescale else x
+
+    c_q = rescale(rms_norm(_dot(a, p["wqa"]), p["q_norm"]["w"],
+                           cfg.norm_eps), cfg.q_lora_rank)
     q = _dot_t(c_q, p["wqb_t"])                              # [B, T, H, Dh]
     kv = _dot_t(a, p["wkva_t"])
-    c_kv = rms_norm(kv[..., :kl], p["kv_norm"]["w"], cfg.norm_eps)
+    c_kv = rescale(rms_norm(kv[..., :kl], p["kv_norm"]["w"], cfg.norm_eps),
+                   kl)
     k_r = apply_rope(kv[..., None, kl:], *rope)               # [B, T, 1, r]
-    iq = rotated(_dot_t(c_q, p["wiq_t"]), r)
-    key = rotated(layer_norm(_dot(a, p["wik"]), p["ik_norm"]["w"],
-                             p["ik_norm"]["b"], 1e-6)[:, :, None], r)
-    iw = (jnp.dot(a.astype(jnp.float32), p["wiw"].astype(jnp.float32))
-          * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5))
+    index = {}
+    key = None
+    if cfg.index_topk:      # a sliding layer has no indexer, and no key
+        index["iq"] = rotated(_dot_t(c_q, p["wiq_t"]), r)
+        key = rotated(layer_norm(_dot(a, p["wik"]), p["ik_norm"]["w"],
+                                 p["ik_norm"]["b"], 1e-6)[:, :, None], r)
+        index["iw"] = (
+            jnp.dot(a.astype(jnp.float32), p["wiw"].astype(jnp.float32))
+            * (cfg.index_n_heads ** -0.5 * cfg.index_head_dim ** -0.5))
     nope = cfg.qk_nope_head_dim
     return ({"nope": q[..., :nope], "rope": apply_rope(q[..., nope:], *rope),
-             "iq": iq, "iw": iw},
+             **index},
             jnp.concatenate([c_kv[:, :, None], k_r], axis=-1), key)
 
 
@@ -996,6 +1008,8 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
     an online softmax over the same blocks, each block's rows expanded
     through ``W_kvb`` to the heads' keys and values once for all T
     queries."""
+    if not cfg.index_topk:          # a sliding layer: its window, no indexer
+        return _attend_window(cfg, lp, q, rows, q_pos)
     kl, nope, vd = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
     heads, topk = cfg.num_heads, cfg.index_topk
     # ``[H, nope + vd, kl]`` as it rests: a head's key rows, then its value
@@ -1112,6 +1126,138 @@ def _attend_latent(cfg, lp, q, rows, keys, q_pos):
     return out.transpose(1, 0, 2).reshape(1, t, -1).astype(dt)
 
 
+# -- latent layers of two kinds alternating in one stack --------------------
+# (``cfg.layer_types``: full layers under the selection, sliding layers of
+# another latent geometry over a window)
+
+class _LatentStacks(NamedTuple):
+    """The latent rows of a family whose layers are of two kinds, where a
+    family of one kind has ONE stack: ``rows`` ``[full layers, S, max_len,
+    .]``, a row a position of every FULL layer, and ``ring`` ``[sliding
+    layers, S, R, .]`` (`ring_rows`), position p of a SLIDING layer at row
+    ``p % R``. Each layer indexes the stack of its kind by its index among
+    that kind. A pytree: the programs carry, donate and return it where
+    they carry a stack (the index keys, of the full layers only, are the
+    other stack they carry)."""
+    rows: Any
+    ring: Any
+
+
+def _full_rows(k_all):
+    """The full layers' stack of latent rows."""
+    return k_all.rows if isinstance(k_all, _LatentStacks) else k_all
+
+
+def ring_rows(window: int, max_len: int) -> int:
+    """Rows of a sliding layer's ring for a window of ``window`` positions:
+    the next multiple of `ATTN_BLOCK` above it (513 -> 640), so that a
+    rewind of up to ``R - window + 1`` positions finds every row its window
+    needs still unwritten (`BatchedStageExecutor.rewind`); never more than
+    the slot."""
+    return min((window // ATTN_BLOCK + 1) * ATTN_BLOCK, max_len)
+
+
+@dataclasses.dataclass(frozen=True)
+class _RingChunk:
+    """What a prefill chunk's queries attend over in a sliding layer:
+    ``before`` ``[1, R, .]``, the slot's ring as the chunks before it left
+    it (position p at row ``p % R``), and ``fresh`` ``[1, T, .]``, the
+    chunk's own rows, the first at position ``start``."""
+    before: Any
+    fresh: Any
+    start: Any
+
+
+def _attend_window(cfg, lp, q, rows, q_pos):
+    """Attention of a SLIDING latent layer (``cfg``: `ModelConfig
+    .sliding_kind`): ``[B, T, H * v_head_dim]``, before the gate and the
+    output projection. The query at t attends to every position s with ``t
+    - cfg.sliding_window < s <= t``; no indexer, no selection.
+
+    A decode step (``rows`` a `_CacheLayer` of the ring stack, the step's
+    own row written; one query row a slot): the slot's whole ring, read
+    ONCE as it rests under the ``window_read`` scope, in the ABSORBED form
+    (`_absorbed`): row j holds position ``t - (t - j) % R``, visible while
+    that is inside the window and not before the slot's first.
+
+    A prefill chunk (``rows`` a `_RingChunk`, ``q_pos`` ``[T, 1]``): the
+    EXPANDED form over the ring as it was before the chunk beside the
+    chunk's own rows, each key at its position, in blocks of
+    `PREFILL_QUERY_ROWS` queries."""
+    kl, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    r, w = cfg.qk_rope_head_dim, cfg.sliding_window
+    wkvb = _plain(lp["attn"]["wkvb_t"])
+    scale = cfg.head_dim ** -0.5
+    dt = q["nope"].dtype
+    if isinstance(rows, _CacheLayer):
+        ring = rows.stack.shape[2]
+        with jax.named_scope("window_read"):
+            got = jax.lax.dynamic_index_in_dim(
+                rows.stack, rows.at, 0, keepdims=False).astype(dt)
+        # a row as it rests: compressed part, rotated key, the lanes' pad
+        mine = jnp.concatenate([_absorbed(q, wkvb[:, :nope]),
+                                q["rope"][:, 0]], -1) * scale
+        mine = jnp.pad(mine, ((0, 0), (0, 0),
+                              (0, got.shape[-1] - mine.shape[-1]))).astype(dt)
+        sc = jnp.einsum("shw,skw->shk", mine, got,
+                        preferred_element_type=jnp.float32)
+        p = q_pos[:, 0]                                        # [S, 1]
+        back = (p - jnp.arange(ring, dtype=jnp.int32)[None, :]) % ring
+        seen = (back < w) & (back <= p)
+        probs = jax.nn.softmax(jnp.where(seen[:, None, :], sc, NEG_INF), -1)
+        o_lat = jnp.einsum("shk,skw->shw", probs.astype(dt), got)[..., :kl]
+        out = jnp.einsum("shl,hvl->shv", o_lat, wkvb[:, nope:].astype(dt))
+        return out.reshape(out.shape[0], 1, -1)
+
+    t, ring = q["nope"].shape[1], rows.before.shape[1]
+    at = jnp.arange(ring, dtype=jnp.int32)
+    last = rows.start - 1           # the newest position the ring holds
+    # a ring row's position: the newest that rests there; negative where
+    # the slot has not reached it
+    k_pos = jnp.concatenate([
+        last - (last - at) % ring,
+        rows.start + jnp.arange(t, dtype=jnp.int32)])          # [R + T]
+    with jax.named_scope("window_read"):
+        got = jnp.concatenate([rows.before[0], rows.fresh[0]]).astype(dt)
+    kv = jnp.einsum("kl,hel->khe", got[:, :kl], wkvb.astype(dt))
+    k_r = got[:, kl:kl + r]
+    qb = next(n for n in range(min(t, PREFILL_QUERY_ROWS), 0, -1)
+              if t % n == 0)
+
+    def block(i):
+        take = lambda x: jax.lax.dynamic_slice_in_dim(x, i * qb, qb, 0)
+        q_n, q_r, pos = take(q["nope"][0]), take(q["rope"][0]), take(q_pos)
+        sc = (jnp.einsum("thn,khn->htk", q_n, kv[..., :nope],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("thr,kr->htk", q_r, k_r,
+                           preferred_element_type=jnp.float32)) * scale
+        seen = ((k_pos[None, :] >= 0) & (k_pos[None, :] <= pos)
+                & (k_pos[None, :] > pos - w))                  # [qb, R + T]
+        probs = jax.nn.softmax(jnp.where(seen[None], sc, NEG_INF), -1)
+        return jnp.einsum("htk,khv->thv", probs.astype(dt), kv[..., nope:])
+
+    out = jax.lax.map(block, jnp.arange(t // qb, dtype=jnp.int32))
+    return out.reshape(1, t, -1)
+
+
+def _write_ring(ring_l, fresh, start, t_real):
+    """A slot's ring layer ``ring_l`` (``[1, R, .]``) after a prefill chunk
+    of ``t_real`` real rows ``fresh`` (``[1, T, .]``, padded past them), the
+    first at position ``start``: each real row at ``position % R``, of a
+    chunk longer than the ring the newest R (a row past ``t_real`` would
+    overwrite one a later query still needs, and is dropped)."""
+    t, ring = fresh.shape[1], ring_l.shape[1]
+    i = jnp.arange(t, dtype=jnp.int32)
+    keep = (i < t_real) & (i >= t_real - ring)
+    at = jnp.where(keep, (start + i) % ring, ring + i)[:, None]
+    return jax.lax.scatter(
+        ring_l[0], at, fresh[0].astype(ring_l.dtype),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0,)),
+        mode="drop", unique_indices=True)[None]
+
+
 def _decoder_layer(cfg, lp, h, rope, cache_policy):
     """One decoder layer of every engine program: ``(h, state)``.
 
@@ -1160,6 +1306,13 @@ def _decoder_layer(cfg, lp, h, rope, cache_policy):
             out = jnp.concatenate(outs, axis=1)
         else:
             out = _attend(cfg, lp, q, keys, values, grid)
+        if "wgate" in lp["attn"]:
+            # headwise: head h's output times ONE number of the normed
+            # stream's. The ONE place the ``attn_gate`` scope is opened.
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(_dot(a, lp["attn"]["wgate"]))
+                out = (out.reshape(*gate.shape, -1)
+                       * gate[..., None].astype(out.dtype)).reshape(out.shape)
         out = _dot(out, lp["attn"]["wo"])
         if "bo" in lp["attn"]:
             out = out + lp["attn"]["bo"]
@@ -1231,33 +1384,105 @@ def _scan_layers(layer, h, layers, *xs):
         body, h, (jnp.arange(n, dtype=jnp.int32), rest, *xs))
 
 
+class _Period(NamedTuple):
+    """The layers behind the leading ones of a family whose layers are of
+    two kinds (``cfg.layer_period``): ``full`` ``[P, ..]``, one full layer a
+    period, and ``sliding`` ``[P * n, ..]``, the n sliding layers behind
+    each, both in their order in the stack. The scans take a period a step:
+    the full layer, then an inner scan over its sliding ones."""
+    full: Any
+    sliding: Any
+
+    @property
+    def counts(self) -> Tuple[int, int]:
+        """``(P, n)``."""
+        p = jax.tree.leaves(self.full)[0].shape[0]
+        return p, jax.tree.leaves(self.sliding)[0].shape[0] // p
+
+
 def _layer_groups(params):
     """The span's layers as the scans take them: ``[(stacked tree, index of
-    its first layer)]``. ONE stack for every family but one with leading
-    dense layers (``cfg.first_k_dense``), whose first layers are another
-    kind than the rest and stacked apart (``dense_layers``): the same layer
-    body scans each group in turn, inside ONE program."""
+    its first layer among the full layers)]``. ONE stack for every family
+    but one with leading dense layers (``cfg.first_k_dense``), whose first
+    layers are another kind than the rest and stacked apart
+    (``dense_layers``): the same layer body scans each group in turn,
+    inside ONE program. Where the rest are of two kinds that alternate
+    (``sliding_layers`` beside ``layers``) they are ONE group, a
+    `_Period`."""
     if "dense_layers" not in params:
         return [(params["layers"], 0)]
     n = jax.tree.leaves(params["dense_layers"])[0].shape[0]
-    return [(params["dense_layers"], 0), (params["layers"], n)]
+    rest = params["layers"]
+    if "sliding_layers" in params:
+        rest = _Period(rest, params["sliding_layers"])
+    return [(params["dense_layers"], 0), (rest, n)]
+
+
+def _layer_of(stack, i):
+    """Layer ``i`` (traced) of a stacked tree, as a scan's own slicing of
+    its ``xs`` would hand it: a dynamic slice of an array the loop does not
+    change, which the compiler reads where it is used. An inner scan's
+    layers are taken so and NOT as the outer scan's ``xs`` (a period's
+    ``[n, ..]`` slice of a weight stack would be copied out whole before
+    the inner loop could start: three layers' held experts a period a
+    tick)."""
+    return jax.tree.map(
+        lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
+        stack)
+
+
+def _scan_period(layer, h, period, first, k_in, v_in):
+    """`_scan_layers` over a `_Period` for the prefill program: a scan over
+    the periods whose body is ``layer(h, (lp, rows_l, keys_l))`` for the
+    full layer (the slot's rows of full layer ``first + p`` of ``k_in.rows``
+    / ``v_in``) and an inner scan of ``layer(h, (lp, ring_l),
+    sliding=True)`` over its sliding layers (their rings of ``k_in.ring``):
+    ``(h, (rows, keys, ring))``, each layer's first outputs stacked by
+    kind."""
+    periods, n = period.counts
+    full, held_f = _split_stacks(period.full)
+    sliding, held_s = _split_stacks(period.sliding)
+
+    def body(h, xs):
+        p, lp_f, rows_l, keys_l = xs
+        h, ys = layer(h, (_layer_at(lp_f, held_f, p), rows_l, keys_l))
+
+        def inner(h, j):
+            i = p * n + j
+            h, ys = layer(h, (_layer_at(_layer_of(sliding, i), held_s, i),
+                              _layer_of(k_in.ring, i)), sliding=True)
+            return h, ys[0]
+
+        h, ring = jax.lax.scan(inner, h, jnp.arange(n, dtype=jnp.int32))
+        return h, (*ys[:2], ring)
+
+    h, (rows, keys, ring) = jax.lax.scan(body, h, (
+        jnp.arange(periods, dtype=jnp.int32), full,
+        k_in.rows[first:first + periods], v_in[first:first + periods]))
+    return h, (rows, keys, ring.reshape(-1, *ring.shape[2:]))
 
 
 def _scan_groups(params, layer, h, *xs):
     """`_scan_layers` over every group of `_layer_groups` in turn, each
     handed its own layers' share of ``xs``: ``(h, ys)`` with every stacked
     output's first two members (a prefill's new cache rows) concatenated
-    over the groups. One group: `_scan_layers` as it is."""
+    over the groups. One group: `_scan_layers` as it is. With a `_Period`
+    among them (``xs``: `_LatentStacks` and the index keys) the first
+    member comes back as `_LatentStacks`."""
     groups = _layer_groups(params)
     if len(groups) == 1:
         return _scan_layers(layer, h, params["layers"], *xs)
-    outs = []
+    outs, ring = [], None
     for layers, first in groups:
-        n = jax.tree.leaves(layers)[0].shape[0]
-        h, ys = _scan_layers(layer, h, layers,
-                             *(x[first:first + n] for x in xs))
+        if isinstance(layers, _Period):
+            h, (*ys, ring) = _scan_period(layer, h, layers, first, *xs)
+        else:
+            n = jax.tree.leaves(layers)[0].shape[0]
+            h, ys = _scan_layers(layer, h, layers, *(
+                _full_rows(x)[first:first + n] for x in xs))
         outs.append(ys[:2])
-    return h, tuple(jnp.concatenate(rows) for rows in zip(*outs))
+    rows, keys = (jnp.concatenate(part) for part in zip(*outs))
+    return h, (rows if ring is None else _LatentStacks(rows, ring), keys)
 
 
 def _scan_layers_in_place(layer, h, layers, k_all, v_all):
@@ -1283,6 +1508,8 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     slab, the write-back and one more copy: 72% of the gpt2-xl tick and
     22% of qwen2-7b's (ledger, PR 31)."""
 
+    if isinstance(layers, _Period):
+        return _scan_period_in_place(layer, h, layers, k_all, v_all)
     rest, held = _split_stacks(layers)
 
     def body(carry, xs):
@@ -1296,6 +1523,43 @@ def _scan_layers_in_place(layer, h, layers, k_all, v_all):
     (h, k_all, v_all), more = jax.lax.scan(
         body, (h, k_all, v_all), (rest, jnp.arange(n, dtype=jnp.int32)))
     return h, k_all, v_all, more
+
+
+def _scan_period_in_place(layer, h, period, k_all, v_all):
+    """`_scan_layers_in_place` over a `_Period`: a scan over the periods
+    whose body is ``layer(h, (lp, p, k_all, v_all))`` for the full layer
+    and an inner scan of ``layer(h, (lp, p * n + j, k_all, v_all),
+    sliding=True)`` over its n sliding ones, the stacks carried through
+    both. What the layers return beside the stacks comes back stacked in
+    the layers' own order, ``[P * (1 + n), ..]``."""
+    periods, n = period.counts
+    full, held_f = _split_stacks(period.full)
+    sliding, held_s = _split_stacks(period.sliding)
+
+    def one_layer(carry, lp, i, **kind):
+        h, k_all, v_all = carry
+        h, (k_all, v_all, *more) = layer(h, (lp, i, k_all, v_all), **kind)
+        return (h, k_all, v_all), tuple(more)
+
+    def body(carry, xs):
+        p, lp_f = xs
+        carry, first = one_layer(carry, _layer_at(lp_f, held_f, p), p)
+
+        def inner(carry, j):
+            i = p * n + j
+            return one_layer(
+                carry, _layer_at(_layer_of(sliding, i), held_s, i), i,
+                sliding=True)
+
+        carry, rest = jax.lax.scan(
+            inner, carry, jnp.arange(n, dtype=jnp.int32))
+        return carry, tuple(jnp.concatenate([a[None], b])
+                            for a, b in zip(first, rest))
+
+    (h, k_all, v_all), more = jax.lax.scan(
+        body, (h, k_all, v_all),
+        (jnp.arange(periods, dtype=jnp.int32), full))
+    return h, k_all, v_all, tuple(m.reshape(-1, *m.shape[2:]) for m in more)
 
 
 def _run_passes(cfg, params, h, one_pass, k_all, v_all):
@@ -1425,7 +1689,11 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     A latent family (``cfg.kv_lora_rank``; T = 1): ``k_all`` is the stack
     of latent rows, ``v_all`` that of index keys, a row of each written a
     position a layer by the same `_append_rows`; ``steps`` is then which
-    rows chose which held expert, ``[expert layers, S, held]``."""
+    rows chose which held expert, ``[expert layers, S, held]``. One whose
+    layers are of two kinds: ``k_all`` is `_LatentStacks`, a full layer
+    reads and writes ``rows`` and ``v_all`` as above, a sliding layer its
+    ring alone (`_attend_window`), each under its own geometry
+    (``cfg.sliding_kind``) and RoPE base."""
     slots = x.shape[0]
     if rider is not None:
         r_pos = rider["start"] + jnp.arange(
@@ -1436,6 +1704,8 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
         h = (embed_tokens(cfg, params["embed"], x, positions)
              if spec.is_first else x)
         rope = make_rope(cfg, positions)
+        if isinstance(k_all, _LatentStacks):    # the sliding layers' base
+            rope_sliding = make_rope(cfg.sliding_kind, positions)
     if rider is not None:
         positions = positions[0, :slots, None]                  # [S, 1]
     qpos = positions[:, :, None]                            # [S, T, 1]
@@ -1451,7 +1721,7 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
         # (latent rows, index keys): the keys are scored to the longest
         # active slot's block; the rows are read as far or, by the kernel,
         # each slot's own blocks under its limit
-        max_len = k_all.shape[2]
+        max_len = _full_rows(k_all).shape[2]
         scored = index_blocks(lengths, active, max_len, jnp)
         rows = latent_block(max_len)
         blocks = (read_plan(
@@ -1489,10 +1759,22 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
             jnp.concatenate([active, rider["valid"]]))
 
     def one_pass(h, base, k_all, v_all):
-        def layer(first, h, xs):
+        def layer(first, h, xs, sliding=False):
             # weight layer ``i`` of the group that starts at layer ``first``
+            # (of a family whose layers are of two kinds: among its KIND)
             lp, i, k_all, v_all = xs
-            at = _at(base, i + first if first else i)
+            at = _at(base, i + first if first and not sliding else i)
+
+            def ring_append(k, _):
+                # a sliding layer: the new row at ``p % R`` of its ring,
+                # THEN the slot's whole ring to `_attend_window`
+                ring = k_all.ring
+                with jax.named_scope("kv_update"):
+                    ring = _append_rows(ring, at, k.astype(ring.dtype),
+                                        lengths % ring.shape[2], active)
+                return (_CacheLayer(ring, at, None), None,
+                        (None, qpos, None),
+                        (k_all._replace(ring=ring), v_all))
 
             def per_slot_append(k, v):
                 # Write the T new rows a slot into the stacks, THEN hand
@@ -1501,9 +1783,10 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
                     return _append_windowed(cfg, lp, at, k, v, k_all, v_all,
                                             lengths, active, qpos, blocks)
                 if rider is None:
+                    k_rows = _full_rows(k_all)
                     with jax.named_scope("kv_update"):
                         k_new = _append_rows(
-                            k_all, at, k.astype(k_all.dtype), lengths,
+                            k_rows, at, k.astype(k_rows.dtype), lengths,
                             active)
                         v_new = _append_rows(
                             v_all, at, v.astype(v_all.dtype), lengths,
@@ -1512,7 +1795,9 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
                         blocks if cfg.kv_lora_rank else (blocks, blocks))
                     return (_CacheLayer(k_new, at, k_blocks),
                             _CacheLayer(v_new, at, v_blocks),
-                            (None, qpos, None), (k_new, v_new))
+                            (None, qpos, None),
+                            (k_new if k_rows is k_all
+                             else k_all._replace(rows=k_new), v_new))
                 new, read = [], []
                 for stack, rows in ((k_all, k), (v_all, v)):
                     rows = rows[0].astype(stack.dtype)      # [S + C, ..]
@@ -1528,6 +1813,9 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
                         ((None, qpos, None), (r_allowed, r_qpos, r_grid)),
                         tuple(new))
 
+            if sliding:
+                return _decoder_layer(cfg.sliding_kind, lp, h, rope_sliding,
+                                      ring_append)
             return _decoder_layer(cfg, lp, h, rope, per_slot_append)
 
         more = ()
@@ -1606,6 +1894,8 @@ class BatchedStageExecutor:
         self._m_written = _tm.get("server_kv_positions_written_total")
         self._m_index_scored = _tm.get("server_index_rows_scored_total")
         self._m_streamed = _tm.get("server_latent_rows_streamed_total")
+        self._m_window_read = _tm.get("server_window_rows_read_total")
+        self._m_window_span = _tm.get("server_window_rows_span_total")
         self._m_moe = [_tm.get(name) for name in MOE_COUNTERS]
         self._m_rows_held = _tm.get("server_state_rows_held_total")
         self._m_pos_held = _tm.get("server_positions_held_total")
@@ -1678,32 +1968,65 @@ class BatchedStageExecutor:
         ``[L, S, max_len, index_head_dim]``: TWO rows a position a layer,
         of different widths, neither per head (a row's numbers are its
         minor dim already; the programs read a latent row's first
-        ``kv_lora_rank + qk_rope_head_dim`` numbers whatever its pad)."""
+        ``kv_lora_rank + qk_rope_head_dim`` numbers whatever its pad). A
+        family whose layers are of two kinds holds THREE stacks: those two
+        over its FULL layers only, and (``self.k`` then `_LatentStacks`)
+        the sliding layers' rings, ``[sliding layers, S, R, swa_kv_lora_rank
+        + swa_qk_rope_head_dim]`` (`ring_rows`)."""
         cfg = self.cfg
-        depth = max(self.spec.num_layers, 1)
+        kinds = cfg.layer_kinds[:max(self.spec.num_layers, 1)]
+        depth = kinds.count("full")
         row = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-        # Where the backend would not keep a row of that many numbers minor
-        # (the v5e holds 576 with ``max_len`` minor, to pad nothing, and
-        # every program then re-lays the stack at its edges: PERF.md
-        # section 6, PRs 45 and 55), the row is held padded with zeros to
-        # whole lane tiles, as a folded row is (`kv_fold_width`: 640).
-        asked = jnp.zeros((1, 1, self.max_len, row), self.dtype).format.layout
-        widths = (kv_fold_width(asked, 1, row) or row, cfg.index_head_dim)
+
+        def asked_of(row: int, rows: int):
+            return jnp.zeros((1, 1, rows, row), self.dtype).format.layout
+
+        def width(row: int, rows: int) -> int:
+            # Where the backend would not keep a row of that many numbers
+            # minor (the v5e holds 576 with ``max_len`` minor, to pad
+            # nothing, and every program then re-lays the stack at its
+            # edges: PERF.md section 6, PRs 45 and 55), the row is held
+            # padded with zeros to whole lane tiles, as a folded row is
+            # (`kv_fold_width`: 640; a sliding layer's 1088 as 1152).
+            return kv_fold_width(asked_of(row, rows), 1, row) or row
+
+        asked = asked_of(row, self.max_len)
+        widths = (width(row, self.max_len), cfg.index_head_dim)
         self.k, self.v = (jnp.zeros((depth, self.slots, self.max_len, w),
                                     self.dtype) for w in widths)
-        _tm.get("server_kv_stack_bytes").set(self.k.nbytes + self.v.nbytes)
+        ring = {}
+        if "sliding" in kinds:
+            # A sliding layer's slot is a RING and never a row a position:
+            # its stack has no axis of ``max_len``.
+            kind = cfg.sliding_kind
+            ring_row = kind.kv_lora_rank + kind.qk_rope_head_dim
+            n_ring = ring_rows(cfg.sliding_window_size, self.max_len)
+            rows = self.k
+            self.k = _LatentStacks(rows, jnp.zeros(
+                (kinds.count("sliding"), self.slots, n_ring,
+                 width(ring_row, n_ring)), self.dtype))
+            ring = dict(
+                ring_shape=list(self.k.ring.shape), ring_row=[ring_row],
+                ring_layout=layout_text(self.k.ring.format.layout),
+                ring_resident_bytes=int(
+                    self.k.ring.on_device_size_in_bytes()),
+                ring_rows=n_ring, window=cfg.sliding_window_size,
+                ring_read="window", layer_kinds=list(kinds))
+        rows = _full_rows(self.k)
+        _tm.get("server_kv_stack_bytes").set(
+            sum(x.nbytes for x in jax.tree.leaves((self.k, self.v))))
         _ev.emit(
-            "kv_layout", shape=list(self.k.shape), dtype=str(self.k.dtype),
-            layout=layout_text(self.k.format.layout), row=[row],
+            "kv_layout", shape=list(rows.shape), dtype=str(rows.dtype),
+            layout=layout_text(rows.format.layout), row=[row],
             row_layout=layout_text(asked),
             folded_to=widths[0] if widths[0] != row else None,
             read=self._cache_read(1, False),
-            logical_bytes_a_stack=int(self.k.nbytes),
-            resident_bytes_a_stack=int(self.k.on_device_size_in_bytes()),
+            logical_bytes_a_stack=int(rows.nbytes),
+            resident_bytes_a_stack=int(rows.on_device_size_in_bytes()),
             index_shape=list(self.v.shape), index_row=[widths[1]],
             index_layout=layout_text(self.v.format.layout),
             index_resident_bytes=int(self.v.on_device_size_in_bytes()),
-            selected_rows=min(cfg.index_topk, self.max_len))
+            selected_rows=min(cfg.index_topk, self.max_len), **ring)
 
     def _cache_read(self, t: int, rider: bool) -> str:
         """`cache_read` of this engine's decode program of ``t`` new rows a
@@ -1729,7 +2052,12 @@ class BatchedStageExecutor:
         SELECTED and read to the rows-read counter, and those it STREAMED
         to read them (the kernel: each active slot's own blocks of
         `latent_block` rows; the gather none) to
-        ``server_latent_rows_streamed_total``."""
+        ``server_latent_rows_streamed_total``; those are ONE FULL layer's.
+        Where sliding layers alternate with them, ONE sliding layer's: the
+        ring rows a tick read (every slot's whole ring) to
+        ``server_window_rows_read_total`` and what the active slots'
+        windows hold, ``min(length + 1, sliding_window_size)`` each, to
+        ``server_window_rows_span_total``."""
         kernel = self._cache_read(t, rider) == "kernel"
         each = 1 if kernel else self.slots      # slots that read a count
         if self.cfg.kv_lora_rank:
@@ -1747,6 +2075,13 @@ class BatchedStageExecutor:
                 self._m_streamed.inc(int(attn_blocks(
                     lengths, active, 1, self.max_len, per_slot=True,
                     block=rows).sum()) * rows)
+            if isinstance(self.k, _LatentStacks):
+                # ONE sliding layer: every slot's whole ring a tick, against
+                # what the active slots' windows hold
+                self._m_window_read.inc(
+                    len(lengths) * self.slots * self.k.ring.shape[2])
+                self._m_window_span.inc(int(np.where(active, np.minimum(
+                    lengths + 1, self.cfg.sliding_window_size), 0).sum()))
         elif self.cfg.eva_window:
             rows_e, rows_s = rows = windowed_rows(self.cfg, self.max_len)
             exact, sums = windowed_blocks(self.cfg, lengths, active, rows,
@@ -1769,9 +2104,16 @@ class BatchedStageExecutor:
         """Once a round, over the slots in it (``held``): the cache rows a
         layer holds for those sessions against the positions they have
         sent. One row a position everywhere but in a windowed family: the
-        rows of the current window and one a chunk of the earlier ones."""
+        rows of the current window and one a chunk of the earlier ones;
+        and where sliding layers hold a ring: the MEAN over the layers, a
+        row a position in a full one and at most the ring's in a sliding
+        one."""
         n = self.lengths[list(held)].astype(np.int64)
         rows = n
+        if isinstance(self.k, _LatentStacks):
+            full, ring = (x.shape[0] for x in self.k)
+            rows = (full * n + ring * np.minimum(n, self.k.ring.shape[2])
+                    ) / (full + ring)
         if self.cfg.eva_window:
             last = np.maximum(n - 1, 0)     # the newest position held
             rows = np.where(n > 0, last % self.cfg.eva_window + 1
@@ -1824,6 +2166,18 @@ class BatchedStageExecutor:
                 f"rewind to {pos} from {cur}: the exact rows of window "
                 f"{pos // w} ({w} rows a window) are gone, only their "
                 "summaries are held; replay the session through prefill")
+        if isinstance(self.k, _LatentStacks) and pos < cur:
+            # A sliding layer's ring holds the newest R positions. The
+            # query at ``pos`` needs those from ``pos - window + 1``: the
+            # oldest of them is still there while no position R past it
+            # has been written.
+            ring, w = self.k.ring.shape[2], self.cfg.sliding_window_size
+            if max(0, pos - w + 1) + ring < cur:
+                raise WindowGone(
+                    f"rewind to {pos} from {cur}: a sliding layer holds "
+                    f"the newest {ring} rows and the window at {pos} needs "
+                    f"row {max(0, pos - w + 1)}, overwritten since; replay "
+                    "the session through prefill")
         self.lengths[s] = pos
 
     # ------------------------------------------------------------------
@@ -2004,22 +2358,43 @@ class BatchedStageExecutor:
                 h = (embed_tokens(cfg, params["embed"], x, positions)
                      if spec.is_first else x)
                 rope = make_rope(cfg, positions)
-            pos_grid = jnp.arange(k_all.shape[2], dtype=jnp.int32)[None, :]
+            pos_grid = jnp.arange(_full_rows(k_all).shape[2],
+                                  dtype=jnp.int32)[None, :]
             qpos = positions[0][:, None]                     # [T, 1]
             allowed = pos_grid <= qpos                       # [T, M] causal
             if cfg.sliding_window:
                 allowed &= pos_grid > qpos - cfg.sliding_window
             with jax.named_scope("kv_update"):
-                k_slot = jax.lax.dynamic_slice_in_dim(k_all, slot, 1, 1)
+                k_slot = jax.tree.map(
+                    lambda st: jax.lax.dynamic_slice_in_dim(st, slot, 1, 1),
+                    k_all)
                 v_slot = jax.lax.dynamic_slice_in_dim(v_all, slot, 1, 1)
+            if isinstance(k_all, _LatentStacks):
+                with jax.named_scope("embed"):
+                    rope_sliding = make_rope(cfg.sliding_kind, positions)
 
-            def layer(h, xs):
+            def layer(h, xs, sliding=False):
+                if sliding:
+                    lp, ring_l = xs                          # [1, R, W]
+
+                    def ring_continuation(k, _):
+                        # the chunk's queries see the ring as the chunks
+                        # before left it, and the chunk's own rows
+                        fresh = _fold(k_all.ring, k)
+                        with jax.named_scope("kv_update"):
+                            new = _write_ring(ring_l, fresh, p_len, t_real)
+                        return (_RingChunk(ring_l, fresh, p_len), None,
+                                (None, qpos, None), (new,))
+
+                    return _decoder_layer(cfg.sliding_kind, lp, h,
+                                          rope_sliding, ring_continuation)
                 lp, k_l, v_l = xs       # k_l: [1, M, Hkv, Dh] or [1, M, W]
 
                 def slot_continuation(k, v):
                     with jax.named_scope("kv_update"):
                         k_new = jax.lax.dynamic_update_slice_in_dim(
-                            k_l, _fold(k_all, k).astype(k_l.dtype), p_len, 1)
+                            k_l, _fold(_full_rows(k_all), k).astype(
+                                k_l.dtype), p_len, 1)
                         v_new = jax.lax.dynamic_update_slice_in_dim(
                             v_l, _fold(v_all, v).astype(v_l.dtype), p_len, 1)
                     if cfg.kv_lora_rank:    # the slot's two rows, as held
@@ -2039,13 +2414,16 @@ class BatchedStageExecutor:
                     v_in = jax.lax.dynamic_slice_in_dim(v_slot, base, n, 0)
                 h, (ks, vs) = _scan_groups(params, layer, h, k_in, v_in)
                 with jax.named_scope("kv_update"):
-                    k_all = jax.lax.dynamic_update_slice(
-                        k_all, ks, _origin(k_all, _at(base, 0), slot))
+                    k_all = jax.tree.map(
+                        lambda st, new: jax.lax.dynamic_update_slice(
+                            st, new, _origin(st, _at(base, 0), slot)),
+                        k_all, ks)
                     v_all = jax.lax.dynamic_update_slice(
                         v_all, vs, _origin(v_all, _at(base, 0), slot))
                 return h, k_all, v_all
 
-            del t_real  # mask correctness needs only qpos; kept for parity
+            # ``t_real``: a ring's write alone needs it (`_write_ring`);
+            # every other mask is by ``qpos``
             return _run_passes(cfg, params, h, one_pass, k_all, v_all)[:3]
 
         return prefill_suffix

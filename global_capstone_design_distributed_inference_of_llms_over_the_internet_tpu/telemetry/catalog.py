@@ -318,6 +318,20 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                  "the host from the lengths a step began with. "
                  "server_attn_rows_read_total keeps the rows SELECTED.",
         (), None),
+    "server_window_rows_read_total": (
+        COUNTER, "Ring rows of ONE sliding layer that the decode steps and "
+                 "burst ticks of a family whose sliding layers hold a ring "
+                 "(runtime.batching._LatentStacks) read: every slot's "
+                 "whole ring a tick (runtime.batching.ring_rows: 640 rows "
+                 "for a window of 513). Counted on the host, a tick.",
+        (), None),
+    "server_window_rows_span_total": (
+        COUNTER, "What the windows of ONE sliding layer held for the slots "
+                 "the same ticks served: min(length + 1, "
+                 "sliding_window_size) an active slot a tick, from the "
+                 "lengths a step began with. server_window_rows_read_total "
+                 "over it is the benchmark's window_rows_read_share.",
+        (), None),
     "server_moe_assignments_total": (
         COUNTER, "Routed assignments (rows x num_experts_per_tok x expert "
                  "layers) of the burst ticks' active rows, over ALL "

@@ -218,7 +218,11 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
         "query reads at most, and read = kernel (the latent rows of each "
         "slot's own blocks streamed under the selection as a mask: a TPU, "
         "a slot of at most LATENT_DENSE x index_topk rows) or select "
-        "(top_k, then a gather of the selected rows))."),
+        "(top_k, then a gather of the selected rows); where sliding "
+        "layers alternate with those, all of that over the FULL layers "
+        "alone, and ring_shape / ring_row / ring_layout / "
+        "ring_resident_bytes / ring_rows / window / ring_read the sliding "
+        "layers' rings, layer_kinds each layer's kind)."),
     "burst_fallback": (
         "client", WARN,
         "A burst-mode session fell back to per-step decode because no "

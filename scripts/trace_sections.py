@@ -44,7 +44,9 @@ SCOPES = ("embed", "attention", "kv_update", "mlp", "loop_norm",
           # opened INSIDE ``attention`` / ``mlp`` by a latent family under a
           # learned selection with expert layers: the innermost name counts
           "indexer", "topk_select", "latent_read", "router", "experts",
-          "shared_expert")
+          "shared_expert",
+          # a sliding latent layer's read of its ring, and the headwise gate
+          "window_read", "attn_gate")
 SCOPE_RE = re.compile(r"(?:^|/)(%s)(?=/|$)" % "|".join(SCOPES))
 
 
