@@ -17,6 +17,8 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+pytest_plugins = ("pytester",)      # tests/test_time_limit.py
+
 # Pin matmuls to full fp32: XLA CPU's DEFAULT GEMM path for m>1 runs a
 # reduced-precision (bf16-class) kernel while m=1 GEMV runs full fp32 —
 # measured ~5e-2 absolute error on unit-scale 64-dim dots. Token-parity
@@ -52,6 +54,7 @@ jax.config.update("jax_cpu_enable_async_dispatch", False)
 # whether the in-file batching corruption is a donation/concurrent-dispatch
 # interaction. Not for normal runs (donation is a real memory optimization).
 import os  # noqa: E402
+import sys  # noqa: E402
 
 if os.environ.get("NO_DONATE"):
     _orig_jit = jax.jit
@@ -70,11 +73,22 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _forget_shared_programs():
+    """tests/engines.py shares an engine's compiled programs among the cases
+    of ONE module; the next module starts with none (an executable pins its
+    memory maps for as long as it lives: see `pytest_sessionfinish`)."""
+    yield
+    helper = sys.modules.get("engines")
+    if helper is not None:
+        helper.forget_programs()
+
+
 def pytest_sessionfinish(session, exitstatus):
     # Machine-readable parity-rerun accounting (advisor r3): a rerun that
     # "recovers" must not scroll by as a warning only. Every run records the
-    # count + nodeids (stdout line parsed by scripts/run_tests.py, plus the
-    # pytest cache); more than one NON-canary rerun in one process exceeds
+    # count + nodeids (a stdout line, plus the pytest cache); more than one
+    # NON-canary rerun in one process exceeds
     # the environmental-corruption allowance and fails the run for
     # re-triage — repeated recoveries are a bug signal, not weather.
     if _PARITY_RERUNS:
@@ -95,8 +109,9 @@ def pytest_sessionfinish(session, exitstatus):
     # mmaps for the life of the process, and a single-process run of the
     # FULL suite deterministically exhausts vm.max_map_count (65530 here)
     # around test ~230 — mmap failures inside XLA then corrupt results or
-    # segfault (measured root cause of the round-2 "environmental" flake;
-    # see scripts/run_tests.py). Print the count so every run records how
+    # segfault (measured root cause of the round-2 "environmental" flake).
+    # The driver's command spreads the files over six worker processes
+    # (`-n 6 --dist loadfile`). Print the count so every run records how
     # close it came.
     try:
         with open("/proc/self/maps") as f:
@@ -105,10 +120,69 @@ def pytest_sessionfinish(session, exitstatus):
             cap = int(f.read())
         print(f"\n[conftest] process memory maps at exit: {n} / "
               f"vm.max_map_count {cap}"
-              + (" — DANGER ZONE, shard this run (scripts/run_tests.py)"
-                 if n > 0.75 * cap else ""))
+              + (" — DANGER ZONE, spread this run over more processes "
+                 "(-n 6 --dist loadfile)" if n > 0.75 * cap else ""))
     except OSError:
         pass
+
+
+# ---------------------------------------------------------------------------
+# A time limit of its own for every test.
+#
+# The driver runs the whole suite under one `timeout`: until PR 59 a test
+# that waited on a thread, a socket or a subprocess that never came took the
+# run with it (rc 124, every later test uncounted) instead of failing alone.
+# Every test now runs under an interval timer of its own in the worker's
+# main thread: when it fires, the test FAILS with the stack of every thread
+# (what it was waiting for), and the run goes on. TIME_LIMIT_S is 3x the
+# slowest honest test outside tests/perfbench/ in a whole run of six busy
+# workers (62 s and 54 s, the first cases of test_dots3_moe.py and
+# test_dots3_attention.py, which compile for their files; PR 59, ROADMAP.md
+# "What the driver runs"); `@pytest.mark.time_limit(seconds)` is for the few
+# that need more. The benchmark's own tests, which this suite may not edit,
+# run whole CPU rehearsals in subprocesses (343 s the longest in the same
+# run): `_TIME_LIMITS` marks them by directory. A property of the rig, not
+# a knob of the program: no environment variable reads it.
+# ---------------------------------------------------------------------------
+
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import tempfile  # noqa: E402
+import threading  # noqa: E402
+
+TIME_LIMIT_S = 180
+_TIME_LIMITS = (("perfbench/", 900),)      # nodeid prefix under tests/
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    marker = request.node.get_closest_marker("time_limit")
+    seconds = marker.args[0] if marker else TIME_LIMIT_S
+    if threading.current_thread() is not threading.main_thread():
+        yield           # no signal reaches another thread: nothing to arm
+        return
+
+    def fire(signum, frame):
+        with tempfile.TemporaryFile("w+") as f:
+            faulthandler.dump_traceback(f, all_threads=True)
+            f.seek(0)
+            stacks = f.read()
+        pytest.fail(f"time limit: {request.node.nodeid} ran past its "
+                    f"{seconds} s (tests/conftest.py TIME_LIMIT_S, "
+                    f"@pytest.mark.time_limit). Every thread then:\n"
+                    f"{stacks}", pytrace=False)
+
+    handler = signal.signal(signal.SIGALRM, fire)
+    outer = signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        # Disarmed before the next test's fixtures are set up and before
+        # the teardown of anything wider than this test. ``outer`` is all
+        # zeros but under `pytester` (tests/test_time_limit.py), where it is
+        # what the enclosing test had left.
+        signal.setitimer(signal.ITIMER_REAL, *outer)
+        signal.signal(signal.SIGALRM, handler)
 
 
 # ---------------------------------------------------------------------------
@@ -134,13 +208,37 @@ _SMOKE = (
 )
 
 
+def _seconds_by_file():
+    """``tests/durations.txt`` (`scripts/test_durations.py`): what each file
+    took in the last whole run that was written down."""
+    with open(os.path.join(os.path.dirname(__file__), "durations.txt")) as f:
+        return {name: float(seconds) for seconds, _, name in map(str.split, f)}
+
+
+def _under_tests(item):
+    """A test's node id from `tests/` on: ``test_net.py::test_x``."""
+    return item.nodeid.replace("\\", "/").split("tests/")[-1]
+
+
 def pytest_collection_modifyitems(config, items):
+    # With `--dist loadfile` a worker takes whole files, in collection order,
+    # and the run ends when its last file does: a long file that starts late
+    # sets the tail (alphabetical order cost 80 s of 930, PR 59). The longest
+    # files go first; one the table does not know yet goes before them all.
+    # A stale table costs seconds, never a test: the order within a file is
+    # kept, and no test may lean on another file's.
+    took = _seconds_by_file()
+    items.sort(key=lambda item: -took.get(
+        _under_tests(item).split("::")[0], float("inf")))
     for item in items:
-        rel = item.nodeid.replace("\\", "/").split("tests/")[-1]
-        mod = rel.split("::")[0]
-        if mod in _SMOKE or any(rel.startswith(s) for s in _SMOKE
-                                if "::" in s):
+        rel = _under_tests(item)
+        if rel.split("::")[0] in _SMOKE or any(
+                rel.startswith(s) for s in _SMOKE if "::" in s):
             item.add_marker(pytest.mark.smoke)
+        for prefix, seconds in _TIME_LIMITS:
+            if rel.startswith(prefix) and not item.get_closest_marker(
+                    "time_limit"):
+                item.add_marker(pytest.mark.time_limit(seconds))
 
 
 # ---------------------------------------------------------------------------

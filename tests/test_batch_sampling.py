@@ -12,10 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
     init_kv_cache,
     init_params,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
     ROLE_FULL,
     StageSpec,
@@ -26,14 +26,14 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     sample_token,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
     _sample_rows,
 )
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
     StageRequest,
 )
 
-from test_runtime_pipeline import tiny_cfg
+from engines import tiny_cfg
 
 
 def full_spec(cfg):

@@ -12,14 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
     init_kv_cache,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
     SamplingParams,
 )
 
-from test_runtime_pipeline import build_cluster, oracle_generate, tiny_cfg
+from engines import build_cluster, oracle_generate, tiny_cfg
 
 
 def oracle_beam(cfg, params, prompt_ids, max_new_tokens, num_beams,

@@ -19,6 +19,9 @@ import pytest
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
     telemetry,
 )
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
+    slot_attention,
+)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
     batching as B,
 )
@@ -26,20 +29,21 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     catalog,
 )
 
-from test_batching import (
-    FAMILIES,
-    PROMPTS,
-    _all_eqns,
-    _cache_writes_and_slabs,
+from engines import (
+    all_eqns,
     both_policies,
+    cache_writes_and_slabs,
+    FAMILIES,
     family_engine,
-)
-from test_looped_stack import (  # noqa: F401  (ref: a fixture)
-    build,
+    greedy_entry as greedy,
     ids_of,
-    ref,
+    looped,
+    LOOPED_HF,
+    program_args,
+    PROMPTS,
     rel_rms,
     rider_of,
+    SLAB_POLICIES,
     two_decoding,
 )
 
@@ -290,12 +294,6 @@ def test_decode_steps_are_the_full_read_s(small_blocks, monkeypatch, family,
             err_msg=name)
 
 
-def greedy(token):
-    return {"token": int(token), "seed": 0, "budget": 4, "eos": None,
-            "generated": (int(token),), "temperature": 0.0, "top_p": 1.0,
-            "top_k": 0, "repetition_penalty": 1.0}
-
-
 def test_a_burst_never_reads_past_its_bound(small_blocks, monkeypatch):
     """NaN in rows ``[16, 32)`` of every slot of every layer: a 4-tick
     burst and a decode step of sessions 3-7 rows long return the clean
@@ -317,14 +315,12 @@ def test_a_burst_never_reads_past_its_bound(small_blocks, monkeypatch):
     assert np.isfinite(dirty[1]).all()
     np.testing.assert_array_equal(dirty[1], clean[1])
     with monkeypatch.context() as m:
-        from test_batching import slab_policy_decode_span
-        m.setattr(B, "_decode_span",
-                  partial(slab_policy_decode_span, full_read=True))
+        m.setattr(B, "_decode_span", SLAB_POLICIES[True])
         assert np.isnan(drive(True)[1]).any()
 
 
 @pytest.mark.parametrize("kind", ["float32", "bfloat16"])
-def test_a_looped_stack_with_a_rider_reads_by_blocks(ref, monkeypatch, kind):
+def test_a_looped_stack_with_a_rider_reads_by_blocks(monkeypatch, kind):
     """A looped stack's burst with a 21-row rider beside two decoding
     sessions (64-row slots), at 8-row blocks against one 64-row block (a
     full read through the same program): the same tokens, the rider's
@@ -333,7 +329,7 @@ def test_a_looped_stack_with_a_rider_reads_by_blocks(ref, monkeypatch, kind):
     for block in (BLOCK, 64):
         with monkeypatch.context() as m:
             m.setattr(B, "ATTN_BLOCK", block)
-            _, _, eng = build(ref, kind)
+            _, _, eng = looped(kind)
             got = eng.decode_burst(two_decoding(eng), 4,
                                    rider=rider_of("r", ids_of(21, 3)))
             engines.append((eng, got))
@@ -387,7 +383,7 @@ def test_the_counters_against_a_hand_count(small_blocks, monkeypatch, folded):
 
 
 def test_a_looped_burst_with_a_rider_reads_its_slots_by_the_kernel(
-        ref, small_blocks, monkeypatch):
+        small_blocks, monkeypatch):
     """A looped stack whose heads fill the lanes (``head_dim`` 128), with
     the kernel engaged: the slots' group of a burst tick goes by the kernel
     BESIDE the rider's rows, which keep their slice of one slot. The tokens
@@ -395,12 +391,7 @@ def test_a_looped_burst_with_a_rider_reads_its_slots_by_the_kernel(
     step path (a twin engine that reads by the loop), the stacks agree, and the counter reads the slots' OWN blocks: x begins the four
     ticks at 8..11 rows and y at 12..15, two 8-row blocks each a tick = 16
     blocks of 8 rows, where the shared bound reads 2 x 8 x 3 slots x 4."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
-        slot_attention,
-    )
-    from test_looped_stack import HF
-
-    hf = {**HF, "hidden_size": 256, "num_attention_heads": 2,
+    hf = {**LOOPED_HF, "hidden_size": 256, "num_attention_heads": 2,
           "num_key_value_heads": 2, "head_dim": 128}
     telemetry.enable()
     try:
@@ -408,7 +399,7 @@ def test_a_looped_burst_with_a_rider_reads_its_slots_by_the_kernel(
         seen = []
         for hook in (True, None):
             monkeypatch.setattr(slot_attention, "_INTERPRET", hook)
-            _, _, eng = build(ref, hf=hf)
+            _, _, eng = looped(hf=hf)
             assert eng._cache_read(1, True) == (
                 "kernel" if hook else "loop")
             r0 = read.value
@@ -444,7 +435,7 @@ def _in_scans(jaxpr, names, depth=0):
     ("gpt2", "decode_step-3"), ("gpt2", "burst_tick"),
     ("gpt2", "decode_step-1"), ("looped", "burst_tick")])
 def test_the_program_bounds_its_read_inside_the_layer_scan(
-        small_blocks, ref, family, program):
+        small_blocks, family, program):
     """ONE program a tick count or a step width, whatever the lengths. In
     its jaxpr the read of a cache layer sits inside the layer scan (inside
     the tick scan in a burst, inside the pass scan of a looped stack) and
@@ -455,36 +446,20 @@ def test_the_program_bounds_its_read_inside_the_layer_scan(
     block-sized slices of the carried stacks; no conditional with a branch
     a block count, and no equation anywhere takes or yields a layer's
     ``[S, max_len, Hkv, Dh]``. No write takes a slab."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
-        RECENT_WINDOW,
-    )
-
     if family == "looped":
-        _, _, ex = build(ref)
+        _, _, ex = looped()
     else:
         ex = family_engine(family, "float32", MAX_LEN)
     S = ex.slots
-    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)         # noqa: E731
-    f32 = lambda *shape: jnp.ones(shape, jnp.float32)        # noqa: E731
-    on = jnp.ones((S,), bool)
-    if program == "burst_tick":
-        depth = 2 + (family == "looped")
-        fn, args = ex._build_burst(2), [
-            ex.params, i32(len(B.BURST_INTS) + RECENT_WINDOW, S),
-            f32(len(B.BURST_FLOATS), S), ex.k, ex.v]
-        if ex.rider_rows:
-            args.append(ex._rider_args(None, 2))
-    else:
-        depth = 1
-        fn, args = ex._build_decode(int(program[-1])), [
-            ex.params, i32(S, int(program[-1])), i32(S), on, ex.k, ex.v]
+    depth = (2 + (family == "looped")) if program == "burst_tick" else 1
+    fn, args = program_args(ex, program)
     jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
     reads = sorted(
-        e.outvars[0].aval.shape[2] for e in _all_eqns(jaxpr)
+        e.outvars[0].aval.shape[2] for e in all_eqns(jaxpr)
         if e.primitive.name == "dynamic_slice"
         and e.invars[0].aval.shape == ex.k.shape
         and e.outvars[0].aval.shape[1] == S)
-    writes, slabs = _cache_writes_and_slabs(jaxpr, ex.k.shape)
+    writes, slabs = cache_writes_and_slabs(jaxpr, ex.k.shape)
     assert {name for name, _ in writes} == {"scatter"}
     whiles = list(_in_scans(jaxpr, ("while",)))
     conds = [d for d, _ in _in_scans(jaxpr, ("cond",)) if d >= depth]
@@ -494,7 +469,7 @@ def test_the_program_bounds_its_read_inside_the_layer_scan(
                for shape in carried), carried
     assert reads == [BLOCK, BLOCK] and slabs == []
     layer = ex.k.shape[1:]
-    for e in _all_eqns(jaxpr):
+    for e in all_eqns(jaxpr):
         assert all(getattr(v.aval, "shape", None) != layer
                    for v in e.invars), e.primitive.name
 
@@ -503,7 +478,8 @@ def test_one_program_for_every_length(small_blocks):
     """Bursts and steps at lengths that need 1, 2 and 4 blocks run the
     programs compiled for the first: one burst program a tick count, one
     decode step a width."""
-    ex = family_engine("gpt2", "float32", MAX_LEN)
+    ex = family_engine("gpt2", "float32", MAX_LEN,   # its own: the programs
+                       make=B.BatchedStageExecutor)  # are counted
     for at in (0, 9, 25):
         ex.lengths[ex._slot_of["a"]] = max(at, len(PROMPTS["a"]))
         ex.decode_burst({"a": greedy(3)}, 2)

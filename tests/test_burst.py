@@ -22,13 +22,17 @@ import pytest
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
+    dequant_tree,
+    quantize_params,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
+    quant_kernel_report,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
     RECENT_WINDOW,
-    SamplingParams,
     sample_token,
+    SamplingParams,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
     BatchedStageExecutor,
@@ -36,25 +40,29 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
     make_server_record,
+    PipelineClient,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+    StageRequest,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.serving.fair_queue import (
     DeficitRoundRobin,
 )
 
-from test_batching import (
-    FAMILIES,
+from engines import (
     bits,
     both_policies,
-    check_clamped_slot,
-    family_engine,
-    slot_rows,
-    transfer_counts,
-)
-from test_runtime_pipeline import (
     build_cluster,
+    check_clamped_slot,
+    engine,
+    FAMILIES,
+    family_engine,
+    full_spec as _full_spec,
     kernel_cfg,
     oracle_generate,
+    slot_rows,
     tiny_cfg,
+    transfer_counts,
 )
 
 GREEDY = SamplingParams(temperature=0.0)
@@ -72,12 +80,6 @@ def cfg():
 @pytest.fixture(scope="module")
 def params(cfg):
     return init_params(jax.random.PRNGKey(0), cfg)
-
-
-def _full_spec(cfg):
-    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
-    assert spec.is_first and spec.is_last
-    return spec
 
 
 def _sample(logits_row, generated, step_seed, sp):
@@ -103,8 +105,7 @@ def _per_session(sp, prompts):
 def _sequential(cfg, params, prompts, sp, seed, max_new, eos=None):
     """Per-step decode with host sampling + host stop rules: the baseline
     a burst must match bit-for-bit."""
-    ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
-                              max_len=64)
+    ex = engine(cfg, _full_spec(cfg), params, slots=4, max_len=64)
     out = {}
     sps = _per_session(sp, prompts)
     for sid, p in prompts.items():
@@ -127,13 +128,12 @@ def _sequential(cfg, params, prompts, sp, seed, max_new, eos=None):
 
 
 def _bursty(cfg, params, prompts, sp, seed, max_new, n_ticks, eos=None,
-            stops=None):
+            stops=None, make=engine):
     """decode_burst driver: re-ships the stateless per-burst protocol
     (sampling params + recent window + seed) each burst, like the wire
     client does. ``stops``: a dict that collects each session's stop
     reason, burst by burst."""
-    ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
-                              max_len=64)
+    ex = make(cfg, _full_spec(cfg), params, slots=4, max_len=64)
     gen = {}
     sps = _per_session(sp, prompts)
     for sid, p in prompts.items():
@@ -169,8 +169,7 @@ def _bursty(cfg, params, prompts, sp, seed, max_new, n_ticks, eos=None,
 
 
 def _add_burst_peer(cfg, transport, registry, params, name="burst-peer"):
-    inner = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
-                                 max_len=64)
+    inner = engine(cfg, _full_spec(cfg), params, slots=4, max_len=64)
     ad = BatchingStageAdapter(inner, window_s=0.0, peer_id=name)
     transport.add_peer(name, ad)
     registry.register(make_server_record(name, _full_spec(cfg),
@@ -315,8 +314,7 @@ def test_burst_tick_never_permutes_the_vocabulary(cfg, params):
     on the unsorted rows."""
     vocab = cfg.vocab_size
     assert vocab not in (4, 32, 64, RECENT_WINDOW)    # dims tell V apart
-    ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
-                              max_len=32)
+    ex = engine(cfg, _full_spec(cfg), params, slots=4, max_len=32)
     ex.prefill("a", np.asarray([PROMPT], np.int32))
     _, args = ex._burst_prep(
         {"a": {"token": 1, "seed": 0, "budget": 4, "eos": None,
@@ -395,10 +393,6 @@ def _round_of(ad, tokens, cur, as_array=np.asarray):
     ``tokens``, all in flight together; -> {session: response}."""
     import threading
 
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-
     got = {}
 
     def ask(sid):
@@ -425,12 +419,7 @@ def test_a_burst_round_crosses_the_boundary_three_times(cfg, params,
     whatever S, by `server_burst_transfers_total` over
     `server_burst_dispatches_total`; a request whose ids come as a device
     array costs the round one more read each, and the count says so."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-
-    ex = BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
-                              max_len=64)
+    ex = engine(cfg, _full_spec(cfg), params, slots=4, max_len=64)
     ad = BatchingStageAdapter(ex, window_s=0.5)
     read = transfer_counts(ex, ad)
     sids = "abcd"[:sessions]
@@ -527,8 +516,8 @@ def _unpacked_burst(ex, entries, n_ticks):
 
 @pytest.fixture(scope="module")
 def twins(cfg, params):
-    return [BatchedStageExecutor(cfg, _full_spec(cfg), params, slots=4,
-                                 max_len=64) for _ in range(2)]
+    return [engine(cfg, _full_spec(cfg), params, slots=4,
+                   max_len=64) for _ in range(2)]
 
 
 def _knob_cases():
@@ -599,10 +588,6 @@ def test_full_span_session_builds_no_stage0(cfg):
     token per round trip without --burst — computes nothing locally: the
     stage-0 factory (a CLI client's weights and device) is never called,
     and both give the oracle's ids."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
-        PipelineClient,
-    )
-
     _base, transport, registry, params, plan = build_cluster(
         cfg, splits="2,4")
     _add_burst_peer(cfg, transport, registry, params)
@@ -718,11 +703,6 @@ def test_burst_engine_quantized_matches_dequantized(cfg, params, mode):
     tokens IDENTICAL to the burst path over the explicitly materialized
     weights — quantization error lives in the weights, never in the
     burst execution."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-        dequant_tree,
-        quantize_params,
-    )
-
     qparams = quantize_params(params, mode)
     dparams = dequant_tree(qparams)       # stacked 3-D: fully materialized
     got, _ = _bursty(cfg, qparams, PROMPTS, GREEDY, seed=0, max_new=10,
@@ -734,10 +714,6 @@ def test_burst_engine_quantized_matches_dequantized(cfg, params, mode):
 
 
 def _kernel_params(kcfg, mode):
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-        quantize_params,
-    )
-
     return quantize_params(init_params(jax.random.PRNGKey(0), kcfg), mode)
 
 
@@ -759,9 +735,9 @@ def test_kernel_launch_count_guard(monkeypatch, mode):
     monkeypatch.setattr(K, "_sites", {})
     monkeypatch.setenv("NF4_KERNEL", "1")
     kcfg = kernel_cfg()
-    ex = BatchedStageExecutor(kcfg, _full_spec(kcfg),
-                              _kernel_params(kcfg, mode), slots=2,
-                              max_len=16)
+    ex = BatchedStageExecutor(           # its own: the test counts its traces
+        kcfg, _full_spec(kcfg), _kernel_params(kcfg, mode), slots=2,
+        max_len=16)
     # The fused layout is what makes 4 the bound (7 canonical sites).
     assert "wqkv" in ex.params["layers"]["attn"]
     assert "wgu" in ex.params["layers"]["mlp"]
@@ -793,10 +769,6 @@ def test_int8_stacked_kernel_burst_matches_materialize(monkeypatch, sp):
     stop reasons of INT8_FOLD=0 (dequant-materialize per layer), greedy
     and seeded-sampled; all four sites report the stacked kernel."""
     import global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.int8_kernel as IK
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
-        quant_kernel_report,
-    )
-
     monkeypatch.setattr(IK, "_INTERPRET", True)
     monkeypatch.setattr(IK, "_sites", {})
     kcfg = kernel_cfg()
@@ -806,7 +778,8 @@ def test_int8_stacked_kernel_burst_matches_materialize(monkeypatch, sp):
         monkeypatch.setenv("INT8_FOLD", fold)
         stops = {}
         gen, _ = _bursty(kcfg, qp, PROMPTS, sp, seed=3, max_new=10,
-                         n_ticks=4, stops=stops)
+                         n_ticks=4, stops=stops,         # its own programs:
+                         make=BatchedStageExecutor)      # `_sites` is a trace's
         return gen, stops
 
     got = serve("1")

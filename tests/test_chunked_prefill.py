@@ -15,8 +15,8 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
     StageExecutionError,
-    StageExecutor,
 )
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
     StageRequest,
 )
@@ -31,7 +31,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     init_params,
 )
 
-from test_runtime_pipeline import build_cluster, oracle_generate, tiny_cfg
+from engines import build_cluster, oracle_generate, tiny_cfg
 
 
 def _seg_executor(cfg, params, max_chunk_bytes):

@@ -9,17 +9,16 @@ forward with the same prompts generates — across chained spans, chunked
 prefill, failover replay, and the TCP wire.
 """
 
-import random
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
     init_kv_cache,
     init_params,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
     ROLE_FULL,
     StagePlan,
@@ -34,14 +33,12 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PipelineClient,
     make_server_record,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
     StageRequest,
 )
 
-from test_runtime_pipeline import build_cluster, tiny_cfg
+from engines import build_cluster, tiny_cfg
 
 
 def make_prompts(cfg, pre_seq, seed=3, scale=0.5):
@@ -267,9 +264,9 @@ def test_batched_and_sp_engines_refuse_prompts():
     import pytest
 
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-        BatchedStageExecutor,
         BatchingStageAdapter,
     )
+    from engines import engine as BatchedStageExecutor
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
         StageExecutionError,
     )

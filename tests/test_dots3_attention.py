@@ -12,33 +12,42 @@ layer order against ``layer_types``; the stacks' shapes; the counters; and
 every engine that cannot hold the state refusing the family by name."""
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
+    main,
+    telemetry,
+)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     config,
     hf_import,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
     slice_stage_params,
+    StagePlan,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
+    init_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
     batching,
-)
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
-    telemetry,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry import (
     catalog as tm,
 )
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from engines import (
+    greedy_entry,
+    reference_engine,
+    reference_logits,
+    reference_weights,
+    stage_executor as StageExecutor,
+)
+
 TOPK, WINDOW, RING = 24, 17, 128
 TYPES = list(config.dots3_layer_types(9))
 HF = dict(
@@ -57,18 +66,6 @@ HF = dict(
 LAYERS = 5                      # the dense layer and ONE whole period
 
 
-def _ref():
-    spec = importlib.util.spec_from_file_location(
-        "dots3_plain", os.path.join(ROOT, "perfbench", "references",
-                                    "dots3_plain.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF = _ref()
-
-
 def small_config(layers=LAYERS, **kw):
     return config.dots3_config(TYPES, **{**dict(
         vocab_size=97, hidden_size=64, num_layers=layers, num_heads=4,
@@ -84,7 +81,7 @@ def small_config(layers=LAYERS, **kw):
 
 @pytest.fixture(scope="module")
 def weights():
-    return REF.make_weights(HF, 9, 7, jnp.float32)
+    return reference_weights("dots3", HF, 9, 7)
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +92,7 @@ def ids():
 @pytest.fixture(scope="module")
 def want(weights, ids):
     """The reference's logits of the 200-row sequence, five layers deep."""
-    return np.asarray(REF.forward(HF, LAYERS, weights, jnp.asarray(ids)))
+    return reference_logits("dots3", HF, LAYERS, weights, ids)
 
 
 @pytest.fixture
@@ -108,12 +105,8 @@ def small_blocks(monkeypatch):
 
 
 def engine(weights, *, slots=2, max_len=256, cfg=None):
-    cfg = cfg or small_config()
-    params = hf_import.convert_state_dict(cfg, weights, dtype=jnp.float32)
-    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
-    return batching.BatchedStageExecutor(
-        cfg, spec, slice_stage_params(cfg, params, spec), slots=slots,
-        max_len=max_len, dtype=jnp.float32)
+    return reference_engine(cfg or small_config(), weights, slots=slots,
+                            max_len=max_len)
 
 
 def logits_of(eng, h):
@@ -121,9 +114,7 @@ def logits_of(eng, h):
 
 
 def burst_entry(token, generated=()):
-    return {"token": int(token), "seed": 0, "budget": 4, "eos": None,
-            "generated": tuple(generated), "temperature": 0.0, "top_p": 1.0,
-            "top_k": 0, "repetition_penalty": 1.0}
+    return greedy_entry(token, generated=generated)
 
 
 @pytest.mark.parametrize("n,steps", [(5, 20), (16, 4), (17, 4), (23, 4),
@@ -147,7 +138,7 @@ def test_two_periods_run_in_the_published_order(weights, ids, small_blocks):
     inside the program; a prompt, then steps over the ring's wrap."""
     cfg = small_config(9)
     assert cfg.layer_period == (1, 2, 3)
-    want = np.asarray(REF.forward(HF, 9, weights, jnp.asarray(ids[:140])))
+    want = reference_logits("dots3", HF, 9, weights, ids[:140])
     eng = engine(weights, cfg=cfg)
     got = logits_of(eng, eng.prefill("a", ids[None, :125]))
     np.testing.assert_allclose(got, want[:125], atol=5e-5)
@@ -223,8 +214,8 @@ def test_burst_rounds_two_slots_and_rewinds(weights, small_blocks):
             emitted[sid] += r["tokens"]
             fed[sid] = r["tokens"][-1]
     for sid, seq in consumed.items():
-        want = np.asarray(REF.forward(
-            HF, LAYERS, weights, jnp.asarray(seq, jnp.int32)))
+        want = reference_logits("dots3", HF, LAYERS, weights,
+                                np.asarray(seq, np.int32))
         assert list(want[lens[sid]:].argmax(-1)) == emitted[sid], sid
     # a rewind is a length: a ring row past it is outside every window
     # until it is rewritten
@@ -415,9 +406,6 @@ def test_the_counters_of_the_window_and_of_the_rows_held(weights,
 
 
 def test_random_init_and_the_import_build_the_same_tree(weights):
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
-        init_params,
-    )
     cfg = small_config(9)
     drawn = jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg, jnp.float32))
@@ -470,17 +458,11 @@ def test_every_other_engine_refuses_the_family_by_name(weights):
     eng.prefill("a", np.arange(4, dtype=np.int32)[None])
     with pytest.raises(NotImplementedError, match="speculative verify"):
         eng.decode_batch({"a": np.zeros((1, 3), np.int32)})
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutor,
-    )
     with pytest.raises(NotImplementedError, match="two kinds"):
         StageExecutor(cfg, whole, params)
 
 
 def test_main_refuses_before_a_weight_is_made():
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
-        main,
-    )
     base = ["--model", "dots3-rehearsal", "--num_layers", "5"]
     parse = main.build_parser().parse_args
     ok = parse(base + ["--mode", "serve", "--stage", "0", "--batched"])

@@ -9,12 +9,9 @@ in-process with assertions.
 import random
 
 import jax
-import numpy as np
-import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
-    llama_config,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
     StagePlan,
@@ -26,9 +23,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
     PipelineClient,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.server import (
     ElasticStageServer,
 )
@@ -39,7 +34,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PlacementRegistry,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, tiny_cfg
 
 
 MIN_BLOCK = 2  # client-local prefix [0, 2): lb_min_block = splits[0]

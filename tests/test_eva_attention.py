@@ -16,18 +16,18 @@ measured 0.02 - 0.04, the limit 0.1; a wrong row or a wrong window reads
 limit: past row W they have to read over it."""
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    config as config_mod,
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
+    main,
+    telemetry,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+    config as config_mod,
     hf_import,
     quant,
 )
@@ -37,24 +37,37 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
     init_params,
 )
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
+    slot_attention,
+)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
     batching,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
     BatchedStageExecutor,
     BatchingStageAdapter,
-    WindowGone,
     windowed_blocks,
     windowed_rows,
+    WindowGone,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
-    telemetry,
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
+    StageExecutionError,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+    SamplingParams,
+    StageRequest,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry import (
     catalog as tm,
 )
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from engines import (
+    engine,
+    reference_engine,
+    reference_logits,
+    reference_weights,
+)
+
 W, C, LAYERS, VOCAB, SLOTS, MAX_LEN = 32, 4, 2, 50, 8, 160
 HF = {"hidden_size": 64, "intermediate_size": 96, "num_attention_heads": 4,
       "num_key_value_heads": 4, "vocab_size": VOCAB, "num_pred_heads": 2,
@@ -67,17 +80,6 @@ GREEDY = {"seed": 0, "eos": None, "temperature": 0.0, "top_p": 1.0,
           "top_k": 0, "repetition_penalty": 1.0}
 
 
-def _load(path):
-    spec = importlib.util.spec_from_file_location("evabyte_plain", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-ref = _load(os.path.join(ROOT, "perfbench", "references",
-                         "evabyte_plain.py"))
-
-
 def tiny_cfg(**kw):
     return config_mod.evabyte_config(
         vocab_size=VOCAB, hidden_size=64, num_layers=LAYERS, num_heads=4,
@@ -86,16 +88,11 @@ def tiny_cfg(**kw):
         **kw)
 
 
-def make_engine(dtype, *, slots=SLOTS, max_len=MAX_LEN, seed=7, quantise=None):
-    cfg = tiny_cfg()
-    weights = ref.make_weights(HF, LAYERS, seed, dtype)
-    params = hf_import.convert_state_dict(cfg, weights, dtype=dtype)
-    if quantise:
-        params = quant.quantize_params(params, quantise)
-    spec = StagePlan.even(LAYERS, 1).stages[0]
-    eng = BatchedStageExecutor(cfg, spec, params, slots=slots,
-                               max_len=max_len, dtype=dtype)
-    return eng, weights
+def make_engine(dtype, *, slots=SLOTS, max_len=MAX_LEN, seed=7, quantise=None,
+                **kw):
+    weights = reference_weights("evabyte", HF, LAYERS, seed, dtype)
+    return reference_engine(tiny_cfg(), weights, dtype, quantise, slots=slots,
+                            max_len=max_len, **kw), weights
 
 
 _RIGS = {}
@@ -124,7 +121,7 @@ def both(*values):
 
 
 def want_logits(weights, ids):
-    return np.asarray(ref.forward(HF, LAYERS, weights, jnp.asarray(ids)))
+    return reference_logits("evabyte", HF, LAYERS, weights, ids, bucket=W)
 
 
 def err(got, want):
@@ -163,7 +160,8 @@ def test_prefill_is_the_reference_at_every_row(rig, dtype, n):
 def test_a_prompt_runs_through_a_bounded_set_of_shapes():
     """Whole windows and a bucketed tail: 13 prompt lengths, three program
     shapes, and nothing compiles for a length once they are built."""
-    eng, _ = make_engine(jnp.float32, slots=2)
+    eng, _ = make_engine(jnp.float32, slots=2,   # its own: compiles counted
+                         make=BatchedStageExecutor)
     assert eng.window_shapes() == [8, 16, W]
     for n in (3, 8, 9, 16, 17, W, W + 1, W + 9, 2 * W, 2 * W + 16,
               3 * W + 31, 4 * W, MAX_LEN):
@@ -477,8 +475,7 @@ def test_the_decode_loops_run_a_window_s_blocks_not_the_slot_s():
                               num_kv_heads=2, intermediate_size=64)
     params = init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
     spec = StagePlan.even(1, 1).stages[0]
-    eng = BatchedStageExecutor(cfg, spec, params, slots=2, max_len=16384,
-                               dtype=jnp.bfloat16)
+    eng = engine(cfg, spec, params, slots=2, max_len=16384, dtype=jnp.bfloat16)
     assert eng._cache_read(1, False) == "loop"
     text = eng._build_decode(1).lower(
         eng.params, jnp.zeros((2, 1), jnp.int32),
@@ -542,10 +539,6 @@ def test_the_counters_follow_each_slot_s_own_blocks_under_the_kernel(
     reads a's and b's window block (2 x 32) and a's summary block (32),
     where the shared bound reads 2 slots x 32 of each; the rows that come
     back are the loop engine's."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
-        slot_attention,
-    )
-
     cfg = config_mod.evabyte_config(
         vocab_size=VOCAB, hidden_size=256, num_layers=LAYERS, num_heads=2,
         num_kv_heads=2, intermediate_size=96, max_position_embeddings=4096,
@@ -560,8 +553,8 @@ def test_the_counters_follow_each_slot_s_own_blocks_under_the_kernel(
         seen = []
         for hook in (True, None):
             monkeypatch.setattr(slot_attention, "_INTERPRET", hook)
-            eng = BatchedStageExecutor(cfg, spec, params, slots=2,
-                                       max_len=MAX_LEN, dtype=jnp.float32)
+            eng = engine(cfg, spec, params, slots=2, max_len=MAX_LEN,
+                         dtype=jnp.float32)
             assert eng._cache_read(1, False) == (
                 "kernel" if hook else "loop")
             eng.prefill("a", ids_of(2 * W + 2)[None])
@@ -603,7 +596,7 @@ def test_hf_import_round_trip_of_the_published_names():
     assert cfg == tiny_cfg()
     assert (cfg.eva_window, cfg.eva_chunk, cfg.pred_heads,
             cfg.norm_offset, cfg.fp32_residual) == (W, C, 2, True, True)
-    weights = ref.make_weights(HF, LAYERS, 3, jnp.float32)
+    weights = reference_weights("evabyte", HF, LAYERS, 3)
     assert {n.split(".", 3)[-1] for n in weights if ".layers.0." in n} == {
         "input_layernorm.weight", "post_attention_layernorm.weight",
         "self_attn.q_proj.weight", "self_attn.k_proj.weight",
@@ -652,8 +645,8 @@ def test_the_prefix_cache_is_refused_by_name():
     params = init_params(jax.random.PRNGKey(0), cfg)
     spec = StagePlan.even(LAYERS, 1).stages[0]
     with pytest.raises(NotImplementedError) as exc:
-        BatchedStageExecutor(cfg, spec, params, slots=2, max_len=64,
-                             prefix_cache_bytes=1 << 20)
+        engine(cfg, spec, params, slots=2, max_len=64,
+               prefix_cache_bytes=1 << 20)
     assert "older rows are summaries" in str(exc.value)
     assert "prefix cache" in str(exc.value)
 
@@ -672,12 +665,8 @@ def test_the_adapter_serves_prefill_and_bursts_and_warms_every_shape():
     """Through `BatchingStageAdapter`: the warm-up builds every prefill
     shape, the decode step and the burst; a session then prefills and
     bursts across a window edge and nothing is built for it."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        SamplingParams,
-        StageRequest,
-    )
-
-    eng, weights = make_engine(jnp.float32, slots=2)
+    eng, weights = make_engine(jnp.float32, slots=2,     # its own: the
+                               make=BatchedStageExecutor)  # compiles counted
     adapter = BatchingStageAdapter(eng, window_s=0.0)
     adapter.warmup(burst=16)
     built = (eng._prefill_jit._cache_size(),
@@ -703,10 +692,6 @@ def test_the_adapter_serves_prefill_and_bursts_and_warms_every_shape():
     assert (eng._prefill_jit._cache_size(),
             eng._burst_jits[16]._cache_size()) == built
     # a step of several rows (a draft block, a replay chunk) is refused
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-
     with pytest.raises(StageExecutionError) as exc:
         adapter.forward(StageRequest(
             session_id="s", hidden=jnp.zeros((1, 3), jnp.int32), seq_len=3,
@@ -731,10 +716,6 @@ def test_the_predicate_is_asked_where_the_engine_is_chosen(argv, refused):
     """`main._refuse_unheld_state`, before a weight is made: the full-span
     batched server holds the state; every other choice is refused by the
     one predicate's text, which names the mechanism and not the model."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
-        main,
-    )
-
     args = main.build_parser().parse_args(["--model", "evabyte", *argv])
     cfg = main.load_config(args)
     if refused is None:

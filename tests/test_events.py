@@ -27,7 +27,7 @@ import textwrap
 
 import pytest
 
-from test_runtime_pipeline import build_cluster, tiny_cfg
+from engines import build_cluster, tiny_cfg
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
     telemetry,
@@ -220,9 +220,7 @@ def test_dump_events_wire_verb_and_live_scrape():
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
         make_server_record,
     )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutor,
-    )
+    from engines import stage_executor as StageExecutor
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
         TcpStageServer,
         TcpTransport,

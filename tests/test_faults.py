@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from test_runtime_pipeline import build_cluster, tiny_cfg
+from engines import build_cluster, tiny_cfg
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.main import (
     chaos_soak,
@@ -46,8 +46,8 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
     StageExecutionError,
-    StageExecutor,
 )
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.faults import (
     FaultPlan,
     FaultRule,

@@ -14,9 +14,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    gpt2_config,
     init_params,
-    llama_config,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.lora import (
+    load_lora,
+    merge_lora,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
+    parse_splits,
+    slice_stage_params,
+    StagePlan,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
     embed_tokens,
@@ -26,11 +33,22 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.trainer import (
     softmax_xent,
 )
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
+    make_server_record,
+    PipelineClient,
+)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.finetune import (
     DistributedFineTuner,
 )
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
+    TcpStageServer,
+    TcpTransport,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.scheduling.registry import (
+    PlacementRegistry,
+)
 
-from test_runtime_pipeline import build_cluster, tiny_cfg
+from engines import build_cluster, stage_executor as StageExecutor, tiny_cfg
 
 
 def oracle_ptune_loss(cfg, params, prompts, ids, targets):
@@ -66,13 +84,13 @@ def test_distributed_ptune_grads_match_oracle():
     ft = make_tuner(cfg, params, client, pre_seq=4, lr=0.0, tune_embed=True)
     prompts0 = ft.trainables["prompts"]
 
-    g_oracle = jax.grad(
+    g_oracle = jax.jit(jax.grad(
         lambda pr, wte: oracle_ptune_loss(
             cfg,
             {**params, "embed": {**params["embed"], "wte": wte}},
             pr, ids, targets),
         argnums=(0, 1),
-    )(prompts0, params["embed"]["wte"])
+    ))(prompts0, params["embed"]["wte"])
 
     loss = ft.step(ids, targets)
     oracle_loss = float(oracle_ptune_loss(cfg, params, prompts0, ids, targets))
@@ -91,10 +109,6 @@ def oracle_lora_loss(cfg, params, prompts, lora, scale, ids, targets):
     """Unpartitioned deep-prompt + LoRA loss on CANONICAL (unfused) weights
     — the distributed path runs engine-FUSED wqkv spans, so agreement also
     proves the fused-slice merge is equivalent."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.lora import (
-        merge_lora,
-    )
-
     merged = {**params, "layers": merge_lora(cfg, params["layers"], lora, scale)}
     return oracle_ptune_loss(cfg, merged, prompts, ids, targets)
 
@@ -123,11 +137,11 @@ def test_distributed_lora_grads_match_oracle():
     lora0 = ft.trainables["lora"]
     prompts0 = ft.trainables["prompts"]
 
-    g_oracle = jax.grad(
+    g_oracle = jax.jit(jax.grad(
         lambda lo, pr: oracle_lora_loss(
             cfg, params, pr, lo, ft.lora_scale, ids, targets),
         argnums=(0, 1),
-    )(lora0, prompts0)
+    ))(lora0, prompts0)
 
     loss = ft.step(ids, targets)
     oracle_loss = float(oracle_lora_loss(
@@ -173,26 +187,6 @@ def test_lora_learns_and_checkpoints(tmp_path):
 def test_lora_over_tcp():
     """LoRA adapters + grads over real sockets (multi-tensor frames with a
     manifest header), composed with deep prompts."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-        StagePlan,
-        parse_splits,
-        slice_stage_params,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
-        PipelineClient,
-        make_server_record,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutor,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        TcpStageServer,
-        TcpTransport,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.scheduling.registry import (
-        PlacementRegistry,
-    )
-
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
     plan = StagePlan.from_splits(cfg.num_layers, parse_splits("3,6"))
@@ -224,10 +218,10 @@ def test_lora_over_tcp():
         oracle = float(oracle_lora_loss(
             cfg, params, prompts0, lora0, ft.lora_scale, ids, targets))
         np.testing.assert_allclose(loss, oracle, rtol=1e-4)
-        g_oracle = jax.grad(
+        g_oracle = jax.jit(jax.grad(
             lambda lo: oracle_lora_loss(
                 cfg, params, prompts0, lo, ft.lora_scale, ids, targets)
-        )(lora0)
+        ))(lora0)
         g_lora = jax.tree.map(lambda m: np.asarray(m) / 0.1,
                               ft.opt_state["mu"]["lora"])
         for t in g_lora:
@@ -294,26 +288,6 @@ def test_ptune_survives_peer_failure():
 def test_ptune_over_tcp():
     """Same step over real sockets (train_forward/backward verbs + multi-
     tensor frames), f32 wire for grads."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-        StagePlan,
-        parse_splits,
-        slice_stage_params,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
-        PipelineClient,
-        make_server_record,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutor,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        TcpStageServer,
-        TcpTransport,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.scheduling.registry import (
-        PlacementRegistry,
-    )
-
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
     plan = StagePlan.from_splits(cfg.num_layers, parse_splits("3,6"))
@@ -343,9 +317,9 @@ def test_ptune_over_tcp():
         oracle = float(oracle_ptune_loss(cfg, params, prompts0, ids, targets))
         np.testing.assert_allclose(loss, oracle, rtol=1e-4)
         g_prompts = np.asarray(ft.opt_state["mu"]["prompts"]) / 0.1
-        g_oracle = jax.grad(
+        g_oracle = jax.jit(jax.grad(
             lambda pr: oracle_ptune_loss(cfg, params, pr, ids, targets)
-        )(prompts0)
+        ))(prompts0)
         np.testing.assert_allclose(g_prompts, np.asarray(g_oracle),
                                    rtol=2e-3, atol=1e-6)
     finally:
@@ -357,11 +331,6 @@ def test_export_lora_serves_merged(tmp_path):
     """export_lora -> load_lora -> merge must reproduce the tuned model:
     the merged-weights forward equals the training-path forward with the
     same adapters (the serving contract of --lora)."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.lora import (
-        load_lora,
-        merge_lora,
-    )
-
     import pytest
 
     cfg = tiny_cfg()

@@ -8,15 +8,15 @@ import numpy as np
 import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
     init_kv_cache,
     init_params,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.fused_decode import (
     make_fused_decode,
 )
 
-from test_runtime_pipeline import tiny_cfg
+from engines import tiny_cfg
 
 
 @pytest.mark.parametrize("family", ["llama", "gpt2", "gemma2"])

@@ -10,21 +10,26 @@ against the expanded form of a prefill chunk; the exact top-k mask against
 every engine that cannot hold the state refusing the family by name."""
 
 import dataclasses
-import importlib.util
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
+    main,
+    telemetry,
+)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     config,
     hf_import,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
     slice_stage_params,
+    StagePlan,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
+    init_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops import (
     slot_attention as FA,
@@ -32,14 +37,18 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
     batching,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
-    telemetry,
-)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry import (
     catalog as tm,
 )
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from engines import (
+    greedy_entry,
+    reference_engine,
+    reference_logits,
+    reference_weights,
+    stage_executor as StageExecutor,
+)
+
 TOPK = 16
 HF = dict(
     model_type="glm_moe_dsa", hidden_size=64, intermediate_size=96,
@@ -50,18 +59,6 @@ HF = dict(
     first_k_dense_replace=1, rms_norm_eps=1e-5, routed_scaling_factor=2.5,
     rope_parameters={"rope_theta": 1e6}, experts_held=4)
 LAYERS = 3
-
-
-def _ref():
-    spec = importlib.util.spec_from_file_location(
-        "glm5_plain", os.path.join(ROOT, "perfbench", "references",
-                                   "glm5_plain.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-REF = _ref()
 
 
 def small_config(**kw):
@@ -77,7 +74,7 @@ def small_config(**kw):
 
 @pytest.fixture(scope="module")
 def weights():
-    return REF.make_weights(HF, LAYERS, 7, jnp.float32)
+    return reference_weights("glm5", HF, LAYERS, 7)
 
 
 @pytest.fixture
@@ -89,12 +86,8 @@ def small_blocks(monkeypatch):
 
 
 def engine(weights, *, slots=2, max_len=64, cfg=None):
-    cfg = cfg or small_config()
-    params = hf_import.convert_state_dict(cfg, weights, dtype=jnp.float32)
-    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
-    return batching.BatchedStageExecutor(
-        cfg, spec, slice_stage_params(cfg, params, spec), slots=slots,
-        max_len=max_len, dtype=jnp.float32)
+    return reference_engine(cfg or small_config(), weights, slots=slots,
+                            max_len=max_len)
 
 
 def logits_of(eng, h):
@@ -102,9 +95,7 @@ def logits_of(eng, h):
 
 
 def burst_entry(token, generated=()):
-    return {"token": int(token), "seed": 0, "budget": 4, "eos": None,
-            "generated": tuple(generated), "temperature": 0.0, "top_p": 1.0,
-            "top_k": 0, "repetition_penalty": 1.0}
+    return greedy_entry(token, generated=generated)
 
 
 @pytest.mark.parametrize("n", [5, 15, 16, 37])
@@ -113,7 +104,7 @@ def test_prefill_and_decode_steps_agree_with_the_reference(
     """Prompts under, at and past the selection's edge (row 16) and the
     chunk's (16 rows), then decode steps that cross both."""
     ids = np.random.default_rng(n).integers(0, 97, (n + 6,)).astype(np.int32)
-    want = np.asarray(REF.forward(HF, LAYERS, weights, jnp.asarray(ids)))
+    want = reference_logits("glm5", HF, LAYERS, weights, ids)
     eng = engine(weights)
     got = logits_of(eng, eng.prefill("a", ids[None, :n]))
     np.testing.assert_allclose(got, want[:n], atol=2e-5)
@@ -146,8 +137,8 @@ def test_burst_rounds_two_slots_and_a_rewind(weights, small_blocks):
             emitted[sid] += r["tokens"]
             fed[sid] = r["tokens"][-1]
     for sid, ids in consumed.items():
-        want = np.asarray(REF.forward(
-            HF, LAYERS, weights, jnp.asarray(ids, jnp.int32)))
+        want = reference_logits("glm5", HF, LAYERS, weights,
+                                np.asarray(ids, np.int32))
         picked = want[lens[sid]:].argmax(-1)
         assert list(picked) == emitted[sid], sid
     # a rewind is a length: the rows past it are masked until rewritten
@@ -600,9 +591,6 @@ def test_random_init_and_the_import_build_the_same_tree(weights):
     """Keys, shapes and dtypes, both layer stacks: what `init_params` draws
     is what `convert_state_dict` makes of a checkpoint, the three weights
     by head among them."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
-        init_params,
-    )
     cfg = small_config()
     drawn = jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg, jnp.float32))
@@ -657,17 +645,11 @@ def test_every_other_engine_refuses_the_family_by_name(weights):
     eng.prefill("a", np.arange(4, dtype=np.int32)[None])
     with pytest.raises(NotImplementedError, match="speculative verify"):
         eng.decode_batch({"a": np.zeros((1, 3), np.int32)})
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutor,
-    )
     with pytest.raises(NotImplementedError, match="latent row"):
         StageExecutor(cfg, whole, params)
 
 
 def test_main_refuses_before_a_weight_is_made():
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
-        main,
-    )
     base = ["--model", "glm5-rehearsal", "--num_layers", "2"]
     parse = main.build_parser().parse_args
     ok = parse(base + ["--mode", "serve", "--stage", "0", "--batched"])

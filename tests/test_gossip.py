@@ -55,7 +55,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     events,
 )
 
-from test_runtime_pipeline import tiny_cfg
+from engines import tiny_cfg
 
 
 def _rec(peer, stage=1, addr="127.0.0.1:1"):

@@ -265,9 +265,7 @@ def test_fold_kill_switch_token_parity(monkeypatch):
         ROLE_FULL,
         StageSpec,
     )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-        BatchedStageExecutor,
-    )
+    from engines import engine as BatchedStageExecutor
 
     cfg = llama_config(vocab_size=128, hidden_size=128, num_layers=2,
                        num_heads=4, num_kv_heads=2, intermediate_size=256,

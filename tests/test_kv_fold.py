@@ -10,8 +10,9 @@ a width in place of the backend's answer and hold it to a twin that was
 told nothing: the same tokens, a prompt's rows in the cache bit for bit, every later row and
 hidden row to float32 rounding."""
 
+import functools
+
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental.layout import Layout
@@ -21,16 +22,20 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     init_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
     slice_stage_params,
+    StagePlan,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime import (
     batching,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-    BatchedStageExecutor,
     kv_fold_width,
 )
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry import (
+    events as events_mod,
+)
+
+from engines import all_eqns, engine as shared_engine, program_args
 
 GRAIN = 8
 SLOTS = 4
@@ -61,11 +66,16 @@ V5E_GPT2_XL = Layout((0, 1, 3, 4, 2), ((8, 128), (2, 1)))   # [.., 25, 64]
 V5E_QWEN2 = Layout((0, 1, 2, 3, 4), ((4, 128), (2, 1)))     # [.., 4, 128]
 
 
+@functools.lru_cache(maxsize=None)
+def _fold_to(lanes):
+    """ONE function a lane count, so that the engines told the same share
+    their programs (`engines`)."""
+    return lambda layout, hkv, dh: -(-hkv * dh // lanes) * lanes
+
+
 def told(monkeypatch, lanes):
     """Engines built from here on fold their rows to whole ``lanes``."""
-    monkeypatch.setattr(
-        batching, "kv_fold_width",
-        lambda layout, hkv, dh: -(-hkv * dh // lanes) * lanes)
+    monkeypatch.setattr(batching, "kv_fold_width", _fold_to(lanes))
 
 
 def engine(family, *, span=None, prefix_cache=True):
@@ -73,7 +83,7 @@ def engine(family, *, span=None, prefix_cache=True):
     params = init_params(jax.random.PRNGKey(3), cfg)
     plan = StagePlan.even(cfg.num_layers, 1 if span is None else 2)
     spec = plan.stages[0 if span is None else span]
-    ex = BatchedStageExecutor(
+    ex = shared_engine(
         cfg, spec, slice_stage_params(cfg, params, spec), slots=SLOTS,
         max_len=MAX_LEN, prefix_cache_bytes=(1 << 20) * prefix_cache)
     if prefix_cache:
@@ -117,28 +127,15 @@ KERNEL_READS = {"gpt2": {"burst", "step"}, "qwen2": {"burst", "step"},
 def reads_by_kernel(ex):
     """Which of the engine's compiled decode programs hold the kernel: the
     burst, the single step, the verify step (`drive` ran all three)."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
-        RECENT_WINDOW,
-    )
-
-    s = ex.slots
-    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)         # noqa: E731
-    burst = [ex.params, i32(len(batching.BURST_INTS) + RECENT_WINDOW, s),
-             jnp.ones((len(batching.BURST_FLOATS), s), jnp.float32), ex.k,
-             ex.v] + ([ex._rider_args(None, TICKS)] if ex.rider_rows else [])
-    step = lambda t: [ex.params, i32(s, t), i32(s),          # noqa: E731
-                      jnp.ones((s,), bool), ex.k, ex.v]
-    programs = {"burst": (ex._burst_jits[TICKS], burst),
-                "step": (ex._decode_jits[1], step(1)),
-                "verify": (ex._decode_jits[3], step(3))}
-    from test_batching import _all_eqns
-
+    programs = {"burst": program_args(ex, "burst_tick", TICKS),
+                "step": program_args(ex, "decode_step-1"),
+                "verify": program_args(ex, "decode_step-3")}
     # By the equation, not by a name in the text: a cached inner jaxpr
     # (`jnp.pad`'s) keeps the source line of whoever traced it first.
     return {name for name, (fn, args) in programs.items()
             if any(e.primitive.name == "pallas_call"
                    and e.params["name"] == "slot_attention"
-                   for e in _all_eqns(jax.make_jaxpr(fn)(*args).jaxpr))}
+                   for e in all_eqns(jax.make_jaxpr(fn)(*args).jaxpr))}
 
 
 def drive(ex):
@@ -291,10 +288,6 @@ def test_no_model_is_named_and_no_field_added():
 def test_one_event_says_how_the_stacks_are_held(monkeypatch):
     """`kv_layout`, where the stacks are made: the shape, the layout as XLA
     spells it, what the backend said of a ``[Hkv, Dh]`` row, the fold."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.telemetry import (
-        events as events_mod,
-    )
-
     rec = events_mod.EventRecorder(enabled=True)
     monkeypatch.setattr(batching._ev, "emit", rec.emit)
     plain = engine("gpt2", prefix_cache=False)

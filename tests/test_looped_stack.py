@@ -5,10 +5,10 @@ plain reference of the family (``perfbench/references/ouro_plain.py``: no
 cache, no pass index, nothing of the program).
 
 Tiny sizes with L = 3 layers and T = 4 passes, unlike on purpose: a cache
-index built from the wrong one of the two shows."""
+index built from the wrong one of the two shows. The rider lane's cases are
+``test_looped_rider.py``; both build their engines by `engines.looped`."""
 
 import dataclasses
-import os
 import types
 
 import jax
@@ -18,21 +18,17 @@ import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     config as config_mod,
-    full_forward,
     init_kv_cache,
     init_params,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.hf_import import (
-    config_from_hf,
     convert_state_dict,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
     StagePlan,
     slice_stage_params,
     stage_forward,
-)
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-    quantize_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
     looped_forward,
@@ -43,82 +39,25 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
     BatchedStageExecutor,
 )
-from perfbench.harness.manifest import load_module
 
-from test_batching import transfer_counts
+from engines import (
+    LAYERS,
+    LOOPED_HF as HF,
+    PASSES,
+    TOLERANCE,
+    VOCAB,
+    engine,
+    greedy_entry,
+    ids_of,
+    looped,
+    looped_config,
+    looped_logits,
+    reference,
+    reference_weights,
+    rel_rms,
+)
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LAYERS, PASSES, VOCAB = 3, 4, 97
-HF = {"model_type": "ouro", "hidden_size": 64, "intermediate_size": 96,
-      "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
-      "num_hidden_layers": LAYERS, "vocab_size": VOCAB,
-      "max_position_embeddings": 128, "rms_norm_eps": 1e-6,
-      "rope_theta": 1000000, "rope_scaling": None,
-      "tie_word_embeddings": False, "total_ut_steps": PASSES,
-      "early_exit_threshold": 1, "use_sliding_window": False,
-      "sliding_window": None}
-
-# How far the engine may lie from the float32 reference, as relative RMS
-# over a row of logits. float32: rounding only. bfloat16: 12 layer-visits
-# of bf16 activations and cache rows, each sublayer re-scaled by its
-# sandwich norm (the CPU reads 0.02-0.04 at these sizes). int8: weight-only
-# quantisation with a scale per output column; at K = 64 a column's 64
-# weights share one scale, so a matrix adds ~1.5% (the CPU reads 0.05-0.07
-# over the 12 layer-visits). A wrong cache layer reads 0.3 and more (the
-# two tests below that break the index on purpose).
-TOLERANCE = {"float32": 1e-4, "bfloat16": 8e-2, "int8": 0.12}
-
-
-@pytest.fixture(scope="module")
-def ref():
-    return load_module(os.path.join(ROOT, "perfbench/references/"
-                                          "ouro_plain.py"))
-
-
-def program_config(hf=HF):
-    return config_from_hf(types.SimpleNamespace(**hf))
-
-
-def build(ref, kind="float32", hf=HF, seed=5, gate_bias=None,
-          gate_scale=1.0, **engine_kw):
-    """(cfg, weights, engine) at the tiny sizes: the reference's seeded
-    checkpoint through the program's importer."""
-    dtype = jnp.bfloat16 if kind == "bfloat16" else jnp.float32
-    weights = dict(ref.make_weights(hf, LAYERS, seed, dtype))
-    if gate_bias is not None:
-        weights["model.early_exit_gate.bias"] = jnp.full((1,), gate_bias,
-                                                         dtype)
-    weights["model.early_exit_gate.weight"] = (
-        weights["model.early_exit_gate.weight"] * gate_scale).astype(dtype)
-    cfg = program_config(hf)
-    params = convert_state_dict(cfg, weights, dtype=dtype)
-    if kind == "int8":
-        params = quantize_params(params, "int8")
-    spec = StagePlan.even(cfg.num_layers, 1).stages[0]
-    eng = BatchedStageExecutor(cfg, spec, params, slots=3, max_len=64,
-                               dtype=dtype, **engine_kw)
-    return cfg, weights, eng
-
-
-def rel_rms(got, want):
-    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-def ids_of(n, seed=0):
-    return np.random.default_rng(seed).integers(0, VOCAB, (n,)).astype(
-        np.int32)
-
-
-def reference_logits(ref, weights, ids, hf=HF):
-    return np.asarray(ref.forward(hf, LAYERS, weights, jnp.asarray(ids)))
-
-
-def burst_entry(token, budget, generated=None):
-    return {"token": int(token), "seed": 0, "budget": budget, "eos": None,
-            "generated": generated or (int(token),), "temperature": 0.0,
-            "top_p": 1.0, "top_k": 0, "repetition_penalty": 1.0}
-
+ref = reference("ouro")
 
 # -- the five ways rows reach the cache, each against the reference ----------
 
@@ -173,291 +112,67 @@ DRIVES = {"prefill_decode": drive_prefill_decode,
 
 @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
 @pytest.mark.parametrize("path", sorted(DRIVES))
-def test_engine_rows_match_the_reference(ref, path, kind):
+def test_engine_rows_match_the_reference(path, kind):
     kw = ({"prefix_cache_bytes": 1 << 22} if path == "suffix_prefill"
           else {})
-    _, weights, eng = build(ref, kind, **kw)
+    _, weights, eng = looped(kind, **kw)
     if path == "suffix_prefill":
         eng.prefix_store.grain = 4
     ids = ids_of(20)
     got, first = DRIVES[path](eng, ids)
-    want = reference_logits(ref, weights, ids)[first:first + len(got)]
+    want = looped_logits(weights, ids)[first:first + len(got)]
     worst = max(rel_rms(g, w) for g, w in zip(got, want))
     assert worst <= TOLERANCE[kind], (path, kind, worst)
 
 
 @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
-def test_the_16_tick_burst_emits_the_reference_s_tokens(ref, kind):
+def test_the_16_tick_burst_emits_the_reference_s_tokens(kind):
     """Greedy, two sessions of unlike lengths in one program: every token
     the burst emits is judged on the reference's row at its position (the
     benchmark's burst_gap)."""
-    _, weights, eng = build(ref, kind)
+    _, weights, eng = looped(kind)
     seqs = {"a": ids_of(9, 1), "b": ids_of(13, 2)}
     for sid, ids in seqs.items():
         eng.prefill(sid, ids[None, :-1])
-    res = eng.decode_burst({sid: burst_entry(ids[-1], 16)
+    res = eng.decode_burst({sid: greedy_entry(ids[-1], 16)
                             for sid, ids in seqs.items()}, 16)
     for sid, ids in seqs.items():
         toks = res[sid]["tokens"]
         assert len(toks) >= 5       # a greedy repeat may stop it, no sooner
         consumed = np.concatenate([ids, toks[:-1]]).astype(np.int32)
-        want = reference_logits(ref, weights, consumed)[len(ids) - 1:]
+        want = looped_logits(weights, consumed)[len(ids) - 1:]
         gaps = [(row.max() - row[t]) / np.sqrt((row * row).mean())
                 for row, t in zip(want, toks)]
         assert np.mean(gaps) <= {"float32": 1e-5, "bfloat16": 0.05,
                                  "int8": 0.05}[kind], (kind, gaps)
 
 
-def test_burst_returns_the_passes_only_for_a_looped_stack(ref):
+def test_burst_returns_the_passes_only_for_a_looped_stack():
     """The looped program's packed result ends, after the tokens, the stops
     and the lengths, in the passes its tokens took and its rider's token
     (none: -1), and it takes the rider as one more argument; a one-pass
     program's ends at the lengths, and it has no lane."""
-    _, _, eng = build(ref)
+    _, _, eng = looped()
     eng.prefill("s", ids_of(6)[None])
-    rows, args = eng._burst_prep({"s": burst_entry(3, 4)}, 4)
+    rows, args = eng._burst_prep({"s": greedy_entry(3, 4)}, 4)
     out = eng._get_burst_jit(4)(eng.params, *args, eng.k, eng.v,
                                 eng._rider_args(None, 4))
     packed = np.asarray(out[0])
     assert len(out) == 3 and packed.shape == ((4 + 2) * eng.slots + 2,)
     assert packed[-2] == PASSES * 4
     assert packed[-1] == -1 and eng.rider_rows == batching.RIDER_ROWS
-    _, _, once = build(ref, hf=dict(HF, total_ut_steps=1))
+    _, _, once = looped(hf=dict(HF, total_ut_steps=1))
     once.prefill("s", ids_of(6)[None])
-    rows, args = once._burst_prep({"s": burst_entry(3, 4)}, 4)
+    rows, args = once._burst_prep({"s": greedy_entry(3, 4)}, 4)
     out = once._get_burst_jit(4)(once.params, *args, once.k, once.v)
     assert len(out) == 3 and out[0].shape == ((4 + 2) * once.slots,)
     assert once.rider_rows == 0 and not once.can_ride(8, 4)
 
 
-# -- the rider lane: a joining request's prompt rows in the burst's ticks ----
-
-def rider_of(sid, ids, **knobs):
-    return {"session_id": sid, "ids": np.asarray(ids), "seed": 7,
-            "generated": (), "temperature": 0.0, "top_p": 1.0, "top_k": 0,
-            "repetition_penalty": 1.0, **knobs}
-
-
-def two_decoding(eng):
-    """Sessions x and y prefilled on ``eng``; their entries of a burst."""
-    seqs = {"x": ids_of(9, 1), "y": ids_of(13, 2)}
-    for sid, ids in seqs.items():
-        eng.prefill(sid, ids[None, :-1])
-    return {sid: burst_entry(ids[-1], 4) for sid, ids in seqs.items()}
-
-
-@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
-@pytest.mark.parametrize("t", [1, 16, 17, 40])
-def test_a_rider_s_rows_are_the_prefill_program_s(ref, t, kind):
-    """A prompt of t rows (less than a chunk, one chunk exactly, one row
-    into the second, three chunks with the last part full) riding a 4-tick
-    burst in which two other sessions decode, against the prefill program
-    on a twin engine after the same burst: the decoding sessions' tokens
-    are the twin's, the rider's first token is the twin's greedy one, its K
-    and V rows of every (pass, layer) are the program's, its length is t,
-    and its NEXT row through the cache is the reference's."""
-    _, weights, a = build(ref, kind)
-    _, _, b = build(ref, kind)
-    ids = ids_of(t + 1, 3)
-    ent_a, ent_b = two_decoding(a), two_decoding(b)
-    got = a.decode_burst(ent_a, 4, rider=rider_of("r", ids[:t]))
-    want = b.decode_burst(ent_b, 4)
-    h = b.prefill("r", ids[None, :t])
-    for sid in ent_a:
-        assert got[sid] == want[sid]
-    assert got["r"] == {
-        "token": int(np.argmax(np.asarray(b.logits(h))[0, -1])),
-        "cache_len": t}
-    assert a.lengths[a.slot("r")] == t
-    loose = {"float32": 1e-5, "bfloat16": 2e-2, "int8": 2e-2}[kind]
-    for mine, theirs in ((a.k, b.k), (a.v, b.v)):
-        assert rel_rms(mine[:, a.slot("r"), :t],
-                       theirs[:, b.slot("r"), :t]) <= loose
-    out = a.decode_batch({"r": ids[None, t:t + 1]})
-    row = np.asarray(a.logits(out["r"]))[0, 0]
-    assert rel_rms(row, reference_logits(ref, weights, ids)[t]) <= (
-        TOLERANCE[kind])
-
-
-def test_a_rider_s_sampled_token_is_the_host_s(ref):
-    """Sampled (temperature, top-p, a penalty over the tokens sent so far):
-    the device draws the rider's first token with the key and the knobs
-    `executor._sample_rows` gives a prefill's on the host."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        _sample_last,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        SamplingParams,
-    )
-
-    _, _, a = build(ref)
-    _, _, b = build(ref)
-    ids = ids_of(21, 4)
-    knobs = {"temperature": 0.9, "top_p": 0.9, "top_k": 0,
-             "repetition_penalty": 1.3}
-    hit = 0
-    for seed in range(6):
-        got = a.decode_burst(two_decoding(a), 4, rider=rider_of(
-            "r", ids, seed=seed, generated=(5, 9), **knobs))
-        req = types.SimpleNamespace(
-            sampling=SamplingParams(**knobs), generated_tokens=(5, 9),
-            step_seed=seed)
-        want = _sample_last(b.logits(b.prefill("r", ids[None])), 21, req)
-        assert got["r"]["token"] == want, seed
-        hit += want != int(np.argmax(np.asarray(
-            b.logits(b.prefill("r", ids[None])))[0, -1]))
-    assert hit          # the draw is not the argmax on every seed
-
-
-def test_a_lane_without_a_rider_writes_nothing(ref):
-    """A burst with no rider leaves every slot that does not decode as it
-    was, bit for bit: the lane's rows point at slot 0 and write back what
-    they read."""
-    _, _, eng = build(ref)
-    eng.prefill("idle", ids_of(30, 5)[None])         # slot 0? whichever
-    entries = two_decoding(eng)
-    s = eng.slot("idle")
-    before = (np.asarray(eng.k[:, s]), np.asarray(eng.v[:, s]))
-    eng.decode_burst(entries, 4)
-    np.testing.assert_array_equal(np.asarray(eng.k[:, s]), before[0])
-    np.testing.assert_array_equal(np.asarray(eng.v[:, s]), before[1])
-    free = [f for f in range(eng.slots) if f not in eng._slot_of.values()]
-    assert not free or not np.asarray(eng.k[:, free[0]]).any()
-
-
-def test_what_does_not_fit_the_lane_does_not_ride(ref):
-    _, _, eng = build(ref)                           # 64-row slots
-    assert eng.can_ride(64, 4) and not eng.can_ride(65, 4)
-    assert not eng.can_ride(0, 4) and not eng.can_ride(17, 1)
-    with pytest.raises(ValueError, match="does not ride"):
-        eng.decode_burst({}, 1, rider=rider_of("r", ids_of(17)))
-    assert eng.slot("r") is None and len(eng._free) == eng.slots
-
-
-def stage_request(sid, ids, *, cur_len=0, burst=0, prefill=False, seed=0):
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        SamplingParams,
-        StageRequest,
-    )
-
-    return StageRequest(
-        session_id=sid, hidden=jnp.asarray([ids], jnp.int32),
-        seq_len=len(ids), cur_len=cur_len, is_prefill=prefill,
-        max_length=64, sampling=SamplingParams(temperature=0.0),
-        step_seed=seed, burst_len=burst, burst_budget=burst)
-
-
-def test_a_prefill_rides_when_another_session_holds_a_slot(ref):
-    """Through the adapter. The first prefill finds the engine empty and
-    runs the prefill program; with that session in its slot, two more
-    prefills that arrive together each ride a burst round of their own (a
-    round carries one rider), beside the first session's burst; the tokens
-    are those of a twin engine that ran the program for all three; a
-    prefill with a stored prefix to copy, or one too long for the lane,
-    runs the program."""
-    import threading
-
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-        BatchingStageAdapter,
-    )
-
-    _, _, eng = build(ref)
-    _, _, twin = build(ref)
-    ad = BatchingStageAdapter(eng, window_s=0.05)
-    ad.warmup(burst=2)
-    assert ad.burst_ticks == 2
-    prompts = {"a": ids_of(11, 1), "b": ids_of(19, 2), "c": ids_of(5, 3)}
-    want = {sid: int(np.argmax(np.asarray(
-        twin.logits(twin.prefill(sid, ids[None])))[0, -1]))
-        for sid, ids in prompts.items()}
-    first = ad.forward(stage_request("a", prompts["a"], prefill=True))
-    assert first.token_id == want["a"] and eng.burst_dispatches == 1
-    got = {}
-
-    def send(sid, req):
-        got[sid] = ad.forward(req)
-
-    threads = [threading.Thread(target=send, args=(sid, stage_request(
-        sid, prompts[sid], prefill=True))) for sid in "bc"]
-    threads.append(threading.Thread(target=send, args=("a", stage_request(
-        "a", [first.token_id], cur_len=11, burst=2))))
-    for th in threads:
-        th.start()
-    for th in threads:
-        th.join(120)
-    assert {sid: got[sid].token_id for sid in "bc"} == {
-        "b": want["b"], "c": want["c"]}
-    assert (got["b"].cache_len, got["c"].cache_len) == (19, 5)
-    assert eng.burst_dispatches == 3        # warm-up's + one a rider
-    assert len(got["a"].burst_tokens) == 2
-    res = twin.decode_burst({"a": burst_entry(want["a"], 2)}, 2)
-    assert list(got["a"].burst_tokens) == res["a"]["tokens"]
-    ad.drop_session("c")
-    long = ad.forward(stage_request("d", ids_of(40, 4), prefill=True))
-    assert eng.burst_dispatches == 3 and long.cache_len == 40
-
-
-@pytest.mark.parametrize("sessions", [1, 2])
-def test_a_round_with_a_lane_crosses_the_boundary_four_times(ref, sessions):
-    """A burst round of an engine with a rider lane, with and without a
-    rider, for one session and for every slot but the rider's: three
-    arrays up (the slots' int32 and float32, the lane's int32 vector) and
-    ONE read, by `server_burst_transfers_total` over
-    `server_burst_dispatches_total`; every argument but the parameters and
-    the stacks is a HOST array when the program is called (so nothing ran
-    on the device to make it)."""
-    _, _, eng = build(ref)
-    read = transfer_counts(eng)
-    for i in range(sessions):
-        eng.prefill(f"s{i}", ids_of(6 + i, i)[None])
-    real, seen = eng._get_burst_jit(2), []
-
-    def recording(params, *args):
-        seen.append([type(a) for a in args if not isinstance(a, tuple)])
-        return real(params, *args)
-
-    eng._burst_jits[2] = recording
-    entries = {f"s{i}": burst_entry(3 + i, 2) for i in range(sessions)}
-    eng.decode_burst(entries, 2)
-    assert read() == (3, 1, 1)
-    res = eng.decode_burst(
-        {sid: burst_entry(4, 2) for sid in entries}, 2,
-        rider=rider_of("r", ids_of(9, 7)))
-    assert read() == (6, 2, 2) and res["r"]["cache_len"] == 9
-    for types_ in seen:
-        host = [t for t in types_ if t is np.ndarray]
-        assert len(host) == 3 and len(types_) == 3 + len(
-            jax.tree.leaves((eng.k, eng.v))), types_
-
-
-def test_no_eager_program_runs_between_two_bursts(ref, caplog):
-    """A looped engine with a rider, every compiled program forgotten
-    after its first burst: the second burst, rider and all, compiles the
-    burst program and NOTHING else (a scalar handed to ``jnp.asarray`` on
-    its own, as the rider's eight once were, would compile a convert
-    program here; so would any eager slice, pad or cast of a result)."""
-    import logging
-
-    _, _, eng = build(ref)
-    eng.prefill("s", ids_of(6)[None])
-    first = eng.decode_burst({"s": burst_entry(3, 2)}, 2)["s"]["tokens"]
-    jax.clear_caches()
-    with jax.log_compiles(), caplog.at_level(logging.WARNING):
-        caplog.clear()
-        res = eng.decode_burst(
-            {"s": burst_entry(first[-1], 2, generated=first)}, 2,
-            rider=rider_of("r", ids_of(9, 7), temperature=0.8, top_p=0.9,
-                           top_k=5, repetition_penalty=1.2))
-    built = [r.getMessage().split()[1] for r in caplog.records
-             if r.getMessage().startswith("Compiling ")]
-    assert built == ["jit(burst_tick)"], built
-    assert len(res["s"]["tokens"]) == 2 and res["r"]["cache_len"] == 9
-
-
 # -- the gate and the exit rule ----------------------------------------------
 
-def test_gates_match_the_reference(ref):
-    cfg, weights, eng = build(ref)
+def test_gates_match_the_reference():
+    cfg, weights, eng = looped()
     ids = ids_of(18)
     kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, 32)
     assert kc.shape[0] == PASSES * LAYERS
@@ -471,7 +186,7 @@ def test_gates_match_the_reference(ref):
 
 
 @pytest.mark.parametrize("bias", [0.0, -1.0, 3.0])
-def test_a_forced_early_exit_chooses_the_reference_s_pass(ref, bias):
+def test_a_forced_early_exit_chooses_the_reference_s_pass(bias):
     """Threshold 0.5, the gate's weight drawn 5 x wider and its bias set so
     that tokens leave at different passes (0.0: at the first or the
     second; -1.0: later; 3.0: all at the first). The engine's logits are
@@ -479,24 +194,24 @@ def test_a_forced_early_exit_chooses_the_reference_s_pass(ref, bias):
     prefill, decode and the burst, and the passes the burst counts on the
     device are the reference's."""
     hf = dict(HF, early_exit_threshold=0.5)
-    cfg, weights, eng = build(ref, hf=hf, gate_bias=bias, gate_scale=5.0)
+    cfg, weights, eng = looped(hf=hf, gate_bias=bias, gate_scale=5.0)
     assert cfg.exit_threshold == 0.5
     ids = ids_of(20, 3)
     _, gates = ref.passes(hf, LAYERS, weights, jnp.asarray(ids))
     at = np.asarray(ref.exit_pass(hf, gates))
     assert len(set(at.tolist())) >= (1 if bias == 3.0 else 2), at
     got, _ = drive_prefill_decode(eng, ids)
-    want = reference_logits(ref, weights, ids, hf)[:len(got)]
+    want = looped_logits(weights, ids, hf)[:len(got)]
     assert max(rel_rms(g, w) for g, w in zip(got, want)) <= 1e-4
     # and NOT the last pass's logits, where the reference left earlier
-    last = reference_logits(ref, weights, ids,
+    last = looped_logits(weights, ids,
                             dict(hf, early_exit_threshold=2.0))[:len(got)]
     early = at[:len(got)] < PASSES - 1
     assert early.any()
     assert min(rel_rms(g, w) for g, w in zip(got[early], last[early])) > 1e-3
     # the burst, straight from its program: greedy tokens, passes counted
     eng.rewind("s", 12)
-    rows, args = eng._burst_prep({"s": burst_entry(ids[12], 6)}, 6)
+    rows, args = eng._burst_prep({"s": greedy_entry(ids[12], 6)}, 6)
     out = eng._get_burst_jit(6)(eng.params, *args, eng.k, eng.v,
                                 eng._rider_args(None, 6))
     packed, eng.k, eng.v = out
@@ -508,26 +223,26 @@ def test_a_forced_early_exit_chooses_the_reference_s_pass(ref, bias):
     at2 = np.asarray(ref.exit_pass(hf, g2))[12:]
     assert len(at2) == len(toks) >= 5
     assert int(packed[-2]) == int((at2 + 1).sum())
-    rows_ref = reference_logits(ref, weights, consumed, hf)[12:]
+    rows_ref = looped_logits(weights, consumed, hf)[12:]
     assert toks.tolist() == rows_ref.argmax(-1).tolist()
 
 
-def test_a_cache_shared_across_passes_fails_the_comparison(ref, monkeypatch):
+def test_a_cache_shared_across_passes_fails_the_comparison(monkeypatch):
     """The check that the comparison has teeth: with every pass reading and
     writing pass 0's cache layers (weight index for cache index) the
     decode rows leave the reference by far more than any tolerance; the
     prefill's own rows, which attend over fresh keys, do not."""
     monkeypatch.setattr(batching, "_at",
                         lambda base, i: i if base is None else 0 * base + i)
-    _, weights, eng = build(ref)
+    _, weights, eng = looped()
     ids = ids_of(20)
     got, _ = drive_prefill_decode(eng, ids)
-    want = reference_logits(ref, weights, ids)
+    want = looped_logits(weights, ids)
     assert max(rel_rms(g, w) for g, w in zip(got[:12], want[:12])) <= 1e-4
     assert min(rel_rms(g, w) for g, w in zip(got[12:], want[12:17])) > 1e-2
 
 
-def test_a_pass_reading_the_pass_before_fails_the_comparison(ref,
+def test_a_pass_reading_the_pass_before_fails_the_comparison(
                                                              monkeypatch):
     """Pass t at pass t-1's cache layers (pass 0 at its own): passes 0 and
     1 then share rows."""
@@ -537,21 +252,21 @@ def test_a_pass_reading_the_pass_before_fails_the_comparison(ref,
         return i if base is None else jnp.maximum(base - per, 0) + i
 
     monkeypatch.setattr(batching, "_at", shifted)
-    _, weights, eng = build(ref)
+    _, weights, eng = looped()
     ids = ids_of(20)
     got, _ = drive_prefill_decode(eng, ids)
-    want = reference_logits(ref, weights, ids)
+    want = looped_logits(weights, ids)
     assert min(rel_rms(g, w) for g, w in zip(got[12:], want[12:17])) > 1e-2
 
 
 # -- one pass is the model the repo already ran -------------------------------
 
-def test_one_pass_is_the_sandwich_norm_llama(ref):
+def test_one_pass_is_the_sandwich_norm_llama():
     """total_ut_steps 1: no gate leaf, the head norms once, and oracle,
     engine and reference agree with a llama layer with sandwich norms
     (what ``post_norms`` has meant since gemma-2)."""
     hf = dict(HF, total_ut_steps=1)
-    cfg, weights, eng = build(ref, hf=hf)
+    cfg, weights, eng = looped(hf=hf)
     assert cfg.loop_steps == 1 and cfg.post_norms
     sd = {k: v for k, v in weights.items() if "early_exit" not in k}
     params = convert_state_dict(cfg, sd, dtype=jnp.float32)
@@ -562,7 +277,7 @@ def test_one_pass_is_the_sandwich_norm_llama(ref):
     assert kc.shape[0] == LAYERS
     oracle, _, _ = full_forward(cfg, params, jnp.asarray(ids[None]), kc, vc,
                                 jnp.int32(0))
-    want = reference_logits(ref, weights, ids, hf)
+    want = looped_logits(weights, ids, hf)
     assert rel_rms(oracle[0], want) <= 1e-5
     h = eng.prefill("s", ids[None])
     assert rel_rms(np.asarray(eng.logits(h))[0], want) <= 1e-5
@@ -578,8 +293,8 @@ def test_one_pass_is_the_sandwich_norm_llama(ref):
     np.testing.assert_array_equal(np.asarray(again), np.asarray(oracle))
 
 
-def test_the_looped_oracle_matches_the_reference(ref):
-    cfg, weights, eng = build(ref)
+def test_the_looped_oracle_matches_the_reference():
+    cfg, weights, eng = looped()
     ids = ids_of(16)
     kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, 32)
     got, kc, vc = full_forward(cfg, eng.params, jnp.asarray(ids[None, :11]),
@@ -591,7 +306,7 @@ def test_the_looped_oracle_matches_the_reference(ref):
                                    jnp.int32(j))
         rows.append(np.asarray(got[0]))
     assert rel_rms(np.concatenate(rows),
-                   reference_logits(ref, weights, ids)) <= 1e-5
+                   looped_logits(weights, ids)) <= 1e-5
     with pytest.raises(ValueError, match="cache layers"):
         full_forward(cfg, eng.params, jnp.asarray(ids[None]),
                      kc[:LAYERS], vc[:LAYERS], jnp.int32(0))
@@ -599,9 +314,9 @@ def test_the_looped_oracle_matches_the_reference(ref):
 
 # -- the importer --------------------------------------------------------------
 
-def test_hf_import_round_trip_of_the_published_names(ref):
-    weights = ref.make_weights(HF, LAYERS, 9, jnp.float32)
-    cfg = program_config()
+def test_hf_import_round_trip_of_the_published_names():
+    weights = reference_weights("ouro", HF, LAYERS, 9)
+    cfg = looped_config()
     assert (cfg.model_type, cfg.loop_steps, cfg.exit_threshold,
             cfg.head_dim, cfg.post_norms, cfg.tie_word_embeddings,
             cfg.use_bias, cfg.attn_qkv_bias) == (
@@ -642,7 +357,7 @@ def test_hf_import_round_trip_of_the_published_names(ref):
     spec = StagePlan.even(cfg.num_layers, 1).stages[0]
     assert "exit_gate" in slice_stage_params(cfg, p, spec)
     with pytest.raises(ValueError, match="sliding"):
-        program_config(dict(HF, use_sliding_window=True))
+        looped_config(dict(HF, use_sliding_window=True))
 
 
 def test_the_preset_holds_the_published_keys():
@@ -660,7 +375,7 @@ def test_the_preset_holds_the_published_keys():
         assert (make().loop_steps > 1) == (name == "ouro-2.6b"), name
 
 
-def test_a_server_frees_what_the_fused_copies_replace(ref):
+def test_a_server_frees_what_the_fused_copies_replace():
     """`main.run_serve` hands its batched engine the fused tree and owns the
     staged one: the unfused projection stacks go before the engine is
     built, the leaves the fused tree still holds stay, and the engine, built
@@ -669,8 +384,8 @@ def test_a_server_frees_what_the_fused_copies_replace(ref):
         main as main_mod,
     )
 
-    weights = ref.make_weights(HF, LAYERS, 5, jnp.float32)
-    cfg = program_config()
+    weights = reference_weights("ouro", HF, LAYERS, 5)
+    cfg = looped_config()
     params = convert_state_dict(cfg, weights, dtype=jnp.float32)
     spec = StagePlan.even(cfg.num_layers, 1).stages[0]
     staged = slice_stage_params(cfg, params, spec)
@@ -681,25 +396,23 @@ def test_a_server_frees_what_the_fused_copies_replace(ref):
     assert all(x.is_deleted() for x in gone)
     assert {"wqkv", "wo"} == set(fused["layers"]["attn"])
     assert not any(x.is_deleted() for x in jax.tree.leaves(fused))
-    eng = BatchedStageExecutor(cfg, spec, fused, slots=2, max_len=32)
+    eng = engine(cfg, spec, fused, slots=2, max_len=32)
     assert eng.params["layers"] is fused["layers"]
     ids = ids_of(10)
     h = eng.prefill("s", ids[None])
     assert rel_rms(np.asarray(eng.logits(h))[0],
-                   reference_logits(ref, weights, ids)) <= 1e-4
+                   looped_logits(weights, ids)) <= 1e-4
 
 
 # -- everything that would run one pass refuses --------------------------------
 
 def looped_tiny():
-    cfg = program_config()
+    cfg = looped_config()
     return cfg, init_params(jax.random.PRNGKey(0), cfg)
 
 
 def refuse_executor(cfg, params):
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutor,
-    )
+    from engines import stage_executor as StageExecutor
 
     spec = StagePlan.even(cfg.num_layers, 1).stages[0]
     StageExecutor(cfg, spec, params)
@@ -838,4 +551,3 @@ def test_the_gate_leaf_has_a_replication_row():
     rows = [(rx, why) for rx, why in REPLICATED_LEAVES
             if re.search(rx, "exit_gate/w") and re.search(rx, "exit_gate/b")]
     assert len(rows) == 1 and len(rows[0][1]) > 20
-

@@ -15,9 +15,9 @@ import torch
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     config_from_hf,
     convert_state_dict,
-    full_forward,
     init_kv_cache,
 )
+from engines import full_forward
 
 def tiny_gpt2():
     torch.manual_seed(0)
@@ -291,11 +291,11 @@ def test_fused_qkv_layers_bitwise_matches_canonical():
     import numpy as np
 
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-        full_forward,
         init_kv_cache,
         init_params,
         llama_config,
     )
+    from engines import full_forward
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
         fuse_qkv_layers,
     )
@@ -329,12 +329,12 @@ def test_fuse_gate_up_stacked_bitwise():
     import jax.numpy as jnp
 
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-        full_forward,
         init_kv_cache,
         init_params,
         llama_config,
         mixtral_config,
     )
+    from engines import full_forward
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.transformer import (
         fuse_qkv_params,
     )

@@ -14,11 +14,11 @@ import numpy as np
 import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
     init_kv_cache,
     init_params,
     mixtral_config,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.moe import (
     dispatch_stats,
     moe_capacity,

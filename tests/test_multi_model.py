@@ -12,26 +12,37 @@ import random
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
     parse_splits,
     slice_stage_params,
+    StagePlan,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
     SamplingParams,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
-    PipelineClient,
     make_server_record,
+    PipelineClient,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
+    StageExecutionError,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+    StageRequest,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
+    RegistryServer,
+    RemoteRegistry,
+    TcpStageServer,
+    TcpTransport,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.server import (
+    ElasticStageServer,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.transport import (
     LocalTransport,
@@ -41,7 +52,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     ServerRecord,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, stage_executor as StageExecutor, tiny_cfg
 
 
 def _register_swarm(cfg, params, registry, transport, model, seed):
@@ -111,10 +122,6 @@ def test_discovery_filters_by_model():
 def test_elastic_server_ignores_other_models_coverage():
     """An elastic server balancing model A must not count model B's span as
     coverage — otherwise it would leave A's blocks unserved."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.server import (
-        ElasticStageServer,
-    )
-
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(2), cfg)
     registry = PlacementRegistry(rng=random.Random(0))
@@ -141,11 +148,6 @@ def test_elastic_server_ignores_other_models_coverage():
 
 def test_remote_registry_model_roundtrip():
     """The model field survives the TCP registry wire schema."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        RegistryServer,
-        RemoteRegistry,
-    )
-
     srv = RegistryServer(port=0, ttl=30.0)
     srv.start()
     try:
@@ -170,19 +172,6 @@ def test_data_plane_rejects_model_mismatch():
     activations into model-B blocks). The error is kind="stage" (retryable),
     so the client's failover taxonomy blacklists the peer and re-discovers."""
     import jax.numpy as jnp
-
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        RegistryServer,
-        RemoteRegistry,
-        TcpStageServer,
-        TcpTransport,
-    )
 
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -230,17 +219,6 @@ def test_relay_propagates_client_model_tag():
     originating client's model tag, not strip it — the tagged downstream
     server is the one that can still catch the mis-route."""
     import jax.numpy as jnp
-
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        TcpStageServer,
-        TcpTransport,
-    )
 
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)

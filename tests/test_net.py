@@ -6,7 +6,6 @@ payload compression, registry-mediated discovery, failover across server
 processes, and the rpc_info introspection verb.
 """
 
-import random
 import socket
 
 import jax
@@ -14,37 +13,50 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
-    native,
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.main import (
+    main as cli_main,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
     parse_splits,
     slice_stage_params,
+    StagePlan,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
     SamplingParams,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
-    PipelineClient,
     make_server_record,
+    PipelineClient,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
+    StageExecutionError,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+    StageRequest,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
+    _decode_tensor,
+    _decode_tensors,
+    _encode_tensor,
+    _encode_tensors,
+    _header_to_request,
+    _recv_frame,
+    _request_header,
+    _send_frame,
+    check_direct_reachability,
     RegistryServer,
     RemoteRegistry,
     TcpStageServer,
     TcpTransport,
-    _encode_tensor,
-    _decode_tensor,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.task_pool import (
+    StageRuntime,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, stage_executor as StageExecutor, tiny_cfg
 
 
 @pytest.fixture
@@ -105,14 +117,6 @@ def test_a_frame_s_ids_stay_on_the_host_and_its_states_go_up(wire):
     the frame decoded to in `StageRequest.hidden`, a FLOAT tensor (hidden
     states entering a later stage) a device array; the rule is the
     tensor's dtype, whatever the wire's."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        _header_to_request,
-        _request_header,
-    )
-
     def over_the_wire(hidden, **kw):
         req = StageRequest(session_id="s", hidden=hidden,
                            seq_len=hidden.shape[1], cur_len=0,
@@ -283,10 +287,6 @@ def test_concurrent_sessions_through_stage_runtime():
     oracle (one compute thread serializes donated-buffer steps)."""
     import threading
 
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.task_pool import (
-        StageRuntime,
-    )
-
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
     plan = StagePlan.from_splits(cfg.num_layers, [4])
@@ -335,10 +335,6 @@ def test_concurrent_sessions_through_stage_runtime():
 def test_reach_check_and_direct_reachability(swarm):
     """V10 parity: peers answer "can you reach X?" (rpc_check) and the
     >=50%-of-<=5-peers direct-reachability rule aggregates the answers."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        check_direct_reachability,
-    )
-
     cfg, params, client, transport, servers, reg = swarm
     a, b = servers[0], servers[1]
     # a can dial b's real address
@@ -425,14 +421,6 @@ def test_stream_step_without_open_refused(swarm):
     protocol wedge."""
     import jax.numpy as jnp
 
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        _recv_frame,
-        _send_frame,
-    )
-
     _, _, _, _, servers, _ = swarm
     srv = servers[0]
     host, port = srv.address.rsplit(":", 1)
@@ -453,13 +441,6 @@ def test_stream_session_deadline_enforced(swarm):
     import time as _time
 
     import jax.numpy as jnp
-
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
 
     cfg, params, client, transport, servers, _ = swarm
     hop = client.route()[0]  # stage-1 server: consumes hidden [B, T, D]
@@ -487,16 +468,6 @@ def test_stream_per_step_timeout_enforced_via_runtime():
     from the runtime's deadline instead of hanging — the server-side
     per-step budget of petals handler.py:132-195."""
     import jax.numpy as jnp
-
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.task_pool import (
-        StageRuntime,
-    )
 
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -588,13 +559,6 @@ def test_structured_request_log_rides_info_verb(swarm):
     # a refused request lands in the ring with its outcome + detail
     import jax.numpy as jnp
 
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-
     with pytest.raises(StageExecutionError):
         transport.call("tcp-s1-r0", StageRequest(
             session_id="ghost", seq_len=1, cur_len=5, is_prefill=False,
@@ -654,16 +618,6 @@ def test_status_swarm_health_aggregates_rings(swarm, capsys):
     swarm-health section: the injected fault's peer shows under `errors`,
     healthy traffic shows under `slowest hops` and `cache pressure`
     (VERDICT r4 item 8 — one operator surface instead of N server logs)."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.main import (
-        main as cli_main,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutionError,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-
     cfg, params, client, transport, servers, reg_server = swarm
     # Real traffic so rings hold ok-records with durations.
     client.generate([5, 9, 23], max_new_tokens=4,
@@ -694,11 +648,6 @@ def test_per_tensor_wire_schema():
     payload can mix wire dtypes — the activation bf16-compressed, the
     learned prompts exactly f32 — and each meta records its own dtype so
     decode needs no side channel."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        _decode_tensors,
-        _encode_tensors,
-    )
-
     rng = np.random.default_rng(0)
     hidden = rng.standard_normal((2, 3, 8)).astype(np.float32)
     prompts = rng.standard_normal((4, 2, 8)).astype(np.float32)
@@ -718,13 +667,6 @@ def test_deep_prompts_exact_over_bf16_wire():
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
     plan = StagePlan.from_splits(cfg.num_layers, parse_splits("4"))
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
-        RegistryServer,
-        RemoteRegistry,
-        TcpStageServer,
-        TcpTransport,
-    )
-
     dp = np.asarray(0.5 * np.random.default_rng(9).standard_normal(
         (cfg.num_layers, 5, cfg.hidden_size)), np.float32)
 

@@ -113,11 +113,11 @@ def test_layer_forward_close_under_kernel_flag(interpret_kernel,
     states stay close to the dequant path's (same dequant VALUES, only
     contraction order differs)."""
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-        full_forward,
         init_kv_cache,
         init_params,
         llama_config,
     )
+    from engines import full_forward
 
     cfg = llama_config(vocab_size=128, hidden_size=128, num_layers=2,
                        num_heads=4, num_kv_heads=2, intermediate_size=256,
@@ -153,9 +153,7 @@ def test_batched_engine_under_kernel_flag(interpret_kernel, monkeypatch):
         ROLE_FULL,
         StageSpec,
     )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-        BatchedStageExecutor,
-    )
+    from engines import engine as BatchedStageExecutor
 
     cfg = llama_config(vocab_size=128, hidden_size=128, num_layers=2,
                        num_heads=4, num_kv_heads=2, intermediate_size=256,

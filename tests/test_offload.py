@@ -35,7 +35,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PlacementRegistry,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, tiny_cfg
 
 
 def _pair(cfg, params, role="mid", keep=0):

@@ -24,9 +24,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
     SamplingParams,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
     StageRequest,
 )
@@ -39,7 +37,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     chain_digests,
 )
 
-from test_runtime_pipeline import tiny_cfg
+from engines import tiny_cfg
 
 GRAIN = 8
 
@@ -253,7 +251,7 @@ def test_end_to_end_client_reuse_token_parity():
     every server's store and produces identical tokens; a shared-prefix
     third prompt reuses only the shared grains and still matches a
     cache-free cluster."""
-    from test_runtime_pipeline import build_cluster
+    from engines import build_cluster
 
     cfg = tiny_cfg()
     client, transport, registry, params, plan = build_cluster(cfg)
@@ -289,9 +287,7 @@ def _batched_engine(cfg, params, role_last=False, cache_mb=64):
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
         StagePlan,
     )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-        BatchedStageExecutor,
-    )
+    from engines import engine as BatchedStageExecutor
 
     plan = StagePlan.from_splits(cfg.num_layers, parse_splits("2,6"))
     spec = plan.stages[-1] if role_last else plan.stages[1]
@@ -460,7 +456,7 @@ def test_cross_client_affinity_warms_the_same_replica():
     """Two independent clients with the same prompt must pick the SAME
     replica chain (rendezvous affinity), so client B's prefill hits the
     store client A warmed."""
-    from test_runtime_pipeline import build_cluster
+    from engines import build_cluster
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
         PipelineClient,
     )

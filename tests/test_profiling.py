@@ -20,7 +20,7 @@ import re
 
 import pytest
 
-from test_runtime_pipeline import build_cluster, tiny_cfg
+from engines import build_cluster, tiny_cfg
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
     main as main_mod,

@@ -9,8 +9,6 @@ blame the right downstream peer and rebuild every hop's KV via chain replay.
 import random
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
@@ -27,14 +25,12 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PipelineClient,
     make_server_record,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.scheduling.registry import (
     PlacementRegistry,
 )
 
-from test_runtime_pipeline import build_cluster, oracle_generate, tiny_cfg
+from engines import build_cluster, oracle_generate, tiny_cfg
 
 
 def test_push_chain_matches_oracle():

@@ -5,30 +5,44 @@ import jax.numpy as jnp
 import numpy as np
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
+    init_kv_cache,
     init_params,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
     parse_splits,
+    ROLE_FULL,
     slice_stage_params,
+    StagePlan,
+    StagePlan as SP,
+    StageSpec,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-    QuantizedTensor,
+    _quantize_leaf_nf4,
     block_bytes,
     choose_num_blocks,
     dequant_tree,
     is_quantized,
+    NF4Tensor,
+    params_per_block,
+    quantize_layers,
     quantize_params,
+    QuantizedTensor,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
     SamplingParams,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
-    PipelineClient,
-    make_server_record,
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.tensor_parallel import (
+    stage_param_specs,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
+    make_server_record,
+    PipelineClient,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.fused_decode import (
+    make_fused_decode,
+)
+from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
+    StageRequest,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.transport import (
     LocalTransport,
@@ -37,7 +51,14 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PlacementRegistry,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import (
+    engine as BatchedStageExecutor,
+    full_forward,
+    oracle_generate,
+    stage_executor as StageExecutor,
+    tiny_cfg,
+)
+
 from test_tensor_parallel import tiny_cfg as tp_tiny_cfg
 
 
@@ -95,10 +116,6 @@ def test_moe_router_stays_full_precision():
     assert isinstance(qp["layers"]["mlp"]["wg"], QuantizedTensor)
     assert isinstance(qp["layers"]["attn"]["wq"], QuantizedTensor)
     # quantized mixtral forward runs end-to-end
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-        full_forward,
-        init_kv_cache,
-    )
 
     kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, 16)
     ids = jnp.asarray([[1, 2, 3]], jnp.int32)
@@ -117,10 +134,6 @@ def test_quantized_offload_combo():
     res = StageExecutor(cfg, spec, sp, peer_id="q")
     off = StageExecutor(cfg, spec, sp, peer_id="qo", offload=True,
                         keep_layers_resident=1)
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
-        StageRequest,
-    )
-
     hid = np.random.default_rng(0).standard_normal(
         (1, 6, cfg.hidden_size)).astype(np.float32)
     a = res.forward(StageRequest(session_id="s", hidden=jnp.asarray(hid),
@@ -154,13 +167,6 @@ def test_tp_over_quantized_params_rejected():
     import pytest
     from jax.sharding import Mesh
 
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-        StagePlan as SP,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.tensor_parallel import (
-        stage_param_specs,
-    )
-
     cfg = tp_tiny_cfg("llama")
     params = quantize_params(init_params(jax.random.PRNGKey(0), cfg))
     with pytest.raises(NotImplementedError):
@@ -180,11 +186,6 @@ def test_block_bytes_rejects_unknown_mode():
 # ---------------------------------------------------------------------------
 
 def test_nf4_roundtrip_error_bounded():
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-        NF4Tensor,
-        _quantize_leaf_nf4,
-    )
-
     rng = np.random.default_rng(0)
     w = jnp.asarray(rng.standard_normal((128, 96)).astype(np.float32))
     q = _quantize_leaf_nf4(w)
@@ -203,10 +204,6 @@ def test_nf4_roundtrip_error_bounded():
 
 
 def test_nf4_padding_for_odd_input_dim():
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-        _quantize_leaf_nf4,
-    )
-
     rng = np.random.default_rng(1)
     w = jnp.asarray(rng.standard_normal((80, 16)).astype(np.float32))  # 80 % 64 != 0
     q = _quantize_leaf_nf4(w)
@@ -219,12 +216,6 @@ def test_nf4_padding_for_odd_input_dim():
 def test_nf4_stacked_layers_slice_and_scan():
     """NF4 leaves are pytree nodes: stacked [L, in, out] weights slice per
     layer and run under lax.scan like plain arrays."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-        NF4Tensor,
-        dequant_tree,
-        quantize_layers,
-    )
-
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(3), cfg)
     ql = quantize_layers(params["layers"], "nf4")
@@ -275,10 +266,6 @@ def test_nf4_pipeline_matches_dequantized_oracle():
 
 
 def test_nf4_sizing_matches_4_25_bits():
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.quant import (
-        params_per_block,
-    )
-
     cfg = tiny_cfg()
     assert block_bytes(cfg, quant="nf4") == int(params_per_block(cfg) * 4.25 / 8)
     # auto-capacity fits more nf4 blocks than int8 than bf16
@@ -293,14 +280,6 @@ def test_quantized_fused_decode_matches_dequantized_fused():
     produce the same greedy tokens whether QuantizedTensor leaves
     dequantize inside the scan or the dequantized weights are materialized
     up front — for BOTH int8 and nf4."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-        full_forward,
-        init_kv_cache,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.fused_decode import (
-        make_fused_decode,
-    )
-
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(1)
@@ -326,13 +305,6 @@ def test_quantized_fused_decode_matches_dequantized_fused():
 def test_quantized_batched_serving_matches_dequantized():
     """The batched serving engine (the --mode serve --batched path that a
     --quant server runs) must match its dequantized twin token-for-token."""
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-        ROLE_FULL,
-        StageSpec,
-    )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-        BatchedStageExecutor,
-    )
 
     cfg = tiny_cfg()
     params = init_params(jax.random.PRNGKey(3), cfg)

@@ -11,7 +11,6 @@ import time
 
 import jax
 import numpy as np
-import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
@@ -28,9 +27,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PipelineClient,
     make_server_record,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
     RegistryServer,
     RemoteRegistry,
@@ -41,7 +38,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     ServerRecord,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, tiny_cfg
 
 
 def _rec(peer, stage=1, addr="127.0.0.1:1"):

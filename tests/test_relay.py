@@ -33,9 +33,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PipelineClient,
     make_server_record,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
     StageRequest,
 )
@@ -74,7 +72,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     events,
 )
 
-from test_runtime_pipeline import build_cluster, oracle_generate, tiny_cfg
+from engines import build_cluster, oracle_generate, tiny_cfg
 
 # An address nothing listens on: direct dials fail instantly (ECONNREFUSED),
 # which is both the NAT model for these tests (advertised-but-unroutable)

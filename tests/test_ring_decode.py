@@ -12,11 +12,11 @@ import numpy as np
 import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
     init_kv_cache,
     init_params,
     llama_config,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.pipeline import (
     IciPipeline,
 )
@@ -430,7 +430,7 @@ def test_ring_decode_gemma2_embed_scale_and_semantics():
     sqrt(hidden) scale (fixed by routing through the shared embed_tokens):
     ring decode of a gemma2 config (embed scale, sandwich norms, softcaps,
     alternating per-layer windows) must match the per-session oracle."""
-    from test_runtime_pipeline import tiny_cfg as shared_tiny_cfg
+    from engines import tiny_cfg as shared_tiny_cfg
 
     cfg = shared_tiny_cfg("gemma2")  # 4 layers, biting softcaps, window=4
     params = init_params(jax.random.PRNGKey(2), cfg)

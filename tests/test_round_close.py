@@ -19,7 +19,7 @@ import jax
 import numpy as np
 import pytest
 
-from test_runtime_pipeline import tiny_cfg
+from engines import engine, tiny_cfg
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
     SamplingParams,
@@ -265,22 +265,27 @@ def test_who_is_not_waited_for(kind, why):
     if why in ("eos", "repeat"):
         eng.stops["b"] = why
     a_waits = 2 * BOUND_S if why == "old_reply" else TURN_S
-    a = Client(ad, "a", kind, [0.0, a_waits])
+    asks_again, dropped = threading.Event(), []
+    a = Client(ad, "a", kind, [0.0, a_waits], gates={1: asks_again.set})
     b = Client(ad, "b", kind, [0.0],
                budgets={0: TICKS - 1} if why == "short_budget" else None)
     if why == "dropped_meanwhile":
-        dropper = threading.Timer(
-            ROUND_S + 4 * BOUND_S + TURN_S + 0.1,
-            lambda: ad.drop_session("b"))
-        dropper.daemon = True
-        dropper.start()
+
+        def drop():             # 0.05 s into the leader's wait for ``b``
+            asks_again.wait(30.0)
+            time.sleep(0.05)
+            dropped.append(time.monotonic())
+            ad.drop_session("b")
+
+        threading.Thread(target=drop, daemon=True).start()
     run_all(a, b)
     assert [r[1] for r in eng.rounds] == [["a", "b"], ["a"]]
     held = eng.rounds[1][0] - a.sent[1]
     if why == "dropped_meanwhile":
-        # waited for (0.8 s allowed), let go by the drop 0.1 s in
+        # waited for (0.8 s allowed), let go by the drop and at once: by the
+        # dropper's own stamp, not by how long the machine let it sleep
         assert closed(ad) == {"window": 1, "joined": 1}
-        assert 0.05 < held < 0.1 + SLACK_S
+        assert 0.0 <= eng.rounds[1][0] - dropped[0] < SLACK_S
         assert "b" not in ad._replied and ad.inner.slot("b") is None
     else:
         assert closed(ad) == {"window": 2}
@@ -433,8 +438,7 @@ def test_a_session_s_sampled_tokens_do_not_depend_on_the_round(tiny, kind):
     cfg, spec, params = tiny
 
     def run(sids):
-        eng = batching.BatchedStageExecutor(cfg, spec, params, slots=4,
-                                            max_len=32)
+        eng = engine(cfg, spec, params, slots=4, max_len=32)
         ad = batching.BatchingStageAdapter(eng, window_s=0.5)
         reg = MetricsRegistry(enabled=True)
         ad._m_fill = catalog.get("server_batch_fill_sessions", reg)

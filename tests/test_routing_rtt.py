@@ -11,11 +11,9 @@ coverage.
 import random
 
 import jax
-import jax.numpy as jnp
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
     init_params,
-    llama_config,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
     ROLE_LAST,
@@ -31,9 +29,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
     PipelineClient,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.server import (
     measure_next_server_rtts,
 )
@@ -50,7 +46,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     route_cost,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, tiny_cfg
 
 
 def rec(peer, start, end, *, thr=1.0, final=False, rtts=None,

@@ -7,147 +7,11 @@ comparing logs). Here the whole 4-stage pipeline runs in one process over
 `full_forward` oracle (the ``scripts/single_gpu_check.py`` role, automated).
 """
 
-import random
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
-    gpt2_config,
-    init_kv_cache,
-    init_params,
-    llama_config,
-)
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
-    StagePlan,
-    parse_splits,
-    slice_stage_params,
-)
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.ops.sampling import (
-    RECENT_WINDOW,
     SamplingParams,
-    sample_token,
-)
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
-    PipelineClient,
-    make_server_record,
-)
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.transport import (
-    LocalTransport,
-)
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.scheduling.registry import (
-    PlacementRegistry,
 )
 
-
-def kernel_cfg():
-    """Llama-shaped, every matmul site eligible for the Pallas kernels (K
-    and N multiples of 128 in the engine-fused layout)."""
-    return llama_config(vocab_size=128, hidden_size=128, num_layers=2,
-                        num_heads=4, num_kv_heads=2, intermediate_size=256,
-                        max_position_embeddings=32)
-
-
-def tiny_cfg(family="llama"):
-    if family == "gpt2":
-        return gpt2_config(vocab_size=257, hidden_size=64, num_layers=8,
-                           num_heads=4, max_position_embeddings=256)
-    if family == "qwen2":
-        from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-            qwen2_config,
-        )
-
-        return qwen2_config(vocab_size=257, hidden_size=64, num_layers=8,
-                            num_heads=4, num_kv_heads=2, intermediate_size=128,
-                            max_position_embeddings=256)
-    if family == "gemma2":
-        from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-            gemma2_config,
-        )
-
-        # Small softcaps so dropping them would change tokens (the
-        # production 50/30 sit in tanh's linear region on tiny models);
-        # window=4 actually truncates at these sequence lengths.
-        return gemma2_config(vocab_size=257, hidden_size=64, num_layers=4,
-                             num_heads=4, num_kv_heads=2,
-                             intermediate_size=128, head_dim=32,
-                             sliding_window=4, query_pre_attn_scalar=16.0,
-                             attn_softcap=2.0, final_softcap=3.0,
-                             max_position_embeddings=256)
-    if family == "mistral-window":
-        from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-            mistral_config,
-        )
-
-        # One sliding window for every layer; 4 truncates at these lengths.
-        return mistral_config(sliding_window=4, vocab_size=257,
-                              hidden_size=64, num_layers=8, num_heads=4,
-                              num_kv_heads=2, intermediate_size=128,
-                              max_position_embeddings=256)
-    return llama_config(vocab_size=257, hidden_size=64, num_layers=8,
-                        num_heads=4, num_kv_heads=2, intermediate_size=128,
-                        max_position_embeddings=256)
-
-
-def build_cluster(cfg, splits="3,6", replicas=1, seed=0):
-    params = init_params(jax.random.PRNGKey(seed), cfg)
-    plan = StagePlan.from_splits(cfg.num_layers, parse_splits(splits))
-    transport = LocalTransport()
-    registry = PlacementRegistry(rng=random.Random(seed))
-    for spec in plan.stages[1:]:
-        for r in range(replicas):
-            peer = f"peer-s{spec.index}-r{r}"
-            ex = StageExecutor(cfg, spec, slice_stage_params(cfg, params, spec),
-                               peer_id=peer)
-            transport.add_peer(peer, ex)
-            registry.register(make_server_record(peer, spec))
-    stage0 = StageExecutor(cfg, plan.stages[0],
-                           slice_stage_params(cfg, params, plan.stages[0]),
-                           peer_id="client-local")
-    client = PipelineClient(cfg, plan, stage0, transport, registry,
-                            settle_seconds=0.0, seed=seed)
-    return client, transport, registry, params, plan
-
-
-def oracle_generate(cfg, params, prompt_ids, max_new_tokens, sampling, seed=0,
-                    max_len=256):
-    """Unpartitioned reference loop with identical sampling semantics."""
-    kc, vc = init_kv_cache(cfg, cfg.num_layers, 1, max_len)
-    ids = jnp.asarray(np.asarray(prompt_ids, np.int32)[None, :])
-    generated = []
-    cache_len = jnp.int32(0)
-    logits, kc, vc = full_forward(cfg, params, ids, kc, vc, cache_len)
-    cur_len = len(prompt_ids)
-
-    def pick(logits_last, step):
-        recent = np.zeros((RECENT_WINDOW,), np.int32)
-        n = min(len(generated), RECENT_WINDOW)
-        if n:
-            recent[:n] = np.asarray(generated[-n:], np.int32)
-        return int(sample_token(
-            jax.random.PRNGKey(seed + step),
-            logits_last,
-            jnp.asarray(recent), jnp.asarray(n, jnp.int32),
-            jnp.asarray(sampling.temperature, jnp.float32),
-            jnp.asarray(sampling.top_p, jnp.float32),
-            jnp.asarray(sampling.top_k, jnp.int32),
-            jnp.asarray(sampling.repetition_penalty, jnp.float32),
-        ))
-
-    generated.append(pick(logits[0, cur_len - 1], 0))
-    for step in range(1, max_new_tokens):
-        if len(generated) >= 5 and len(set(generated[-5:])) == 1:
-            break
-        nxt = jnp.asarray([[generated[-1]]], jnp.int32)
-        logits, kc, vc = full_forward(cfg, params, nxt, kc, vc, jnp.int32(cur_len))
-        generated.append(pick(logits[0, 0], step))
-        cur_len += 1
-    return generated
+from engines import build_cluster, oracle_generate, tiny_cfg
 
 
 def test_pipeline_greedy_matches_oracle():

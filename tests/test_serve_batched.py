@@ -13,8 +13,6 @@ import random
 import threading
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
@@ -29,16 +27,14 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     SamplingParams,
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.batching import (
-    BatchedStageExecutor,
     BatchingStageAdapter,
 )
+from engines import engine as BatchedStageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
     PipelineClient,
     make_server_record,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
     RegistryServer,
     RemoteRegistry,
@@ -50,7 +46,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     ServerRecord,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, tiny_cfg
 
 SPLITS = "2,4"   # 8 layers -> stage0 [0,2) client, stage1 [2,4), stage2 [4,8) final
 

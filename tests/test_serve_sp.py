@@ -35,8 +35,8 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 )
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
     StageExecutionError,
-    StageExecutor,
 )
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.kv_cache import (
     KVArena,
 )
@@ -50,7 +50,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     SpStageAdapter,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, tiny_cfg
 
 SP = 4
 PROMPT_LEN = 96

@@ -42,7 +42,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     TcpTransport,
 )
 
-from test_runtime_pipeline import oracle_generate, tiny_cfg
+from engines import oracle_generate, tiny_cfg
 
 
 def _tp_mesh(n):

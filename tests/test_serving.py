@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from test_runtime_pipeline import tiny_cfg
+from engines import tiny_cfg
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
     telemetry,
@@ -52,9 +52,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.client import (
     make_server_record,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.messages import (
     StageRequest,
 )

@@ -13,13 +13,13 @@ import numpy as np
 from jax.sharding import Mesh
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models import (
-    full_forward,
     gpt2_config,
     init_kv_cache,
     init_params,
     llama_config,
     qwen2_config,
 )
+from engines import full_forward
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.models.partition import (
     ROLE_FULL,
     StagePlan,

@@ -32,9 +32,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PipelineClient,
     make_server_record,
 )
-from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-    StageExecutor,
-)
+from engines import stage_executor as StageExecutor
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.speculative import (
     ngram_draft,
 )
@@ -45,7 +43,7 @@ from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.
     PlacementRegistry,
 )
 
-from test_runtime_pipeline import build_cluster, oracle_generate, tiny_cfg
+from engines import build_cluster, oracle_generate, tiny_cfg
 
 GREEDY = SamplingParams(temperature=0.0)
 PROMPT = [5, 9, 23, 7, 81]
@@ -352,7 +350,7 @@ def test_speculative_generation_with_sampling_runs():
         SamplingParams,
     )
 
-    from test_runtime_pipeline import build_cluster, tiny_cfg
+    from engines import build_cluster, tiny_cfg
 
     cfg = tiny_cfg()
     client, _, _, _, _ = build_cluster(cfg)

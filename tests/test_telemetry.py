@@ -16,7 +16,7 @@ import threading
 
 import jax
 
-from test_runtime_pipeline import build_cluster, tiny_cfg
+from engines import build_cluster, tiny_cfg
 
 from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu import (
     telemetry,
@@ -260,9 +260,7 @@ def test_tcp_metrics_verb_and_trace_over_wire():
         PipelineClient,
         make_server_record,
     )
-    from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.executor import (
-        StageExecutor,
-    )
+    from engines import stage_executor as StageExecutor
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.runtime.net import (
         RegistryServer,
         RemoteRegistry,

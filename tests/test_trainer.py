@@ -47,7 +47,8 @@ def test_pipeline_loss_matches_oracle(num_stages, num_micro, tp):
     params = init_params(jax.random.PRNGKey(0), cfg)
     ids, targets = make_batch(cfg, num_micro, 2, 16)
 
-    oracle = float(single_device_loss(cfg, params, ids, targets))
+    oracle = float(jax.jit(lambda p: single_device_loss(
+        cfg, p, ids, targets))(params))
 
     mesh_devs = jax.devices()[: num_stages * tp]
     from global_capstone_design_distributed_inference_of_llms_over_the_internet_tpu.parallel.pipeline import (
@@ -76,7 +77,7 @@ def test_pipeline_grads_match_oracle():
         p2["embed"] = dict(params["embed"], wte=wte)
         return single_device_loss(cfg, p2, ids, targets)
 
-    g_oracle = jax.grad(oracle_loss)(params["embed"]["wte"])
+    g_oracle = jax.jit(jax.grad(oracle_loss))(params["embed"]["wte"])
 
     tr = PipelineTrainer.build(cfg, params, num_stages=num_stages,
                                num_micro=num_micro, lr=0.0)
@@ -104,7 +105,8 @@ def test_interleaved_loss_matches_oracle(num_stages, num_micro, virtual):
     params = init_params(jax.random.PRNGKey(4), cfg)
     ids, targets = make_batch(cfg, num_micro, 2, 12, seed=11)
 
-    oracle = float(single_device_loss(cfg, params, ids, targets))
+    oracle = float(jax.jit(lambda p: single_device_loss(
+        cfg, p, ids, targets))(params))
     tr = PipelineTrainer.build(cfg, params, num_stages=num_stages,
                                num_micro=num_micro, lr=0.0,
                                virtual_stages=virtual)
@@ -126,7 +128,7 @@ def test_interleaved_grads_match_oracle():
         p2["embed"] = dict(params["embed"], wte=wte)
         return single_device_loss(cfg, p2, ids, targets)
 
-    g_oracle = jax.grad(oracle_loss)(params["embed"]["wte"])
+    g_oracle = jax.jit(jax.grad(oracle_loss))(params["embed"]["wte"])
     tr = PipelineTrainer.build(cfg, params, num_stages=num_stages,
                                num_micro=num_micro, lr=0.0,
                                virtual_stages=virtual)
