@@ -184,7 +184,9 @@ REJOIN_SHARE = 1.0 / 4
 STALL_FACTOR = 4.0
 # The parts of a burst round the phase profiler brackets
 # (`BatchedStageExecutor.burst_parts`); a stall's rest is ``other``.
-STALL_PARTS = ("build", "dispatch", "device", "readback")
+# ``queued``: enqueue returned -> a prompt's programs ahead of the burst
+# finished; ``device`` is from there to the results.
+STALL_PARTS = ("build", "dispatch", "queued", "device", "readback")
 
 # The client's repeat-stop heuristic (runtime.client.REPEAT_STOP), mirrored
 # on device so a burst truncates exactly where the sequential host loop
@@ -1833,6 +1835,15 @@ class BatchedStageExecutor:
     # The last burst's seconds by `STALL_PARTS`, where the phase profiler
     # measured them (`decode_burst`); None with it off.
     burst_parts: Optional[Dict[str, float]] = None
+    # With telemetry on (the registry's switch), the LAST device result of
+    # the prompt's programs a `prefill` enqueued since the last round's
+    # dispatch (caller holds the adapter's lock, as for every slot table);
+    # None with it off, always.
+    _registry = _tm.get_registry()
+    _ahead = None
+    # Whether the last round's dispatch found that result unfinished: its
+    # program sits behind a prompt's on the one in-order device queue.
+    behind_prefill = False
 
     def __init__(
         self,
@@ -2477,8 +2488,22 @@ class BatchedStageExecutor:
         computed suffix (final stage: suffix only — it samples from the
         last row and stores no outputs)."""
         if self.prefix_store is not None and prefix_len > 0:
-            return self._prefill_with_store(session_id, x, prefix_len)
-        return self._prefill_full(session_id, x)
+            h = self._prefill_with_store(session_id, x, prefix_len)
+        else:
+            h = self._prefill_full(session_id, x)
+        if self._registry.enabled:
+            self._ahead = h
+        return h
+
+    def _dispatched(self):
+        """A round's program has just been enqueued: the prompt's result
+        kept since the last round (`prefill`), taken and cleared, and
+        whether this round queues behind it (``behind_prefill``: a
+        non-blocking query; a prompt whose programs ran while the sessions
+        were on their way back is finished and does not count)."""
+        ahead, self._ahead = self._ahead, None
+        self.behind_prefill = ahead is not None and not ahead.is_ready()
+        return ahead
 
     def _prefill_with_store(self, session_id: str, x,
                             prefix_len: int) -> jnp.ndarray:
@@ -2730,6 +2755,7 @@ class BatchedStageExecutor:
         h, self.k, self.v = step(
             self.params, jnp.asarray(x), jnp.asarray(self.lengths.copy()),
             jnp.asarray(active), self.k, self.v)
+        self._dispatched()
         self._count_attn_rows(self.lengths[None], active[None], t)
         for s in rows:
             self.lengths[s] += t
@@ -3088,10 +3114,17 @@ class BatchedStageExecutor:
                 extra = [self._rider_args(rider, n_ticks)]
         # Profiled: a fenced dispatch. The device phase is dispatch-to-ready
         # and the bubble gauge charges idle time between successive readies.
+        # Inside it ``device_queued``: the wait for a prompt's programs
+        # enqueued ahead (on one in-order queue their end is the burst's
+        # start; the results complete in order, so no wait is added).
         try:
             with prof.device_phase(sessions=n) as ran:
                 with prof.phase("dispatch", sessions=n) as issued:
                     out = fn(self.params, *args, self.k, self.v, *extra)
+                ahead = self._dispatched()
+                with prof.phase("device_queued", sessions=n) as queued:
+                    if prof.enabled:
+                        jax.block_until_ready(ahead)     # None: no wait
                 if prof.enabled:
                     jax.block_until_ready(out)
         except Exception:
@@ -3111,9 +3144,11 @@ class BatchedStageExecutor:
                 self.lengths[rider["slot"]] = t
                 res[rider["session_id"]] = {"token": token, "cache_len": t}
         # What this burst's wall time was made of, where the profiler has
-        # just measured it (``device``: enqueue returned -> results ready).
+        # just measured it (``queued`` + ``device``: enqueue returned ->
+        # results ready).
         self.burst_parts = dict(zip(STALL_PARTS, (
-            built.seconds, issued.seconds, ran.seconds - issued.seconds,
+            built.seconds, issued.seconds, queued.seconds,
+            ran.seconds - issued.seconds - queued.seconds,
             read.seconds))) if prof.enabled else None
         return res
 
@@ -3248,6 +3283,7 @@ class BatchingStageAdapter:
         self._m_ids_read = _tm.get("server_burst_transfers_total").labels(
             dir="down")
         self._m_round = _tm.get("server_decode_round_seconds")
+        self._m_behind = _tm.get("server_round_behind_prefill_seconds")
         self._m_closed = _tm.get("server_round_closed_total")
         self._m_rejoin = _tm.get("server_round_rejoin_seconds")
         self._m_period = _tm.get("server_round_period_seconds")
@@ -3492,13 +3528,19 @@ class BatchingStageAdapter:
         """Caller holds the lock; the step of round ``r`` has run and its
         results are on the host: time it, and note which sessions (``back``)
         will ask for the next round of ``key``. ``parts``: what the engine
-        measured of the step (`BatchedStageExecutor.burst_parts`)."""
+        measured of the step (`BatchedStageExecutor.burst_parts`). A round
+        whose program the engine found behind a prompt's
+        (``behind_prefill``) is timed a second time, in a series of those
+        rounds alone: the clear rounds are the difference of the two."""
         r.t_done = time.monotonic()
         wall = r.t_done - r.t_exec
         self._m_round.observe(wall)
+        behind = self.inner.behind_prefill
+        if behind:
+            self._m_behind.observe(wall)
         last = self._last_round.get(key)
         if last and wall > STALL_FACTOR * last[1]:
-            self._stalled(r, key, wall, last[1], parts)
+            self._stalled(r, key, wall, last[1], parts, behind)
         if self._events.enabled:
             self._gc_seen = _gc_counts()
         self._last_round[key] = (r.t_done, wall)
@@ -3507,14 +3549,16 @@ class BatchingStageAdapter:
             self._replied_locked(s_id, key, r.t_done)
 
     def _stalled(self, r: _Round, key, wall: float, last_wall: float,
-                 parts) -> None:
+                 parts, behind: bool) -> None:
         """Round ``r`` took over `STALL_FACTOR` x the last round of ``key``:
         count it, add its seconds by part (``other``: what no phase of the
         profiler covers; all of it with the profiler off) and leave ONE
-        event that says what it was made of."""
+        event that says what it was made of. ``behind``: its program was
+        enqueued behind a prompt's, so the stall is the device's queue (a
+        long prompt ahead of a short round) and no fault."""
         by_part = {p: (parts or {}).get(p, 0.0) for p in STALL_PARTS}
         by_part["other"] = max(0.0, wall - sum(by_part.values()))
-        self._m_stalls.inc()
+        self._m_stalls.labels(behind_prefill=str(behind).lower()).inc()
         for part, seconds in by_part.items():
             self._m_stall_s.labels(part=part).inc(seconds)
         burst = isinstance(key, tuple)
@@ -3522,6 +3566,7 @@ class BatchingStageAdapter:
             "round_stall", wall_s=round(wall, 6),
             last_wall_s=round(last_wall, 6), sessions=len(r.reqs),
             ticks=key[1] if burst else 1, rider=r.rider is not None,
+            behind_prefill=behind,
             gc_collections=(None if self._gc_seen is None else [
                 now - was for now, was in zip(_gc_counts(), self._gc_seen)]),
             **{p + "_s": round(s, 6) for p, s in by_part.items()})
@@ -3562,7 +3607,8 @@ class BatchingStageAdapter:
             return self._prefill_riding(req)
         # A request's life up to its first token, as three phases: the wait
         # for the lock (a round leader holds it through its whole step,
-        # readback included), the prefill under it, the first token after it.
+        # readback included), the prefill under it, the first token after it
+        # (and inside that one, profiled, the prompt's programs finishing).
         with prof.phase("prefill_wait", session=sid):
             self._lock.acquire()  # slot tables + cache arrays: shared state
         t_held = self._in_a_hold_locked()
@@ -3591,6 +3637,11 @@ class BatchingStageAdapter:
             resp = self._respond(req, h, cache_len)
         else:
             with prof.phase("first_token", session=sid):
+                # Profiled: the prompt's own programs first, the head and
+                # the sampler's (behind whatever burst came meanwhile) after.
+                with prof.phase("prefill_ready", session=sid):
+                    if prof.enabled:
+                        jax.block_until_ready(h)
                 resp = self._respond(req, h, cache_len)
         # The session asks for a round from now on: on record if the lock
         # is free (a leader that is waiting then waits for this one too).
@@ -3625,7 +3676,8 @@ class BatchingStageAdapter:
         step starts (the lock, the lane if another request has it, the
         window), ``prefill`` the step under the lock, which writes the
         prompt's rows and samples the token, ``first_token`` from the
-        results on the host to the response."""
+        results on the host to the response (``prefill_ready`` inside it
+        is 0: the prompt has no program of its own to wait for)."""
         from .messages import StageResponse
 
         prof = _get_profiler()
@@ -3635,6 +3687,7 @@ class BatchingStageAdapter:
         prof.observe("prefill_wait", r.t_exec - t0)
         prof.observe("prefill", r.t_done - r.t_exec)
         prof.observe("first_token", time.monotonic() - r.t_done)
+        prof.observe("prefill_ready", 0.0)
         return self._stamped(r, StageResponse(
             session_id=req.session_id, token_id=out["token"],
             cache_len=out["cache_len"]))
@@ -3760,12 +3813,6 @@ class BatchingStageAdapter:
                             for s_id in good
                         }
                         self._answered(r, key, back, parts)
-                        if self._events.enabled and isinstance(key, tuple):
-                            self._events.emit(
-                                "burst_round", sessions=len(good),
-                                ticks=key[1],
-                                tokens=sum(len(r.outs[s_id]["tokens"])
-                                           for s_id in good))
             except Exception as exc:  # whole-round failure
                 r.err = exc
                 with self._lock:  # a dead round must not accept joiners

@@ -110,13 +110,22 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                    "join of a round under the batched engine's lock, for "
                    "the joins server_round_rejoin_seconds counts.",
         (), REJOIN_BUCKETS),
+    "server_round_behind_prefill_seconds": (
+        HISTOGRAM, "Wall time of the batched decode rounds whose program "
+                   "was enqueued behind a prompt's: a prefill's last "
+                   "device result was unfinished at the round's dispatch. "
+                   "Its count and sum are parts of "
+                   "server_decode_round_seconds'; the rest are the clear "
+                   "rounds.", (), FAST_BUCKETS),
     "server_round_stalls_total": (
         COUNTER, "Rounds whose wall time was over 4 x that of the last "
-                 "round of their width.", (), None),
+                 "round of their width, by whether their program was "
+                 "enqueued behind a prompt's (true|false).",
+        ("behind_prefill",), None),
     "server_round_stall_seconds_total": (
         COUNTER, "Wall time of those rounds by part (build|dispatch|"
-                 "device|readback|other): the burst's phases where the "
-                 "phase profiler measured them, else all of it other.",
+                 "queued|device|readback|other): the burst's phases where "
+                 "the phase profiler measured them, else all of it other.",
         ("part",), None),
     "server_tokens_total": (
         COUNTER, "Tokens processed by this stage, per phase.",
@@ -446,8 +455,8 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
     "server_phase_seconds": (
         HISTOGRAM, "Serving hot-path phase wall time from the phase "
                    "profiler, per phase (gateway_queue|prefill_wait|prefill|"
-                   "first_token|burst_build|dispatch|device|readback|socket|"
-                   "server).",
+                   "first_token|prefill_ready|burst_build|dispatch|device|"
+                   "device_queued|readback|socket|server).",
         ("phase",), FAST_BUCKETS),
     "server_device_bubble_ratio": (
         GAUGE, "Fraction of wall time the accelerator sat idle between "

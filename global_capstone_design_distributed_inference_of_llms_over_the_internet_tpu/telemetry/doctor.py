@@ -67,9 +67,11 @@ _ANOMALY_COUNTERS = (
     ("server_kv_evictions_total", "idle sessions evicted by the KV arena"),
     ("server_prefix_cache_evictions_total", "prefix-cache grains evicted"),
     ("gateway_shed_total", "requests refused by gateway admission control"),
-    ("server_round_stalls_total",
-     "batched rounds over 4 x their predecessor (round_stall events say "
-     "what each was made of)"),
+    # a stall behind a prompt's programs (behind_prefill="true") is the
+    # device's queue under long prompts: ordinary traffic, no anomaly
+    ('server_round_stalls_total{behind_prefill="false"}',
+     "batched rounds over 4 x their predecessor with no prompt's programs "
+     "ahead of them (round_stall events say what each was made of)"),
 )
 _ERR_REQ_RE = re.compile(
     r'^server_requests_total\{outcome="(error|timeout)"\} ([0-9.e+]+)',

@@ -181,18 +181,17 @@ EVENTS: Dict[str, Tuple[str, str, str]] = {
     "task_rejected": (
         "server", ERROR,
         "The task pool refused work (fields: pool, reason)."),
-    "burst_round": (
-        "server", DEBUG,
-        "A batched final stage ran one multi-tick burst dispatch (fields: "
-        "sessions, ticks, tokens)."),
     "round_stall": (
         "server", WARN,
         "A batched round took over 4 x the wall time of the last round of "
         "its width (fields: wall_s, last_wall_s, build_s, dispatch_s, "
-        "device_s = enqueue returned -> results ready, readback_s, other_s "
-        "= what no phase of the profiler covers, all of it with the "
-        "profiler off; sessions, ticks, rider, gc_collections = per "
-        "generation since the last round ended)."),
+        "queued_s = enqueue returned -> a prompt's programs ahead of the "
+        "burst finished, device_s = from there to results ready, "
+        "readback_s, other_s = what no phase of the profiler covers, all "
+        "of it with the profiler off; behind_prefill = the round's program "
+        "was enqueued behind a prompt's: a queue, not a fault; sessions, "
+        "ticks, rider, gc_collections = per generation since the last "
+        "round ended)."),
     "kv_layout": (
         "server", INFO,
         "A batched engine made its K and V cache stacks: at its start, and "

@@ -11,12 +11,18 @@ path into named phases —
                         program dispatch (the host returns at enqueue)
   * ``first_token``   — a last stage's head + host-side sampling of the
                         prefill's token, through the host read
+  * ``prefill_ready`` — inside ``first_token``: the lock released -> the
+                        prompt's programs finished on the device
   * ``burst_build``   — host-side burst argument prep (``_burst_prep``)
   * ``dispatch``      — issuing the jitted burst program (host returns as soon
                         as XLA enqueues; this is pure host overhead)
   * ``device``        — dispatch to results-ready, fenced via
                         ``block_until_ready`` so it measures the accelerator,
                         not the host's willingness to look away
+  * ``device_queued`` — inside ``device``: enqueue returned -> the prompt's
+                        programs enqueued AHEAD of the burst finished (on
+                        one in-order queue: the burst starts); 0 where
+                        nothing was ahead
   * ``readback``      — device buffers to host tokens (``_burst_collect``)
   * ``socket``        — client-observed request/response turnaround per hop
   * ``server``        — the whole serving boundary (validate + forward +
@@ -73,9 +79,11 @@ PHASES: Tuple[str, ...] = (
     "prefill_wait",
     "prefill",
     "first_token",
+    "prefill_ready",
     "burst_build",
     "dispatch",
     "device",
+    "device_queued",
     "readback",
     "socket",
     "server",

@@ -18,10 +18,15 @@ the period of a round by its parts, as three identities whose two sides
 are measured apart (``identities``: period = exec + back + hold; rejoin =
 reply + away + request, away being the client's turnaround and the wire;
 exec = host phases + launch lag + ticks + rest; the last needs
-``trace_summary.json``, which a traced run leaves); what the program
-recorded of its stalls (rounds over 4 x their predecessor, seconds by
-part); and the host-device transfers a burst round issued, up and down
-(``transfers_per_round``). One JSON line a run; reads files only."""
+``trace_summary.json``, which a traced run leaves) and the rounds split by
+whether their program was enqueued behind a prompt's (exec = share x behind
++ (1 - share) x clear, exact; queued = share x (behind - clear); clear =
+host phases + ticks + rest, the rest being the launch; a prompt's device
+time over the whole window against the traced stretch's); what the program
+recorded of its stalls (rounds over 4 x their predecessor, those behind a
+prompt apart, seconds by part); and the host-device transfers a burst
+round issued, up and down (``transfers_per_round``). One JSON line a run;
+reads files only."""
 
 import json
 import os
@@ -89,7 +94,11 @@ def buckets(ctx: dict, family: str) -> dict:
 PERIOD_METRICS = ("round_period_ms", "round_exec_ms", "round_back_ms",
                   "round_hold_ms", "hold_prefill_ms_per_round",
                   "round_rejoin_ms", "reply_leg_ms", "request_leg_ms",
-                  "engine_host_ms_per_round", "burst_launch_lag_ms")
+                  "engine_host_ms_per_round", "burst_launch_lag_ms",
+                  "round_behind_prefill_share", "round_exec_clear_ms",
+                  "round_exec_behind_prefill_ms",
+                  "burst_queued_ms_per_round", "prefill_ready_ms",
+                  "prefill_device_ms")
 
 
 def identities(ctx: dict, man: Manifest, gap_p50_ms=None) -> dict:
@@ -105,6 +114,15 @@ def identities(ctx: dict, man: Manifest, gap_p50_ms=None) -> dict:
         # the client's turnaround and the wire: what the legs leave
         v["away_ms"] = (v["round_rejoin_ms"] - v["reply_leg_ms"]
                         - v["request_leg_ms"])
+    if None not in (v["round_behind_prefill_share"],
+                    v["round_exec_behind_prefill_ms"],
+                    v["round_exec_clear_ms"]):
+        # a mean round's two kinds, and the wait that tells them apart
+        share = v["round_behind_prefill_share"] / 100.0
+        v["behind_part_ms"] = share * v["round_exec_behind_prefill_ms"]
+        v["clear_part_ms"] = (1.0 - share) * v["round_exec_clear_ms"]
+        v["queue_ms"] = share * (v["round_exec_behind_prefill_ms"]
+                                 - v["round_exec_clear_ms"])
 
     def line(whole, *parts):
         vals = [v.get(n) for n in (whole,) + parts]
@@ -124,6 +142,17 @@ def identities(ctx: dict, man: Manifest, gap_p50_ms=None) -> dict:
            "exec = host + lag + ticks + rest": line(
                "round_exec_ms", "engine_host_ms_per_round",
                "burst_launch_lag_ms", "ticks_ms"),
+           "exec = share x behind + (1 - share) x clear": line(
+               "round_exec_ms", "behind_part_ms", "clear_part_ms"),
+           "queued = share x (behind - clear)": line(
+               "burst_queued_ms_per_round", "queue_ms"),
+           # the rest is the launch: 2-4 ms where nothing is in its way
+           "clear = host + ticks + rest": line(
+               "round_exec_clear_ms", "engine_host_ms_per_round",
+               "ticks_ms"),
+           # one quantity, the whole window against the traced stretch
+           "prefill_ready = prefill_device + rest": line(
+               "prefill_ready_ms", "prefill_device_ms"),
            # every prefill program's time under the lock a round (traced
            # runs: the phase), and the part of it that fell into a hold;
            # the rest fell between a round's results and the next's opening
@@ -145,8 +174,18 @@ def stalls_report(ctx: dict) -> dict:
     parts = sorted({re.search(r'part="([^"]+)"', k).group(1)
                     for peer in ctx["counters_after"].values() for k in peer
                     if k.startswith(fam + "{")})
-    return {"rounds": readers.counter_delta(
-                ctx, "server_round_stalls_total"),
+    if readers.counter_delta(
+            ctx, "server_round_behind_prefill_seconds_count") is None:
+        # a program before the label: every stall is one to look into
+        faults = readers.counter_delta(ctx, "server_round_stalls_total")
+        queues = None
+    else:                     # a child is there once it has counted
+        faults, queues = (readers.counter_delta(
+            ctx, f'server_round_stalls_total{{behind_prefill="{b}"}}') or 0.0
+            for b in ("false", "true"))
+    return {"rounds": faults,
+            # a long prompt ahead of a short round: a queue, no fault
+            "rounds_behind_prefill": queues,
             "seconds_by_part": {
                 p: readers.counter_delta(ctx, f'{fam}{{part="{p}"}}')
                 for p in parts}}
@@ -166,7 +205,9 @@ def transfers_report(ctx: dict) -> dict:
 
 
 def rounds_report(run_dir: str, man: Manifest, gap_p50_ms=None) -> dict:
-    ctx = {"counters_before": load_counters(
+    w0, w1 = window_of(run_dir)
+    ctx = {"w0": w0, "w1": w1,
+           "counters_before": load_counters(
                os.path.join(run_dir, "metrics_before.jsonl")),
            "counters_after": load_counters(
                os.path.join(run_dir, "metrics_after.jsonl"))}

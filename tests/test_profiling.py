@@ -410,9 +410,10 @@ def test_stage_engine_phases_and_slots_held(monkeypatch, burst):
     adapter, prof, reg, spans = _stage_adapter(monkeypatch, profiled=True)
     _prefill_then_round(adapter, prof, burst)
     snap = prof.snapshot()
-    for phase in ("prefill_wait", "prefill", "first_token"):
+    for phase in ("prefill_wait", "prefill", "first_token", "prefill_ready"):
         assert snap[phase]["count"] == 1, phase
-    round_phases = {"burst_build", "dispatch", "device", "readback"}
+    round_phases = {"burst_build", "dispatch", "device", "device_queued",
+                    "readback"}
     assert round_phases & set(snap) == (round_phases if burst else set())
     # Mirrored into server_phase_seconds{phase}, which the benchmark reads.
     mirrored = {dict(h.labels)["phase"]: h.count
@@ -427,7 +428,7 @@ def test_stage_engine_phases_and_slots_held(monkeypatch, burst):
     # with no statistic.
     names = {n for n, _ in spans.made}
     want = {"stage.prefill_wait", "stage.prefill", "stage.first_token",
-            "stage.round_window"}
+            "stage.prefill_ready", "stage.round_window"}
     if burst:
         want |= {"stage." + p for p in round_phases}
     assert names == want
@@ -515,11 +516,12 @@ def test_round_follower_wait_is_a_span(monkeypatch):
     assert not [n for n in names if "wait" in n]
     assert sorted(names) == sorted(
         "stage." + p for p in ("round_window", "burst_build", "device",
-                               "dispatch", "readback"))
+                               "device_queued", "dispatch", "readback"))
     assert ("stage.dispatch", {"sessions": 2}) in spans.made
     # what the engine keeps of the burst for a stall's record
     parts = adapter.inner.burst_parts
-    assert sorted(parts) == ["build", "device", "dispatch", "readback"]
+    assert sorted(parts) == ["build", "device", "dispatch", "queued",
+                             "readback"]
     assert min(parts.values()) >= 0.0
     assert (adapter._m_fill.sum, adapter._m_held.sum) == (2.0, 2.0)
 

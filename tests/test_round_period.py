@@ -71,6 +71,13 @@ class Seen:
         self.values.append(v)
 
 
+def stalls(ad):
+    """The stalls counted so far, by whether the round was behind a
+    prompt's programs."""
+    return {dict(c.labels)["behind_prefill"]: int(c.value)
+            for c in ad._m_stalls.children() if c.value}
+
+
 def make(kind, *, on=True, window_s=WINDOW_S, engine=SlotsOnly, **kw):
     """An adapter over the slot tables whose series live in a registry of
     the test's own (``on`` False: one that is switched off) and whose
@@ -203,7 +210,8 @@ def test_a_rider_runs_no_program_and_is_not_a_prefill_in_the_hold():
     assert out["first"].t_done > 0.0
 
 
-PARTS = {"build": 0.004, "dispatch": 0.002, "device": 0.25, "readback": 0.01}
+PARTS = {"build": 0.004, "dispatch": 0.002, "queued": 0.0, "device": 0.25,
+         "readback": 0.01}
 
 
 class SkewedClock:
@@ -257,8 +265,7 @@ def test_a_stalled_round_says_what_it_was_made_of(monkeypatch, kind,
     seat(ad, "a", "b")
     for _ in range(2):
         ask(ad, "a", kind)                        # rounds of 0.12 s
-    assert ad._m_stalls.value == 0 and len(ad._events) == (
-        2 if kind == "burst" else 0)
+    assert stalls(ad) == {} and len(ad._events) == 0
     eng.round_s = 5 * ROUND_S
     if profiled:
         eng.burst_parts = dict(PARTS)
@@ -271,7 +278,7 @@ def test_a_stalled_round_says_what_it_was_made_of(monkeypatch, kind,
     assert eng.rounds[2][1] == ["a", "b"]
     eng.round_s = ROUND_S
     ask(ad, "a", kind)                 # 1/5 of the last: no stall either
-    assert ad._m_stalls.value == 1
+    assert stalls(ad) == {"false": 1}
     by_part = {dict(c.labels)["part"]: c.value
                for c in reg.get("server_round_stall_seconds_total").children()}
     assert set(by_part) == set(batching.STALL_PARTS) | {"other"}
@@ -283,10 +290,11 @@ def test_a_stalled_round_says_what_it_was_made_of(monkeypatch, kind,
     f = ev.fields
     assert {p + "_s" for p in by_part} | {
         "wall_s", "last_wall_s", "sessions", "ticks", "rider",
-        "gc_collections"} == set(f)
+        "behind_prefill", "gc_collections"} == set(f)
     assert f["wall_s"] == pytest.approx(wall, abs=1e-5)
     assert f["last_wall_s"] == pytest.approx(ROUND_S, abs=0.05)
-    assert (f["sessions"], f["rider"]) == (2, False)
+    assert (f["sessions"], f["rider"], f["behind_prefill"]) == (
+        2, False, False)
     assert f["ticks"] == (TICKS if kind == "burst" else 1)
     assert f["device_s"] == (PARTS["device"] if profiled else 0.0)
     assert len(f["gc_collections"]) == 3 and min(f["gc_collections"]) >= 0
@@ -295,9 +303,8 @@ def test_a_stalled_round_says_what_it_was_made_of(monkeypatch, kind,
 @pytest.mark.parametrize("kind", KINDS)
 def test_with_the_registry_and_the_recorder_off_nothing_is_kept(kind):
     """The dark path: every series stays empty, no event is kept, the
-    engine keeps no parts, no collector count is read, and the burst
-    round's event is not even built (its arguments are a sum under the
-    lock); a stall, once in many rounds, is the one thing handed on."""
+    engine keeps no parts, no collector count is read, and a round builds
+    no event; a stall, once in many rounds, is the one thing handed on."""
     ad, eng, reg = make(kind, on=False)
     built, emit = [], ad._events.emit
     ad._events.emit = lambda name, **kw: (built.append(name),
@@ -311,7 +318,7 @@ def test_with_the_registry_and_the_recorder_off_nothing_is_kept(kind):
         m = getattr(ad, attr)
         if hasattr(m, "count"):
             assert (m.count, m.sum) == (0, 0.0), attr
-    assert ad._m_stalls.value == 0
+    assert stalls(ad) == {}
     assert not any(c.value for c in reg.get(
         "server_round_stall_seconds_total").children())
     assert built == ["round_stall"] and len(ad._events) == 0
