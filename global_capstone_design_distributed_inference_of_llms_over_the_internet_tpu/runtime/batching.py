@@ -59,6 +59,7 @@ the same.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import threading
@@ -1829,6 +1830,27 @@ def _decode_span(cfg, spec, params, x, positions, lengths, active, k_all,
     return _run_passes(cfg, params, h, one_pass, k_all, v_all)
 
 
+@dataclasses.dataclass
+class _Burst:
+    """One burst between its enqueue and its collect
+    (`BatchedStageExecutor.burst_enqueue` / `burst_fetch` /
+    `burst_collect`): the sessions' rows, the packed result on the device
+    and, once fetched, on the host (``flat``; ``error``: what the fetch
+    failed with), the rider with its slot, the prompt's result that lay
+    ahead of it, the open phase ``device`` and the brackets that timed its
+    parts."""
+
+    rows: Dict[str, int]
+    packed: Any
+    n_ticks: int
+    rider: Optional[dict]
+    ahead: Any
+    fence: contextlib.ExitStack
+    timed: tuple
+    flat: Optional[np.ndarray] = None
+    error: Optional[Exception] = None
+
+
 class BatchedStageExecutor:
     """One stage span serving up to `slots` sessions with batched decode."""
 
@@ -3008,13 +3030,15 @@ class BatchedStageExecutor:
 
     _BURST_STOPS = {0: None, 1: "eos", 2: "repeat"}
 
-    def _burst_collect(self, rows: Dict[str, int], packed, n_ticks: int):
-        """Read one burst's packed results back (`_build_burst`): the only
-        host sync per burst, and ONE read. Returns the sessions' results
-        and the rider's first token (None: an engine without a lane)."""
+    def _burst_collect(self, rows: Dict[str, int], flat: np.ndarray,
+                       n_ticks: int):
+        """One burst's packed results (`_build_burst`), read to the host as
+        ``flat`` (`burst_fetch`: the only host sync per burst, and ONE
+        read), into the slot tables and the counters. Returns the sessions'
+        results and the rider's first token (None: an engine without a
+        lane). It writes ``lengths`` of its own rows only: a prompt that
+        took another slot while the burst ran keeps what it wrote."""
         S = self.slots
-        flat = np.asarray(packed)
-        self._m_transfers.labels(dir="down").inc()
         toks_np = flat[:n_ticks * S].reshape(n_ticks, S)
         stop_np, len_np = flat[n_ticks * S:(n_ticks + 2) * S].reshape(2, S)
         tail = flat[(n_ticks + 2) * S:]     # passes (looped), token (lane)
@@ -3095,9 +3119,27 @@ class BatchedStageExecutor:
         temperature, top_p, top_k, repetition_penalty}``. It gets a slot,
         its prompt's K/V rows are written by the burst's ticks, and its
         entry of the result is ``{token, cache_len}``: the first token,
-        sampled on the device as a prefill's is on the host."""
+        sampled on the device as a prefill's is on the host.
+
+        The burst's three steps in turn (`burst_enqueue`, `burst_fetch`,
+        `burst_collect`), for a caller that shares the engine with nobody:
+        the adapter's round leader calls them itself and lets go of its
+        lock for the middle one."""
         if not entries and rider is None:
             return {}
+        flight = self.burst_enqueue(entries, n_ticks, rider)
+        self.burst_fetch(flight)
+        return self.burst_collect(flight)
+
+    def burst_enqueue(self, entries: Dict[str, dict], n_ticks: int,
+                      rider: Optional[dict] = None) -> "_Burst":
+        """The first half of `decode_burst`: the arguments built, the
+        rider's slot taken, the program ENQUEUED and the stacks it will
+        return put in place of those it was given, so that whatever is
+        enqueued next (another session's prompt) runs behind it on the
+        device's one in-order queue. Nothing here waits for the device, so
+        the adapter's lock is held for host work only. Returns the burst in
+        flight, for `burst_fetch` and `burst_collect`."""
         prof = _get_profiler()
         n = len(entries)
         extra = []
@@ -3113,43 +3155,95 @@ class BatchedStageExecutor:
             if self.rider_rows:
                 extra = [self._rider_args(rider, n_ticks)]
         # Profiled: a fenced dispatch. The device phase is dispatch-to-ready
-        # and the bubble gauge charges idle time between successive readies.
-        # Inside it ``device_queued``: the wait for a prompt's programs
-        # enqueued ahead (on one in-order queue their end is the burst's
-        # start; the results complete in order, so no wait is added).
+        # (`burst_fetch` closes it) and the bubble gauge charges idle time
+        # between successive readies.
         try:
-            with prof.device_phase(sessions=n) as ran:
+            with contextlib.ExitStack() as opened:
+                ran = opened.enter_context(prof.device_phase(sessions=n))
                 with prof.phase("dispatch", sessions=n) as issued:
-                    out = fn(self.params, *args, self.k, self.v, *extra)
-                ahead = self._dispatched()
-                with prof.phase("device_queued", sessions=n) as queued:
-                    if prof.enabled:
-                        jax.block_until_ready(ahead)     # None: no wait
-                if prof.enabled:
-                    jax.block_until_ready(out)
+                    packed, self.k, self.v = fn(
+                        self.params, *args, self.k, self.v, *extra)
+                fence = opened.pop_all()      # stays open: `burst_fetch`
         except Exception:
             if rider is not None:
                 self._recover_slot(rider["session_id"], rider["slot"])
             raise
-        packed, self.k, self.v = out
+        ahead = self._dispatched()
         self._m_transfers.labels(dir="up").inc(len(args) + len(extra))
         self.decode_steps += 1
         self.burst_dispatches += 1
         self._m_burst_disp.inc()
         self._m_burst_ticks.observe(n_ticks)
-        with prof.phase("readback", sessions=n) as read:
-            res, token = self._burst_collect(rows, packed, n_ticks)
+        return _Burst(rows, packed, n_ticks, rider, ahead, fence,
+                      (built, issued, ran))
+
+    def burst_fetch(self, flight: "_Burst") -> None:
+        """The one step of a burst that BLOCKS, and the one its caller runs
+        with the adapter's lock free: the packed results read to the host
+        (``flight.flat``), which takes as long as the burst's ticks and
+        whatever lay ahead of them on the device. It touches no slot table.
+        Profiled, the two fences first, inside phase ``device``:
+        ``device_queued`` is the wait for a prompt's programs enqueued
+        AHEAD of the burst (on one in-order queue their end is the burst's
+        start; the results complete in order, so no wait is added). A
+        failure is kept for `burst_collect`, which recovers under the lock
+        what this step may not touch."""
+        prof = _get_profiler()
+        n = len(flight.rows)
+        try:
+            with flight.fence:
+                with prof.phase("device_queued", sessions=n) as queued:
+                    if prof.enabled:
+                        jax.block_until_ready(flight.ahead)  # None: no wait
+                if prof.enabled:
+                    jax.block_until_ready(flight.packed)
+            with prof.phase("readback", sessions=n) as read:
+                flight.flat = np.asarray(flight.packed)
+            flight.timed += (queued, read)
+        except Exception as exc:
+            flight.error = exc
+
+    def burst_collect(self, flight: "_Burst") -> Dict[str, dict]:
+        """The second half of `decode_burst`, under the adapter's lock
+        again: what `burst_fetch` read goes into the slot tables and the
+        counters, the rider's length is set, and the result is what
+        `decode_burst` returns. A failure of the fetch is raised here, the
+        rider's slot recovered first (`_recover_slot`)."""
+        prof = _get_profiler()
+        rider = flight.rider
+        try:
+            if flight.error is not None:
+                raise flight.error
+            gone = [sid for sid, s in flight.rows.items()
+                    if self._slot_of.get(sid) != s]
+            if gone:        # `_recover_slot` took the stacks meanwhile
+                raise RuntimeError(
+                    f"sessions {gone} lost their slots while their burst "
+                    "ran (a failed prefill rebuilt the stacks)")
+            self._m_transfers.labels(dir="down").inc()
+            with prof.phase("readback", sessions=len(flight.rows)) as read:
+                res, token = self._burst_collect(
+                    flight.rows, flight.flat, flight.n_ticks)
+                if rider is not None:
+                    t = len(rider["ids"])
+                    self.lengths[rider["slot"]] = t
+                    res[rider["session_id"]] = {"token": token,
+                                                "cache_len": t}
+        except Exception:
             if rider is not None:
-                t = len(rider["ids"])
-                self.lengths[rider["slot"]] = t
-                res[rider["session_id"]] = {"token": token, "cache_len": t}
+                self._recover_slot(rider["session_id"], rider["slot"])
+            raise
         # What this burst's wall time was made of, where the profiler has
         # just measured it (``queued`` + ``device``: enqueue returned ->
-        # results ready).
-        self.burst_parts = dict(zip(STALL_PARTS, (
-            built.seconds, issued.seconds, queued.seconds,
-            ran.seconds - issued.seconds - queued.seconds,
-            read.seconds))) if prof.enabled else None
+        # results ready; ``readback``: the read and the tables, not the
+        # wait for the lock between them).
+        self.burst_parts = None
+        if prof.enabled:
+            built, issued, ran, queued, fetched = flight.timed
+            self.burst_parts = dict(zip(STALL_PARTS, (
+                built.seconds, issued.seconds, queued.seconds,
+                ran.seconds - issued.seconds - queued.seconds,
+                fetched.seconds + read.seconds)))
         return res
 
     # ------------------------------------------------------------------
@@ -3178,7 +3272,7 @@ class _Round:
 
     __slots__ = ("reqs", "outs", "err", "bad", "lengths", "spec", "event",
                  "closed", "t_open", "t_exec", "t_done", "rider", "rejoined",
-                 "back")
+                 "back", "flight")
 
     def __init__(self):
         self.reqs: Dict[str, Any] = {}
@@ -3189,10 +3283,13 @@ class _Round:
         self.bad: Dict[str, str] = {}             # per-session exclusions
         self.event = threading.Event()
         self.closed = False
-        self.t_open = time.monotonic()   # opened: its first session is in
+        # opened: its first session is in (under a step in flight: that
+        # step is collected, `_close_round`)
+        self.t_open = time.monotonic()
         self.t_exec = 0.0    # monotonic instant the round's step started
         self.t_done = 0.0    # ... and the instant its results were read
         self.rider = None    # a burst round's ONE joining request (prefill)
+        self.flight = None   # a burst round's burst, enqueue -> collect
         # Holds a session that the last round of its key answered (`_join`):
         # only then is the time since that round a PERIOD of the machine.
         self.rejoined = False
@@ -3209,7 +3306,10 @@ class _SlotArenaView:
     iteration there can raise mid-resize), but with a BOUNDED wait: the
     adapter holds its lock across whole prefill dispatches (including
     compiles), and blocking the heartbeat thread past the registry TTL would
-    expire a healthy server. A busy adapter returns the last known value."""
+    expire a healthy server. A busy adapter returns the last known value.
+    Since a round's leader lets go of the lock while its step runs on the
+    device the wait rarely times out: what is left to outlast it is a
+    program that compiles under the lock."""
 
     def __init__(self, inner: BatchedStageExecutor, lock: threading.Lock):
         self._inner = inner
@@ -3233,7 +3333,15 @@ class BatchingStageAdapter:
     that width answered are back (at most `REJOIN_SHARE` of that round's
     wall time after their reply; ``window_s`` where nobody is on the way:
     `_close_round`), runs ONE `decode_batch`, and every waiter picks up its
-    own row. Draft steps
+    own row. The leader holds the adapter's lock to BUILD and ENQUEUE its
+    round's step and to COLLECT the results, and lets go of it while the
+    device runs the step: a prompt of another session takes a slot and
+    enqueues its programs meanwhile, BEHIND the running step on the device's
+    in-order queue and not ahead of the next one. What the held lock used to
+    guard is said by ``_flying``, the ONE step in flight and its sessions:
+    no other round closes, and nothing frees, gives anew, rewinds or
+    validates against the slot of a session in it, before it is collected
+    (`_landed_locked`). Draft steps
     (width K+1) coalesce with each other; the final stage verifies each
     row and rewinds its slot past the rejected tail before releasing
     waiters. Beam/training/replay/sub-span requests are refused with a
@@ -3255,8 +3363,14 @@ class BatchingStageAdapter:
         self.requests_served = 0
         self._lock = threading.Lock()
         # What a round's leader waits on while its round is open: a join, a
-        # drop and a prefill of a session it waits for notify it.
+        # drop and a prefill of a session it waits for notify it; and what
+        # whoever needs a step in flight to be over waits on: its collect
+        # notifies.
         self._cond = threading.Condition(self._lock)
+        # The step in flight, (round key, its sessions): enqueued, the lock
+        # let go of, not collected yet. None between a collect and the next
+        # enqueue. At most one at a time, whatever the rounds' keys.
+        self._flying: Optional[Tuple[Any, frozenset]] = None
         # Open coalescing rounds, keyed by step width T (classic decode /
         # speculative verify) or ('burst', N) (burst rounds never share a
         # compiled program with single-tick rounds).
@@ -3283,6 +3397,7 @@ class BatchingStageAdapter:
         self._m_ids_read = _tm.get("server_burst_transfers_total").labels(
             dir="down")
         self._m_round = _tm.get("server_decode_round_seconds")
+        self._m_enqueued = _tm.get("server_prefill_enqueued_total")
         self._m_behind = _tm.get("server_round_behind_prefill_seconds")
         self._m_closed = _tm.get("server_round_closed_total")
         self._m_rejoin = _tm.get("server_round_rejoin_seconds")
@@ -3413,6 +3528,7 @@ class BatchingStageAdapter:
 
     def drop_session(self, session_id: str) -> None:
         with self._lock:
+            self._landed_locked(session_id)
             self.inner.end_session(session_id)
             self._forget_locked(session_id)
 
@@ -3429,12 +3545,32 @@ class BatchingStageAdapter:
         ``key`` next (None: of any width)."""
         self._replied[sid] = (key, t)
 
+    def _landed_locked(self, sid: str) -> None:
+        """Caller holds the lock and is about to touch ``sid``'s slot: free
+        it, give it anew, rewind it, or validate a request against its
+        length. Waits (the lock released) while the step in flight has
+        ``sid`` in it: its slot's rows are being written and its length is
+        the collect's to set."""
+        while self._flying is not None and sid in self._flying[1]:
+            self._cond.wait()
+
+    def _enqueues_locked(self) -> None:
+        """Caller holds the lock and enqueues a prompt's programs next:
+        BEHIND the step the device is running, or into the gap between two
+        (``server_prefill_enqueued_total{during}``: the share of prompts
+        that the free lock engages for)."""
+        self._m_enqueued.labels(
+            during="gap" if self._flying is None else "burst").inc()
+
     def _in_a_hold_locked(self) -> float:
-        """Now, where a round of any key is open and not closed: its
-        leader's hold pays for what the caller does under the lock (a
-        prefill's program: ``server_round_hold_prefill_seconds``). Else
-        0.0."""
-        return time.monotonic() if self._rounds else 0.0
+        """Now, where a round of any key is open and not closed, and no
+        step is in flight: its leader's hold pays for what the caller does
+        under the lock (a prefill's program:
+        ``server_round_hold_prefill_seconds``). Else 0.0: a round that was
+        opened under a running step waits for the collect whatever the
+        caller does."""
+        return (time.monotonic() if self._rounds and self._flying is None
+                else 0.0)
 
     def _join(self, r: _Round, req, key) -> None:
         """Caller holds the lock and has put ``req`` into ``r``, the open
@@ -3473,7 +3609,12 @@ class BatchingStageAdapter:
 
     def _close_round(self, r: _Round, key, sid: str) -> None:
         """The ONE place a round decides to close (its leader, holding the
-        lock; waits release it, so joins and prefills go on). The round
+        lock; waits release it, so joins and prefills go on). A round that
+        was opened while a step is in flight (a session whose first token
+        came meanwhile; a rider) first waits for that step's collect, open
+        to joins all the while, and counts as opened then: the sessions
+        that step answered are waited for as after any round, and no round
+        runs with a rider alone beside a running one. The round
         stays open while a session is on its way back (`_returning`) and
         closes the moment the last of them is in: no sleep after it. The
         bound on that wait is the engine's own measurement: `REJOIN_SHARE`
@@ -3485,6 +3626,10 @@ class BatchingStageAdapter:
         it always was. Counted by what closed it
         (``server_round_closed_total{by}``)."""
         with _get_profiler().span("round_window", session=sid):
+            if self._flying is not None:
+                while self._flying is not None:
+                    self._cond.wait()
+                r.t_open = time.monotonic()
             now = time.monotonic()
             last = self._last_round.get(key)
             bound = (max(self.window_s, REJOIN_SHARE * last[1])
@@ -3503,6 +3648,9 @@ class BatchingStageAdapter:
                 if any(s in self._replied for s in back if s not in still):
                     by = "bound"       # somebody's time ran out, still away
                 back = still
+            # A round of ANOTHER key may have gone up during those waits.
+            while self._flying is not None:
+                self._cond.wait()
         self._m_closed.labels(by=by).inc()
         r.closed = True
         if self._rounds.get(key) is r:
@@ -3606,13 +3754,18 @@ class BatchingStageAdapter:
         if self._rides(req):
             return self._prefill_riding(req)
         # A request's life up to its first token, as three phases: the wait
-        # for the lock (a round leader holds it through its whole step,
-        # readback included), the prefill under it, the first token after it
-        # (and inside that one, profiled, the prompt's programs finishing).
+        # for the lock (a round leader holds it to build and enqueue its
+        # step and to collect the results, NOT while the device runs it:
+        # the wait is for a build, a collect or another prompt, or, for a
+        # session that is in the step in flight itself, for that step's
+        # collect), the prefill under it, the first token after it (and
+        # inside that one, profiled, the prompt's programs finishing).
         with prof.phase("prefill_wait", session=sid):
             self._lock.acquire()  # slot tables + cache arrays: shared state
+            self._landed_locked(sid)
         t_held = self._in_a_hold_locked()
         try:
+            self._enqueues_locked()
             self._forget_locked(sid)   # a new prompt: not on its way back
             with prof.phase("prefill", session=sid):
                 try:
@@ -3644,9 +3797,12 @@ class BatchingStageAdapter:
                         jax.block_until_ready(h)
                 resp = self._respond(req, h, cache_len)
         # The session asks for a round from now on: on record if the lock
-        # is free (a leader that is waiting then waits for this one too).
-        # Busy means a round is running, and whoever asks during one queues
-        # for the next anyway: a first token never waits for the lock.
+        # is free (a leader that is waiting then waits for this one too; one
+        # whose round opens under a running step finds the record at that
+        # step's collect, unless the session has joined by then). Busy
+        # means a build, a collect or a prompt, a few ms: whoever asks then
+        # joins the open round or the next anyway, and a first token never
+        # waits for the lock.
         if self._lock.acquire(blocking=False):
             try:
                 self._replied_locked(sid, None, time.monotonic())
@@ -3673,9 +3829,10 @@ class BatchingStageAdapter:
     def _prefill_riding(self, req):
         """A prefill as the rider of the next burst round (`_burst_round`).
         Its three phases: ``prefill_wait`` from entry until that round's
-        step starts (the lock, the lane if another request has it, the
-        window), ``prefill`` the step under the lock, which writes the
-        prompt's rows and samples the token, ``first_token`` from the
+        step starts (a step in flight, the lane if another request has it,
+        the window), ``prefill`` the step from its enqueue to its collect,
+        which writes the prompt's rows and samples the token (the lock is
+        free while the device runs it), ``first_token`` from the
         results on the host to the response (``prefill_ready`` inside it
         is 0: the prompt has no program of its own to wait for)."""
         from .messages import StageResponse
@@ -3738,7 +3895,8 @@ class BatchingStageAdapter:
                     f"server {cur} (stale retry?)")
         return None
 
-    def _round(self, req, key, validate, step, rider: bool = False) -> _Round:
+    def _round(self, req, key, validate, enqueue, collect,
+               rider: bool = False) -> _Round:
         """Take ``req`` through one round of ``key`` (a step's width T, or
         ``("burst", N)``) as its leader (whoever CREATES it) or a follower,
         and return the round once its step has run; raises what the round
@@ -3746,18 +3904,26 @@ class BatchingStageAdapter:
         caller states ``validate(rq)``, a member's admission under the lock
         (a refusal reason or None; the leader asks again once the round has
         closed: a session may have been dropped since it joined, and an
-        exclusion fails ONLY its own waiter), and ``step(r, good, riding)``,
-        the engine's call on the admitted members and the rider's entry (or
-        None) into ``r.outs``, under the lock: ``(back, parts)``, what
-        `_answered` takes. ``rider``: ``req`` is a PREFILL that joins a
-        burst round as its rider (`_rides`); a round carries one, a second
-        waits for that round to run and tries the next."""
+        exclusion fails ONLY its own waiter), and the step's two halves,
+        both under the lock: ``enqueue(r, good, riding)``, the engine's
+        call on the admitted members and the rider's entry (or None), which
+        returns what the leader calls with the lock FREE and blocks in
+        until the results are on the host (None: nothing to wait for), and
+        ``collect(r, good, riding)``, the results into ``r.outs``:
+        ``(back, parts)``, what `_answered` takes. In between the step is
+        on record as the one in flight (``_flying``). ``rider``: ``req`` is
+        a PREFILL that joins a burst round as its rider (`_rides`); a round
+        carries one, a second waits for that round to run and tries the
+        next."""
         from .executor import StageExecutionError
 
         sid = req.session_id
         t_join = time.monotonic()
         while True:
             with self._lock:
+                # a retry of a session in flight waits for the lengths the
+                # collect writes (and is then refused, as it always was)
+                self._landed_locked(sid)
                 reason = None if rider else validate(req)
                 if reason is not None:
                     raise StageExecutionError(reason)
@@ -3784,35 +3950,7 @@ class BatchingStageAdapter:
             # exception anywhere (not just inside the engine's call) must
             # still release the followers, else they block for step_timeout.
             try:
-                with self._lock:
-                    self._close_round(r, key, sid)
-                    good = {}
-                    for s_id, rq in r.reqs.items():
-                        reason = validate(rq)
-                        if reason is None:
-                            good[s_id] = rq
-                        else:
-                            r.bad[s_id] = reason
-                    riding = None
-                    if r.rider is not None:
-                        reason = self._validate_rider(r, key[1])
-                        if reason is None:
-                            riding = _rider_entry(r.rider,
-                                                  self._m_ids_read)
-                        else:
-                            r.bad[r.rider.session_id] = reason
-                    if good or riding:
-                        self._step_starts(r, key)
-                        if good:
-                            self._m_fill.observe(len(good))
-                            self._m_held.observe(len(self.inner._slot_of))
-                        back, parts = step(r, good, riding)
-                        r.lengths = {
-                            s_id: int(
-                                self.inner.lengths[self.inner.slot(s_id)])
-                            for s_id in good
-                        }
-                        self._answered(r, key, back, parts)
+                self._lead(r, key, sid, validate, enqueue, collect)
             except Exception as exc:  # whole-round failure
                 r.err = exc
                 with self._lock:  # a dead round must not accept joiners
@@ -3834,20 +3972,71 @@ class BatchingStageAdapter:
             raise StageExecutionError(r.bad[sid])
         return r
 
+    def _lead(self, r: _Round, key, sid: str, validate, enqueue,
+              collect) -> None:
+        """The leader's way through round ``r`` (`_round`): close it, admit
+        its members and enqueue their step under the lock; wait for the
+        results with the lock FREE, the step on record as the one in flight;
+        collect them under the lock again, which ends the flight whatever
+        either half raised (the engine recovers a rider's slot in its own
+        collect), and wakes whoever waited for it."""
+        with self._lock:
+            self._close_round(r, key, sid)
+            good = {}
+            for s_id, rq in r.reqs.items():
+                reason = validate(rq)
+                if reason is None:
+                    good[s_id] = rq
+                else:
+                    r.bad[s_id] = reason
+            riding = None
+            if r.rider is not None:
+                reason = self._validate_rider(r, key[1])
+                if reason is None:
+                    riding = _rider_entry(r.rider, self._m_ids_read)
+                else:
+                    r.bad[r.rider.session_id] = reason
+            if not (good or riding):
+                return
+            self._step_starts(r, key)
+            if good:
+                self._m_fill.observe(len(good))
+                self._m_held.observe(len(self.inner._slot_of))
+            landed = enqueue(r, good, riding)
+            self._flying = (key, frozenset(good).union(
+                [riding["session_id"]] if riding else ()))
+        try:
+            if landed is not None:
+                landed()
+        finally:
+            with self._lock:
+                self._flying = None
+                self._cond.notify_all()
+                back, parts = collect(r, good, riding)
+                r.lengths = {
+                    s_id: int(self.inner.lengths[self.inner.slot(s_id)])
+                    for s_id in good
+                }
+                self._answered(r, key, back, parts)
+
     def _decode(self, req):
         """A step of width T = ``req.seq_len`` (plain decode, a speculative
         verify, a replay chunk) through the round of that width."""
         from .messages import StageResponse
 
-        def step(r, good, riding):
+        def enqueue(r, good, riding):
+            # nothing of a step is read back under the lock: each waiter
+            # reads its own row after the round (`_respond`)
             r.outs = self.inner.decode_batch(
                 {s_id: rq.hidden for s_id, rq in good.items()})
             if self.spec.is_last:
                 self._verify_spec_rows(r, good)
+
+        def collect(r, good, riding):
             return good, None   # a step's reply never says it was the last
 
         sid = req.session_id
-        r = self._round(req, req.seq_len, self._validate, step)
+        r = self._round(req, req.seq_len, self._validate, enqueue, collect)
         if sid in r.spec:
             tokens, n_acc = r.spec[sid]
             return self._stamped(r, StageResponse(
@@ -3893,10 +4082,14 @@ class BatchingStageAdapter:
         def validate(rq):
             return self._validate(rq) or self._validate_burst(rq)
 
-        def step(r, good, riding):
-            r.outs = self.inner.decode_burst(
+        def enqueue(r, good, riding):
+            r.flight = self.inner.burst_enqueue(
                 {s_id: _burst_entry(rq, self._m_ids_read)
                  for s_id, rq in good.items()}, n, rider=riding)
+            return partial(self.inner.burst_fetch, r.flight)
+
+        def collect(r, good, riding):
+            r.outs = self.inner.burst_collect(r.flight)
             # Back for the next round: the rider, with its first token, and
             # every session whose burst did not end its request (no stop,
             # and a budget of a whole burst: a client asks for min(burst,
@@ -3908,7 +4101,8 @@ class BatchingStageAdapter:
                 back.append(riding["session_id"])
             return back, self.inner.burst_parts
 
-        return self._round(req, ("burst", n), validate, step, rider)
+        return self._round(req, ("burst", n), validate, enqueue, collect,
+                           rider)
 
     def _verify_spec_rows(self, r: _Round, good: Dict[str, Any]) -> None:
         """Per-row speculative verification on the final stage (caller holds

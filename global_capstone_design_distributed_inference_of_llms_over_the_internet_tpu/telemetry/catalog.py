@@ -117,6 +117,12 @@ SPEC: Dict[str, Tuple[str, str, Tuple[str, ...], Optional[Sequence[float]]]] = {
                    "Its count and sum are parts of "
                    "server_decode_round_seconds'; the rest are the clear "
                    "rounds.", (), FAST_BUCKETS),
+    "server_prefill_enqueued_total": (
+        COUNTER, "Prefills that took the batched engine's lock for a "
+                 "program of their own, by what the device was doing: "
+                 "burst, a round's step was in flight and the prompt's "
+                 "programs went BEHIND it; gap, between a collect and the "
+                 "next enqueue.", ("during",), None),
     "server_round_stalls_total": (
         COUNTER, "Rounds whose wall time was over 4 x that of the last "
                  "round of their width, by whether their program was "

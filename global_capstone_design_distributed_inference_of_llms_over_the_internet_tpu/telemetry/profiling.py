@@ -6,7 +6,9 @@ path into named phases —
 
   * ``gateway_queue`` — admission to first pipeline step (serving/gateway.py)
   * ``prefill_wait``  — a prefill's wait for the batched stage's lock
-                        (runtime/batching.py ``_prefill``: entry -> lock held)
+                        (runtime/batching.py ``_prefill``: entry -> lock
+                        held; a round's leader lets go of it while its step
+                        runs on the device)
   * ``prefill``       — the prefill under that lock: slot, prefix store,
                         program dispatch (the host returns at enqueue)
   * ``first_token``   — a last stage's head + host-side sampling of the
@@ -23,7 +25,9 @@ path into named phases —
                         programs enqueued AHEAD of the burst finished (on
                         one in-order queue: the burst starts); 0 where
                         nothing was ahead
-  * ``readback``      — device buffers to host tokens (``_burst_collect``)
+  * ``readback``      — device buffers to host tokens: the read
+                        (``burst_fetch``, the lock free) and the tables
+                        (``burst_collect``), two brackets a round
   * ``socket``        — client-observed request/response turnaround per hop
   * ``server``        — the whole serving boundary (validate + forward +
                         respond, runtime/transport.py)

@@ -25,8 +25,12 @@ host phases + ticks + rest, the rest being the launch; a prompt's device
 time over the whole window against the traced stretch's); what the program
 recorded of its stalls (rounds over 4 x their predecessor, those behind a
 prompt apart, seconds by part); and the host-device transfers a burst
-round issued, up and down (``transfers_per_round``). One JSON line a run;
-reads files only."""
+round issued, up and down (``transfers_per_round``); and the prompts that
+took the engine's lock for a program of their own, by whether a round's
+step was in flight then (``prefill_enqueued``: ``burst``, behind the running
+step; ``gap``, between two; ``burst_share`` is the share of prompts for
+which the lock that is free under a running step engages). One JSON line a
+run; reads files only."""
 
 import json
 import os
@@ -204,6 +208,22 @@ def transfers_report(ctx: dict) -> dict:
             for d, n in moved.items()}
 
 
+def prefills_report(ctx: dict) -> dict:
+    """The window's prompts that enqueued a program of their own, by what
+    the device was doing when they took the engine's lock
+    (`server_prefill_enqueued_total`); None where a run's scrapes lack the
+    series (a program whose leader held the lock through its step), a share
+    only where some prompt was counted."""
+    by = {d: readers.counter_delta(
+              ctx, f'server_prefill_enqueued_total{{during="{d}"}}')
+          for d in ("burst", "gap")}
+    if all(n is None for n in by.values()):
+        return {"burst": None, "gap": None, "burst_share": None}
+    by = {d: n or 0.0 for d, n in by.items()}     # a child: once counted
+    total = sum(by.values())
+    return {**by, "burst_share": by["burst"] / total if total else None}
+
+
 def rounds_report(run_dir: str, man: Manifest, gap_p50_ms=None) -> dict:
     w0, w1 = window_of(run_dir)
     ctx = {"w0": w0, "w1": w1,
@@ -234,6 +254,7 @@ def rounds_report(run_dir: str, man: Manifest, gap_p50_ms=None) -> dict:
     out["identities"] = identities(ctx, man, gap_p50_ms)
     out["stalls_recorded"] = stalls_report(ctx)
     out["transfers_per_round"] = transfers_report(ctx)
+    out["prefill_enqueued"] = prefills_report(ctx)
     return out
 
 

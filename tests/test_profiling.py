@@ -514,9 +514,12 @@ def test_round_follower_wait_is_a_span(monkeypatch):
     names = [n for n, _ in spans.made]
     assert names.count("stage.round_window") == 1
     assert not [n for n in names if "wait" in n]
+    # ``readback`` twice: the read, with the lock free, and the tables,
+    # under it again
     assert sorted(names) == sorted(
         "stage." + p for p in ("round_window", "burst_build", "device",
-                               "device_queued", "dispatch", "readback"))
+                               "device_queued", "dispatch", "readback",
+                               "readback"))
     assert ("stage.dispatch", {"sessions": 2}) in spans.made
     # what the engine keeps of the burst for a stall's record
     parts = adapter.inner.burst_parts

@@ -110,9 +110,15 @@ class SlotsOnly(batching.BatchedStageExecutor):
             self.lengths[self._slot_of[sid]] += np.shape(h)[1]
         return dict(hidden)
 
-    def decode_burst(self, entries, n_ticks, rider=None):
+    def burst_enqueue(self, entries, n_ticks, rider=None):
         self._begins(entries, rider and rider["session_id"])
-        time.sleep(self.round_s)
+        return entries, n_ticks, rider
+
+    def burst_fetch(self, flight):
+        time.sleep(self.round_s)       # the device: the lock is free
+
+    def burst_collect(self, flight):
+        entries, n_ticks, rider = flight
         out = {}
         for sid, e in entries.items():
             s = self._slot_of[sid]
@@ -512,12 +518,8 @@ def test_the_reply_record_goes_with_the_slot(kind):
     assert ad._replied == {}
 
 
-def test_the_report_prints_a_round_s_transfers_over_its_dispatches():
-    """`scripts/round_close_report.py` ``transfers_per_round``: what moved
-    of `server_burst_transfers_total` in the window, per direction, over
-    the burst programs dispatched in it, summed over the servers; None
-    where a run's scrapes lack the series (a program before PR 49) or no
-    burst ran."""
+def report_module():
+    """`scripts/round_close_report.py`, loaded from its file."""
     import importlib.util
     import os
 
@@ -526,6 +528,16 @@ def test_the_report_prints_a_round_s_transfers_over_its_dispatches():
     spec = importlib.util.spec_from_file_location("round_close_report", path)
     report = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(report)
+    return report
+
+
+def test_the_report_prints_a_round_s_transfers_over_its_dispatches():
+    """`scripts/round_close_report.py` ``transfers_per_round``: what moved
+    of `server_burst_transfers_total` in the window, per direction, over
+    the burst programs dispatched in it, summed over the servers; None
+    where a run's scrapes lack the series (a program before PR 49) or no
+    burst ran."""
+    report = report_module()
     up, down = ('server_burst_transfers_total{dir="up"}',
                 'server_burst_transfers_total{dir="down"}')
     rounds = "server_burst_dispatches_total"

@@ -246,8 +246,8 @@ class Skips(SlotsOnly):
     def decode_batch(self, hidden):
         return self._round(super().decode_batch, hidden)
 
-    def decode_burst(self, entries, n_ticks, rider=None):
-        return self._round(super().decode_burst, entries, n_ticks, rider)
+    def burst_fetch(self, flight):
+        return self._round(super().burst_fetch, flight)
 
 
 @pytest.mark.parametrize("kind, profiled", [

@@ -292,9 +292,9 @@ class Queued(Skips):
         self._dispatched()
         return super().decode_batch(hidden)
 
-    def decode_burst(self, entries, n_ticks, rider=None):
+    def burst_enqueue(self, entries, n_ticks, rider=None):
         self._dispatched()
-        return super().decode_burst(entries, n_ticks, rider)
+        return super().burst_enqueue(entries, n_ticks, rider)
 
 
 def queued(monkeypatch, kind="burst", **kw):
